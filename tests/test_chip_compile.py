@@ -1,0 +1,156 @@
+"""The Pallas kernels compile for the chip — asked of the chip's own
+compiler, with no chip attached.
+
+Interpret mode (every other kernel test) cannot see what Mosaic refuses:
+a slice that is not tile-aligned, or more scoped VMEM than a kernel may
+use.  Here each kernel of the serving path is AOT-compiled for a described
+TPU v5e at the widths of the models the engine serves (Qwen3-0.6B, the
+flagship; Llama-3.1-8B, the registered model that needs tp) and at the
+sizes the engine dispatches, with ``interpret=False``.  Nothing runs: a
+compile that passes says nothing about results or times.
+
+All AOT compiles live in this ONE file: only one process may load the
+TPU's library, so the topology is described inside a fixture, by the one
+xdist worker that is given this file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# (num_q_heads, num_kv_heads, head_dim)
+WIDTHS = {
+    "qwen3-0.6b": (16, 8, 128),
+    "llama-8b": (32, 8, 128),
+    "llama-8b-tp4": (8, 2, 128),     # one shard of the four-chip smoke
+}
+PAGE = 32            # server default --block-size
+NUM_BLOCKS = 2048    # server default --num-blocks
+MAX_PAGES = 128      # 4096-token sequences
+MAX_NUM_SEQS = 64    # SchedulerConfig.max_num_seqs
+CHUNK = 2048         # SchedulerConfig.prefill_chunk_size
+MIN_BUCKET = 32      # SchedulerConfig.min_prefill_bucket
+MIXED_BUDGET = 512   # SchedulerConfig.mixed_token_budget
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without a chip: keep it out of these."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _decode(S, hq, hkv, d, quantized):
+    from tpuserve.ops.pallas_paged_attention import paged_decode_attention
+    B = MAX_NUM_SEQS
+    pages, scales = _cache(S, hkv, d, quantized)
+    return (lambda q, k, v, bt, sl, *s: paged_decode_attention(
+        q, k, v, bt, sl, d ** -0.5, interpret=False, **_scales(s)),
+        [S((B, hq, d), jnp.bfloat16), *pages, S((B, MAX_PAGES), jnp.int32),
+         S((B,), jnp.int32), *scales])
+
+
+def _flash(S, hq, hkv, d, quantized):
+    from tpuserve.ops.pallas_flash_attention import flash_prefill_attention
+    B, T = 8, 1024       # max_prefill_seqs x (max_prefill_tokens / 8)
+    kv = S((B, T, hkv, d), jnp.bfloat16)
+    return (lambda q, k, v, n: flash_prefill_attention(
+        q, k, v, n, d ** -0.5, interpret=False),
+        [S((B, T, hq, d), jnp.bfloat16), kv, kv, S((B,), jnp.int32)])
+
+
+def _window(S, hq, hkv, d, quantized, C=CHUNK):
+    from tpuserve.ops.pallas_chunked_prefill import paged_window_attention
+    lens = S((1,), jnp.int32)       # the engine chunks one sequence at a time
+    pages, scales = _cache(S, hkv, d, quantized)
+    return (lambda q, k, v, bt, cx, ck, *s: paged_window_attention(
+        q, k, v, bt, cx, ck, d ** -0.5, interpret=False, **_scales(s)),
+        [S((1, C, hq, d), jnp.bfloat16), *pages,
+         S((1, MAX_PAGES), jnp.int32), lens, lens, *scales])
+
+
+def _tail(S, hq, hkv, d, quantized):
+    """The window kernel at the smallest chunk bucket: the tail of a long
+    prompt, or the few tokens a prefix-cache hit leaves to compute."""
+    return _window(S, hq, hkv, d, quantized, C=MIN_BUCKET)
+
+
+def _ragged(S, hq, hkv, d, quantized, monkeypatch):
+    from tpuserve.ops import pallas_ragged_attention as ragged
+    # ragged_block() asks jax.default_backend(), which is the CPU here:
+    # steer it to the block the engine packs with on a TPU
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        blk = ragged.ragged_block()
+    T, B = MIXED_BUDGET, MAX_NUM_SEQS
+    seq = S((B,), jnp.int32)
+    pages, scales = _cache(S, hkv, d, quantized)
+    return (lambda q, k, v, bt, kl, qs, ql, m, bs, *s:
+            ragged.ragged_paged_attention(
+                q, k, v, bt, kl, qs, ql, m, bs, d ** -0.5, interpret=False,
+                blk_q=blk, **_scales(s)),
+            [S((T, hq, d), jnp.bfloat16), *pages,
+             S((B, MAX_PAGES), jnp.int32), seq, seq, seq,
+             S((2,), jnp.int32), S((T // blk,), jnp.int32), *scales])
+
+
+def _cache(S, hkv, d, quantized):
+    """([k, v] page arrays, [k_scale, v_scale] — empty unless int8)."""
+    from tpuserve.ops.attention import SCALE_LANES
+    page = S((NUM_BLOCKS, PAGE, hkv, d),
+             jnp.int8 if quantized else jnp.bfloat16)
+    scale = S((NUM_BLOCKS, PAGE, SCALE_LANES), jnp.float32)
+    return [page, page], [scale, scale] if quantized else []
+
+
+def _scales(s):
+    return dict(k_scale=s[0], v_scale=s[1]) if s else {}
+
+
+CASES = [(kernel, width, False)
+         for kernel in ("decode", "flash", "window", "tail", "ragged")
+         for width in WIDTHS
+         # the ragged kernel has no tp wrapper (mixed steps run reference
+         # attention under a mesh), so a tp shard never reaches it
+         if (kernel, width) != ("ragged", "llama-8b-tp4")]
+CASES += [(kernel, width, True)
+          for kernel in ("decode", "window", "ragged")
+          for width in ("qwen3-0.6b", "llama-8b")]
+
+
+@pytest.mark.parametrize(
+    "kernel,width,quantized", CASES,
+    ids=[f"{k}-{w}{'-int8kv' if q else ''}" for k, w, q in CASES])
+def test_kernel_compiles_for_v5e(kernel, width, quantized, one_chip,
+                                 monkeypatch):
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    build = {"decode": _decode, "flash": _flash, "window": _window,
+             "tail": _tail, "ragged": _ragged}[kernel]
+    extra = (monkeypatch,) if kernel == "ragged" else ()
+    fn, args = build(S, *WIDTHS[width], quantized, *extra)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -1,7 +1,6 @@
 """Persistent grammar-FSM compile cache (runtime/grammar/cache.py): disk
 entries keyed by (spec hash, tokenizer fingerprint) skip the inline
-determinizing walk — the BENCHMARKS.md round-6 production-vocab
-follow-up."""
+determinizing walk at production-vocab size."""
 
 import dataclasses
 

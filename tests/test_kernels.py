@@ -90,7 +90,7 @@ def test_paged_decode_int8_matches_reference():
     """int8 cache path: the Pallas kernel DMAs int8 pages + scale blocks
     and dequantizes in VMEM; must match the reference impl fed the same
     quantized cache bit-for-bit (both dequantize identically)."""
-    from tpuserve.ops.attention import quantize_kv
+    from tpuserve.ops.attention import pad_scale_lanes, quantize_kv
     B, Hq, Hkv, D, page, nb, mp = 5, 4, 2, 128, 8, 64, 8
     rng = np.random.default_rng(23)
     q = jnp.asarray(rng.standard_normal((B, Hq, D)), jnp.float32)
@@ -98,6 +98,7 @@ def test_paged_decode_int8_matches_reference():
     vc = jnp.asarray(rng.standard_normal((nb, page, Hkv, D)), jnp.float32)
     kq, ks = quantize_kv(kc)
     vq, vs = quantize_kv(vc)
+    ks, vs = pad_scale_lanes(ks), pad_scale_lanes(vs)
     bt = jnp.asarray(rng.integers(0, nb, (B, mp)), jnp.int32)
     sl = jnp.asarray(rng.integers(1, page * mp + 1, (B,)), jnp.int32)
     ref = ref_ops.paged_decode_attention(q, kq, vq, bt, sl, D ** -0.5,
@@ -114,7 +115,7 @@ def test_paged_decode_int8_matches_reference():
 
 def test_paged_window_int8_matches_reference():
     """int8 cache in the chunked-prefill/verify window kernel."""
-    from tpuserve.ops.attention import quantize_kv
+    from tpuserve.ops.attention import pad_scale_lanes, quantize_kv
     from tpuserve.ops.pallas_chunked_prefill import paged_window_attention
     B, C, Hq, Hkv, D, page, nb, mp = 2, 16, 4, 2, 128, 8, 64, 8
     rng = np.random.default_rng(29)
@@ -123,6 +124,7 @@ def test_paged_window_int8_matches_reference():
     vc = jnp.asarray(rng.standard_normal((nb, page, Hkv, D)), jnp.float32)
     kq, ks = quantize_kv(kc)
     vq, vs = quantize_kv(vc)
+    ks, vs = pad_scale_lanes(ks), pad_scale_lanes(vs)
     bt = jnp.asarray(rng.integers(0, nb, (B, mp)), jnp.int32)
     ctx = jnp.asarray([9, 0], jnp.int32)
     chunk = jnp.asarray([C, C - 3], jnp.int32)
@@ -139,24 +141,33 @@ def test_paged_window_int8_matches_reference():
 
 
 def test_paged_decode_vmem_clamp():
-    """Knob combinations whose scratch would blow the VMEM budget clamp
-    (with a warning) instead of reaching the compiler — the r3 sweep
-    measured a silent 40% collapse from an oversized sweep knob
-    (VERDICT r3 weak #5); the clamp turns that cliff into a bounded,
-    logged degradation."""
+    """Knob combinations whose footprint would blow the VMEM limit clamp
+    (with a warning) instead of reaching the compiler; the clamped sizes
+    fit the same model the compiler's limit is set from."""
     from tpuserve.ops.pallas_paged_attention import (
-        VMEM_BUDGET_BYTES, _clamp_to_vmem_budget)
+        VMEM_LIMIT_BYTES, _clamp_to_vmem_budget, vmem_footprint)
     # fp32 KV, page 32, 8 kv heads, D 128: one (K+V, double-buffered) page
     # group of 64 pages is 2*2*64*32*8*128*4 = 64 MiB >> any budget
-    pg, sp = _clamp_to_vmem_budget(64, 8, page_size=32, num_kv_heads=8,
-                                   head_dim=128, kv_itemsize=4,
-                                   num_q_heads=16, q_itemsize=4)
+    shape = dict(page_size=32, num_kv_heads=8, head_dim=128, kv_itemsize=4,
+                 num_q_heads=16, q_itemsize=4)
+    pg, sp = _clamp_to_vmem_budget(64, 8, **shape)
     assert pg < 64
-    kv = 2 * 2 * pg * 32 * 8 * 128 * 4
-    qo = 2 * 2 * sp * 16 * 128 * 4
-    assert kv + qo <= VMEM_BUDGET_BYTES
+    assert vmem_footprint(pg, sp, 1, **shape) <= VMEM_LIMIT_BYTES
     # in-budget knobs pass through untouched
     assert _clamp_to_vmem_budget(4, 8, 32, 8, 128, 2, 16, 2) == (4, 8)
+
+
+@pytest.mark.parametrize("hq,expect", [
+    (16, (16, 128)),     # Qwen3-0.6B: the default group and block fit
+    (32, (4, 128)),      # Llama-8B: twice the score rows, a shorter group
+    (64, (1, 64)),       # 70B-class: shrinks by the same rule, no constant
+])
+def test_window_clamp_shrinks_with_q_heads(hq, expect):
+    """The window/ragged clamp counts the f32 score tiles, so wider-q
+    models get a shorter page group from the shapes alone."""
+    from tpuserve.ops.pallas_paged_attention import _clamp_to_vmem_budget
+    assert _clamp_to_vmem_budget(16, 128, 32, 8, 128, 2, hq, 2,
+                                 rows_per_dot=True) == expect
 
 
 def test_paged_decode_vmem_clamp_end_to_end(caplog):
@@ -196,8 +207,8 @@ def test_paged_window_vmem_clamp(caplog):
     chunk = jnp.asarray([C], jnp.int32)
     ref = ref_ops.chunked_prefill_attention(q, kc, vc, bt, ctx, chunk,
                                             D ** -0.5)
-    # 256-page groups of fp32 KV = ~16.8 MiB of double-buffered scratch:
-    # over the 12 MiB budget, must clamp
+    # 256-page groups of fp32 KV = 64 MiB of double-buffered scratch:
+    # over the VMEM limit, must clamp
     with caplog.at_level(logging.WARNING, "tpuserve.ops.paged_attention"):
         out = paged_window_attention(q, kc, vc, bt, ctx, chunk, D ** -0.5,
                                      interpret=True, pages_per_group=256)
@@ -342,7 +353,7 @@ def test_paged_decode_sliding_window(W, spp):
 
 def test_paged_decode_sliding_window_int8():
     """Window + int8 cache compose (both alter the DMA schedule)."""
-    from tpuserve.ops.attention import quantize_kv
+    from tpuserve.ops.attention import pad_scale_lanes, quantize_kv
     B, Hq, Hkv, D, page, nb, mp = 3, 4, 2, 128, 4, 64, 16
     rng = np.random.default_rng(53)
     q = jnp.asarray(rng.standard_normal((B, Hq, D)), jnp.float32)
@@ -350,6 +361,7 @@ def test_paged_decode_sliding_window_int8():
     vc = jnp.asarray(rng.standard_normal((nb, page, Hkv, D)), jnp.float32)
     kq, ks = quantize_kv(kc)
     vq, vs = quantize_kv(vc)
+    ks, vs = pad_scale_lanes(ks), pad_scale_lanes(vs)
     bt = jnp.asarray(rng.integers(0, nb, (B, mp)), jnp.int32)
     sl = jnp.asarray([3, 30, page * mp], jnp.int32)
     ref = ref_ops.paged_decode_attention(q, kq, vq, bt, sl, D ** -0.5,
@@ -397,7 +409,7 @@ def _ragged_case(rng, n_dec, chunk_shapes, blk, Hq=4, Hkv=2, D=16, page=4,
     chunks) + descriptors, the way engine._run_mixed packs them.  Returns
     everything both the kernel and the reference need, plus the valid-row
     mask (padding rows are unspecified by contract)."""
-    from tpuserve.ops.attention import quantize_kv
+    from tpuserve.ops.attention import pad_scale_lanes, quantize_kv
     max_kv = max_kv or page * mp
     kc = jnp.asarray(rng.standard_normal((nb, page, Hkv, D)), jnp.float32)
     vc = jnp.asarray(rng.standard_normal((nb, page, Hkv, D)), jnp.float32)
@@ -405,7 +417,8 @@ def _ragged_case(rng, n_dec, chunk_shapes, blk, Hq=4, Hkv=2, D=16, page=4,
     if int8:
         kc, ks = quantize_kv(kc)
         vc, vs = quantize_kv(vc)
-        scales = dict(k_scale=ks, v_scale=vs)
+        scales = dict(k_scale=pad_scale_lanes(ks),
+                      v_scale=pad_scale_lanes(vs))
     kv_dec = rng.integers(1, max_kv + 1, size=n_dec)
     B = n_dec + len(chunk_shapes)
     starts, cursor = [], -(-n_dec // blk) * blk if n_dec else 0

@@ -364,26 +364,37 @@ def test_restore_aborted_request_still_commits(monkeypatch):
         eng._check_block_integrity()
 
 
-def test_int8_pages_demote_at_half_size():
-    cfg8 = EngineConfig(
-        model="tiny-qwen3",
-        cache=CacheConfig(block_size=4, num_blocks=24, max_blocks_per_seq=16,
-                          dtype="int8"),
-        scheduler=SchedulerConfig(max_num_seqs=4, max_prefill_tokens=256,
-                                  min_prefill_bucket=8, min_decode_bucket=2),
-        enable_prefix_caching=True, kv_tiers=True)
-    e8 = Engine(cfg8)
-    ebf = _mk_engine(True)
-    for e in (e8, ebf):
+def test_int8_pages_demote_smaller_at_real_head_widths():
+    """Pages demote in their stored dtype.  At the head widths models ship
+    with (8 kv heads of 128) an int8 page — values plus its lane-padded f32
+    scale rows (ops/attention.py pad_scale_lanes) — is 0.75x a bf16 one;
+    the tiny test models' 16-wide heads would be all padding."""
+    import dataclasses
+
+    from tpuserve.models.config import get_model_config
+    wide = dataclasses.replace(get_model_config("tiny-qwen3"), num_heads=8,
+                               num_kv_heads=8, head_dim=128)
+
+    def engine(dtype):
+        return Engine(EngineConfig(
+            model="tiny-qwen3",
+            cache=CacheConfig(block_size=4, num_blocks=24,
+                              max_blocks_per_seq=16, dtype=dtype),
+            scheduler=SchedulerConfig(max_num_seqs=4, max_prefill_tokens=256,
+                                      min_prefill_bucket=8,
+                                      min_decode_bucket=2),
+            enable_prefix_caching=True, kv_tiers=True), model_cfg=wide)
+
+    from tpuserve.runtime.kv_tiers import pages_nbytes
+    nbytes = {}
+    for dtype in ("int8", "bfloat16"):
+        e = engine(dtype)
         e.generate([SHARED + [30]], PARAMS)
         _churn(e)
         assert e._kv_tiers.host_count > 0
-    from tpuserve.runtime.kv_tiers import pages_nbytes
-    b8 = pages_nbytes(next(iter(e8._kv_tiers._host.values()))[0])
-    bbf = pages_nbytes(next(iter(ebf._kv_tiers._host.values()))[0])
-    # int8 pages carry f32 scales, so "half" is approximate — but they
-    # must be decisively smaller than bf16 pages of the same block
-    assert b8 < bbf
+        nbytes[dtype] = pages_nbytes(
+            next(iter(e._kv_tiers._host.values()))[0])
+    assert nbytes["int8"] == 0.75 * nbytes["bfloat16"]
 
 
 # ---------------------------------------------------------------------------

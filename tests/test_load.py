@@ -10,7 +10,7 @@ terminator.  Greedy streams for the SAME prompt must also be identical
 across clients — continuous batching must not leak tokens across requests.
 
 The throughput side (aggregate tok/s vs engine-only, HTTP overhead) is
-measured by tools/load_test.py, which appends to BENCHMARKS.md.
+measured by tools/load_test.py, which appends to bench_results.md.
 """
 
 import json

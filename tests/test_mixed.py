@@ -30,11 +30,17 @@ def fp32_cfg():
 
 
 def _engine(fp32_cfg, mixed, *, budget=16, prefix=False, max_seqs=4,
-            num_blocks=128, multi_step=None, attn_impl="auto", **sched_kw):
+            num_blocks=128, multi_step=None, attn_impl="auto",
+            kv_dtype="bfloat16", **sched_kw):
+    """``kv_dtype="float32"`` where a test compares a route that attends
+    over fresh K/V (batched prefill) with one that reads them back from the
+    cache (chunked, mixed): through bf16 pages the two differ by ~1e-3,
+    enough to flip a greedy near-tie of these random weights."""
     return Engine(
         EngineConfig(model="tiny-qwen3",
                      cache=CacheConfig(block_size=4, num_blocks=num_blocks,
-                                       max_blocks_per_seq=24),
+                                       max_blocks_per_seq=24,
+                                       dtype=kv_dtype),
                      scheduler=SchedulerConfig(
                          max_num_seqs=max_seqs, mixed_batching=mixed,
                          mixed_token_budget=budget, **sched_kw),
@@ -58,8 +64,9 @@ def test_mixed_greedy_token_identical(fp32_cfg):
     budget — multiple mixed steps per prompt), and ride decode rows."""
     prompts = _prompts()
     params = SamplingParams(max_tokens=8, temperature=0.0, ignore_eos=True)
-    ref = _engine(fp32_cfg, False).generate(prompts, params)
-    eng = _engine(fp32_cfg, True)
+    ref = _engine(fp32_cfg, False,
+                  kv_dtype="float32").generate(prompts, params)
+    eng = _engine(fp32_cfg, True, kv_dtype="float32")
     mix = eng.generate(prompts, params)
     assert _ids(ref) == _ids(mix)
     assert eng.stats.num_mixed_steps > 0
@@ -154,9 +161,10 @@ def test_mixed_pallas_interpret_matches_reference(fp32_cfg):
     token-identical (greedy) to the reference ragged trunk."""
     prompts = _prompts(seed=17, lens=(19, 6, 9))
     params = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
-    ref = _engine(fp32_cfg, True).generate(prompts, params)
-    pal = _engine(fp32_cfg, True,
-                  attn_impl="pallas").generate(prompts, params)
+    ref = _engine(fp32_cfg, True,
+                  kv_dtype="float32").generate(prompts, params)
+    pal = _engine(fp32_cfg, True, attn_impl="pallas",
+                  kv_dtype="float32").generate(prompts, params)
     assert _ids(ref) == _ids(pal)
 
 

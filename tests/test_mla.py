@@ -182,9 +182,19 @@ def test_disagg_matches_colocated():
     assert d.output_token_ids == c.output_token_ids
 
 
-def test_pallas_request_downgrades_to_reference():
-    eng = _engine(attn_impl="pallas")
+def test_pallas_request_downgrades_to_reference(monkeypatch, caplog):
+    """MLA cannot run the Pallas kernels: asking for them by name is an
+    error, while "auto" (resolving to pallas as it does on a TPU) serves
+    on the reference path and says so."""
+    with pytest.raises(ValueError, match="attn_impl='pallas' was requested"):
+        _engine(attn_impl="pallas")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("TPUSERVE_HBM_BYTES", str(1 << 30))
+    with caplog.at_level("WARNING", "tpuserve.engine"):
+        eng = _engine(attn_impl="auto", multi_step=1, pipeline_decode=False)
     assert eng.attn_impl == "reference"
+    assert any("using reference attention" in r.message
+               for r in caplog.records)
 
 
 def test_int8_covers_mla_and_shared_weights():

@@ -38,3 +38,24 @@ def test_python_fallback_needs_no_toolchain(monkeypatch):
     monkeypatch.setenv("TPUSERVE_BLOCK_MANAGER", "python")
     bm = create_block_manager(8, 4, impl="auto")
     assert isinstance(bm, BlockManager)
+
+
+def test_build_from_source_ignores_what_is_on_disk_and_fails_loudly(
+        monkeypatch, tmp_path):
+    """chip_smoke.py's entry: always compile from the tracked sources
+    (a stale or foreign .so on disk is never reused), and raise — not
+    fall back — when the compile fails."""
+    import os
+
+    import tpuserve.native as native
+    out = native.build_from_source()
+    assert os.path.isfile(out)
+    built = os.path.getmtime(out)
+    assert native.build_from_source() == out
+    assert os.path.getmtime(out) >= built            # rebuilt, not reused
+    bad = tmp_path / "broken.cc"
+    bad.write_text("this is not C++\n")
+    monkeypatch.setattr(native, "_EXT_SRC", str(bad))
+    with pytest.raises(RuntimeError, match="native build failed"):
+        native.build_from_source()
+    assert os.path.isfile(out)                       # the good one survives

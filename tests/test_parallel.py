@@ -208,3 +208,97 @@ def test_tp_pallas_window_matches_reference(cfg, tp4_mesh):
     tp1, tp2 = run("pallas", tp4_mesh)
     np.testing.assert_allclose(tp1, ref1, atol=2e-4)
     np.testing.assert_allclose(tp2, ref2, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# No silent fallback: a caller that names attn_impl="pallas" gets Pallas or
+# an error, never a quiet substitute; parameters are born sharded.
+# ---------------------------------------------------------------------------
+
+def _pallas_engine(cfg, mesh, tpu=False, **kw):
+    from tpuserve.runtime import (CacheConfig, Engine, EngineConfig,
+                                  SchedulerConfig)
+    return Engine(EngineConfig(
+        model="tiny-qwen3",
+        cache=CacheConfig(block_size=4, num_blocks=32, max_blocks_per_seq=8,
+                          dtype=kw.pop("kv_dtype", "bfloat16")),
+        scheduler=SchedulerConfig(min_prefill_bucket=8, min_decode_bucket=2,
+                                  **kw.pop("scheduler", {})),
+        attn_impl=kw.pop("attn_impl", "pallas"), multi_step=1,
+        pipeline_decode=False), model_cfg=cfg, mesh=mesh)
+
+
+@pytest.mark.parametrize("case", ["pp", "ragged_under_tp",
+                                  "narrow_kv_rows_on_tpu"])
+def test_explicit_pallas_downgrade_raises(case, cfg, monkeypatch, caplog):
+    """Each place the engine used to drop Pallas for reference with a log
+    line only: asked for by name it is an error; under "auto" (resolving
+    to pallas, as on a TPU) the engine serves on reference and warns."""
+    import dataclasses
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # ... a TPU whose memory_stats() the CPU backend cannot stand in for
+    monkeypatch.setenv("TPUSERVE_HBM_BYTES", str(1 << 30))
+    kw = {}
+    if case == "pp":
+        mesh = make_mesh(MeshConfig(pp=2))
+        cfg = dataclasses.replace(cfg, num_layers=4)
+        kw["scheduler"] = dict(allow_chunked_prefill=False)
+    elif case == "ragged_under_tp":
+        mesh = make_mesh(MeshConfig(dp=1, tp=2))
+        kw["scheduler"] = dict(mixed_batching=True, mixed_token_budget=512)
+    else:
+        mesh = None                 # int8 pages of 2 kv heads: 2 bytes a row
+        cfg = dataclasses.replace(cfg, num_kv_heads=2)
+        kw["kv_dtype"] = "int8"
+    attr = "_ragged_attn" if case == "ragged_under_tp" else "attn_impl"
+    with pytest.raises(ValueError, match="attn_impl='pallas' was requested"):
+        _pallas_engine(cfg, mesh, **{k: (dict(v) if isinstance(v, dict) else v)
+                                     for k, v in kw.items()})
+    # (kv heads that do not divide tp have no downgrade to test: the
+    # kv-head-sharded cache cannot be created for them at all)
+    with caplog.at_level("WARNING", "tpuserve.engine"):
+        eng = _pallas_engine(cfg, mesh, attn_impl="auto", **kw)
+    assert getattr(eng, attr) == "reference"
+    assert any("using reference attention" in r.message
+               for r in caplog.records)
+
+
+def test_init_params_is_born_sharded(cfg):
+    """Random init under a mesh never lands whole on one device — a model
+    that needs tp to fit could not pass through device 0 — and its values
+    do not depend on the placement."""
+    from tpuserve.models.weights import init_params, param_nbytes
+    mesh = make_mesh(MeshConfig(dp=1, tp=4))
+    sharded = init_params(cfg, seed=3, mesh=mesh)
+    plain = init_params(cfg, seed=3)
+    total = param_nbytes(plain)
+    held = {}
+    for leaf in jax.tree.leaves(sharded):
+        for shard in leaf.addressable_shards:
+            held[shard.device.id] = (held.get(shard.device.id, 0)
+                                     + shard.data.nbytes)
+    assert len(held) == 4
+    assert max(held.values()) < 0.3 * total      # a quarter, plus norms
+    for a, b in zip(jax.tree.leaves(sharded), jax.tree.leaves(plain)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_engine_under_mesh_never_holds_params_whole(cfg, monkeypatch):
+    """The engine hands its mesh to the initialiser: what init_params
+    returns is already in the tensor-parallel shards."""
+    from tpuserve.models import weights
+    seen = {}
+    real = weights.init_params
+
+    def spy(model_cfg, seed=0, mesh=None):
+        out = real(model_cfg, seed, mesh)
+        seen["mesh"] = mesh
+        seen["whole"] = [leaf.shape for leaf in jax.tree.leaves(out)
+                         if leaf.ndim == 2 and leaf.sharding.is_fully_replicated]
+        return out
+
+    monkeypatch.setattr(weights, "init_params", spy)
+    mesh = make_mesh(MeshConfig(dp=1, tp=2))
+    _pallas_engine(cfg, mesh, attn_impl="reference")
+    assert seen["mesh"] is mesh
+    assert seen["whole"] == []      # every matrix is sharded at birth

@@ -39,7 +39,7 @@ def test_runner_fault_mid_window_fails_request_and_recovers(runner):
         time.sleep(0.01)
     assert eng._pending_window is not None
 
-    # poison the next window dispatch (device fault / dead tunnel analog)
+    # poison the next window dispatch (device fault analog)
     orig = eng._exec_decode_multi
 
     def boom(*a, **k):

@@ -38,10 +38,14 @@ def cfg():
 
 
 def _engine(cfg, spec):
+    # float32 pages as well as weights: the verify window and the decode
+    # step read the same cache through different executables, and through
+    # bf16 pages a 1e-4 greedy near-tie of these random weights can flip
     return Engine(
         EngineConfig(model="tiny-qwen3",
                      cache=CacheConfig(block_size=4, num_blocks=256,
-                                       max_blocks_per_seq=32),
+                                       max_blocks_per_seq=32,
+                                       dtype="float32"),
                      scheduler=SchedulerConfig(max_num_seqs=4),
                      enable_prefix_caching=False,
                      pipeline_decode=False,
@@ -223,7 +227,8 @@ def test_spec_composed_with_pipelined_windows(cfg):
     eng = Engine(
         EngineConfig(model="tiny-qwen3",
                      cache=CacheConfig(block_size=4, num_blocks=256,
-                                       max_blocks_per_seq=32),
+                                       max_blocks_per_seq=32,
+                                       dtype="float32"),
                      scheduler=SchedulerConfig(max_num_seqs=4),
                      enable_prefix_caching=False,
                      pipeline_decode=True, multi_step=4,

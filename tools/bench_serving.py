@@ -15,8 +15,9 @@ Usage:
       [--rate 0] [--num-requests 64] [--prompt-len 128] [--gen-len 128]
       [--url http://host:port]   # benchmark an ALREADY-RUNNING server
 
-Without --url an in-process OpenAIServer is started (TPU if reachable,
-else CPU).  Prints one JSON line and appends a section to BENCHMARKS.md.
+Without --url an in-process OpenAIServer is started on the device JAX
+gives this process (the one process that then owns the chip).  Prints one
+JSON line and appends a section to bench_results.md.
 """
 
 from __future__ import annotations
@@ -146,7 +147,7 @@ def main(argv=None):
                     help="route through an in-process gateway (adds the "
                          "relay hop the K8s deployment has)")
     ap.add_argument("--no-md", action="store_true",
-                    help="don't append the BENCHMARKS.md section (tests)")
+                    help="don't append the bench_results.md section (tests)")
     ap.add_argument("--multi-step", type=int, default=None, metavar="S",
                     help="fused decode window for the in-process engine "
                          "(default: engine auto).  The S=32 throughput "
@@ -181,6 +182,8 @@ def main(argv=None):
         from tpuserve.runtime import (CacheConfig, Engine, EngineConfig,
                                       SchedulerConfig)
         from tpuserve.server.openai_api import OpenAIServer, ServerConfig
+        from tpuserve.utils import compile_cache
+        compile_cache.configure()
         backend = jax.default_backend()
         if args.smoke or backend != "tpu":
             model, plen, glen = "tiny-qwen3", 16, 16
@@ -279,7 +282,7 @@ def main(argv=None):
             else f"closed-loop burst of {n}")
     cap = (f"{args.clients} max concurrent (server-enforced)"
            if concurrency_capped else "concurrency uncapped (external server)")
-    with open(os.path.join(ROOT, "BENCHMARKS.md"), "a") as f:
+    with open(os.path.join(ROOT, "bench_results.md"), "a") as f:
         f.write(
             f"\n## Serving latency @ {stamp}\n\n"
             f"{mode}, {cap}, {model}, "

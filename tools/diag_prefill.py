@@ -13,8 +13,8 @@ Each is run 3x after a warmup execution; the median is reported.
 Caveat: the standalone ops are separate dispatches — inside the fused
 prefill they overlap/fuse, so the parts can sum past the whole
 (unattributed_ms < 0 means fusion is winning, not measurement error).
-One JSON line; run by the tunnel watcher after the sweep so the TTFT
-budget (BASELINE p50 <= 150 ms) gets an attribution, not just a total.
+One JSON line, so the TTFT budget (BASELINE p50 <= 150 ms) gets an
+attribution, not just a total.
 """
 
 from __future__ import annotations
@@ -49,7 +49,8 @@ def main():
     from tpuserve.ops import sampling as sampling_ops
     from tpuserve.ops.attention import PAD_SLOT
     from tpuserve.runtime.kv_cache import CacheConfig, create_kv_cache
-    from tpuserve.utils import hard_sync
+    from tpuserve.utils import compile_cache
+    compile_cache.configure()
 
     backend = jax.default_backend()
     if backend == "tpu":
@@ -88,7 +89,7 @@ def main():
         state["logits"], state["kv"] = transformer.prefill(
             params, cfg, tokens, lens, slots, state["kv"],
             attn_impl=attn_impl)
-        hard_sync(state["logits"])
+        jax.block_until_ready(state["logits"])
     run_full()                                   # compile
     out["full_ms"] = round(1000 * _median3(run_full), 1)
 
@@ -125,7 +126,7 @@ def main():
         o = None
         for _ in range(cfg.num_layers):
             o = attn()
-        hard_sync(o)
+        jax.block_until_ready(o)
     run_attn()
     out["attn_all_layers_ms"] = round(1000 * _median3(run_attn), 1)
 
@@ -139,7 +140,7 @@ def main():
         for _ in range(cfg.num_layers):
             ck = write_kv_cache(ck, k, slots)
             ck = write_kv_cache(ck, v, slots)
-        hard_sync(ck)
+        jax.block_until_ready(ck)
         wstate["ck"] = ck
     run_writes()
     out["kv_writes_all_layers_ms"] = round(1000 * _median3(run_writes), 1)

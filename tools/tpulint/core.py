@@ -282,12 +282,6 @@ DEFAULT_CONFIG: dict = {
         # deliberately NOT part of the deploy config or README surface.
         # The reason string is the documentation.
         "env_debug_only": {
-            "TPUSERVE_BENCH_REEXEC": "bench.py TPU re-exec handshake",
-            "TPUSERVE_BENCH_DEGRADED": "bench.py probe->run handoff",
-            "TPUSERVE_BENCH_PROBE_ERROR": "bench.py probe->run handoff",
-            "TPUSERVE_BENCH_START_TS": "bench.py budget bookkeeping",
-            "TPUSERVE_BENCH_BUDGET_S": "harness wall-clock budget guard",
-            "TPUSERVE_TIER1_LOG": "tier-1 harness log path plumbing",
             "TPUSERVE_HBM_BYTES": "test/bench HBM budget override",
             "TPUSERVE_VMEM_BUDGET_MB": "kernel tuning (bench_sweep)",
             "TPUSERVE_RAGGED_BLOCK": "kernel tuning (bench_sweep)",
@@ -316,7 +310,6 @@ DEFAULT_CONFIG: dict = {
         # script that reads it.  The pass verifies the var still appears
         # in that file, so an entry can't outlive the read site.
         "env_shell": {
-            "TPUSERVE_WATCH_BUDGET_S": "tools/tpu_watch.sh",
             "TPUSERVE_CONFIG": "deploy-tpu-cluster.sh",
         },
         # DeployConfig fields allowed to have no provision-layer read

@@ -54,7 +54,7 @@ RULES = {
 }
 
 # explicit sync primitives (flagged in both traced and dispatch contexts)
-_SYNC_CALLS = {"jax.device_get", "jax.block_until_ready", "hard_sync"}
+_SYNC_CALLS = {"jax.device_get", "jax.block_until_ready"}
 _SYNC_METHODS = {"item", "block_until_ready", "tolist"}
 _NP_MATERIALIZE = {"np.asarray", "np.array", "numpy.asarray", "numpy.array",
                    "np.copy"}

@@ -15,6 +15,7 @@ from __future__ import annotations
 import glob
 import json
 import os
+from functools import partial
 from typing import Any
 
 import jax
@@ -43,113 +44,151 @@ def param_dtype(cfg: ModelConfig):
 # Random initialisation (tests, CPU smoke, air-gapped benches)
 # --------------------------------------------------------------------------
 
-def init_params(cfg: ModelConfig, seed: int = 0) -> Params:
-    """Random-normal initialised params in the transformer's pytree layout."""
-    rng = np.random.default_rng(seed)
-    dtype = param_dtype(cfg)
+class _Draw:
+    """Leaf factory for :func:`init_params`: every random leaf draws from
+    its own fold of the key, in creation order."""
 
-    def dense(n_in, n_out, bias):
-        p = {"kernel": jnp.asarray(
-            rng.standard_normal((n_in, n_out), dtype=np.float32) / np.sqrt(n_in),
-            dtype=dtype)}
+    def __init__(self, cfg: ModelConfig, key):
+        self.cfg, self.key, self.n = cfg, key, 0
+        self.dtype = param_dtype(cfg)
+        # Families with a norm-weight offset (Gemma: effective scale =
+        # 1 + w) init the stored weight so the EFFECTIVE gain is 1 — plain
+        # ones would compound a 2x gain per norm through every layer on
+        # random-init paths.
+        self.norm_init = 1.0 - cfg.norm_weight_offset
+
+    def normal(self, shape, std: float):
+        self.n += 1
+        k = jax.random.fold_in(self.key, self.n)
+        return (jax.random.normal(k, shape, jnp.float32) * std
+                ).astype(self.dtype)
+
+    def dense(self, n_in: int, n_out: int, bias: bool):
+        p = {"kernel": self.normal((n_in, n_out), n_in ** -0.5)}
         if bias:
-            p["bias"] = jnp.zeros((n_out,), dtype)
+            p["bias"] = jnp.zeros((n_out,), self.dtype)
         return p
 
-    # Families with a norm-weight offset (Gemma: effective scale = 1 + w)
-    # init the stored weight so the EFFECTIVE gain is 1 — plain ones would
-    # compound a 2x gain per norm through every layer on random-init paths.
-    norm_init = 1.0 - cfg.norm_weight_offset
-
-    def norm(n):
-        p = {"scale": jnp.full((n,), norm_init, dtype)}
-        if cfg.norm == "layernorm":
-            p["bias"] = jnp.zeros((n,), dtype)
+    def norm(self, n: int):
+        p = {"scale": jnp.full((n,), self.norm_init, self.dtype)}
+        if self.cfg.norm == "layernorm":
+            p["bias"] = jnp.zeros((n,), self.dtype)
         return p
 
-    h, d = cfg.hidden_size, cfg.head_dim
-    layers = []
-    for li in range(cfg.num_layers):
-        if cfg.is_mla:
-            # DeepSeek MLA: low-rank q (optional), compressed-KV latent +
-            # shared roped key, per-head up-projections packed in kv_b_proj
-            lp = {
-                "attn_norm": norm(h),
-                "kv_a_proj": dense(h, cfg.mla_latent_dim,
-                                   cfg.attention_bias),
-                "kv_a_norm": norm(cfg.mla_kv_lora_rank),
-                "kv_b_proj": dense(
-                    cfg.mla_kv_lora_rank,
-                    cfg.num_heads * (cfg.mla_qk_nope_head_dim
-                                     + cfg.mla_v_head_dim), False),
-                "o_proj": dense(cfg.num_heads * cfg.mla_v_head_dim, h,
-                                cfg.attention_bias),
-                "mlp_norm": norm(h),
-            }
-            if cfg.mla_q_lora_rank:
-                lp["q_a_proj"] = dense(h, cfg.mla_q_lora_rank,
-                                       cfg.attention_bias)
-                lp["q_a_norm"] = norm(cfg.mla_q_lora_rank)
-                lp["q_b_proj"] = dense(cfg.mla_q_lora_rank, cfg.q_size,
-                                       False)
-            else:
-                lp["q_proj"] = dense(h, cfg.q_size, False)
-        else:
-            lp = {
-                "attn_norm": norm(h),
-                "q_proj": dense(h, cfg.q_size, cfg.attention_bias),
-                "k_proj": dense(h, cfg.kv_size, cfg.attention_bias),
-                "v_proj": dense(h, cfg.kv_size, cfg.attention_bias),
-                "o_proj": dense(cfg.q_size, h, cfg.attention_bias and cfg.pos == "learned"),
-                "mlp_norm": norm(h),
-            }
-        if cfg.qk_norm:
-            lp["q_norm"] = {"scale": jnp.full((d,), norm_init, dtype)}
-            lp["k_norm"] = {"scale": jnp.full((d,), norm_init, dtype)}
-        if cfg.sandwich_norms:
-            lp["post_attn_norm"] = norm(h)
-            lp["post_mlp_norm"] = norm(h)
-        if cfg.num_experts and not cfg.moe_layer_is_dense(li):
-            ei = cfg.expert_intermediate_size
-            E = cfg.num_experts
 
-            def experts(n_in, n_out):
-                return {"kernel": jnp.asarray(
-                    rng.standard_normal((E, n_in, n_out), dtype=np.float32)
-                    / np.sqrt(n_in), dtype=dtype)}
-            lp["router"] = dense(h, E, False)
-            if cfg.moe_router_bias:
-                # e_score_correction_bias: selection-only, stays f32
-                lp["router_bias"] = {"bias": jnp.zeros((E,), jnp.float32)}
-            lp["experts"] = {"gate_proj": experts(h, ei),
-                             "up_proj": experts(h, ei),
-                             "down_proj": experts(ei, h)}
-            if cfg.moe_shared_experts:
-                si = ei * cfg.moe_shared_experts
-                lp["shared"] = {"gate_proj": dense(h, si, False),
-                                "up_proj": dense(h, si, False),
-                                "down_proj": dense(si, h, False)}
-        elif cfg.mlp_style == "gated":
-            lp["gate_proj"] = dense(h, cfg.intermediate_size, cfg.mlp_bias)
-            lp["up_proj"] = dense(h, cfg.intermediate_size, cfg.mlp_bias)
-            lp["down_proj"] = dense(cfg.intermediate_size, h, cfg.mlp_bias)
-        else:
-            lp["fc1"] = dense(h, cfg.intermediate_size, cfg.mlp_bias)
-            lp["fc2"] = dense(cfg.intermediate_size, h, cfg.mlp_bias)
-        layers.append(lp)
+def _shard(tree, cfg: ModelConfig, mesh):
+    """Constrain a param (sub)tree to its tensor-parallel shardings inside
+    the init program, so each device generates only its own shards."""
+    if mesh is None:
+        return tree
+    from tpuserve.parallel.sharding import param_shardings
+    return jax.lax.with_sharding_constraint(
+        tree, param_shardings(tree, cfg, mesh))
 
-    params = {
-        "embed": {"weight": jnp.asarray(
-            rng.standard_normal((cfg.vocab_size, h), dtype=np.float32) * 0.02, dtype=dtype)},
-        "layers": layers,
-        "final_norm": norm(h),
-    }
+
+@partial(jax.jit, static_argnames=("cfg", "dense_mlp", "mesh"))
+def _init_layer(key, cfg: ModelConfig, dense_mlp: bool, mesh=None) -> Params:
+    """One transformer layer; ``dense_mlp``: an MoE model's dense layer."""
+    d = _Draw(cfg, key)
+    h = cfg.hidden_size
+    if cfg.is_mla:
+        # DeepSeek MLA: low-rank q (optional), compressed-KV latent +
+        # shared roped key, per-head up-projections packed in kv_b_proj
+        lp = {
+            "attn_norm": d.norm(h),
+            "kv_a_proj": d.dense(h, cfg.mla_latent_dim, cfg.attention_bias),
+            "kv_a_norm": d.norm(cfg.mla_kv_lora_rank),
+            "kv_b_proj": d.dense(
+                cfg.mla_kv_lora_rank,
+                cfg.num_heads * (cfg.mla_qk_nope_head_dim
+                                 + cfg.mla_v_head_dim), False),
+            "o_proj": d.dense(cfg.num_heads * cfg.mla_v_head_dim, h,
+                              cfg.attention_bias),
+            "mlp_norm": d.norm(h),
+        }
+        if cfg.mla_q_lora_rank:
+            lp["q_a_proj"] = d.dense(h, cfg.mla_q_lora_rank,
+                                     cfg.attention_bias)
+            lp["q_a_norm"] = d.norm(cfg.mla_q_lora_rank)
+            lp["q_b_proj"] = d.dense(cfg.mla_q_lora_rank, cfg.q_size, False)
+        else:
+            lp["q_proj"] = d.dense(h, cfg.q_size, False)
+    else:
+        lp = {
+            "attn_norm": d.norm(h),
+            "q_proj": d.dense(h, cfg.q_size, cfg.attention_bias),
+            "k_proj": d.dense(h, cfg.kv_size, cfg.attention_bias),
+            "v_proj": d.dense(h, cfg.kv_size, cfg.attention_bias),
+            "o_proj": d.dense(cfg.q_size, h,
+                              cfg.attention_bias and cfg.pos == "learned"),
+            "mlp_norm": d.norm(h),
+        }
+    if cfg.qk_norm:
+        lp["q_norm"] = {"scale": jnp.full((cfg.head_dim,), d.norm_init,
+                                          d.dtype)}
+        lp["k_norm"] = {"scale": jnp.full((cfg.head_dim,), d.norm_init,
+                                          d.dtype)}
+    if cfg.sandwich_norms:
+        lp["post_attn_norm"] = d.norm(h)
+        lp["post_mlp_norm"] = d.norm(h)
+    if cfg.num_experts and not dense_mlp:
+        ei = cfg.expert_intermediate_size
+        E = cfg.num_experts
+
+        def experts(n_in, n_out):
+            return {"kernel": d.normal((E, n_in, n_out), n_in ** -0.5)}
+        lp["router"] = d.dense(h, E, False)
+        if cfg.moe_router_bias:
+            # e_score_correction_bias: selection-only, stays f32
+            lp["router_bias"] = {"bias": jnp.zeros((E,), jnp.float32)}
+        lp["experts"] = {"gate_proj": experts(h, ei),
+                         "up_proj": experts(h, ei),
+                         "down_proj": experts(ei, h)}
+        if cfg.moe_shared_experts:
+            si = ei * cfg.moe_shared_experts
+            lp["shared"] = {"gate_proj": d.dense(h, si, False),
+                            "up_proj": d.dense(h, si, False),
+                            "down_proj": d.dense(si, h, False)}
+    elif cfg.mlp_style == "gated":
+        lp["gate_proj"] = d.dense(h, cfg.intermediate_size, cfg.mlp_bias)
+        lp["up_proj"] = d.dense(h, cfg.intermediate_size, cfg.mlp_bias)
+        lp["down_proj"] = d.dense(cfg.intermediate_size, h, cfg.mlp_bias)
+    else:
+        lp["fc1"] = d.dense(h, cfg.intermediate_size, cfg.mlp_bias)
+        lp["fc2"] = d.dense(cfg.intermediate_size, h, cfg.mlp_bias)
+    return _shard(lp, cfg, mesh)
+
+
+@partial(jax.jit, static_argnames=("cfg", "mesh"))
+def _init_head(key, cfg: ModelConfig, mesh=None) -> Params:
+    """Everything outside the layer stack: embeddings, final norm, head."""
+    d = _Draw(cfg, key)
+    h = cfg.hidden_size
+    params = {"embed": {"weight": d.normal((cfg.vocab_size, h), 0.02)},
+              "final_norm": d.norm(h)}
     if cfg.pos == "learned":
-        params["pos_embed"] = {"weight": jnp.asarray(
-            rng.standard_normal((cfg.max_position_embeddings + cfg.learned_pos_offset, h),
-                                dtype=np.float32) * 0.02, dtype=dtype)}
+        params["pos_embed"] = {"weight": d.normal(
+            (cfg.max_position_embeddings + cfg.learned_pos_offset, h), 0.02)}
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = dense(h, cfg.vocab_size, False)
+        params["lm_head"] = d.dense(h, cfg.vocab_size, False)
+    return _shard(params, cfg, mesh)
+
+
+def init_params(cfg: ModelConfig, seed: int = 0, mesh=None) -> Params:
+    """Random-normal initialised params in the transformer's pytree layout.
+
+    The leaves are generated ON the device by two jitted programs (one per
+    layer structure, one for the head).  With ``mesh`` each leaf is born
+    in its tensor-parallel shards (parallel/sharding.py) — never whole on
+    one device, so a model that needs tp to fit (Llama-3.1-8B on 16 GB
+    chips) can be initialised at all.  The values depend on ``(cfg,
+    seed)`` alone, not on the placement."""
+    key = jax.random.key(seed)
+    params = _init_head(jax.random.fold_in(key, cfg.num_layers), cfg, mesh)
+    params["layers"] = [
+        _init_layer(jax.random.fold_in(key, li), cfg,
+                    cfg.moe_layer_is_dense(li), mesh)
+        for li in range(cfg.num_layers)]
     return params
 
 
@@ -353,11 +392,13 @@ def _load_opt(cfg: ModelConfig, raw: dict, dtype) -> Params:
     }
 
 
-def load_or_init(cfg: ModelConfig, ckpt_dir: str | None, seed: int = 0) -> Params:
-    """Load from a checkpoint dir when given/present, else random-init."""
+def load_or_init(cfg: ModelConfig, ckpt_dir: str | None, seed: int = 0,
+                 mesh=None) -> Params:
+    """Load from a checkpoint dir when given/present, else random-init
+    (sharded over ``mesh`` from birth, see :func:`init_params`)."""
     if ckpt_dir and glob.glob(os.path.join(ckpt_dir, "*.safetensors")):
         return load_hf_checkpoint(cfg, ckpt_dir)
-    return init_params(cfg, seed)
+    return init_params(cfg, seed, mesh)
 
 
 # --------------------------------------------------------------------------

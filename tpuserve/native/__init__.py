@@ -37,13 +37,12 @@ def _ext_path() -> str:
     return os.path.join(_PKG_DIR, f"_tpuserve_native{suffix}")
 
 
-def _build() -> bool:
+def build_from_source() -> str:
+    """Compile the extension from native/*.cc, whatever is already on
+    disk, and return its path.  Raises if the sources, the toolchain or
+    the compile fail — for callers (chip_smoke.py) that must know the
+    manager they run was built from the tracked sources."""
     out = _ext_path()
-    if not (os.path.isfile(_EXT_SRC) and os.path.isfile(_HDR)):
-        return os.path.isfile(out)
-    src_mtime = max(os.path.getmtime(_EXT_SRC), os.path.getmtime(_HDR))
-    if os.path.isfile(out) and os.path.getmtime(out) >= src_mtime:
-        return True
     include = sysconfig.get_paths()["include"]
     # Compile to a private temp path and os.replace() it into place: the
     # publish is atomic, so a concurrent process (pytest-xdist worker,
@@ -55,16 +54,28 @@ def _build() -> bool:
              f"-I{include}", "-o", tmp, _EXT_SRC],
             check=True, capture_output=True, timeout=180)
         os.replace(tmp, out)
-        logger.info("built %s", out)
-        return True
-    except (OSError, subprocess.SubprocessError) as e:
-        stderr = getattr(e, "stderr", b"") or b""
-        logger.warning("native build failed (%s%s); using pure Python",
-                       e, stderr.decode(errors="replace")[:500])
-        try:
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError("native build failed: "
+                           + e.stderr.decode(errors="replace")[:500]) from e
+    finally:
+        if os.path.exists(tmp):
             os.remove(tmp)
-        except OSError:
-            pass
+    logger.info("built %s", out)
+    return out
+
+
+def _build() -> bool:
+    out = _ext_path()
+    if not (os.path.isfile(_EXT_SRC) and os.path.isfile(_HDR)):
+        return os.path.isfile(out)
+    src_mtime = max(os.path.getmtime(_EXT_SRC), os.path.getmtime(_HDR))
+    if os.path.isfile(out) and os.path.getmtime(out) >= src_mtime:
+        return True
+    try:
+        build_from_source()
+        return True
+    except (OSError, RuntimeError, subprocess.SubprocessError) as e:
+        logger.warning("%s; using pure Python", e)
         return False
 
 

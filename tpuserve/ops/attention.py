@@ -80,8 +80,12 @@ def _dequant_gathered(k, v, k_scale, v_scale, block_tables, B, S, Hkv,
             v_scale[block_tables].reshape(B, S, n), scale_slices)
         return ((k.astype(jnp.float32) * ksc).astype(dtype),
                 (v.astype(jnp.float32) * vsc).astype(dtype))
-    return (dequantize_kv(k, k_scale[block_tables].reshape(B, S, Hkv), dtype),
-            dequantize_kv(v, v_scale[block_tables].reshape(B, S, Hkv), dtype))
+
+    def scales(cache):
+        return unpad_scale_lanes(
+            cache[block_tables].reshape(B, S, cache.shape[-1]), Hkv)
+    return (dequantize_kv(k, scales(k_scale), dtype),
+            dequantize_kv(v, scales(v_scale), dtype))
 
 
 def paged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
@@ -98,8 +102,9 @@ def paged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     q: (B, Hq, D); k_cache/v_cache: (num_blocks, block_size, Hkv, D);
     block_tables: (B, max_blocks) int32 physical block ids;
     seq_lens: (B,) total tokens in cache per sequence (including current).
-    ``k_scale``/``v_scale``: (num_blocks, block_size, Hkv) dequantization
-    scales when the cache stores int8 — or, with ``scale_slices`` set
+    ``k_scale``/``v_scale``: (num_blocks, block_size, groups*SCALE_LANES)
+    lane-padded dequantization scales (:func:`pad_scale_lanes`) when the
+    cache stores int8 — or, with ``scale_slices`` set
     (int8 MLA), (num_blocks, block_size, len(scale_slices)) per-slice
     scales over the channel axis.  ``sliding_window``: attend only
     the last W cached positions.  Returns (B, Hq, D).
@@ -346,13 +351,49 @@ def chunked_prefill_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
 # int8 KV quantization (per-token, per-kv-head scales)
 #
 # Decode is HBM-bandwidth-bound and at the headline shape KV reads rival
-# weight reads (VERDICT r3 weak #4's roofline): int8 storage halves KV
-# bytes per step AND doubles cache capacity per HBM byte.  Scales are one
-# f32 per (token, kv head) — 3% overhead at head_dim 128 — stored in a
-# parallel paged array so a physical block stays a contiguous DMA unit.
+# weight reads (byte count from shapes; not measured on the current code):
+# int8 storage halves the value bytes per step.  Scales are one f32 per
+# (token, kv head), stored in a parallel paged array so a physical block
+# stays a contiguous DMA unit.
+#
+# Scale-page layout: (num_blocks, block_size, groups * SCALE_LANES).  The
+# Pallas kernels DMA one block's scales as a (block_size, lanes) slab, and
+# Mosaic only slices a memref whose minor dimension is a whole number of
+# 128-lane tiles — a minor dimension of Hkv (8) is refused at compile time.
+# So each row carries its kv heads in the first lanes of a 128-lane group
+# and zeros after them.  ``groups`` is the number of shards of the kv-head
+# axis (tp; 1 unsharded): every shard owns one whole lane group, so the
+# array shards over its minor axis exactly as the value pages shard over
+# kv heads.  The padding is real HBM (512 B of scales per token per K or V
+# next to Hkv*D int8 value bytes); a denser layout needs an in-kernel lane
+# relayout Mosaic does not lower today.
 # --------------------------------------------------------------------------
 
 KV_QUANT_MAX = 127.0
+SCALE_LANES = 128
+
+
+def pad_scale_lanes(scales: jnp.ndarray, groups: int = 1) -> jnp.ndarray:
+    """(..., Hkv) per-head scales -> (..., groups * SCALE_LANES): head h
+    of shard g = h // (Hkv / groups) lands in lane g * SCALE_LANES + h %
+    (Hkv / groups); the other lanes are zero."""
+    hkv = scales.shape[-1]
+    per = hkv // groups
+    if per * groups != hkv or per > SCALE_LANES:
+        raise ValueError(f"{hkv} kv heads do not split into {groups} lane "
+                         f"groups of at most {SCALE_LANES}")
+    s = scales.reshape(*scales.shape[:-1], groups, per)
+    s = jnp.pad(s, [(0, 0)] * (s.ndim - 1) + [(0, SCALE_LANES - per)])
+    return s.reshape(*scales.shape[:-1], groups * SCALE_LANES)
+
+
+def unpad_scale_lanes(padded: jnp.ndarray, num_kv_heads: int) -> jnp.ndarray:
+    """Inverse of :func:`pad_scale_lanes`: (..., groups * SCALE_LANES) ->
+    (..., Hkv)."""
+    groups = padded.shape[-1] // SCALE_LANES
+    s = padded.reshape(*padded.shape[:-1], groups, SCALE_LANES)
+    s = s[..., :num_kv_heads // groups]
+    return s.reshape(*padded.shape[:-1], num_kv_heads)
 
 
 def quantize_kv(x: jnp.ndarray):
@@ -376,14 +417,14 @@ def dequantize_kv(q: jnp.ndarray, scales: jnp.ndarray,
 
 def write_kv_scales(scale_cache: jnp.ndarray, scales: jnp.ndarray,
                     slots: jnp.ndarray) -> jnp.ndarray:
-    """Scatter per-token scales into the paged scale array
-    (num_blocks, block_size, Hkv); same PAD_SLOT drop semantics as
-    :func:`write_kv_cache`."""
-    nb, bs, Hkv = scale_cache.shape
-    flat = scale_cache.reshape(nb * bs, Hkv)
+    """Scatter per-token scale rows into the paged scale array
+    (num_blocks, block_size, lanes) — ``scales`` already in the array's
+    row layout; same PAD_SLOT drop semantics as :func:`write_kv_cache`."""
+    nb, bs, lanes = scale_cache.shape
+    flat = scale_cache.reshape(nb * bs, lanes)
     flat = flat.at[slots.reshape(-1)].set(
-        scales.reshape(-1, Hkv).astype(scale_cache.dtype), mode="drop")
-    return flat.reshape(nb, bs, Hkv)
+        scales.reshape(-1, lanes).astype(scale_cache.dtype), mode="drop")
+    return flat.reshape(nb, bs, lanes)
 
 
 def write_kv_entry(entry: dict, k: jnp.ndarray, v: jnp.ndarray,
@@ -397,10 +438,13 @@ def write_kv_entry(entry: dict, k: jnp.ndarray, v: jnp.ndarray,
     if "ks" in entry:
         qk, sk = quantize_kv(k)
         qv, sv = quantize_kv(v)
+        groups = entry["ks"].shape[-1] // SCALE_LANES
         return {"k": write_kv_cache(entry["k"], qk, slots),
                 "v": write_kv_cache(entry["v"], qv, slots),
-                "ks": write_kv_scales(entry["ks"], sk, slots),
-                "vs": write_kv_scales(entry["vs"], sv, slots)}
+                "ks": write_kv_scales(entry["ks"],
+                                      pad_scale_lanes(sk, groups), slots),
+                "vs": write_kv_scales(entry["vs"],
+                                      pad_scale_lanes(sv, groups), slots)}
     return {"k": write_kv_cache(entry["k"], k, slots),
             "v": write_kv_cache(entry["v"], v, slots)}
 

@@ -24,15 +24,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpuserve.ops.attention import SCALE_LANES, dequantize_kv
+from tpuserve.ops.pallas_paged_attention import (TARGET_GROUP_ROWS,
+                                                 _clamp_to_vmem_budget,
+                                                 _scale_rows,
+                                                 compiler_params)
+
 NEG_INF = -1e30
-
-from tpuserve.ops.pallas_paged_attention import _COMPILER_PARAMS
-
-
-# Target K rows per compute iteration (same rationale as the decode kernel:
-# deep enough to amortise relayout/loop overhead, small enough that the
-# double-buffered K+V scratch stays well inside VMEM).
-TARGET_GROUP_ROWS = 512
 
 
 def _window_kernel(bt_ref, ctx_ref, chunk_ref, q_ref, k_hbm, v_hbm, o_ref,
@@ -142,13 +140,10 @@ def _window_kernel(bt_ref, ctx_ref, chunk_ref, q_ref, k_hbm, v_hbm, o_ref,
         v = jnp.swapaxes(v_scr[slot].reshape(rows_g, num_kv_heads, head_dim),
                          0, 1)
         if quantized:
-            from tpuserve.ops.attention import dequantize_kv
-            k = dequantize_kv(k, jnp.swapaxes(
-                ks_scr[slot].reshape(rows_g, num_kv_heads), 0, 1),
-                q_ref.dtype)
-            v = dequantize_kv(v, jnp.swapaxes(
-                vs_scr[slot].reshape(rows_g, num_kv_heads), 0, 1),
-                q_ref.dtype)
+            k = dequantize_kv(k, _scale_rows(ks_scr[slot], num_kv_heads),
+                              q_ref.dtype)
+            v = dequantize_kv(v, _scale_rows(vs_scr[slot], num_kv_heads),
+                              q_ref.dtype)
         # Zero V rows past THIS PROGRAM'S loaded range: pages beyond
         # kv_limit are never DMA'd (even when within the written keys —
         # early q blocks stop at their causal limit), so their scratch is
@@ -225,16 +220,14 @@ def paged_window_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     blk_q = min(blk_q, C)
     pages_g = pages_per_group or max(1, -(-TARGET_GROUP_ROWS // page_size))
     pages_g = min(pages_g, max_pages)
-    # Same VMEM-budget clamp as the decode kernel: wide-Hkv models (phi3:
-    # 32 kv heads) push the double-buffered KV scratch past the budget at
-    # the default group size — clamp with a log line instead of handing
-    # the compiler an oversized allocation.  blk_q plays seqs_pp's role
-    # in the q/out-block term (it IS the q rows per program).
-    from tpuserve.ops.pallas_paged_attention import _clamp_to_vmem_budget
+    # Same VMEM clamp as the decode kernel, with the whole q block in one
+    # contraction: the (Hkv, blk_q*G, rows_g) f32 score tiles are the
+    # largest term here, so many-q-head models get a shorter page group
+    # (then a smaller q block) by rule.
     pages_g, blk_q = _clamp_to_vmem_budget(
         pages_g, blk_q, page_size, Hkv, D, k_cache.dtype.itemsize,
-        Hq, q.dtype.itemsize,
-        scale_itemsize=4 if k_scale is not None else 0)
+        Hq, q.dtype.itemsize, quantized=k_scale is not None,
+        rows_per_dot=True)
 
     quantized = k_scale is not None
     kernel = functools.partial(
@@ -263,7 +256,8 @@ def paged_window_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     scales = ()
     if quantized:
         in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
-        scratch += [pltpu.VMEM((2, pages_g, page_size, Hkv), jnp.float32)] * 2
+        scratch += [pltpu.VMEM((2, pages_g, page_size, SCALE_LANES),
+                               jnp.float32)] * 2
         scales = (k_scale, v_scale)
     scratch.append(pltpu.SemaphoreType.DMA((4 if quantized else 2,
                                             2, pages_g)))
@@ -279,8 +273,6 @@ def paged_window_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
+        compiler_params=compiler_params("arbitrary", "arbitrary"),
         interpret=interpret,
     )(block_tables, ctx_lens, chunk_lens, q, k_cache, v_cache, *scales)

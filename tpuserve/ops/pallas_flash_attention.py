@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpuserve.ops.pallas_paged_attention import _COMPILER_PARAMS
-
 
 NEG_INF = -1e30
 
@@ -174,9 +172,9 @@ def _flash_prefill_attention(q, k, v, prompt_lens, *, scale: float,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
     )(prompt_lens, qt, kt, vt)
     return jnp.swapaxes(out, 1, 2)

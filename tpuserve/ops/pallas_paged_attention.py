@@ -16,10 +16,11 @@ inside the vLLM image the reference deploys (reference:
 kubernetes-single-node.yaml:14; SURVEY.md §2.2, §7 "hard parts" — see also
 PAPERS.md "Ragged Paged Attention").
 
-Why the occupancy lever is DMA, not the MXU (BENCHMARKS.md carries the
-full analysis): decode reads each KV byte exactly once per step, so its
-arithmetic intensity is ~1 FLOP/byte — two orders of magnitude below the
-MXU's compute:bandwidth balance point.  The kernel is therefore
+Why the occupancy lever is DMA, not the MXU (reasoning from shapes; not
+measured on the current code): decode reads each KV byte exactly once per
+step, so its arithmetic intensity is ~1 FLOP/byte — two orders of
+magnitude below the MXU's compute:bandwidth balance point.  The kernel is
+therefore
 bandwidth-bound by construction; padding the QK contraction to 128 q rows
 (e.g. cross-sequence block-diagonal packing) multiplies FLOPs by the
 packing factor for identical wall-clock at best.  What matters is (a)
@@ -52,78 +53,117 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpuserve.ops.attention import SCALE_LANES, dequantize_kv
+
 logger = logging.getLogger("tpuserve.ops.paged_attention")
-
-# jax has renamed TPUCompilerParams <-> CompilerParams across releases;
-# use whichever this build provides (0.4.x ships only TPUCompilerParams).
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 NEG_INF = -1e30
 
-# VMEM is ~16 MiB/core on v5e; budget 12 MiB for this kernel's buffers and
-# leave the rest for Mosaic's own needs.  A knob combination that exceeds
-# the budget used to reach the compiler unchecked and could silently
-# regress the kernel 40% (VERDICT r3 weak #5: the spp16 sweep collapse);
-# now it clamps with a log line instead.  Env-overridable for sweeps that
-# want to probe the cliff deliberately.
-VMEM_BUDGET_BYTES = int(os.environ.get("TPUSERVE_VMEM_BUDGET_MB", "12")) * 2**20
-
+# Scoped VMEM the three paged kernels (decode, chunk window, ragged) are
+# sized for.  It is BOTH the budget the block-size clamp below holds the
+# footprint model to AND the ``vmem_limit_bytes`` handed to Mosaic, so the
+# two cannot drift: a v5e core has 128 MiB of VMEM, but a kernel that does
+# not say what it needs gets the compiler's 16 MiB default scope — which
+# the chunk-window and ragged kernels overflow at Qwen3-0.6B widths
+# through their f32 score tiles (tests/test_chip_compile.py pins the
+# compiles).  Env-overridable for sweeps that probe the cliff.
+VMEM_LIMIT_BYTES = int(os.environ.get("TPUSERVE_VMEM_BUDGET_MB", "32")) * 2**20
 
 MIN_SUBLANES = {1: 32, 2: 16, 4: 8}   # Mosaic min tile rows by itemsize
 
 
-def _clamp_to_vmem_budget(pages_g: int, seqs_pp: int, page_size: int,
-                          num_kv_heads: int, head_dim: int,
-                          kv_itemsize: int, num_q_heads: int,
-                          q_itemsize: int,
-                          scale_itemsize: int = 0) -> tuple[int, int]:
-    """Shrink (pages_g, seqs_pp) until the kernel's VMEM footprint fits.
+def compiler_params(*dimension_semantics: str) -> pltpu.CompilerParams:
+    """Mosaic params shared by the paged kernels: the grid semantics plus
+    the VMEM scope the clamp sized the kernel for."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
-    Footprint model (what Mosaic actually allocates — the trailing two
-    dims of every VMEM array are padded to the dtype's minimum tile, so
-    narrow-head caches cost far more than their dense byte count):
-      - KV scratch: 2 slots (double buffer) x {K,V} x pages_g x page x
+
+def vmem_footprint(pages_g: int, block_rows: int, dot_rows: int,
+                   page_size: int, num_kv_heads: int, head_dim: int,
+                   kv_itemsize: int, num_q_heads: int, q_itemsize: int,
+                   quantized: bool = False) -> int:
+    """Upper bound on the scoped VMEM one program of a paged kernel needs.
+
+    ``block_rows``: q rows in the pipelined q/out block (seqs_per_program
+    for decode, blk_q for the window/ragged kernels); ``dot_rows``: q rows
+    contracted against one page group at a time (1 for decode, which walks
+    its block a sequence at a time; blk_q otherwise).
+
+    Counts what Mosaic allocates, not dense bytes — the trailing two dims
+    of every VMEM array pad to the dtype's minimum tile, so narrow-head
+    caches cost far more than their element count:
+      - KV scratch: 2 slots (double buffer) x {K,V} x rows_g x
         padded(Hkv) x D at the cache dtype — Hkv pads to 32 rows for
         int8, 16 for bf16, 8 for f32, which is why an 8-kv-head int8
         cache does NOT shrink scratch 2x;
-      - int8 scale scratch (2 x {K,V} x pages_g x page x Hkv f32): the
-        trailing dim Hkv pads to the 128-lane width;
+      - int8 scale scratch: 2 x {K,V} x rows_g x SCALE_LANES f32;
       - q/out pipeline blocks: 2 buffers each (Pallas double-buffers
-        grid-indexed blocks) x seqs_pp x padded(Hq) x D.
-    pages_g halves first (it dominates and shrinking it only shortens the
-    DMA pipeline), then seqs_pp."""
+        grid-indexed blocks) x block_rows x padded(Hq) x D;
+      - what the body keeps live while it contracts one page group,
+        which the compiler places on the same scoped stack.  Mosaic
+        walks the key axis a 128-lane tile at a time, so the live set is
+        about eleven (Hkv, rows_q, 128) f32 slabs (score, exp, mask and
+        key positions of the tile, the accumulator and its update) plus
+        half the full (Hkv, rows_q, rows_g) f32 score tile, next to the
+        relayouted K and V with one transposition temporary (and their
+        f32 dequantization when the cache is int8).  The factors are
+        fitted to the least ``vmem_limit_bytes`` the v5e compiler
+        accepted over 54 shapes — Hq 4..64, GQA and MHA, pages_g 4..16,
+        blk_q 32..128, bf16 and int8 — and bound every one from above
+        (by 0..18 MiB).  This term, not the scratch, is what overflowed
+        the default scope."""
     from tpuserve.utils import round_up
     kv_rows = round_up(num_kv_heads, MIN_SUBLANES.get(kv_itemsize, 8))
-    q_rows = round_up(num_q_heads, MIN_SUBLANES.get(q_itemsize, 8))
+    q_heads = round_up(num_q_heads, MIN_SUBLANES.get(q_itemsize, 8))
     lanes = round_up(head_dim, 128)   # lane dim pads to the 128 width too
+    rows_g = pages_g * page_size
+    rows_q = round_up(dot_rows * (num_q_heads // num_kv_heads), 8)
+    kv = 2 * 2 * rows_g * kv_rows * lanes * kv_itemsize
+    scales = 2 * 2 * round_up(rows_g, 8) * SCALE_LANES * 4 if quantized else 0
+    qo = 2 * 2 * block_rows * q_heads * lanes * q_itemsize
+    slab = num_kv_heads * rows_q * 128 * 4
+    score_tile = slab * round_up(rows_g, 128) // 128
+    kv_values = num_kv_heads * rows_g * lanes * (
+        3 * q_itemsize + (2 * 4 if quantized else 0))
+    return kv + scales + qo + 11 * slab + score_tile // 2 + kv_values
 
-    def footprint(pg: int, sp: int) -> int:
-        kv = 2 * 2 * pg * page_size * kv_rows * lanes * kv_itemsize
-        scales = (2 * 2 * pg * round_up(page_size, 8)
-                  * round_up(num_kv_heads, 128) * scale_itemsize)
-        qo = 2 * 2 * sp * q_rows * lanes * q_itemsize
-        return kv + scales + qo
 
-    orig = (pages_g, seqs_pp)
-    while footprint(pages_g, seqs_pp) > VMEM_BUDGET_BYTES and pages_g > 1:
+def _clamp_to_vmem_budget(pages_g: int, block_rows: int, page_size: int,
+                          num_kv_heads: int, head_dim: int,
+                          kv_itemsize: int, num_q_heads: int,
+                          q_itemsize: int, quantized: bool = False,
+                          rows_per_dot: bool = False) -> tuple[int, int]:
+    """Shrink (pages_g, block_rows) until :func:`vmem_footprint` fits
+    ``VMEM_LIMIT_BYTES``.  ``rows_per_dot``: the kernel contracts its
+    whole q block at once (window/ragged) rather than a sequence at a
+    time (decode).  pages_g halves first (it dominates and shrinking it
+    only shortens the DMA pipeline), then block_rows — so wide models get
+    smaller blocks by rule, not by a per-model constant."""
+    def footprint(pg: int, br: int) -> int:
+        return vmem_footprint(pg, br, br if rows_per_dot else 1, page_size,
+                              num_kv_heads, head_dim, kv_itemsize,
+                              num_q_heads, q_itemsize, quantized)
+
+    orig = (pages_g, block_rows)
+    while footprint(pages_g, block_rows) > VMEM_LIMIT_BYTES and pages_g > 1:
         pages_g //= 2
-    while footprint(pages_g, seqs_pp) > VMEM_BUDGET_BYTES and seqs_pp > 1:
-        seqs_pp //= 2
-    if (pages_g, seqs_pp) != orig:
+    while footprint(pages_g, block_rows) > VMEM_LIMIT_BYTES and block_rows > 1:
+        block_rows //= 2
+    if (pages_g, block_rows) != orig:
         logger.warning(
-            "paged-decode knobs (pages_per_group=%d, seqs_per_program=%d) "
-            "need %.1f MiB of VMEM scratch (budget %.1f MiB); clamped to "
-            "(%d, %d)", orig[0], orig[1],
-            footprint(*orig) / 2**20, VMEM_BUDGET_BYTES / 2**20,
-            pages_g, seqs_pp)
-    return pages_g, seqs_pp
+            "paged-attention blocks (pages_per_group=%d, q rows=%d) need "
+            "%.1f MiB of VMEM (limit %.1f MiB); clamped to (%d, %d)",
+            orig[0], orig[1], footprint(*orig) / 2**20,
+            VMEM_LIMIT_BYTES / 2**20, pages_g, block_rows)
+    return pages_g, block_rows
+
 
 # Target K rows per compute iteration: G = ceil(TARGET_GROUP_ROWS / page).
 # 512 rows x 128 lanes is deep enough to amortise relayout/loop overhead
-# while 2 slots x (K+V) x 512 rows x 8 kv heads x 128 x 2B = 4 MiB stays
-# comfortably inside VMEM next to the q/output blocks.
+# while 2 slots x (K+V) x 512 rows x 16 (8 kv heads padded to the bf16
+# tile) x 128 x 2B = 8 MiB stays inside VMEM_LIMIT_BYTES next to the
+# q/output blocks and the score tiles.
 TARGET_GROUP_ROWS = 512
 
 # Sequences per grid program: deep enough that the cross-sequence DMA
@@ -138,6 +178,13 @@ DEFAULT_SEQS_PER_PROGRAM = 8
 def _env_int(name: str) -> int | None:
     val = os.environ.get(name)
     return int(val) if val else None
+
+
+def _scale_rows(scr, num_kv_heads: int):
+    """One slot's landed scale pages (pages_g, page, SCALE_LANES) ->
+    (Hkv, rows_g): the kv heads sit in the first lanes of each row."""
+    rows = scr.reshape(-1, scr.shape[-1])[:, :num_kv_heads]
+    return jnp.swapaxes(rows, 0, 1)
 
 
 def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
@@ -260,13 +307,10 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
                 # dequantize in VMEM: one VPU multiply per element, paid
                 # AFTER the halved DMA — results in q's dtype (bf16 on
                 # TPU) keep the dots on the fast MXU path
-                from tpuserve.ops.attention import dequantize_kv
-                k = dequantize_kv(k, jnp.swapaxes(
-                    ks_scr[slot].reshape(rows_g, num_kv_heads), 0, 1),
-                    q_ref.dtype)
-                v = dequantize_kv(v, jnp.swapaxes(
-                    vs_scr[slot].reshape(rows_g, num_kv_heads), 0, 1),
-                    q_ref.dtype)
+                k = dequantize_kv(k, _scale_rows(ks_scr[slot], num_kv_heads),
+                                  q_ref.dtype)
+                v = dequantize_kv(v, _scale_rows(vs_scr[slot], num_kv_heads),
+                                  q_ref.dtype)
             # Zero V rows outside [win_start, seq_len): pages that were
             # never DMA'd hold unspecified scratch (possibly NaN), and
             # 0 * NaN would poison the accumulator even though those
@@ -325,9 +369,10 @@ def paged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                            logit_softcap: float | None = None) -> jnp.ndarray:
     """q: (B, Hq, D); k_cache/v_cache: (num_blocks, page, Hkv, D);
     block_tables: (B, max_pages) int32; seq_lens: (B,). -> (B, Hq, D).
-    ``k_scale``/``v_scale``: (num_blocks, page, Hkv) f32 when the cache
-    stores int8 (ops/attention.py quantize_kv) — pages then move over HBM
-    at half the bytes and dequantize on the VPU inside the kernel.
+    ``k_scale``/``v_scale``: (num_blocks, page, SCALE_LANES) f32 when the
+    cache stores int8 (ops/attention.py pad_scale_lanes) — value pages
+    then move over HBM at half the bytes and dequantize on the VPU inside
+    the kernel.
     ``sliding_window``: attend only the last W positions; out-of-window
     pages are never DMA'd.
 
@@ -348,7 +393,7 @@ def paged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     pages_g, seqs_pp = _clamp_to_vmem_budget(
         pages_g, seqs_pp, page_size, k_cache.shape[2], k_cache.shape[3],
         k_cache.dtype.itemsize, q.shape[1], q.dtype.itemsize,
-        scale_itemsize=4 if k_scale is not None else 0)
+        quantized=k_scale is not None)
     scales = () if k_scale is None else (k_scale, v_scale)
     return _paged_decode_attention(q, k_cache, v_cache, block_tables,
                                    seq_lens, scales, scale=scale,
@@ -407,7 +452,8 @@ def _paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens,
     ]
     if quantized:
         in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2   # scale pages
-        scratch += [pltpu.VMEM((2, pages_g, page_size, Hkv), jnp.float32)] * 2
+        scratch += [pltpu.VMEM((2, pages_g, page_size, SCALE_LANES),
+                               jnp.float32)] * 2
     scratch.append(pltpu.SemaphoreType.DMA((4 if quantized else 2,
                                             2, pages_g)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -421,9 +467,7 @@ def _paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Bp, Hq, D), q.dtype),
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("arbitrary",),
-        ),
+        compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
     )(block_tables, seq_lens, q, k_cache, v_cache, *scales)
     return out[:B]

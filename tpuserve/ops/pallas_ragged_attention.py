@@ -40,9 +40,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpuserve.ops.pallas_paged_attention import (_COMPILER_PARAMS,
-                                                 TARGET_GROUP_ROWS,
-                                                 _clamp_to_vmem_budget)
+from tpuserve.ops.attention import SCALE_LANES, dequantize_kv
+from tpuserve.ops.pallas_paged_attention import (TARGET_GROUP_ROWS,
+                                                 _clamp_to_vmem_budget,
+                                                 _scale_rows,
+                                                 compiler_params)
 
 NEG_INF = -1e30
 
@@ -123,13 +125,10 @@ def _ragged_kernel(bt_ref, kv_ref, qs_ref, ql_ref, meta_ref, bseq_ref,
         v = jnp.swapaxes(
             v_scr[slot].reshape(rows_g, num_kv_heads, head_dim), 0, 1)
         if quantized:
-            from tpuserve.ops.attention import dequantize_kv
-            k = dequantize_kv(k, jnp.swapaxes(
-                ks_scr[slot].reshape(rows_g, num_kv_heads), 0, 1),
-                q_ref.dtype)
-            v = dequantize_kv(v, jnp.swapaxes(
-                vs_scr[slot].reshape(rows_g, num_kv_heads), 0, 1),
-                q_ref.dtype)
+            k = dequantize_kv(k, _scale_rows(ks_scr[slot], num_kv_heads),
+                              q_ref.dtype)
+            v = dequantize_kv(v, _scale_rows(vs_scr[slot], num_kv_heads),
+                              q_ref.dtype)
         return k, v
 
     # ---- decode part: blk_q one-row sequences, flat row == sequence ----
@@ -378,7 +377,8 @@ def ragged_paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     # over budget crashes Mosaic allocation with a much worse message.
     pages_g, blk_clamped = _clamp_to_vmem_budget(
         pages_g, blk, page_size, Hkv, D, k_cache.dtype.itemsize,
-        Hq, q.dtype.itemsize, scale_itemsize=4 if k_scale is not None else 0)
+        Hq, q.dtype.itemsize, quantized=k_scale is not None,
+        rows_per_dot=True)
     if blk_clamped != blk:
         raise ValueError(
             f"ragged block {blk} needs more VMEM than the budget allows "
@@ -414,7 +414,7 @@ def ragged_paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     scales = ()
     if quantized:
         in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
-        scratch += [pltpu.VMEM((2, pages_g, page_size, Hkv),
+        scratch += [pltpu.VMEM((2, pages_g, page_size, SCALE_LANES),
                                jnp.float32)] * 2
         scales = (k_scale, v_scale)
     scratch.append(pltpu.SemaphoreType.DMA((4 if quantized else 2,
@@ -431,9 +431,7 @@ def ragged_paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("arbitrary",),
-        ),
+        compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
     )(block_tables, kv_lens, q_starts, q_lens, meta, blk_seq,
       q, k_cache, v_cache, *scales)

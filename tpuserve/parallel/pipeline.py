@@ -320,8 +320,8 @@ def pp_decode_multi(head, stages, cfg: ModelConfig, tokens, positions,
     Each iteration is a full pipeline pass (M microbatches overlap across
     stages); the sampled token feeds the next iteration entirely on
     device, so the host syncs once per window instead of once per token —
-    the same S-fold host-round-trip win the single-device engine measured
-    (BENCHMARKS.md S=1 vs S=32).  Sampling runs on the replicated logits
+    the same S-fold host-round-trip saving as on the single-device engine
+    (not measured on the current code).  Sampling runs on the replicated logits
     outside the shard_map region.  Slot ids are derived on device from
     ``block_tables`` and the advancing positions; the window's KV slots
     must be pre-reserved (engine._try_reserve_window).

@@ -17,10 +17,13 @@ prefix, evicted only when fresh blocks run out.
 from __future__ import annotations
 
 import dataclasses
+import logging
 from collections import OrderedDict
 from typing import Optional
 
 from tpuserve.utils import cdiv, next_power_of_2
+
+logger = logging.getLogger("tpuserve.block_manager")
 
 
 # Sentinel in a sequence's block table for a leading block returned to the
@@ -598,17 +601,20 @@ def create_block_manager(num_blocks: int, block_size: int,
     if impl == "auto" and os.environ.get("TPUSERVE_STRICT_BLOCKS"):
         impl = "python"
     if impl in ("auto", "native"):
-        try:
-            from tpuserve.native import NativeBlockManager, native_available
-            if native_available():
-                return NativeBlockManager(
-                    num_blocks, block_size,
-                    enable_prefix_caching=enable_prefix_caching)
-            if impl == "native":
-                raise RuntimeError("native block manager requested but "
-                                   "library unavailable")
-        except RuntimeError:
-            if impl == "native":
-                raise
+        from tpuserve.native import NativeBlockManager, native_available
+        if native_available():
+            return NativeBlockManager(
+                num_blocks, block_size,
+                enable_prefix_caching=enable_prefix_caching)
+        if impl == "native":
+            raise RuntimeError("native block manager requested but "
+                               "library unavailable")
+        import jax
+        if jax.default_backend() == "tpu":
+            # serving on the chip without the C++ host path is a broken
+            # deployment (no toolchain in the image, or the build failed),
+            # not a choice: say so where an operator will see it
+            logger.error("native block manager unavailable on a TPU host; "
+                         "serving on the pure-Python manager")
     return BlockManager(num_blocks, block_size,
                         enable_prefix_caching=enable_prefix_caching)

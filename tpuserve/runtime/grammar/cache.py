@@ -1,6 +1,5 @@
-"""Persistent grammar-FSM compile cache (the BENCHMARKS.md round-6
-follow-up): compiled token-level FSMs keyed by (spec hash, tokenizer
-fingerprint), stored as ``.npz`` files on disk.
+"""Persistent grammar-FSM compile cache: compiled token-level FSMs keyed
+by (spec hash, tokenizer fingerprint), stored as ``.npz`` files on disk.
 
 A production-vocab (151k) inline compile walks every token's text through
 cloned char machines — seconds of admission latency per new grammar.  The

@@ -14,9 +14,9 @@ serving pays nothing for the instrumentation.  Enabled, each phase
 costs two ``perf_counter`` calls.  Since the flight recorder landed
 (runtime/flight.py) the profiler is ALWAYS-ON in practice: building an
 engine with the recorder enabled (the default) flips ``PROF.enabled``
-so every step record carries its phase breakdown; the measured cost is
-inside the <1%-tok/s recorder budget (BENCHMARKS.md "Flight
-recorder"), and ``TPUSERVE_FLIGHT=0`` restores the fully-off state.
+so every step record carries its phase breakdown; its cost on the chip
+is not measured on the current code, and ``TPUSERVE_FLIGHT=0`` restores
+the fully-off state.
 The profiler is engine-loop single-threaded like everything else it
 brackets; it is NOT meant to be shared across engines running in
 different threads (per-cycle deltas in multi-engine processes are

@@ -11,12 +11,14 @@ here capacity is derived from the HBM budget).
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 from tpuserve.models.config import ModelConfig
+from tpuserve.ops.attention import SCALE_LANES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -26,9 +28,9 @@ class CacheConfig:
     max_blocks_per_seq: int = 64
     # "bfloat16"/"float32" store raw; "int8" stores symmetric-absmax
     # quantized values plus one f32 scale per (token, kv head) in parallel
-    # ``ks``/``vs`` paged arrays — halves KV bytes per decode step and
-    # doubles cache capacity per HBM byte (decode is bandwidth-bound;
-    # BENCHMARKS.md roofline).
+    # lane-padded ``ks``/``vs`` paged arrays (ops/attention.py
+    # pad_scale_lanes) — halves the value bytes a decode step reads; the
+    # saving has not been measured on the current code.
     dtype: str = "bfloat16"
 
     @property
@@ -40,23 +42,30 @@ class CacheConfig:
         return self.block_size * self.max_blocks_per_seq
 
 
-def bytes_per_block(model_cfg: ModelConfig, cache_cfg: CacheConfig) -> int:
+def bytes_per_block(model_cfg: ModelConfig, cache_cfg: CacheConfig,
+                    head_shards: int = 1) -> int:
+    """Bytes one block takes across all layers and all ``head_shards``
+    shards of the kv-head axis — what :func:`create_kv_cache` allocates,
+    lane padding of the int8 scale pages included."""
     itemsize = jnp.dtype(cache_cfg.dtype).itemsize
-    per_vector = model_cfg.cache_head_dim * itemsize
+    per_token = (model_cfg.cache_kv_heads * model_cfg.cache_head_dim
+                 * itemsize)
     if cache_cfg.quantized:
-        # one f32 scale per (token, head); MLA carries two per token
-        # (latent + rope slices)
-        per_vector += 8 if model_cfg.is_mla else 4
+        # MLA carries two f32 scales per token (latent + rope slices);
+        # everyone else one 128-lane f32 row per token per head shard
+        per_token += (8 if model_cfg.is_mla
+                      else head_shards * SCALE_LANES * 4)
     # MLA stores ONE latent array (no V pages) — that asymmetry is the
     # ~10x cache-capacity win (models/transformer.py MLA section)
     kv_arrays = 1 if model_cfg.is_mla else 2
     return (kv_arrays * model_cfg.num_layers * cache_cfg.block_size
-            * model_cfg.cache_kv_heads * per_vector)
+            * per_token)
 
 
 def num_blocks_for_budget(model_cfg: ModelConfig, cache_cfg: CacheConfig,
                           hbm_bytes: int, utilization: float = 0.9,
-                          weight_bytes: int | None = None) -> int:
+                          weight_bytes: int | None = None,
+                          head_shards: int = 1) -> int:
     """How many KV blocks fit in ``hbm_bytes`` after weights, at the given
     utilization fraction.  ``weight_bytes``: the ACTUAL loaded parameter
     bytes when known (int8-quantized weights buy a larger cache); defaults
@@ -77,7 +86,8 @@ def num_blocks_for_budget(model_cfg: ModelConfig, cache_cfg: CacheConfig,
             "utilization) — no room for a KV cache; use a bigger "
             "device/share, quantize the weights, or set num_blocks "
             "explicitly")
-    return max(budget // bytes_per_block(model_cfg, cache_cfg), 16)
+    return max(budget // bytes_per_block(model_cfg, cache_cfg, head_shards),
+               16)
 
 
 # --------------------------------------------------------------------------
@@ -153,6 +163,15 @@ def scatter_block_pages(kv_cache: list[dict], blocks: list[int],
     return _scatter_pages(kv_cache, idx, batched)
 
 
+def _kv_head_shards(sharding) -> int:
+    """How many ways a K/V page sharding splits the kv-head axis (axis 2)."""
+    if sharding is None or len(sharding.spec) < 3 or sharding.spec[2] is None:
+        return 1
+    axes = sharding.spec[2]
+    return math.prod(sharding.mesh.shape[a]
+                     for a in ((axes,) if isinstance(axes, str) else axes))
+
+
 def create_kv_cache(model_cfg: ModelConfig, cache_cfg: CacheConfig,
                     shardings=None) -> list[dict]:
     """Zero-initialised per-layer [{"k","v"}] paged cache.
@@ -164,7 +183,6 @@ def create_kv_cache(model_cfg: ModelConfig, cache_cfg: CacheConfig,
     shape = (cache_cfg.num_blocks, cache_cfg.block_size,
              model_cfg.cache_kv_heads, model_cfg.cache_head_dim)
     dtype = jnp.dtype(cache_cfg.dtype)
-    scale_shape = shape[:3]             # one scale per (block, pos, head)
 
     def zeros(sh, shape=shape, dtype=dtype):
         if sh is not None:
@@ -178,6 +196,13 @@ def create_kv_cache(model_cfg: ModelConfig, cache_cfg: CacheConfig,
             return None
         from jax.sharding import NamedSharding, PartitionSpec
         return NamedSharding(sh.mesh, PartitionSpec(*sh.spec[:3]))
+
+    def scale_zeros(sh):
+        """One 128-lane group of scales per shard of the kv-head axis
+        (ops/attention.py pad_scale_lanes)."""
+        groups = _kv_head_shards(sh)
+        return zeros(scale_sharding(sh),
+                     (*shape[:2], groups * SCALE_LANES), jnp.float32)
 
     cache = []
     for li in range(model_cfg.num_layers):
@@ -196,15 +221,13 @@ def create_kv_cache(model_cfg: ModelConfig, cache_cfg: CacheConfig,
             # ranges (ops/attention.py write_mla_entry).
             entry = {"k": zeros(k_sh)}
             if cache_cfg.quantized:
-                entry["ks"] = zeros(scale_sharding(k_sh),
-                                    (*scale_shape[:2], 2), jnp.float32)
+                entry["ks"] = zeros(scale_sharding(k_sh), (*shape[:2], 2),
+                                    jnp.float32)
             cache.append(entry)
             continue
         entry = {"k": zeros(k_sh), "v": zeros(v_sh)}
         if cache_cfg.quantized:
-            entry["ks"] = zeros(scale_sharding(k_sh), scale_shape,
-                                jnp.float32)
-            entry["vs"] = zeros(scale_sharding(v_sh), scale_shape,
-                                jnp.float32)
+            entry["ks"] = scale_zeros(k_sh)
+            entry["vs"] = scale_zeros(v_sh)
         cache.append(entry)
     return cache

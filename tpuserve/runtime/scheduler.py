@@ -98,8 +98,8 @@ class Scheduler:
         # cycle — native when the C++ manager is loaded) vs the
         # historical inline per-candidate loop: TPUSERVE_HOST_BATCHED=0
         # keeps the pre-batching path so the host-overhead A/B
-        # (bench.py --clients-sweep, BENCHMARKS.md) measures what it
-        # claims on every phase, admission included.
+        # (bench.py --clients-sweep) measures what it claims on every
+        # phase, admission included.
         self._batched_admission = env_flag("TPUSERVE_HOST_BATCHED")
         # Mixed mode: the engine pads the decode region and every prefill
         # chunk to this flat-row block (the ragged kernel's grid

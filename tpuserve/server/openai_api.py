@@ -1904,7 +1904,14 @@ class _Handler(BaseHTTPRequestHandler):
                 ctx.engine.requests.pop(rid, None)
 
 
-def main(argv=None):
+def build_server(argv=None):
+    """Parse ``argv`` and build the :class:`OpenAIServer` it describes
+    (engine included), not yet started.  Returns ``(server, args)``;
+    ``server`` is None on a multi-host follower, which has by then run its
+    lockstep loop to the coordinator's stop.  Everything that maps flags
+    to ``EngineConfig`` lives here, so a caller that drives the server
+    in-process (chip_smoke.py) gets exactly what ``python -m
+    tpuserve.server`` would."""
     import argparse
 
     from tpuserve.runtime.engine import Engine, EngineConfig
@@ -2205,7 +2212,7 @@ def main(argv=None):
             # Followers never serve HTTP: mirror the coordinator's steps
             # until it broadcasts OP_STOP, then exit.
             multihost.follower_loop(engine)
-            return
+            return None, args
         multihost.MultihostCoordinator(engine)
     chat_template = None
     if args.chat_template:
@@ -2231,6 +2238,16 @@ def main(argv=None):
         weight_host_bytes=args.weight_host_bytes,
         weight_spill_dir=args.weight_spill_dir,
         allow_kv_migration=args.role == "decode"))
+    return server, args
+
+
+def main(argv=None):
+    """Start the server ``argv`` describes, wait for SIGTERM, drain."""
+    from tpuserve.utils import compile_cache
+    compile_cache.configure()
+    server, args = build_server(argv)
+    if server is None:
+        return
     port = server.start(warmup=not args.no_warmup)
     print(f"tpuserve listening on {args.host}:{port}", flush=True)
     # K8s rolling updates SIGTERM the pod, then SIGKILL after

@@ -1,0 +1,41 @@
+"""Where the persistent XLA compilation cache lives.
+
+One rule for every process that builds an engine (the server, bench.py,
+chip_smoke.py, the tools): when ``JAX_COMPILATION_CACHE_DIR`` is set —
+the manifests mount it on the model PVC — JAX reads it itself and this
+module sets nothing; otherwise the cache goes to ONE fixed directory
+inside the checkout.  The directory is part of what a cache entry is
+found by, so a path that moves (a temp dir, a pid, a platform suffix)
+never hits.
+"""
+
+from __future__ import annotations
+
+import os
+
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+# compiles quicker than this are not worth a file each
+MIN_COMPILE_SECS = 1.0
+
+
+def configure() -> str:
+    """Place the persistent compile cache and return its directory."""
+    env = os.environ.get(ENV_VAR)
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      MIN_COMPILE_SECS)
+    return CHECKOUT_CACHE_DIR
+
+
+def entries(cache_dir: str) -> int:
+    """Number of cache entries on disk (0 for a directory not made yet)."""
+    try:
+        return len(os.listdir(cache_dir))
+    except FileNotFoundError:
+        return 0
