@@ -154,3 +154,30 @@ def test_kernel_compiles_for_v5e(kernel, width, quantized, one_chip,
     fn, args = build(S, *WIDTHS[width], quantized, *extra)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel", ["decode", "flash"])
+def test_the_custom_call_carries_the_name_the_benchmark_matches(
+        kernel, one_chip):
+    """A profiler trace prints a Pallas kernel as its HLO instruction, and
+    ``benchmark/harness`` reduces the trace by that name: it is
+    ``pallas_call(name=KERNEL_NAME)``, not whatever function happens to
+    wrap the call."""
+    import re
+
+    from tpuserve.ops import pallas_flash_attention, pallas_paged_attention
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    build, name, want = {
+        "decode": (_decode, pallas_paged_attention.KERNEL_NAME,
+                   "_paged_decode_attention"),
+        "flash": (_flash, pallas_flash_attention.KERNEL_NAME,
+                  "_flash_prefill_attention")}[kernel]
+    assert name == want
+    fn, args = build(S, *WIDTHS["qwen3-0.6b"], False)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call\([^\n]*"
+                     r"tpu_custom_call", text)
+

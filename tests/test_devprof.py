@@ -19,7 +19,8 @@ import pytest
 
 from tpuserve.runtime import (CacheConfig, Engine, EngineConfig,
                               SamplingParams, SchedulerConfig)
-from tpuserve.runtime.devprof import _NOOP, DeviceProfiler
+from tpuserve.runtime.devprof import DeviceProfiler
+from tpuserve.runtime.hostprof import NOOP, PROF
 from tpuserve.server.openai_api import OpenAIServer, ServerConfig
 
 PARAMS = SamplingParams(max_tokens=8, temperature=0.0, ignore_eos=True)
@@ -220,7 +221,8 @@ def test_devprof_disabled_is_removed_byte_identical():
     """TPUSERVE_DEVPROF=0 / EngineConfig(devprof=False): greedy token
     streams are byte-identical to the devprof-on engine, the flight
     handle is None (step records carry no dev field), and every bracket
-    is the shared no-op (the --no-devprof off arm)."""
+    feeds nothing of devprof's: the span is hostprof's alone, and the
+    shared no-op once hostprof is off too (the --no-devprof off arm)."""
     def _mk(devprof):
         return Engine(EngineConfig(
             model="tiny-qwen3",
@@ -237,8 +239,16 @@ def test_devprof_disabled_is_removed_byte_identical():
     assert not off.devprof.enabled
     assert off.flight.devprof is None, \
         "disabled devprof must unhook from the flight recorder"
-    assert off.devprof.dispatch("decode", ((1, 1),)) is _NOOP
-    assert off.devprof.sync("window") is _NOOP
+    with off.devprof.dispatch("decode", ((1, 1),)), \
+            off.devprof.sync("window"):
+        pass
+    assert not off.devprof.sync_s and not off.devprof.dispatch_s
+    was, PROF.enabled = PROF.enabled, False
+    try:
+        assert off.devprof.dispatch("decode", ((1, 1),)) is NOOP
+        assert off.devprof.sync("window") is NOOP
+    finally:
+        PROF.enabled = was
     off_toks = [r.output_token_ids for r in off.generate(prompts, PARAMS)]
     assert on_toks == off_toks, \
         "TPUSERVE_DEVPROF=0 changed greedy token streams"

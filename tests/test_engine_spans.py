@@ -1,0 +1,201 @@
+"""The engine loop's spans on the profiler's clock (runtime/hostprof.py).
+
+A tiny engine runs prefill and fused-window cycles, some of which evict
+prefix blocks into the KV tier, under ``jax.profiler.start_trace`` with
+the options the benchmark traces with.  What is pinned: the spans are in
+the trace's host plane and join the step records by ``seq``; they come
+from the documented set and nest as documented; the demotion's copy is a
+``sync.demote`` inside ``kv.demote``; ``flush`` is the cycle's ``sync.*``
+time and nothing else; ``ctx_tokens`` is the context the dispatched rows
+attend.  CPU run: control flow and counts, no device number."""
+
+import os
+import signal
+
+import jax
+import pytest
+
+from tpuserve.runtime import (CacheConfig, Engine, EngineConfig,
+                              SamplingParams, SchedulerConfig)
+from tpuserve.runtime.hostprof import PROF
+
+TIME_LIMIT_S = 240
+ROOT = "engine.step"
+# span name (or prefix, ending in ".") -> the spans it may open under
+TREE = {
+    "slo.admission": {ROOT}, "kv.restore": {ROOT}, "schedule": {ROOT},
+    "block": {ROOT}, "kv.demote": {ROOT, "kv.restore"},
+    "dispatch": {ROOT}, "dispatch.": {"dispatch", "sample"},
+    "sample": {ROOT}, "sync.demote": {"kv.demote"},
+    "sync.": {ROOT, "sample"}, "detokenize": {ROOT}, "step.close": {ROOT},
+}
+PARAMS = SamplingParams(max_tokens=9, temperature=0.0, ignore_eos=True)
+
+
+@pytest.fixture(autouse=True)
+def time_limit():
+    """This file's own limit: a profiler session that hangs must fail
+    here, not eat the suite's."""
+    def late(signum, frame):
+        raise TimeoutError(f"test passed its {TIME_LIMIT_S}s limit")
+    old = signal.signal(signal.SIGALRM, late)
+    signal.alarm(TIME_LIMIT_S)
+    yield
+    signal.alarm(0)
+    signal.signal(signal.SIGALRM, old)
+
+
+def kind_of(name):
+    if name in TREE or name == ROOT:
+        return name
+    head = name.split(".", 1)[0] + "."
+    return head if head in TREE and "." in name else None
+
+
+def read_spans(trace_dir):
+    """``[(start, end, name, stats)]`` of the loop thread's line: the one
+    that holds ``engine.step`` events, the tracer's own events dropped;
+    and every name on that line."""
+    path = next(os.path.join(root, f) for root, _, files in os.walk(trace_dir)
+                for f in files if f.endswith(".xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    lines = [list(line.events) for plane in data.planes
+             if plane.name.startswith("/host:") for line in plane.lines]
+    loop = [evs for evs in lines if any(e.name == ROOT for e in evs)]
+    assert len(loop) == 1, "engine.step must be on exactly one host line"
+    spans = sorted(((e.start_ns, e.start_ns + e.duration_ns, e.name,
+                     dict(e.stats)) for e in loop[0] if kind_of(e.name)),
+                   key=lambda x: (x[0], -x[1]))
+    return spans, {e.name for e in loop[0]}
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    eng = Engine(EngineConfig(
+        model="tiny-qwen3",
+        cache=CacheConfig(block_size=4, num_blocks=24, max_blocks_per_seq=16),
+        scheduler=SchedulerConfig(max_num_seqs=4, max_prefill_tokens=256,
+                                  min_prefill_bucket=8, min_decode_bucket=2),
+        enable_prefix_caching=True, kv_tiers=True, multi_step=4))
+    assert PROF.enabled, "the flight recorder turns the spans on"
+    prompts = [list(range(2, 26)), [7] * 13]
+    churn = [[100 + i] * 40 for i in range(3)]
+    eng.generate(prompts, PARAMS)            # compile outside the trace
+    eng.generate(churn, PARAMS)
+    first = eng.flight.seq
+    trace_dir = str(tmp_path_factory.mktemp("trace"))
+    opts = jax.profiler.ProfileOptions()     # benchmark/harness/session.py
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        eng.generate(prompts, PARAMS)        # evicts the churn's blocks
+        eng.generate(churn, PARAMS)          # evicts the prompts' blocks
+    finally:
+        jax.profiler.stop_trace()
+    steps = {s["seq"]: s for s in eng.flight.steps_snapshot(limit=10_000)
+             if s["seq"] > first}
+    spans, names = read_spans(trace_dir)
+    return {"spans": spans, "names": names, "steps": steps, "engine": eng,
+            "prompts": prompts}
+
+
+def parents(spans):
+    """``[(span, its parent or None)]`` by nesting in time."""
+    out, stack = [], []
+    for span in spans:
+        while stack and stack[-1][1] <= span[0]:
+            stack.pop()
+        out.append((span, stack[-1] if stack else None))
+        stack.append(span)
+    return out
+
+
+def test_every_cycle_has_a_span_that_joins_its_step_record(traced):
+    roots = [s for s in traced["spans"] if s[2] == ROOT]
+    seqs = [s[3]["seq"] for s in roots]
+    assert len(seqs) == len(set(seqs)) == len(traced["steps"]) > 8
+    assert set(seqs) == set(traced["steps"])
+    kinds = {traced["steps"][q]["kind"] for q in seqs}
+    assert {"prefill", "prefill_chunk", "window", "idle"} <= kinds
+
+
+def test_children_come_from_the_documented_set_and_nest(traced):
+    seen = set()
+    for span, parent in parents(traced["spans"]):
+        kind = kind_of(span[2])
+        seen.add(kind)
+        if kind == ROOT:
+            assert parent is None, "cycles do not nest"
+            continue
+        assert parent is not None, f"{span[2]} outside every engine.step"
+        assert parent[2] in TREE[kind] or kind_of(parent[2]) in TREE[kind], \
+            f"{span[2]} opened under {parent[2]}"
+        assert parent[0] <= span[0] and span[1] <= parent[1] + 1000, \
+            f"{span[2]} leaves its parent {parent[2]}"
+    assert {ROOT, "slo.admission", "kv.restore", "schedule", "block",
+            "kv.demote", "dispatch", "dispatch.", "sample", "sync.",
+            "sync.demote", "detokenize", "step.close"} <= seen
+
+
+def test_a_cycle_with_evictions_copies_under_sync_demote(traced):
+    eng = traced["engine"]
+    assert eng.stats.kv_demoted_blocks > 0
+    by_parent = parents(traced["spans"])
+    copies = [(s, p) for s, p in by_parent if s[2] == "sync.demote"]
+    assert copies and all(p[2] == "kv.demote" for _, p in copies)
+    assert eng.devprof.sync_counts["demote"] >= len(copies)
+    # most cycles evict nothing: their kv.demote span holds no sync
+    demotes = [s for s in traced["spans"] if s[2] == "kv.demote"]
+    assert len(demotes) > len(copies)
+
+
+def test_the_programs_keep_the_names_the_benchmark_matches(traced):
+    """``step.decode_device_ms`` sums the ``XLA Modules`` events whose name
+    holds ``decode_multi``, and the ledger's gaps are named by program: the
+    jitted functions' names are part of the yardstick (the kernels' names
+    are pinned where they compile, tests/test_chip_compile.py)."""
+    for program in ("decode_multi", "prefill", "prefill_chunk",
+                    "_gather_pages", "_scatter_pages", "sample_tokens"):
+        assert f"PjitFunction({program})" in traced["names"], program
+
+
+def test_flush_is_the_cycles_sync_time(traced):
+    checked = 0
+    for step in traced["steps"].values():
+        phases = step.get("phase_ms") or {}
+        syncs = sum(v for k, v in phases.items() if k.startswith("sync."))
+        assert phases.get("flush", 0.0) == pytest.approx(syncs, abs=1e-3)
+        assert (step.get("dev") or {}).get("device_ms", 0.0) == \
+            pytest.approx(syncs, abs=1e-3)
+        checked += syncs > 0
+    assert checked > 4
+
+
+def test_ctx_tokens_is_the_context_the_dispatched_rows_attend(traced):
+    eng = Engine(EngineConfig(
+        model="tiny-qwen3",
+        cache=CacheConfig(block_size=4, num_blocks=64, max_blocks_per_seq=16),
+        scheduler=SchedulerConfig(max_num_seqs=4, max_prefill_tokens=256,
+                                  min_prefill_bucket=8, min_decode_bucket=2),
+        multi_step=4))
+    lens = [len(p) for p in traced["prompts"]]
+    eng.generate(traced["prompts"], PARAMS)
+    prefill, first, second = eng.flight.steps_snapshot(limit=3)
+    assert [s["kind"] for s in (prefill, first, second)] == \
+        ["prefill", "window", "window"]
+    assert prefill["ctx_tokens"] == prefill["actual_tokens"] == sum(lens)
+    # after the prefill's token a row holds len + 1 tokens; the second
+    # window is dispatched while the first (4 steps) is still in flight
+    assert first["rows"] == second["rows"] == 2
+    assert first["ctx_tokens"] == sum(n + 1 for n in lens)
+    assert second["ctx_tokens"] == sum(n + 1 + 4 for n in lens)
+    # with a cached or restored prefix, a chunk attends what it skipped
+    for step in traced["steps"].values():
+        if step["kind"] == "prefill_chunk":
+            assert step["ctx_tokens"] >= step["actual_tokens"]
+        if step["kind"] == "idle":
+            assert step["ctx_tokens"] == 0
+    assert any(s["kind"] == "prefill_chunk"
+               and s["ctx_tokens"] > s["actual_tokens"]
+               for s in traced["steps"].values())

@@ -294,7 +294,7 @@ def test_hostprof_report_shape_and_noop_when_disabled():
     assert rep["cycles"] == 1
     assert set(rep["phases"]) >= {"block", "schedule"}
     assert rep["host_ms_per_cycle"] >= 0
-    assert rep["all_phases_ms_per_cycle"] >= rep["host_ms_per_cycle"]
+    assert rep["phases"]["block"]["calls"] == 1
 
 
 def test_engine_soak_fills_host_phases():

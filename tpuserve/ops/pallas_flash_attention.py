@@ -20,6 +20,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+#: what the kernel's custom call is called in a profiler trace (the HLO
+#: instruction's name); the benchmark's trace readers match it
+KERNEL_NAME = "_flash_prefill_attention"
+
 
 NEG_INF = -1e30
 
@@ -176,5 +180,6 @@ def _flash_prefill_attention(q, k, v, prompt_lens, *, scale: float,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(prompt_lens, qt, kt, vt)
     return jnp.swapaxes(out, 1, 2)

@@ -55,6 +55,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tpuserve.ops.attention import SCALE_LANES, dequantize_kv
 
+#: what the kernel's custom call is called in a profiler trace (the HLO
+#: instruction's name); the benchmark's trace readers match it
+KERNEL_NAME = "_paged_decode_attention"
+
 logger = logging.getLogger("tpuserve.ops.paged_attention")
 
 NEG_INF = -1e30
@@ -469,5 +473,6 @@ def _paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens,
         out_shape=jax.ShapeDtypeStruct((Bp, Hq, D), q.dtype),
         compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(block_tables, seq_lens, q, k_cache, v_cache, *scales)
     return out[:B]

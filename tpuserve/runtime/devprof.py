@@ -12,7 +12,8 @@ the capability/cost signals heterogeneous routing wants (arxiv
 
 - **device-time attribution**: the engine brackets its EXISTING
   designated sync points (window flush, pending flush, sample read,
-  spec verify, draft proposal, guided top-k) with ``sync(kind)`` — the
+  spec verify, draft proposal, guided top-k, and the KV tier's copy of
+  evicted pages to the host, ``demote``) with ``sync(kind)`` — the
   host seconds blocked in a ``device_get`` are the device time the
   pipelined design successfully hid everywhere else, split per sync
   kind.  Dispatch brackets (``dispatch(kind, key)``) time the ASYNC
@@ -33,11 +34,12 @@ the capability/cost signals heterogeneous routing wants (arxiv
   SLO auto-capture hook (server/tracing.py holds the capture lock), so
   post-mortem bundles reference the traces written beside them.
 
-Cost contract: mirrors hostprof — disabled, every bracket returns a
-shared no-op context manager (an attribute load and a falsy check per
-site, no timestamps); enabled, a bracket costs two ``perf_counter``
-calls and a dict update, inside the same <1% tok/s budget the flight
-recorder holds (``bench.py --devprof`` is the interleaved A/B guard).
+Cost contract: ``sync`` and ``dispatch`` are hostprof's one span
+primitive (``runtime/hostprof.py`` ``Span``, names ``sync.<kind>`` /
+``dispatch.<kind>``, on the profiler's clock while a capture runs) with
+this module's accumulators added; with devprof off a bracket feeds
+hostprof alone, and with the flight recorder off too it is the shared
+no-op.  The cost on the chip is a measured number (PERF.md §6, PR 24).
 ``TPUSERVE_DEVPROF=0`` / ``EngineConfig.devprof=False`` /
 ``--no-devprof`` removes the layer with byte-identical serving
 behaviour: nothing here ever touches a jax array or changes a dispatch.
@@ -51,86 +53,17 @@ processes (disagg) keep per-engine attribution exact.
 
 from __future__ import annotations
 
-import time
 from collections import defaultdict
+from functools import partial
 from typing import Optional
 
+from tpuserve.runtime.hostprof import NOOP, PROF, Span
 from tpuserve.utils import env_flag
 
 #: bound the ladder table in snapshots/bundles: a pathological bucket
 #: explosion must not turn /debug/engine into a megabyte payload (the
 #: registry itself is unbounded — seeing the overflow COUNT is the point)
 MAX_LADDER_SNAPSHOT = 128
-
-
-class _NoopCtx:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP = _NoopCtx()
-
-
-class _Dispatch:
-    """Brackets one async exec-hook call: accumulates host dispatch wall
-    per kind and maintains the (kind, key) ladder entry — first call
-    records the bracket wall as the executable's compile cost."""
-
-    __slots__ = ("_dp", "_kind", "_key", "_t0")
-
-    def __init__(self, dp, kind, key):
-        self._dp = dp
-        self._kind = kind
-        self._key = key
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
-        dp = self._dp
-        dp.dispatch_s[self._kind] += dt
-        dp.dispatch_counts[self._kind] += 1
-        lk = (self._kind, self._key)
-        ent = dp.ladder.get(lk)
-        if ent is None:
-            # first dispatch of this (kind, bucket): the blocking XLA
-            # compile ran inside this bracket — that wall IS the
-            # compile cost (tools/profile_step.py measures the same way)
-            dp.ladder[lk] = [round(dt * 1000, 3), 1,
-                             dp.estimate_bytes(self._key)]
-            dp.compiles += 1
-            dp.compile_s += dt
-        else:
-            ent[1] += 1
-        return False
-
-
-class _Sync:
-    """Brackets one EXISTING designated device_get: seconds the host
-    blocked waiting for the device, attributed to the sync kind."""
-
-    __slots__ = ("_dp", "_kind", "_t0")
-
-    def __init__(self, dp, kind):
-        self._dp = dp
-        self._kind = kind
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        dp = self._dp
-        dp.sync_s[self._kind] += time.perf_counter() - self._t0
-        dp.sync_counts[self._kind] += 1
-        return False
 
 
 class DeviceProfiler:
@@ -180,14 +113,37 @@ class DeviceProfiler:
     # ---- hot path (engine loop thread) --------------------------------
 
     def dispatch(self, kind: str, key: tuple):
+        """Span ``dispatch.<kind>`` around one async exec-hook call: host
+        dispatch wall per kind, and the (kind, key) ladder entry."""
+        name = "dispatch." + kind
         if not self.enabled:
-            return _NOOP
-        return _Dispatch(self, kind, key)
+            return PROF.phase(name)
+        return Span(name, PROF.sinks(name)
+                    + ((self.dispatch_s, self.dispatch_counts, kind),),
+                    partial(self._note_dispatch, (kind, key)))
+
+    def _note_dispatch(self, lk: tuple, dt: float) -> None:
+        ent = self.ladder.get(lk)
+        if ent is None:
+            # first dispatch of this (kind, bucket): the blocking XLA
+            # compile ran inside this bracket — that wall IS the
+            # compile cost (tools/profile_step.py measures the same way)
+            self.ladder[lk] = [round(dt * 1000, 3), 1,
+                               self.estimate_bytes(lk[1])]
+            self.compiles += 1
+            self.compile_s += dt
+        else:
+            ent[1] += 1
 
     def sync(self, kind: str):
-        if not self.enabled:
-            return _NOOP
-        return _Sync(self, kind)
+        """Span ``sync.<kind>`` around one designated device_get: seconds
+        the host blocked waiting for the device, per kind; every sync
+        also feeds hostprof's ``flush``."""
+        name = "sync." + kind
+        sinks = PROF.sinks(name, "flush")
+        if self.enabled:
+            sinks += ((self.sync_s, self.sync_counts, kind),)
+        return Span(name, sinks) if sinks else NOOP
 
     def bump_cycle(self) -> None:
         if self.enabled:

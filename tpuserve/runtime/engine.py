@@ -226,6 +226,9 @@ class EngineStats:
     # plus running totals for before/after efficiency ratios
     step_padded_tokens: int = 0
     step_actual_tokens: int = 0
+    # context tokens the LAST dispatch's attention read, summed over its
+    # real rows (the step record's ctx_tokens; _note_step_tokens)
+    step_ctx_tokens: int = 0
     padded_tokens_total: int = 0
     actual_tokens_total: int = 0
     prompt_tokens: int = 0
@@ -1478,15 +1481,22 @@ class Engine:
         runtime complement to tpulint's static kv-leak pass (faulted
         steps skip the check: their orphans are reconciled by the
         runner's salvage path, not mid-exception)."""
-        t_cycle = self.clock.monotonic()
-        outputs = self._step_inner()
+        with PROF.phase("engine.step", seq=self.flight.begin_step()):
+            t_cycle = self.clock.monotonic()
+            outputs = self._step_inner()
+            with PROF.phase("step.close"):
+                self._close_step(t_cycle)
+        return outputs
+
+    def _close_step(self, t_cycle: float) -> None:
         if self._flight_on:
             dispatched = bool(self._dispatch_rids)
             self.flight.note_step(
                 self._step_kind, len(self._dispatch_rids),
                 self.stats.step_actual_tokens if dispatched else 0,
                 self.stats.step_padded_tokens if dispatched else 0,
-                self.clock.monotonic() - t_cycle)
+                self.clock.monotonic() - t_cycle,
+                ctx_tokens=self.stats.step_ctx_tokens if dispatched else 0)
         if self._slo is not None:
             # estimator tick once per successful cycle (queue depth +
             # the EWMAs fed during scheduling) drives the brownout
@@ -1508,7 +1518,6 @@ class Engine:
                 running=len(self.scheduler.running))
         if self._strict_blocks:
             self._check_block_integrity()
-        return outputs
 
     def _check_block_integrity(self) -> None:
         chk = getattr(self.block_manager, "check_integrity", None)
@@ -1533,15 +1542,17 @@ class Engine:
         # requests leave without spending prefill, and a stricter-class
         # waiting head may preempt running batch rows for its seat/blocks
         # (runtime/slo.py; no-ops when SLO scheduling is off)
-        self._expire_queued_deadlines()
-        pre = self._slo_preempt_for_admission()
+        with PROF.phase("slo.admission"):
+            self._expire_queued_deadlines()
+            pre = self._slo_preempt_for_admission()
         if self._kv_tiers is not None:
             # commit FIRST: last cycle's restored prefixes become HBM
             # prefix entries, so their requests admit THIS cycle with the
             # restored span as shared blocks; then start new restores,
             # whose copies overlap the batch dispatched below
-            self._commit_tier_restores()
-            self._begin_tier_restores()
+            with PROF.phase("kv.restore"):
+                self._commit_tier_restores()
+                self._begin_tier_restores()
         with PROF.phase("schedule"):
             batch = self.scheduler.schedule()
         if batch is None:
@@ -1623,22 +1634,25 @@ class Engine:
         store = self._kv_tiers
         if store is None:
             return
-        # filter out hashes that became HBM-resolvable again since their
-        # eviction (a later allocation in the SAME cycle recomputed and
-        # re-registered the prefix — two requests sharing it in one
-        # batch): HBM holds the canonical copy, demoting the stale block
-        # would put the hash in two tiers at once
-        ev = [(b, h) for b, h in self.block_manager.take_evictions()
-              if not self.block_manager.prefix_resolvable(h)]
-        if not ev:
-            return
-        from tpuserve.runtime.kv_cache import gather_block_pages
-        pages = gather_block_pages(self.kv_cache, [b for b, _ in ev])
-        for (_b, h), p in zip(ev, pages):
-            store.put(h, p)
-        self.stats.kv_demoted_blocks += len(ev)
-        self.stats.kv_spilled_blocks = store.spilled_blocks
-        self.stats.kv_tier_dropped_blocks = store.dropped_blocks
+        with PROF.phase("kv.demote"):
+            # filter out hashes that became HBM-resolvable again since
+            # their eviction (a later allocation in the SAME cycle
+            # recomputed and re-registered the prefix — two requests
+            # sharing it in one batch): HBM holds the canonical copy,
+            # demoting the stale block would put the hash in two tiers
+            # at once
+            ev = [(b, h) for b, h in self.block_manager.take_evictions()
+                  if not self.block_manager.prefix_resolvable(h)]
+            if not ev:
+                return
+            from tpuserve.runtime.kv_cache import gather_block_pages
+            pages = gather_block_pages(self.kv_cache, [b for b, _ in ev],
+                                       sync=self.devprof.sync("demote"))
+            for (_b, h), p in zip(ev, pages):
+                store.put(h, p)
+            self.stats.kv_demoted_blocks += len(ev)
+            self.stats.kv_spilled_blocks = store.spilled_blocks
+            self.stats.kv_tier_dropped_blocks = store.dropped_blocks
 
     def _drop_superseded_tier_entries(self, ids: list[int]) -> None:
         """Called right after a first allocate: the request's prefill is
@@ -1745,13 +1759,19 @@ class Engine:
                 self.stats.restore_latencies.append(now - t0)
         self._restores.clear()
 
-    def _note_step_tokens(self, actual: int, padded: int) -> None:
+    def _note_step_tokens(self, actual: int, padded: int,
+                          ctx_tokens: int) -> None:
         """Record one dispatch's real vs padded token counts (the
         padding-waste observability behind the
         ``tpuserve_step_padded/actual_tokens`` gauges) — ONE home so the
-        phase-split and mixed paths count identically."""
+        phase-split and mixed paths count identically.  ``ctx_tokens`` is
+        the work at the attention kernel's boundary: the context the
+        dispatch's real rows attend, summed (``seq_lens`` for decode,
+        window — at its first step — and verify; context + chunk length
+        for prefill, chunk and mixed), from host-known integers."""
         self.stats.step_actual_tokens = actual
         self.stats.step_padded_tokens = padded
+        self.stats.step_ctx_tokens = ctx_tokens
         self.stats.actual_tokens_total += actual
         self.stats.padded_tokens_total += padded
         if self._slo is not None:
@@ -2058,7 +2078,8 @@ class Engine:
                 jnp.asarray(slot_ids), **kw)
         self.scheduler.mark_running(reqs)
         self.stats.num_prefill_steps += 1
-        self._note_step_tokens(int(prompt_lens[:len(reqs)].sum()), B * L)
+        n_tok = int(prompt_lens[:len(reqs)].sum())
+        self._note_step_tokens(n_tok, B * L, n_tok)
         new_tokens = self._sample(logits, reqs, B)
         now = self.clock.monotonic()
         for req in reqs:
@@ -2126,14 +2147,15 @@ class Engine:
         block_tables[0, :len(bt)] = bt
         kw = self._lora_kw([req], 1)
         self._demote_evicted()
-        logits, self.kv_cache = self._exec_prefill_chunk(
-            jnp.asarray(tokens),
-            jnp.asarray(np.asarray([done], np.int32)),
-            jnp.asarray(np.asarray([n], np.int32)),
-            jnp.asarray(slot_ids), jnp.asarray(block_tables), **kw)
+        with PROF.phase("dispatch"):
+            logits, self.kv_cache = self._exec_prefill_chunk(
+                jnp.asarray(tokens),
+                jnp.asarray(np.asarray([done], np.int32)),
+                jnp.asarray(np.asarray([n], np.int32)),
+                jnp.asarray(slot_ids), jnp.asarray(block_tables), **kw)
         req.num_prefilled = done + n
         self.stats.num_prefill_steps += 1
-        self._note_step_tokens(n, C)
+        self._note_step_tokens(n, C, done + n)
         if req.num_prefilled < len(ids):
             # more chunks to go: back to the head of the queue
             self.scheduler.waiting.appendleft(req)
@@ -2308,7 +2330,8 @@ class Engine:
         if chunks:
             self.stats.num_prefill_steps += 1
         actual = n_dec + sum(c[3] for c in chunks)
-        self._note_step_tokens(actual, T)
+        self._note_step_tokens(
+            actual, T, int(kv_lens[:n_dec + len(chunks)].sum()))
         # bookkeeping: chunk progress, requeue continuations, promote
         # completions to running BEFORE sampling/emit (finish() removes
         # from running; same order as _run_prefill_chunk)
@@ -2539,14 +2562,14 @@ class Engine:
                 gstate_in = jnp.asarray(gstate_host)
             kw.update(gstate=gstate_in, gmasks=gm, gclass=gc, gnext=gn)
             self.stats.guided_fsm_windows += 1
-        if p is not None:
-            tokens = _select_tokens(p.toks[:, -1], jnp.asarray(gather),
-                                    jnp.asarray(host_tokens),
-                                    jnp.asarray(use_host))
-        else:
-            tokens = jnp.asarray(host_tokens)
         self._demote_evicted()
         with PROF.phase("dispatch"):
+            if p is not None:
+                tokens = _select_tokens(p.toks[:, -1], jnp.asarray(gather),
+                                        jnp.asarray(host_tokens),
+                                        jnp.asarray(use_host))
+            else:
+                tokens = jnp.asarray(host_tokens)
             res = self._exec_decode_multi(
                 tokens, jnp.asarray(positions),
                 jnp.asarray(block_tables), jnp.asarray(seq_lens),
@@ -2560,7 +2583,8 @@ class Engine:
             ri += 1
         gstate_out = res[ri] if gfsm is not None else None
         self.stats.num_decode_steps += S
-        self._note_step_tokens(len(reqs) * S, B * S)
+        self._note_step_tokens(len(reqs) * S, B * S,
+                               int(seq_lens[:len(reqs)].sum()))
         if S < self._multi_step:
             # counted at the dispatch, not in _window_steps(): eligibility
             # bailouts above return before any window actually shrinks
@@ -2602,7 +2626,7 @@ class Engine:
         # exactly what the salvage path expects to find.
         self.faults.check("window_flush",
                           tuple(r.request_id for r in p.reqs))
-        with PROF.phase("flush"), self.devprof.sync("window"):
+        with self.devprof.sync("window"):
             # tpulint: sync-ok(THE designated sync: one device_get per S-token window is the whole fused-window design)
             toks_h = np.asarray(jax.device_get(p.toks))
         lp_h = None
@@ -2852,26 +2876,28 @@ class Engine:
         if self._flight_on:
             self.flight.req_event_many(self._dispatch_rids, "WINDOW",
                                        steps=1)
-        if pending is not None:
-            tokens = _select_tokens(pending.toks, jnp.asarray(gather),
-                                    jnp.asarray(host_tokens),
-                                    jnp.asarray(use_host))
-        else:
-            tokens = jnp.asarray(host_tokens)
         kw = self._lora_kw(reqs, B)
         self._demote_evicted()
         with PROF.phase("dispatch"):
+            if pending is not None:
+                tokens = _select_tokens(pending.toks, jnp.asarray(gather),
+                                        jnp.asarray(host_tokens),
+                                        jnp.asarray(use_host))
+            else:
+                tokens = jnp.asarray(host_tokens)
             logits, self.kv_cache = self._exec_decode(
                 tokens, jnp.asarray(positions), jnp.asarray(slot_arr),
                 jnp.asarray(block_tables), jnp.asarray(seq_lens), **kw)
         self.stats.num_decode_steps += 1
-        self._note_step_tokens(len(reqs), B)
+        self._note_step_tokens(len(reqs), B,
+                               int(seq_lens[:len(reqs)].sum()))
         if pipeline_ok:
             if any(r.params.needs_logit_bias for r in reqs):
                 # static per request (no host token history), so safe on
                 # the pipelined path — unlike penalties
                 logits = self._apply_logit_bias(logits, reqs, B)
-            toks = self._sample_modes(logits, reqs, B, in_flight)
+            with PROF.phase("sample"):
+                toks = self._sample_modes(logits, reqs, B, in_flight)
             # resolve the PREVIOUS step while this one runs on device
             outputs += self._flush_pending()
             self._pending = PendingDecode(reqs=list(reqs), toks=toks)
@@ -2943,12 +2969,14 @@ class Engine:
                 keys[i] = self._row_key(r)
                 temperature[i] = r.params.temperature
             top_k, top_p, min_p = self._truncation_arrays(reqs, B)
-            accept, pred, self.kv_cache = self._exec_decode_verify_sampled(
-                jnp.asarray(tokens), jnp.asarray(ctx_lens),
-                jnp.asarray(chunk_lens), jnp.asarray(slot_ids),
-                jnp.asarray(block_tables), jnp.asarray(keys),
-                jnp.asarray(temperature), jnp.asarray(top_k),
-                jnp.asarray(top_p), jnp.asarray(min_p))
+            with PROF.phase("dispatch"):
+                accept, pred, self.kv_cache = \
+                    self._exec_decode_verify_sampled(
+                        jnp.asarray(tokens), jnp.asarray(ctx_lens),
+                        jnp.asarray(chunk_lens), jnp.asarray(slot_ids),
+                        jnp.asarray(block_tables), jnp.asarray(keys),
+                        jnp.asarray(temperature), jnp.asarray(top_k),
+                        jnp.asarray(top_p), jnp.asarray(min_p))
             # ONE round trip for both arrays
             with self.devprof.sync("verify"):
                 accept_h, pred_h = (
@@ -2956,16 +2984,19 @@ class Engine:
                     # tpulint: sync-ok(spec verify is synchronous by design: accept/pred decide host-side emission this step)
                     jax.device_get((accept, pred)))
         else:
-            pred, self.kv_cache = self._exec_decode_verify(
-                jnp.asarray(tokens), jnp.asarray(ctx_lens),
-                jnp.asarray(chunk_lens), jnp.asarray(slot_ids),
-                jnp.asarray(block_tables))
+            with PROF.phase("dispatch"):
+                pred, self.kv_cache = self._exec_decode_verify(
+                    jnp.asarray(tokens), jnp.asarray(ctx_lens),
+                    jnp.asarray(chunk_lens), jnp.asarray(slot_ids),
+                    jnp.asarray(block_tables))
             with self.devprof.sync("verify"):
                 # tpulint: sync-ok(greedy spec verify twin of the sampled sync above)
                 pred_h = np.asarray(jax.device_get(pred))
         self.stats.num_decode_steps += 1
         self.stats.spec_steps += 1
-        self._note_step_tokens(int(chunk_lens[:len(reqs)].sum()), B * K)
+        n_tok = int(chunk_lens[:len(reqs)].sum())
+        self._note_step_tokens(
+            n_tok, B * K, int(ctx_lens[:len(reqs)].sum()) + n_tok)
         step_proposed = step_accepted = 0
         for i, r in enumerate(reqs):
             emitted = (spec_mod.accept_greedy(drafts[i], pred_h[i])
@@ -3000,8 +3031,9 @@ class Engine:
             ids = (r.prompt_token_ids + r.output_token_ids)[-W:]
             tokens[i, :len(ids)] = ids
             lens[i] = len(ids)
-        out_d = self._exec_draft_propose(jnp.asarray(tokens),
-                                         jnp.asarray(lens), k=k)
+        with PROF.phase("dispatch"):
+            out_d = self._exec_draft_propose(jnp.asarray(tokens),
+                                             jnp.asarray(lens), k=k)
         # designated sync: draft proposals feed the verify batch built
         # host-side this same step (the spec path is synchronous)
         with self.devprof.sync("draft"):
@@ -3039,7 +3071,7 @@ class Engine:
         p, self._pending = self._pending, None
         if p is None:
             return []
-        with PROF.phase("flush"), self.devprof.sync("decode"):
+        with self.devprof.sync("decode"):
             # tpulint: sync-ok(the single-step pipeline's designated sync: resolves the PREVIOUS step while the next runs)
             toks = np.asarray(jax.device_get(p.toks))
         reqs, vals = [], []
@@ -3057,6 +3089,11 @@ class Engine:
     MAX_LOGPROBS = 20
 
     def _sample(self, logits: jnp.ndarray, reqs: list[Request], B: int) -> np.ndarray:
+        with PROF.phase("sample"):
+            return self._sample_sync(logits, reqs, B)
+
+    def _sample_sync(self, logits: jnp.ndarray, reqs: list[Request],
+                     B: int) -> np.ndarray:
         n = len(reqs)
         if any(r.params.needs_penalties for r in reqs):
             logits = self._apply_penalties(logits, reqs, B)
@@ -3075,7 +3112,7 @@ class Engine:
         toks = self._sample_modes(logits, reqs, B, frozenset())
         if any(r.params.logprobs is not None for r in reqs):
             self._record_logprobs(logits, toks, reqs)
-        with PROF.phase("flush"), self.devprof.sync("sample"):
+        with self.devprof.sync("sample"):
             # tpulint: sync-ok(the synchronous per-step path's one sync; the pipelined paths never call _sample)
             toks_np = np.asarray(jax.device_get(toks))[:n].copy()
         if any(r.request_id in self._guided for r in reqs):

@@ -132,6 +132,11 @@ class FlightRecorder:
         # per-cycle hostprof deltas are diffs against this snapshot of the
         # module profiler's cumulative seconds
         self._prof_last: dict = {}
+        # the current cycle's number (begin_step): the ``seq`` argument of
+        # the cycle's engine.step span and of its step record, so a
+        # profiler trace and the records join; the recorder owns it
+        # because it outlives the engine across a model swap
+        self.seq = 0
         # device telemetry handle (runtime/devprof.py): set by the OWNING
         # engine when devprof is enabled; None keeps every record
         # byte-identical to a devprof-less build (the TPUSERVE_DEVPROF=0
@@ -173,12 +178,19 @@ class FlightRecorder:
             self._events.append((t, rid, "FAULT",
                                  {"site": site, "mode": mode}))
 
+    def begin_step(self) -> int:
+        self.seq += 1
+        return self.seq
+
     def note_step(self, kind: str, rows: int, actual: int, padded: int,
-                  dur_s: float) -> None:
+                  dur_s: float, ctx_tokens: int = 0) -> None:
         """One engine cycle's step record.  Phase ms are deltas of the
-        module hostprof profiler since the previous record — exact for a
-        one-engine process (the common case); multi-engine processes
-        interleave and the attribution is approximate."""
+        module hostprof profiler since the previous record, one key per
+        span name (runtime/hostprof.py): a span still open here
+        (engine.step, step.close) or opened by the runner after the step
+        lands in the NEXT record.  Exact for a one-engine process (the
+        common case); multi-engine processes interleave and the
+        attribution is approximate."""
         if not self.enabled:
             return
         phases = None
@@ -196,7 +208,8 @@ class FlightRecorder:
             # diffing idiom as the hostprof phases above
             dev = self.devprof.step_delta()
         self._steps.append((self._clock.monotonic(), kind, rows, actual, padded,
-                            round(dur_s * 1000, 4), phases or None, dev))
+                            round(dur_s * 1000, 4), phases or None, dev,
+                            self.seq, ctx_tokens))
 
     def note_engine_facts(self, **facts) -> None:
         """Engine configuration facts stamped into every bundle (model,
@@ -267,11 +280,11 @@ class FlightRecorder:
 
     def steps_snapshot(self, limit: int = 128) -> list[dict]:
         out = []
-        for t, kind, rows, actual, padded, ms, phases, dev in \
+        for t, kind, rows, actual, padded, ms, phases, dev, seq, ctx in \
                 self._steps.snapshot()[-limit:]:
-            rec = {"t": t, "kind": kind, "rows": rows,
+            rec = {"t": t, "seq": seq, "kind": kind, "rows": rows,
                    "actual_tokens": actual, "padded_tokens": padded,
-                   "ms": ms}
+                   "ctx_tokens": ctx, "ms": ms}
             if phases:
                 rec["phase_ms"] = phases
             if dev:

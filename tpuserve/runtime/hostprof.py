@@ -1,72 +1,105 @@
-"""Host hot-path phase timer (opt-in, near-zero cost when off).
+"""Engine-loop spans: one primitive, on the profiler's clock.
 
 The device loop is pipelined (one sync per S-token window), which makes
 the PYTHON between dispatches the scaling wall at high stream counts —
 DeepServe's host-overhead observation (PAPERS.md, arxiv 2501.14417).
-This module gives that cost a number: the engine brackets its per-cycle
-phases (schedule / block-accounting / dispatch / detokenize / flush)
-with ``PROF.phase(...)`` context managers, and ``tools/profile_step.py
---json`` / ``bench.py --clients-sweep`` report ms-per-cycle per phase.
+``Span`` is the one way the engine loop times an interval: it opens a
+``jax.profiler.TraceAnnotation`` (so while a profiler capture runs the
+interval lands on the host plane of the SAME trace as the device's
+``XLA Ops``, with a start and an end on the profiler's clock) and adds
+its ``perf_counter`` seconds to the accumulators it was given.
+``PROF.phase(name)`` is a span that feeds ``PROF.seconds[name]``;
+devprof's ``sync(kind)`` / ``dispatch(kind, key)`` are spans named
+``sync.<kind>`` / ``dispatch.<kind>`` that also feed the per-engine
+device telemetry (runtime/devprof.py).  Nesting is by time on the one
+engine-loop thread, which is how the trace carries a span's parent.
 
-Disabled, ``phase()`` returns a shared no-op context manager — two
-attribute loads and a dict miss per use, no timestamps taken — so
-serving pays nothing for the instrumentation.  Enabled, each phase
-costs two ``perf_counter`` calls.  Since the flight recorder landed
-(runtime/flight.py) the profiler is ALWAYS-ON in practice: building an
-engine with the recorder enabled (the default) flips ``PROF.enabled``
-so every step record carries its phase breakdown; its cost on the chip
-is not measured on the current code, and ``TPUSERVE_FLIGHT=0`` restores
-the fully-off state.
-The profiler is engine-loop single-threaded like everything else it
-brackets; it is NOT meant to be shared across engines running in
-different threads (per-cycle deltas in multi-engine processes are
-approximate — see FlightRecorder.note_step).
+The span tree (where each opens -> what reads it):
+
+    runner.intake   server/runner.py _loop: _drain_intake   idle.host_loop_share
+    runner.route    _loop: _drain_engine_errors + _route_outputs      "
+    runner.gauges   _loop: _update_gauges                             "
+    engine.step     Engine.step, arg seq = the step record's seq; its
+                    self time and what lies between spans is
+                    idle.unattributed_share; seq joins
+                    kernel.decode_attn_ns_per_ctx_tok to ctx_tokens
+      slo.admission   _step_inner: deadline expiry, SLO pre-emption   idle.host_loop_share
+      kv.restore      _commit_tier_restores + _begin_tier_restores    idle.kv_demote_share
+      schedule        scheduler.schedule()            idle.host_loop_share, engine.cycle_host_ms
+      block           block-manager crossings (_bm_*, reserve)        "
+      kv.demote       _demote_evicted (gather + copy to the host)     idle.kv_demote_share
+        sync.demote     the blocking device_get of the gathered pages "
+      dispatch        input arrays + the _exec_* hook  idle.host_loop_share, engine.cycle_host_ms
+        dispatch.<kind> the async enqueue (first call: the compile)   idle.host_loop_share
+      sample          _sample: host-side logit edits + sampler        "
+      sync.<kind>     a designated device_get (window, decode, sample,
+                      verify, draft, guided)   idle.sync_share, engine.sync_wait_share
+      detokenize      append, detokenize, stop checks, emission
+                                                      idle.host_loop_share, engine.cycle_host_ms
+      step.close      note_step, SLO tick, note_control, block check  idle.host_loop_share
+
+``flush`` is not a span: it is the sum of the cycle's ``sync.*`` spans
+(every ``sync`` feeds it), kept because step records, ``profile_step``
+and the bench rows report it under that name.
+
+Cost: off (``TPUSERVE_FLIGHT=0`` and devprof off) every site gets the
+shared no-op context manager; on (the default — building an engine with
+the flight recorder flips ``PROF.enabled``) a span costs one
+``TraceAnnotation`` and two ``perf_counter`` calls, measured on the chip
+in PERF.md §6 (PR 24).  The profiler is engine-loop single-threaded like
+everything it brackets; per-cycle deltas in multi-engine processes are
+approximate (FlightRecorder.note_step).
 """
 
 from __future__ import annotations
 
+import contextlib
 import time
 from collections import defaultdict
 
+from jax.profiler import TraceAnnotation
 
-class _NoopPhase:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
+#: what every site gets while nothing listens: shared, reusable, free
+NOOP = contextlib.nullcontext()
 
 
-_NOOP = _NoopPhase()
+class Span:
+    """One timed interval of the engine loop (see the module docstring).
+    ``sinks`` are ``(seconds, counts, key)`` accumulator triples fed at
+    exit; ``done(dt)`` is devprof's ladder bookkeeping; ``args`` go into
+    the trace as the annotation's arguments."""
 
+    __slots__ = ("_ann", "_sinks", "_done", "_t0")
 
-class _Phase:
-    __slots__ = ("_prof", "_name", "_t0")
-
-    def __init__(self, prof, name):
-        self._prof = prof
-        self._name = name
+    def __init__(self, name, sinks, done=None, **args):
+        self._ann = TraceAnnotation(name, **args)
+        self._sinks = sinks
+        self._done = done
 
     def __enter__(self):
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._prof.seconds[self._name] += time.perf_counter() - self._t0
-        self._prof.counts[self._name] += 1
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        for seconds, counts, key in self._sinks:
+            seconds[key] += dt
+            counts[key] += 1
+        if self._done is not None:
+            self._done(dt)
         return False
 
 
 class HostPhaseProfiler:
-    """Accumulates wall seconds per named host phase; ``cycles`` is bumped
-    once per engine cycle (the denominator for ms-per-cycle)."""
+    """Accumulates wall seconds per span name; ``cycles`` is bumped once
+    per engine cycle (the denominator for ms-per-cycle)."""
 
     # canonical phase names, in report order
     PHASES = ("schedule", "block", "dispatch", "detokenize", "flush")
     # the phases that are PURE host time (dispatch covers array build +
-    # async dispatch; flush is the device->host sync, i.e. mostly device
+    # async dispatch; flush is the device->host syncs, i.e. mostly device
     # wait) — "host_ms_per_cycle" sums only these
     HOST_PHASES = ("schedule", "block", "detokenize")
 
@@ -76,10 +109,16 @@ class HostPhaseProfiler:
         self.counts: dict[str, int] = defaultdict(int)
         self.cycles = 0
 
-    def phase(self, name: str):
+    def sinks(self, *names) -> tuple:
+        """Accumulator triples for a span that feeds these names; empty
+        while disabled."""
         if not self.enabled:
-            return _NOOP
-        return _Phase(self, name)
+            return ()
+        return tuple((self.seconds, self.counts, n) for n in names)
+
+    def phase(self, name: str, **args):
+        sinks = self.sinks(name)
+        return Span(name, sinks, **args) if sinks else NOOP
 
     def bump_cycle(self) -> None:
         if self.enabled:
@@ -91,21 +130,18 @@ class HostPhaseProfiler:
         self.cycles = 0
 
     def report(self) -> dict:
-        """Per-phase breakdown: ms per engine cycle plus totals — the
+        """Per-span breakdown: ms per engine cycle plus totals — the
         machine-readable shape profile_step --json and the bench rows
-        emit (diffable across commits)."""
+        emit (diffable across commits).  Spans nest, so the rows do not
+        add up to a cycle."""
         cycles = max(self.cycles, 1)
-        phases = {}
-        for name in list(self.PHASES) + sorted(
-                set(self.seconds) - set(self.PHASES)):
-            if name not in self.seconds and name not in self.PHASES:
-                continue
-            phases[name] = {
-                "ms_per_cycle": round(1000 * self.seconds[name] / cycles, 4),
-                "total_ms": round(1000 * self.seconds[name], 2),
-                "calls": self.counts[name],
-            }
-        total = sum(self.seconds.values())
+        names = list(self.PHASES) + sorted(set(self.seconds)
+                                           - set(self.PHASES))
+        phases = {name: {
+            "ms_per_cycle": round(1000 * self.seconds[name] / cycles, 4),
+            "total_ms": round(1000 * self.seconds[name], 2),
+            "calls": self.counts[name],
+        } for name in names}
         host = sum(self.seconds[p] for p in self.HOST_PHASES
                    if p in self.seconds)
         return {
@@ -113,7 +149,6 @@ class HostPhaseProfiler:
             # schedule + block accounting + detokenize/emit — the phases
             # the native/batched host path migrated off per-request Python
             "host_ms_per_cycle": round(1000 * host / cycles, 4),
-            "all_phases_ms_per_cycle": round(1000 * total / cycles, 4),
             "phases": phases,
         }
 

@@ -10,6 +10,7 @@ here capacity is derived from the HBM budget).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -112,7 +113,8 @@ def _scatter_pages(cache, idx, pages):
             for li, layer in enumerate(cache)]
 
 
-def gather_block_pages(kv_cache: list[dict], blocks: list[int]) -> list[list[dict]]:
+def gather_block_pages(kv_cache: list[dict], blocks: list[int],
+                       sync=contextlib.nullcontext()) -> list[list[dict]]:
     """Copy the given physical blocks' KV pages to host numpy, returned
     per block: ``out[i]`` is a per-layer ``{key: (block_size, heads,
     head_dim) ndarray}`` list for ``blocks[i]`` — the value format the
@@ -123,6 +125,8 @@ def gather_block_pages(kv_cache: list[dict], blocks: list[int]) -> list[list[dic
     per-block one.  The sync is safe by construction — the engine drains
     evictions BEFORE dispatching the step that would overwrite these
     pages, so the read is ordered after every write that produced them.
+    ``sync`` brackets that blocking read (the engine passes
+    ``devprof.sync("demote")``, so the wait is counted as the sync it is).
 
     The block-count axis is padded to a power of two (repeating the last
     id; the extra gathers are discarded) so the jitted gather compiles a
@@ -133,7 +137,9 @@ def gather_block_pages(kv_cache: list[dict], blocks: list[int]) -> list[list[dic
     n = len(blocks)
     padded = list(blocks) + [blocks[-1]] * (next_power_of_2(n) - n)
     idx = jnp.asarray(padded, jnp.int32)
-    batched = jax.device_get(_gather_pages(kv_cache, idx))
+    gathered = _gather_pages(kv_cache, idx)
+    with sync:
+        batched = jax.device_get(gathered)
     return [[{k: v[i] for k, v in layer.items()} for layer in batched]
             for i in range(n)]
 

@@ -18,6 +18,7 @@ from typing import Optional, Sequence, Union
 
 from tpuserve.runtime.clock import MONOTONIC
 from tpuserve.runtime.engine import Engine
+from tpuserve.runtime.hostprof import PROF
 from tpuserve.runtime.request import RequestOutput, RequestState, SamplingParams
 from tpuserve.runtime.slo import ShedError
 
@@ -1145,7 +1146,8 @@ class AsyncEngineRunner:
     def _loop(self) -> None:
         logger.info("engine loop started")
         while not self._stop.is_set():
-            self._drain_intake()
+            with PROF.phase("runner.intake"):
+                self._drain_intake()
             if not self.engine.has_work():
                 self._maybe_swap_pool()
                 self._update_gauges()
@@ -1177,7 +1179,9 @@ class AsyncEngineRunner:
             if self._consume_hard_trip(seq):
                 continue
             self._note_salvage_progress()
-            self._drain_engine_errors()
-            self._route_outputs(outputs)
-            self._update_gauges()
+            with PROF.phase("runner.route"):
+                self._drain_engine_errors()
+                self._route_outputs(outputs)
+            with PROF.phase("runner.gauges"):
+                self._update_gauges()
         logger.info("engine loop stopped")

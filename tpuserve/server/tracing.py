@@ -172,12 +172,21 @@ def capture_profile(seconds: float, out_dir: str | None = None) -> dict:
     """Capture a jax.profiler device trace for ``seconds``.
 
     Returns {"trace_dir": path, "seconds": n}.  The directory holds the
-    TensorBoard-loadable profile (plugins/profile/...).
+    TensorBoard-loadable profile (plugins/profile/...): the device's
+    planes and, on the host plane, the engine loop's spans
+    (runtime/hostprof.py) on the same clock.  The Python tracer stays
+    off: the spans say what the loop was doing, and tracing every Python
+    call stalls the loop that is being traced (a fast-burn auto-capture
+    cost a loaded server a second-long stall and ~10 % of a 45 s
+    window's tokens, PERF.md PR 24).
     """
     import jax
     seconds = min(max(seconds, 0.1), 60.0)
     out_dir = out_dir or tempfile.mkdtemp(prefix="tpuserve-profile-")
-    jax.profiler.start_trace(out_dir)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(out_dir, profiler_options=opts)
     try:
         time.sleep(seconds)
     finally:
