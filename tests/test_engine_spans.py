@@ -4,9 +4,10 @@ A tiny engine runs prefill and fused-window cycles, some of which evict
 prefix blocks into the KV tier, under ``jax.profiler.start_trace`` with
 the options the benchmark traces with.  What is pinned: the spans are in
 the trace's host plane and join the step records by ``seq``; they come
-from the documented set and nest as documented; the demotion's copy is a
-``sync.demote`` inside ``kv.demote``; ``flush`` is the cycle's ``sync.*``
-time and nothing else; ``ctx_tokens`` is the context the dispatched rows
+from the documented set and nest as documented; a demotion is enqueued
+under ``kv.demote`` with no wait before the cycle's dispatch, and a wait
+for its copy, where there is one, is a ``sync.demote``; ``flush`` is the
+cycle's ``sync.*`` time and nothing else; ``ctx_tokens`` is the context the dispatched rows
 attend.  CPU run: control flow and counts, no device number."""
 
 import os
@@ -24,9 +25,9 @@ ROOT = "engine.step"
 # span name (or prefix, ending in ".") -> the spans it may open under
 TREE = {
     "slo.admission": {ROOT}, "kv.restore": {ROOT}, "schedule": {ROOT},
-    "block": {ROOT}, "kv.demote": {ROOT, "kv.restore"},
+    "block": {ROOT}, "kv.demote": {ROOT, "kv.restore", "sample"},
     "dispatch": {ROOT}, "dispatch.": {"dispatch", "sample"},
-    "sample": {ROOT}, "sync.demote": {"kv.demote"},
+    "sample": {ROOT}, "sync.demote": {"kv.demote", "kv.restore"},
     "sync.": {ROOT, "sample"}, "detokenize": {ROOT}, "step.close": {ROOT},
 }
 PARAMS = SamplingParams(max_tokens=9, temperature=0.0, ignore_eos=True)
@@ -66,7 +67,10 @@ def read_spans(trace_dir):
     spans = sorted(((e.start_ns, e.start_ns + e.duration_ns, e.name,
                      dict(e.stats)) for e in loop[0] if kind_of(e.name)),
                    key=lambda x: (x[0], -x[1]))
-    return spans, {e.name for e in loop[0]}
+    gathers = sorted((e.start_ns, e.start_ns + e.duration_ns)
+                     for e in loop[0]
+                     if e.name == "PjitFunction(_gather_pages)")
+    return spans, {e.name for e in loop[0]}, gathers
 
 
 @pytest.fixture(scope="module")
@@ -95,9 +99,9 @@ def traced(tmp_path_factory):
         jax.profiler.stop_trace()
     steps = {s["seq"]: s for s in eng.flight.steps_snapshot(limit=10_000)
              if s["seq"] > first}
-    spans, names = read_spans(trace_dir)
+    spans, names, gathers = read_spans(trace_dir)
     return {"spans": spans, "names": names, "steps": steps, "engine": eng,
-            "prompts": prompts}
+            "prompts": prompts, "gathers": gathers}
 
 
 def parents(spans):
@@ -135,19 +139,36 @@ def test_children_come_from_the_documented_set_and_nest(traced):
             f"{span[2]} leaves its parent {parent[2]}"
     assert {ROOT, "slo.admission", "kv.restore", "schedule", "block",
             "kv.demote", "dispatch", "dispatch.", "sample", "sync.",
-            "sync.demote", "detokenize", "step.close"} <= seen
+            "detokenize", "step.close"} <= seen
 
 
-def test_a_cycle_with_evictions_copies_under_sync_demote(traced):
+def test_a_cycle_with_evictions_dispatches_without_waiting_for_the_copy(
+        traced):
+    """``kv.demote`` brackets the enqueue (before the cycle's dispatch)
+    and the landing (before a blocking sync, or going idle); a wait for a
+    copy is a ``sync.demote``, and none lies between a gather's enqueue
+    and the dispatch that follows it in its cycle (a restore's gather is
+    followed by its scatter, which no ``dispatch`` span brackets)."""
     eng = traced["engine"]
     assert eng.stats.kv_demoted_blocks > 0
-    by_parent = parents(traced["spans"])
-    copies = [(s, p) for s, p in by_parent if s[2] == "sync.demote"]
-    assert copies and all(p[2] == "kv.demote" for _, p in copies)
-    assert eng.devprof.sync_counts["demote"] >= len(copies)
-    # most cycles evict nothing: their kv.demote span holds no sync
-    demotes = [s for s in traced["spans"] if s[2] == "kv.demote"]
-    assert len(demotes) > len(copies)
+    spans = traced["spans"]
+    waits = [s for s in spans if s[2] == "sync.demote"]
+    assert eng.devprof.sync_counts["demote"] >= len(waits)
+    launches = [s for s in spans if s[2] == "dispatch"]
+    roots = [s for s in spans if s[2] == ROOT]
+    followed = 0
+    for start, end in traced["gathers"]:
+        under = [s for s in spans if s[2] == "kv.demote"
+                 and s[0] <= start and end <= s[1]]
+        assert under, "a gather enqueued outside kv.demote"
+        root = next(r for r in roots if r[0] <= start and end <= r[1])
+        launch = min((s for s in launches if end <= s[0] < root[1]),
+                     key=lambda s: s[0], default=None)
+        if launch is not None:
+            followed += 1
+            assert not any(end <= w[0] < launch[0] for w in waits), \
+                "the loop waited for a copy between a gather and a dispatch"
+    assert followed > 2
 
 
 def test_the_programs_keep_the_names_the_benchmark_matches(traced):

@@ -398,6 +398,314 @@ def test_int8_pages_demote_smaller_at_real_head_widths():
 
 
 # ---------------------------------------------------------------------------
+# demotions in flight: the copy runs behind the chip's work
+# ---------------------------------------------------------------------------
+
+PAGE_DTYPES = ("bfloat16", "int8")
+
+
+def _dtype_engine(dtype, tp=1):
+    """A tiered engine whose cache pages hold a prompt's KV in ``dtype``
+    (int8 pages carry their ``ks``/``vs`` scale pages along); under
+    ``tp`` the pages are sharded over the kv-head axis."""
+    from tpuserve.parallel import MeshConfig, make_mesh
+    mesh = make_mesh(MeshConfig(dp=1, tp=tp)) if tp > 1 else None
+    eng = Engine(EngineConfig(
+        model="tiny-qwen3",
+        cache=CacheConfig(block_size=4, num_blocks=24, max_blocks_per_seq=16,
+                          dtype=dtype),
+        scheduler=SchedulerConfig(max_num_seqs=4, max_prefill_tokens=256,
+                                  min_prefill_bucket=8, min_decode_bucket=2),
+        enable_prefix_caching=True, kv_tiers=True), mesh=mesh)
+    eng.generate([SHARED + [30]], PARAMS)
+    return eng
+
+
+class _Gate:
+    """A ``fetch`` for ``put_async`` that holds the copy back until it is
+    opened: the batch stays in flight for as long as the test wants."""
+
+    def __init__(self, gathered):
+        import threading
+        self.gathered = gathered
+        self.open = threading.Event()
+
+    def __call__(self):
+        from tpuserve.runtime.kv_cache import fetch_block_pages
+        assert self.open.wait(30), "the gate was never opened"
+        return fetch_block_pages(self.gathered)
+
+    def open_soon(self, seconds=0.05):
+        import threading
+        threading.Timer(seconds, self.open.set).start()
+
+
+def _start(eng, blocks, hashes, opened=False):
+    """Demote ``blocks`` under ``hashes`` behind a gate ("a byte a block")."""
+    from tpuserve.runtime.kv_cache import enqueue_block_pages_gather
+    gate = _Gate(enqueue_block_pages_gather(eng.kv_cache, blocks))
+    if opened:
+        gate.open.set()
+    eng._kv_tiers.put_async(hashes, gate, nbytes=len(blocks))
+    return gate
+
+
+def _same_pages(got, want):
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert set(g) == set(w)
+        for k in w:
+            assert g[k].dtype == w[k].dtype and g[k].shape == w[k].shape
+            np.testing.assert_array_equal(np.asarray(g[k], np.float32),
+                                          np.asarray(w[k], np.float32))
+
+
+@pytest.mark.parametrize("dtype,tp", [(d, 1) for d in PAGE_DTYPES]
+                         + [("bfloat16", 2)])
+def test_in_flight_hash_is_resolvable_and_takes_the_gathered_pages(dtype, tp):
+    from tpuserve.runtime.kv_cache import gather_block_pages
+    eng = _dtype_engine(dtype, tp)
+    store, blocks, hashes = eng._kv_tiers, [1, 2, 3], [901, 902, 903]
+    want = gather_block_pages(eng.kv_cache, blocks)
+    # a landed page is the whole block, whatever the device sharding
+    assert want[0][0]["k"].shape == eng.kv_cache[0]["k"].shape[1:]
+    assert eng._kv_block_bytes * tp == sum(
+        a.nbytes for layer in want[0] for a in layer.values())
+    assert any(np.asarray(a, np.float32).any() for a in want[0][0].values())
+    if dtype == "int8":
+        assert {"ks", "vs"} <= set(want[0][0])
+    gate = _start(eng, blocks, hashes)
+    for h in hashes:
+        assert store.has(h) and store.where(h) == "host"
+    assert set(hashes) <= set(store.hashes())
+    assert len(store) == store.in_flight_count == 3
+    assert store.host_count == 0 and store.host_bytes_used == 0
+    gate.open_soon()
+    _same_pages(store.take(902), want[1])      # waits for that one copy
+    assert store.waited_blocks == 1 and not store.has(902)
+    assert store.in_flight_count == 2
+    store.land()                               # the copy is done: no wait
+    assert store.in_flight_batches == 0 and store.host_count == 2
+    assert store.waited_blocks == 1
+    _same_pages(store.take(901), want[0])
+    _same_pages(store.take(903), want[2])
+    assert len(store) == 0 and store.host_bytes_used == 0
+
+
+@pytest.mark.parametrize("dtype", PAGE_DTYPES)
+def test_dropped_in_flight_hash_is_never_filed(dtype):
+    eng = _dtype_engine(dtype)
+    store = eng._kv_tiers
+    store.put(900, _pages())
+    used, dropped = store.host_bytes_used, store.dropped_blocks
+    gate = _start(eng, [1, 2], [901, 902])
+    store.drop(901)             # superseded while its copy is in flight
+    store.drop(902)
+    assert not store.has(901) and len(store) == 1
+    gate.open.set()
+    store.land(wait=True)
+    assert store.in_flight_batches == 0
+    assert not store.has(901) and not store.has(902)
+    assert store.host_count == 1 and store.host_bytes_used == used
+    assert store.dropped_blocks == dropped     # superseded is not lost
+
+
+@pytest.mark.parametrize("dtype", PAGE_DTYPES)
+def test_landed_pages_are_the_kv_from_before_the_next_dispatch(dtype):
+    """The gather reads the evicted pages in device order: a dispatch
+    enqueued after it (here a scatter that donates the cache and writes
+    other pages over the same blocks) cannot change what lands."""
+    from tpuserve.runtime.kv_cache import (gather_block_pages,
+                                           scatter_block_pages)
+    eng = _dtype_engine(dtype)
+    store, blocks = eng._kv_tiers, [1, 2]
+    before = gather_block_pages(eng.kv_cache, blocks)
+    other = gather_block_pages(eng.kv_cache, [0, 0])    # block 0: no KV
+    gate = _start(eng, blocks, [901, 902])
+    eng.kv_cache = scatter_block_pages(eng.kv_cache, blocks, other)
+    now = gather_block_pages(eng.kv_cache, blocks)
+    _same_pages(now[0], other[0])               # the overwrite happened
+    assert any((np.asarray(now[0][0][k], np.float32)
+                != np.asarray(before[0][0][k], np.float32)).any()
+               for k in before[0][0])
+    gate.open.set()
+    store.land(wait=True)
+    _same_pages(store.take(901), before[0])
+    _same_pages(store.take(902), before[1])
+
+
+def test_third_batch_waits_for_the_oldest_and_is_counted():
+    from tpuserve.runtime.kv_tiers import MAX_IN_FLIGHT
+    eng = _dtype_engine("bfloat16")
+    store = eng._kv_tiers
+    assert MAX_IN_FLIGHT == 2
+    first = _start(eng, [1, 2, 3], [901, 902, 903])
+    store.reserve(1)                    # a second batch: nobody waits
+    second = _start(eng, [4], [904])
+    assert store.in_flight_batches == 2 and store.waited_blocks == 0
+    second.open.set()
+    first.open_soon()
+    store.reserve(1)                    # a third batch: the oldest lands
+    assert store.waited_blocks == 3
+    assert store.host_count == 3 and store.where(904) == "host"
+    # the device budget bounds the bytes in flight the same way
+    store.land(wait=True)
+    store.device_budget_bytes = 4
+    third = _start(eng, [5, 6, 7], [905, 906, 907])      # "3 bytes"
+    third.open_soon()
+    store.reserve(2)                    # 3 + 2 > 4: it gives way
+    assert store.in_flight_batches == 0 and store.waited_blocks == 6
+    # and a batch the budget cannot hold at all is copied out at once
+    _start(eng, [8, 9, 10, 11, 12], [908 + i for i in range(5)], opened=True)
+    assert store.in_flight_batches == 0 and store.host_count == 12
+
+
+def test_a_plain_run_waits_for_no_copy(monkeypatch):
+    """Evictions cycle after cycle, each prompt new (so no restore takes a
+    hash whose copy is still running): every copy is filed behind a later
+    dispatch or when the loop goes idle, and none is waited for."""
+    monkeypatch.setenv("TPUSERVE_STRICT_BLOCKS", "1")
+    eng = _mk_engine(True)
+    eng.generate([SHARED + [30 + i] for i in range(2)], PARAMS)
+    for r in range(3):
+        eng.generate([[100 + 10 * r + i] * 40 for i in range(3)], PARAMS)
+    assert eng.stats.kv_demoted_blocks > 8 and eng.stats.kv_restores == 0
+    assert eng.stats.kv_demote_waited_blocks == 0
+    # no work left means nothing in flight: every demoted block is filed
+    assert not eng.has_work() and eng._kv_tiers.in_flight_batches == 0
+
+
+def test_a_batch_the_device_cannot_hold_is_copied_out_before_the_dispatch(
+        monkeypatch):
+    """The device budget is what no dispatch has touched (limit - peak,
+    read once); a gathered batch over it must not ride through the next
+    dispatch: the loop waits for its copy first, as it always used to."""
+    import jax
+    monkeypatch.setenv("TPUSERVE_STRICT_BLOCKS", "1")
+    eng = _mk_engine(True)
+    store = eng._kv_tiers
+    assert store.device_budget_bytes == float("inf")    # CPU: no statistics
+
+    class Device:
+        def memory_stats(self):
+            return {"bytes_limit": 1000, "peak_bytes_in_use": 990,
+                    "bytes_in_use": 500}
+    monkeypatch.setattr(jax, "local_devices", lambda: [Device()])
+    eng._read_demote_budget()
+    assert store.device_budget_bytes == 10 < eng._kv_block_bytes
+    left = []
+    demote = eng._demote_evicted
+    monkeypatch.setattr(eng, "_demote_evicted", lambda: (
+        demote(), left.append(store.in_flight_batches))[0])
+    eng.generate([SHARED + [30 + i] for i in range(2)], PARAMS)
+    _churn(eng)
+    assert eng.stats.kv_demoted_blocks > 0 and set(left) == {0}
+    tiered = eng.generate([SHARED + [77]], PARAMS)[0]
+    cold = _mk_engine(False).generate([SHARED + [77]], PARAMS)[0]
+    assert tiered.output_token_ids == cold.output_token_ids
+
+
+def test_no_wait_between_the_gather_and_the_cycles_dispatch(monkeypatch):
+    """Between ``_gather_pages`` and what the cycle dispatches next (its
+    ``_exec_*``; for a restore, its scatter) the loop opens no ``sync.*``
+    span and calls no ``device_get``: the demotion is dispatch-only."""
+    import threading
+
+    import jax
+
+    from tpuserve.runtime import kv_cache
+    monkeypatch.setenv("TPUSERVE_STRICT_BLOCKS", "1")
+    eng = _mk_engine(True)
+    loop, log = threading.get_ident(), []
+
+    def spy(obj, name, tag):
+        real = getattr(obj, name)
+
+        def wrapped(*a, **k):
+            if threading.get_ident() == loop:
+                log.append(tag if isinstance(tag, str) else tag(*a))
+            return real(*a, **k)
+        monkeypatch.setattr(obj, name, wrapped)
+
+    spy(kv_cache, "_gather_pages", "gather")
+    spy(kv_cache, "_scatter_pages", "dispatch")
+    spy(jax, "device_get", "device_get")
+    spy(eng.devprof, "sync", lambda kind: "sync." + kind)
+    for name in dir(eng):
+        if name.startswith("_exec_"):
+            spy(eng, name, "dispatch")
+    eng.generate([SHARED + [30 + i] for i in range(2)], PARAMS)
+    _churn(eng)
+    eng.generate([SHARED + [77]], PARAMS)       # restores, and demotes
+    _churn(eng)
+    after = [log[i + 1] for i, tag in enumerate(log[:-1]) if tag == "gather"]
+    assert len(after) > 4 and eng.stats.kv_restores >= 1
+    assert set(after) == {"dispatch"}, after
+
+
+@pytest.mark.parametrize("copy", ["lands", "fails"])
+def test_loop_shutdown_files_or_counts_what_is_in_flight(copy):
+    """The engine loop's last act: a demotion whose copy is still running
+    is filed, one whose copy failed is counted as dropped; neither is
+    lost without a trace."""
+    from tpuserve.server.runner import AsyncEngineRunner
+    eng = _dtype_engine("bfloat16")
+    store = eng._kv_tiers
+    runner = AsyncEngineRunner(eng)
+    if copy == "fails":
+        def broken():
+            raise RuntimeError("the copy failed")
+        store.put_async([901, 902, 903], broken, nbytes=3)
+    else:
+        _start(eng, [1, 2, 3], [901, 902, 903]).open_soon()
+    dropped = store.dropped_blocks
+    runner._stop.set()
+    runner._loop()                      # no cycle runs; the loop winds down
+    assert store.in_flight_batches == 0
+    if copy == "lands":
+        assert store.host_count == 3 and store.dropped_blocks == dropped
+    else:
+        assert len(store) == 0 and store.dropped_blocks == dropped + 3
+    assert store.waited_blocks == 0     # nothing was waiting behind it
+
+
+def test_store_clear_counts_what_was_in_flight():
+    eng = _dtype_engine("bfloat16")
+    store = eng._kv_tiers
+    gate = _start(eng, [1, 2], [901, 902])
+    store.clear()
+    gate.open.set()
+    assert len(store) == 0 and store.in_flight_batches == 0
+    assert store.dropped_blocks == 2
+
+
+def test_demotion_runs_the_executables_the_blocking_gather_warms(monkeypatch):
+    """The benchmark (and ``Engine.warmup``) warm the demotion's ladder by
+    calling ``gather_block_pages(kv_cache, [0] * n)``: the engine's
+    dispatch-only demotion must hit those very executables (the same
+    jitted function at the same padded shapes), or the gather compiles
+    inside a measured window."""
+    from tpuserve.runtime import kv_cache
+    monkeypatch.setenv("TPUSERVE_STRICT_BLOCKS", "1")
+    eng = _mk_engine(True)
+    shapes = []
+    real = kv_cache._gather_pages
+    monkeypatch.setattr(
+        kv_cache, "_gather_pages",
+        lambda cache, idx: (shapes.append(int(idx.shape[0])),
+                            real(cache, idx))[1])
+    for n in (1, 2, 4, 8, 16, 32):
+        kv_cache.gather_block_pages(eng.kv_cache, [0] * n)
+    warmed, compiled = set(shapes), real._cache_size()
+    del shapes[:]
+    eng.generate([SHARED + [30 + i] for i in range(2)], PARAMS)
+    _churn(eng)
+    assert eng.stats.kv_demoted_blocks > 0 and shapes
+    assert set(shapes) <= warmed
+    assert real._cache_size() == compiled, "a demotion compiled a new gather"
+
+
+# ---------------------------------------------------------------------------
 # cache-aware routing digest
 # ---------------------------------------------------------------------------
 

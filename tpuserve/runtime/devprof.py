@@ -12,8 +12,9 @@ the capability/cost signals heterogeneous routing wants (arxiv
 
 - **device-time attribution**: the engine brackets its EXISTING
   designated sync points (window flush, pending flush, sample read,
-  spec verify, draft proposal, guided top-k, and the KV tier's copy of
-  evicted pages to the host, ``demote``) with ``sync(kind)`` — the
+  spec verify, draft proposal, guided top-k, and a wait for the KV
+  tier's copy of evicted pages to the host, ``demote``) with
+  ``sync(kind)`` — the
   host seconds blocked in a ``device_get`` are the device time the
   pipelined design successfully hid everywhere else, split per sync
   kind.  Dispatch brackets (``dispatch(kind, key)``) time the ASYNC

@@ -279,7 +279,12 @@ class EngineStats:
     # begin->commit wall times of recent restores (drained into the
     # tpuserve_kv_restore_latency_seconds histogram by server/runner.py;
     # bounded so a runner-less engine can't grow it without bound).
+    # kv_demote_waited_blocks: demoted blocks whose device-to-host copy
+    # the loop had to wait for (the in-flight bound, or a restore of a
+    # hash still in flight); 1 - waited/demoted is the share of demotion
+    # copies that ran wholly behind the chip's work.
     kv_demoted_blocks: int = 0
+    kv_demote_waited_blocks: int = 0
     kv_spilled_blocks: int = 0
     kv_tier_dropped_blocks: int = 0
     kv_restored_blocks: int = 0
@@ -523,8 +528,18 @@ class Engine:
                 _os_t.environ.get("TPUSERVE_KV_HOST_BYTES", 0) or (1 << 30))
             spill = (config.kv_spill_dir
                      or _os_t.environ.get("TPUSERVE_KV_SPILL_DIR") or None)
-            self._kv_tiers = TieredPageStore(host_bytes, spill_dir=spill)
+            self._kv_tiers = TieredPageStore(
+                host_bytes, spill_dir=spill,
+                sync=lambda: self.devprof.sync("demote"))
             self.block_manager.record_evictions = True
+            # bytes one block's pages take on one device (under tp the
+            # kv-head axis is sharded, the block axis is not)
+            self._kv_block_bytes = sum(
+                leaf.addressable_shards[0].data.nbytes
+                for leaf in jax.tree.leaves(self.kv_cache)
+            ) // self.cache_cfg.num_blocks
+        # whether the store's device budget was read (_read_demote_budget)
+        self._demote_budget_read = False
         sched_cfg = config.scheduler
         if sched_cfg.mixed_batching and (self._pp > 1
                                          or jax.process_count() > 1):
@@ -1466,9 +1481,13 @@ class Engine:
         # _restores counts as work: an in-flight tier restore must reach
         # its commit step even if every request was aborted meanwhile, or
         # its blocks would sit in the restore-in-flight set forever
+        # so does a demotion in flight: the idle cycle lands it, which
+        # keeps "no work" meaning "every demoted block is filed"
         return (self.scheduler.has_work() or self._pending is not None
                 or self._pending_window is not None
-                or bool(self._restores))
+                or bool(self._restores)
+                or (self._kv_tiers is not None
+                    and self._kv_tiers.in_flight_batches > 0))
 
     # ------------------------------------------------------------------
     # Step
@@ -1557,7 +1576,10 @@ class Engine:
             batch = self.scheduler.schedule()
         if batch is None:
             # nothing schedulable but a decode result may still be in flight
-            return pre + self._flush_pending() + self._flush_window()
+            outputs = pre + self._flush_pending() + self._flush_window()
+            # nor anything left for a demotion's copy to hide behind
+            self._land_demotions(wait=True)
+            return outputs
         t0 = self.clock.monotonic()
         if batch.kind == "prefill":
             outputs = self._run_prefill(batch)
@@ -1629,8 +1651,13 @@ class Engine:
         dispatch that could overwrite those pages (every _run_* path
         calls this right before its _exec_*; adopt_prefilled before its
         KV scatter): until that dispatch executes, the pages still hold
-        the evicted prefix's KV, so one fused gather + one device_get
-        moves the whole cycle's evictions host-side."""
+        the evicted prefix's KV, so one fused gather enqueued here reads
+        the whole cycle's evictions.  Dispatch-only, like the restore:
+        the gather's output is a fresh buffer, the store's copier thread
+        copies it to the host while the chip does what the caller
+        dispatches next, and the loop files the pages later
+        (_land_demotions); the hashes resolve in the store from here
+        on."""
         store = self._kv_tiers
         if store is None:
             return
@@ -1645,14 +1672,64 @@ class Engine:
                   if not self.block_manager.prefix_resolvable(h)]
             if not ev:
                 return
-            from tpuserve.runtime.kv_cache import gather_block_pages
-            pages = gather_block_pages(self.kv_cache, [b for b, _ in ev],
-                                       sync=self.devprof.sync("demote"))
-            for (_b, h), p in zip(ev, pages):
-                store.put(h, p)
+            from tpuserve.runtime.kv_cache import (
+                enqueue_block_pages_gather, fetch_block_pages)
+            if not self._demote_budget_read:
+                self._read_demote_budget()
+            # older batches give way BEFORE the gather takes its buffer
+            nbytes = next_power_of_2(len(ev)) * self._kv_block_bytes
+            store.reserve(nbytes)
+            gathered = enqueue_block_pages_gather(self.kv_cache,
+                                                  [b for b, _ in ev])
+            store.put_async([h for _, h in ev],
+                            lambda: fetch_block_pages(gathered), nbytes)
             self.stats.kv_demoted_blocks += len(ev)
-            self.stats.kv_spilled_blocks = store.spilled_blocks
-            self.stats.kv_tier_dropped_blocks = store.dropped_blocks
+            self._mirror_tier_counters()
+
+    def _land_demotions(self, wait: bool = False) -> None:
+        """File the demotions whose copy has landed (all of them, waiting,
+        with ``wait``).  Called where the chip has work queued behind the
+        gather — before each blocking sync (_sync) — so the host's filing
+        hides behind it, and when the loop goes idle or stops."""
+        store = self._kv_tiers
+        if store is None or not store.in_flight_batches:
+            return
+        with PROF.phase("kv.demote"):
+            store.land(wait)
+            self._mirror_tier_counters()
+
+    def _mirror_tier_counters(self) -> None:
+        store = self._kv_tiers
+        self.stats.kv_spilled_blocks = store.spilled_blocks
+        self.stats.kv_tier_dropped_blocks = store.dropped_blocks
+        self.stats.kv_demote_waited_blocks = store.waited_blocks
+
+    def _read_demote_budget(self) -> None:
+        """Device memory the gathered demotion batches may hold while a
+        dispatch runs: what the allocator has never handed out
+        (``bytes_limit - peak_bytes_in_use``), so a batch in flight plus
+        the largest workspace seen so far still fit.  Read when warm-up
+        ends (every dispatch shape has then run) or, in an engine never
+        warmed, at the first demotion; no limit where the backend keeps
+        no memory statistics (the CPU).  A batch over it is copied out
+        before the next dispatch (store.put_async): the in-flight bound
+        gives way, never the cache size."""
+        stats = jax.local_devices()[0].memory_stats() or {}
+        if self._kv_tiers is not None and "peak_bytes_in_use" in stats:
+            self._kv_tiers.device_budget_bytes = max(
+                0, stats["bytes_limit"] - stats["peak_bytes_in_use"])
+            logger.info("KV demotion batches in flight may hold %d B of "
+                        "device memory (limit %d, peak so far %d)",
+                        self._kv_tiers.device_budget_bytes,
+                        stats["bytes_limit"], stats["peak_bytes_in_use"])
+        self._demote_budget_read = True
+
+    def _sync(self, kind: str):
+        """The span for one designated blocking ``device_get``.  What the
+        host is about to wait for has this cycle's dispatch queued with
+        or behind it, so landed demotions are filed first."""
+        self._land_demotions()
+        return self.devprof.sync(kind)
 
     def _drop_superseded_tier_entries(self, ids: list[int]) -> None:
         """Called right after a first allocate: the request's prefill is
@@ -1725,7 +1802,7 @@ class Engine:
                 blocks, span = blocks[:len(pages)], span[:len(pages)]
                 # the unreadable entry was dropped as LOST KV — surface
                 # the store's counter without waiting for the next demote
-                self.stats.kv_tier_dropped_blocks = store.dropped_blocks
+                self._mirror_tier_counters()
             if not blocks:
                 continue
             # claiming restore blocks can itself evict cold cached blocks
@@ -2626,12 +2703,12 @@ class Engine:
         # exactly what the salvage path expects to find.
         self.faults.check("window_flush",
                           tuple(r.request_id for r in p.reqs))
-        with self.devprof.sync("window"):
+        with self._sync("window"):
             # tpulint: sync-ok(THE designated sync: one device_get per S-token window is the whole fused-window design)
             toks_h = np.asarray(jax.device_get(p.toks))
         lp_h = None
         if p.lp is not None:
-            with self.devprof.sync("window"):
+            with self._sync("window"):
                 # tpulint: sync-ok(rides the same window-flush sync point; logprob arrays resolve with the tokens)
                 lp_h = tuple(np.asarray(x) for x in jax.device_get(p.lp))
         outputs: list[RequestOutput] = []
@@ -2978,7 +3055,7 @@ class Engine:
                         jnp.asarray(temperature), jnp.asarray(top_k),
                         jnp.asarray(top_p), jnp.asarray(min_p))
             # ONE round trip for both arrays
-            with self.devprof.sync("verify"):
+            with self._sync("verify"):
                 accept_h, pred_h = (
                     np.asarray(x) for x in
                     # tpulint: sync-ok(spec verify is synchronous by design: accept/pred decide host-side emission this step)
@@ -2989,7 +3066,7 @@ class Engine:
                     jnp.asarray(tokens), jnp.asarray(ctx_lens),
                     jnp.asarray(chunk_lens), jnp.asarray(slot_ids),
                     jnp.asarray(block_tables))
-            with self.devprof.sync("verify"):
+            with self._sync("verify"):
                 # tpulint: sync-ok(greedy spec verify twin of the sampled sync above)
                 pred_h = np.asarray(jax.device_get(pred))
         self.stats.num_decode_steps += 1
@@ -3036,7 +3113,7 @@ class Engine:
                                              jnp.asarray(lens), k=k)
         # designated sync: draft proposals feed the verify batch built
         # host-side this same step (the spec path is synchronous)
-        with self.devprof.sync("draft"):
+        with self._sync("draft"):
             out = np.asarray(out_d)
         return [[int(t) for t in out[i]] for i in range(len(reqs))]
 
@@ -3071,7 +3148,7 @@ class Engine:
         p, self._pending = self._pending, None
         if p is None:
             return []
-        with self.devprof.sync("decode"):
+        with self._sync("decode"):
             # tpulint: sync-ok(the single-step pipeline's designated sync: resolves the PREVIOUS step while the next runs)
             toks = np.asarray(jax.device_get(p.toks))
         reqs, vals = [], []
@@ -3112,7 +3189,7 @@ class Engine:
         toks = self._sample_modes(logits, reqs, B, frozenset())
         if any(r.params.logprobs is not None for r in reqs):
             self._record_logprobs(logits, toks, reqs)
-        with self.devprof.sync("sample"):
+        with self._sync("sample"):
             # tpulint: sync-ok(the synchronous per-step path's one sync; the pipelined paths never call _sample)
             toks_np = np.asarray(jax.device_get(toks))[:n].copy()
         if any(r.request_id in self._guided for r in reqs):
@@ -3294,7 +3371,7 @@ class Engine:
         is written by the NEXT dispatch."""
         k = min(self.GUIDED_TOP_K, self.model_cfg.vocab_size)
         _, top_ids = jax.lax.top_k(logits, k)
-        with self.devprof.sync("guided"):
+        with self._sync("guided"):
             # tpulint: sync-ok(legacy guided substitution is host-side by design; FSM-compilable grammars stay on device)
             ids_h = np.asarray(jax.device_get(top_ids))
         for i, r in enumerate(reqs):
@@ -4218,6 +4295,7 @@ class Engine:
         # on a chain of its own.
         jax.block_until_ready((self.kv_cache, self._warm_tails))
         self._warm_tails.clear()
+        self._read_demote_budget()
         logger.info("warmup complete: prefill buckets %s, decode buckets %s",
                     prefill_buckets, decode_buckets)
 

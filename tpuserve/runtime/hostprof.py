@@ -27,8 +27,14 @@ The span tree (where each opens -> what reads it):
       kv.restore      _commit_tier_restores + _begin_tier_restores    idle.kv_demote_share
       schedule        scheduler.schedule()            idle.host_loop_share, engine.cycle_host_ms
       block           block-manager crossings (_bm_*, reserve)        "
-      kv.demote       _demote_evicted (gather + copy to the host)     idle.kv_demote_share
-        sync.demote     the blocking device_get of the gathered pages "
+      kv.demote       _demote_evicted: the evicted pages' gather, enqueued
+                      before the cycle's dispatch and handed to the tier
+                      store's copier thread; _land_demotions: the copied pages
+                      filed in the tier store, before a blocking sync
+                      (also under sample) or going idle       idle.kv_demote_share
+        sync.demote     only a WAIT for such a copy: the in-flight bound,
+                        a restore (kv.restore) taking a hash in flight,
+                        going idle                            ", engine.sync_wait_share
       dispatch        input arrays + the _exec_* hook  idle.host_loop_share, engine.cycle_host_ms
         dispatch.<kind> the async enqueue (first call: the compile)   idle.host_loop_share
       sample          _sample: host-side logit edits + sampler        "

@@ -10,7 +10,6 @@ here capacity is derived from the HBM budget).
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -113,35 +112,61 @@ def _scatter_pages(cache, idx, pages):
             for li, layer in enumerate(cache)]
 
 
-def gather_block_pages(kv_cache: list[dict], blocks: list[int],
-                       sync=contextlib.nullcontext()) -> list[list[dict]]:
-    """Copy the given physical blocks' KV pages to host numpy, returned
-    per block: ``out[i]`` is a per-layer ``{key: (block_size, heads,
-    head_dim) ndarray}`` list for ``blocks[i]`` — the value format the
-    tier store (kv_tiers.TieredPageStore) files.
-
-    ONE gather dispatch + ONE device_get for the whole batch, however
-    many blocks evicted this cycle: demotion is a per-cycle cost, not a
-    per-block one.  The sync is safe by construction — the engine drains
-    evictions BEFORE dispatching the step that would overwrite these
-    pages, so the read is ordered after every write that produced them.
-    ``sync`` brackets that blocking read (the engine passes
-    ``devprof.sync("demote")``, so the wait is counted as the sync it is).
+def enqueue_block_pages_gather(kv_cache: list[dict],
+                               blocks: list[int]) -> list[dict]:
+    """Enqueue ONE fused gather of the given physical blocks' pages and
+    return its device arrays without waiting: per layer ``{key: (padded
+    blocks, block_size, heads, head_dim)}``, row ``i`` is ``blocks[i]``.
+    Dispatch-only, like the scatter: the gather's output is a fresh
+    buffer, ordered after every write that produced the pages and before
+    any later-dispatched step that overwrites them, so the caller may
+    dispatch that step at once and leave the copy to the host to
+    ``fetch_block_pages`` on another thread.  (Not ``copy_to_host_async``
+    here: on the chip it costs the calling thread 20-94 ms for 235-470 MB
+    and holds up the enqueues that follow; PERF.md, PR 25.)
 
     The block-count axis is padded to a power of two (repeating the last
-    id; the extra gathers are discarded) so the jitted gather compiles a
+    id; the extra rows are discarded) so the jitted gather compiles a
     log-sized executable ladder instead of one per distinct eviction
     count.
     """
     from tpuserve.utils import next_power_of_2
     n = len(blocks)
     padded = list(blocks) + [blocks[-1]] * (next_power_of_2(n) - n)
-    idx = jnp.asarray(padded, jnp.int32)
-    gathered = _gather_pages(kv_cache, idx)
-    with sync:
-        batched = jax.device_get(gathered)
+    return _gather_pages(kv_cache, jnp.asarray(padded, jnp.int32))
+
+
+def fetch_block_pages(gathered: list[dict]) -> list[dict]:
+    """Copy a gathered batch (``enqueue_block_pages_gather``) to host
+    numpy, blocking; the tier store's copier thread runs this.  Leaf by
+    leaf, each leaf's copy started while the one before it is awaited:
+    starting all of them at once (``jax.device_get`` of the whole batch)
+    sets up hundreds of MB of host staging in one go, and the runtime
+    holds up the engine thread's enqueues and host-to-device copies
+    meanwhile (28 ms stalls behind a 470 MB batch; PERF.md, PR 25)."""
+    import numpy as np
+    leaves, treedef = jax.tree.flatten(gathered)
+    if leaves:
+        leaves[0].copy_to_host_async()
+    out = []
+    for leaf, ahead in zip(leaves, leaves[1:] + [None]):
+        if ahead is not None:
+            ahead.copy_to_host_async()
+        out.append(np.asarray(leaf))
+    return jax.tree.unflatten(treedef, out)
+
+
+def gather_block_pages(kv_cache: list[dict],
+                       blocks: list[int]) -> list[list[dict]]:
+    """Copy the given physical blocks' KV pages to host numpy, returned
+    per block: ``out[i]`` is a per-layer ``{key: (block_size, heads,
+    head_dim) ndarray}`` list for ``blocks[i]`` — the value format the
+    tier store (kv_tiers.TieredPageStore) files.  The blocking form of
+    ``enqueue_block_pages_gather`` (same gather executable): warm-up and
+    tests; the engine's demotion never waits here."""
+    batched = fetch_block_pages(enqueue_block_pages_gather(kv_cache, blocks))
     return [[{k: v[i] for k, v in layer.items()} for layer in batched]
-            for i in range(n)]
+            for i in range(len(blocks))]
 
 
 def scatter_block_pages(kv_cache: list[dict], blocks: list[int],

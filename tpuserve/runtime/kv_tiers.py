@@ -12,10 +12,14 @@ Cache Offloading", arxiv 2504.11816 — PAPERS.md).
 This module is the demotion target: a chain-hash-keyed store of KV block
 pages with two tiers under HBM —
 
-- tier ``host``: pinned-host numpy pages under a byte budget
-  (``jax.device_get`` of the evicted block BEFORE its device page is
-  overwritten; int8 KV pages stay half-size because the dtype rides
-  through the copy);
+- tier ``host``: pinned-host numpy pages under a byte budget (a gather
+  of the evicted blocks enqueued BEFORE the dispatch that overwrites
+  their device pages; int8 KV pages stay half-size because the dtype
+  rides through the copy).  The device-to-host copy of that gather runs
+  while the chip works: a demotion is IN FLIGHT from ``put_async`` until
+  the engine loop ``land``s it, and counts as tier ``host`` from the
+  first moment (``has``/``where``/``hashes``/``len``/``take``/``drop``
+  all see it), so a lookup hits exactly as if the copy had blocked;
 - tier ``spill``: ``.npz`` files on a directory (the model PVC in-cluster
   — provision/manifests.py mounts it), absorbing host-budget overflow.
   Spill WRITES run on a background thread (the engine loop must never
@@ -34,18 +38,23 @@ as its pages are scattered back into HBM.  The ``TPUSERVE_STRICT_BLOCKS``
 integrity checker cross-checks this invariant every engine cycle
 (engine._check_block_integrity).
 
-Writers: the engine loop (put/take/drop) and the spill-writer thread
-(pending -> file transitions); shared maps are guarded by one lock held
-only for dict surgery, never for file I/O.
+Writers: the engine loop (put/put_async/land/take/drop) and the spill-
+writer thread (pending -> file transitions); shared maps are guarded by
+one lock held only for dict surgery, never for file I/O.  The copier
+thread runs the ``fetch`` it was handed (the wait for one batch's
+device-to-host copy) and touches no map: what is in flight, and the
+host tier it lands in, stay engine-loop only.
 """
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import queue
 import threading
-from collections import OrderedDict
+from collections import OrderedDict, deque
+from concurrent.futures import Future, ThreadPoolExecutor
 
 import numpy as np
 
@@ -57,10 +66,22 @@ logger = logging.getLogger("tpuserve.kv_tiers")
 # init-rescan time too, so crashed pods can't accumulate files forever.
 DEFAULT_MAX_SPILL_ENTRIES = 1 << 16
 
+# Demotion batches whose device-to-host copy may be in flight at once:
+# the one being gathered plus one older.  A gathered batch holds its
+# device buffer until its copy is done, so this (with
+# ``device_budget_bytes``) is what bounds the HBM the tier borrows; a
+# third batch waits for the oldest.
+MAX_IN_FLIGHT = 2
+
 
 def pages_nbytes(pages: list[dict]) -> int:
     """Host bytes one block's per-layer page dict consumes."""
     return sum(int(a.nbytes) for layer in pages for a in layer.values())
+
+
+def _row_pages(layers: list[dict], i: int) -> list[dict]:
+    """Block ``i``'s pages (views) out of a gathered batch's arrays."""
+    return [{k: v[i] for k, v in layer.items()} for layer in layers]
 
 
 def _np_dtype(name: str) -> np.dtype:
@@ -82,6 +103,17 @@ def _encode_npz(a: np.ndarray) -> tuple[np.ndarray, str]:
     return np.ascontiguousarray(a).view(np.uint8), str(a.dtype)
 
 
+class _InFlight:
+    """One demotion batch between ``put_async`` and its landing."""
+
+    __slots__ = ("rows", "future", "nbytes")
+
+    def __init__(self, rows: dict[int, int], future: Future, nbytes: int):
+        self.rows = rows          # live hash -> its row of the batch
+        self.future = future      # -> per-layer {key: (rows, ...) ndarray}
+        self.nbytes = nbytes      # device bytes the gathered batch holds
+
+
 class TieredPageStore:
     """Chain-hash-keyed KV block pages in host DRAM with PVC overflow.
 
@@ -92,8 +124,19 @@ class TieredPageStore:
     """
 
     def __init__(self, host_bytes: int, spill_dir: str | None = None,
-                 max_spill_entries: int = DEFAULT_MAX_SPILL_ENTRIES):
+                 max_spill_entries: int = DEFAULT_MAX_SPILL_ENTRIES,
+                 sync=contextlib.nullcontext,
+                 device_budget_bytes: float = float("inf")):
         self.host_budget_bytes = int(host_bytes)
+        # demotions in flight, oldest first (engine-loop only).  ``sync``
+        # opens the span around a wait for a copy (the engine passes
+        # devprof's ``sync.demote``); ``device_budget_bytes`` is the
+        # device memory the gathered batches may hold while a dispatch
+        # runs (the engine: what no warmed dispatch has ever touched).
+        self._in_flight: deque[_InFlight] = deque()
+        self._copier: ThreadPoolExecutor | None = None
+        self._sync = sync
+        self.device_budget_bytes = device_budget_bytes
         self.spill_dir = spill_dir
         self.max_spill_entries = max_spill_entries
         # hash -> (pages, nbytes); LRU order, oldest first.  Engine-loop
@@ -112,6 +155,7 @@ class TieredPageStore:
         # EngineStats so server/runner.py can export them)
         self.spilled_blocks = 0     # host -> PVC demotions (at enqueue)
         self.dropped_blocks = 0     # fell off the last tier (KV lost)
+        self.waited_blocks = 0      # demoted blocks whose copy was waited for
         if spill_dir:
             os.makedirs(spill_dir, exist_ok=True)
             self._rescan_spill_dir()
@@ -127,17 +171,32 @@ class TieredPageStore:
         with self._lock:
             return len(self._spill) + len(self._spill_pending)
 
+    @property
+    def in_flight_batches(self) -> int:
+        return len(self._in_flight)
+
+    @property
+    def in_flight_count(self) -> int:
+        """Blocks demoted whose copy has not landed in the host tier."""
+        return sum(len(b.rows) for b in self._in_flight)
+
     def __len__(self) -> int:
-        return len(self._host) + self.spill_count
+        return len(self._host) + self.spill_count + self.in_flight_count
+
+    def _batch_of(self, h: int) -> _InFlight | None:
+        for batch in self._in_flight:
+            if h in batch.rows:
+                return batch
+        return None
 
     def has(self, h: int) -> bool:
-        if h in self._host:
+        if h in self._host or self._batch_of(h) is not None:
             return True
         with self._lock:
             return h in self._spill or h in self._spill_pending
 
     def where(self, h: int) -> str | None:
-        if h in self._host:
+        if h in self._host or self._batch_of(h) is not None:
             return "host"
         with self._lock:
             if h in self._spill or h in self._spill_pending:
@@ -145,8 +204,11 @@ class TieredPageStore:
         return None
 
     def hashes(self):
-        """Every resolvable hash across both tiers (host first)."""
+        """Every resolvable hash across both tiers (host first, what is
+        in flight to it included)."""
         yield from list(self._host)
+        for batch in list(self._in_flight):
+            yield from list(batch.rows)
         with self._lock:
             snap = list(self._spill_pending) + list(self._spill)
         yield from snap
@@ -267,10 +329,78 @@ class TieredPageStore:
             pass
 
     def flush(self) -> None:
-        """Block until queued spill writes have landed (tests/shutdown)."""
+        """Block until every demotion in flight is filed and queued spill
+        writes have landed (tests/shutdown)."""
+        self.land(wait=True)
         self._writeq.join()
 
     # ---- demote ---------------------------------------------------------
+
+    def reserve(self, nbytes: int) -> None:
+        """Make room for a gather of ``nbytes`` device bytes BEFORE it is
+        enqueued: wait for the oldest batches until fewer than
+        ``MAX_IN_FLIGHT`` remain and theirs plus the new one's bytes fit
+        ``device_budget_bytes``.  Nothing else is filed here: the caller
+        is about to dispatch, and the chip may be waiting for it."""
+        while self._in_flight and (
+                len(self._in_flight) >= MAX_IN_FLIGHT
+                or nbytes + sum(b.nbytes for b in self._in_flight)
+                > self.device_budget_bytes):
+            self._land_oldest(forced=True)
+
+    def put_async(self, hashes: list[int], fetch, nbytes: int) -> None:
+        """Demote a batch whose gather has been enqueued: ``fetch()``,
+        run on the copier thread, copies it to the host and returns it as
+        per-layer ``{key: ndarray}`` with row ``i`` the pages of
+        ``hashes[i]`` (more rows are padding).  The hashes are resolvable
+        from now; ``land`` files them (``put``, budget cascade and all).
+        A batch the device budget cannot hold through a dispatch is
+        waited for here, so the caller's next dispatch finds it gone."""
+        rows = {h: i for i, h in enumerate(hashes) if not self.has(h)}
+        if self._copier is None:
+            self._copier = ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix="tpuserve-kv-demote")
+        self._in_flight.append(
+            _InFlight(rows, self._copier.submit(fetch), nbytes))
+        if nbytes > self.device_budget_bytes:
+            while self._in_flight:
+                self._land_oldest(forced=True)
+
+    def land(self, wait: bool = False) -> None:
+        """File the batches whose copy is done, oldest first (engine loop;
+        the engine calls this where the chip has work queued).  With
+        ``wait`` every batch in flight, waiting for its copy: the loop
+        has gone idle or is stopping, so nothing waits behind it and the
+        blocks do not count as waited for."""
+        while self._in_flight and (wait or self._in_flight[0].future.done()):
+            self._land_oldest(forced=False)
+
+    def _fetched(self, batch: _InFlight, waited: int) -> list[dict] | None:
+        """The batch's host arrays, waiting for the copy under the sync
+        span if it is still running (and counting ``waited`` blocks as
+        waited for); None, and the batch's blocks counted as dropped,
+        if the copy failed."""
+        try:
+            if batch.future.done():
+                return batch.future.result()
+            self.waited_blocks += waited
+            with self._sync():
+                return batch.future.result()
+        except Exception:       # whatever the copy raised: the KV is lost
+            logger.exception("KV demotion copy failed; dropping %d block(s)",
+                             len(batch.rows))
+            self.dropped_blocks += len(batch.rows)
+            batch.rows.clear()
+            return None
+
+    def _land_oldest(self, forced: bool) -> None:
+        """File the oldest batch; ``forced`` says the loop needs its room
+        now (the in-flight bound), so a wait for it counts."""
+        batch = self._in_flight[0]
+        layers = self._fetched(batch, len(batch.rows) if forced else 0)
+        self._in_flight.popleft()        # before put: has() must miss them
+        for h, i in batch.rows.items():
+            self.put(h, _row_pages(layers, i))
 
     def put(self, h: int, pages: list[dict]) -> None:
         """Demote one evicted HBM block's pages under hash ``h``.  Host-
@@ -300,11 +430,18 @@ class TieredPageStore:
         is about to become resolvable in HBM again, and a block must live
         in exactly one tier).  None when unresolvable or the spill file is
         unreadable (the caller falls back to recompute; the loss is
-        counted — that KV is gone)."""
+        counted — that KV is gone).  A hash in flight waits for that one
+        batch's copy."""
         ent = self._host.pop(h, None)
         if ent is not None:
             self.host_bytes_used -= ent[1]
             return ent[0]
+        batch = self._batch_of(h)
+        if batch is not None:
+            layers = self._fetched(batch, 1)
+            if layers is None:
+                return None
+            return _row_pages(layers, batch.rows.pop(h))
         with self._lock:
             pending = self._spill_pending.pop(h, None)
             if pending is not None:
@@ -337,6 +474,10 @@ class TieredPageStore:
         if ent is not None:
             self.host_bytes_used -= ent[1]
             return
+        batch = self._batch_of(h)
+        if batch is not None:
+            del batch.rows[h]           # the landing files nothing for it
+            return
         with self._lock:
             if self._spill_pending.pop(h, None) is not None:
                 return                  # writer cleans any half-born file
@@ -345,6 +486,10 @@ class TieredPageStore:
             self._drop_spill_file(path)
 
     def clear(self) -> None:
+        for batch in self._in_flight:   # wiped like the rest, but counted:
+            self.dropped_blocks += len(batch.rows)  # they reached no tier
+            batch.future.cancel()
+        self._in_flight.clear()
         with self._lock:
             self._spill_pending.clear()
             paths = list(self._spill.values())

@@ -200,6 +200,12 @@ class ServerMetrics:
             "Prefix blocks demoted out of HBM into the host-DRAM tier "
             "instead of destroyed on eviction (tiered KV cache; "
             "TPUSERVE_KV_TIERS=0 restores destroy-on-evict)")
+        self.kv_demote_waited = counter(
+            "tpuserve_kv_blocks_demote_waited",
+            "Demoted blocks whose device-to-host copy the engine loop had "
+            "to wait for (the in-flight bound, or a restore of a hash "
+            "still in flight); 1 - waited/demoted is the share of "
+            "demotion copies hidden behind the chip's work")
         self.kv_spilled = counter(
             "tpuserve_kv_blocks_spilled",
             "Host-tier blocks cascaded to the PVC spill tier under "

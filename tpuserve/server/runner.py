@@ -1000,6 +1000,8 @@ class AsyncEngineRunner:
                                   self.metrics.mixed_steps),
                                  ("kv_demoted_blocks",
                                   self.metrics.kv_demoted),
+                                 ("kv_demote_waited_blocks",
+                                  self.metrics.kv_demote_waited),
                                  ("kv_spilled_blocks",
                                   self.metrics.kv_spilled),
                                  ("kv_tier_dropped_blocks",
@@ -1184,4 +1186,10 @@ class AsyncEngineRunner:
                 self._route_outputs(outputs)
             with PROF.phase("runner.gauges"):
                 self._update_gauges()
+        # demotions whose copy is still in flight are filed (or counted
+        # as dropped) before the loop's thread goes: none is lost silently
+        for e in self._inner_engines():
+            store = getattr(e, "_kv_tiers", None)
+            if store is not None:
+                store.flush()
         logger.info("engine loop stopped")
