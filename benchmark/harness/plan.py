@@ -1,14 +1,28 @@
-"""Finding a cell's files by name.  Standard library only.
+"""Finding a cell's files by name.  Standard library only (a reference
+file imports what it needs).
 
-Everything that belongs to one configuration, one traffic mix, one cell or
-one per-layer metric is a file of its own under the benchmark's root:
+Everything that belongs to one configuration, one traffic mix, one cell,
+one per-layer metric or one model family's plain reference is a file of
+its own under the benchmark's root:
 
     configs/<configuration>.json      traffic/<mix>.json
     cells/<cell>.json                 layer_metrics/<metric>.py
+    reference/<family>.py
 
-``BENCHMARK.json`` names them; this module finds them.  Nothing here (or
-in ``run.py``) names a configuration, a mix, a cell or a metric, so a later
-PR adds a cell by adding files and entries and edits nothing.
+``BENCHMARK.json`` names the first four and a configuration file names its
+reference (``"reference": "<family>"``); this module finds them.  Nothing
+here (or in ``run.py``) names a configuration, a mix, a cell, a metric or a
+reference, so a later PR adds a cell, of a new architecture too, by adding
+files and entries and edits nothing.
+
+A reference file has ``check_family(model_cfg)``, which raises ValueError
+on an architecture it does not describe, and ``score_probes(params,
+model_cfg, probes)``: for ``probes``, a list of ``(prompt ids, served
+token ids, the choice's logprobs object as the server returned it)``, one
+float32 row of log-probabilities over the vocabulary for every served
+token, in served order.  It may also state which further keys of its
+family's ``config.json`` are checked against which ModelConfig field
+(``FIXED``, as below) and which size nothing (``DESCRIPTIVE``).
 """
 
 from __future__ import annotations
@@ -40,7 +54,30 @@ FIXED = {"hidden_size": "hidden_size",
          "sliding_window": "sliding_window",
          "tie_word_embeddings": "tie_word_embeddings",
          "rope_theta": "rope_theta",
-         "rms_norm_eps": "norm_eps"}
+         "rms_norm_eps": "norm_eps",
+         "attention_bias": "attention_bias",
+         "num_experts": "num_experts",
+         "n_routed_experts": "num_experts",
+         "num_experts_per_tok": "num_experts_per_tok",
+         "moe_intermediate_size": "expert_intermediate_size",
+         "norm_topk_prob": "norm_topk_prob",
+         "n_shared_experts": "moe_shared_experts",
+         "first_k_dense_replace": "moe_first_k_dense",
+         "kv_lora_rank": "mla_kv_lora_rank",
+         "q_lora_rank": "mla_q_lora_rank",
+         "qk_nope_head_dim": "mla_qk_nope_head_dim",
+         "qk_rope_head_dim": "mla_qk_rope_head_dim",
+         "v_head_dim": "mla_v_head_dim"}
+# A configuration file's own keys, and the keys of a ``config.json`` that
+# describe and size nothing.  Any other key has to be in CUTTABLE, FIXED or
+# the reference's own lists: a size that nothing checks is refused.
+OWN_KEYS = ("model", "source", "chips", "deployment", "reduced", "assumed",
+            "server_args", "expect", "reference", "status")
+DESCRIPTIVE = ("model_type", "architectures", "hidden_act", "torch_dtype",
+               "use_sliding_window", "transformers_version", "use_cache",
+               "initializer_range", "attention_dropout", "bos_token_id",
+               "eos_token_id", "pad_token_id")
+REFERENCE_INTERFACE = ("check_family", "score_probes")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,6 +93,7 @@ class Cell:
     end_to_end: tuple            # metric names, setup_s included
     per_layer: tuple             # metric names
     units: dict = dataclasses.field(default_factory=dict)   # by metric name
+    reference: object = None     # the module the configuration names
 
 
 def read_json(path: str) -> dict:
@@ -91,6 +129,7 @@ def load_cell(name: str, bench: dict, bench_root: str = BENCH_ROOT) -> Cell:
                          f"{config['chips']} chip(s), the cell "
                          f"{entry['chips']}")
     return Cell(name=name, chips=entry["chips"],
+                reference=load_reference(config, bench_root),
                 config_name=entry["config"], config=config,
                 traffic_name=entry["traffic"],
                 traffic=read_json(traffic_path), traffic_path=traffic_path,
@@ -99,6 +138,14 @@ def load_cell(name: str, bench: dict, bench_root: str = BENCH_ROOT) -> Cell:
                 per_layer=_metrics_for(bench["per_layer"], name),
                 units={m["name"]: m["unit"] for m in
                        bench["end_to_end"] + bench["per_layer"]})
+
+
+def _load_module(kind: str, name: str, path: str):
+    spec = importlib.util.spec_from_file_location(
+        kind + "_" + re.sub(r"\W", "_", name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def discover_layer_metrics(bench_root: str = BENCH_ROOT) -> dict:
@@ -110,14 +157,34 @@ def discover_layer_metrics(bench_root: str = BENCH_ROOT) -> dict:
     for fname in sorted(os.listdir(folder)):
         if not fname.endswith(".py") or fname.startswith("_"):
             continue
-        name = fname[:-3]
-        spec = importlib.util.spec_from_file_location(
-            "layer_metric_" + re.sub(r"\W", "_", name),
-            os.path.join(folder, fname))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        found[name] = mod
+        found[fname[:-3]] = _load_module("layer_metric", fname[:-3],
+                                         os.path.join(folder, fname))
     return found
+
+
+def load_reference(config: dict, bench_root: str = BENCH_ROOT):
+    """The plain reference a configuration file names, loaded by path from
+    ``reference/<name>.py``.  ValueError, with the sentence, where the file
+    names none, the name has no file or the file lacks the interface."""
+    name = config.get("reference")
+    if not isinstance(name, str) or not NAME.match(name):
+        raise ValueError(f"the configuration names no reference "
+                         f"(\"reference\": {name!r})")
+    path = os.path.join(bench_root, "reference", name + ".py")
+    if not os.path.isfile(path):
+        raise ValueError(f"reference {name!r}: no file reference/{name}.py")
+    mod = _load_module("reference", name, path)
+    lacks = [f for f in REFERENCE_INTERFACE
+             if not callable(getattr(mod, f, None))]
+    if lacks:
+        raise ValueError(f"reference {name!r} lacks {lacks}")
+    return mod
+
+
+def checked_keys(reference=None) -> dict:
+    """HF key -> ModelConfig field for every key that is checked against
+    what runs: this module's, then the reference's own."""
+    return {**getattr(reference, "FIXED", {}), **CUTTABLE, **FIXED}
 
 
 def architecture_overrides(config: dict) -> dict:
@@ -132,15 +199,23 @@ def architecture_overrides(config: dict) -> dict:
     return out
 
 
-def architecture_mismatches(config: dict, model_cfg) -> list:
+def architecture_mismatches(config: dict, model_cfg, reference=None) -> list:
     """Where the architecture the file states differs from the ModelConfig
     that runs.  Empty means the file holds the configuration as it runs."""
     bad = []
-    for key, field in {**CUTTABLE, **FIXED}.items():
+    for key, field in checked_keys(reference).items():
         if key in config and getattr(model_cfg, field) != config[key]:
             bad.append(f"{key}: file {config[key]!r}, runs "
                        f"{getattr(model_cfg, field)!r}")
     return bad
+
+
+def unchecked_keys(config: dict, reference=None) -> list:
+    """Keys of a configuration file that nothing checks and no list calls
+    descriptive."""
+    known = set(checked_keys(reference)) | set(OWN_KEYS) | set(DESCRIPTIVE) \
+        | set(getattr(reference, "DESCRIPTIVE", ()))
+    return sorted(k for k in config if k not in known)
 
 
 def lint(bench: dict, bench_root: str = BENCH_ROOT,
@@ -198,6 +273,15 @@ def lint(bench: dict, bench_root: str = BENCH_ROOT,
                 bad.append(f"config {c['name']}: reduces {key!r}")
         if not any(w["config"] == c["name"] for w in bench["workloads"]):
             bad.append(f"config {c['name']}: no cell uses it")
+        try:
+            reference = load_reference(data, bench_root)
+        except ValueError as e:
+            bad.append(f"config {c['name']}: {e}")
+            continue
+        for key in unchecked_keys(data, reference):
+            bad.append(f"config {c['name']}: {key!r} is checked against "
+                       "nothing that runs and is on no list of "
+                       "descriptive keys")
     readers = discover_layer_metrics(bench_root)
     for w in bench["workloads"]:
         name_ok("traffic", w["traffic"])

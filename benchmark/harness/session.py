@@ -3,9 +3,10 @@
 Set-up (everything before the window opens, reported as ``setup_s``):
 compile cache, the real server built from argv by the program's own
 ``build_server``, weights on the device, warm-up of the cell's own shapes,
-three correctness probes against the plain reference, the load generator's
-child started, its pre-roll.  Then the window.  Nothing in here names a
-configuration, a traffic mix, a cell or a per-layer metric.
+three correctness probes scored by the plain reference the configuration
+names, the load generator's child started, its pre-roll.  Then the window.
+Nothing in here names a configuration, a traffic mix, a cell, a per-layer
+metric or a reference.
 """
 
 from __future__ import annotations
@@ -21,7 +22,7 @@ import time
 import urllib.error
 import urllib.request
 
-from . import plan, stats, trace_reduce
+from . import host_spans, plan, stats, trace_reduce
 from . import traffic as traffic_mod
 from .meter import CompileMeter
 from .shapes import warm_shapes
@@ -38,6 +39,7 @@ TIE_ATOL = 0.1
 PROBE_PROMPTS = (64, 96, 128)
 PROBE_TOKENS = 16
 POLL_S = 0.5
+NO_SPAN = "no_span"          # idle time under no span of the engine loop
 
 
 def say(msg: str) -> None:
@@ -88,17 +90,25 @@ def memory_peak_bytes() -> int:
 def register_configuration(cell) -> str:
     """Register the cell's architecture under the configuration's own
     name and return that name: the registered model with the keys listed
-    under ``reduced`` replaced, and nothing else."""
+    under ``reduced`` replaced, and nothing else.  Refused, before any
+    server is built, where the file misdescribes what runs or its
+    reference does not describe the family."""
     from tpuserve.models.config import (get_model_config,
                                         register_model_config)
     base = get_model_config(cell.config["model"])
     name = "bench/" + cell.config_name
     model_cfg = dataclasses.replace(
         base, name=name, **plan.architecture_overrides(cell.config))
-    wrong = plan.architecture_mismatches(cell.config, model_cfg)
+    wrong = plan.architecture_mismatches(cell.config, model_cfg,
+                                         cell.reference)
     if wrong:
         raise Refused(f"configuration {cell.config_name} does not describe "
                       f"what runs: {wrong}")
+    try:
+        cell.reference.check_family(model_cfg)
+    except ValueError as e:
+        raise Refused(f"configuration {cell.config_name}: its reference "
+                      f"does not describe what runs: {e}") from e
     register_model_config(model_cfg)
     return name
 
@@ -176,6 +186,13 @@ def warm_chained_decode(engine, decode_buckets: list) -> None:
         f"warmed in {time.monotonic() - t0:.1f}s")
 
 
+def kv_block_bytes(engine) -> int:
+    """Bytes of one block of the paged cache, over all its leaves."""
+    import jax
+    return sum(leaf.nbytes for leaf in jax.tree.leaves(
+        engine.kv_cache)) // engine.cache_cfg.num_blocks
+
+
 def warm_demotion_ladder(engine) -> None:
     """The tiered KV cache copies every evicted prefix block to the host
     through a gather whose block axis is padded to a power of two;
@@ -188,8 +205,7 @@ def warm_demotion_ladder(engine) -> None:
     from tpuserve.runtime.kv_cache import gather_block_pages
     block = engine.cache_cfg.block_size
     top = engine.scheduler.cfg.max_prefill_tokens // block
-    block_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(
-        engine.kv_cache)) // engine.cache_cfg.num_blocks
+    block_bytes = kv_block_bytes(engine)
     stats = jax.local_devices()[0].memory_stats()
     # a backend without memory statistics (the CPU rehearsal) has no limit
     free = (stats["bytes_limit"] - stats["bytes_in_use"]) if stats \
@@ -238,12 +254,14 @@ def scrape(url: str) -> dict:
     return out
 
 
-def probe(url: str, model: str, engine, seed: int) -> dict:
+def probe(url: str, model: str, engine, seed: int, reference) -> dict:
     """Seeded prompts through the served path, scored by the plain
-    reference at the same positions."""
+    reference the configuration names.  Which row of log-probabilities
+    belongs to which served token is the reference's to say (it is handed
+    each choice's whole ``logprobs`` object, unread here beyond its tokens
+    and their logprobs); the prompts, the request, the tolerances and the
+    comparison are this function's, so no reference can loosen them."""
     import numpy as np
-
-    from ..reference import dense_gqa
 
     vocab = engine.model_cfg.vocab_size
     served = []
@@ -257,21 +275,17 @@ def probe(url: str, model: str, engine, seed: int) -> dict:
         if len(toks) != PROBE_TOKENS:
             return {"ok": False, "why": f"probe {i} returned {len(toks)} "
                                         f"tokens, wanted {PROBE_TOKENS}"}
-        served.append((ids, toks, lp["token_logprobs"]))
-    width = max(PROBE_PROMPTS) + PROBE_TOKENS
-    tokens = np.zeros((len(served), width), np.int32)
-    rows = []
-    for i, (ids, toks, _) in enumerate(served):
-        seq = ids + toks
-        tokens[i, :len(seq)] = seq
-        rows += [(i, len(ids) + j - 1) for j in range(PROBE_TOKENS)]
-    ref = np.asarray(dense_gqa.logprobs_at(engine.params, engine.model_cfg,
-                                           tokens, rows))
+        served.append((ids, toks, lp))
+    ref = np.asarray(reference.score_probes(
+        engine.params, engine.model_cfg, served), np.float32)
+    if ref.shape != (len(served) * PROBE_TOKENS, vocab):
+        return {"ok": False, "why": f"the reference scored {ref.shape}, "
+                f"wanted {(len(served) * PROBE_TOKENS, vocab)}"}
     worst_lp = worst_tie = 0.0
     parted = 0
     r = 0
-    for ids, toks, lps in served:
-        for tok, lp in zip(toks, lps):
+    for _, toks, lps in served:
+        for tok, lp in zip(toks, lps["token_logprobs"]):
             worst_lp = max(worst_lp, abs(float(ref[r, tok]) - float(lp)))
             tie = float(ref[r].max() - ref[r, tok])
             worst_tie = max(worst_tie, tie)
@@ -407,7 +421,32 @@ def run_window(cell, server, url: str, model: str, seed: int, seconds: float,
             "metrics_start": page0, "metrics_end": page1,
             "polls": poller.samples if poller else [],
             "trace_dir": trace_dir if trace else None, "trace_span": span,
-            "chips": cell.chips, "multi_step": engine._multi_step}
+            "chips": cell.chips, "multi_step": engine._multi_step,
+            "config": cell.config,
+            "cell": {"name": cell.name, "chips": cell.chips,
+                     "params": cell.params},
+            "kv_bytes_per_token":
+                kv_block_bytes(engine) / engine.cache_cfg.block_size}
+
+
+def moved_counters(start: dict, end: dict) -> dict:
+    """``end - start`` of two /metrics pages for the counters that moved:
+    ``*_total``, and a histogram's ``_sum`` and ``_count``.  A gauge's
+    difference between two instants says nothing and is left out."""
+    return {k: v - start.get(k, 0.0) for k, v in end.items()
+            if k.endswith(("_total", "_sum", "_count"))
+            and v != start.get(k, 0.0)}
+
+
+def idle_gaps(run: dict) -> list:
+    """The ten largest ``[name, seconds]`` of the busiest chip's idle time:
+    by the engine loop's innermost span where the trace has such spans, by
+    the programs around each gap only where it has none."""
+    spans = host_spans.analyse(run)
+    if not spans:
+        return run["trace"]["gaps"][:10]
+    return [[name or NO_SPAN, ns * 1e-9]
+            for name, ns in spans["by_span"].items()][:10]
 
 
 def find_xplane(trace_dir: str):
@@ -421,7 +460,9 @@ def find_xplane(trace_dir: str):
 def measure(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
             out_dir: str, peaks_path: str, keep_trace: str = "",
             trace_seconds: float = 2.0) -> dict:
-    """The whole run.  Returns the object of the last line."""
+    """The whole run.  Returns the object of the last line, and under
+    ``compared`` the sentence of each number compared beside its limit,
+    which the command prints as the last line of standard error."""
     device = device_info(peaks_path, cell.chips)
     from tpuserve.utils import compile_cache
     cache_dir = compile_cache.configure()
@@ -437,7 +478,7 @@ def measure(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
     server, url, model = build(cell, meter, seconds)
     try:
         t0 = time.monotonic()
-        verdict = probe(url, model, server.engine, seed)
+        verdict = probe(url, model, server.engine, seed, cell.reference)
         say(f"probes {time.monotonic() - t0:.1f}s: {verdict}")
         run = run_window(cell, server, url, model, seed, seconds, trace,
                          out_dir, meter, trace_seconds)
@@ -459,7 +500,8 @@ def measure(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
         + " max_gap_p95_ms "
         + (f"{stats.percentile(summary['max_gap_ms'], 95):.3f}"
            if summary["max_gap_ms"] else "n/a")
-        + f" steps_in_window {len(run['steps'])}")
+        + f" steps_in_window {len(run['steps'])} kv_bytes_per_token "
+        f"{run['kv_bytes_per_token']}")
     correct = verdict["ok"]
     if run["compiles_in_window"]:
         correct = False
@@ -472,6 +514,16 @@ def measure(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
     device["memory_peak_bytes"] = memory_peak_bytes()
     result = {"correct": bool(correct), "attempted": summary["attempted"],
               "failed": summary["failed"], "metrics": {}, "device": device}
+    compared = (
+        f"correct {bool(correct)}: logprob_diff_max "
+        f"{verdict.get('logprob_diff_max')} (limit {LOGPROB_ATOL}); "
+        f"tie_gap_max {verdict.get('tie_gap_max')} (limit {TIE_ATOL}); "
+        f"positions {verdict.get('positions')} (of "
+        f"{len(PROBE_PROMPTS) * PROBE_TOKENS}); compiles_in_window "
+        f"{run['compiles_in_window']} (limit 0); attempted "
+        f"{summary['attempted']} (over 0)")
+    say(compared)
+    result["compared"] = compared
     units = cell.units
     if not trace:
         for name in cell.end_to_end:
@@ -491,6 +543,9 @@ def measure(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
             shutil.copy(xplane, keep_trace)
     if reduced is None or reduced["busy_s"] <= 0:
         raise Refused("the traced run saw no operation on the device")
+    say("/metrics counters over the window (end - start, those that moved): "
+        + json.dumps(moved_counters(run["metrics_start"],
+                                    run["metrics_end"]), sort_keys=True))
     run["trace"] = reduced
     run["summary"] = summary
     run["peaks"] = plan.read_json(peaks_path)["devices"][device["kind"]]
@@ -503,6 +558,6 @@ def measure(cell, seed: int, seconds: float, trace: bool, t_proc0: float,
     device["busy_s"] = reduced["busy_s"]
     device["window_s"] = reduced["window_s"]
     result["breakdown"] = {"device_ops": reduced["ops"][:10],
-                           "idle_gaps": reduced["gaps"][:10]}
+                           "idle_gaps": idle_gaps(run)}
     return result
 

@@ -21,6 +21,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
 VOCAB_SLICE = 16384
@@ -147,3 +148,20 @@ def logprobs_at(params, cfg, tokens, rows):
         parts = [_head_slice(h, head[:, lo:hi], False) for lo, hi in spans]
     logits = jnp.concatenate(parts, axis=-1)
     return jax.nn.log_softmax(logits, axis=-1)
+
+
+def score_probes(params, cfg, probes):
+    """The harness's call (``harness/plan.py`` has the interface): for
+    each probe ``(prompt ids, served token ids, the server's logprobs
+    object)`` one row of log-probabilities for every served token, in
+    served order.  This family makes its tokens left to right, so served
+    token j is scored after position ``len(prompt) + j - 1`` of prompt and
+    served tokens run as one sequence; the logprobs object is not read."""
+    width = max(len(ids) + len(toks) for ids, toks, _ in probes)
+    tokens = np.zeros((len(probes), width), np.int32)
+    rows = []
+    for i, (ids, toks, _) in enumerate(probes):
+        seq = list(ids) + list(toks)
+        tokens[i, :len(seq)] = seq
+        rows += [(i, len(ids) + j - 1) for j in range(len(toks))]
+    return logprobs_at(params, cfg, tokens, rows)

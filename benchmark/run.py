@@ -11,11 +11,12 @@ a run of its own that also takes a profiler trace of the window's last
 seconds and prints the per-layer metrics.  The last line of stdout is one
 JSON object (``correct``, ``attempted``, ``failed``, ``metrics``,
 ``device`` and, traced, ``breakdown``); everything else is on earlier
-lines.  Off the TPU, or with fewer chips than the cell asks for, it exits
-non-zero and prints no result line.
+lines, and the last line of stderr holds each number compared for
+``correct`` beside its limit.  Off the TPU, or with fewer chips than the
+cell asks for, it exits non-zero and prints no result line.
 
-Which configuration, traffic mix, cell and per-layer metric exist is data:
-``BENCHMARK.json`` and the files under ``benchmark/`` (see
+Which configuration, traffic mix, cell, per-layer metric and reference
+exist is data: ``BENCHMARK.json`` and the files under ``benchmark/`` (see
 ``harness/plan.py``).  This file names none of them.
 """
 
@@ -54,6 +55,7 @@ def main(argv=None) -> int:
         cell, args.seed, args.seconds, bool(args.trace), T_PROCESS_START,
         out_dir, os.path.join(plan.BENCH_ROOT, "peaks.json"),
         keep_trace=args.keep_trace, trace_seconds=args.trace_seconds)
+    print("[bench] " + result.pop("compared"), file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -10,11 +10,14 @@ import os
 import pytest
 
 from benchmark.harness import host_spans as hs
-from benchmark.harness import plan
+from benchmark.harness import plan, roofline, session
 
 FIXTURES = os.path.join(plan.BENCH_ROOT, "fixtures")
 NEW = ("idle.kv_demote_share", "idle.host_loop_share", "idle.sync_share",
        "idle.unattributed_share", "kernel.decode_attn_ns_per_ctx_tok")
+ROOFLINE = "kernel.decode_attn_roofline"
+V5E = plan.read_json(os.path.join(plan.BENCH_ROOT, "peaks.json"))[
+    "devices"]["TPU v5 lite"]
 STEP = ("engine.step", 0, 1000)
 
 
@@ -108,7 +111,7 @@ def test_context_tokens_a_dispatch_attends(step, tokens):
 ], ids=["untraced", "no-trace-dir", "no-reduction", "no-xplane"])
 def test_every_reader_returns_none_without_a_trace(run, capsys):
     readers = plan.discover_layer_metrics()
-    for name in NEW:
+    for name in NEW + (ROOFLINE,):
         assert readers[name].compute(dict(run)) is None, name
     assert capsys.readouterr().out == ""
 
@@ -116,11 +119,13 @@ def test_every_reader_returns_none_without_a_trace(run, capsys):
 def test_the_new_entries_are_appended_and_lint_clean():
     bench = plan.load_benchmark()
     assert plan.lint(bench) == []
-    assert tuple(m["name"] for m in bench["per_layer"][-5:]) == NEW
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(NEW[0])
+    assert tuple(names[first:first + 5]) == NEW
     readers = plan.discover_layer_metrics()
-    keys = set(bench["per_layer"][0])
-    for m in bench["per_layer"][-5:]:
-        assert set(m) == keys
+    keys = set(bench["per_layer"][1])
+    for m in bench["per_layer"][first:first + 5]:
+        assert set(m) - {"workloads"} == keys
         assert m["source"] == readers[m["name"]].SOURCE == "device_trace"
         assert m["moves"] == "out_tok_s"
 
@@ -180,3 +185,75 @@ def test_a_trace_without_spans_or_seq_reads_nothing(tmp_path, expected):
     run = unpacked(tmp_path, "qwen3_batch_spans_v5e", old_steps)
     assert readers[NEW[0]].compute(run) is not None
     assert readers[NEW[4]].compute(run) is None
+
+
+@pytest.mark.parametrize("seconds,flops,nbytes,want", [
+    (1.0, 0.0, 819e9, 1.0),                  # the memory rate bounds it
+    (2.0, 197e12, 0.0, 0.5),                 # the operation rate does
+    (1.0, 197e12, 2 * 819e9, 2.0),           # over 1 is never clipped
+    (140.0e-9 / 0.69, 229376.0, 114688.0, 0.69),
+    (0.0, 1.0, 1.0, None), (1.0, 0.0, 0.0, None),
+])
+def test_a_share_of_the_roofline(seconds, flops, nbytes, want):
+    got = roofline.share(seconds, flops, nbytes, V5E)
+    assert got == (want if want is None else pytest.approx(want, rel=1e-3))
+
+
+def qwen3_run(tmp_path, expected):
+    """The recorded trace as the session hands it over since PR 26: with
+    the configuration file, the cache's bytes a token and the peaks."""
+    run = unpacked(tmp_path, "qwen3_batch_spans_v5e", expected["steps"])
+    config = plan.load_cell("qwen3-0.6b.batch", plan.load_benchmark()).config
+    run.update(config=config, peaks=V5E, chips=1, kv_bytes_per_token=float(
+        2 * config["num_hidden_layers"] * config["num_key_value_heads"]
+        * config["head_dim"] * 2))
+    return run
+
+
+def test_the_decode_kernels_share_of_its_roofline(tmp_path, expected):
+    """Recorded on the chip (not a measurement of this machine): the
+    cache's bytes a context token over the HBM rate, over the kernel's
+    time a context token."""
+    run = qwen3_run(tmp_path, expected)
+    assert run["kv_bytes_per_token"] == 114688
+    readers = plan.discover_layer_metrics()
+    ns = readers[NEW[4]].compute(run)
+    got = readers[ROOFLINE].compute(run)
+    assert got == pytest.approx(100 * 114688 / 819e9 / (ns * 1e-9), rel=1e-9)
+    assert 55 < got < 80
+    flops, nbytes = readers[ROOFLINE].work_per_ctx_token(run)
+    assert (flops, nbytes) == (4.0 * 16 * 128 * 28, 114688.0)
+    # a cache sharded over four chips: a chip reads its part
+    assert readers[ROOFLINE].work_per_ctx_token(dict(run, chips=4)) == \
+        (flops / 4, nbytes / 4)
+    for missing in ("kv_bytes_per_token", "peaks"):
+        assert readers[ROOFLINE].compute(
+            {k: v for k, v in run.items() if k != missing}) is None
+
+
+def test_the_breakdowns_idle_gaps_are_by_span(tmp_path, expected):
+    run = qwen3_run(tmp_path, expected)
+    run["trace"]["gaps"] = [["jit_a -> jit_b", 0.5]]
+    gaps = session.idle_gaps(run)
+    assert len(gaps) == 10 and gaps == sorted(gaps, key=lambda g: -g[1])
+    by_span = {k or session.NO_SPAN: v * 1e-9
+               for k, v in expected["by_span"].items()}
+    assert dict(gaps) == {k: by_span[k] for k, _ in gaps}
+    assert {"kv.demote", "dispatch"} <= set(dict(gaps))
+    assert all(plan.NAME.match(name) for name, _ in gaps)
+    # a trace from before the spans: by the programs around each gap
+    old = unpacked(tmp_path, "qwen3_batch_v5e", [])
+    old["trace"]["gaps"] = [["jit_a -> jit_b", 0.5]] * 12
+    assert session.idle_gaps(old) == [["jit_a -> jit_b", 0.5]] * 10
+
+
+def test_the_counters_that_moved_over_the_window():
+    start = {"kv_blocks_demoted_total": 10.0, "same_total": 3.0,
+             "latency_bucket": 5.0, "latency_sum": 1.5, "latency_count": 2.0,
+             "running": 2.0}
+    end = {"kv_blocks_demoted_total": 74.0, "same_total": 3.0,
+           "latency_bucket": 9.0, "latency_sum": 4.0, "latency_count": 6.0,
+           "running": 1.0, "new_total": 4.0}
+    assert session.moved_counters(start, end) == {
+        "kv_blocks_demoted_total": 64.0, "latency_sum": 2.5,
+        "latency_count": 4.0, "new_total": 4.0}

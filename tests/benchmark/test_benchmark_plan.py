@@ -6,6 +6,7 @@ import json
 import os
 import re
 import shutil
+import types
 
 import pytest
 
@@ -97,6 +98,76 @@ def test_lint_sees_what_the_contract_forbids(change, needle):
     assert any(needle in line for line in corrupt(change))
 
 
+def lint_with_config(tmp_path, change, reference_file=None):
+    """Lint of a copy of the benchmark whose first configuration's file
+    was changed (and, optionally, with one more reference file)."""
+    root = tmp_path / "benchmark"
+    shutil.copytree(plan.BENCH_ROOT, root,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    path = root / "configs" / (BENCH["configs"][0]["name"] + ".json")
+    config = plan.read_json(path)
+    change(config)
+    path.write_text(json.dumps(config))
+    if reference_file:
+        (root / "reference" / reference_file[0]).write_text(
+            reference_file[1])
+    return plan.lint(BENCH, str(root), str(tmp_path))
+
+
+@pytest.mark.parametrize("change,reference_file,needle", [
+    (lambda c: c.pop("reference"), None, "names no reference"),
+    (lambda c: c.update(reference="no such"), None, "names no reference"),
+    (lambda c: c.update(reference="nowhere"), None,
+     "no file reference/nowhere.py"),
+    (lambda c: c.update(reference="half"),
+     ("half.py", "def check_family(cfg):\n    pass\n"),
+     "lacks ['score_probes']"),
+    (lambda c: c.update(expert_width=768), None,
+     "'expert_width' is checked against nothing"),
+    (lambda c: c.update(decoder_sparse_step=1), None,
+     "'decoder_sparse_step' is checked against nothing"),
+], ids=["no-key", "bad-name", "no-file", "no-interface", "unchecked-size",
+        "unchecked-key"])
+def test_lint_sees_a_configuration_file_nothing_vouches_for(
+        tmp_path, change, reference_file, needle):
+    found = lint_with_config(tmp_path, change, reference_file)
+    assert any(needle in line for line in found), found
+
+
+def test_lint_passes_keys_the_harness_or_the_reference_knows(tmp_path):
+    assert lint_with_config(tmp_path, lambda c: c.update(
+        num_experts=0, attention_dropout=0.0)) == []
+    assert lint_with_config(
+        tmp_path / "again", lambda c: c.update(reference="wider",
+                                               block_length=4),
+        ("wider.py", NEW_FAMILY)) == []
+
+
+@pytest.mark.parametrize("model,stated,needle", [
+    ("tiny-moe", {"num_experts": 128}, "num_experts: file 128, runs 4"),
+    ("tiny-moe", {"n_routed_experts": 8}, "n_routed_experts: file 8"),
+    ("tiny-moe", {"num_experts_per_tok": 8}, "num_experts_per_tok"),
+    ("tiny-moe", {"moe_intermediate_size": 768}, "moe_intermediate_size"),
+    ("tiny-moe", {"norm_topk_prob": False}, "norm_topk_prob"),
+    ("tiny-deepseek", {"n_shared_experts": 4}, "n_shared_experts"),
+    ("tiny-deepseek", {"first_k_dense_replace": 3},
+     "first_k_dense_replace"),
+    ("tiny-deepseek", {"kv_lora_rank": 512}, "kv_lora_rank"),
+    ("tiny-deepseek", {"q_lora_rank": 1536}, "q_lora_rank"),
+    ("tiny-deepseek", {"qk_rope_head_dim": 64}, "qk_rope_head_dim"),
+    ("tiny-deepseek", {"v_head_dim": 128}, "v_head_dim"),
+    ("tiny-qwen3", {"attention_bias": True}, "attention_bias"),
+])
+def test_a_stated_size_that_differs_from_what_runs_is_seen(model, stated,
+                                                           needle):
+    from tpuserve.models.config import get_model_config
+    cfg = get_model_config(model)
+    wrong = plan.architecture_mismatches(stated, cfg)
+    assert len(wrong) == 1 and needle in wrong[0], wrong
+    runs = {key: getattr(cfg, plan.FIXED[key]) for key in stated}
+    assert plan.architecture_mismatches(runs, cfg) == []
+
+
 def test_a_width_can_never_be_reduced():
     with pytest.raises(ValueError):
         plan.architecture_overrides({"reduced": ["hidden_size"],
@@ -106,9 +177,27 @@ def test_a_width_can_never_be_reduced():
         {"num_layers": 16}
 
 
+NEW_FAMILY = '''"""A reference of another family, as a later PR would add
+it: its own scoring, the sizes of its family that are checked, the keys
+that size nothing."""
+FIXED = {"block_length": "block_length"}
+DESCRIPTIVE = ("denoising_steps", "mask_token_id")
+
+
+def check_family(cfg):
+    if not getattr(cfg, "block_length", 0):
+        raise ValueError("not a block-diffusion model")
+
+
+def score_probes(params, cfg, probes):
+    return [[0.0] for _, toks, _ in probes for _ in toks]
+'''
+
+
 def test_additions_are_data(tmp_path):
-    """A configuration, a traffic mix, a cell and a per-layer metric added
-    as files (and entries) are found with no edit to the harness."""
+    """A configuration of another family with its own reference, a traffic
+    mix, a cell and a per-layer metric added as files (and entries) are
+    found with no edit to the harness."""
     root = tmp_path / "benchmark"
     shutil.copytree(plan.BENCH_ROOT, root,
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -118,8 +207,10 @@ def test_additions_are_data(tmp_path):
     config = plan.read_json(root / "configs" / (BENCH["configs"][0]["name"]
                                                 + ".json"))
     config.update(reduced=["num_hidden_layers"], num_hidden_layers=2,
-                  source="https://example.org/new")
+                  source="https://example.org/new", reference="new_family",
+                  block_length=4, denoising_steps=4, mask_token_id=7)
     (root / "configs" / "new-model.json").write_text(json.dumps(config))
+    (root / "reference" / "new_family.py").write_text(NEW_FAMILY)
     mix = plan.read_json(root / "traffic" / (BENCH["workloads"][0]["traffic"]
                                              + ".json"))
     mix.update(pool=8, end_to_end=["out_tok_s"])
@@ -150,6 +241,18 @@ def test_additions_are_data(tmp_path):
     cell = plan.load_cell("new-model.new-mix", bench, str(root))
     assert cell.params["clients"] == 5 and cell.traffic["pool"] == 8
     assert cell.config["num_hidden_layers"] == 2
+    assert cell.reference.__file__ == str(root / "reference"
+                                          / "new_family.py")
+    rows = cell.reference.score_probes(None, None, [([1, 2], [3, 4, 5], {})])
+    assert len(rows) == 3
+    assert plan.architecture_mismatches(
+        {"block_length": cell.config["block_length"]},
+        types.SimpleNamespace(block_length=8),
+        cell.reference) == ["block_length: file 4, runs 8"]
+    old = plan.load_cell(BENCH["workloads"][0]["name"], bench, str(root))
+    assert old.reference.__file__ != cell.reference.__file__
+    assert plan.unchecked_keys(cell.config, old.reference) == [
+        "block_length", "denoising_steps", "mask_token_id"]
     assert "new.metric" in cell.per_layer
     assert "new.metric" not in plan.load_cell(
         BENCH["workloads"][0]["name"], bench, str(root)).per_layer
@@ -163,6 +266,12 @@ def test_additions_are_data(tmp_path):
 def test_harness_and_command_name_no_cell_config_mix_or_metric():
     names = {e["name"] for g in ("configs", "workloads", "per_layer")
              for e in BENCH[g]} | {w["traffic"] for w in BENCH["workloads"]}
+    names |= {f[:-3] for f in os.listdir(os.path.join(plan.BENCH_ROOT,
+                                                      "reference"))
+              if f.endswith(".py") and not f.startswith("_")}
+    names |= {plan.read_json(os.path.join(plan.REPO_ROOT, c["file"]))
+              ["reference"] for c in BENCH["configs"]}
+    assert "dense_gqa" in names
     sources = [os.path.join(plan.BENCH_ROOT, "run.py")] + [
         os.path.join(plan.BENCH_ROOT, "harness", f)
         for f in os.listdir(os.path.join(plan.BENCH_ROOT, "harness"))

@@ -85,3 +85,25 @@ def test_another_family_is_refused(name):
     from tpuserve.models.config import get_model_config
     with pytest.raises(ValueError):
         dense_gqa.check_family(get_model_config(name))
+
+
+def test_probes_are_scored_left_to_right_in_served_order():
+    """``score_probes``, the harness's call: served token j of a probe is
+    scored after position ``len(prompt) + j - 1``, each probe alone or
+    right-padded beside longer ones; the logprobs object is not read."""
+    from tpuserve.models.weights import init_params
+    cfg = f32("tiny-qwen3")
+    params = init_params(cfg, seed=4)
+    rng = np.random.default_rng(2)
+    probes = [(rng.integers(1, 500, size=n).tolist(),
+               rng.integers(1, 500, size=m).tolist(), {"unread": object()})
+              for n, m in ((9, 4), (20, 6), (13, 5))]
+    got = np.asarray(dense_gqa.score_probes(params, cfg, probes))
+    assert got.shape == (15, cfg.vocab_size) and got.dtype == np.float32
+    r = 0
+    for ids, toks, _ in probes:
+        seq = np.asarray([ids + toks], np.int32)
+        rows = [(0, len(ids) + j - 1) for j in range(len(toks))]
+        want = np.asarray(dense_gqa.logprobs_at(params, cfg, seq, rows))
+        np.testing.assert_allclose(got[r:r + len(toks)], want, atol=1e-5)
+        r += len(toks)
