@@ -22,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 # (num_q_heads, num_kv_heads, head_dim)
 WIDTHS = {
     "qwen3-0.6b": (16, 8, 128),
-    "llama-8b": (32, 8, 128),
+    "llama-8b": (32, 8, 128),        # Mistral-7B's widths too
     "llama-8b-tp4": (8, 2, 128),     # one shard of the four-chip smoke
 }
 PAGE = 32            # server default --block-size
@@ -32,6 +32,7 @@ MAX_NUM_SEQS = 64    # SchedulerConfig.max_num_seqs
 CHUNK = 2048         # SchedulerConfig.prefill_chunk_size
 MIN_BUCKET = 32      # SchedulerConfig.min_prefill_bucket
 MIXED_BUDGET = 512   # SchedulerConfig.mixed_token_budget
+PREFILL_SEQS = 8     # SchedulerConfig.max_prefill_seqs
 
 
 @pytest.fixture(scope="module")
@@ -97,20 +98,24 @@ def _tail(S, hq, hkv, d, quantized):
     return _window(S, hq, hkv, d, quantized, C=MIN_BUCKET)
 
 
-def _ragged(S, hq, hkv, d, quantized, monkeypatch):
+def _ragged(S, hq, hkv, d, quantized, monkeypatch, T=MIXED_BUDGET,
+            B=MAX_NUM_SEQS, decode_rows=True):
+    """The ragged kernel at a mixed step's shape (the default), or at a
+    packed batched prefill's: any rung T of the engine's flat-token
+    ladder, the descriptors PREFILL_SEQS wide, built without the decode
+    part (``decode_rows=False``)."""
     from tpuserve.ops import pallas_ragged_attention as ragged
     # ragged_block() asks jax.default_backend(), which is the CPU here:
     # steer it to the block the engine packs with on a TPU
     with monkeypatch.context() as m:
         m.setattr(jax, "default_backend", lambda: "tpu")
         blk = ragged.ragged_block()
-    T, B = MIXED_BUDGET, MAX_NUM_SEQS
     seq = S((B,), jnp.int32)
     pages, scales = _cache(S, hkv, d, quantized)
     return (lambda q, k, v, bt, kl, qs, ql, m, bs, *s:
             ragged.ragged_paged_attention(
                 q, k, v, bt, kl, qs, ql, m, bs, d ** -0.5, interpret=False,
-                blk_q=blk, **_scales(s)),
+                blk_q=blk, decode_rows=decode_rows, **_scales(s)),
             [S((T, hq, d), jnp.bfloat16), *pages,
              S((B, MAX_PAGES), jnp.int32), seq, seq, seq,
              S((2,), jnp.int32), S((T // blk,), jnp.int32), *scales])
@@ -156,16 +161,39 @@ def test_kernel_compiles_for_v5e(kernel, width, quantized, one_chip,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("kernel", ["decode", "flash"])
+@pytest.mark.parametrize("width", ["qwen3-0.6b", "llama-8b"])
+@pytest.mark.parametrize("rows", [128, 1792, 8192])
+def test_ragged_kernel_compiles_at_the_packed_prefill_ladder(
+        rows, width, one_chip, monkeypatch):
+    """A packed batched prefill (engine ``_run_prefill``) dispatches the
+    ragged kernel at a rung of ``packed_prefill_bucket``'s ladder, not at
+    a power of two: its ends and a middle rung, at both cells' widths.
+    The kernel raises, rather than shrink its block, when the VMEM budget
+    is short, so this is also the check that 128-row blocks fit 32 query
+    heads."""
+    from tpuserve.runtime.scheduler import packed_prefill_bucket
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert packed_prefill_bucket(rows, 128) == rows
+    fn, args = _ragged(S, *WIDTHS[width], False, monkeypatch, T=rows,
+                       B=PREFILL_SEQS, decode_rows=False)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel", ["decode", "flash", "ragged"])
 def test_the_custom_call_carries_the_name_the_benchmark_matches(
-        kernel, one_chip):
+        kernel, one_chip, monkeypatch):
     """A profiler trace prints a Pallas kernel as its HLO instruction, and
     ``benchmark/harness`` reduces the trace by that name: it is
     ``pallas_call(name=KERNEL_NAME)``, not whatever function happens to
     wrap the call."""
     import re
 
-    from tpuserve.ops import pallas_flash_attention, pallas_paged_attention
+    from tpuserve.ops import (pallas_flash_attention, pallas_paged_attention,
+                              pallas_ragged_attention)
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -174,9 +202,12 @@ def test_the_custom_call_carries_the_name_the_benchmark_matches(
         "decode": (_decode, pallas_paged_attention.KERNEL_NAME,
                    "_paged_decode_attention"),
         "flash": (_flash, pallas_flash_attention.KERNEL_NAME,
-                  "_flash_prefill_attention")}[kernel]
+                  "_flash_prefill_attention"),
+        "ragged": (_ragged, pallas_ragged_attention.KERNEL_NAME,
+                   "_ragged_paged_attention")}[kernel]
     assert name == want
-    fn, args = build(S, *WIDTHS["qwen3-0.6b"], False)
+    extra = (monkeypatch,) if kernel == "ragged" else ()
+    fn, args = build(S, *WIDTHS["qwen3-0.6b"], False, *extra)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call\([^\n]*"
                      r"tpu_custom_call", text)

@@ -176,7 +176,8 @@ def test_the_programs_keep_the_names_the_benchmark_matches(traced):
     holds ``decode_multi``, and the ledger's gaps are named by program: the
     jitted functions' names are part of the yardstick (the kernels' names
     are pinned where they compile, tests/test_chip_compile.py)."""
-    for program in ("decode_multi", "prefill", "prefill_chunk",
+    # a batched prefill on a single chip is the ragged trunk's program
+    for program in ("decode_multi", "forward_ragged", "prefill_chunk",
                     "_gather_pages", "_scatter_pages", "sample_tokens"):
         assert f"PjitFunction({program})" in traced["names"], program
 

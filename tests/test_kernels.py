@@ -469,9 +469,9 @@ def _ragged_ref(c, sliding_window=None):
         jnp.asarray(c["row_pos"] + 1), c["scale"], seg_size=8, **kw)
 
 
-def _ragged_out(c, blk, ppg=2, sliding_window=None):
+def _ragged_out(c, blk, ppg=2, sliding_window=None, **kw):
     from tpuserve.ops.pallas_ragged_attention import ragged_paged_attention
-    kw = dict(c["scales"])
+    kw.update(c["scales"])
     if sliding_window is not None:
         kw["sliding_window"] = sliding_window
     return ragged_paged_attention(
@@ -493,6 +493,26 @@ def test_ragged_kernel_matches_reference(n_dec, chunks, blk):
     out = _ragged_out(c, blk)
     np.testing.assert_allclose(np.asarray(out)[c["valid"]],
                                np.asarray(ref)[c["valid"]], atol=2e-5)
+
+
+@pytest.mark.parametrize("chunks,blk,W", [
+    ([(13, 20)], 8, None),               # one prompt on a cached prefix
+    ([(21, 21), (3, 3), (9, 17)], 8, None),   # a packed batch of three
+    ([(7, 25), (12, 12)], 4, 5),         # under a sliding window
+])
+def test_ragged_kernel_without_its_decode_part(chunks, blk, W):
+    """A packed batched prefill dispatches no decode rows and builds the
+    kernel without the decode part (``decode_rows=False``): the prefill
+    part alone gives what the whole kernel gives."""
+    rng = np.random.default_rng(len(chunks) * 13 + blk)
+    c = _ragged_case(rng, 0, chunks, blk, page=4, mp=12, max_kv=40)
+    ref = _ragged_ref(c, sliding_window=W)
+    out = _ragged_out(c, blk, sliding_window=W, decode_rows=False)
+    whole = _ragged_out(c, blk, sliding_window=W)
+    np.testing.assert_allclose(np.asarray(out)[c["valid"]],
+                               np.asarray(ref)[c["valid"]], atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(out)[c["valid"]],
+                                  np.asarray(whole)[c["valid"]])
 
 
 def test_ragged_kernel_matches_phase_split_kernels():

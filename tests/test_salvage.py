@@ -145,14 +145,16 @@ def test_salvage_requeue_rescues_orphaned_prefill_batch():
     rids = [eng.add_request(prompt_token_ids=p, params=PARAMS)
             for p in PROMPTS[:2]]
     boom = {"armed": True}
-    orig = eng._exec_prefill
+    # the batched prefill's exec hook on this engine's route
+    hook = "_exec_forward_ragged" if eng._packed_prefill else "_exec_prefill"
+    orig = getattr(eng, hook)
 
     def exploding(*a, **k):
         if boom.pop("armed", None):
             raise InjectedFault("injected prefill fault")
         return orig(*a, **k)
 
-    eng._exec_prefill = exploding
+    setattr(eng, hook, exploding)
     with pytest.raises(InjectedFault):
         eng.step()
     # orphaned: popped from waiting, never marked running

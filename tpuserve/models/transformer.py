@@ -1055,7 +1055,7 @@ def decode_multi(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
 def _ragged_reference_attn(q, ck, cv, block_tables, row_seq, row_lens,
                            blk_seq, meta, blk: int, scale, ks, vs, sw,
-                           softcap, scale_slices=None):
+                           softcap, scale_slices=None, decode_rows=True):
     """Reference (non-Pallas) ragged attention for one mixed layer:
 
     - prefill-chunk blocks take the BLOCK-gather path (one KV gather per
@@ -1066,12 +1066,16 @@ def _ragged_reference_attn(q, ck, cv, block_tables, row_seq, row_lens,
       decode attention — the exact math of the phase-split decode trunk,
       so decode-row logits are bit-identical between mixed and
       phase-split (the seeded-sampling token-identity contract).
+      ``decode_rows=False`` (a packed batched prefill: ``meta`` is zero)
+      leaves the overlay out.
     """
     T = q.shape[0]
     out = attn_ops.ragged_blocked_attention(
         q, ck, cv, block_tables[jnp.clip(blk_seq, 0, None)], row_lens,
         blk, scale, k_scale=ks, v_scale=vs, sliding_window=sw,
         logit_softcap=softcap, scale_slices=scale_slices)
+    if not decode_rows:
+        return out
     # static head slice: decode rows r < meta[0] are rows r themselves,
     # and meta[0] <= max_num_seqs <= block_tables.shape[0]
     Bc = min(block_tables.shape[0], T)
@@ -1085,7 +1089,7 @@ def _ragged_reference_attn(q, ck, cv, block_tables, row_seq, row_lens,
 
 
 @partial(jax.jit,
-         static_argnames=("cfg", "ragged_blk", "attn_impl"),
+         static_argnames=("cfg", "ragged_blk", "attn_impl", "decode_rows"),
          donate_argnames=("kv_cache",))
 def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                    positions: jnp.ndarray, slot_ids: jnp.ndarray,
@@ -1094,7 +1098,8 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                    q_lens: jnp.ndarray, meta: jnp.ndarray,
                    blk_seq: jnp.ndarray, last_rows: jnp.ndarray,
                    kv_cache: list, ad: jnp.ndarray | None = None, *,
-                   ragged_blk: int = 8, attn_impl: str = "reference"):
+                   ragged_blk: int = 8, attn_impl: str = "reference",
+                   decode_rows: bool = True):
     """One MIXED prefill+decode step over a flat token stream.
 
     The phase-split engine runs prefill batches and decode steps as
@@ -1114,6 +1119,9 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     the reference path); last_rows: (B,) flat row of each sequence's last
     valid token, where the logits are taken (meaningful for decode rows
     and for a prompt's final chunk — exactly the prefill_chunk contract).
+    ``decode_rows=False`` (static): the stream holds prompts only (``meta``
+    is zero: the engine's packed batched prefill), and the attention is
+    built without its decode part.
 
     Semantics per row are exactly the cache-relative window semantics:
     each row's KV is written first, then the row attends its own
@@ -1144,7 +1152,8 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 row_lens, blk_seq, meta, ragged_blk, scale,
                 entry.get("ks"), entry.get("ks"), None, None,
                 scale_slices=(cfg.mla_kv_lora_rank,
-                              cfg.mla_qk_rope_head_dim))
+                              cfg.mla_qk_rope_head_dim),
+                decode_rows=decode_rows)
             out = _mla_unabsorb(out, lp, cfg)
             out = out.reshape(T, cfg.num_heads * cfg.mla_v_head_dim)
             h = h + _attn_residual(out, lp, cfg, ad)
@@ -1162,12 +1171,13 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 q, ck, cv, block_tables, kv_lens, q_starts, q_lens,
                 meta, blk_seq, scale, blk_q=ragged_blk, k_scale=ks,
                 v_scale=vs, sliding_window=sw,
-                logit_softcap=cfg.attn_logit_softcapping)
+                logit_softcap=cfg.attn_logit_softcapping,
+                decode_rows=decode_rows)
         else:
             out = _ragged_reference_attn(
                 q, ck, cv, block_tables, row_seq, row_lens, blk_seq,
                 meta, ragged_blk, scale, ks, vs, sw,
-                cfg.attn_logit_softcapping)
+                cfg.attn_logit_softcapping, decode_rows=decode_rows)
         out = out.reshape(T, cfg.q_size)
         h = h + _attn_residual(out, lp, cfg, ad)
         h = h + _mlp_residual(h, lp, cfg, ad)

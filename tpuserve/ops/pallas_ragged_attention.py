@@ -19,9 +19,10 @@ tell every block what it is serving:
   top of the cached context.
 
 The host layout contract (engine._run_mixed): decode rows first, densely
-packed; each prefill chunk starts ``blk_q``-aligned; T is a power-of-two
-flat-token bucket — the ONE bucketed dimension that replaces the old
-(batch x length) grid.  int8-KV dequant-in-VMEM and sliding-window
+packed; each prefill chunk starts ``blk_q``-aligned; T is a flat-token
+bucket, any multiple of ``blk_q`` (mixed steps take powers of two, packed
+batched prefills a finer ladder) — the ONE bucketed dimension that
+replaces the old (batch x length) grid.  int8-KV dequant-in-VMEM and sliding-window
 page-skip carry over from both parent kernels unchanged.
 
 Semantics match ``tpuserve.ops.attention.ragged_attention``; verified
@@ -45,6 +46,10 @@ from tpuserve.ops.pallas_paged_attention import (TARGET_GROUP_ROWS,
                                                  _clamp_to_vmem_budget,
                                                  _scale_rows,
                                                  compiler_params)
+
+#: what the kernel's custom call is called in a profiler trace (the HLO
+#: instruction's name), as the decode and flash kernels have theirs
+KERNEL_NAME = "_ragged_paged_attention"
 
 NEG_INF = -1e30
 
@@ -79,10 +84,13 @@ def _ragged_kernel(bt_ref, kv_ref, qs_ref, ql_ref, meta_ref, bseq_ref,
                    q_ref, k_hbm, v_hbm, o_ref, k_scr, v_scr, sems, *,
                    scale, page_size, pages_g, num_kv_heads, group,
                    head_dim, blk_q, ks_hbm=None, vs_hbm=None, ks_scr=None,
-                   vs_scr=None, sliding_window=None, logit_softcap=None):
+                   vs_scr=None, sliding_window=None, logit_softcap=None,
+                   decode_rows=True):
     """``ks_hbm``/``vs_hbm`` present = int8 cache (pages DMA as int8 with
     per-page scale blocks, dequantized in VMEM).  ``sliding_window``
-    (static): out-of-window pages are never DMA'd, in both parts."""
+    (static): out-of-window pages are never DMA'd, in both parts.
+    ``decode_rows`` (static) False: the caller dispatches no decode rows
+    (``meta`` is zero), and the kernel is built without its decode part."""
     quantized = ks_hbm is not None
     p = pl.program_id(0)
     B = kv_ref.shape[0]
@@ -133,7 +141,6 @@ def _ragged_kernel(bt_ref, kv_ref, qs_ref, ql_ref, meta_ref, bseq_ref,
 
     # ---- decode part: blk_q one-row sequences, flat row == sequence ----
 
-    @pl.when(p < n_dec_blocks)
     def _decode_part():
         base = p * blk_q
 
@@ -240,6 +247,9 @@ def _ragged_kernel(bt_ref, kv_ref, qs_ref, ql_ref, meta_ref, bseq_ref,
 
         jax.lax.fori_loop(0, blk_q, seq_body, 0)
 
+    if decode_rows:
+        pl.when(p < n_dec_blocks)(_decode_part)
+
     # ---- prefill part: one sequence's blk_q-row chunk window ----------
 
     @pl.when((p >= n_dec_blocks) & (bseq_ref[p] >= 0))
@@ -328,7 +338,8 @@ def _ragged_kernel(bt_ref, kv_ref, qs_ref, ql_ref, meta_ref, bseq_ref,
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "blk_q",
                                              "pages_per_group",
                                              "sliding_window",
-                                             "logit_softcap"))
+                                             "logit_softcap",
+                                             "decode_rows"))
 def ragged_paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                            v_cache: jnp.ndarray, block_tables: jnp.ndarray,
                            kv_lens: jnp.ndarray, q_starts: jnp.ndarray,
@@ -340,8 +351,8 @@ def ragged_paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                            k_scale: jnp.ndarray | None = None,
                            v_scale: jnp.ndarray | None = None,
                            sliding_window: int | None = None,
-                           logit_softcap: float | None = None
-                           ) -> jnp.ndarray:
+                           logit_softcap: float | None = None,
+                           decode_rows: bool = True) -> jnp.ndarray:
     """q: (T, Hq, D) flat mixed token stream; k_cache/v_cache: (num_blocks,
     page, Hkv, D); block_tables: (B, max_pages) per SEQUENCE; kv_lens /
     q_starts / q_lens: (B,) per-sequence descriptors (cached tokens
@@ -357,6 +368,11 @@ def ragged_paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     and descriptor padding rows are UNSPECIFIED in the output (fully
     masked programs produce zeros; skipped padding blocks write nothing)
     — the engine's last-row gather never reads them.
+
+    ``decode_rows=False`` (static) is the caller's word that ``meta`` is
+    zero — a packed batched prefill — and builds the kernel without its
+    decode part: half the kernel to trace, lower and compile for each
+    rung of that route's ladder.
     """
     T, Hq, D = q.shape
     num_blocks, page_size, Hkv, _ = k_cache.shape
@@ -390,7 +406,8 @@ def ragged_paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     kernel = functools.partial(
         _ragged_kernel, scale=scale, page_size=page_size, pages_g=pages_g,
         num_kv_heads=Hkv, group=group, head_dim=D, blk_q=blk,
-        sliding_window=sliding_window, logit_softcap=logit_softcap)
+        sliding_window=sliding_window, logit_softcap=logit_softcap,
+        decode_rows=decode_rows)
     if quantized:
         base_kernel = kernel
 
@@ -433,5 +450,6 @@ def ragged_paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(block_tables, kv_lens, q_starts, q_lens, meta, blk_seq,
       q, k_cache, v_cache, *scales)

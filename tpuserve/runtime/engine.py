@@ -29,7 +29,7 @@ import numpy as np
 from tpuserve.models import transformer
 from tpuserve.models.config import ModelConfig, get_model_config
 from tpuserve.models.tokenizer import IncrementalDetokenizer, load_tokenizer
-from tpuserve.models.weights import load_or_init
+from tpuserve.models.weights import load_or_init, param_dtype
 from tpuserve.ops import sampling as sampling_ops
 from tpuserve.ops.attention import PAD_SLOT
 from tpuserve.runtime.block_manager import BlockManager, create_block_manager
@@ -37,7 +37,8 @@ from tpuserve.runtime.hostprof import PROF
 from tpuserve.runtime.kv_cache import CacheConfig, create_kv_cache
 from tpuserve.runtime.request import (
     FinishReason, Request, RequestOutput, RequestState, SamplingParams, check_stop)
-from tpuserve.runtime.scheduler import ScheduledBatch, Scheduler, SchedulerConfig
+from tpuserve.runtime.scheduler import (
+    ScheduledBatch, Scheduler, SchedulerConfig, packed_prefill_bucket)
 from tpuserve.runtime.slo import (
     ShedError, SloConfig, SloController, class_rank)
 from tpuserve.utils import env_flag, next_power_of_2
@@ -231,6 +232,13 @@ class EngineStats:
     step_ctx_tokens: int = 0
     padded_tokens_total: int = 0
     actual_tokens_total: int = 0
+    # the same pair over batched-prefill and prefill-chunk dispatches
+    # alone (decode pads little, so the sum above hides what prefill
+    # bucketing costs), and how many batched prefills went out packed on
+    # one flat token axis (Engine._packed_prefill)
+    prefill_tokens_total: int = 0
+    prefill_padded_tokens_total: int = 0
+    prefill_packed_steps: int = 0
     prompt_tokens: int = 0
     generated_tokens: int = 0
     preemptions: int = 0
@@ -564,6 +572,24 @@ class Engine:
         from tpuserve.ops.pallas_ragged_attention import ragged_block
         self._ragged_blk = ragged_block()
         self._ragged_seqs = next_power_of_2(sched_cfg.max_num_seqs)
+        # Packed batched prefill: a prefill batch laid out on ONE flat
+        # token axis through the same ragged trunk (zero decode rows),
+        # bucketed on T alone, instead of a (power-of-two batch x
+        # power-of-two length) grid that dispatches about twice the
+        # tokens it was given.  Taken where the ragged kernel gives the
+        # (B, L) route's result at speed, from what the engine observes
+        # (no option): not under a mesh (the kernel has no tp wrapper —
+        # which also covers multi-host), not on the pipeline engine, not
+        # for MLA (the (B, L) route attends the decompressed fresh K/V)
+        # and only with pages in the model's own dtype — the (B, L)
+        # route attends the FRESH K/V, and reading them back from int8 or
+        # narrower pages is a different result, not a faster one.  The
+        # descriptors have ONE fixed width for these dispatches too.
+        self._packed_prefill = (
+            mesh is None and self._pp == 1 and jax.process_count() == 1
+            and not self.model_cfg.is_mla
+            and jnp.dtype(self.cache_cfg.dtype) == param_dtype(self.model_cfg))
+        self._prefill_seqs = next_power_of_2(sched_cfg.max_prefill_seqs)
         # Pallas-under-tp runs the phase-split kernels via shard_map
         # (ops/pallas_tp.py); the ragged kernel has no tp wrapper yet, so
         # mixed steps fall back to the reference ragged attention there
@@ -1837,7 +1863,7 @@ class Engine:
         self._restores.clear()
 
     def _note_step_tokens(self, actual: int, padded: int,
-                          ctx_tokens: int) -> None:
+                          ctx_tokens: int, prefill: bool = False) -> None:
         """Record one dispatch's real vs padded token counts (the
         padding-waste observability behind the
         ``tpuserve_step_padded/actual_tokens`` gauges) — ONE home so the
@@ -1845,7 +1871,12 @@ class Engine:
         the work at the attention kernel's boundary: the context the
         dispatch's real rows attend, summed (``seq_lens`` for decode,
         window — at its first step — and verify; context + chunk length
-        for prefill, chunk and mixed), from host-known integers."""
+        for prefill, chunk and mixed), from host-known integers.
+        ``prefill``: a batched-prefill or prefill-chunk dispatch, counted
+        in the prefill-only pair as well."""
+        if prefill:
+            self.stats.prefill_tokens_total += actual
+            self.stats.prefill_padded_tokens_total += padded
         self.stats.step_actual_tokens = actual
         self.stats.step_padded_tokens = padded
         self.stats.step_ctx_tokens = ctx_tokens
@@ -2097,20 +2128,26 @@ class Engine:
 
     def _exec_forward_ragged(self, tokens, positions, slot_ids, row_seq,
                              block_tables, kv_lens, q_starts, q_lens,
-                             meta, blk_seq, last_rows, ad=None):
-        self.faults.check("mixed_dispatch", self._dispatch_rids)
-        # mixed batching is gated single-process/non-pp in __init__, so
-        # no coordinator wraps this hook; it exists for the AST coverage
-        # test's "no direct transformer calls" line (_exec_decode_verify
-        # precedent).  No mesh arg: under tp _ragged_attn is forced to
-        # "reference" (the ragged kernel has no shard_map wrapper yet)
-        # and GSPMD partitions the reference einsums on its own.
-        with self.devprof.dispatch("mixed", (tuple(tokens.shape),)):
+                             meta, blk_seq, last_rows, ad=None, *,
+                             kind="mixed"):
+        # ``kind``: "mixed", or "prefill" for a packed batched prefill
+        # (_run_prefill) — its fault site and dispatch span stay prefill's,
+        # and its program is built without the decode rows' part
+        self.faults.check(kind + "_dispatch", self._dispatch_rids)
+        # mixed batching and packed prefill are gated single-process/
+        # non-pp in __init__, so no coordinator wraps this hook; it exists
+        # for the AST coverage test's "no direct transformer calls" line
+        # (_exec_decode_verify precedent).  No mesh arg: under tp
+        # _ragged_attn is forced to "reference" (the ragged kernel has no
+        # shard_map wrapper yet) and GSPMD partitions the reference
+        # einsums on its own.
+        with self.devprof.dispatch(kind, (tuple(tokens.shape),)):
             return transformer.forward_ragged(
                 self.params, self.model_cfg, tokens, positions, slot_ids,
                 row_seq, block_tables, kv_lens, q_starts, q_lens, meta,
                 blk_seq, last_rows, self.kv_cache, ad,
-                ragged_blk=self._ragged_blk, attn_impl=self._ragged_attn)
+                ragged_blk=self._ragged_blk, attn_impl=self._ragged_attn,
+                decode_rows=kind != "prefill")
 
     def _exec_sample(self, logits, keys, temperature, top_k, top_p, *,
                      min_p=None, mode):
@@ -2125,38 +2162,72 @@ class Engine:
     # ---- prefill ------------------------------------------------------
 
     def _run_prefill(self, batch: ScheduledBatch) -> list[RequestOutput]:
+        """One batched prefill.  The batch is what ``_schedule_prefill``
+        admitted either way; the LAYOUT is one of two.  On the packed
+        route (``self._packed_prefill``) the prompts lie on one flat token
+        axis, each starting on a ragged-block boundary, and run through
+        the ragged trunk with zero decode rows at the next rung of a fine
+        ladder of token counts (scheduler.packed_prefill_bucket); a
+        prefix-cache hit starts at its cached offset, as in _run_mixed.
+        Elsewhere they run as a (power-of-two batch) x (power-of-two
+        length of the longest) grid over the fresh K/V."""
         reqs = batch.requests
         self._dispatch_rids = tuple(r.request_id for r in reqs)
         self._step_kind = "prefill"
-        L = batch.padded_len
-        B = next_power_of_2(len(reqs))
-        tokens = np.zeros((B, L), np.int32)
-        slot_ids = np.full((B, L), PAD_SLOT, np.int32)
-        prompt_lens = np.ones((B,), np.int32)
+        packed = self._packed_prefill
+        chunks = []                       # packed: (req, ids, done, take)
+        if not packed:
+            L = batch.padded_len
+            B = next_power_of_2(len(reqs))
+            tokens = np.zeros((B, L), np.int32)
+            slot_ids = np.full((B, L), PAD_SLOT, np.int32)
+            prompt_lens = np.ones((B,), np.int32)
         for i, req in enumerate(reqs):
             ids = self._prefill_tokens(req)
             self.faults.check("kv_alloc", (req.request_id,))
-            shared, _cached = self.block_manager.lookup_prefix(ids)
+            shared, cached = self.block_manager.lookup_prefix(ids)
             self.block_manager.allocate(req.request_id, ids, shared_blocks=shared)
             self._drop_superseded_tier_entries(ids)
-            tokens[i, :len(ids)] = ids
-            prompt_lens[i] = len(ids)
-            slot_ids[i, :len(ids)] = self._token_slots(req.request_id, 0,
-                                                       len(ids))
+            if packed:
+                # the shared blocks hold the cached tokens' KV already —
+                # or, for a prefix first seen in THIS batch, get it from
+                # this same dispatch: every layer writes all rows' K/V
+                # before its attention reads a page
+                chunks.append((req, ids, cached, len(ids) - cached))
+            else:
+                tokens[i, :len(ids)] = ids
+                prompt_lens[i] = len(ids)
+                slot_ids[i, :len(ids)] = self._token_slots(
+                    req.request_id, 0, len(ids))
             if self._flight_on:
                 self.flight.req_event(req.request_id, "PREFILL",
                                       tokens=len(ids),
                                       replay=bool(req.output_token_ids))
-        kw = self._lora_kw(reqs, B)
+        if packed:
+            B = self._prefill_seqs
+            blk = self._ragged_blk
+            arrays, kw = self._pack_ragged(
+                [], None, chunks, lambda rows: packed_prefill_bucket(rows, blk), B)
+            n_tok = sum(c[3] for c in chunks)
+            padded = len(arrays[0])
+            ctx_tok = sum(len(c[1]) for c in chunks)
+        else:
+            kw = self._lora_kw(reqs, B)
+            n_tok = ctx_tok = int(prompt_lens[:len(reqs)].sum())
+            padded = B * L
         self._demote_evicted()
         with PROF.phase("dispatch"):
-            logits, self.kv_cache = self._exec_prefill(
-                jnp.asarray(tokens), jnp.asarray(prompt_lens),
-                jnp.asarray(slot_ids), **kw)
+            if packed:
+                logits, self.kv_cache = self._exec_forward_ragged(
+                    *map(jnp.asarray, arrays), **kw, kind="prefill")
+            else:
+                logits, self.kv_cache = self._exec_prefill(
+                    jnp.asarray(tokens), jnp.asarray(prompt_lens),
+                    jnp.asarray(slot_ids), **kw)
         self.scheduler.mark_running(reqs)
         self.stats.num_prefill_steps += 1
-        n_tok = int(prompt_lens[:len(reqs)].sum())
-        self._note_step_tokens(n_tok, B * L, n_tok)
+        self.stats.prefill_packed_steps += packed
+        self._note_step_tokens(n_tok, padded, ctx_tok, prefill=True)
         new_tokens = self._sample(logits, reqs, B)
         now = self.clock.monotonic()
         for req in reqs:
@@ -2232,7 +2303,7 @@ class Engine:
                 jnp.asarray(slot_ids), jnp.asarray(block_tables), **kw)
         req.num_prefilled = done + n
         self.stats.num_prefill_steps += 1
-        self._note_step_tokens(n, C, done + n)
+        self._note_step_tokens(n, C, done + n, prefill=True)
         if req.num_prefilled < len(ids):
             # more chunks to go: back to the head of the queue
             self.scheduler.waiting.appendleft(req)
@@ -2247,6 +2318,91 @@ class Engine:
         return self._append_and_emit([req], new_tokens, from_prefill=True)
 
     # ---- mixed ragged prefill+decode ----------------------------------
+
+    def _pack_ragged(self, decode_reqs: list, slots, chunks: list, bucket,
+                     B: int) -> tuple:
+        """Lay one ragged dispatch out as ONE flat token stream — the host
+        side of the Pallas kernel's layout contract
+        (ops/pallas_ragged_attention.py), shared by mixed steps and packed
+        batched prefills: ``decode_reqs``' rows first, densely packed
+        (flat row == sequence index; ``slots`` are their fresh cache
+        slots), the decode region padded to the ragged block, then each
+        of ``chunks`` ((req, ids, done, take): ``take`` prompt tokens from
+        offset ``done``) starting block-aligned, in order.  ``bucket``
+        maps the rows used to the dispatched T; ``B`` is the fixed
+        descriptor width.  Returns ``(arrays, kw)``: _exec_forward_ragged's
+        positional arguments as numpy arrays, and its ``ad`` keyword when
+        an adapter stack is loaded."""
+        blk = self._ragged_blk
+        n_dec = len(decode_reqs)
+        cursor = -(-n_dec // blk) * blk if n_dec else 0
+        n_dec_blocks = cursor // blk
+        starts = []
+        for _, _, _, take in chunks:
+            starts.append(cursor)
+            cursor += -(-take // blk) * blk
+        T = bucket(max(cursor, 1))
+        mb = self.cache_cfg.max_blocks_per_seq
+        tokens = np.zeros((T,), np.int32)
+        positions = np.zeros((T,), np.int32)
+        slot_ids = np.full((T,), PAD_SLOT, np.int32)
+        row_seq = np.zeros((T,), np.int32)
+        kv_lens = np.zeros((B,), np.int32)
+        q_starts = np.full((B,), T, np.int32)
+        q_lens = np.zeros((B,), np.int32)
+        last_rows = np.zeros((B,), np.int32)
+        block_tables = np.zeros((B, mb), np.int32)
+        for i, r in enumerate(decode_reqs):
+            nt = r.num_tokens
+            tokens[i] = r.output_token_ids[-1]
+            positions[i] = nt - 1
+            slot_ids[i] = slots[i]
+            row_seq[i] = i
+            kv_lens[i] = nt
+            q_starts[i] = i
+            q_lens[i] = 1
+            last_rows[i] = i
+        if decode_reqs:
+            if self._flight_on:
+                self.flight.req_event_many(
+                    tuple(r.request_id for r in decode_reqs), "WINDOW",
+                    steps=1, mixed=True)
+            self._bm_fill_tables(decode_reqs, block_tables)
+        blk_seq = np.full((T // blk,), -1, np.int32)
+        for si, ((req, ids, done, take), start) in enumerate(
+                zip(chunks, starts), start=n_dec):
+            rows = slice(start, start + take)
+            tokens[rows] = ids[done:done + take]
+            positions[rows] = done + np.arange(take)
+            bt = self.block_manager.block_table(req.request_id)
+            slot_ids[rows] = self._token_slots(req.request_id, done, take,
+                                               block_table=bt)
+            row_seq[rows] = si
+            kv_lens[si] = done + take
+            q_starts[si] = start
+            q_lens[si] = take
+            last_rows[si] = start + take - 1
+            block_tables[si, :len(bt)] = bt
+            blk_seq[start // blk:(start + -(-take // blk) * blk) // blk] = si
+        meta = np.asarray([n_dec, n_dec_blocks], np.int32)
+        kw = {}
+        if self._lora_names:
+            # per-ROW one-hot adapter weights: the ragged trunk applies
+            # LoRA on the flat (T, H) stream, so each VALID row carries
+            # its sequence's adapter; padding rows are filled explicitly
+            # all-zero (= base model) rather than gathered through
+            # row_seq, whose padding value of 0 would hand them sequence
+            # 0's adapter
+            ad_rows = np.zeros((T, len(self._lora_names)), np.float32)
+            for i, r in enumerate(decode_reqs):
+                if r.adapter_idx is not None:
+                    ad_rows[i, r.adapter_idx] = 1.0
+            for (req, _, _, take), start in zip(chunks, starts):
+                if req.adapter_idx is not None:
+                    ad_rows[start:start + take, req.adapter_idx] = 1.0
+            kw["ad"] = jnp.asarray(ad_rows)
+        return (tokens, positions, slot_ids, row_seq, block_tables, kv_lens,
+                q_starts, q_lens, meta, blk_seq, last_rows), kw
 
     def _run_mixed(self, batch: ScheduledBatch) -> list[RequestOutput]:
         """One ragged mixed step (scheduler mixed mode): every running
@@ -2322,85 +2478,16 @@ class Engine:
         # continuing ones so the sampled rows form a prefix
         comp = [c for c in chunks if c[2] + c[3] == len(c[1])]
         cont = [c for c in chunks if c[2] + c[3] < len(c[1])]
-        blk = self._ragged_blk
         n_dec = len(decode_reqs)
-        cursor = -(-n_dec // blk) * blk if n_dec else 0
-        n_dec_blocks = cursor // blk
-        starts = []
-        for _, _, _, take in comp + cont:
-            starts.append(cursor)
-            cursor += -(-take // blk) * blk
-        total_rows = max(cursor, 1)
-        T = max(next_power_of_2(total_rows), blk)
         B = self._ragged_seqs
-        mb = self.cache_cfg.max_blocks_per_seq
-        tokens = np.zeros((T,), np.int32)
-        positions = np.zeros((T,), np.int32)
-        slot_ids = np.full((T,), PAD_SLOT, np.int32)
-        row_seq = np.zeros((T,), np.int32)
-        kv_lens = np.zeros((B,), np.int32)
-        q_starts = np.full((B,), T, np.int32)
-        q_lens = np.zeros((B,), np.int32)
-        last_rows = np.zeros((B,), np.int32)
-        block_tables = np.zeros((B, mb), np.int32)
-        for i, r in enumerate(decode_reqs):
-            nt = r.num_tokens
-            tokens[i] = r.output_token_ids[-1]
-            positions[i] = nt - 1
-            slot_ids[i] = slots[i]
-            row_seq[i] = i
-            kv_lens[i] = nt
-            q_starts[i] = i
-            q_lens[i] = 1
-            last_rows[i] = i
-        if self._flight_on and decode_reqs:
-            self.flight.req_event_many(
-                tuple(r.request_id for r in decode_reqs), "WINDOW",
-                steps=1, mixed=True)
-        self._bm_fill_tables(decode_reqs, block_tables)
-        blk_seq = np.full((T // blk,), -1, np.int32)
-        for si, ((req, ids, done, take), start) in enumerate(
-                zip(comp + cont, starts), start=n_dec):
-            chunk = ids[done:done + take]
-            rows = slice(start, start + take)
-            tokens[rows] = chunk
-            positions[rows] = done + np.arange(take)
-            bt = self.block_manager.block_table(req.request_id)
-            slot_ids[rows] = self._token_slots(req.request_id, done, take,
-                                               block_table=bt)
-            row_seq[rows] = si
-            kv_lens[si] = done + take
-            q_starts[si] = start
-            q_lens[si] = take
-            last_rows[si] = start + take - 1
-            block_tables[si, :len(bt)] = bt
-            blk_seq[start // blk:(start + -(-take // blk) * blk) // blk] = si
-        meta = np.asarray([n_dec, n_dec_blocks], np.int32)
-        kw = {}
-        if self._lora_names:
-            # per-ROW one-hot adapter weights: the ragged trunk applies
-            # LoRA on the flat (T, H) stream, so each VALID row carries
-            # its sequence's adapter; padding rows are filled explicitly
-            # all-zero (= base model) rather than gathered through
-            # row_seq, whose padding value of 0 would hand them sequence
-            # 0's adapter
-            ad_rows = np.zeros((T, len(self._lora_names)), np.float32)
-            for i, r in enumerate(decode_reqs):
-                if r.adapter_idx is not None:
-                    ad_rows[i, r.adapter_idx] = 1.0
-            for (req, _, _, take), start in zip(comp + cont, starts):
-                if req.adapter_idx is not None:
-                    ad_rows[start:start + take, req.adapter_idx] = 1.0
-            kw["ad"] = jnp.asarray(ad_rows)
+        blk = self._ragged_blk
+        arrays, kw = self._pack_ragged(
+            decode_reqs, slots, comp + cont,
+            lambda rows: max(next_power_of_2(rows), blk), B)
         self._demote_evicted()
         with PROF.phase("dispatch"):
             logits, self.kv_cache = self._exec_forward_ragged(
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(slot_ids), jnp.asarray(row_seq),
-                jnp.asarray(block_tables), jnp.asarray(kv_lens),
-                jnp.asarray(q_starts), jnp.asarray(q_lens),
-                jnp.asarray(meta), jnp.asarray(blk_seq),
-                jnp.asarray(last_rows), **kw)
+                *map(jnp.asarray, arrays), **kw)
         self.stats.num_mixed_steps += 1
         if decode_reqs:
             self.stats.num_decode_steps += 1
@@ -2408,7 +2495,9 @@ class Engine:
             self.stats.num_prefill_steps += 1
         actual = n_dec + sum(c[3] for c in chunks)
         self._note_step_tokens(
-            actual, T, int(kv_lens[:n_dec + len(chunks)].sum()))
+            actual, len(arrays[0]),
+            sum(r.num_tokens for r in decode_reqs)
+            + sum(done + take for _, _, done, take in chunks))
         # bookkeeping: chunk progress, requeue continuations, promote
         # completions to running BEFORE sampling/emit (finish() removes
         # from running; same order as _run_prefill_chunk)
@@ -4028,7 +4117,10 @@ class Engine:
         """Pre-compile executables.  ``prefill_buckets`` entries are either a
         padded prompt length L (compiled at batch 1) or a ``(batch, L)`` pair
         — _run_prefill pads the batch to a power of two, so warming only
-        batch 1 leaves the multi-sequence prefill shapes cold.  An EMPTY
+        batch 1 leaves the multi-sequence prefill shapes cold.  An engine
+        on the packed route (``_packed_prefill``) dispatches none of them:
+        it warms, in their place, every rung of its flat-token ladder that
+        batches of those shapes can reach.  An EMPTY
         ``prefill_buckets`` list means "warm no batched prefill" (workloads
         routed entirely through chunked prefill); None means "not
         specified" and warms the minimum bucket.  ``chunk_buckets`` are
@@ -4069,7 +4161,24 @@ class Engine:
             # production size)
             prefill_buckets = []
             chunk_buckets = ()
-        mixed_buckets = list(mixed_buckets or ())
+        # ragged executables to warm: (flat tokens, descriptor width, kind)
+        ragged_warm = [(Tm, self._ragged_seqs, "mixed")
+                       for Tm in sorted(set(mixed_buckets or ()))]
+        if self._packed_prefill and prefill_buckets:
+            # Packed route: no (B, L) program is ever dispatched.  What
+            # replaces the caller's list is derivable from config, so the
+            # engine warms it itself: every rung of the flat-token ladder
+            # that a batch of those shapes can reach (B prompts of up to L
+            # tokens, each aligned to the ragged block), at the one
+            # descriptor width prefill dispatches use.
+            blk = self._ragged_blk
+            top = max(b * -(-n // blk) * blk for b, n in (
+                bk if isinstance(bk, tuple) else (1, bk)
+                for bk in prefill_buckets))
+            ragged_warm += [(t, self._prefill_seqs, "prefill") for t in sorted(
+                {packed_prefill_bucket(r, blk)
+                 for r in range(blk, top + 1, blk)})]
+            prefill_buckets = []
         decode_buckets = decode_buckets or [scfg.min_decode_bucket]
         logits = None
         # Two rounds: round 1 compiles each executable against the cache
@@ -4237,31 +4346,34 @@ class Engine:
                     tokens, jnp.zeros((1,), jnp.int32),
                     jnp.ones((1,), jnp.int32), slots, bt, **ckw)
                 self._warm_sampling(logits, sample_modes)
-            for Tm in sorted(set(mixed_buckets)):
-                # ragged mixed trunk: one executable per flat-token
-                # bucket (the whole point — no (batch x length) grid);
-                # left cold, the first admission-under-load mixed step
-                # stalls the loop on its compile
+            for Tm, Bm, kind in ragged_warm:
+                # ragged trunk (mixed steps, packed prefills): one
+                # executable per flat-token bucket (the whole point — no
+                # (batch x length) grid); left cold, the first
+                # admission-under-load step stalls the loop on its compile
                 blkm = self._ragged_blk
                 Tm = -(-Tm // blkm) * blkm
-                Bm = self._ragged_seqs
                 mbm = self.cache_cfg.max_blocks_per_seq
                 mkw = {}
                 if self._lora_names:
                     mkw["ad"] = jnp.zeros((Tm, len(self._lora_names)),
                                           jnp.float32)
+                # host arrays, as a served dispatch hands over: an eager
+                # jnp.full of each new shape is a small program of its
+                # own to load, three a rung
                 logits, self.kv_cache = self._exec_forward_ragged(
-                    jnp.zeros((Tm,), jnp.int32),
-                    jnp.zeros((Tm,), jnp.int32),
-                    jnp.full((Tm,), PAD_SLOT, jnp.int32),
-                    jnp.zeros((Tm,), jnp.int32),
-                    jnp.zeros((Bm, mbm), jnp.int32),
-                    jnp.zeros((Bm,), jnp.int32),
-                    jnp.full((Bm,), Tm, jnp.int32),
-                    jnp.zeros((Bm,), jnp.int32),
-                    jnp.zeros((2,), jnp.int32),
-                    jnp.full((Tm // blkm,), -1, jnp.int32),
-                    jnp.zeros((Bm,), jnp.int32), **mkw)
+                    *map(jnp.asarray, (
+                        np.zeros((Tm,), np.int32),
+                        np.zeros((Tm,), np.int32),
+                        np.full((Tm,), PAD_SLOT, np.int32),
+                        np.zeros((Tm,), np.int32),
+                        np.zeros((Bm, mbm), np.int32),
+                        np.zeros((Bm,), np.int32),
+                        np.full((Bm,), Tm, np.int32),
+                        np.zeros((Bm,), np.int32),
+                        np.zeros((2,), np.int32),
+                        np.full((Tm // blkm,), -1, np.int32),
+                        np.zeros((Bm,), np.int32))), **mkw, kind=kind)
                 self._warm_sampling(logits, sample_modes)
         if self._kv_tiers is not None:
             # tiered KV cache: the demote gather and restore scatter pad
@@ -4296,8 +4408,9 @@ class Engine:
         jax.block_until_ready((self.kv_cache, self._warm_tails))
         self._warm_tails.clear()
         self._read_demote_budget()
-        logger.info("warmup complete: prefill buckets %s, decode buckets %s",
-                    prefill_buckets, decode_buckets)
+        logger.info("warmup complete: prefill buckets %s, ragged buckets %s, "
+                    "decode buckets %s", prefill_buckets,
+                    [(kind, t) for t, _, kind in ragged_warm], decode_buckets)
 
     def _warm_sampling(self, logits: jnp.ndarray,
                        modes: Sequence[str]) -> None:

@@ -6,7 +6,9 @@ continuous-batching loop that, in the reference, lives inside the deployed
 vLLM container (reference: SURVEY.md §2.2; the repo itself has no scheduler).
 Prefill lengths and decode batch sizes are bucketed to powers of two so XLA
 compiles a small, reusable set of executables (static shapes — see
-SURVEY.md §7 "hard parts").
+SURVEY.md §7 "hard parts"); where the engine packs a prefill batch on one
+flat token axis, that axis is bucketed on a finer ladder
+(``packed_prefill_bucket``).
 """
 
 from __future__ import annotations
@@ -22,10 +24,34 @@ from tpuserve.runtime.slo import BATCH, class_rank
 from tpuserve.utils import env_flag, next_power_of_2
 
 
+def packed_prefill_bucket(rows: int, blk: int) -> int:
+    """Flat-token bucket T of a PACKED prefill dispatch (engine
+    ``_run_prefill`` on the single-chip route) holding ``rows`` block-aligned
+    rows: the next multiple of 2 blocks up to 16 blocks, of 8 up to 32, of
+    16 beyond — on the chip (128-row blocks) multiples of 256 up to 2,048,
+    of 1,024 up to 4,096, of 2,048 above: 13 rungs to a budget of 8,192 —
+    and one lone block for a short prompt.  A ladder, not a power of two:
+    T is the only dimension a packed dispatch varies, so a rung costs one
+    executable (one to two seconds of warm-up each at the benchmark's
+    sizes, which is what keeps the ladder this coarse above 2,048, where
+    few batches land).  Under a third of a dispatch is the ladder's padding."""
+    if rows <= blk:
+        return blk
+    step = (2 if rows <= 16 * blk else 8 if rows <= 32 * blk else 16) * blk
+    return -(-rows // step) * step
+
+
 @dataclasses.dataclass(frozen=True)
 class SchedulerConfig:
     max_num_seqs: int = 64              # decode batch capacity
-    max_prefill_tokens: int = 8192      # per-step prefill token budget
+    # Per-step prefill token budget, charged by admission as (power-of-2
+    # bucket of the batch's longest prompt) x (prompts picked) on every
+    # route (block_manager.admit_prefill).  Only the (batch x length)
+    # route — mesh, pipeline, multi-host, MLA and quantized/narrower-KV
+    # engines — also DISPATCHES that grid; the single-chip engine packs
+    # the same batch on one flat token axis (Engine._packed_prefill),
+    # whose ladder tops out at this budget.
+    max_prefill_tokens: int = 8192
     max_prefill_seqs: int = 8
     min_prefill_bucket: int = 32        # smallest padded prompt length
     min_decode_bucket: int = 4          # smallest padded decode batch
@@ -213,6 +239,12 @@ class Scheduler:
     # ---- policy ---------------------------------------------------------
 
     def prefill_bucket(self, n: int) -> int:
+        """Power-of-two length bucket of an ``n``-token prompt: what
+        admission charges against ``max_prefill_tokens`` on every route,
+        the padded length of a chunk-route tail, and — on the (batch x
+        length) route only (see ``max_prefill_tokens``) — the L a batched
+        prefill is dispatched at.  A packed batched prefill is bucketed by
+        ``packed_prefill_bucket`` instead."""
         return max(next_power_of_2(n), self.cfg.min_prefill_bucket)
 
     def _chunk_bucket(self, remaining: int) -> int:

@@ -151,6 +151,20 @@ class ServerMetrics:
         self.actual_tokens_total = counter(
             "tpuserve_actual_tokens_total",
             "Cumulative real tokens computed across all engine steps")
+        self.prefill_tokens_total = counter(
+            "tpuserve_prefill_tokens_total",
+            "Real prompt tokens computed by batched-prefill and "
+            "prefill-chunk dispatches (mixed steps excluded)")
+        self.prefill_padded_tokens_total = counter(
+            "tpuserve_prefill_padded_tokens_total",
+            "Token slots those dispatches occupied, padding included: "
+            "over tpuserve_prefill_tokens_total, what prefill bucketing "
+            "costs (decode pads little, so the all-steps pair hides it)")
+        self.prefill_packed_steps = counter(
+            "tpuserve_prefill_packed_steps_total",
+            "Batched prefills dispatched packed on one flat token axis "
+            "through the ragged trunk (single chip, no mesh, pages in the "
+            "model's dtype) rather than as a (batch x length) grid")
         self.mixed_steps = counter(
             "tpuserve_mixed_steps",
             "Ragged mixed prefill+decode dispatches (scheduler mixed "

@@ -996,6 +996,12 @@ class AsyncEngineRunner:
                                   self.metrics.padded_tokens_total),
                                  ("actual_tokens_total",
                                   self.metrics.actual_tokens_total),
+                                 ("prefill_tokens_total",
+                                  self.metrics.prefill_tokens_total),
+                                 ("prefill_padded_tokens_total",
+                                  self.metrics.prefill_padded_tokens_total),
+                                 ("prefill_packed_steps",
+                                  self.metrics.prefill_packed_steps),
                                  ("num_mixed_steps",
                                   self.metrics.mixed_steps),
                                  ("kv_demoted_blocks",
