@@ -317,7 +317,7 @@ STORM_POLICY = PolicyConfig(min_replicas=1, max_replicas=3,
 def _storm(n=28):
     # sized down for the 870s tier-1 budget: still ~2x oversubscribes
     # one 2-seat replica (L3 reached without scaling); the full n=80
-    # storm lives in the slow-marked A/B + bench --autoscale-replay
+    # storm lives in the slow-marked A/B
     return make_storm_workload(n=n, ramp_s=3.0, span_s=6.0,
                                max_tokens=16)
 
@@ -408,7 +408,7 @@ def test_pool_replay_scale_from_zero_with_warm_prefix(tmp_path):
 
 
 # ---------------------------------------------------------------------
-# slow: the full storm A/B (the bench.py --autoscale-replay shape)
+# slow: the full storm A/B
 # ---------------------------------------------------------------------
 
 @pytest.mark.slow

@@ -1,17 +1,14 @@
 """Device telemetry (runtime/devprof.py): per-dispatch attribution,
-executable-ladder registry, HBM watermark reconciliation, profiler
-capture, and the TPUSERVE_DEVPROF=0 removal pin.
+executable-ladder registry, HBM watermark reconciliation and profiler
+capture.
 
 One module-scoped server/engine serves every HTTP test (the tier-1
 wall budget is tight — no per-test engine builds); the module arms
 TPUSERVE_STRICT_BLOCKS so the block-manager view the HBM watermark
-reconciles against is itself cross-checked every cycle.  The <1%
-interleaved overhead soak is slow-marked — tier-1 covers the removal
-semantics and the disabled path's no-op contract instead."""
+reconciles against is itself cross-checked every cycle."""
 
 import json
 import os
-import time
 import urllib.error
 import urllib.request
 
@@ -19,8 +16,6 @@ import pytest
 
 from tpuserve.runtime import (CacheConfig, Engine, EngineConfig,
                               SamplingParams, SchedulerConfig)
-from tpuserve.runtime.devprof import DeviceProfiler
-from tpuserve.runtime.hostprof import NOOP, PROF
 from tpuserve.server.openai_api import OpenAIServer, ServerConfig
 
 PARAMS = SamplingParams(max_tokens=8, temperature=0.0, ignore_eos=True)
@@ -89,7 +84,7 @@ def test_step_records_carry_device_attribution(server):
     # a window step's flush blocked on the device: device_ms is real
     assert any(d.get("device_ms", 0) > 0 for d in devs)
     dp = snap["devprof"]
-    assert dp["enabled"] and dp["cycles"] > 0
+    assert dp["cycles"] > 0
     assert dp["device_ms_per_cycle"] >= 0
     # per-kind split: the served request prefetched and flushed windows
     assert {"prefill", "decode_multi"} & set(dp["dispatch"])
@@ -104,7 +99,6 @@ def test_ladder_registry_correctness(server):
     srv, url, _, eng = server
     _serve_one(url)
     dp = eng.devprof
-    assert dp.enabled
     # one ladder entry per compile, by construction
     assert dp.compiles == len(dp.ladder) > 0
     assert dp.compile_s > 0
@@ -142,7 +136,6 @@ def test_debug_engine_surfaces_compile_cache_stats(server):
     for k in ("hits", "misses", "disk_hits", "size"):
         assert isinstance(caches["fsm"][k], int)
     lad = caches["ladder"]
-    assert lad["tracked"] is True
     assert lad["misses"] == eng.devprof.compiles > 0
     assert lad["size"] == len(eng.devprof.ladder)
     # prior tests re-served warm shapes: hits outnumber compiles
@@ -213,115 +206,3 @@ def test_profile_capture_busy_is_409(server):
         assert ei.value.code == 409
     finally:
         tracing._capture_lock.release()
-
-
-# ---- removal pin (same-commit A/B) --------------------------------------
-
-def test_devprof_disabled_is_removed_byte_identical():
-    """TPUSERVE_DEVPROF=0 / EngineConfig(devprof=False): greedy token
-    streams are byte-identical to the devprof-on engine, the flight
-    handle is None (step records carry no dev field), and every bracket
-    feeds nothing of devprof's: the span is hostprof's alone, and the
-    shared no-op once hostprof is off too (the --no-devprof off arm)."""
-    def _mk(devprof):
-        return Engine(EngineConfig(
-            model="tiny-qwen3",
-            cache=CacheConfig(block_size=4, num_blocks=32,
-                              max_blocks_per_seq=8),
-            scheduler=SchedulerConfig(max_num_seqs=4, min_prefill_bucket=8,
-                                      min_decode_bucket=2),
-            multi_step=4, seed=0, devprof=devprof))
-
-    prompts = [[1, 2, 3, 4], [5, 6, 7]]
-    on = _mk(True)
-    on_toks = [r.output_token_ids for r in on.generate(prompts, PARAMS)]
-    off = _mk(False)
-    assert not off.devprof.enabled
-    assert off.flight.devprof is None, \
-        "disabled devprof must unhook from the flight recorder"
-    with off.devprof.dispatch("decode", ((1, 1),)), \
-            off.devprof.sync("window"):
-        pass
-    assert not off.devprof.sync_s and not off.devprof.dispatch_s
-    was, PROF.enabled = PROF.enabled, False
-    try:
-        assert off.devprof.dispatch("decode", ((1, 1),)) is NOOP
-        assert off.devprof.sync("window") is NOOP
-    finally:
-        PROF.enabled = was
-    off_toks = [r.output_token_ids for r in off.generate(prompts, PARAMS)]
-    assert on_toks == off_toks, \
-        "TPUSERVE_DEVPROF=0 changed greedy token streams"
-    # removed means REMOVED: no cycles, no ladder, no step deltas
-    assert off.devprof.cycles == 0 and not off.devprof.ladder
-    snap = off.flight.engine_snapshot()
-    assert "devprof" not in snap
-    assert all("dev" not in s for s in snap["steps"])
-    # ...while the ON engine recorded the same workload's attribution
-    assert on.devprof.cycles > 0 and on.devprof.ladder
-
-
-def test_env_flag_resolution(monkeypatch):
-    """TPUSERVE_DEVPROF is the env twin of --no-devprof: default on,
-    =0 off, EngineConfig field wins over the env."""
-    monkeypatch.delenv("TPUSERVE_DEVPROF", raising=False)
-    assert DeviceProfiler().enabled
-    monkeypatch.setenv("TPUSERVE_DEVPROF", "0")
-    assert not DeviceProfiler().enabled
-    assert DeviceProfiler(enabled=True).enabled
-    monkeypatch.setenv("TPUSERVE_DEVPROF", "1")
-    assert not DeviceProfiler(enabled=False).enabled
-
-
-# ---- overhead guard (slow: the 256-stream soak) -------------------------
-
-@pytest.mark.slow
-def test_interleaved_overhead_guard_256_stream_soak():
-    """--recorder-ab-style guard: interleaved on/off pairs over a
-    256-stream soak on the SAME warm engine, devprof toggled into the
-    exact TPUSERVE_DEVPROF=0 state per arm; median rates must agree
-    within the 1% contract (bench.py --devprof runs the same guard on
-    capture hardware)."""
-    import numpy as np
-    from tpuserve.runtime.slo import SloConfig
-    rng = np.random.default_rng(7)
-    eng = Engine(EngineConfig(
-        model="tiny-qwen3",
-        cache=CacheConfig(block_size=4, num_blocks=512,
-                          max_blocks_per_seq=16),
-        scheduler=SchedulerConfig(max_num_seqs=32, max_waiting=512,
-                                  min_prefill_bucket=8,
-                                  min_decode_bucket=2),
-        # the soak measures instrumentation cost, not overload policy:
-        # a deliberately deep queue with the brownout ladder disarmed
-        # (256 one-shot submissions would otherwise shed at level 4)
-        slo=SloConfig(target_queue_delay_s=1e6),
-        multi_step=8, seed=0))
-    prompts = [[int(x) for x in rng.integers(1, 500, size=8)]
-               for _ in range(256)]
-    params = SamplingParams(max_tokens=8, temperature=0.0, ignore_eos=True)
-    eng.generate(prompts[:32], params)          # warm every bucket
-
-    def _set(enabled):
-        eng.devprof.enabled = enabled
-        eng.flight.devprof = eng.devprof if enabled else None
-
-    def _run():
-        t0 = time.perf_counter()
-        out = eng.generate(prompts, params)
-        wall = time.perf_counter() - t0
-        return sum(len(r.output_token_ids) for r in out) / wall
-
-    on_rates, off_rates = [], []
-    for _ in range(3):
-        _set(True)
-        on_rates.append(_run())
-        _set(False)
-        off_rates.append(_run())
-    _set(True)
-    on_med = sorted(on_rates)[1]
-    off_med = sorted(off_rates)[1]
-    overhead = 1.0 - on_med / off_med
-    assert overhead < 0.01, (
-        f"devprof costs {overhead:.1%} tok/s on the 256-stream soak "
-        f"(on {on_med:.0f} vs off {off_med:.0f}; budget <1%)")

@@ -18,7 +18,6 @@ import pytest
 
 from tpuserve.runtime import (CacheConfig, Engine, EngineConfig,
                               SamplingParams, SchedulerConfig)
-from tpuserve.runtime.hostprof import PROF
 
 TIME_LIMIT_S = 240
 ROOT = "engine.step"
@@ -81,7 +80,6 @@ def traced(tmp_path_factory):
         scheduler=SchedulerConfig(max_num_seqs=4, max_prefill_tokens=256,
                                   min_prefill_bucket=8, min_decode_bucket=2),
         enable_prefix_caching=True, kv_tiers=True, multi_step=4))
-    assert PROF.enabled, "the flight recorder turns the spans on"
     prompts = [list(range(2, 26)), [7] * 13]
     churn = [[100 + i] * 40 for i in range(3)]
     eng.generate(prompts, PARAMS)            # compile outside the trace

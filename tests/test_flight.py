@@ -231,30 +231,55 @@ def test_unknown_request_404(server):
     assert ei.value.code == 404
 
 
-def test_recorder_disabled_is_removed():
-    """TPUSERVE_FLIGHT=0 / EngineConfig(flight=False): no events, no
-    step records, no scheduler/slo hooks — the --recorder-ab off arm."""
+@pytest.fixture(scope="module")
+def idle_server():
+    """A server that has served nothing yet: the recorder is there from
+    construction, not from the first request."""
     eng = Engine(EngineConfig(
         model="tiny-qwen3",
         cache=CacheConfig(block_size=4, num_blocks=32,
                           max_blocks_per_seq=8),
         scheduler=SchedulerConfig(max_num_seqs=4, min_prefill_bucket=8,
-                                  min_decode_bucket=2),
-        flight=False))
-    assert not eng.flight.enabled
-    assert eng.scheduler.flight is None
-    # max_tokens=1: the first token samples during prefill, so the test
-    # pays ONE compile (tier-1 wall budget is tight)
-    eng.generate([[1, 2, 3]], SamplingParams(max_tokens=1, temperature=0.0,
-                                             ignore_eos=True))
-    snap = eng.flight.engine_snapshot()
-    assert snap["events_recorded"] == 0 and snap["steps_recorded"] == 0
-    assert eng.flight.postmortem("test") is None
+                                  min_decode_bucket=2)))
+    srv = OpenAIServer(eng, ServerConfig(host="127.0.0.1", port=0))
+    port = srv.start()
+    yield f"http://127.0.0.1:{port}"
+    srv.shutdown()
+
+
+@pytest.mark.parametrize("endpoint,status", [
+    ("/debug/engine", 200),
+    ("/debug/engine/dump", 200),
+    ("/debug/requests/never-sent", 404),
+])
+def test_debug_endpoints_always_answer(idle_server, endpoint, status):
+    """There is no "recorder disabled" answer left: before the first
+    request the snapshot and the dump are whole (empty rings, devprof's
+    section, the engine's facts) and only an unknown request id is a
+    404."""
+    try:
+        got, body = _get(idle_server + endpoint)
+    except urllib.error.HTTPError as e:
+        got, body = e.code, json.loads(e.read())
+    assert got == status
+    if endpoint == "/debug/engine":
+        assert "enabled" not in body and "enabled" not in body["devprof"]
+        assert body["requests"] == []
+        assert {"steps", "sli", "control", "devprof",
+                "compile_caches"} <= set(body)
+        assert "tracked" not in body["compile_caches"]["ladder"]
+    elif endpoint == "/debug/engine/dump":
+        assert body["engine"]["model"] == "tiny-qwen3"
+        assert body["requests"] == {} and "devprof" in body
+        assert not body["rings"]["events"]["torn"]
+    else:
+        assert "never-sent" in body["error"]["message"]
+        assert "disabled" not in body["error"]["message"]
 
 
 def test_event_ring_bounded():
     from tpuserve.runtime.flight import FlightRecorder
-    fr = FlightRecorder(enabled=True, events=16, steps=4)
+    fr = FlightRecorder(events=16, steps=4)
     for i in range(100):
         fr.req_event(f"r{i}", "QUEUED")
     snap = fr.engine_snapshot()
@@ -343,20 +368,6 @@ def test_grafana_dashboard_configmap_validates():
     data = objs[0]["data"]["tpuserve-engine.json"]
     dash = json.loads(data)
     assert dash["uid"] == "tpuserve-engine" and dash["panels"]
-
-
-def test_flight_env_wiring_in_manifests():
-    from tpuserve.provision.config import DeployConfig
-    from tpuserve.provision.manifests import engine_deployment
-    on = engine_deployment(DeployConfig())
-    env = {e["name"]: e.get("value")
-           for e in on["spec"]["template"]["spec"]["containers"][0]["env"]}
-    assert env.get("TPUSERVE_FLIGHT_DIR") == "/models/.flight"
-    assert "TPUSERVE_FLIGHT" not in env        # default: always-on
-    off = engine_deployment(DeployConfig(flight=False))
-    env = {e["name"]: e.get("value")
-           for e in off["spec"]["template"]["spec"]["containers"][0]["env"]}
-    assert env.get("TPUSERVE_FLIGHT") == "0"
 
 
 # ---- traceparent propagation (ISSUE 9 satellite: gateway span) ---------

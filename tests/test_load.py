@@ -9,8 +9,8 @@ framing, exactly max_tokens chunks, a finish_reason, and the [DONE]
 terminator.  Greedy streams for the SAME prompt must also be identical
 across clients — continuous batching must not leak tokens across requests.
 
-The throughput side (aggregate tok/s vs engine-only, HTTP overhead) is
-measured by tools/load_test.py, which appends to bench_results.md.
+Throughput is the benchmark's to measure (benchmark/run.py), not this
+test's.
 """
 
 import json

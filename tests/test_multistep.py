@@ -633,7 +633,7 @@ def test_adaptive_hold_expires_back_to_full_windows():
 
 def test_adaptive_idle_burst_keeps_full_windows():
     # burst admission into an IDLE engine must not trip latency mode:
-    # the headline burst bench keeps its full-window throughput
+    # a closed-loop burst keeps its full windows
     eng = _engine(multi_step=8, min_multi_step=2)
     p = SamplingParams(max_tokens=8, temperature=0.0, ignore_eos=True)
     for pr in PROMPTS:

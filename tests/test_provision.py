@@ -367,13 +367,43 @@ def test_autoscale_config_validation():
         _cfg(autoscale=True, disaggregated=True)
     with pytest.raises(ValueError, match="multihost"):
         _cfg(autoscale=True, tensor_parallel=8)
-    # the policy is blind without the SLO scalars / recorder SLIs
+    # the policy is blind without the SLO scalars
     with pytest.raises(ValueError, match="slo_classes"):
         _cfg(autoscale=True, slo_classes=False)
-    with pytest.raises(ValueError, match="flight"):
-        _cfg(autoscale=True, flight=False)
     # same knobs are inert without autoscale
     assert _cfg(autoscale_min_replicas=9).autoscale is False
+
+
+@pytest.mark.parametrize("topology,kw", [
+    ("single-engine", {}),
+    ("multihost-statefulset", {"tensor_parallel": 8}),
+    ("disaggregated", {"disaggregated": True}),
+    ("autoscaled-pool", {"autoscale": True}),
+])
+def test_no_manifest_can_turn_measurement_off(topology, kw):
+    """The flight recorder and devprof have no off state: no DeployConfig
+    field names them, no container of any topology carries a variable
+    that would, and every engine container still gets the bundle
+    directory on the model PVC."""
+    import dataclasses
+    fields = {f.name for f in dataclasses.fields(DeployConfig)}
+    assert not fields & {"flight", "devprof"}
+    with pytest.raises(ValueError, match="unknown"):
+        _cfg(flight=False, **kw)
+    cfg = _cfg(**kw)
+    engines = 0
+    for obj in manifests.serving_manifests(cfg):
+        pod = obj.get("spec", {}).get("template", {}).get("spec", {})
+        for c in pod.get("containers", []) + pod.get("initContainers", []):
+            env = {e["name"]: e.get("value") for e in c.get("env", [])}
+            assert not set(env) & {"TPUSERVE_FLIGHT", "TPUSERVE_DEVPROF"}, \
+                (obj["metadata"]["name"], c["name"])
+            assert "--no-devprof" not in c.get("command", []) \
+                + c.get("args", [])
+            if "TPUSERVE_FLIGHT_DIR" in env:
+                engines += 1
+                assert env["TPUSERVE_FLIGHT_DIR"] == "/models/.flight"
+    assert engines >= 1, f"{topology}: no engine container rendered"
 
 
 def test_engine_deployment_tpu_resources_and_probes():

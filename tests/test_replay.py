@@ -133,7 +133,7 @@ def test_workload_schema_guards():
 
 
 def test_bundle_schema_guards():
-    fr = FlightRecorder(enabled=True, events=64, steps=16)
+    fr = FlightRecorder(events=64, steps=16)
     fr.req_event("r1", "QUEUED", slo_class="standard", prompt_tokens=4,
                  max_tokens=3)
     fr.req_event("r1", "FINISHED", cause="length", output_tokens=3)
@@ -154,7 +154,7 @@ def test_truncated_ring_is_reported_not_silently_shrunk():
     that lost their QUEUED event surface as meta.truncated, so replay
     extraction reports a shorter-than-reality workload instead of
     synthesizing one quietly."""
-    fr = FlightRecorder(enabled=True, events=8, steps=4)
+    fr = FlightRecorder(events=8, steps=4)
     for i in range(12):      # overflow the 8-slot ring
         fr.req_event(f"r{i}", "QUEUED", slo_class="standard",
                      prompt_tokens=4, max_tokens=2)

@@ -832,7 +832,7 @@ def test_fault_site_registry_matches_engine():
 P6_PRODUCER = """
     class FlightRecorder:
         def engine_snapshot(self):
-            return {"enabled": True, "engines": [], "sli": {},
+            return {"engines": [], "sli": {},
                     "control": dict(self._control),
                     "cold_start_s": None,
                     "queue_delay_ewma": {}}
@@ -1132,24 +1132,6 @@ def test_p7_shipping_slo_burn_is_reachable():
     assert "TPUSERVE_SLO_BURN" not in env_on
 
 
-def test_p7_shipping_devprof_is_reachable():
-    """ISSUE 16 wiring pin: TPUSERVE_DEVPROF is backed by
-    DeployConfig.devprof (P7's DeployConfig-field legitimization path)
-    and the manifests emit the kill switch only when devprof=False —
-    the always-on default ships no env var."""
-    import dataclasses as _dc
-    from tpuserve.provision.config import DeployConfig
-    from tpuserve.provision.manifests import _engine_container
-    assert any(f.name == "devprof" for f in _dc.fields(DeployConfig))
-    cfg = DeployConfig(provider="local", devprof=False)
-    env = {e["name"]: e.get("value")
-           for e in _engine_container(cfg)["env"]}
-    assert env.get("TPUSERVE_DEVPROF") == "0"
-    cfg_on = DeployConfig(provider="local")
-    env_on = {e["name"] for e in _engine_container(cfg_on)["env"]}
-    assert "TPUSERVE_DEVPROF" not in env_on
-
-
 def test_p5_devprof_families_registered_and_documented(metric_registry):
     """ISSUE 16 (P5 both directions): the device-telemetry families are
     in the parsed registry with the right kinds AND in README's metric
@@ -1202,8 +1184,8 @@ def test_json_findings_carry_pass_and_suppressible():
 
 
 def test_suppression_honored_in_disk_loaded_files(tmp_path):
-    """P6/P7 anchor findings in files they load from disk (tools/,
-    bench.py) — a reasoned per-line tag there must suppress exactly like
+    """P6/P7 anchor findings in files they load from disk (tools/)
+    — a reasoned per-line tag there must suppress exactly like
     in the lint set, or the documented escape hatch is a lie."""
     tools = tmp_path / "tools"
     tools.mkdir()
@@ -1230,7 +1212,7 @@ def test_suppression_honored_in_disk_loaded_files(tmp_path):
 
 
 def test_p7_tools_read_does_not_mask_engine_unreachability():
-    """A var read in BOTH bench/tools and tpuserve/ is judged by its
+    """A var read in BOTH tools/ and tpuserve/ is judged by its
     engine-side site — a tools read (sorted first) must not swallow the
     reachability rule."""
     read = 'import os\nX = os.environ.get("TPUSERVE_GHOST_KNOB")\n'

@@ -7,9 +7,8 @@ design*, not of Python:
 - ``host-sync`` (P1): host synchronization (``jax.device_get`` /
   ``np.asarray`` / ``.item()`` / traced truthiness) inside jit/scan bodies
   and inside the pipelined dispatch path.  The fused-window pipeline's
-  one-sync-per-S-tokens property (bench_r03_tpu.jsonl, older code: S=1
-  810 -> S=32 4,210 tok/s/chip) is one stray sync away from silently
-  degrading 5x.
+  one-sync-per-S-tokens property is one stray sync away from silently
+  degrading.
 - ``thread-ownership`` (P2): engine-loop-owned state mutated from
   watchdog / gateway / health threads — the exact cross-thread bug class
   fixed by hand after PR 3's review.

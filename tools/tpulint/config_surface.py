@@ -97,7 +97,7 @@ def run(files: dict, config: Config, repo_root: str) -> list:
     reads: dict = {}            # var -> first Site anywhere (doc rule)
     # var -> first Site under tpuserve/ — the reachability rule judges
     # engine-side reads specifically; keying off the first site found
-    # anywhere would let a bench.py/tools read (sorted earlier) mask an
+    # anywhere would let a tools/ read (sorted earlier) mask an
     # unreachable engine read of the same var
     tpu_reads: dict = {}
     flags_all: set = set()      # every argparse flag in scanned sources

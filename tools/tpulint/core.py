@@ -16,9 +16,9 @@ import os
 import re
 from typing import Optional
 
-# The fault-site registry shared with the engine and bench.py --faults
-# validation: one source of truth, so a site renamed in runtime/faults.py
-# breaks the lint fixture AND the bench flag in the same commit.
+# The fault-site registry shared with the engine: one source of truth,
+# so a site renamed in runtime/faults.py breaks the lint fixture in the
+# same commit.
 from tpuserve.runtime.faults import SITES as FAULT_SITES  # noqa: F401
 
 _SUPPRESS_RE = re.compile(
@@ -185,7 +185,7 @@ DEFAULT_CONFIG: dict = {
         # from the write-only dead-surface warning
         "operator_keys": [
             # /debug/engine ring bookkeeping + per-request detail
-            "enabled", "events_recorded", "steps_recorded", "requests",
+            "events_recorded", "steps_recorded", "requests",
             "steps", "postmortems", "last_postmortem",
             # SLI/controller scalars beyond what the autoscaler reads
             "n", "p50", "pressure",
@@ -271,7 +271,7 @@ DEFAULT_CONFIG: dict = {
         "provision_dir": "tpuserve/provision",
         "env_prefix": "TPUSERVE_",
         # env/flag read sites outside the default lint roots
-        "extra_paths": ["bench.py", "tools"],
+        "extra_paths": ["tools"],
         # operator-facing entrypoints whose every flag must be in the
         # README flag tables (both directions; tools keep their own
         # --help as documentation)
@@ -282,13 +282,13 @@ DEFAULT_CONFIG: dict = {
         # deliberately NOT part of the deploy config or README surface.
         # The reason string is the documentation.
         "env_debug_only": {
-            "TPUSERVE_HBM_BYTES": "test/bench HBM budget override",
-            "TPUSERVE_VMEM_BUDGET_MB": "kernel tuning (bench_sweep)",
-            "TPUSERVE_RAGGED_BLOCK": "kernel tuning (bench_sweep)",
-            "TPUSERVE_FLASH_BLK_Q": "kernel tuning (bench_sweep)",
-            "TPUSERVE_FLASH_BLK_K": "kernel tuning (bench_sweep)",
-            "TPUSERVE_SEQS_PER_PROGRAM": "kernel tuning (bench_sweep)",
-            "TPUSERVE_PAGES_PER_GROUP": "kernel tuning (bench_sweep)",
+            "TPUSERVE_HBM_BYTES": "test HBM budget override",
+            "TPUSERVE_VMEM_BUDGET_MB": "kernel tuning",
+            "TPUSERVE_RAGGED_BLOCK": "kernel tuning",
+            "TPUSERVE_FLASH_BLK_Q": "kernel tuning",
+            "TPUSERVE_FLASH_BLK_K": "kernel tuning",
+            "TPUSERVE_SEQS_PER_PROGRAM": "kernel tuning",
+            "TPUSERVE_PAGES_PER_GROUP": "kernel tuning",
             "TPUSERVE_FSM_MAX_STATES": "grammar-compile guard rail",
             "TPUSERVE_FSM_MAX_WALK_CHARS": "grammar-compile guard rail",
             "TPUSERVE_FSM_JSON_DEPTH": "grammar-compile guard rail",
@@ -528,7 +528,7 @@ def run_lint_sources(sources: dict, config: Config,
             findings.append(f)
     tag_for_pass = {name: mods[name].TAG for name in mods}
     # P6/P7 anchor findings in files they load from disk (tools/,
-    # bench.py, interface files outside the lint roots); their per-line
+    # interface files outside the lint roots); their per-line
     # suppressions must work there too, so pull in the source of any
     # finding-bearing file the lint set doesn't already hold.  Python
     # only: suppressions are Python comments, and scanning a

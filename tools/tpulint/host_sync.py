@@ -24,8 +24,7 @@ Three sync-discipline rules plus the fault-site registry check:
   (watchdog hang detection, client-side queue waits) carry a reasoned
   ``# tpulint: sync-ok(...)``.
 - ``unknown-fault-site``: a literal site name passed to
-  ``faults.check(...)`` that is not in ``tpuserve.runtime.faults.SITES``
-  (the same registry ``bench.py --faults`` validates against).
+  ``faults.check(...)`` that is not in ``tpuserve.runtime.faults.SITES``.
 """
 
 from __future__ import annotations

@@ -381,7 +381,7 @@ def headers_in(rel: str, tree: ast.Module, interesting) -> tuple:
 
 # ---- env vars + argparse flags (one cached walk) -------------------------
 
-# P7 scans EVERY source (tpuserve + tools + bench.py); one walk per tree
+# P7 scans EVERY source (tpuserve + tools); one walk per tree
 # per process, cached like func_index, keeps the added passes out of the
 # tier-1 wall-time budget.
 _ENV_FLAG_CACHE: dict = {}

@@ -147,7 +147,6 @@ def _build_pool_engine(workload: Workload, opts: PoolReplayOptions,
         kv_tiers=tiers,
         kv_host_bytes=opts.kv_host_bytes,
         kv_spill_dir=opts.kv_spill_dir,
-        flight=True,
         seed=seed,
         clock=clock))
 

@@ -30,8 +30,8 @@ Params = Any
 def param_nbytes(params) -> int:
     """Total bytes of a parameter pytree as actually materialized —
     quantized trees count their int8 values + scales, not the fp
-    estimate.  The one byte-count used by both the KV-cache auto-sizer
-    (Engine._auto_num_blocks) and the bench roofline (bench.py)."""
+    estimate.  The one byte-count used by the KV-cache auto-sizer
+    (Engine._auto_num_blocks), the model pool and the runner's gauges."""
     return sum(getattr(leaf, "nbytes", 0)
                for leaf in jax.tree_util.tree_leaves(params))
 

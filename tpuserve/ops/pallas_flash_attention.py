@@ -117,8 +117,8 @@ def flash_prefill_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     leaves the default (sweepable on silicon — prefill bounds TTFT); an
     explicit argument always wins so tests pin their shapes.  The env is
     read per PROCESS: serving jits this inside the engine's prefill
-    executable, so changing it mid-process is ignored — fresh-process
-    sweeps (tools/bench_sweep.py) pick it up."""
+    executable, so changing it mid-process is ignored — a sweep needs
+    a fresh process per value."""
     import os
     if blk_q is None:
         blk_q = int(os.environ.get("TPUSERVE_FLASH_BLK_Q") or 128)

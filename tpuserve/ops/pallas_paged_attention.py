@@ -38,7 +38,7 @@ never letting the HBM pipe drain (the cross-sequence prefetch above) and
 Semantics match ``tpuserve.ops.attention.paged_decode_attention``; verified
 against it in interpret mode on CPU.
 
-Sweepable knobs (bench_sweep drives them via env, static at trace time):
+Sweepable knobs (read from the environment, static at trace time):
 ``TPUSERVE_PAGES_PER_GROUP`` and ``TPUSERVE_SEQS_PER_PROGRAM``.
 """
 

@@ -18,8 +18,9 @@ ICI).  This module adds the cross-pod form:
 
 The wire format stages through host memory and rides the pod network (the
 DCN path); within a slice the in-process ICI handoff (parallel/disagg.py)
-is strictly cheaper, which is why it stays the default — ``bench.py
---compare-disagg`` records the difference.  Against the reference stack
+is strictly cheaper, which is why it stays the default (the difference
+is not measured on this code: no benchmark cell runs a disaggregated
+pair).  Against the reference stack
 this replaces the NIXL/NCCL KV connector inside vLLM/llm-d images
 (SURVEY.md §2.2 "Disaggregated prefill/decode + KV transfer").
 """

@@ -97,21 +97,12 @@ class DeployConfig:
     # TPUSERVE_SLO_BURN=0 to the engine pods (the env twin of the
     # server's --no-slo-burn).
     slo_burn: bool = True
-    # Engine flight recorder (runtime/flight.py): always-on lifecycle
-    # tracing + post-mortem bundles.  False exports TPUSERVE_FLIGHT=0
-    # (the measured-overhead A/B lever, bench.py --recorder-ab).
-    flight: bool = True
-    # Post-mortem bundle directory — on the model PVC next to the
-    # compile caches, so watchdog/fault-storm bundles survive the pod
-    # that wrote them (exported as TPUSERVE_FLIGHT_DIR).
+    # Post-mortem bundle directory of the engine flight recorder
+    # (runtime/flight.py) — on the model PVC next to the compile
+    # caches, so watchdog/fault-storm bundles and the profiler traces
+    # of runtime/devprof.py survive the pod that wrote them (exported
+    # as TPUSERVE_FLIGHT_DIR).
     flight_dir: str = "/models/.flight"
-    # Device telemetry (runtime/devprof.py): per-dispatch device-time
-    # attribution, the executable-ladder registry, HBM watermark gauges,
-    # and on-demand/auto jax.profiler capture.  False exports
-    # TPUSERVE_DEVPROF=0 (the env twin of --no-devprof; serving output
-    # is byte-identical either way — bench.py --devprof is the
-    # measured-overhead A/B lever).
-    devprof: bool = True
     # Hang watchdog threshold (server --step-watchdog-s): a dispatch
     # blocking past this is failed + salvaged like an exception instead
     # of stranding clients behind a wedged device call.  0 disables.
@@ -309,14 +300,13 @@ class DeployConfig:
                 raise ValueError(
                     "autoscale does not cover multihost StatefulSet "
                     "replicas (one replica = N pods there)")
-            if not self.slo_classes or not self.flight:
+            if not self.slo_classes:
                 # the policy's scale-out triggers ARE the SLO
-                # controller's scalars and the recorder's SLIs; a pool
-                # without them looks permanently idle to the scaler
+                # controller's scalars; a pool without them looks
+                # permanently idle to the scaler
                 raise ValueError(
                     "autoscale consumes the SLO controller's brownout/"
-                    "queue-delay scalars and the flight recorder's "
-                    "SLIs — it requires slo_classes and flight enabled")
+                    "queue-delay scalars — it requires slo_classes")
         # NOTE: the GCP-project requirement is enforced at provision time
         # (infra._provision_gke), not here — subcommands like `test` read
         # cluster identity from the inventory file and need no project.

@@ -214,20 +214,12 @@ def _engine_container(cfg: DeployConfig, *, role: Optional[str] = None,
         # kill switch for the in-process burn-rate evaluator (the env
         # twin of --no-slo-burn; default on)
         env.append({"name": "TPUSERVE_SLO_BURN", "value": "0"})
-    if not cfg.flight:
-        # kill switch for the engine flight recorder (the --recorder-ab
-        # measured-overhead lever; default on)
-        env.append({"name": "TPUSERVE_FLIGHT", "value": "0"})
-    elif cfg.flight_dir:
+    if cfg.flight_dir:
         # post-mortem bundles (watchdog trips, fault storms, poison
-        # isolation) land on the model PVC and survive the pod
+        # isolation) and profiler traces land on the model PVC and
+        # survive the pod
         env.append({"name": "TPUSERVE_FLIGHT_DIR",
                     "value": cfg.flight_dir})
-    if not cfg.devprof:
-        # kill switch for device telemetry (runtime/devprof.py; the
-        # bench.py --devprof measured-overhead lever; default on —
-        # profiler traces share flight_dir with the bundles)
-        env.append({"name": "TPUSERVE_DEVPROF", "value": "0"})
     if cfg.faults:
         # chaos drill: arm the engine's deterministic fault-injection
         # layer (runtime/faults.py) so recovery claims are verified

@@ -1,6 +1,6 @@
 """Trace-driven replay harness (ROADMAP item 5).
 
-Flight-recorder dumps, post-mortem bundles and bench traces convert
+Flight-recorder dumps and post-mortem bundles convert
 into portable, versioned workload files (``workload.py`` /
 ``extract.py``) that replay deterministically against the real engine
 in virtual time (``harness.py``) and report the same SLI families

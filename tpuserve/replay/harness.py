@@ -126,7 +126,6 @@ def build_replay_engine(workload: Workload, opts: ReplayOptions):
         multi_step=(opts.multi_step
                     or int(facts.get("multi_step") or 1)),
         slo_classes=opts.slo_classes,
-        flight=True,
         faults=workload.faults or "",
         seed=seed,
         clock=(clock := VirtualClock())))

@@ -13,9 +13,8 @@ file portable across models and hosts: the same file replays against
 the tiny CPU model in CI and against a real checkpoint on a chip.
 
 Sources: flight-recorder bundles (post-mortems and on-demand
-``/debug/engine/dump`` exports) via ``tpuserve/replay/extract.py``, and
-``bench.py --emit-trace`` (which also stores exact prompt token ids,
-since it has them).
+``/debug/engine/dump`` exports) via ``tpuserve/replay/extract.py``; a
+hand-written workload may also carry exact prompt token ids.
 
 Schema versioning is loud by design: a missing/foreign ``kind``, a
 missing ``schema_version``, or a version newer than this build refuses
@@ -60,7 +59,7 @@ class WorkloadRequest:
     # name), so prefix caching and tier restores engage like the incident
     prefix_group: Optional[str] = None
     prefix_tokens: int = 0
-    # exact ids when the source had them (bench traces); replay prefers
+    # exact ids when the source had them; replay prefers
     # these (modulo the target vocab) over synthesized ids
     prompt_token_ids: Optional[list] = None
     # terminal state observed at the source, for the replay report's
@@ -119,10 +118,10 @@ class Workload:
         recorded ids when the source had them (folded into the vocab),
         else ``prefix_tokens`` ids deterministic from the prefix group
         followed by ids deterministic from the request id.  Ids stay in
-        [1, vocab-2] like bench.py's generator (no specials)."""
+        [1, vocab-2] (no specials)."""
         hi = max(vocab_size - 2, 1)
         if req.prompt_token_ids:
-            # ids already in range pass through UNCHANGED (a bench trace
+            # ids already in range pass through UNCHANGED (a trace
             # replayed against its own model must send the recorded
             # prompts verbatim); only out-of-vocab ids fold
             return [int(t) if 1 <= int(t) <= hi else 1 + (int(t) % hi)

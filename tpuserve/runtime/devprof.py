@@ -38,12 +38,10 @@ the capability/cost signals heterogeneous routing wants (arxiv
 Cost contract: ``sync`` and ``dispatch`` are hostprof's one span
 primitive (``runtime/hostprof.py`` ``Span``, names ``sync.<kind>`` /
 ``dispatch.<kind>``, on the profiler's clock while a capture runs) with
-this module's accumulators added; with devprof off a bracket feeds
-hostprof alone, and with the flight recorder off too it is the shared
-no-op.  The cost on the chip is a measured number (PERF.md §6, PR 24).
-``TPUSERVE_DEVPROF=0`` / ``EngineConfig.devprof=False`` /
-``--no-devprof`` removes the layer with byte-identical serving
-behaviour: nothing here ever touches a jax array or changes a dispatch.
+this module's accumulators added.  The cost on the chip is a measured
+number (PERF.md §6, PR 24), and there is no off state: nothing here
+ever touches a jax array or changes a dispatch, and the benchmark's
+per-layer metrics read these sums and the ladder.
 
 Threading contract (the flight recorder's): every mutating call happens
 on the engine loop thread; serving threads read ``snapshot()`` copies
@@ -58,8 +56,7 @@ from collections import defaultdict
 from functools import partial
 from typing import Optional
 
-from tpuserve.runtime.hostprof import NOOP, PROF, Span
-from tpuserve.utils import env_flag
+from tpuserve.runtime.hostprof import PROF, Span
 
 #: bound the ladder table in snapshots/bundles: a pathological bucket
 #: explosion must not turn /debug/engine into a megabyte payload (the
@@ -68,16 +65,9 @@ MAX_LADDER_SNAPSHOT = 128
 
 
 class DeviceProfiler:
-    """Per-engine device telemetry accumulator (see module docstring).
+    """Per-engine device telemetry accumulator (see module docstring)."""
 
-    ``enabled=None`` resolves the ``TPUSERVE_DEVPROF`` env flag
-    (default on — the layer is meant to be always-on, like the flight
-    recorder it rides beside)."""
-
-    def __init__(self, enabled: Optional[bool] = None):
-        if enabled is None:
-            enabled = env_flag("TPUSERVE_DEVPROF")
-        self.enabled = bool(enabled)
+    def __init__(self):
         # host wall spent inside exec-hook brackets (async enqueue +
         # first-call compile), per dispatch kind
         self.dispatch_s: dict[str, float] = defaultdict(float)
@@ -117,8 +107,6 @@ class DeviceProfiler:
         """Span ``dispatch.<kind>`` around one async exec-hook call: host
         dispatch wall per kind, and the (kind, key) ladder entry."""
         name = "dispatch." + kind
-        if not self.enabled:
-            return PROF.phase(name)
         return Span(name, PROF.sinks(name)
                     + ((self.dispatch_s, self.dispatch_counts, kind),),
                     partial(self._note_dispatch, (kind, key)))
@@ -128,7 +116,7 @@ class DeviceProfiler:
         if ent is None:
             # first dispatch of this (kind, bucket): the blocking XLA
             # compile ran inside this bracket — that wall IS the
-            # compile cost (tools/profile_step.py measures the same way)
+            # compile cost
             self.ladder[lk] = [round(dt * 1000, 3), 1,
                                self.estimate_bytes(lk[1])]
             self.compiles += 1
@@ -141,14 +129,11 @@ class DeviceProfiler:
         the host blocked waiting for the device, per kind; every sync
         also feeds hostprof's ``flush``."""
         name = "sync." + kind
-        sinks = PROF.sinks(name, "flush")
-        if self.enabled:
-            sinks += ((self.sync_s, self.sync_counts, kind),)
-        return Span(name, sinks) if sinks else NOOP
+        return Span(name, PROF.sinks(name, "flush")
+                    + ((self.sync_s, self.sync_counts, kind),))
 
     def bump_cycle(self) -> None:
-        if self.enabled:
-            self.cycles += 1
+        self.cycles += 1
 
     # ---- facts (engine construction / capture paths) -------------------
 
@@ -250,11 +235,10 @@ class DeviceProfiler:
             "executables": rows,
         }
 
-    def report(self) -> dict:
-        """Machine-readable breakdown (bench.py --devprof rows,
-        /debug/engine, flight bundles): per-kind device/dispatch ms
-        totals and ms-per-cycle, ladder summary, HBM watermark,
-        recorded captures."""
+    def snapshot(self) -> dict:
+        """Machine-readable breakdown (/debug/engine, flight bundles):
+        per-kind device/dispatch ms totals and ms-per-cycle, ladder
+        summary, HBM watermark, recorded captures."""
         cycles = max(self.cycles, 1)
         device = {k: {"total_ms": round(v * 1000, 2),
                       "syncs": self.sync_counts[k]}
@@ -265,7 +249,6 @@ class DeviceProfiler:
         dev_total = sum(self.sync_s.values())
         disp_total = sum(self.dispatch_s.values())
         return {
-            "enabled": self.enabled,
             "cycles": self.cycles,
             "device_ms_per_cycle": round(1000 * dev_total / cycles, 4),
             "dispatch_ms_per_cycle": round(1000 * disp_total / cycles, 4),
@@ -275,18 +258,3 @@ class DeviceProfiler:
             "hbm": self.hbm_snapshot(),
             "captures": list(self.captures),
         }
-
-    # /debug/engine + bundle alias; report() is the bench-facing name
-    snapshot = report
-
-    def reset(self) -> None:
-        self.dispatch_s.clear()
-        self.dispatch_counts.clear()
-        self.sync_s.clear()
-        self.sync_counts.clear()
-        self.ladder.clear()
-        self.compiles = 0
-        self.compile_s = 0.0
-        self.cycles = 0
-        self._last_sync = self._last_dispatch = 0.0
-        self._last_compiles = 0

@@ -80,7 +80,7 @@ class EngineConfig:
     # Requests needing penalties or logprobs fall back to the sync path.
     # None = auto: on for TPU (async dispatch, real overlap), off for CPU
     # (synchronous backend — nothing overlaps, the extra dispatches only
-    # cost; measured 2.6x slower on the CPU smoke bench).
+    # cost).
     pipeline_decode: Optional[bool] = None
     # Sliding-window rolling buffer (Engine._release_window_blocks).
     # Disabled for disagg PREFILL engines: migration ships block_table()
@@ -97,17 +97,18 @@ class EngineConfig:
     # top-k/top-p truncation fall back to single-step.  None = auto: 32 on
     # TPU (dispatch latency amortised N-fold; decisive on multi-host
     # backends), 1 (off) on CPU where the synchronous backend gains
-    # little and tests expect per-token streaming.
+    # little and tests expect per-token streaming.  The window length
+    # is not measured on this code (ROADMAP.md A3).
     multi_step: Optional[int] = None
-    # Adaptive window sizing: a full multi_step window blocks admission for
-    # its whole duration, which was the dominant TTFT term under timed
-    # arrivals on older code (bench_r04_tpu.jsonl, poisson16 vs base; not
-    # measured on the current code).  When an arrival
-    # lands while decode is busy, subsequent windows shrink to
-    # ``min_multi_step`` for ``adaptive_window_hold_s`` seconds, bounding
-    # a new request's wait to one small window; burst workloads (arrivals
-    # into an idle engine) and arrival-free steady state keep the full
-    # window, so peak throughput is unaffected.
+    # Adaptive window sizing: a full multi_step window blocks admission
+    # for its whole duration.  What that costs a timed arrival's TTFT is
+    # not measured on this code (ROADMAP.md A3; no cell has timed
+    # arrivals yet).  When an arrival lands while decode is busy,
+    # subsequent windows shrink to ``min_multi_step`` for
+    # ``adaptive_window_hold_s`` seconds, bounding a new request's wait
+    # to one small window; burst workloads (arrivals into an idle
+    # engine) and arrival-free steady state keep the full window, so
+    # peak throughput is unaffected.
     adaptive_multi_step: bool = True
     min_multi_step: int = 4
     adaptive_window_hold_s: float = 0.5
@@ -147,26 +148,10 @@ class EngineConfig:
     # replay), and walk a hysteretic brownout ladder (spec off for
     # batch -> batch max_tokens cap -> shed) under sustained overload.
     # None = TPUSERVE_SLO_CLASSES env (default on; =0 restores classless
-    # FIFO byte-identically — the bench.py --two-class A/B lever).
+    # FIFO byte-identically).
     slo_classes: Optional[bool] = None
     # Brownout/estimator knobs; None = SloConfig() defaults.
     slo: Optional["SloConfig"] = None
-    # Engine flight recorder (runtime/flight.py): always-on ring of
-    # per-request lifecycle events + per-cycle step records, surfaced at
-    # /debug/requests/{id} and /debug/engine, exported as OTLP child
-    # spans, and dumped as post-mortem bundles on watchdog trips /
-    # fault storms / poison isolation.  None = TPUSERVE_FLIGHT env
-    # (default on; =0 removes the recorder byte-for-byte — the
-    # bench.py --recorder-ab overhead A/B lever).
-    flight: Optional[bool] = None
-    # Device telemetry (runtime/devprof.py): per-dispatch device-time
-    # attribution at the EXISTING designated sync points (zero new
-    # syncs), the (kind, bucket) executable-ladder registry, HBM
-    # watermark accounting behind the tpuserve_hbm_bytes gauges, and
-    # jax.profiler capture bookkeeping.  None = TPUSERVE_DEVPROF env
-    # (default on; =0 removes the layer byte-identically — the
-    # bench.py --devprof overhead A/B lever).
-    devprof: Optional[bool] = None
     # Injectable monotonic-time source (runtime/clock.py): None = the
     # shared real clock.  The trace-replay harness (tpuserve/replay/)
     # installs a VirtualClock here so recorded incidents re-run in
@@ -207,10 +192,9 @@ class EngineConfig:
         if self.multi_step is not None:
             return max(1, self.multi_step)
         # Each window ends in one host sync, so wider windows amortise the
-        # host round-trip (bench_r03_tpu.jsonl, older code: throughput
-        # rose with S up to 32; not measured on the current code);
-        # overrun waste (window_overrun_tokens) stays bounded by S-1 per
-        # finished sequence.
+        # host round-trip (32 is not measured on this code: ROADMAP.md
+        # A3); overrun waste (window_overrun_tokens) stays bounded by S-1
+        # per finished sequence.
         return 32 if jax.default_backend() == "tpu" else 1
 
 
@@ -326,9 +310,7 @@ class PendingWindow:
     """An in-flight fused multi-step window (pipelined): the (B, S) token
     block stays on device while the NEXT window is dispatched from its last
     column, so the host sync that ends every window overlaps the next
-    window's device time instead of serialising with it (that sync was
-    the decode floor on older code, bench_r03_tpu.jsonl; not measured on
-    the current code)."""
+    window's device time instead of serialising with it."""
     reqs: list
     toks: jax.Array                  # (B, S) int32, device-resident
     steps: int
@@ -626,7 +608,7 @@ class Engine:
         # class-aware preemption victims), and per cycle (estimator
         # tick).  TPUSERVE_SLO_CLASSES=0 / EngineConfig.slo_classes=False
         # leaves it None — every consumer degrades to classless FIFO
-        # byte-identically (the bench.py --two-class A/B lever).
+        # byte-identically.
         slo_on = config.slo_classes
         if slo_on is None:
             slo_on = env_flag("TPUSERVE_SLO_CLASSES")
@@ -637,13 +619,9 @@ class Engine:
         self.scheduler.slo = self._slo
         # Flight recorder (runtime/flight.py): always-on lifecycle ring
         # + per-cycle step records; single-writer from this engine's
-        # loop thread, snapshot reads from serving threads.  Hot-path
-        # emission sites gate on the cached bool so TPUSERVE_FLIGHT=0
-        # costs one attribute load per site (the --recorder-ab lever).
+        # loop thread, snapshot reads from serving threads.
         from tpuserve.runtime.flight import FlightRecorder
-        self.flight = FlightRecorder(enabled=config.flight,
-                                     clock=self.clock)
-        self._flight_on = self.flight.enabled
+        self.flight = FlightRecorder(clock=self.clock)
         # engine-shape facts ride every bundle so the replay harness
         # (tpuserve/replay/) can build a comparably-sized engine — an
         # incident replayed against twice the seats/blocks diffs
@@ -657,25 +635,18 @@ class Engine:
             mixed_batching=sched_cfg.mixed_batching,
             multi_step=config.resolve_multi_step(),
             slo_classes=bool(self._slo is not None))
-        self.scheduler.flight = self.flight if self._flight_on else None
+        self.scheduler.flight = self.flight
         if self._slo is not None:
-            self._slo.flight = self.flight if self._flight_on else None
-        if self._flight_on:
-            # hostprof goes always-on at low overhead (two perf_counter
-            # calls per phase) so every step record carries its
-            # schedule/block/dispatch/detokenize/flush breakdown
-            PROF.enabled = True
+            self._slo.flight = self.flight
         # Device telemetry (runtime/devprof.py): device-time attribution
         # at the existing sync points, the executable-ladder registry,
         # HBM watermark accounting and profiler-capture bookkeeping.
-        # Always-on by default like the recorder; the recorder handle
-        # lets note_step stamp per-step device-ms deltas and bundles
-        # carry the ladder/HBM/capture sections.  TPUSERVE_DEVPROF=0 /
-        # EngineConfig.devprof=False removes it byte-identically.
+        # Always on like the recorder; the recorder handle lets
+        # note_step stamp per-step device-ms deltas and bundles carry
+        # the ladder/HBM/capture sections.
         from tpuserve.runtime.devprof import DeviceProfiler
-        self.devprof = DeviceProfiler(enabled=config.devprof)
-        self.flight.devprof = (self.devprof if self.devprof.enabled
-                               else None)
+        self.devprof = DeviceProfiler()
+        self.flight.devprof = self.devprof
         self._step_kind = "idle"
         # terminal errors for QUEUED requests decided engine-side
         # (deadline expiry, queue-full class eviction): (rid, exc) pairs
@@ -694,10 +665,9 @@ class Engine:
         spec = (config.faults if config.faults is not None
                 else _os.environ.get("TPUSERVE_FAULTS"))
         self.faults = FaultInjector.from_spec(spec, seed=config.seed)
-        if self._flight_on:
-            # firing chaos rules land in the affected requests' timelines
-            # (post-mortems and salvage sequences become self-explanatory)
-            self.faults.on_fire = self.flight.fault_hook
+        # firing chaos rules land in the affected requests' timelines
+        # (post-mortems and salvage sequences become self-explanatory)
+        self.faults.on_fire = self.flight.fault_hook
         # Debug strict mode: cross-check block refcounts against live
         # requests after every successful step (block_manager.py
         # check_integrity) — the chaos/salvage tests run with it on, so
@@ -859,12 +829,10 @@ class Engine:
         # re-attach the replica-lifetime observability objects the
         # re-init replaced with fresh ones
         self.flight = flight
-        self._flight_on = flight.enabled
-        self.scheduler.flight = flight if self._flight_on else None
+        self.scheduler.flight = flight
         if self._slo is not None:
-            self._slo.flight = flight if self._flight_on else None
+            self._slo.flight = flight
         self.devprof = devprof
-        flight.devprof = devprof if devprof.enabled else None
         self.stats = stats
         flight.note_engine_facts(
             model=config.model,
@@ -882,10 +850,9 @@ class Engine:
             stats.model_swaps_by_outcome.get(source_tier, 0) + 1)
         stats.swap_latencies.append((source_tier, dt))
         del stats.swap_latencies[:-256]
-        if self._flight_on:
-            flight.req_event(f"swap:{old_model}->{config.model}", "SWAP",
-                             source_tier=source_tier,
-                             seconds=round(dt, 4))
+        flight.req_event(f"swap:{old_model}->{config.model}", "SWAP",
+                         source_tier=source_tier,
+                         seconds=round(dt, 4))
         logger.info("model swap %s -> %s (%s, %.2fs)", old_model,
                     config.model, source_tier, dt)
         return old_model, old_params
@@ -933,8 +900,6 @@ class Engine:
         and live in-use bytes from device memory_stats when the backend
         reports them (TPU does; CPU tests fall back to the
         weights+kv floor, making "other" zero there)."""
-        if not self.devprof.enabled:
-            return
 
         def _tree_bytes(tree) -> int:
             if tree is None:
@@ -1534,14 +1499,13 @@ class Engine:
         return outputs
 
     def _close_step(self, t_cycle: float) -> None:
-        if self._flight_on:
-            dispatched = bool(self._dispatch_rids)
-            self.flight.note_step(
-                self._step_kind, len(self._dispatch_rids),
-                self.stats.step_actual_tokens if dispatched else 0,
-                self.stats.step_padded_tokens if dispatched else 0,
-                self.clock.monotonic() - t_cycle,
-                ctx_tokens=self.stats.step_ctx_tokens if dispatched else 0)
+        dispatched = bool(self._dispatch_rids)
+        self.flight.note_step(
+            self._step_kind, len(self._dispatch_rids),
+            self.stats.step_actual_tokens if dispatched else 0,
+            self.stats.step_padded_tokens if dispatched else 0,
+            self.clock.monotonic() - t_cycle,
+            ctx_tokens=self.stats.step_ctx_tokens if dispatched else 0)
         if self._slo is not None:
             # estimator tick once per successful cycle (queue depth +
             # the EWMAs fed during scheduling) drives the brownout
@@ -1549,18 +1513,17 @@ class Engine:
             # tpuserve_brownout_level gauge
             self._slo.tick(self.scheduler.num_waiting)
             self.stats.brownout_level = self._slo.level
-        if self._flight_on:
-            # control-plane scalars for /debug/engine, dump bundles and
-            # the autoscaler's scrape: the level + per-class delay
-            # EWMAs as plain numbers (ISSUE 12 — consumers must not
-            # reconstruct these from histogram buckets).  waiting/
-            # running are scheduler facts published even with SLO
-            # classes off, so a pool observer is never blind to load.
-            self.flight.note_control(
-                **(self._slo.snapshot() if self._slo is not None
-                   else {"brownout_level": 0}),
-                waiting=self.scheduler.num_waiting,
-                running=len(self.scheduler.running))
+        # control-plane scalars for /debug/engine, dump bundles and
+        # the autoscaler's scrape: the level + per-class delay
+        # EWMAs as plain numbers (ISSUE 12 — consumers must not
+        # reconstruct these from histogram buckets).  waiting/
+        # running are scheduler facts published even with SLO
+        # classes off, so a pool observer is never blind to load.
+        self.flight.note_control(
+            **(self._slo.snapshot() if self._slo is not None
+               else {"brownout_level": 0}),
+            waiting=self.scheduler.num_waiting,
+            running=len(self.scheduler.running))
         if self._strict_blocks:
             self._check_block_integrity()
 
@@ -1940,8 +1903,7 @@ class Engine:
     # ONE manager crossing per operation kind per cycle (the native
     # manager makes each a single C++ call; the Python manager loops
     # internally) — TPUSERVE_HOST_BATCHED=0 keeps the historical
-    # per-request call pattern for A/B measurement (bench.py
-    # --clients-sweep).
+    # per-request call pattern.
 
     def _bm_decode_shortfall(self, reqs: list[Request]) -> int:
         with PROF.phase("block"):
@@ -2199,10 +2161,9 @@ class Engine:
                 prompt_lens[i] = len(ids)
                 slot_ids[i, :len(ids)] = self._token_slots(
                     req.request_id, 0, len(ids))
-            if self._flight_on:
-                self.flight.req_event(req.request_id, "PREFILL",
-                                      tokens=len(ids),
-                                      replay=bool(req.output_token_ids))
+            self.flight.req_event(req.request_id, "PREFILL",
+                                  tokens=len(ids),
+                                  replay=bool(req.output_token_ids))
         if packed:
             B = self._prefill_seqs
             blk = self._ragged_blk
@@ -2281,9 +2242,8 @@ class Engine:
         done = req.num_prefilled
         chunk = ids[done:done + C]
         n = len(chunk)
-        if self._flight_on:
-            self.flight.req_event(req.request_id, "PREFILL_CHUNK",
-                                  done=done, tokens=n, total=len(ids))
+        self.flight.req_event(req.request_id, "PREFILL_CHUNK",
+                              done=done, tokens=n, total=len(ids))
         tokens = np.zeros((1, C), np.int32)
         tokens[0, :n] = chunk
         slot_ids = np.full((1, C), PAD_SLOT, np.int32)
@@ -2363,10 +2323,9 @@ class Engine:
             q_lens[i] = 1
             last_rows[i] = i
         if decode_reqs:
-            if self._flight_on:
-                self.flight.req_event_many(
-                    tuple(r.request_id for r in decode_reqs), "WINDOW",
-                    steps=1, mixed=True)
+            self.flight.req_event_many(
+                tuple(r.request_id for r in decode_reqs), "WINDOW",
+                steps=1, mixed=True)
             self._bm_fill_tables(decode_reqs, block_tables)
         blk_seq = np.full((T // blk,), -1, np.int32)
         for si, ((req, ids, done, take), start) in enumerate(
@@ -2465,10 +2424,9 @@ class Engine:
             done = req.num_prefilled
             take = min(n, len(ids) - done)
             chunks.append((req, ids, done, take))
-            if self._flight_on:
-                self.flight.req_event(req.request_id, "PREFILL_CHUNK",
-                                      done=done, tokens=take,
-                                      total=len(ids), mixed=True)
+            self.flight.req_event(req.request_id, "PREFILL_CHUNK",
+                                  done=done, tokens=take,
+                                  total=len(ids), mixed=True)
         if not decode_reqs and not chunks:
             return outputs
         self._dispatch_rids = tuple(
@@ -2656,14 +2614,13 @@ class Engine:
                 # chained rows overwrite this with the device gstate via
                 # the same use_host/gather select as their input tokens
                 gstate_host[i] = gent[1]
-        if self._flight_on:
-            # recorded at DISPATCH (entered a fused window), so a fault
-            # at the flush still shows the window in the timeline;
-            # consumed tokens land in FINISHED.  One batched ring entry
-            # for the whole dispatch — per-row events cost tok/s at 256
-            # streams (--recorder-ab guard).
-            self.flight.req_event_many(self._dispatch_rids, "WINDOW",
-                                       steps=S)
+        # recorded at DISPATCH (entered a fused window), so a fault
+        # at the flush still shows the window in the timeline;
+        # consumed tokens land in FINISHED.  One batched ring entry
+        # for the whole dispatch, so the cost does not grow with
+        # the batch.
+        self.flight.req_event_many(self._dispatch_rids, "WINDOW",
+                                   steps=S)
         self._bm_fill_tables(reqs, block_tables)
         mode = ("greedy" if all(r.params.greedy for r in reqs)
                 else "temperature"
@@ -3039,9 +2996,8 @@ class Engine:
                 in_flight.add(req.request_id)
             positions[i] = nt - 1
             seq_lens[i] = nt
-        if self._flight_on:
-            self.flight.req_event_many(self._dispatch_rids, "WINDOW",
-                                       steps=1)
+        self.flight.req_event_many(self._dispatch_rids, "WINDOW",
+                                   steps=1)
         kw = self._lora_kw(reqs, B)
         self._demote_evicted()
         with PROF.phase("dispatch"):
@@ -3120,11 +3076,10 @@ class Engine:
             # verify window sits inside the reserved table
             slot_ids[i] = self._token_slots(r.request_id, base[i], K,
                                             block_table=block_tables[i])
-        if self._flight_on:
-            # spec verify window: K is the max per-row window; accepted
-            # counts surface in FINISHED/output deltas
-            self.flight.req_event_many(self._dispatch_rids, "WINDOW",
-                                       steps=K, spec=True)
+        # spec verify window: K is the max per-row window; accepted
+        # counts surface in FINISHED/output deltas
+        self.flight.req_event_many(self._dispatch_rids, "WINDOW",
+                                   steps=K, spec=True)
         sampled = not all(r.params.greedy for r in reqs)
         self._demote_evicted()
         accept_h = None
@@ -3391,8 +3346,7 @@ class Engine:
         without log archaeology.  FSM misses count full determinizing
         walks AND disk-cache loads (disk_hits is the subset the
         fleet-wide PVC cache absorbed); ladder misses are first-dispatch
-        compiles as attributed by devprof (tracked=False when
-        TPUSERVE_DEVPROF=0 leaves the ladder unobserved)."""
+        compiles as attributed by devprof."""
         dp = self.devprof
         return {
             "fsm": {"hits": self._fsm_stats["hits"],
@@ -3403,8 +3357,7 @@ class Engine:
                                    - dp.compiles),
                        "misses": dp.compiles,
                        "size": len(dp.ladder),
-                       "compile_ms": round(dp.compile_s * 1000.0, 3),
-                       "tracked": dp.enabled},
+                       "compile_ms": round(dp.compile_s * 1000.0, 3)},
         }
 
     def _fsm_device_tables(self, fsm):

@@ -12,9 +12,8 @@ layer the autoscaler (ROADMAP item 2) and the host-overhead work
   ADMITTED, RESTORING, PREFILL, PREFILL_CHUNK, WINDOW, PREEMPTED,
   SALVAGED, BROWNOUT_CLAMPED, SHED, FAULT, FINISHED-with-cause);
 - a fixed-size ring of per-cycle **step records** (dispatch kind, rows,
-  actual/padded flat tokens, wall ms, hostprof phase ms — the profiler
-  is flipped always-on when the recorder is enabled; its cost is two
-  ``perf_counter`` calls per phase);
+  actual/padded flat tokens, wall ms, hostprof phase ms, devprof's
+  device/dispatch/compile deltas);
 - per-SLO-class **SLI reservoirs** (client-observable TTFT/ITL/e2e,
   fed by the runner loop) behind the ``tpuserve_ttft/itl/e2e_seconds``
   histogram families and the brownout controller's transition logs;
@@ -37,9 +36,10 @@ Timestamps come from the injectable monotonic clock seam ONLY
 (runtime/clock.py — virtual under trace replay, the real clock in
 production; no wall-clock deltas, pinned by tests/test_flight.py) and no
 device syncs happen anywhere (tpulint P1 stays green: the recorder
-stores host-known ints/strs, never a jax array).
-``TPUSERVE_FLIGHT=0`` (or ``EngineConfig.flight=False``) removes it —
-the ``bench.py --recorder-ab`` overhead A/B lever.
+stores host-known ints/strs, never a jax array).  There is no off
+state: every per-layer metric of the benchmark, the autoscaler's scrape
+and the post-mortems read these records, and the cost of writing them
+was measured on the chip (PERF.md §6, PR 24).
 """
 
 from __future__ import annotations
@@ -52,7 +52,6 @@ from typing import Optional, Sequence
 
 from tpuserve.runtime.clock import MONOTONIC
 from tpuserve.runtime.hostprof import PROF
-from tpuserve.utils import env_flag
 
 logger = logging.getLogger("tpuserve.flight")
 
@@ -100,12 +99,8 @@ class _Ring:
 
 
 class FlightRecorder:
-    def __init__(self, enabled: Optional[bool] = None,
-                 events: int = 0, steps: int = 0,
+    def __init__(self, events: int = 0, steps: int = 0,
                  dirpath: Optional[str] = None, clock=None):
-        if enabled is None:
-            enabled = env_flag("TPUSERVE_FLIGHT")
-        self.enabled = bool(enabled)
         ev_n = events or int(os.environ.get("TPUSERVE_FLIGHT_EVENTS",
                                             0) or 8192)
         st_n = steps or int(os.environ.get("TPUSERVE_FLIGHT_STEPS",
@@ -138,10 +133,9 @@ class FlightRecorder:
         # because it outlives the engine across a model swap
         self.seq = 0
         # device telemetry handle (runtime/devprof.py): set by the OWNING
-        # engine when devprof is enabled; None keeps every record
-        # byte-identical to a devprof-less build (the TPUSERVE_DEVPROF=0
-        # removal pin).  Per-engine like the recorder itself — step
-        # records carry THIS engine's device deltas, not a process blur
+        # engine; None only for a recorder built on its own.  Per-engine
+        # like the recorder itself — step records carry THIS engine's
+        # device deltas, not a process blur
         self.devprof = None
         # client-observable SLI reservoirs: (class, kind) -> bounded ring
         self._sli: dict = {}
@@ -151,17 +145,15 @@ class FlightRecorder:
     # ---- writes (engine-loop thread) ----------------------------------
 
     def req_event(self, rid: str, event: str, **detail) -> None:
-        if not self.enabled:
-            return
         self._events.append((self._clock.monotonic(), rid, event,
                              detail or None))
 
     def req_event_many(self, rids: tuple, event: str, **detail) -> None:
         """Batched twin of :meth:`req_event` for per-dispatch events that
         cover every row (WINDOW): ONE timestamp, ONE ring entry, ONE
-        shared detail dict for the whole batch — at 256 streams the
-        per-row form measurably cost tok/s (the --recorder-ab guard)."""
-        if not self.enabled or not rids:
+        shared detail dict for the whole batch, so the recorder's cost
+        per dispatch does not grow with the batch."""
+        if not rids:
             return
         self._events.append((self._clock.monotonic(), tuple(rids), event,
                              detail or None))
@@ -171,8 +163,6 @@ class FlightRecorder:
         """FaultInjector.on_fire target: a firing chaos rule shows up in
         every affected request's timeline (post-mortems and the salvage
         sequence become self-explanatory)."""
-        if not self.enabled:
-            return
         t = self._clock.monotonic()
         for rid in rids or ("(engine)",):
             self._events.append((t, rid, "FAULT",
@@ -191,17 +181,13 @@ class FlightRecorder:
         lands in the NEXT record.  Exact for a one-engine process (the
         common case); multi-engine processes interleave and the
         attribution is approximate."""
-        if not self.enabled:
-            return
-        phases = None
-        if PROF.enabled:
-            cur = dict(PROF.seconds)
-            phases = {}
-            for k, v in cur.items():
-                d = v - self._prof_last.get(k, 0.0)
-                if d > 0:
-                    phases[k] = round(d * 1000, 4)
-            self._prof_last = cur
+        cur = dict(PROF.seconds)
+        phases = {}
+        for k, v in cur.items():
+            d = v - self._prof_last.get(k, 0.0)
+            if d > 0:
+                phases[k] = round(d * 1000, 4)
+        self._prof_last = cur
         dev = None
         if self.devprof is not None:
             # per-step device-ms / dispatch-ms / compile deltas, same
@@ -217,8 +203,7 @@ class FlightRecorder:
         what the replay harness needs to size a *comparable* engine —
         an overload incident replayed against a pool twice the size
         would diff meaninglessly.  Called once at engine construction;
-        cheap dict update, recorded even when disabled (facts are not
-        trace data)."""
+        cheap dict update."""
         self._facts.update({k: v for k, v in facts.items()
                             if v is not None})
 
@@ -230,8 +215,6 @@ class FlightRecorder:
         /debug/engine or a dump bundle) never reconstruct them from
         histogram buckets.  The dict is replaced atomically; readers on
         serving threads at worst see the previous cycle's values."""
-        if not self.enabled:
-            return
         self._control = scalars
 
     def note_sli(self, slo_class: str, kind: str, value: float) -> None:
@@ -240,8 +223,6 @@ class FlightRecorder:
         Mirrors what the tpuserve_{ttft,itl,e2e}_seconds histograms
         export, kept here so /debug/engine and the brownout transition
         logs can quote recent percentiles without scraping."""
-        if not self.enabled:
-            return
         ring = self._sli.get((slo_class, kind))
         if ring is None:
             ring = self._sli[(slo_class, kind)] = _Ring(256)
@@ -313,7 +294,6 @@ class FlightRecorder:
 
     def engine_snapshot(self, steps: int = 128) -> dict:
         out = {
-            "enabled": self.enabled,
             "events_recorded": self._events.idx,
             "steps_recorded": self._steps.idx,
             "requests": self.recent_request_ids(),
@@ -386,11 +366,11 @@ class FlightRecorder:
     def postmortem(self, reason: str, rids: Sequence[str] = (),
                    extra: Optional[dict] = None) -> Optional[str]:
         """Write the last N cycles + affected request timelines to a JSON
-        bundle and return its path (None when disabled, capped, or the
+        bundle and return its path (None when capped or the
         write fails — a post-mortem must never take serving down with
         it).  Callable from the watchdog thread while the engine loop is
         wedged: snapshot reads only."""
-        if not self.enabled or self.postmortems >= MAX_POSTMORTEMS:
+        if self.postmortems >= MAX_POSTMORTEMS:
             return None
         try:
             import tempfile

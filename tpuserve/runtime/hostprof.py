@@ -45,28 +45,22 @@ The span tree (where each opens -> what reads it):
       step.close      note_step, SLO tick, note_control, block check  idle.host_loop_share
 
 ``flush`` is not a span: it is the sum of the cycle's ``sync.*`` spans
-(every ``sync`` feeds it), kept because step records, ``profile_step``
-and the bench rows report it under that name.
+(every ``sync`` feeds it), kept because step records report it under
+that name (``phase_ms.flush``).
 
-Cost: off (``TPUSERVE_FLIGHT=0`` and devprof off) every site gets the
-shared no-op context manager; on (the default — building an engine with
-the flight recorder flips ``PROF.enabled``) a span costs one
-``TraceAnnotation`` and two ``perf_counter`` calls, measured on the chip
-in PERF.md §6 (PR 24).  The profiler is engine-loop single-threaded like
+Cost: a span is one ``TraceAnnotation`` and two ``perf_counter`` calls,
+always: there is no off state, and the cost was measured on the chip
+(PERF.md §6, PR 24).  The profiler is engine-loop single-threaded like
 everything it brackets; per-cycle deltas in multi-engine processes are
 approximate (FlightRecorder.note_step).
 """
 
 from __future__ import annotations
 
-import contextlib
 import time
 from collections import defaultdict
 
 from jax.profiler import TraceAnnotation
-
-#: what every site gets while nothing listens: shared, reusable, free
-NOOP = contextlib.nullcontext()
 
 
 class Span:
@@ -110,25 +104,19 @@ class HostPhaseProfiler:
     HOST_PHASES = ("schedule", "block", "detokenize")
 
     def __init__(self):
-        self.enabled = False
         self.seconds: dict[str, float] = defaultdict(float)
         self.counts: dict[str, int] = defaultdict(int)
         self.cycles = 0
 
     def sinks(self, *names) -> tuple:
-        """Accumulator triples for a span that feeds these names; empty
-        while disabled."""
-        if not self.enabled:
-            return ()
+        """Accumulator triples for a span that feeds these names."""
         return tuple((self.seconds, self.counts, n) for n in names)
 
     def phase(self, name: str, **args):
-        sinks = self.sinks(name)
-        return Span(name, sinks, **args) if sinks else NOOP
+        return Span(name, self.sinks(name), **args)
 
     def bump_cycle(self) -> None:
-        if self.enabled:
-            self.cycles += 1
+        self.cycles += 1
 
     def reset(self) -> None:
         self.seconds.clear()
@@ -136,10 +124,8 @@ class HostPhaseProfiler:
         self.cycles = 0
 
     def report(self) -> dict:
-        """Per-span breakdown: ms per engine cycle plus totals — the
-        machine-readable shape profile_step --json and the bench rows
-        emit (diffable across commits).  Spans nest, so the rows do not
-        add up to a cycle."""
+        """Per-span breakdown: ms per engine cycle plus totals.  Spans
+        nest, so the rows do not add up to a cycle."""
         cycles = max(self.cycles, 1)
         names = list(self.PHASES) + sorted(set(self.seconds)
                                            - set(self.PHASES))

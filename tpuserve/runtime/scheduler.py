@@ -123,9 +123,8 @@ class Scheduler:
         # Batched admission (one block_manager.admit_prefill call per
         # cycle — native when the C++ manager is loaded) vs the
         # historical inline per-candidate loop: TPUSERVE_HOST_BATCHED=0
-        # keeps the pre-batching path so the host-overhead A/B
-        # (bench.py --clients-sweep) measures what it claims on every
-        # phase, admission included.
+        # keeps the pre-batching path on every phase, admission
+        # included.
         self._batched_admission = env_flag("TPUSERVE_HOST_BATCHED")
         # Mixed mode: the engine pads the decode region and every prefill
         # chunk to this flat-row block (the ragged kernel's grid
@@ -145,11 +144,11 @@ class Scheduler:
         # below degrades byte-identically to the pre-SLO behaviour
         # (TPUSERVE_SLO_CLASSES=0, the same-commit A/B lever).
         self.slo = None
-        # Flight recorder (runtime/flight.py), set by the engine when
-        # enabled: admissions and preemptions are recorded HERE — the
-        # one place each decision is made — so every admission path
-        # (batched / chunked / mixed) and both preemption kinds emit
-        # identically.  None = no recording.
+        # Flight recorder (runtime/flight.py), set by the engine:
+        # admissions and preemptions are recorded HERE — the one place
+        # each decision is made — so every admission path (batched /
+        # chunked / mixed) and both preemption kinds emit identically.
+        # None only for a scheduler built without an engine.
         self.flight = None
         # Injectable time source (runtime/clock.py): the engine overwrites
         # this with ITS clock so queue-delay measurement replays in

@@ -35,8 +35,7 @@ Shedding answers with a clean retryable status *before* any prefill is
 spent; the alternative — unbounded queues — turns overload into
 timeout storms for every class at once.  The whole layer is behind the
 ``TPUSERVE_SLO_CLASSES`` kill switch (``=0`` restores classless FIFO
-byte-identically — the same-commit A/B lever ``bench.py --two-class``
-measures).
+byte-identically).
 """
 
 from __future__ import annotations
@@ -141,8 +140,9 @@ class SloController:
         # histograms (drained by server/runner.py on the same thread)
         self.delay_obs: list[tuple[str, float]] = []
         self.shed_total = 0            # mirrored into EngineStats
-        # flight recorder (runtime/flight.py), set by the engine when
-        # enabled: every ladder transition is logged against the
+        # flight recorder (runtime/flight.py), set by the engine (None
+        # only for a controller built without one): every ladder
+        # transition is logged against the
         # client-observable per-class SLIs the recorder holds, so a
         # brownout decision is auditable against what clients actually
         # experienced at that moment (not just the internal EWMAs)

@@ -638,7 +638,7 @@ class _GatewayHandler(BaseHTTPRequestHandler):
     ctx: Gateway
     protocol_version = "HTTP/1.1"
     # small chunked re-writes per relayed SSE event — same Nagle story as
-    # the engine server (tools/load_test.py)
+    # the engine server
     disable_nagle_algorithm = True
 
     def log_message(self, fmt, *args):

@@ -375,8 +375,7 @@ class ServerMetrics:
         # Device telemetry (runtime/devprof.py): the engine's own view of
         # device time, HBM occupancy, and the bucketed-executable ladder —
         # the step-time/HBM breakdowns the reference's DCGM-only GPU
-        # metrics never had (PARITY.md).  TPUSERVE_DEVPROF=0 leaves these
-        # families at zero.
+        # metrics never had (PARITY.md).
         self.hbm_bytes = Gauge(
             "tpuserve_hbm_bytes",
             "Per-device HBM watermark by kind= weights (loaded param "

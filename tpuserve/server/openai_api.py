@@ -565,7 +565,7 @@ class OpenAIServer:
 class _Handler(BaseHTTPRequestHandler):
     # TCP_NODELAY: per-token SSE events are small writes; Nagle holding
     # them for the delayed ACK adds ~40ms per decode step per stream
-    # under concurrent load (measured by tools/load_test.py).
+    # under concurrent load.
     disable_nagle_algorithm = True
     ctx: OpenAIServer
     protocol_version = "HTTP/1.1"
@@ -691,15 +691,11 @@ class _Handler(BaseHTTPRequestHandler):
             # facts + ring-integrity markers — so an operator can capture
             # an incident WITHOUT waiting for a watchdog/poison event.
             # Snapshot reads only; the engine keeps serving.
-            recorders = self._flight_recorders()
-            if not recorders:
-                self._error(404, "flight recorder disabled "
-                                 "(TPUSERVE_FLIGHT=0): nothing to dump")
-            else:
-                bundles = [fl.dump_bundle("on_demand") for fl in recorders]
-                ctx.metrics.replay_dumps.inc()
-                self._json(200, bundles[0] if len(bundles) == 1
-                           else {"engines": bundles})
+            bundles = [fl.dump_bundle("on_demand")
+                       for fl in self._flight_recorders()]
+            ctx.metrics.replay_dumps.inc()
+            self._json(200, bundles[0] if len(bundles) == 1
+                       else {"engines": bundles})
         elif self.path.startswith("/debug/requests/"):
             from urllib.parse import unquote
             rid = unquote(self.path[len("/debug/requests/"):])
@@ -709,9 +705,6 @@ class _Handler(BaseHTTPRequestHandler):
             if timeline:
                 timeline.sort(key=lambda e: e["t"])
                 self._json(200, {"request_id": rid, "events": timeline})
-            elif not self._flight_recorders():
-                self._error(404, "flight recorder disabled "
-                                 "(TPUSERVE_FLIGHT=0)")
             else:
                 self._error(404, f"no recorded events for {rid!r} (the "
                                  "ring holds the most recent "
@@ -748,22 +741,16 @@ class _Handler(BaseHTTPRequestHandler):
                         "server_error")
 
     def _flight_recorders(self) -> list:
-        """Enabled flight recorders across the (possibly disagg) engine —
-        one source of truth for inner-engine discovery (the runner's)."""
+        """Flight recorders across the (possibly disagg) engine — one
+        source of truth for inner-engine discovery (the runner's)."""
         return self.ctx.runner._flights()
 
     def _debug_engine_payload(self) -> dict:
         recorders = self._flight_recorders()
-        if not recorders:
-            out = {"enabled": False}
-            if self.ctx.pool is not None:
-                out["modelpool"] = self.ctx.pool.status()
-            return out
         if len(recorders) == 1:
             out = recorders[0].engine_snapshot()
         else:
-            out = {"enabled": True,
-                   "engines": [f.engine_snapshot() for f in recorders]}
+            out = {"engines": [f.engine_snapshot() for f in recorders]}
         # cold-pod-to-first-token (wall seconds since process boot):
         # the autoscaler's probe exports this once per replica into
         # tpuserve_cold_start_seconds
@@ -2074,12 +2061,6 @@ def build_server(argv=None):
                     help="disable the in-process SLO burn-rate "
                          "evaluator (tpuserve/obs; TPUSERVE_SLO_BURN=0 "
                          "is the env twin)")
-    ap.add_argument("--no-devprof", action="store_true",
-                    help="disable device telemetry (runtime/devprof.py): "
-                         "no device-time attribution, executable ladder, "
-                         "HBM watermark, or profiler-capture bookkeeping "
-                         "(TPUSERVE_DEVPROF=0 is the env twin); serving "
-                         "output is byte-identical either way")
     ap.add_argument("--slo-objectives", default=None,
                     metavar="JSON|PATH",
                     help="SLO objectives override (tpuserve/obs/"
@@ -2169,7 +2150,6 @@ def build_server(argv=None):
         kv_tiers=False if args.no_kv_tiers else None,
         kv_host_bytes=args.kv_host_bytes, kv_spill_dir=args.kv_spill_dir,
         slo_classes=False if args.no_slo_classes else None,
-        devprof=False if args.no_devprof else None,
         faults=args.faults, step_watchdog_s=args.step_watchdog_s)
     mesh = None
     if args.pp > 1 and args.tp > 1:

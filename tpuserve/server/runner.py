@@ -697,10 +697,10 @@ class AsyncEngineRunner:
                             for e in self._inner_engines()) if f is not None]
 
     def _flights(self) -> list:
-        """Enabled flight recorders of the inner engines (runtime/flight)."""
+        """Flight recorders of the inner engines (runtime/flight)."""
         return [f for f in (getattr(e, "flight", None)
                             for e in self._inner_engines())
-                if f is not None and f.enabled]
+                if f is not None]
 
     def _dump_postmortem(self, reason: str, rids=()) -> None:
         """Write flight post-mortem bundles (last N cycles + affected
@@ -845,8 +845,8 @@ class AsyncEngineRunner:
         OF the degraded serving).  The trace lands under
         TPUSERVE_FLIGHT_DIR beside any post-mortem and is recorded on
         each engine's DeviceProfiler, so bundles written during the
-        incident reference it.  No-ops when devprof is disabled, inside
-        the cooldown, or when a manual capture holds the process lock."""
+        incident reference it.  No-ops inside the cooldown, or when a
+        manual capture holds the process lock."""
         fired = [tr for tr in transitions
                  if tr.get("state") == "firing"
                  and tr.get("window") == "fast"]
@@ -854,7 +854,7 @@ class AsyncEngineRunner:
             return
         profs = [dp for dp in (getattr(e, "devprof", None)
                                for e in self._inner_engines())
-                 if dp is not None and dp.enabled]
+                 if dp is not None]
         if not profs:
             return
         now = time.monotonic()  # tpulint: sync-ok(capture cooldown is real wall seconds; jax.profiler cannot run in replay time)
@@ -1077,11 +1077,10 @@ class AsyncEngineRunner:
         # device telemetry (runtime/devprof.py): HBM watermark gauges,
         # per-sync-kind device seconds, ladder compile totals, capture
         # count.  Engines keep cumulative totals; counters advance by
-        # delta (_advance_counter), gauges set wholesale.  Disabled
-        # devprofs are skipped — the families stay at zero.
+        # delta (_advance_counter), gauges set wholesale.
         profs = [dp for dp in (getattr(e, "devprof", None)
                                for e in (inners or [eng]))
-                 if dp is not None and dp.enabled]
+                 if dp is not None]
         if profs:
             hbm = [dp.hbm_snapshot() for dp in profs]
             for kind, field in (("weights", "weights_bytes"),

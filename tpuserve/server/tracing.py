@@ -240,6 +240,6 @@ def capture_profile_locked(seconds: float, *, reason: str = "manual",
         _capture_lock.release()
     out["reason"] = reason
     for dp in profilers:
-        if dp is not None and getattr(dp, "enabled", False):
+        if dp is not None:
             dp.note_capture(out["trace_dir"], reason, out["seconds"])
     return out

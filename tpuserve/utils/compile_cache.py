@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
-One rule for every process that builds an engine (the server, bench.py,
-chip_smoke.py, the tools): when ``JAX_COMPILATION_CACHE_DIR`` is set —
+One rule for every process that builds an engine (the server,
+benchmark/run.py, chip_smoke.py, the tools): when ``JAX_COMPILATION_CACHE_DIR`` is set —
 the manifests mount it on the model PVC — JAX reads it itself and this
 module sets nothing; otherwise the cache goes to ONE fixed directory
 inside the checkout.  The directory is part of what a cache entry is
