@@ -361,7 +361,8 @@ def test_pool_replay_scale_from_zero_with_warm_prefix(tmp_path):
     spill = str(tmp_path / "spill")
     shared = list(range(2, 26))
     # phase 1: a (past-life) replica serves the prefix; churn demotes
-    # it through host DRAM onto the spill dir; the pod "dies"
+    # it through host DRAM onto the spill dir (on its second time cold:
+    # the tier declines a first eviction); the pod "dies"
     eng = Engine(EngineConfig(
         model="tiny-qwen3",
         cache=CacheConfig(block_size=4, num_blocks=24,
@@ -372,8 +373,8 @@ def test_pool_replay_scale_from_zero_with_warm_prefix(tmp_path):
         enable_prefix_caching=True, kv_tiers=True, kv_host_bytes=3000,
         kv_spill_dir=spill))
     p = SamplingParams(max_tokens=4, temperature=0.0, ignore_eos=True)
-    eng.generate([shared + [30]], p)
-    eng.generate([[100 + i] * 40 for i in range(3)], p)
+    from tier_drive import cold_twice
+    cold_twice(eng, [shared + [30]], p)
     eng._kv_tiers.flush()
     assert eng.stats.kv_spilled_blocks > 0
     del eng

@@ -10,6 +10,7 @@ for its copy, where there is one, is a ``sync.demote``; ``flush`` is the
 cycle's ``sync.*`` time and nothing else; ``ctx_tokens`` is the context the dispatched rows
 attend.  CPU run: control flow and counts, no device number."""
 
+import contextlib
 import os
 import signal
 
@@ -18,6 +19,8 @@ import pytest
 
 from tpuserve.runtime import (CacheConfig, Engine, EngineConfig,
                               SamplingParams, SchedulerConfig)
+
+from tier_drive import CHURN, cold_twice, tiny_engine
 
 TIME_LIMIT_S = 240
 ROOT = "engine.step"
@@ -72,29 +75,36 @@ def read_spans(trace_dir):
     return spans, {e.name for e in loop[0]}, gathers
 
 
-@pytest.fixture(scope="module")
-def traced(tmp_path_factory):
-    eng = Engine(EngineConfig(
-        model="tiny-qwen3",
-        cache=CacheConfig(block_size=4, num_blocks=24, max_blocks_per_seq=16),
-        scheduler=SchedulerConfig(max_num_seqs=4, max_prefill_tokens=256,
-                                  min_prefill_bucket=8, min_decode_bucket=2),
-        enable_prefix_caching=True, kv_tiers=True, multi_step=4))
-    prompts = [list(range(2, 26)), [7] * 13]
-    churn = [[100 + i] * 40 for i in range(3)]
-    eng.generate(prompts, PARAMS)            # compile outside the trace
-    eng.generate(churn, PARAMS)
-    first = eng.flight.seq
-    trace_dir = str(tmp_path_factory.mktemp("trace"))
-    opts = jax.profiler.ProfileOptions()     # benchmark/harness/session.py
+def tiered_engine():
+    return tiny_engine(True, multi_step=4)
+
+
+@contextlib.contextmanager
+def tracing(trace_dir):
+    """A profiler session with the options the benchmark traces with
+    (benchmark/harness/session.py)."""
+    opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 1
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
     try:
-        eng.generate(prompts, PARAMS)        # evicts the churn's blocks
-        eng.generate(churn, PARAMS)          # evicts the prompts' blocks
+        yield
     finally:
         jax.profiler.stop_trace()
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    eng = tiered_engine()
+    prompts = [list(range(2, 26)), [7] * 13]
+    # compile outside the trace, and drive both sets cold twice: a first
+    # eviction is declined, so only now does the tier hold the prompts
+    cold_twice(eng, prompts, PARAMS)
+    first = eng.flight.seq
+    trace_dir = str(tmp_path_factory.mktemp("trace"))
+    with tracing(trace_dir):
+        eng.generate(prompts, PARAMS)        # evicts the churn's blocks
+        eng.generate(CHURN, PARAMS)          # evicts the prompts' blocks
     steps = {s["seq"]: s for s in eng.flight.steps_snapshot(limit=10_000)
              if s["seq"] > first}
     spans, names, gathers = read_spans(trace_dir)
@@ -167,6 +177,29 @@ def test_a_cycle_with_evictions_dispatches_without_waiting_for_the_copy(
             assert not any(end <= w[0] < launch[0] for w in waits), \
                 "the loop waited for a copy between a gather and a dispatch"
     assert followed > 2
+
+
+def test_a_cycle_of_declined_evictions_opens_kv_demote_and_nothing_under_it(
+        tmp_path):
+    """Prompts that never come back (the benchmark's traffic): every
+    eviction is a hash's first, the tier declines it, and the cycle pays
+    the ``kv.demote`` span alone: no gather is enqueued, no copy is waited
+    for, and the cycle's dispatch follows as if no tier were there."""
+    eng = tiered_engine()
+    rounds = [[[100 + 10 * r + i] * 40 for i in range(3)] for r in range(3)]
+    eng.generate(rounds[0], PARAMS)          # compile outside the trace
+    declined = eng.stats.kv_demote_declined_blocks
+    with tracing(str(tmp_path)):
+        for prompts in rounds[1:]:
+            eng.generate(prompts, PARAMS)
+    assert eng.stats.kv_demote_declined_blocks > declined + 8
+    assert eng.stats.kv_demoted_blocks == 0 and len(eng._kv_tiers) == 0
+    spans, names, gathers = read_spans(str(tmp_path))
+    opened = {s[2] for s in spans}
+    assert "kv.demote" in opened and "dispatch" in opened
+    assert "sync.demote" not in opened and not gathers
+    assert "PjitFunction(_gather_pages)" not in names
+    assert eng.devprof.sync_counts.get("demote", 0) == 0
 
 
 def test_the_programs_keep_the_names_the_benchmark_matches(traced):

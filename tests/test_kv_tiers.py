@@ -17,6 +17,9 @@ from tpuserve.runtime import (CacheConfig, Engine, EngineConfig,
 from tpuserve.runtime.block_manager import BlockManager
 from tpuserve.runtime.kv_tiers import TieredPageStore
 
+from tier_drive import CHURN, cold_twice
+from tier_drive import tiny_engine as _mk_engine
+
 
 def _pages(nbytes=64, dtype=np.int8):
     return [{"k": np.arange(nbytes, dtype=dtype)}]
@@ -210,16 +213,6 @@ def test_prefix_query_counted_once_per_lookup_on_first_block_miss():
 # engine round trip
 # ---------------------------------------------------------------------------
 
-def _mk_engine(tiers, **kw):
-    cfg = EngineConfig(
-        model="tiny-qwen3",
-        cache=CacheConfig(block_size=4, num_blocks=24, max_blocks_per_seq=16),
-        scheduler=SchedulerConfig(max_num_seqs=4, max_prefill_tokens=256,
-                                  min_prefill_bucket=8, min_decode_bucket=2),
-        enable_prefix_caching=True, kv_tiers=tiers, **kw)
-    return Engine(cfg)
-
-
 SHARED = list(range(2, 26))      # 24 tokens = 6 full blocks at block_size 4
 PARAMS = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
 
@@ -227,7 +220,7 @@ PARAMS = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
 def _churn(eng):
     """Unrelated prompts that exhaust the pool and evict the shared
     prefix out of HBM."""
-    eng.generate([[100 + i] * 40 for i in range(3)], PARAMS)
+    eng.generate(CHURN, PARAMS)
 
 
 def test_demote_restore_token_identity(monkeypatch):
@@ -238,8 +231,7 @@ def test_demote_restore_token_identity(monkeypatch):
     monkeypatch.setenv("TPUSERVE_STRICT_BLOCKS", "1")
     eng = _mk_engine(True)
     assert eng._kv_tiers is not None
-    eng.generate([SHARED + [30 + i] for i in range(2)], PARAMS)
-    _churn(eng)
+    cold_twice(eng, [SHARED + [30 + i] for i in range(2)], PARAMS)
     assert eng.stats.kv_demoted_blocks > 0
     assert len(eng._kv_tiers) > 0
     tiered = eng.generate([SHARED + [77]], PARAMS)[0]
@@ -252,8 +244,7 @@ def test_demote_restore_token_identity(monkeypatch):
 def test_spill_tier_restore_token_identity(tmp_path, monkeypatch):
     monkeypatch.setenv("TPUSERVE_STRICT_BLOCKS", "1")
     eng = _mk_engine(True, kv_host_bytes=3000, kv_spill_dir=str(tmp_path))
-    eng.generate([SHARED + [30]], PARAMS)
-    _churn(eng)
+    cold_twice(eng, [SHARED + [30]], PARAMS)
     assert eng.stats.kv_spilled_blocks > 0
     tiered = eng.generate([SHARED + [77]], PARAMS)[0]
     cold = _mk_engine(False).generate([SHARED + [77]], PARAMS)[0]
@@ -283,8 +274,7 @@ def test_recompute_supersedes_gapped_tier_entries(monkeypatch):
     would squat on host budget forever)."""
     monkeypatch.setenv("TPUSERVE_STRICT_BLOCKS", "1")
     eng = _mk_engine(True)
-    eng.generate([SHARED + [30]], PARAMS)
-    _churn(eng)
+    cold_twice(eng, [SHARED + [30]], PARAMS)
     store = eng._kv_tiers
     assert len(store) >= 3
     # punch a gap: drop a MIDDLE entry of the shared chain from the store
@@ -310,8 +300,9 @@ def test_exact_block_multiple_prompt_supersedes_store(monkeypatch):
     eng = _mk_engine(True)
     exact = list(range(2, 26))              # 24 tokens = exactly 6 blocks
     assert len(exact) % eng.cache_cfg.block_size == 0
-    eng.generate([exact], PARAMS)           # registers all 6 block hashes
-    _churn(eng)                             # demotes them
+    cold_twice(eng, [exact], PARAMS)       # registers all 6 block hashes; demoted
+    assert all(eng._kv_tiers.has(h)
+               for h in eng.block_manager.prefix_chain(exact + [0]))
     # re-admit the SAME exact-multiple prompt: lookup probes only 5
     # blocks, the 6th is recomputed + re-registered — strict mode checks
     # the store copy left (every step cross-checks tier_hashes)
@@ -329,8 +320,7 @@ def test_same_cycle_shared_prefix_batch_demotes_once(monkeypatch):
     monkeypatch.setenv("TPUSERVE_STRICT_BLOCKS", "1")
     eng = _mk_engine(True)
     shared = SHARED
-    eng.generate([shared + [30]], PARAMS)
-    _churn(eng)
+    cold_twice(eng, [shared + [30]], PARAMS)
     # a BATCH of same-prefix requests admitted together: the first
     # allocation may evict, the second re-registers the same hashes
     for r in range(3):
@@ -349,8 +339,7 @@ def test_restore_aborted_request_still_commits(monkeypatch):
     blocks: the commit publishes them to the cached pool regardless."""
     monkeypatch.setenv("TPUSERVE_STRICT_BLOCKS", "1")
     eng = _mk_engine(True)
-    eng.generate([SHARED + [30]], PARAMS)
-    _churn(eng)
+    cold_twice(eng, [SHARED + [30]], PARAMS)
     assert len(eng._kv_tiers) > 0
     rid = eng.add_request(prompt_token_ids=SHARED + [88], params=PARAMS)
     eng.step()                     # begins the restore, holds admission
@@ -389,8 +378,7 @@ def test_int8_pages_demote_smaller_at_real_head_widths():
     nbytes = {}
     for dtype in ("int8", "bfloat16"):
         e = engine(dtype)
-        e.generate([SHARED + [30]], PARAMS)
-        _churn(e)
+        cold_twice(e, [SHARED + [30]], PARAMS)
         assert e._kv_tiers.host_count > 0
         nbytes[dtype] = pages_nbytes(
             next(iter(e._kv_tiers._host.values()))[0])
@@ -561,13 +549,15 @@ def test_third_batch_waits_for_the_oldest_and_is_counted():
 
 
 def test_a_plain_run_waits_for_no_copy(monkeypatch):
-    """Evictions cycle after cycle, each prompt new (so no restore takes a
-    hash whose copy is still running): every copy is filed behind a later
-    dispatch or when the loop goes idle, and none is waited for."""
+    """Evictions cycle after cycle, no prompt coming back while its blocks
+    are in the tier (so no restore takes a hash whose copy is still
+    running; the rounds run twice, since a first eviction is declined):
+    every copy is filed behind a later dispatch or when the loop goes
+    idle, and none is waited for."""
     monkeypatch.setenv("TPUSERVE_STRICT_BLOCKS", "1")
     eng = _mk_engine(True)
     eng.generate([SHARED + [30 + i] for i in range(2)], PARAMS)
-    for r in range(3):
+    for r in 2 * list(range(3)):
         eng.generate([[100 + 10 * r + i] * 40 for i in range(3)], PARAMS)
     assert eng.stats.kv_demoted_blocks > 8 and eng.stats.kv_restores == 0
     assert eng.stats.kv_demote_waited_blocks == 0
@@ -597,8 +587,7 @@ def test_a_batch_the_device_cannot_hold_is_copied_out_before_the_dispatch(
     demote = eng._demote_evicted
     monkeypatch.setattr(eng, "_demote_evicted", lambda: (
         demote(), left.append(store.in_flight_batches))[0])
-    eng.generate([SHARED + [30 + i] for i in range(2)], PARAMS)
-    _churn(eng)
+    cold_twice(eng, [SHARED + [30 + i] for i in range(2)], PARAMS)
     assert eng.stats.kv_demoted_blocks > 0 and set(left) == {0}
     tiered = eng.generate([SHARED + [77]], PARAMS)[0]
     cold = _mk_engine(False).generate([SHARED + [77]], PARAMS)[0]
@@ -634,8 +623,7 @@ def test_no_wait_between_the_gather_and_the_cycles_dispatch(monkeypatch):
     for name in dir(eng):
         if name.startswith("_exec_"):
             spy(eng, name, "dispatch")
-    eng.generate([SHARED + [30 + i] for i in range(2)], PARAMS)
-    _churn(eng)
+    cold_twice(eng, [SHARED + [30 + i] for i in range(2)], PARAMS)
     eng.generate([SHARED + [77]], PARAMS)       # restores, and demotes
     _churn(eng)
     after = [log[i + 1] for i, tag in enumerate(log[:-1]) if tag == "gather"]
@@ -698,8 +686,7 @@ def test_demotion_runs_the_executables_the_blocking_gather_warms(monkeypatch):
         kv_cache.gather_block_pages(eng.kv_cache, [0] * n)
     warmed, compiled = set(shapes), real._cache_size()
     del shapes[:]
-    eng.generate([SHARED + [30 + i] for i in range(2)], PARAMS)
-    _churn(eng)
+    cold_twice(eng, [SHARED + [30 + i] for i in range(2)], PARAMS)
     assert eng.stats.kv_demoted_blocks > 0 and shapes
     assert set(shapes) <= warmed
     assert real._cache_size() == compiled, "a demotion compiled a new gather"

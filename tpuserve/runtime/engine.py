@@ -275,7 +275,12 @@ class EngineStats:
     # the loop had to wait for (the in-flight bound, or a restore of a
     # hash still in flight); 1 - waited/demoted is the share of demotion
     # copies that ran wholly behind the chip's work.
+    # kv_demote_declined_blocks: evicted blocks the store did not admit
+    # (their hash had never left HBM before): nothing gathered, KV lost
+    # as with no tier; demoted / (demoted + declined) is the admitted
+    # share of evictions.
     kv_demoted_blocks: int = 0
+    kv_demote_declined_blocks: int = 0
     kv_demote_waited_blocks: int = 0
     kv_spilled_blocks: int = 0
     kv_tier_dropped_blocks: int = 0
@@ -1629,10 +1634,11 @@ class Engine:
                     r.request_id, max(0, r.num_prefilled - W))
 
     # ---- tiered KV cache (runtime/kv_tiers.py) ------------------------
-    # HBM -> host-DRAM -> PVC prefix offload: evictions demote instead of
-    # destroying KV, lower-tier hits restore asynchronously ahead of
-    # admission.  TPUSERVE_KV_TIERS=0 (or kv_tiers=False) removes all of
-    # it — self._kv_tiers is None and no path below runs.
+    # HBM -> host-DRAM -> PVC prefix offload: evictions of a prefix that
+    # has left HBM before demote instead of destroying KV, lower-tier
+    # hits restore asynchronously ahead of admission.
+    # TPUSERVE_KV_TIERS=0 (or kv_tiers=False) removes all of it —
+    # self._kv_tiers is None and no path below runs.
 
     def _demote_evicted(self) -> None:
         """Drain the block manager's eviction log and demote the evicted
@@ -1641,7 +1647,9 @@ class Engine:
         calls this right before its _exec_*; adopt_prefilled before its
         KV scatter): until that dispatch executes, the pages still hold
         the evicted prefix's KV, so one fused gather enqueued here reads
-        the whole cycle's evictions.  Dispatch-only, like the restore:
+        the whole cycle's evictions.  Only blocks the store admits are
+        gathered (kv_tiers.py: a hash that has left HBM before); the rest
+        die in place.  Dispatch-only, like the restore:
         the gather's output is a fresh buffer, the store's copier thread
         copies it to the host while the chip does what the caller
         dispatches next, and the loop files the pages later
@@ -1659,6 +1667,12 @@ class Engine:
             # at once
             ev = [(b, h) for b, h in self.block_manager.take_evictions()
                   if not self.block_manager.prefix_resolvable(h)]
+            # admission: a hash leaving HBM for the first time is declined
+            # (no request has yet come back for it), and a cycle of such
+            # evictions touches neither the device nor the copier
+            cold = len(ev)
+            ev = [(b, h) for b, h in ev if store.admit(h)]
+            self.stats.kv_demote_declined_blocks += cold - len(ev)
             if not ev:
                 return
             from tpuserve.runtime.kv_cache import (

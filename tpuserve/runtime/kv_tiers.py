@@ -31,6 +31,18 @@ pages with two tiers under HBM —
   the pure-Python manager pre-restart files are cap-bounded dead weight
   that ages out).
 
+ADMISSION: a block's pages enter the tier only when its chain hash has
+left HBM before (``admit``).  The first eviction of a hash is declined —
+nothing is gathered or copied, the KV dies as it did before the tier
+existed, and the store remembers the hash alone.  A hash can be evicted a
+second time only if some request recomputed (or restored) it in between:
+the proof that this prefix comes back at a distance HBM cannot bridge, so
+from then on every eviction of it is demoted.  This is the cache-on-
+second-request rule of CDN disk caches and the ghost list of 2Q/ARC:
+traffic whose prompts never repeat costs the tier nothing, and a prefix
+that does return is recomputed once more before the tier holds it.  The
+memory of hashes seen is bounded, insertion-ordered and in NO tier.
+
 A hash lives in EXACTLY ONE tier: HBM (the block manager's prefix map),
 host, or spill — ``put`` demotes out of HBM, host-budget pressure moves
 host entries to spill, and ``take`` (the restore path) removes the entry
@@ -65,6 +77,11 @@ logger = logging.getLogger("tpuserve.kv_tiers")
 # weights and compile caches).  Oldest entries are dropped past it — at
 # init-rescan time too, so crashed pods can't accumulate files forever.
 DEFAULT_MAX_SPILL_ENTRIES = 1 << 16
+
+# Hashes ``admit`` remembers as having left HBM (8-byte keys, no pages):
+# the same order as the spill cap, minutes of evictions at hundreds a
+# second.  Oldest forgotten first; a forgotten hash proves itself again.
+MAX_SEEN_HASHES = 1 << 16
 
 # Demotion batches whose device-to-host copy may be in flight at once:
 # the one being gathered plus one older.  A gathered batch holds its
@@ -148,6 +165,9 @@ class TieredPageStore:
         self._spill_pending: OrderedDict[int, list] = OrderedDict()
         self._spill: OrderedDict[int, str] = OrderedDict()
         self._lock = threading.Lock()
+        # hashes that have left HBM before, oldest first (engine-loop
+        # only).  Not a tier: no lookup below ever reads it.
+        self._seen: OrderedDict[int, None] = OrderedDict()
         self._writeq: "queue.Queue[int | None]" = queue.Queue()
         self._writer: threading.Thread | None = None
         self.host_bytes_used = 0
@@ -336,6 +356,22 @@ class TieredPageStore:
 
     # ---- demote ---------------------------------------------------------
 
+    def admit(self, h: int) -> bool:
+        """Whether the block evicted under hash ``h`` is worth its copy:
+        True when ``h`` has left HBM before (an earlier eviction asked
+        here, or a restore took it out of this store), so some request
+        brought the prefix back after it went cold.  A first eviction is
+        declined and only remembered."""
+        if h in self._seen:
+            return True
+        self._remember(h)
+        return False
+
+    def _remember(self, h: int) -> None:
+        self._seen[h] = None
+        if len(self._seen) > MAX_SEEN_HASHES:
+            self._seen.popitem(last=False)
+
     def reserve(self, nbytes: int) -> None:
         """Make room for a gather of ``nbytes`` device bytes BEFORE it is
         enqueued: wait for the oldest batches until fewer than
@@ -431,7 +467,14 @@ class TieredPageStore:
         in exactly one tier).  None when unresolvable or the spill file is
         unreadable (the caller falls back to recompute; the loss is
         counted — that KV is gone).  A hash in flight waits for that one
-        batch's copy."""
+        batch's copy.  What was taken has left HBM before, whoever put it
+        here (a spill file adopted at start too): ``admit`` will say so."""
+        pages = self._take(h)
+        if pages is not None:
+            self._remember(h)
+        return pages
+
+    def _take(self, h: int) -> list | None:
         ent = self._host.pop(h, None)
         if ent is not None:
             self.host_bytes_used -= ent[1]
@@ -498,3 +541,4 @@ class TieredPageStore:
             self._drop_spill_file(path)
         self._host.clear()
         self.host_bytes_used = 0
+        self._seen.clear()

@@ -214,6 +214,12 @@ class ServerMetrics:
             "Prefix blocks demoted out of HBM into the host-DRAM tier "
             "instead of destroyed on eviction (tiered KV cache; "
             "TPUSERVE_KV_TIERS=0 restores destroy-on-evict)")
+        self.kv_demote_declined = counter(
+            "tpuserve_kv_blocks_demote_declined",
+            "Evicted prefix blocks the tier did not admit: their chain "
+            "hash had never left HBM before, so nothing was copied and "
+            "the KV died as with no tier; demoted / (demoted + declined) "
+            "is the admitted share of evictions")
         self.kv_demote_waited = counter(
             "tpuserve_kv_blocks_demote_waited",
             "Demoted blocks whose device-to-host copy the engine loop had "

@@ -1006,6 +1006,8 @@ class AsyncEngineRunner:
                                   self.metrics.mixed_steps),
                                  ("kv_demoted_blocks",
                                   self.metrics.kv_demoted),
+                                 ("kv_demote_declined_blocks",
+                                  self.metrics.kv_demote_declined),
                                  ("kv_demote_waited_blocks",
                                   self.metrics.kv_demote_waited),
                                  ("kv_spilled_blocks",
