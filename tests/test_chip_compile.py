@@ -24,6 +24,10 @@ WIDTHS = {
     "qwen3-0.6b": (16, 8, 128),
     "llama-8b": (32, 8, 128),        # Mistral-7B's widths too
     "llama-8b-tp4": (8, 2, 128),     # one shard of the four-chip smoke
+    # five query heads a KV head: the first group that is not a power of
+    # two (the block-size clamps halve rows, never heads, so it needs no
+    # rule of its own; these compiles are the check)
+    "falcon-h1-34b": (20, 4, 128),
 }
 PAGE = 32            # server default --block-size
 NUM_BLOCKS = 2048    # server default --num-blocks
@@ -143,6 +147,8 @@ CASES = [(kernel, width, False)
 CASES += [(kernel, width, True)
           for kernel in ("decode", "window", "ragged")
           for width in ("qwen3-0.6b", "llama-8b")]
+# the hybrid model never takes a mesh, an int8 cache is not in its cell
+CASES = [c for c in CASES if c[1] != "falcon-h1-34b" or not c[2]]
 
 
 @pytest.mark.parametrize(
@@ -161,7 +167,8 @@ def test_kernel_compiles_for_v5e(kernel, width, quantized, one_chip,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("width", ["qwen3-0.6b", "llama-8b"])
+@pytest.mark.parametrize("width", ["qwen3-0.6b", "llama-8b",
+                                   "falcon-h1-34b"])
 @pytest.mark.parametrize("rows", [128, 1792, 8192])
 def test_ragged_kernel_compiles_at_the_packed_prefill_ladder(
         rows, width, one_chip, monkeypatch):
@@ -181,6 +188,42 @@ def test_ragged_kernel_compiles_at_the_packed_prefill_ladder(
                        B=PREFILL_SEQS, decode_rows=False)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _ssm_update(S, rows=MAX_NUM_SEQS, heads=32, head=128, state=256,
+                groups=2):
+    """The decode-time state update at Falcon-H1-34B's sizes: a full
+    decode batch on a pool of one seat a row and the trash seat."""
+    from tpuserve.ops.pallas_ssm_update import ssm_state_update
+    f32 = jnp.float32
+    return (lambda pool, seats, decay, dtx, b, c: ssm_state_update(
+        pool, seats, decay, dtx, b, c, interpret=False),
+        [S((MAX_NUM_SEQS + 1, heads, head, state), f32),
+         S((rows,), jnp.int32), S((rows, heads), f32),
+         S((rows, heads, head), f32), S((rows, groups, state), f32),
+         S((rows, groups, state), f32)])
+
+
+@pytest.mark.parametrize("rows", [4, MAX_NUM_SEQS])
+def test_the_state_update_kernel_compiles_for_v5e(rows, one_chip):
+    """``_ssm_state_update`` at the smallest and the largest decode bucket:
+    compiled, named as the benchmark's ``ssm.*`` readers match it, and in
+    place — the pool's bytes are aliased from input to output, not
+    copied (65 seats x 4 MiB would be 273 MB a layer a step)."""
+    import re
+
+    from tpuserve.ops.pallas_ssm_update import KERNEL_NAME
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert KERNEL_NAME == "_ssm_state_update"
+    fn, args = _ssm_update(S, rows)
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+    assert re.search(rf"%{KERNEL_NAME}(\.\d+)? = [^\n]*custom-call\([^\n]*"
+                     r"tpu_custom_call", compiled.as_text())
+    pool_bytes = (MAX_NUM_SEQS + 1) * 32 * 128 * 256 * 4
+    assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
 
 
 @pytest.mark.parametrize("kernel", ["decode", "flash", "ragged"])

@@ -121,6 +121,75 @@ class ModelConfig:
     # once at load (models/weights.py _mla_deinterleave), so the forward
     # always runs the NeoX split-half rope — zero runtime cost.
     mla_rope_interleave: bool = True
+    # Hybrid state-space layers (Falcon-H1): EVERY layer runs a Mamba-2
+    # mixer beside its attention heads on the same normed input and sums
+    # the two into the residual.  ``mamba_d_ssm`` 0 = no such branch.  The
+    # mixer keeps a recurrent state (mamba_n_heads x mamba_d_head x
+    # mamba_d_state, float32) and the last mamba_d_conv - 1 inputs of its
+    # short convolution per SEQUENCE, not per token: the engine holds them
+    # in a pool indexed by seat beside the paged KV cache
+    # (runtime/kv_cache.create_ssm_state).  B and C are shared by the
+    # heads of one of mamba_n_groups groups; prefill evaluates the
+    # recurrence mamba_chunk_size rows at a time (ops/ssm.py).
+    mamba_d_ssm: int = 0
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 128
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False     # bias on the mixer's in_proj
+    mamba_out_bias: bool = False      # ... on its out_proj (HF projectors_bias)
+    mamba_rms_norm: bool = True       # grouped RMSNorm on the gated output
+    mamba_norm_before_gate: bool = False
+    # Falcon-H1's fixed muP multipliers: plain scalars on the embedding,
+    # the keys, the attention branch's input and output, the mixer's input
+    # and output, the MLP's gate and down projections and the logits, and
+    # five more on the slices [z | x | B | C | dt] of the mixer's input
+    # projection.  1.0 everywhere else (and then not applied at all).
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    mlp_multipliers: tuple = (1.0, 1.0)               # (gate, down)
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)  # z, x, B, C, dt
+
+    @property
+    def has_ssm(self) -> bool:
+        """True when every layer carries a recurrent state: what the
+        engine observes to build the seat pool and to close the routes
+        that would need a snapshot of it."""
+        return self.mamba_d_ssm > 0
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels of the short convolution: x, then B and C a group."""
+        return self.mamba_d_ssm + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def mamba_proj_widths(self) -> tuple:
+        """Columns of the mixer's input projection, slice by slice, in
+        the order ``ssm_multipliers`` scales them: [z | x | B | C | dt]."""
+        gn = self.mamba_n_groups * self.mamba_d_state
+        return (self.mamba_d_ssm, self.mamba_d_ssm, gn, gn,
+                self.mamba_n_heads)
+
+    @property
+    def mamba_proj_size(self) -> int:
+        return sum(self.mamba_proj_widths)
+
+    @property
+    def mlp_multiplier_list(self) -> list:
+        """As config.json spells it (a JSON list never equals a tuple)."""
+        return list(self.mlp_multipliers)
+
+    @property
+    def ssm_multiplier_list(self) -> list:
+        return list(self.ssm_multipliers)
 
     def layer_window(self, layer_idx: int) -> Optional[int]:
         """Effective sliding window for one layer — ONE implementation for
@@ -231,7 +300,12 @@ class ModelConfig:
         else:
             mlp = (3 if self.mlp_style == "gated" else 2) * h * i
         embed = v * h * (1 if self.tie_word_embeddings else 2)
-        return l * (attn + mlp) + embed
+        ssm = 0
+        if self.has_ssm:
+            ssm = (h * self.mamba_proj_size + self.mamba_d_ssm * h
+                   + self.mamba_conv_dim * (self.mamba_d_conv + 1)
+                   + 3 * self.mamba_n_heads + self.mamba_d_ssm)
+        return l * (attn + mlp + ssm) + embed
 
 
 _REGISTRY: dict[str, ModelConfig] = {}
@@ -277,6 +351,8 @@ def config_from_hf_json(name: str, hf: dict) -> ModelConfig:
         bos_token_id=hf.get("bos_token_id"),
         eos_token_id=_first(hf.get("eos_token_id")),
     )
+    if family == "falcon_h1" or arch.startswith("falconh1"):
+        return _falcon_h1_config(hf, common)
     if "opt" in family:
         common["tie_word_embeddings"] = hf.get("tie_word_embeddings", True)
         return ModelConfig(
@@ -498,6 +574,56 @@ def config_from_hf_json(name: str, hf: dict) -> ModelConfig:
     )
 
 
+def _falcon_h1_config(hf: dict, common: dict) -> ModelConfig:
+    """Falcon-H1 (HF ``modeling_falcon_h1``): attention heads and a Mamba-2
+    mixer side by side in every layer.  What this code does not implement
+    rejects loudly: a subset of attention layers, rope scaling, a mixer
+    without the MLP."""
+    if hf.get("attn_layer_indices") is not None:
+        raise ValueError("falcon_h1 with attn_layer_indices (attention in "
+                         "some layers only) is not supported")
+    if hf.get("rope_scaling"):
+        raise ValueError(f"unsupported rope_scaling {hf['rope_scaling']!r} "
+                         "for falcon_h1")
+    if not hf.get("mamba_use_mlp", True):
+        raise ValueError("falcon_h1 with mamba_use_mlp false is not "
+                         "supported")
+    nh = hf["num_attention_heads"]
+    d_ssm = hf.get("mamba_d_ssm") or hf["mamba_expand"] * hf["hidden_size"]
+    return ModelConfig(
+        intermediate_size=hf["intermediate_size"],
+        num_kv_heads=hf.get("num_key_value_heads", nh),
+        head_dim=hf.get("head_dim") or hf["hidden_size"] // nh,
+        norm_eps=hf.get("rms_norm_eps", 1e-5),
+        act=hf.get("hidden_act", "silu"),
+        rope_theta=hf.get("rope_theta", 100000.0),
+        attention_bias=hf.get("attention_bias", False),
+        mlp_bias=hf.get("mlp_bias", False),
+        mamba_d_ssm=d_ssm,
+        mamba_n_heads=hf["mamba_n_heads"],
+        mamba_d_head=hf["mamba_d_head"],
+        mamba_d_state=hf["mamba_d_state"],
+        mamba_n_groups=hf.get("mamba_n_groups", 1),
+        mamba_d_conv=hf.get("mamba_d_conv", 4),
+        mamba_chunk_size=hf.get("mamba_chunk_size", 128),
+        mamba_conv_bias=hf.get("mamba_conv_bias", True),
+        mamba_proj_bias=hf.get("mamba_proj_bias", False),
+        mamba_out_bias=hf.get("projectors_bias", False),
+        mamba_rms_norm=hf.get("mamba_rms_norm", True),
+        mamba_norm_before_gate=hf.get("mamba_norm_before_gate", False),
+        embedding_multiplier=hf.get("embedding_multiplier", 1.0),
+        lm_head_multiplier=hf.get("lm_head_multiplier", 1.0),
+        key_multiplier=hf.get("key_multiplier", 1.0),
+        attention_in_multiplier=hf.get("attention_in_multiplier", 1.0),
+        attention_out_multiplier=hf.get("attention_out_multiplier", 1.0),
+        ssm_in_multiplier=hf.get("ssm_in_multiplier", 1.0),
+        ssm_out_multiplier=hf.get("ssm_out_multiplier", 1.0),
+        mlp_multipliers=tuple(hf.get("mlp_multipliers") or (1.0, 1.0)),
+        ssm_multipliers=tuple(hf.get("ssm_multipliers") or (1.0,) * 5),
+        **common,
+    )
+
+
 def _rope_scaling(hf: dict):
     """Llama-3.1-style rope_scaling for the llama-family path.  Ignoring
     an unknown scheme would SILENTLY mis-rotate long contexts, so
@@ -704,6 +830,31 @@ register_model_config(ModelConfig(
     bos_token_id=0, eos_token_id=1,
 ), "deepseek-v3", "deepseek-r1")
 
+# Falcon-H1 (hybrid): attention heads and Mamba-2 state-space heads side
+# by side in every layer, under fixed muP multipliers.  The numbers are
+# config.json's; 33.6 B parameters, so one chip serves a cut of the depth.
+register_model_config(ModelConfig(
+    name="tiiuae/Falcon-H1-34B-Instruct",
+    vocab_size=261120, hidden_size=5120, intermediate_size=21504,
+    num_layers=72, num_heads=20, num_kv_heads=4, head_dim=128,
+    max_position_embeddings=262144, rope_theta=1e11, norm_eps=1e-5,
+    tie_word_embeddings=False,
+    mamba_d_ssm=4096, mamba_n_heads=32, mamba_d_head=128,
+    mamba_d_state=256, mamba_n_groups=2, mamba_d_conv=4,
+    mamba_chunk_size=128,
+    embedding_multiplier=5.656854249492381,
+    lm_head_multiplier=0.0078125,
+    key_multiplier=0.011048543456039804,
+    attention_in_multiplier=1.0,
+    attention_out_multiplier=0.0375,
+    ssm_in_multiplier=0.25,
+    ssm_out_multiplier=0.08838834764831845,
+    mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+    ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                     0.3535533905932738),
+    bos_token_id=1, eos_token_id=11,
+), "falcon-h1-34b")
+
 # Tiny configs for tests / CPU smoke (one per architectural family).
 register_model_config(ModelConfig(
     name="tiny-qwen3",
@@ -711,6 +862,24 @@ register_model_config(ModelConfig(
     num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16,
     max_position_embeddings=512, rope_theta=1e6,
     qk_norm=True, tie_word_embeddings=True, eos_token_id=1,
+))
+
+# Falcon-H1 in small: 10 query heads on 2 KV heads (the family's group of
+# five), two B/C groups, a scan chunk of 8 and every multiplier off 1.
+# float32 like tiny-mistral: its tests compare tokens across routes.
+register_model_config(ModelConfig(
+    name="tiny-falcon-h1",
+    vocab_size=256, hidden_size=64, intermediate_size=128,
+    num_layers=2, num_heads=10, num_kv_heads=2, head_dim=16,
+    max_position_embeddings=512, rope_theta=1e6, norm_eps=1e-5,
+    tie_word_embeddings=False, eos_token_id=1, dtype="float32",
+    mamba_d_ssm=64, mamba_n_heads=4, mamba_d_head=16, mamba_d_state=16,
+    mamba_n_groups=2, mamba_d_conv=4, mamba_chunk_size=8,
+    embedding_multiplier=4.0, lm_head_multiplier=0.125,
+    key_multiplier=0.25, attention_in_multiplier=0.8,
+    attention_out_multiplier=0.3, ssm_in_multiplier=0.5,
+    ssm_out_multiplier=0.4, mlp_multipliers=(0.6, 0.2),
+    ssm_multipliers=(0.7, 0.5, 0.35, 0.9, 0.6),
 ))
 
 register_model_config(ModelConfig(

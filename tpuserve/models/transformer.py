@@ -10,6 +10,7 @@ The reference delegates the model entirely to the vLLM container image
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Any
 
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 from tpuserve.models.config import ModelConfig
 from tpuserve.ops import attention as attn_ops
 from tpuserve.ops import rope as rope_ops
+from tpuserve.ops import ssm as ssm_ops
 
 Params = Any  # nested dict/list pytree of jnp arrays
 
@@ -89,6 +91,12 @@ def _lora_delta(x: jnp.ndarray, la: dict, ad: jnp.ndarray) -> jnp.ndarray:
     return jnp.einsum("bth,bhr,brw->btw", x, Ar, Br)   # prefill: (B, T, H)
 
 
+def _scaled(x: jnp.ndarray, mult: float) -> jnp.ndarray:
+    """``x`` times a fixed multiplier of the model (Falcon-H1's muP
+    scalars), in ``x``'s dtype; not applied at all where it is 1."""
+    return x if mult == 1.0 else x * jnp.asarray(mult, x.dtype)
+
+
 def _act(x: jnp.ndarray, name: str) -> jnp.ndarray:
     if name == "silu":
         return jax.nn.silu(x)
@@ -104,7 +112,8 @@ def _attn_residual(out: jnp.ndarray, lp: dict, cfg: ModelConfig,
     """Attention output projection; Gemma2 sandwich norms apply a
     post-attention layernorm to the projected output before the residual
     add."""
-    att = _linear(out, lp["o_proj"], ad)
+    att = _scaled(_linear(out, lp["o_proj"], ad),
+                  cfg.attention_out_multiplier)
     if cfg.sandwich_norms:
         att = _norm(att, lp["post_attn_norm"], cfg)
     return att
@@ -129,9 +138,10 @@ def _mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
     if "experts" in p:
         return _moe_mlp(x, p, cfg)
     if cfg.mlp_style == "gated":
-        gate = _act(_linear(x, p["gate_proj"], ad), cfg.act)
-        return _linear(gate * _linear(x, p["up_proj"], ad), p["down_proj"],
-                       ad)
+        gate_m, down_m = cfg.mlp_multipliers
+        gate = _act(_scaled(_linear(x, p["gate_proj"], ad), gate_m), cfg.act)
+        return _scaled(_linear(gate * _linear(x, p["up_proj"], ad),
+                               p["down_proj"], ad), down_m)
     return _linear(_act(_linear(x, p["fc1"], ad), cfg.act), p["fc2"], ad)
 
 
@@ -231,9 +241,11 @@ def _qkv(h: jnp.ndarray, lp: dict, cfg: ModelConfig, positions: jnp.ndarray,
     RoPE.  ``layer_idx`` selects per-layer rope (Gemma3: windowed layers
     rotate at the local base frequency unscaled; full layers at
     rope_theta with the linear position scaling)."""
+    h = _scaled(h, cfg.attention_in_multiplier)
     q = _linear(h, lp["q_proj"], ad).reshape(*h.shape[:-1], cfg.num_heads, cfg.head_dim)
     k = _linear(h, lp["k_proj"], ad).reshape(*h.shape[:-1], cfg.num_kv_heads, cfg.head_dim)
     v = _linear(h, lp["v_proj"], ad).reshape(*h.shape[:-1], cfg.num_kv_heads, cfg.head_dim)
+    k = _scaled(k, cfg.key_multiplier)
     if cfg.qk_norm:
         q = rmsnorm(q, lp["q_norm"]["scale"], cfg.norm_eps,
                     cfg.norm_weight_offset)
@@ -359,6 +371,191 @@ def _mla_unabsorb(out_lat, lp, cfg: ModelConfig) -> jnp.ndarray:
                       out_lat[..., :cfg.mla_kv_lora_rank], w_uv)
 
 
+# --------------------------------------------------------------------------
+# Mamba-2 mixer beside the attention heads (Falcon-H1)
+# --------------------------------------------------------------------------
+#
+# Every layer of a hybrid model runs this branch on the SAME normed input
+# as its attention heads and adds both to the residual.  It keeps, per
+# sequence and not per token, a float32 state (heads, head size, state
+# size) and the last ``mamba_d_conv - 1`` inputs of a short convolution.
+# The engine holds them in a pool indexed by SEAT — one slot a running
+# sequence, one more that padding rows share (runtime/kv_cache.py
+# create_ssm_state) — which the trunks below take as ``ssm`` (per layer
+# {"state", "conv"}) with ``seats`` (one a sequence) and return updated,
+# beside the KV cache.  A window that starts at position 0 starts from
+# zeros, whatever its seat held: that is how a seat is cleared for its
+# next sequence.  Rows with PAD_SLOT as their cache slot are padding and
+# change nothing.  Equations: HF modeling_falcon_h1 (FalconH1Mixer).
+
+def _ssm_project(hn: jnp.ndarray, sp: dict, cfg: ModelConfig):
+    """hn (..., hidden) -> gate z (..., d_ssm), convolution input xBC
+    (..., conv_dim), raw step dt (..., heads): the input projection under
+    its input multiplier and the five per-slice multipliers."""
+    p = _linear(_scaled(hn, cfg.ssm_in_multiplier), sp["in_proj"])
+    if any(m != 1.0 for m in cfg.ssm_multipliers):
+        p = p * jnp.concatenate([
+            jnp.full((w,), m, p.dtype) for w, m
+            in zip(cfg.mamba_proj_widths, cfg.ssm_multipliers)])
+    d = cfg.mamba_d_ssm
+    return p[..., :d], p[..., d:d + cfg.mamba_conv_dim], \
+        p[..., d + cfg.mamba_conv_dim:]
+
+
+def _ssm_inputs(conv_out: jnp.ndarray, dt_raw: jnp.ndarray, sp: dict,
+                cfg: ModelConfig, valid: jnp.ndarray):
+    """Convolved xBC (f32) and raw dt -> x (..., H, P), B, C (..., G, N),
+    dt (..., H) f32, A (H,).  Rows that are not ``valid`` come out as
+    zeros: a padding row's input may be anything (the paged kernels leave
+    such rows unspecified), and the scan SUMS over a chunk's rows, where
+    a NaN times a zero step is still a NaN."""
+    xbc = jnp.where(valid[..., None], jax.nn.silu(conv_out), 0.0)
+    # activations in the model's dtype, as everywhere else in the trunk;
+    # the scan and the state update widen what they accumulate
+    xbc = xbc.astype(dt_raw.dtype)
+    d, gn = cfg.mamba_d_ssm, cfg.mamba_n_groups * cfg.mamba_d_state
+    lead = xbc.shape[:-1]
+    x = xbc[..., :d].reshape(*lead, cfg.mamba_n_heads, cfg.mamba_d_head)
+    bm = xbc[..., d:d + gn].reshape(*lead, cfg.mamba_n_groups,
+                                    cfg.mamba_d_state)
+    cm = xbc[..., d + gn:].reshape(*lead, cfg.mamba_n_groups,
+                                   cfg.mamba_d_state)
+    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + sp["dt_bias"])
+    dt = jnp.where(valid[..., None], dt, 0.0)
+    return x, bm, cm, dt, -jnp.exp(sp["A_log"].astype(jnp.float32))
+
+
+def _ssm_output(y: jnp.ndarray, x: jnp.ndarray, z: jnp.ndarray, sp: dict,
+                cfg: ModelConfig) -> jnp.ndarray:
+    """Scan output y and its input x (..., H, P), gate z (..., d_ssm) ->
+    the branch's contribution to the residual (..., hidden)."""
+    y = y + sp["D"].astype(jnp.float32)[:, None] * x
+    y = y.reshape(*y.shape[:-2], cfg.mamba_d_ssm)
+    if cfg.mamba_rms_norm:
+        y = ssm_ops.gated_group_norm(y, z, sp["norm"]["scale"], cfg.norm_eps,
+                                     cfg.mamba_n_groups,
+                                     cfg.mamba_norm_before_gate)
+    else:
+        y = y * jax.nn.silu(z.astype(jnp.float32))
+    return _scaled(_linear(y.astype(z.dtype), sp["out_proj"]),
+                   cfg.ssm_out_multiplier)
+
+
+def _ssm_window(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
+                lens: jnp.ndarray, entry: dict | None = None,
+                seats: jnp.ndarray | None = None,
+                fresh: jnp.ndarray | None = None):
+    """The mixer over a window of rows a sequence: hn (B, L, hidden), the
+    first ``lens`` (B,) rows of each valid.  ``fresh`` (B,) bool, for a
+    window against the cache: True where the window starts its sequence,
+    elsewhere state and convolution memory come from ``entry`` at
+    ``seats``; None: every window starts from zeros.  Returns (m (B, L,
+    hidden), entry with the seats' state and memory after the window —
+    None in, None out: the cache-less trunks)."""
+    B, L, _ = hn.shape
+    H, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
+    W = cfg.mamba_d_conv
+    z, xbc, dt_raw = _ssm_project(hn, sp, cfg)
+    s0 = jnp.zeros((B, H, P, N), jnp.float32)
+    tail = jnp.zeros((B, W - 1, cfg.mamba_conv_dim), xbc.dtype)
+    if fresh is not None:
+        keep = ~fresh
+        s0 = jnp.where(keep[:, None, None, None], entry["state"][seats], s0)
+        tail = jnp.where(keep[:, None, None], entry["conv"][seats], tail)
+    conv_out, rows = ssm_ops.causal_conv(xbc, tail, sp["conv"]["kernel"],
+                                         sp["conv"].get("bias"))
+    valid = jnp.arange(L)[None, :] < lens[:, None]
+    x, bm, cm, dt, a = _ssm_inputs(conv_out, dt_raw, sp, cfg, valid)
+    # the scan's chunk: the published size where the window holds whole
+    # chunks, else the largest part of it that divides the window
+    Q = math.gcd(cfg.mamba_chunk_size, L)
+    y, finals = ssm_ops.ssd_chunk_scan(
+        x.reshape(B * L, H, P), dt.reshape(B * L, H), a,
+        bm.reshape(B * L, *bm.shape[2:]), cm.reshape(B * L, *cm.shape[2:]),
+        s0, jnp.repeat(jnp.arange(B, dtype=jnp.int32), L // Q), chunk=Q)
+    m = _ssm_output(y.reshape(B, L, H, P), x, z, sp, cfg)
+    if entry is None:
+        return m, None
+    return m, {"state": entry["state"].at[seats].set(finals),
+               "conv": entry["conv"].at[seats].set(
+                   ssm_ops.next_tail(rows, lens, W).astype(
+                       entry["conv"].dtype))}
+
+
+def _ssm_nocache(hn: jnp.ndarray, lp: dict, cfg: ModelConfig,
+                 lens: jnp.ndarray):
+    """The mixer's term of the cache-less trunks (embeddings, prompt
+    scoring, the draft proposer, the plain forward): every sequence from
+    zeros, nothing kept.  Zero for a model without the branch."""
+    if not cfg.has_ssm:
+        return 0
+    return _ssm_window(hn, lp["ssm"], cfg, lens)[0]
+
+
+def _ssm_packed(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
+                positions: jnp.ndarray, valid: jnp.ndarray,
+                blk_seq: jnp.ndarray, q_starts: jnp.ndarray,
+                q_lens: jnp.ndarray, blk: int, entry: dict,
+                seats: jnp.ndarray):
+    """The mixer over a PACKED prefill: hn (T, hidden), each prompt
+    starting at position 0 on a ``blk``-row boundary of the flat axis
+    (``blk_seq`` names each block's prompt, -1 padding; Engine._pack_ragged)
+    and running ``q_lens`` rows from ``q_starts``.  The scan's chunk
+    divides the block, so no chunk straddles two prompts; every prompt
+    starts from zeros.  Returns (m (T, hidden), entry)."""
+    T = hn.shape[0]
+    H, P = cfg.mamba_n_heads, cfg.mamba_d_head
+    W = cfg.mamba_d_conv
+    z, xbc, dt_raw = _ssm_project(hn, sp, cfg)
+    # the flat axis as one row of the convolution, a tap that would reach
+    # back past its prompt's first row reading zero
+    conv_out, _ = ssm_ops.causal_conv(
+        xbc[None], jnp.zeros((1, W - 1, xbc.shape[-1]), xbc.dtype),
+        sp["conv"]["kernel"], sp["conv"].get("bias"),
+        positions=positions[None])
+    x, bm, cm, dt, a = _ssm_inputs(conv_out[0], dt_raw, sp, cfg, valid)
+    Q = math.gcd(cfg.mamba_chunk_size, blk)
+    n_seq = q_lens.shape[0]
+    y, finals = ssm_ops.ssd_chunk_scan(
+        x, dt, a, bm, cm,
+        jnp.zeros((n_seq, H, P, cfg.mamba_d_state), jnp.float32),
+        jnp.repeat(blk_seq, blk // Q), chunk=Q)
+    m = _ssm_output(y, x, z, sp, cfg)
+    # each prompt's last W - 1 inputs (zeros before its first row)
+    back = jnp.arange(W - 1)[None, :] - (W - 1)                 # -3 .. -1
+    idx = (q_starts + q_lens)[:, None] + back
+    tails = jnp.where((q_lens[:, None] + back >= 0)[..., None],
+                      xbc[jnp.clip(idx, 0, T - 1)], 0)
+    return m, {"state": entry["state"].at[seats].set(finals),
+               "conv": entry["conv"].at[seats].set(
+                   tails.astype(entry["conv"].dtype))}
+
+
+def _ssm_decode(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
+                valid: jnp.ndarray, entry: dict, seats: jnp.ndarray,
+                attn_impl: str):
+    """One token a row: hn (B, hidden).  The convolution's memory shifts
+    by one; the state is updated in place on the pool — by the Pallas
+    kernel under ``attn_impl="pallas"`` (ops/pallas_ssm_update.py), by the
+    same formula in ``jax.numpy`` otherwise.  Padding rows (not ``valid``)
+    carry the trash seat.  Returns (m (B, hidden), entry)."""
+    z, xbc, dt_raw = _ssm_project(hn, sp, cfg)
+    conv_out, rows = ssm_ops.causal_conv(
+        xbc[:, None], entry["conv"][seats], sp["conv"]["kernel"],
+        sp["conv"].get("bias"))
+    x, bm, cm, dt, a = _ssm_inputs(conv_out[:, 0], dt_raw, sp, cfg, valid)
+    x = x.astype(jnp.float32)
+    from tpuserve.ops import pallas_ssm_update as upd
+    update = (upd.ssm_state_update if attn_impl == "pallas"
+              else upd.ssm_state_update_reference)
+    y, state = update(entry["state"], seats, jnp.exp(dt * a),
+                      dt[..., None] * x, bm, cm)
+    m = _ssm_output(y, x, z, sp, cfg)
+    return m, {"state": state,
+               "conv": entry["conv"].at[seats].set(
+                   rows[:, 1:].astype(entry["conv"].dtype))}
+
+
 def _embed(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
            positions: jnp.ndarray) -> jnp.ndarray:
     h = params["embed"]["weight"][tokens]
@@ -368,6 +565,7 @@ def _embed(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
              * params["embed"]["scale"][tokens][..., None].astype(dtype))
     if cfg.embed_scale_by_sqrt_dim:       # Gemma: normalizer in h's dtype,
         h = h * jnp.asarray(cfg.hidden_size ** 0.5, h.dtype)  # like HF
+    h = _scaled(h, cfg.embedding_multiplier)            # Falcon-H1
     if cfg.pos == "learned":
         h = h + params["pos_embed"]["weight"][positions + cfg.learned_pos_offset]
     return h
@@ -384,11 +582,19 @@ def _unembed(params: Params, cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
             logits = h @ ew["weight"].T
     else:
         logits = _linear(h, params["lm_head"])
-    logits = logits.astype(jnp.float32)
+    logits = _scaled(logits.astype(jnp.float32), cfg.lm_head_multiplier)
     if cfg.final_logit_softcapping:
         cap = cfg.final_logit_softcapping
         logits = cap * jnp.tanh(logits / cap)
     return logits
+
+
+def _with_ssm(out, new_cache: list, ssm, new_ssm: list) -> tuple:
+    """A cache trunk's result: ``(out, kv_cache)``, and the seat pool third
+    where the model has one."""
+    if ssm is None:
+        return out, new_cache
+    return out, new_cache, new_ssm
 
 
 # --------------------------------------------------------------------------
@@ -396,10 +602,11 @@ def _unembed(params: Params, cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
 # --------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("cfg", "attn_impl", "mesh"),
-         donate_argnames=("kv_cache",))
+         donate_argnames=("kv_cache", "ssm"))
 def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             prompt_lens: jnp.ndarray, slot_ids: jnp.ndarray,
-            kv_cache: list, ad: jnp.ndarray | None = None, *,
+            kv_cache: list, ad: jnp.ndarray | None = None,
+            ssm: list | None = None, seats: jnp.ndarray | None = None, *,
             attn_impl: str = "reference", mesh=None):
     """Run full prompts through the model.
 
@@ -410,12 +617,18 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     ``mesh``: static; when set with attn_impl="pallas", the Pallas kernels
     run head-parallel over the tp axis via shard_map (ops/pallas_tp.py) —
     GSPMD cannot partition a pallas_call on its own.
+
+    A model with recurrent state (``cfg.has_ssm``) also takes ``ssm`` — the
+    seat pool, per layer {"state", "conv"} — and ``seats`` (B,), and
+    returns the pool third: this holds for every trunk below that takes
+    the KV cache.
     """
     B, T = tokens.shape
     positions = jnp.arange(T)[None, :].repeat(B, axis=0)
     h = _embed(params, cfg, tokens, positions)
     scale = cfg.attn_scale
     new_cache = []
+    new_ssm = []
     for li, lp in enumerate(params["layers"]):
         sw = cfg.layer_window(li)
         hn = _norm(h, lp["attn_norm"], cfg)
@@ -453,11 +666,17 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                                              sliding_window=sw,
                                              logit_softcap=cfg.attn_logit_softcapping)
         out = out.reshape(B, T, cfg.q_size)
-        h = h + _attn_residual(out, lp, cfg, ad)
+        att = _attn_residual(out, lp, cfg, ad)
+        if ssm is not None:
+            m, entry = _ssm_window(hn, lp["ssm"], cfg, prompt_lens, ssm[li],
+                                   seats)
+            new_ssm.append(entry)
+            att = att + m
+        h = h + att
         h = h + _mlp_residual(h, lp, cfg, ad)
     last_idx = jnp.maximum(prompt_lens - 1, 0)
     h_last = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]  # (B, H)
-    return _unembed(params, cfg, h_last), new_cache
+    return _with_ssm(_unembed(params, cfg, h_last), new_cache, ssm, new_ssm)
 
 
 # --------------------------------------------------------------------------
@@ -465,12 +684,13 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 # --------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("cfg", "attn_impl", "mesh"),
-         donate_argnames=("kv_cache",))
+         donate_argnames=("kv_cache", "ssm"))
 def prefill_chunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                   ctx_lens: jnp.ndarray, chunk_lens: jnp.ndarray,
                   slot_ids: jnp.ndarray, block_tables: jnp.ndarray,
-                  kv_cache: list, ad: jnp.ndarray | None = None, *,
-                  attn_impl: str = "reference", mesh=None):
+                  kv_cache: list, ad: jnp.ndarray | None = None,
+                  ssm: list | None = None, seats: jnp.ndarray | None = None,
+                  *, attn_impl: str = "reference", mesh=None):
     """Process one chunk of each prompt against the paged cache.
 
     Long prompts run as a sequence of fixed-size chunks (bounded memory and
@@ -490,12 +710,12 @@ def prefill_chunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     online-softmax einsum in ops/attention.py.  ``mesh``: static; when set
     with pallas, the kernel runs head-parallel over tp via shard_map.
     """
-    h, new_cache = _chunk_trunk(params, cfg, tokens, ctx_lens, chunk_lens,
-                                slot_ids, block_tables, kv_cache, ad,
-                                attn_impl=attn_impl, mesh=mesh)
+    h, new_cache, new_ssm = _chunk_trunk(
+        params, cfg, tokens, ctx_lens, chunk_lens, slot_ids, block_tables,
+        kv_cache, ad, ssm, seats, attn_impl=attn_impl, mesh=mesh)
     last_idx = jnp.maximum(chunk_lens - 1, 0)
     h_last = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]
-    return _unembed(params, cfg, h_last), new_cache
+    return _with_ssm(_unembed(params, cfg, h_last), new_cache, ssm, new_ssm)
 
 
 # --------------------------------------------------------------------------
@@ -527,7 +747,8 @@ def embed_forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                                          sliding_window=sw,
                                          logit_softcap=cfg.attn_logit_softcapping)
         out = out.reshape(B, T, cfg.attn_out_size)
-        h = h + _attn_residual(out, lp, cfg)
+        h = h + _attn_residual(out, lp, cfg) + _ssm_nocache(hn, lp, cfg,
+                                                            prompt_lens)
         h = h + _mlp_residual(h, lp, cfg)
     if cfg.final_layernorm:
         h = _norm(h, params["final_norm"], cfg)
@@ -573,7 +794,8 @@ def score_prompt(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         out = attn_ops.prefill_attention(q, k, v, prompt_lens, scale,
                                          sliding_window=sw,
                                          logit_softcap=cfg.attn_logit_softcapping)
-        h = h + _attn_residual(out.reshape(B, T, cfg.attn_out_size), lp, cfg)
+        h = h + _attn_residual(out.reshape(B, T, cfg.attn_out_size), lp,
+                               cfg) + _ssm_nocache(hn, lp, cfg, prompt_lens)
         h = h + _mlp_residual(h, lp, cfg)
     # next-token targets: position i scores tokens[i+1]
     nxt = jnp.concatenate([tokens[:, 1:], jnp.zeros((B, 1), tokens.dtype)],
@@ -608,16 +830,19 @@ def score_prompt(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 def _chunk_trunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                  ctx_lens: jnp.ndarray, chunk_lens: jnp.ndarray,
                  slot_ids: jnp.ndarray, block_tables: jnp.ndarray,
-                 kv_cache: list, ad: jnp.ndarray | None = None, *,
-                 attn_impl: str = "reference", mesh=None):
+                 kv_cache: list, ad: jnp.ndarray | None = None,
+                 ssm: list | None = None, seats: jnp.ndarray | None = None,
+                 *, attn_impl: str = "reference", mesh=None):
     """Shared layer loop for cache-relative windows: writes the window's KV
     and attends against cached context + causal-within-window.  Used by both
-    prefill_chunk (last-row logits) and decode_verify (all-row argmax)."""
+    prefill_chunk (last-row logits) and decode_verify (all-row argmax).
+    Returns (h, kv_cache, seat pool or None)."""
     B, C = tokens.shape
     positions = ctx_lens[:, None] + jnp.arange(C)[None, :]
     h = _embed(params, cfg, tokens, positions)
     scale = cfg.attn_scale
     new_cache = []
+    new_ssm = []
     for li, lp in enumerate(params["layers"]):
         sw = cfg.layer_window(li)
         hn = _norm(h, lp["attn_norm"], cfg)
@@ -663,9 +888,15 @@ def _chunk_trunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 k_scale=ks, v_scale=vs, sliding_window=sw,
                 logit_softcap=cfg.attn_logit_softcapping)
         out = out.reshape(B, C, cfg.q_size)
-        h = h + _attn_residual(out, lp, cfg, ad)
+        att = _attn_residual(out, lp, cfg, ad)
+        if ssm is not None:
+            m, entry = _ssm_window(hn, lp["ssm"], cfg, chunk_lens, ssm[li],
+                                   seats, fresh=ctx_lens == 0)
+            new_ssm.append(entry)
+            att = att + m
+        h = h + att
         h = h + _mlp_residual(h, lp, cfg, ad)
-    return h, new_cache
+    return h, new_cache, new_ssm or None
 
 
 @partial(jax.jit, static_argnames=("cfg", "attn_impl", "mesh"),
@@ -686,9 +917,9 @@ def decode_verify(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     cache before the window; chunk_lens: (B,) valid rows; slot_ids: (B, K);
     block_tables: (B, max_blocks).  Returns (pred (B, K) int32, kv_cache).
     """
-    h, new_cache = _chunk_trunk(params, cfg, tokens, ctx_lens, chunk_lens,
-                                slot_ids, block_tables, kv_cache,
-                                attn_impl=attn_impl, mesh=mesh)
+    h, new_cache, _ = _chunk_trunk(params, cfg, tokens, ctx_lens,
+                                   chunk_lens, slot_ids, block_tables,
+                                   kv_cache, attn_impl=attn_impl, mesh=mesh)
     logits = _unembed(params, cfg, h)                       # (B, K, V)
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_cache
 
@@ -713,9 +944,9 @@ def decode_verify_sampled(params: Params, cfg: ModelConfig,
     greedy acceptance.  Returns (accept (B, K-1) bool, pred (B, K) int32,
     kv_cache)."""
     from tpuserve.ops.sampling import spec_accept_sampled
-    h, new_cache = _chunk_trunk(params, cfg, tokens, ctx_lens, chunk_lens,
-                                slot_ids, block_tables, kv_cache,
-                                attn_impl=attn_impl, mesh=mesh)
+    h, new_cache, _ = _chunk_trunk(params, cfg, tokens, ctx_lens,
+                                   chunk_lens, slot_ids, block_tables,
+                                   kv_cache, attn_impl=attn_impl, mesh=mesh)
     logits = _unembed(params, cfg, h)                       # (B, K, V)
     accept, pred = spec_accept_sampled(logits, tokens[:, 1:], chunk_lens,
                                        keys, temperature, top_k, top_p,
@@ -842,15 +1073,17 @@ def _decode_body(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                  positions: jnp.ndarray, slot_ids: jnp.ndarray,
                  block_tables: jnp.ndarray, seq_lens: jnp.ndarray,
                  kv_cache: list, attn_impl: str, mesh,
-                 ad: jnp.ndarray | None = None):
+                 ad: jnp.ndarray | None = None, ssm: list | None = None,
+                 seats: jnp.ndarray | None = None):
     """Shared single-token decode trunk: write the token's KV, attend
-    against the paged cache, return (logits (B, V), new kv_cache).  Used by
-    :func:`decode_step` (one dispatch per token) and :func:`decode_multi`
-    (scanned — one dispatch per window)."""
+    against the paged cache, return (logits (B, V), new kv_cache, seat
+    pool or None).  Used by :func:`decode_step` (one dispatch per token) and
+    :func:`decode_multi` (scanned — one dispatch per window)."""
     B = tokens.shape[0]
     h = _embed(params, cfg, tokens, positions)                 # (B, H)
     scale = cfg.attn_scale
     new_cache = []
+    new_ssm = []
     for li, lp in enumerate(params["layers"]):
         sw = cfg.layer_window(li)
         hn = _norm(h, lp["attn_norm"], cfg)
@@ -896,18 +1129,26 @@ def _decode_body(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                                                   sliding_window=sw,
                                                   logit_softcap=cfg.attn_logit_softcapping)
         out = out.reshape(B, cfg.q_size)
-        h = h + _attn_residual(out, lp, cfg, ad)
+        att = _attn_residual(out, lp, cfg, ad)
+        if ssm is not None:
+            m, entry = _ssm_decode(hn, lp["ssm"], cfg,
+                                   slot_ids != attn_ops.PAD_SLOT, ssm[li],
+                                   seats, attn_impl)
+            new_ssm.append(entry)
+            att = att + m
+        h = h + att
         h = h + _mlp_residual(h, lp, cfg, ad)
-    return _unembed(params, cfg, h), new_cache
+    return _unembed(params, cfg, h), new_cache, new_ssm or None
 
 
 @partial(jax.jit, static_argnames=("cfg", "attn_impl", "mesh"),
-         donate_argnames=("kv_cache",))
+         donate_argnames=("kv_cache", "ssm"))
 def decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 positions: jnp.ndarray, slot_ids: jnp.ndarray,
                 block_tables: jnp.ndarray, seq_lens: jnp.ndarray,
-                kv_cache: list, ad: jnp.ndarray | None = None, *,
-                attn_impl: str = "reference", mesh=None):
+                kv_cache: list, ad: jnp.ndarray | None = None,
+                ssm: list | None = None, seats: jnp.ndarray | None = None,
+                *, attn_impl: str = "reference", mesh=None):
     """One decode step for a batch of sequences.
 
     tokens/positions/slot_ids/seq_lens: (B,); block_tables: (B, max_blocks).
@@ -916,21 +1157,23 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
     ``mesh``: static; see :func:`prefill` — head-parallel Pallas under tp.
     """
-    return _decode_body(params, cfg, tokens, positions, slot_ids,
-                        block_tables, seq_lens, kv_cache, attn_impl, mesh,
-                        ad=ad)
+    logits, new_cache, new_ssm = _decode_body(
+        params, cfg, tokens, positions, slot_ids, block_tables, seq_lens,
+        kv_cache, attn_impl, mesh, ad=ad, ssm=ssm, seats=seats)
+    return _with_ssm(logits, new_cache, ssm, new_ssm)
 
 
 @partial(jax.jit,
          static_argnames=("cfg", "steps", "mode", "logprobs_n", "attn_impl",
                           "mesh", "out_mesh"),
-         donate_argnames=("kv_cache",))
+         donate_argnames=("kv_cache", "ssm"))
 def decode_multi(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                  positions: jnp.ndarray, block_tables: jnp.ndarray,
                  seq_lens: jnp.ndarray, active: jnp.ndarray,
                  keys: jnp.ndarray, temperature: jnp.ndarray,
-                 kv_cache: list, ad: jnp.ndarray | None = None, *,
-                 steps: int, mode: str = "greedy",
+                 kv_cache: list, ad: jnp.ndarray | None = None,
+                 ssm: list | None = None, seats: jnp.ndarray | None = None,
+                 *, steps: int, mode: str = "greedy",
                  top_k: jnp.ndarray | None = None,
                  top_p: jnp.ndarray | None = None,
                  min_p: jnp.ndarray | None = None,
@@ -977,9 +1220,10 @@ def decode_multi(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     state by the sampled token, folding the per-step host-FSM loop
     entirely into the scan.
 
-    Returns (tokens (B, steps) int32, kv_cache[, logprobs][, gstate'])
-    — the logprobs triple when ``logprobs_n``, the final (B,) FSM states
-    when ``gstate`` was passed.
+    Returns (tokens (B, steps) int32, kv_cache[, logprobs][, gstate']
+    [, ssm]) — the logprobs triple when ``logprobs_n``, the final (B,) FSM
+    states when ``gstate`` was passed, the seat pool (a carry of the scan
+    like the cache, updated in place) when ``ssm`` was.
     """
     B = tokens.shape[0]
     block_size = kv_cache[0]["k"].shape[1]
@@ -987,13 +1231,13 @@ def decode_multi(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
     def one(carry, s):
         if guided:
-            toks, pos, lens, cache, cnt, gst = carry
+            toks, pos, lens, cache, cnt, pool, gst = carry
         else:
-            (toks, pos, lens, cache, cnt), gst = carry, None
+            (toks, pos, lens, cache, cnt, pool), gst = carry, None
         slot = window_slot(block_tables, pos, active, block_size)
-        logits, cache = _decode_body(params, cfg, toks, pos, slot,
-                                     block_tables, lens, cache,
-                                     attn_impl, mesh, ad=ad)
+        logits, cache, pool = _decode_body(
+            params, cfg, toks, pos, slot, block_tables, lens, cache,
+            attn_impl, mesh, ad=ad, ssm=pool, seats=seats)
         # extras ordered before sampling AND before logprobs, exactly
         # like the per-step path (penalties -> bias -> floor); whichever
         # features aren't in play ride along as zeros so one executable
@@ -1017,12 +1261,12 @@ def decode_multi(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             # previously dropped them to per-token dispatches)
             from tpuserve.ops.sampling import compute_logprobs
             ys = (nxt, compute_logprobs(logits, nxt, logprobs_n))
-        new_carry = (nxt, pos + 1, lens + 1, cache, cnt)
+        new_carry = (nxt, pos + 1, lens + 1, cache, cnt, pool)
         if guided:
             new_carry += (gst,)
         return new_carry, ys
 
-    carry = (tokens, positions, seq_lens, kv_cache, counts)
+    carry = (tokens, positions, seq_lens, kv_cache, counts, ssm)
     if guided:
         carry += (gstate,)
     final, outs = jax.lax.scan(
@@ -1045,6 +1289,8 @@ def decode_multi(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     if logprobs_n:
         res += (lp,)
     if guided:
+        res += (final[6],)
+    if ssm is not None:
         res += (final[5],)
     return res
 
@@ -1090,14 +1336,16 @@ def _ragged_reference_attn(q, ck, cv, block_tables, row_seq, row_lens,
 
 @partial(jax.jit,
          static_argnames=("cfg", "ragged_blk", "attn_impl", "decode_rows"),
-         donate_argnames=("kv_cache",))
+         donate_argnames=("kv_cache", "ssm"))
 def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                    positions: jnp.ndarray, slot_ids: jnp.ndarray,
                    row_seq: jnp.ndarray, block_tables: jnp.ndarray,
                    kv_lens: jnp.ndarray, q_starts: jnp.ndarray,
                    q_lens: jnp.ndarray, meta: jnp.ndarray,
                    blk_seq: jnp.ndarray, last_rows: jnp.ndarray,
-                   kv_cache: list, ad: jnp.ndarray | None = None, *,
+                   kv_cache: list, ad: jnp.ndarray | None = None,
+                   ssm: list | None = None,
+                   seats: jnp.ndarray | None = None, *,
                    ragged_blk: int = 8, attn_impl: str = "reference",
                    decode_rows: bool = True):
     """One MIXED prefill+decode step over a flat token stream.
@@ -1127,12 +1375,23 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     each row's KV is written first, then the row attends its own
     sequence's cached keys at positions ``<= position``.  Returns
     (last_logits (B, V), kv_cache).
+
+    With recurrent state (``ssm``, ``seats`` (B,)) the stream must be a
+    packed prefill of prompts that each start at position 0: decode rows
+    lie one a row, so a chunk of the scan would straddle sequences, and a
+    prompt continued from the cache would need its seat's state mid-stream
+    (the engine keeps mixed steps and the prefix cache off for such a
+    model).
     """
     T = tokens.shape[0]
+    if ssm is not None and decode_rows:
+        raise ValueError("a model with recurrent state takes the ragged "
+                         "trunk for packed prefills only (decode_rows=False)")
     h = _embed(params, cfg, tokens, positions)                 # (T, H)
     scale = cfg.attn_scale
     row_lens = positions + 1
     new_cache = []
+    new_ssm = []
     for li, lp in enumerate(params["layers"]):
         sw = cfg.layer_window(li)
         hn = _norm(h, lp["attn_norm"], cfg)
@@ -1179,10 +1438,18 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 meta, ragged_blk, scale, ks, vs, sw,
                 cfg.attn_logit_softcapping, decode_rows=decode_rows)
         out = out.reshape(T, cfg.q_size)
-        h = h + _attn_residual(out, lp, cfg, ad)
+        att = _attn_residual(out, lp, cfg, ad)
+        if ssm is not None:
+            m, entry = _ssm_packed(hn, lp["ssm"], cfg, positions,
+                                   slot_ids != attn_ops.PAD_SLOT, blk_seq,
+                                   q_starts, q_lens, ragged_blk, ssm[li],
+                                   seats)
+            new_ssm.append(entry)
+            att = att + m
+        h = h + att
         h = h + _mlp_residual(h, lp, cfg, ad)
     h_sel = h[last_rows]                                       # (B, H)
-    return _unembed(params, cfg, h_sel), new_cache
+    return _with_ssm(_unembed(params, cfg, h_sel), new_cache, ssm, new_ssm)
 
 
 @partial(jax.jit, static_argnames=("cfg", "k"))
@@ -1219,7 +1486,7 @@ def draft_propose(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 q, kk, v, cur, scale, sliding_window=cfg.layer_window(li),
                 logit_softcap=cfg.attn_logit_softcapping)
             h = h + _attn_residual(out.reshape(B, T, cfg.attn_out_size),
-                                   lp, cfg)
+                                   lp, cfg) + _ssm_nocache(hn, lp, cfg, cur)
             h = h + _mlp_residual(h, lp, cfg)
         # unembed ONLY each row's last position — the full (B, T, V)
         # logits would be GBs at serving batch sizes
@@ -1256,6 +1523,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         out = attn_ops.prefill_attention(q, k, v, seq_lens, scale,
                                          sliding_window=cfg.layer_window(li),
                                          logit_softcap=cfg.attn_logit_softcapping)
-        h = h + _attn_residual(out.reshape(B, T, cfg.attn_out_size), lp, cfg)
+        h = h + _attn_residual(out.reshape(B, T, cfg.attn_out_size), lp,
+                               cfg) + _ssm_nocache(hn, lp, cfg, seq_lens)
         h = h + _mlp_residual(h, lp, cfg)
     return _unembed(params, cfg, h)

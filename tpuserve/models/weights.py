@@ -63,8 +63,15 @@ class _Draw:
         return (jax.random.normal(k, shape, jnp.float32) * std
                 ).astype(self.dtype)
 
-    def dense(self, n_in: int, n_out: int, bias: bool):
-        p = {"kernel": self.normal((n_in, n_out), n_in ** -0.5)}
+    def dense(self, n_in: int, n_out: int, bias: bool, mult: float = 1.0):
+        """``mult``: a fixed multiplier the forward pass puts on this
+        projection (Falcon-H1's muP scalars).  The draw is of the
+        EFFECTIVE weight at the usual scale, stored divided by ``mult``:
+        left at the plain scale, a head behind ``lm_head_multiplier``
+        1/128 would make every logit ~0.01 and the output uniform, and
+        no comparison of log-probabilities could tell a right trunk from
+        a wrong one."""
+        p = {"kernel": self.normal((n_in, n_out), n_in ** -0.5 / mult)}
         if bias:
             p["bias"] = jnp.zeros((n_out,), self.dtype)
         return p
@@ -116,13 +123,20 @@ def _init_layer(key, cfg: ModelConfig, dense_mlp: bool, mesh=None) -> Params:
     else:
         lp = {
             "attn_norm": d.norm(h),
-            "q_proj": d.dense(h, cfg.q_size, cfg.attention_bias),
-            "k_proj": d.dense(h, cfg.kv_size, cfg.attention_bias),
-            "v_proj": d.dense(h, cfg.kv_size, cfg.attention_bias),
+            "q_proj": d.dense(h, cfg.q_size, cfg.attention_bias,
+                              cfg.attention_in_multiplier),
+            "k_proj": d.dense(h, cfg.kv_size, cfg.attention_bias,
+                              cfg.attention_in_multiplier
+                              * cfg.key_multiplier),
+            "v_proj": d.dense(h, cfg.kv_size, cfg.attention_bias,
+                              cfg.attention_in_multiplier),
             "o_proj": d.dense(cfg.q_size, h,
-                              cfg.attention_bias and cfg.pos == "learned"),
+                              cfg.attention_bias and cfg.pos == "learned",
+                              cfg.attention_out_multiplier),
             "mlp_norm": d.norm(h),
         }
+    if cfg.has_ssm:
+        lp["ssm"] = _init_ssm(d, cfg)
     if cfg.qk_norm:
         lp["q_norm"] = {"scale": jnp.full((cfg.head_dim,), d.norm_init,
                                           d.dtype)}
@@ -150,13 +164,49 @@ def _init_layer(key, cfg: ModelConfig, dense_mlp: bool, mesh=None) -> Params:
                             "up_proj": d.dense(h, si, False),
                             "down_proj": d.dense(si, h, False)}
     elif cfg.mlp_style == "gated":
-        lp["gate_proj"] = d.dense(h, cfg.intermediate_size, cfg.mlp_bias)
+        lp["gate_proj"] = d.dense(h, cfg.intermediate_size, cfg.mlp_bias,
+                                  cfg.mlp_multipliers[0])
         lp["up_proj"] = d.dense(h, cfg.intermediate_size, cfg.mlp_bias)
-        lp["down_proj"] = d.dense(cfg.intermediate_size, h, cfg.mlp_bias)
+        lp["down_proj"] = d.dense(cfg.intermediate_size, h, cfg.mlp_bias,
+                                  cfg.mlp_multipliers[1])
     else:
         lp["fc1"] = d.dense(h, cfg.intermediate_size, cfg.mlp_bias)
         lp["fc2"] = d.dense(cfg.intermediate_size, h, cfg.mlp_bias)
     return _shard(lp, cfg, mesh)
+
+
+def _init_ssm(d: _Draw, cfg: ModelConfig) -> Params:
+    """The Mamba-2 mixer of one layer (Falcon-H1).  ``in_proj``'s columns
+    are [z | x | B | C | dt], each slice drawn for its own multiplier
+    (:meth:`_Draw.dense`); ``A_log`` and ``dt_bias`` as Mamba-2 draws them
+    (A in [1, 16], the step dt log-uniform in [1e-3, 1e-1]); ``D``, the
+    convolution and its bias random, so that no term of the layer is a
+    no-op under random weights."""
+    h, hs = cfg.hidden_size, cfg.mamba_n_heads
+    cols = [d.normal((h, w), h ** -0.5 / (cfg.ssm_in_multiplier * m))
+            for w, m in zip(cfg.mamba_proj_widths, cfg.ssm_multipliers)]
+    in_proj = {"kernel": jnp.concatenate(cols, axis=1)}
+    if cfg.mamba_proj_bias:
+        in_proj["bias"] = jnp.zeros((cfg.mamba_proj_size,), d.dtype)
+    d.n += 1
+    ka, kd = jax.random.split(jax.random.fold_in(d.key, d.n))
+    a = jax.random.uniform(ka, (hs,), jnp.float32, 1.0, 16.0)
+    dt = jnp.exp(jax.random.uniform(kd, (hs,), jnp.float32,
+                                    jnp.log(1e-3), jnp.log(1e-1)))
+    conv = {"kernel": d.normal((cfg.mamba_d_conv, cfg.mamba_conv_dim),
+                               cfg.mamba_d_conv ** -0.5)}
+    if cfg.mamba_conv_bias:
+        conv["bias"] = d.normal((cfg.mamba_conv_dim,), 0.1)
+    return {
+        "in_proj": in_proj,
+        "conv": conv,
+        "A_log": jnp.log(a),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),    # softplus^-1(dt)
+        "D": 1.0 + d.normal((hs,), 0.25).astype(jnp.float32),
+        "norm": {"scale": jnp.full((cfg.mamba_d_ssm,), 1.0, d.dtype)},
+        "out_proj": d.dense(cfg.mamba_d_ssm, h, cfg.mamba_out_bias,
+                            cfg.ssm_out_multiplier),
+    }
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh"))
@@ -164,13 +214,15 @@ def _init_head(key, cfg: ModelConfig, mesh=None) -> Params:
     """Everything outside the layer stack: embeddings, final norm, head."""
     d = _Draw(cfg, key)
     h = cfg.hidden_size
-    params = {"embed": {"weight": d.normal((cfg.vocab_size, h), 0.02)},
+    params = {"embed": {"weight": d.normal(
+        (cfg.vocab_size, h), 0.02 / cfg.embedding_multiplier)},
               "final_norm": d.norm(h)}
     if cfg.pos == "learned":
         params["pos_embed"] = {"weight": d.normal(
             (cfg.max_position_embeddings + cfg.learned_pos_offset, h), 0.02)}
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = d.dense(h, cfg.vocab_size, False)
+        params["lm_head"] = d.dense(h, cfg.vocab_size, False,
+                                    cfg.lm_head_multiplier)
     return _shard(params, cfg, mesh)
 
 
@@ -250,6 +302,30 @@ def load_hf_checkpoint(cfg: ModelConfig, ckpt_dir: str) -> Params:
     return _load_llama_family(cfg, raw, dtype)
 
 
+def _load_falcon_h1_ssm(raw: dict, pre: str, dtype) -> Params:
+    """One layer's Mamba-2 mixer from HF ``modeling_falcon_h1`` names.
+    ``conv1d.weight`` is (channels, 1, width), the last tap weighing the
+    row itself; the scalars a head stay float32."""
+    def f32(name):
+        return jnp.asarray(raw[pre + name], jnp.float32)
+
+    def dense(name):
+        p = {"kernel": _t(raw[pre + name + ".weight"], dtype)}
+        if pre + name + ".bias" in raw:
+            p["bias"] = jnp.asarray(raw[pre + name + ".bias"], dtype=dtype)
+        return p
+
+    conv = {"kernel": jnp.asarray(raw[pre + "conv1d.weight"],
+                                  dtype=dtype)[:, 0, :].T}
+    if pre + "conv1d.bias" in raw:
+        conv["bias"] = jnp.asarray(raw[pre + "conv1d.bias"], dtype=dtype)
+    return {"in_proj": dense("in_proj"), "conv": conv,
+            "A_log": f32("A_log"), "dt_bias": f32("dt_bias"), "D": f32("D"),
+            "norm": {"scale": jnp.asarray(raw[pre + "norm.weight"],
+                                          dtype=dtype)},
+            "out_proj": dense("out_proj")}
+
+
 def _load_llama_family(cfg: ModelConfig, raw: dict, dtype) -> Params:
     def get(name):
         return raw[name]
@@ -279,6 +355,9 @@ def _load_llama_family(cfg: ModelConfig, raw: dict, dtype) -> Params:
                 pre + "pre_feedforward_layernorm.weight")
             lp["post_mlp_norm"] = norm_scale(
                 pre + "post_feedforward_layernorm.weight")
+        elif cfg.has_ssm:                                       # Falcon-H1
+            lp["mlp_norm"] = norm_scale(pre + "pre_ff_layernorm.weight")
+            lp["ssm"] = _load_falcon_h1_ssm(raw, pre + "mamba.", dtype)
         else:
             lp["mlp_norm"] = norm_scale(
                 pre + "post_attention_layernorm.weight")
@@ -335,16 +414,21 @@ def _load_llama_family(cfg: ModelConfig, raw: dict, dtype) -> Params:
             g, u = jnp.split(gu, 2, axis=0)
             lp["gate_proj"], lp["up_proj"] = {"kernel": g.T}, {"kernel": u.T}
         else:
-            lp["gate_proj"] = dense(pre + "mlp.gate_proj.weight")
-            lp["up_proj"] = dense(pre + "mlp.up_proj.weight")
+            # Falcon-H1 calls its MLP feed_forward
+            mlp = "feed_forward." if cfg.has_ssm else "mlp."
+            lp["gate_proj"] = dense(pre + mlp + "gate_proj.weight")
+            lp["up_proj"] = dense(pre + mlp + "up_proj.weight")
         if not moe_layer:
-            lp["down_proj"] = dense(pre + "mlp.down_proj.weight")
+            mlp = "feed_forward." if cfg.has_ssm else "mlp."
+            lp["down_proj"] = dense(pre + mlp + "down_proj.weight")
         layers.append(lp)
 
     params = {
         "embed": {"weight": jnp.asarray(get("model.embed_tokens.weight"), dtype=dtype)},
         "layers": layers,
-        "final_norm": {"scale": jnp.asarray(get("model.norm.weight"), dtype=dtype)},
+        "final_norm": {"scale": jnp.asarray(
+            get("model.final_layernorm.weight" if cfg.has_ssm
+                else "model.norm.weight"), dtype=dtype)},
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = {"kernel": _t(get("lm_head.weight"), dtype)}

@@ -105,6 +105,10 @@ class NativeBlockManager:
     """Drop-in for runtime.block_manager.BlockManager (see that module for
     the semantics; native/block_manager.hh mirrors them)."""
 
+    #: a runtime.block_manager.SeatPool when the model has recurrent
+    #: state (set by the engine): taken and given back with the blocks
+    seats = None
+
     def __init__(self, num_blocks: int, block_size: int,
                  enable_prefix_caching: bool = True):
         ext = _load()
@@ -189,6 +193,8 @@ class NativeBlockManager:
         blocks = self._core.allocate(seq_id, list(prompt_token_ids),
                                      list(shared_blocks or []))
         from tpuserve.runtime.block_manager import SeqAlloc
+        if self.seats is not None:
+            self.seats.acquire(seq_id)
         return SeqAlloc(blocks=blocks, num_tokens=len(prompt_token_ids))
 
     def needs_new_block(self, seq_id: str) -> bool:
@@ -218,6 +224,8 @@ class NativeBlockManager:
 
     def free(self, seq_id: str, cache_blocks: bool = True) -> None:
         self._core.free(seq_id, cache_blocks)
+        if self.seats is not None:
+            self.seats.release(seq_id)
 
     def num_seqs(self) -> int:
         return self._core.num_seqs()

@@ -39,8 +39,49 @@ class SeqAlloc:
     released_upto: int = 0           # logical blocks returned to the pool
 
 
+class SeatPool:
+    """Seats of the engine's recurrent-state pool (a model with
+    state-space layers keeps one slot of state a SEQUENCE beside its pages
+    of keys and values; runtime/kv_cache.create_ssm_state).  A manager that
+    is given one (``manager.seats``) takes a seat with a sequence's first
+    blocks and gives it back when it frees them, so every path that drops
+    a sequence's KV — finish, abort, pre-emption, salvage — drops its
+    state with it.  ``trash`` is the extra slot padding rows share."""
+
+    def __init__(self, num_seats: int):
+        self.num_seats = self.trash = num_seats
+        self._free = list(range(num_seats - 1, -1, -1))
+        self._seat: dict[str, int] = {}
+        self.acquired = 0               # seats handed out, ever
+
+    def acquire(self, seq_id: str) -> int:
+        if not self._free:
+            raise RuntimeError(
+                f"no free state seat for {seq_id}: {self.num_seats} "
+                "sequences already hold one (the scheduler admits at most "
+                "max_num_seqs)")
+        seat = self._seat[seq_id] = self._free.pop()
+        self.acquired += 1
+        return seat
+
+    def release(self, seq_id: str) -> None:
+        seat = self._seat.pop(seq_id, None)
+        if seat is not None:
+            self._free.append(seat)
+
+    def of(self, seq_id: str) -> int:
+        return self._seat[seq_id]
+
+    @property
+    def in_use(self) -> int:
+        return len(self._seat)
+
+
 class BlockManager:
     """Allocates physical cache blocks to sequences; optional prefix cache."""
+
+    #: a SeatPool when the model has recurrent state (set by the engine)
+    seats: Optional[SeatPool] = None
 
     def __init__(self, num_blocks: int, block_size: int, enable_prefix_caching: bool = True):
         self.num_blocks = num_blocks
@@ -270,6 +311,8 @@ class BlockManager:
                          num_tokens=len(prompt_token_ids))
         self._seqs[seq_id] = alloc
         self._register_prefix_blocks(seq_id, prompt_token_ids)
+        if self.seats is not None:
+            self.seats.acquire(seq_id)
         return alloc
 
     def needs_new_block(self, seq_id: str) -> bool:
@@ -384,6 +427,8 @@ class BlockManager:
         alloc = self._seqs.pop(seq_id, None)
         if alloc is None:
             return
+        if self.seats is not None:
+            self.seats.release(seq_id)
         for b in alloc.blocks:
             if b == RELEASED:               # already back in the pool
                 continue

@@ -28,7 +28,7 @@ the capability/cost signals heterogeneous routing wants (arxiv
 - **HBM watermark accounting**: the engine reconciles its block-manager
   KV reservation with loaded weight bytes and the backend's
   ``memory_stats`` into one watermark dict (``set_hbm``), exported as
-  the ``tpuserve_hbm_bytes{kind=weights|kv|other}`` gauges plus a
+  the ``tpuserve_hbm_bytes{kind=weights|kv|state|other}`` gauges plus a
   headroom scalar.
 - **profiler-capture bookkeeping**: ``note_capture`` records every
   ``jax.profiler`` trace taken through /debug/profile or the fast-burn
@@ -159,26 +159,30 @@ class DeviceProfiler:
 
     def set_hbm(self, *, weights: int, kv_reserved: int, limit: int,
                 num_blocks: int, block_bytes: int,
-                in_use: Optional[int] = None) -> None:
+                in_use: Optional[int] = None, state: int = 0) -> None:
         """Record the HBM watermark: ``weights`` (loaded param bytes,
         draft included), ``kv_reserved`` (the paged cache's full static
-        reservation = num_blocks * block_bytes), ``limit`` (detected or
+        reservation = num_blocks * block_bytes), ``state`` (the
+        recurrent-state pool of a model with state-space layers, one slot
+        a decode seat), ``limit`` (detected or
         TPUSERVE_HBM_BYTES-overridden device budget), and the backend's
         live ``bytes_in_use`` when it reports one.  ``other`` is the
         workspace/fragmentation remainder the backend sees beyond
-        weights+KV; ``headroom`` is what is left under the limit."""
+        weights+KV+state; ``headroom`` is what is left under the limit."""
         other = 0
         if in_use is not None:
-            other = max(0, int(in_use) - int(weights) - int(kv_reserved))
+            other = max(0, int(in_use) - int(weights) - int(kv_reserved)
+                        - int(state))
         self._hbm = {
             "limit_bytes": int(limit),
             "weights_bytes": int(weights),
             "kv_reserved_bytes": int(kv_reserved),
+            "state_bytes": int(state),
             "other_bytes": int(other),
             "num_blocks": int(num_blocks),
             "block_bytes": int(block_bytes),
             "headroom_bytes": int(limit) - int(weights)
-                              - int(kv_reserved) - int(other),
+                              - int(kv_reserved) - int(state) - int(other),
         }
 
     def note_capture(self, trace_dir: str, reason: str,

@@ -293,6 +293,11 @@ class EngineStats:
     # tpuserve_model_swaps_total).  swap_latencies holds recent
     # (outcome, seconds) pairs drained into tpuserve_model_swap_seconds
     # by server/runner.py; bounded like restore_latencies.
+    # recurrent state (models with state-space layers): seats zeroed for
+    # a sequence's first window, and tokens prefilled AGAIN because a
+    # pre-empted or salvaged sequence's state was dropped (no snapshot)
+    ssm_state_resets: int = 0
+    ssm_rebuilt_tokens: int = 0
     model_swaps: int = 0
     model_swaps_by_outcome: dict = dataclasses.field(default_factory=dict)
     swap_latencies: list = dataclasses.field(default_factory=list)
@@ -365,6 +370,32 @@ class Engine:
         self.mesh = mesh
         from tpuserve.parallel.mesh import AXIS_PP
         self._pp = mesh.shape.get(AXIS_PP, 1) if mesh is not None else 1
+        # Recurrent state (state-space layers beside attention): one slot
+        # of state a running sequence, in a pool beside the paged KV cache
+        # (self.ssm_state, below).  Nothing snapshots a slot, so every
+        # route that would need the state at some EARLIER token is closed
+        # here or observed off below, each with its sentence — no option.
+        recurrent = self.model_cfg.has_ssm
+        if recurrent:
+            name = self.model_cfg.name
+            if mesh is not None or jax.process_count() > 1:
+                raise ValueError(
+                    f"{name} keeps recurrent state, which has no sharding "
+                    "yet: the seat pool, the chunked scan and the "
+                    "state-update kernel run on one device (no tp/pp mesh, "
+                    "single process)")
+            if config.speculative:
+                raise ValueError(
+                    f"{name} keeps recurrent state: a rejected draft token "
+                    "has already advanced it and there is no snapshot to "
+                    "roll back to, so speculative decoding is not "
+                    "supported")
+            if config.lora_modules:
+                raise ValueError(
+                    f"{name}: multi-LoRA serving is not supported with "
+                    "state-space layers (the mixer's projections carry no "
+                    "per-row adapter path); merge one adapter at load "
+                    "(lora_dir)")
         self.tokenizer = load_tokenizer(config.checkpoint_dir or config.model,
                                         vocab_size=self.model_cfg.vocab_size)
         if params is None:
@@ -469,6 +500,13 @@ class Engine:
                 shardings=cache_shardings(self.model_cfg, mesh))
         else:
             self.kv_cache = create_kv_cache(self.model_cfg, self.cache_cfg)
+        # the recurrent-state pool: NOT a leaf of self.kv_cache, which
+        # stays "bytes a token" for everything that sizes or copies pages
+        self.ssm_state = None
+        if recurrent:
+            from tpuserve.runtime.kv_cache import create_ssm_state
+            self.ssm_state = create_ssm_state(
+                self.model_cfg, config.scheduler.max_num_seqs)
         # Pallas under TP: head-parallel shard_map (ops/pallas_tp.py) keeps
         # the fused kernels, each shard on its own kv heads.  (kv heads
         # that do not split evenly over tp never get here: the kv-head
@@ -502,9 +540,24 @@ class Engine:
             logger.info("multi-LoRA: prefix caching disabled (cached KV "
                         "is adapter-specific)")
             prefix_caching = False
+        if prefix_caching and recurrent:
+            # a prefix hit's pages hold keys and values, not the recurrent
+            # state at the prefix's end; with the prefix cache goes the KV
+            # tier below, which files blocks by prefix hash
+            logger.info("%s keeps recurrent state: prefix caching and the "
+                        "KV tier are off (cached pages do not hold the "
+                        "state at a prefix's end)", self.model_cfg.name)
+            prefix_caching = False
         self.block_manager = create_block_manager(
             self.cache_cfg.num_blocks, self.cache_cfg.block_size,
             enable_prefix_caching=prefix_caching)
+        if recurrent:
+            # a sequence takes its seat with its blocks and gives it back
+            # with them: finish, abort, pre-emption and salvage all free
+            # through the manager
+            from tpuserve.runtime.block_manager import SeatPool
+            self.block_manager.seats = SeatPool(
+                config.scheduler.max_num_seqs)
         # Tiered KV cache (runtime/kv_tiers.py): demote evicted prefix
         # blocks to host DRAM / PVC instead of losing the KV; restore
         # asynchronously ahead of admission.  Gated off under pp (the
@@ -536,6 +589,13 @@ class Engine:
         # whether the store's device budget was read (_read_demote_budget)
         self._demote_budget_read = False
         sched_cfg = config.scheduler
+        if sched_cfg.mixed_batching and recurrent:
+            # decode rows lie one a row on the flat axis, so a chunk of
+            # the scan would straddle sequences
+            logger.warning("%s keeps recurrent state: mixed ragged "
+                           "batching is off; falling back to phase-split "
+                           "scheduling", self.model_cfg.name)
+            sched_cfg = dataclasses.replace(sched_cfg, mixed_batching=False)
         if sched_cfg.mixed_batching and (self._pp > 1
                                          or jax.process_count() > 1):
             # the ragged trunk is neither stage-stacked nor in the
@@ -639,7 +699,8 @@ class Engine:
             max_model_len=self.cache_cfg.max_model_len,
             mixed_batching=sched_cfg.mixed_batching,
             multi_step=config.resolve_multi_step(),
-            slo_classes=bool(self._slo is not None))
+            slo_classes=bool(self._slo is not None),
+            ssm_state_seats=sched_cfg.max_num_seqs if recurrent else 0)
         self.scheduler.flight = self.flight
         if self._slo is not None:
             self._slo.flight = self.flight
@@ -847,7 +908,9 @@ class Engine:
             max_model_len=self.cache_cfg.max_model_len,
             mixed_batching=self.scheduler.cfg.mixed_batching,
             multi_step=config.resolve_multi_step(),
-            slo_classes=bool(self._slo is not None))
+            slo_classes=bool(self._slo is not None),
+            ssm_state_seats=(self.scheduler.cfg.max_num_seqs
+                             if self.ssm_state is not None else 0))
         self._note_hbm_budget()         # HBM watermark per resident model
         dt = self.clock.monotonic() - t0
         stats.model_swaps += 1
@@ -914,6 +977,7 @@ class Engine:
 
         weights = _tree_bytes(self.params) + _tree_bytes(self._draft_params)
         kv = _tree_bytes(self.kv_cache)
+        state = _tree_bytes(self.ssm_state)
         block_bytes = (kv // self.cache_cfg.num_blocks
                        if self.cache_cfg.num_blocks else 0)
         in_use = None
@@ -922,7 +986,7 @@ class Engine:
             in_use = stats.get("bytes_in_use")
         except Exception:
             pass
-        self.devprof.set_hbm(weights=weights, kv_reserved=kv,
+        self.devprof.set_hbm(weights=weights, kv_reserved=kv, state=state,
                              limit=self._device_hbm_limit(),
                              num_blocks=self.cache_cfg.num_blocks,
                              block_bytes=block_bytes, in_use=in_use)
@@ -950,8 +1014,11 @@ class Engine:
         from tpuserve.runtime.kv_cache import num_blocks_for_budget
         limit = self._device_hbm_limit()
         from tpuserve.models.weights import param_nbytes
+        from tpuserve.runtime.kv_cache import ssm_state_bytes
         shards = tp_n = 1
-        param_bytes = param_nbytes(self.params)
+        # the recurrent-state pool is as fixed a cost as the weights
+        param_bytes = param_nbytes(self.params) + ssm_state_bytes(
+            self.model_cfg, self.config.scheduler.max_num_seqs)
         if mesh is not None:
             # tp shards all weights and the cache, so the per-device
             # arithmetic cancels to the total-budget form.  pp shards the
@@ -1190,6 +1257,10 @@ class Engine:
         """
         from tpuserve.parallel.disagg import insert_seq_kv
         prompt_token_ids = list(prompt_token_ids)
+        if self.ssm_state is not None:
+            raise ValueError("KV adoption (disaggregation) is not supported "
+                             "for a model with recurrent state: the "
+                             "transferred pages do not carry it")
         if self._pp > 1:
             raise ValueError("KV adoption (disaggregation) is not supported "
                              "on the pipeline engine — the transferred "
@@ -1982,16 +2053,65 @@ class Engine:
                 ad[i, r.adapter_idx] = 1.0
         return jnp.asarray(ad)
 
-    def _lora_kw(self, reqs: list, B: int) -> dict:
-        """Conditional ``ad=`` kwarg for the exec hooks: an EMPTY dict
-        when no adapter stack is loaded, so multihost wrappers (whose
-        hook signatures predate the arg) are never passed it.  One home
-        for the dance instead of six call sites."""
+    def _row_kw(self, reqs: list, B: int) -> dict:
+        """Conditional per-row kwargs for the exec hooks — ``ad=`` with an
+        adapter stack, ``seats=`` with recurrent state (never both: the
+        engine refuses that pair): an EMPTY dict otherwise, so multihost
+        wrappers (whose hook signatures predate the args) are never passed
+        them.  One home for the dance instead of six call sites."""
+        if self.ssm_state is not None:
+            return {"seats": self._seat_ids(reqs, B)}
         if not self._lora_names:
             return {}
         return {"ad": self._lora_ad(reqs, B)}
 
-    def _exec_prefill(self, tokens, prompt_lens, slot_ids, ad=None):
+    def _seat_ids(self, reqs: list, B: int) -> jnp.ndarray:
+        """Each row's seat in the recurrent-state pool, in row order;
+        rows past ``reqs`` (padding, warm-up) share the trash seat."""
+        seats = self.block_manager.seats
+        ids = np.full((B,), seats.trash, np.int32)
+        with PROF.phase("block"):
+            for i, r in enumerate(reqs):
+                ids[i] = seats.of(r.request_id)
+        return jnp.asarray(ids)
+
+    def _pool_kw(self, seats, rows: int) -> dict:
+        """What a cache trunk of models/transformer.py takes beside the KV
+        cache for a model with recurrent state: the seat pool (donated,
+        like the cache) and each row's seat; nothing for any other model.
+        ``seats`` None: a warm-up dispatch, every one of its ``rows`` on
+        the trash seat.  (Keywords for a call the hook makes itself, not
+        a wrapper around it: one more Python frame under every traced
+        operation cost the warm-up of the dense cells 28 % on the chip,
+        PERF.md, PR 32.)"""
+        if self.ssm_state is None:
+            return {}
+        if seats is None:
+            seats = self._seat_ids([], rows)
+        return {"ssm": self.ssm_state, "seats": seats}
+
+    def _keep_pool(self, res: tuple) -> tuple:
+        """The trunk's result as every caller reads it: the updated seat
+        pool, which a model with recurrent state returns last, is kept
+        here."""
+        if self.ssm_state is None:
+            return res
+        *res, self.ssm_state = res
+        return tuple(res)
+
+    def _note_seat_start(self, req: Request, n_tokens: int) -> None:
+        """A sequence of a model with recurrent state starts (or starts
+        AGAIN: nothing snapshots a seat, so a pre-empted or salvaged
+        sequence rebuilds its state from a zeroed slot by prefilling its
+        prompt and everything generated so far)."""
+        if self.ssm_state is None:
+            return
+        self.stats.ssm_state_resets += 1
+        if req.output_token_ids:
+            self.stats.ssm_rebuilt_tokens += n_tokens
+
+    def _exec_prefill(self, tokens, prompt_lens, slot_ids, ad=None,
+                      seats=None):
         self.faults.check("prefill_dispatch", self._dispatch_rids)
         with self.devprof.dispatch("prefill", (tuple(tokens.shape),)):
             if self._pp > 1:
@@ -1999,13 +2119,14 @@ class Engine:
                 return pp_prefill(self._pp_head, self._pp_stages,
                                   self.model_cfg, tokens, prompt_lens,
                                   slot_ids, self.kv_cache, mesh=self.mesh)
-            return transformer.prefill(
+            return self._keep_pool(transformer.prefill(
                 self.params, self.model_cfg, tokens, prompt_lens, slot_ids,
                 self.kv_cache, ad, attn_impl=self.attn_impl,
-                mesh=self._attn_mesh)
+                mesh=self._attn_mesh,
+                **self._pool_kw(seats, tokens.shape[0])))
 
     def _exec_decode(self, tokens, positions, slot_ids, block_tables,
-                     seq_lens, ad=None):
+                     seq_lens, ad=None, seats=None):
         self.faults.check("decode_dispatch", self._dispatch_rids)
         with self.devprof.dispatch("decode", (tuple(tokens.shape),)):
             if self._pp > 1:
@@ -2014,22 +2135,24 @@ class Engine:
                                       self.model_cfg, tokens, positions,
                                       slot_ids, block_tables, seq_lens,
                                       self.kv_cache, mesh=self.mesh)
-            return transformer.decode_step(
+            return self._keep_pool(transformer.decode_step(
                 self.params, self.model_cfg, tokens, positions, slot_ids,
                 block_tables, seq_lens, self.kv_cache, ad,
-                attn_impl=self.attn_impl, mesh=self._attn_mesh)
+                attn_impl=self.attn_impl, mesh=self._attn_mesh,
+                **self._pool_kw(seats, tokens.shape[0])))
 
     def _exec_prefill_chunk(self, tokens, ctx_lens, chunk_lens, slot_ids,
-                            block_tables, ad=None):
+                            block_tables, ad=None, seats=None):
         self.faults.check("prefill_dispatch", self._dispatch_rids)
         if self._pp > 1:            # unreachable: gated at add_request
             raise RuntimeError("chunked prefill is not supported on the "
                                "pipeline engine")
         with self.devprof.dispatch("prefill_chunk", (tuple(tokens.shape),)):
-            return transformer.prefill_chunk(
+            return self._keep_pool(transformer.prefill_chunk(
                 self.params, self.model_cfg, tokens, ctx_lens, chunk_lens,
                 slot_ids, block_tables, self.kv_cache, ad,
-                attn_impl=self.attn_impl, mesh=self._attn_mesh)
+                attn_impl=self.attn_impl, mesh=self._attn_mesh,
+                **self._pool_kw(seats, tokens.shape[0])))
 
     def _exec_decode_verify(self, tokens, ctx_lens, chunk_lens, slot_ids,
                             block_tables):
@@ -2075,7 +2198,7 @@ class Engine:
                            frequency=None, repetition=None, bias=None,
                            floor_bias=None, floor_remaining=None,
                            gstate=None, gmasks=None, gclass=None,
-                           gnext=None, ad=None):
+                           gnext=None, ad=None, seats=None):
         self.faults.check("decode_dispatch", self._dispatch_rids)
         with self.devprof.dispatch(
                 "decode_multi", (tuple(tokens.shape), steps, mode,
@@ -2090,7 +2213,7 @@ class Engine:
                     logprobs_n=logprobs_n, counts=counts, presence=presence,
                     frequency=frequency, repetition=repetition, bias=bias,
                     floor_bias=floor_bias, floor_remaining=floor_remaining)
-            return transformer.decode_multi(
+            return self._keep_pool(transformer.decode_multi(
                 self.params, self.model_cfg, tokens, positions, block_tables,
                 seq_lens, active, keys, temperature, self.kv_cache, ad,
                 steps=steps, mode=mode, top_k=top_k, top_p=top_p,
@@ -2100,12 +2223,13 @@ class Engine:
                 floor_remaining=floor_remaining, gstate=gstate,
                 gmasks=gmasks, gclass=gclass, gnext=gnext,
                 attn_impl=self.attn_impl,
-                mesh=self._attn_mesh, out_mesh=self.mesh)
+                mesh=self._attn_mesh, out_mesh=self.mesh,
+                **self._pool_kw(seats, tokens.shape[0])))
 
     def _exec_forward_ragged(self, tokens, positions, slot_ids, row_seq,
                              block_tables, kv_lens, q_starts, q_lens,
-                             meta, blk_seq, last_rows, ad=None, *,
-                             kind="mixed"):
+                             meta, blk_seq, last_rows, ad=None, seats=None,
+                             *, kind="mixed"):
         # ``kind``: "mixed", or "prefill" for a packed batched prefill
         # (_run_prefill) — its fault site and dispatch span stay prefill's,
         # and its program is built without the decode rows' part
@@ -2118,12 +2242,13 @@ class Engine:
         # shard_map wrapper yet) and GSPMD partitions the reference
         # einsums on its own.
         with self.devprof.dispatch(kind, (tuple(tokens.shape),)):
-            return transformer.forward_ragged(
+            return self._keep_pool(transformer.forward_ragged(
                 self.params, self.model_cfg, tokens, positions, slot_ids,
                 row_seq, block_tables, kv_lens, q_starts, q_lens, meta,
                 blk_seq, last_rows, self.kv_cache, ad,
                 ragged_blk=self._ragged_blk, attn_impl=self._ragged_attn,
-                decode_rows=kind != "prefill")
+                decode_rows=kind != "prefill",
+                **self._pool_kw(seats, q_lens.shape[0])))
 
     def _exec_sample(self, logits, keys, temperature, top_k, top_p, *,
                      min_p=None, mode):
@@ -2163,6 +2288,7 @@ class Engine:
             self.faults.check("kv_alloc", (req.request_id,))
             shared, cached = self.block_manager.lookup_prefix(ids)
             self.block_manager.allocate(req.request_id, ids, shared_blocks=shared)
+            self._note_seat_start(req, len(ids))
             self._drop_superseded_tier_entries(ids)
             if packed:
                 # the shared blocks hold the cached tokens' KV already —
@@ -2187,7 +2313,7 @@ class Engine:
             padded = len(arrays[0])
             ctx_tok = sum(len(c[1]) for c in chunks)
         else:
-            kw = self._lora_kw(reqs, B)
+            kw = self._row_kw(reqs, B)
             n_tok = ctx_tok = int(prompt_lens[:len(reqs)].sum())
             padded = B * L
         self._demote_evicted()
@@ -2246,6 +2372,7 @@ class Engine:
             shared, cached = self.block_manager.lookup_prefix(ids)
             self.block_manager.allocate(req.request_id, ids,
                                         shared_blocks=shared)
+            self._note_seat_start(req, len(ids))
             self._drop_superseded_tier_entries(ids)
             # Compute skip: the shared blocks already hold valid KV for the
             # cached tokens, so prefill starts at the cached offset instead
@@ -2267,7 +2394,7 @@ class Engine:
         block_tables = np.zeros((1, self.cache_cfg.max_blocks_per_seq),
                                 np.int32)
         block_tables[0, :len(bt)] = bt
-        kw = self._lora_kw([req], 1)
+        kw = self._row_kw([req], 1)
         self._demote_evicted()
         with PROF.phase("dispatch"):
             logits, self.kv_cache = self._exec_prefill_chunk(
@@ -2359,6 +2486,9 @@ class Engine:
             blk_seq[start // blk:(start + -(-take // blk) * blk) // blk] = si
         meta = np.asarray([n_dec, n_dec_blocks], np.int32)
         kw = {}
+        if self.ssm_state is not None:
+            # one seat a descriptor row (a packed prefill: chunks only)
+            kw["seats"] = self._seat_ids([c[0] for c in chunks], B)
         if self._lora_names:
             # per-ROW one-hot adapter weights: the ragged trunk applies
             # LoRA on the flat (T, H) stream, so each VALID row carries
@@ -2640,7 +2770,7 @@ class Engine:
                 else "temperature"
                 if not any(r.params.needs_truncation for r in reqs)
                 else "full")
-        kw = self._lora_kw(reqs, B)
+        kw = self._row_kw(reqs, B)
         if mode == "full":
             top_k, top_p, min_p = self._truncation_arrays(reqs, B)
             kw.update(top_k=jnp.asarray(top_k), top_p=jnp.asarray(top_p),
@@ -3012,7 +3142,7 @@ class Engine:
             seq_lens[i] = nt
         self.flight.req_event_many(self._dispatch_rids, "WINDOW",
                                    steps=1)
-        kw = self._lora_kw(reqs, B)
+        kw = self._row_kw(reqs, B)
         self._demote_evicted()
         with PROF.phase("dispatch"):
             if pending is not None:
@@ -4165,7 +4295,7 @@ class Engine:
                 tokens = jnp.zeros((B, L), jnp.int32)
                 lens = jnp.ones((B,), jnp.int32)
                 slots = jnp.full((B, L), PAD_SLOT, jnp.int32)
-                wkw = self._lora_kw([], B)
+                wkw = self._row_kw([], B)
                 logits, self.kv_cache = self._exec_prefill(tokens, lens,
                                                            slots, **wkw)
                 self._warm_sampling(logits, sample_modes)
@@ -4175,7 +4305,7 @@ class Engine:
                 slots = jnp.full((B,), PAD_SLOT, jnp.int32)
                 bt = jnp.zeros((B, self.cache_cfg.max_blocks_per_seq), jnp.int32)
                 seq_lens = jnp.ones((B,), jnp.int32)
-                wkw = self._lora_kw([], B)
+                wkw = self._row_kw([], B)
                 logits, self.kv_cache = self._exec_decode(
                     tokens, positions, slots, bt, seq_lens, **wkw)
                 self._warm_sampling(logits, sample_modes)
@@ -4308,7 +4438,7 @@ class Engine:
                 slots = jnp.full((1, C), PAD_SLOT, jnp.int32)
                 bt = jnp.zeros((1, self.cache_cfg.max_blocks_per_seq),
                                jnp.int32)
-                ckw = self._lora_kw([], 1)
+                ckw = self._row_kw([], 1)
                 logits, self.kv_cache = self._exec_prefill_chunk(
                     tokens, jnp.zeros((1,), jnp.int32),
                     jnp.ones((1,), jnp.int32), slots, bt, **ckw)

@@ -62,6 +62,39 @@ def bytes_per_block(model_cfg: ModelConfig, cache_cfg: CacheConfig,
             * per_token)
 
 
+def _ssm_layer(c: ModelConfig, num_seats: int) -> dict:
+    """One layer of the recurrent-state pool, as shapes."""
+    return {"state": jax.ShapeDtypeStruct(
+                (num_seats + 1, c.mamba_n_heads, c.mamba_d_head,
+                 c.mamba_d_state), jnp.float32),
+            "conv": jax.ShapeDtypeStruct(
+                (num_seats + 1, c.mamba_d_conv - 1, c.mamba_conv_dim),
+                jnp.dtype(c.dtype))}
+
+
+def ssm_state_bytes(model_cfg: ModelConfig, num_seats: int) -> int:
+    """Bytes :func:`create_ssm_state` allocates for ``num_seats`` seats
+    (the trash seat counted): zero for a model without recurrent state."""
+    if not model_cfg.has_ssm:
+        return 0
+    return model_cfg.num_layers * sum(
+        math.prod(x.shape) * x.dtype.itemsize
+        for x in _ssm_layer(model_cfg, num_seats).values())
+
+
+def create_ssm_state(model_cfg: ModelConfig, num_seats: int) -> list[dict]:
+    """Zero-initialised recurrent state of a model with state-space layers
+    (Falcon-H1), per layer ``{"state": (seats + 1, H, P, N) float32,
+    "conv": (seats + 1, W - 1, channels)}``: one slot a running sequence —
+    NOT a page a token like the KV cache beside it — and a last one that
+    padding rows read and write (``SeatPool.trash``).  Float32 state: the
+    recurrence accumulates over every token of a sequence.  The trunks
+    update it in place (models/transformer.py, donated like the cache)."""
+    return [{k: jnp.zeros(x.shape, x.dtype)
+             for k, x in _ssm_layer(model_cfg, num_seats).items()}
+            for _ in range(model_cfg.num_layers)]
+
+
 def num_blocks_for_budget(model_cfg: ModelConfig, cache_cfg: CacheConfig,
                           hbm_bytes: int, utilization: float = 0.9,
                           weight_bytes: int | None = None,

@@ -220,6 +220,24 @@ class ServerMetrics:
             "hash had never left HBM before, so nothing was copied and "
             "the KV died as with no tier; demoted / (demoted + declined) "
             "is the admitted share of evictions")
+        # recurrent state (models with state-space layers beside
+        # attention): one slot of state a running sequence, in a pool
+        # beside the paged KV cache (tpuserve_hbm_bytes{kind="state"})
+        self.ssm_state_slots = gauge(
+            "tpuserve_ssm_state_slots",
+            "Seats of the recurrent-state pool held by a sequence (taken "
+            "with its first KV blocks, given back with them); 0 for a "
+            "model without state-space layers")
+        self.ssm_state_resets = counter(
+            "tpuserve_ssm_state_resets",
+            "Seats started from zeros for a sequence's first window: one "
+            "an admission, and one more each time a pre-empted or "
+            "salvaged sequence comes back")
+        self.ssm_rebuilt_tokens = counter(
+            "tpuserve_ssm_rebuilt_tokens",
+            "Tokens prefilled AGAIN (prompt plus everything generated) "
+            "because a pre-empted or salvaged sequence's recurrent state "
+            "was dropped: the price of having no state snapshot")
         self.kv_demote_waited = counter(
             "tpuserve_kv_blocks_demote_waited",
             "Demoted blocks whose device-to-host copy the engine loop had "
@@ -386,9 +404,11 @@ class ServerMetrics:
             "tpuserve_hbm_bytes",
             "Per-device HBM watermark by kind= weights (loaded param "
             "bytes, draft included), kv (the paged cache's full static "
-            "reservation), other (workspace/fragmentation the backend "
-            "reports beyond weights+kv) — reconciled against jax "
-            "memory_stats at engine construction",
+            "reservation), state (the recurrent-state pool of a model "
+            "with state-space layers: one slot a decode seat), other "
+            "(workspace/fragmentation the backend reports beyond "
+            "weights+kv+state) — reconciled against jax memory_stats at "
+            "engine construction",
             ["model_name", "kind"], registry=self.registry)
         self.hbm_headroom = gauge(
             "tpuserve_hbm_headroom_bytes",

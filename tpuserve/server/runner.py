@@ -1029,7 +1029,11 @@ class AsyncEngineRunner:
                                  ("engine_restarts",
                                   self.metrics.engine_restarts),
                                  ("flight_postmortems",
-                                  self.metrics.flight_postmortems)):
+                                  self.metrics.flight_postmortems),
+                                 ("ssm_state_resets",
+                                  self.metrics.ssm_state_resets),
+                                 ("ssm_rebuilt_tokens",
+                                  self.metrics.ssm_rebuilt_tokens)):
                 _advance_counter(
                     metric, sum(getattr(s, attr, 0) for s in stats_objs))
             # last-step padding-waste gauges (the bucketing win's live
@@ -1068,6 +1072,9 @@ class AsyncEngineRunner:
         # host/spill come from the engines' tier stores (exactly-one-tier:
         # the three gauges partition every resolvable prefix hash)
         label = {"model_name": self.metrics.model_name}
+        self.metrics.ssm_state_slots.set(sum(
+            seats.in_use for seats in (getattr(bm, "seats", None)
+                                       for bm in bms) if seats is not None))
         self.metrics.kv_tier_blocks.labels(tier="hbm", **label).set(
             sum(getattr(bm, "num_cached_blocks", 0) for bm in bms))
         stores = [t for t in (getattr(e, "_kv_tiers", None)
@@ -1087,6 +1094,7 @@ class AsyncEngineRunner:
             hbm = [dp.hbm_snapshot() for dp in profs]
             for kind, field in (("weights", "weights_bytes"),
                                 ("kv", "kv_reserved_bytes"),
+                                ("state", "state_bytes"),
                                 ("other", "other_bytes")):
                 self.metrics.hbm_bytes.labels(kind=kind, **label).set(
                     sum(h.get(field, 0) for h in hbm))
