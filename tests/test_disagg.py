@@ -98,22 +98,27 @@ def test_disagg_decode_pool_too_small_rejected_at_intake():
     assert disagg.prefill.block_manager.num_seqs() == 0
 
 
-def test_disagg_with_pipelined_windows_matches_colocated():
+@pytest.mark.parametrize("prefill_pipelined", [False, True],
+                         ids=["decode-pool", "both-pools"])
+def test_disagg_with_pipelined_windows_matches_colocated(prefill_pipelined):
     """The decode pool running the TPU-default decode shape (pipelined
     fused windows) must still match the plain colocated engine: adopted
     sequences enter windows with host-known first tokens, and the pool
-    drains its in-flight window at the end."""
+    drains its in-flight window at the end.  A pipelined PREFILL pool
+    leaves a first token on the device; the handoff reads it first."""
     colocated = Engine(_cfg())
     p = SamplingParams(max_tokens=9, temperature=0.0, ignore_eos=True)
     prompts = ["Hello world", "abcdefgh", "xy"]
     ref = colocated.generate(prompts, p)
 
+    pipelined = _cfg(multi_step=4, pipeline_decode=True)
     disagg = DisaggregatedEngine(
-        _cfg(), _cfg(multi_step=4, pipeline_decode=True))
+        pipelined if prefill_pipelined else _cfg(), pipelined)
     out = disagg.generate(prompts, p)
     for r, o in zip(ref, out):
         assert r.output_token_ids == o.output_token_ids
     assert disagg.decode._pending_window is None
+    assert disagg.prefill._pending_first is None
     assert disagg.prefill.block_manager.num_seqs() == 0
     assert disagg.decode.block_manager.num_seqs() == 0
 

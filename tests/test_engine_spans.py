@@ -13,6 +13,7 @@ attend.  CPU run: control flow and counts, no device number."""
 import contextlib
 import os
 import signal
+import sys
 
 import jax
 import pytest
@@ -21,6 +22,13 @@ from tpuserve.runtime import (CacheConfig, Engine, EngineConfig,
                               SamplingParams, SchedulerConfig)
 
 from tier_drive import CHURN, cold_twice, tiny_engine
+
+ROOT_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT_DIR not in sys.path:
+    sys.path.insert(0, ROOT_DIR)
+from benchmark.harness import session  # noqa: E402
+from benchmark.harness.meter import CompileMeter  # noqa: E402
+from benchmark.harness.shapes import warm_shapes  # noqa: E402
 
 TIME_LIMIT_S = 240
 ROOT = "engine.step"
@@ -252,3 +260,116 @@ def test_ctx_tokens_is_the_context_the_dispatched_rows_attend(traced):
     assert any(s["kind"] == "prefill_chunk"
                and s["ctx_tokens"] > s["actual_tokens"]
                for s in traced["steps"].values())
+
+
+# --------------------------------------------------------------------------
+# a prefill's first token is read behind the next dispatch (pipelined decode)
+# --------------------------------------------------------------------------
+
+def pipelined_engine(**sched):
+    return Engine(EngineConfig(
+        model="tiny-qwen3",
+        cache=CacheConfig(block_size=4, num_blocks=256, max_blocks_per_seq=16),
+        scheduler=SchedulerConfig(**{
+            "max_num_seqs": 8, "max_prefill_tokens": 256,
+            "min_prefill_bucket": 8, "min_decode_bucket": 2,
+            "prefill_chunk_size": 16, **sched}),
+        enable_prefix_caching=False, multi_step=4, pipeline_decode=True))
+
+
+def drive(eng, waves, params=PARAMS):
+    """Each wave of prompts joins a running batch three cycles after the
+    one before: prefills behind windows."""
+    for prompts in waves:
+        for p in prompts:
+            eng.add_request(prompt_token_ids=p, params=params)
+        for _ in range(3):
+            eng.step()
+    while eng.has_work():
+        eng.step()
+    eng.requests.clear()
+
+
+WAVES = [[[5, 6, 7], [8, 9, 10, 11]], [list(range(3, 43))],
+         [[21, 22, 23, 24, 25]], [[31, 32], [33, 34, 35]]]
+
+
+def test_no_sync_sample_between_a_prefills_dispatch_and_the_next(tmp_path):
+    """The first tokens a prefill samples stay on the device: the host
+    opens no ``sync.sample`` for them between the prefill's ``dispatch``
+    and the next cycle's ``dispatch``, and reads them right after that
+    one, in the same cycle (a second prefill directly after a first reads
+    the first's behind its own dispatch)."""
+    eng = pipelined_engine()
+    drive(eng, WAVES)                    # compile outside the trace
+    first = eng.flight.seq
+    early = eng.stats.prefill_first_token_flushed_early
+    with tracing(str(tmp_path)):
+        drive(eng, WAVES)
+    assert eng.stats.prefill_first_token_flushed_early == early
+    steps = {s["seq"]: s for s in eng.flight.steps_snapshot(limit=10_000)
+             if s["seq"] > first}
+    spans, _, _ = read_spans(str(tmp_path))
+    roots = [s for s in spans if s[2] == ROOT]
+    launches = [s for s in spans if s[2] == "dispatch"]
+    reads = [s for s in spans if s[2] == "sync.sample"]
+    assert reads
+    def kind_at(launch):
+        root = next(r for r in roots if r[0] <= launch[0] <= r[1])
+        return steps[root[3]["seq"]]["kind"]
+
+    checked = behind_a_prefill = 0
+    for prev, launch, nxt in zip([None] + launches, launches, launches[1:]):
+        if kind_at(launch) not in ("prefill", "prefill_chunk"):
+            continue
+        between = [r for r in reads if launch[1] <= r[0] < nxt[0]]
+        # what a prefill's cycle may read behind its own dispatch is the
+        # PREVIOUS prefill's record, never its own
+        if prev is None or kind_at(prev) not in ("prefill", "prefill_chunk"):
+            assert not between, \
+                "the loop read a first token before the next dispatch"
+        assert len(between) <= 1
+        behind_a_prefill += len(between)
+        checked += 1
+    assert checked > 4 and behind_a_prefill >= 1
+    # and every read follows a dispatch of its own cycle
+    for read in reads:
+        root = next(r for r in roots if r[0] <= read[0] <= r[1])
+        assert any(root[0] <= s[0] and s[1] <= read[0] for s in launches) \
+            or steps[root[3]["seq"]]["kind"] == "idle"
+
+
+def test_a_warm_engine_chains_prefill_into_window_without_a_compile():
+    """``compiles_in_window`` has a limit of 0.  After ``Engine.warmup``
+    over the traffic's shapes and the harness's ``warm_chained_decode``,
+    run as ``session.build`` runs them, a window that takes its rows'
+    input tokens from a pending prefill compiles nothing: at every decode
+    bucket, from the packed route's token vector and from the chunk
+    route's."""
+    meter = CompileMeter()
+    # a token vector of 16 from the packed route (no decode bucket has
+    # that size, so the harness's pairs do not cover it) and of 1 from
+    # the chunk route
+    eng = pipelined_engine(max_prefill_seqs=16)
+    assert eng._prefill_seqs == 16
+    shapes = warm_shapes(eng.scheduler, {"prompt_min": 2, "prompt_max": 40,
+                                         "output_max": 24, "total_max": 64})
+    eng.warmup(sample_modes=("greedy",), **shapes)
+    session.warm_chained_decode(eng, shapes["decode_buckets"])
+    assert shapes["decode_buckets"] == [2, 4, 8]
+    before = meter.snapshot()["requests"]
+    # answers long enough that the short rows still run when the long
+    # prompt's last chunk joins them
+    params = SamplingParams(max_tokens=24, temperature=0.0, ignore_eos=True)
+    short = [[10 * i + j for j in range(2, 5 + i % 3)] for i in range(1, 9)]
+    for rows in (2, 3, 5, 8):
+        # the packed route: ``rows`` prompts in one prefill, then a window
+        drive(eng, [short[:rows]], params)
+        assert eng.flight.steps_snapshot(limit=2)[0]["rows"] == rows
+        # the chunk route: the last chunk's one token joins rows - 1
+        drive(eng, [short[:rows - 1], [list(range(3, 43))]], params)
+        assert max(s["rows"] for s in eng.flight.steps_snapshot(limit=8)
+                   if s["kind"] == "window") == rows
+    assert meter.snapshot()["requests"] == before
+    assert eng.stats.prefill_first_token_deferred >= 2 * (2 + 3 + 5 + 8) - 4
+    assert eng.stats.prefill_first_token_flushed_early == 0

@@ -38,16 +38,18 @@ def _record_prefills(eng):
     """[(logits of the real rows, sampled tokens, dispatched shape)] of
     every batched prefill the engine runs from now on."""
     seen = []
-    sample = eng._sample
+    defer = eng._defer_first
 
     def spy(logits, reqs, B):
-        toks = sample(logits, reqs, B)
+        # an engine that does not pipeline (the CPU's default) reads the
+        # first tokens in the same call
+        outs = defer(logits, reqs, B)
         if eng._step_kind == "prefill":
             seen.append((np.asarray(logits, np.float32)[:len(reqs)],
-                         np.asarray(toks)[:len(reqs)].copy(),
+                         np.asarray([r.output_token_ids[-1] for r in reqs]),
                          eng.stats.step_padded_tokens))
-        return toks
-    eng._sample = spy
+        return outs
+    eng._defer_first = spy
     return seen
 
 

@@ -220,3 +220,39 @@ def test_salvage_budget_bounds_retry_loops():
     assert err is not None and "salvage budget" in str(err)
     assert eng.stats.requests_poisoned == 1
     assert eng.block_manager.num_seqs() == 0
+
+
+def test_window_flush_fault_with_a_first_token_pending(reference):
+    """The window's flush faults while a later prefill's first token is
+    still on the device (read after the window's, so still unread):
+    salvage drops both records with the rest of the in-flight state,
+    leaves no block held (strict mode checks every cycle) and replays
+    both streams token-identically."""
+    eng = Engine(EngineConfig(
+        model="tiny-qwen3",
+        cache=CacheConfig(block_size=4, num_blocks=128,
+                          max_blocks_per_seq=16),
+        scheduler=SchedulerConfig(max_num_seqs=8, min_prefill_bucket=8,
+                                  min_decode_bucket=2),
+        faults="window_flush:raise:1.0:count=1", seed=0, multi_step=4,
+        pipeline_decode=True))
+    eng.add_request(prompt_token_ids=PROMPTS[0], params=PARAMS,
+                    request_id="req-0")
+    eng.step()                              # prefill: first token pending
+    eng.step()                              # window 1, then the token read
+    assert eng._pending_window is not None and eng._pending_first is None
+    eng.add_request(prompt_token_ids=PROMPTS[1], params=PARAMS,
+                    request_id="req-1")
+    eng.step()                              # prefill behind window 1
+    assert eng._pending_window is not None and eng._pending_first is not None
+    with pytest.raises(InjectedFault):
+        eng.step()                          # window 2 enqueued; flush faults
+    assert eng._pending_first is not None
+    assert set(eng.salvage_requeue()) == {"req-0", "req-1"}
+    assert eng._pending_first is None and eng._pending_window is None
+    assert eng.block_manager.num_seqs() == 0
+    while eng.has_work():
+        eng.step()
+    assert eng.block_manager.num_seqs() == 0
+    for rid in ("req-0", "req-1"):
+        assert eng.requests[rid].output_token_ids == reference[rid]

@@ -268,6 +268,9 @@ class DisaggregatedEngine:
 
         if self.prefill.scheduler.num_waiting:
             outputs.extend(self.prefill.step())
+            # the handoff ships a request WITH its first token: read it
+            # now (a pipelined prefill engine leaves it on the device)
+            outputs.extend(self.prefill._flush_first())
             # Park freshly prefilled requests for migration; pull them out of
             # the prefill scheduler so it never decodes them.
             for req in list(self.prefill.scheduler.running):

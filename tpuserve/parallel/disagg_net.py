@@ -219,6 +219,9 @@ class PrefillHandoffEngine:
         # zombie-only pipelined window behind (scheduler idle, flush owed)
         if self.prefill.has_work():
             outputs.extend(self.prefill.step())
+            # the handoff ships a request WITH its first token: read it
+            # now (a pipelined prefill engine leaves it on the device)
+            outputs.extend(self.prefill._flush_first())
             # Freshly prefilled requests: pull out of the local scheduler
             # (this pod never decodes) and hand off — mirror of
             # parallel/disagg.DisaggregatedEngine.step's parking.  Requests

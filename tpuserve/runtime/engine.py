@@ -74,10 +74,16 @@ class EngineConfig:
     enable_prefix_caching: bool = True
     seed: int = 0
     # Pipelined decode: sampled tokens stay on device and feed the next
-    # decode step directly; host bookkeeping (detokenize, stop checks,
-    # emission) resolves one step behind, overlapped with the next step's
-    # device work, so the decode loop never stalls on a device->host read.
-    # Requests needing penalties or logprobs fall back to the sync path.
+    # dispatch directly; host bookkeeping (detokenize, stop checks,
+    # emission) resolves one dispatch behind, overlapped with the next
+    # dispatch's device work, so the loop never stalls on a device->host
+    # read with the chip's queue empty.  That holds for a fused window's
+    # tokens, a single decode step's, and a prefill's first token (read
+    # behind the next window or prefill, Engine._flush_first).  What the
+    # host must know before the next dispatch is read at once instead:
+    # count-dependent penalties, an active min_tokens floor and guided
+    # rows (first tokens and windows), logprobs on the single-step path,
+    # and every dispatch that is not a fused window or a prefill.
     # None = auto: on for TPU (async dispatch, real overlap), off for CPU
     # (synchronous backend — nothing overlaps, the extra dispatches only
     # cost).
@@ -223,6 +229,13 @@ class EngineStats:
     prefill_tokens_total: int = 0
     prefill_padded_tokens_total: int = 0
     prefill_packed_steps: int = 0
+    # requests whose first token was still on the device when the next
+    # dispatch was enqueued (read behind it), against those whose record
+    # had to be read before it (Engine._flush_first): deferred /
+    # (deferred + flushed_early) is the share of prefills that do not
+    # drain the chip's queue
+    prefill_first_token_deferred: int = 0
+    prefill_first_token_flushed_early: int = 0
     prompt_tokens: int = 0
     generated_tokens: int = 0
     preemptions: int = 0
@@ -332,6 +345,21 @@ class PendingWindow:
     # device, exactly like toks[:, -1] chains the input tokens; the host
     # mirror advances at flush through the same table
     gstate: jax.Array | None = None
+
+
+@dataclasses.dataclass
+class PendingFirst:
+    """A prefill whose first tokens are sampled but not read: the (B,)
+    tokens stay on device and feed the next window's rows directly, and
+    the host reads them behind that dispatch (Engine._flush_first)."""
+    reqs: list
+    toks: jax.Array                  # (B,) int32, device-resident
+    # (chosen_lp (B,), top_ids (B, N), top_lps (B, N)) device arrays when
+    # a row asked for logprobs, else None
+    lp: tuple | None = None
+    # the sampled-from logits, kept only for rows on the guided
+    # substitution path (read at once: the host picks their token)
+    logits: jax.Array | None = None
 
 
 @jax.jit
@@ -788,6 +816,7 @@ class Engine:
         self._greedy_cache: dict[int, tuple] = {}
         self._pending: Optional[PendingDecode] = None
         self._pending_window: Optional[PendingWindow] = None
+        self._pending_first: Optional[PendingFirst] = None
         self._pipeline_decode = config.resolve_pipeline_decode()
         self._multi_step = config.resolve_multi_step()
         self._min_multi_step = min(max(1, config.min_multi_step),
@@ -1458,8 +1487,9 @@ class Engine:
         blocks, preempt the loosest-class most-recent running rows
         (bounded per cycle and by each victim's preemption budget)
         through the token-identical re-prefill replay path.  Flushes the
-        pipelined window first — preempting a request with an in-flight
-        device window would double-append its tokens at replay."""
+        pipelined window and a pending first token first — preempting a
+        request with tokens still on the device would double-append them
+        at replay."""
         slo, sched = self._slo, self.scheduler
         if slo is None or not sched.waiting or not sched.running:
             return []
@@ -1495,7 +1525,8 @@ class Engine:
 
         if not victims() or not shortfall():
             return []
-        outputs = self._flush_pending() + self._flush_window()
+        outputs = (self._flush_pending() + self._flush_window()
+                   + self._flush_first())
         for _ in range(slo.cfg.max_preempt_per_cycle):
             cand = victims()
             if not cand or not shortfall():
@@ -1523,6 +1554,7 @@ class Engine:
         Returns the re-queued request ids (queue-head first)."""
         self._pending = None
         self._pending_window = None
+        self._pending_first = None
         cohort = list(self.scheduler.running)
         self.scheduler.running.clear()
         seen = ({r.request_id for r in cohort}
@@ -1552,6 +1584,7 @@ class Engine:
         # keeps "no work" meaning "every demoted block is filed"
         return (self.scheduler.has_work() or self._pending is not None
                 or self._pending_window is not None
+                or self._pending_first is not None
                 or bool(self._restores)
                 or (self._kv_tiers is not None
                     and self._kv_tiers.in_flight_batches > 0))
@@ -1641,7 +1674,8 @@ class Engine:
             batch = self.scheduler.schedule()
         if batch is None:
             # nothing schedulable but a decode result may still be in flight
-            outputs = pre + self._flush_pending() + self._flush_window()
+            outputs = (pre + self._flush_pending() + self._flush_window()
+                       + self._flush_first())
             # nor anything left for a demotion's copy to hide behind
             self._land_demotions(wait=True)
             return outputs
@@ -2329,14 +2363,7 @@ class Engine:
         self.stats.num_prefill_steps += 1
         self.stats.prefill_packed_steps += packed
         self._note_step_tokens(n_tok, padded, ctx_tok, prefill=True)
-        new_tokens = self._sample(logits, reqs, B)
-        now = self.clock.monotonic()
-        for req in reqs:
-            if req.first_token_time is None:      # not a re-prefill after preemption
-                req.first_token_time = now
-                self.stats.ttft_sum += now - req.arrival_time
-                self.stats.ttft_count += 1
-        return self._append_and_emit(reqs, new_tokens, from_prefill=True)
+        return self._defer_first(logits, reqs, B)
 
     def _prefill_tokens(self, req: Request) -> list[int]:
         """Tokens to prefill — prompt plus, after a preemption, everything
@@ -2406,17 +2433,12 @@ class Engine:
         self.stats.num_prefill_steps += 1
         self._note_step_tokens(n, C, done + n, prefill=True)
         if req.num_prefilled < len(ids):
-            # more chunks to go: back to the head of the queue
+            # more chunks to go: back to the head of the queue (an earlier
+            # prefill's first tokens are read behind this chunk)
             self.scheduler.waiting.appendleft(req)
-            return []
+            return self._flush_first(deferred=True)
         self.scheduler.mark_running([req])
-        new_tokens = self._sample(logits, [req], 1)
-        now = self.clock.monotonic()
-        if req.first_token_time is None:
-            req.first_token_time = now
-            self.stats.ttft_sum += now - req.arrival_time
-            self.stats.ttft_count += 1
-        return self._append_and_emit([req], new_tokens, from_prefill=True)
+        return self._defer_first(logits, [req], 1)
 
     # ---- mixed ragged prefill+decode ----------------------------------
 
@@ -2530,7 +2552,8 @@ class Engine:
         and the per-step ``_sample`` (penalties, logprobs, guided — all
         host-side, identical to the phase-split paths) applies unchanged.
         """
-        outputs = self._flush_pending() + self._flush_window()
+        outputs = (self._flush_pending() + self._flush_window()
+                   + self._flush_first())
         decode_reqs = [r for r in batch.requests if not r.finished]
         self._dispatch_rids = tuple(r.request_id for r in decode_reqs)
         self._step_kind = "mixed"
@@ -2699,29 +2722,54 @@ class Engine:
             # window first — the same staleness rule the per-step path
             # enforces (pipeline_ok in _run_decode).
             outputs += self._flush_window()
+        pf = self._pending_first
+        if pf is not None and any(
+                r.params.needs_penalties or r.params.guided is not None
+                or (r.params.needs_min_tokens
+                    and r.params.min_tokens_active(
+                        len(r.output_token_ids), slack=1))
+                for r in pf.reqs):
+            # the same staleness rule for a prefill's first token still on
+            # the device: penalty counts and the min_tokens floor read host
+            # history, a guided row's FSM mirror advances by the token
+            outputs += self._flush_first()
+            pf = None
         p = self._pending_window
         reqs = [r for r in batch.requests if not r.finished]
+        # rid -> its row in the in-flight window / the pending prefill (a
+        # row is in one record at most), and how many of its tokens are
+        # sampled on the device but not read yet
         pend_idx: dict[str, int] = {}
+        first_idx: dict[str, int] = {}
+        ahead: dict[str, int] = {}
+        if pf is not None:
+            first_idx = {r.request_id: i for i, r in enumerate(pf.reqs)}
+            ahead = dict.fromkeys(first_idx, 1)
         if p is not None:
             pend_idx = {r.request_id: i for i, r in enumerate(p.reqs)}
-            # host-known completion rules: a request whose in-flight window
-            # reaches max_tokens / max_model_len must not get another
-            # window — it finishes when ``p`` is flushed below.
+            ahead.update(dict.fromkeys(pend_idx, p.steps))
+        if ahead:
+            # host-known completion rules: a request whose unread tokens
+            # reach max_tokens / max_model_len must not get another
+            # window — it finishes when its record is flushed below.
             reqs = [r for r in reqs
-                    if r.request_id not in pend_idx
-                    or (len(r.output_token_ids) + p.steps
+                    if r.request_id not in ahead
+                    or (len(r.output_token_ids) + ahead[r.request_id]
                         < r.params.max_tokens
-                        and r.num_tokens + p.steps < self.max_seq_len)]
+                        and r.num_tokens + ahead[r.request_id]
+                        < self.max_seq_len)]
         if not reqs:
-            return outputs + self._flush_window()
+            return outputs + self._flush_window() + self._flush_first()
         self._dispatch_rids = tuple(r.request_id for r in reqs)
         self._step_kind = "window"
         self.faults.check("kv_alloc", self._dispatch_rids)
         # Rows continuing from the in-flight window need p.steps extra KV
-        # slots (its advance hasn't run yet); reserving the conservative
-        # bound for every row over-reserves fresh rows by p.steps slots,
-        # which stay attached and get used as the sequence grows.
-        window_need = S + (p.steps if p is not None else 0)
+        # slots (its advance hasn't run yet), rows of a pending prefill
+        # one; reserving the conservative bound for every row
+        # over-reserves the others by that many slots, which stay
+        # attached and get used as the sequence grows.
+        window_need = S + max(p.steps if p is not None else 0,
+                              1 if pf is not None else 0)
         if not self._try_reserve_window(reqs, window_need):
             # _run_decode flushes the in-flight window before preempting
             return outputs + self._run_decode(batch)
@@ -2729,6 +2777,8 @@ class Engine:
         host_tokens = np.zeros((B,), np.int32)
         use_host = np.ones((B,), bool)
         gather = np.zeros((B,), np.int32)
+        not_first = np.ones((B,), bool)
+        gather_first = np.zeros((B,), np.int32)
         positions = np.zeros((B,), np.int32)
         seq_lens = np.ones((B,), np.int32)
         active = np.zeros((B,), bool)
@@ -2739,15 +2789,19 @@ class Engine:
                                 np.int32)
         for i, r in enumerate(reqs):
             pi = pend_idx.get(r.request_id)
-            extra = p.steps if pi is not None else 0
+            extra = ahead.get(r.request_id, 0)
             nt = r.num_tokens + extra
-            if pi is None:
-                host_tokens[i] = r.output_token_ids[-1]
-            else:
+            if pi is not None:
                 # input token = last column of the in-flight window,
                 # gathered on device — no host round-trip
                 use_host[i] = False
                 gather[i] = pi
+            elif extra:
+                # input token = the pending prefill's sampled token
+                not_first[i] = False
+                gather_first[i] = first_idx[r.request_id]
+            else:
+                host_tokens[i] = r.output_token_ids[-1]
             positions[i] = nt - 1
             seq_lens[i] = nt
             active[i] = True
@@ -2831,12 +2885,14 @@ class Engine:
             self.stats.guided_fsm_windows += 1
         self._demote_evicted()
         with PROF.phase("dispatch"):
+            tokens = jnp.asarray(host_tokens)
             if p is not None:
                 tokens = _select_tokens(p.toks[:, -1], jnp.asarray(gather),
-                                        jnp.asarray(host_tokens),
-                                        jnp.asarray(use_host))
-            else:
-                tokens = jnp.asarray(host_tokens)
+                                        tokens, jnp.asarray(use_host))
+            if pf is not None:
+                # the second device source, over the first select's result
+                tokens = _select_tokens(pf.toks, jnp.asarray(gather_first),
+                                        tokens, jnp.asarray(not_first))
             res = self._exec_decode_multi(
                 tokens, jnp.asarray(positions),
                 jnp.asarray(block_tables), jnp.asarray(seq_lens),
@@ -2865,8 +2921,9 @@ class Engine:
             # so any later owner of those blocks overwrites the stale slots
             # (same invariant the single-step pipeline established for its
             # one-slot overrun) — and its tokens are dropped at the next
-            # flush.
-            outputs += self._flush_window()
+            # flush.  A row that ends on its prefill's first token (read
+            # here, after the window's) is the same case.
+            outputs += self._flush_window() + self._flush_first(deferred=True)
             self._pending_window = PendingWindow(reqs=list(reqs), toks=toks,
                                                  steps=S, lp=window_lp,
                                                  gstate=gstate_out)
@@ -3059,8 +3116,8 @@ class Engine:
         outputs: list[RequestOutput] = []
         # resolve any in-flight fused window first: this path mutates
         # request/block state (append_slot, preemption) that must see the
-        # window's finishes
-        outputs += self._flush_window()
+        # window's finishes, and a row's first token as its input
+        outputs += self._flush_window() + self._flush_first()
         reqs = [r for r in batch.requests if not r.finished]
         pending = self._pending
         # Penalties/logprobs read host-side token history, which is one step
@@ -3180,7 +3237,7 @@ class Engine:
         outputs: list[RequestOutput] = []
         if self._pending is not None:           # spec steps are synchronous
             outputs += self._flush_pending()
-        outputs += self._flush_window()
+        outputs += self._flush_window() + self._flush_first()
         reqs = [r for r in batch.requests if not r.finished]
         if not reqs:
             return outputs
@@ -3357,9 +3414,10 @@ class Engine:
         with PROF.phase("sample"):
             return self._sample_sync(logits, reqs, B)
 
-    def _sample_sync(self, logits: jnp.ndarray, reqs: list[Request],
-                     B: int) -> np.ndarray:
-        n = len(reqs)
+    def _sample_enqueue(self, logits: jnp.ndarray, reqs: list[Request],
+                        B: int) -> tuple:
+        """The per-step sampling rules and the sampler, enqueued: returns
+        (the logits sampled from, DEVICE tokens (B,))."""
         if any(r.params.needs_penalties for r in reqs):
             logits = self._apply_penalties(logits, reqs, B)
         if any(r.params.needs_logit_bias for r in reqs):
@@ -3374,7 +3432,12 @@ class Engine:
             # grammar-FSM rows: TRUE logit masking before sampling — the
             # sampled token is legal by construction, no substitution
             logits = self._apply_fsm_mask(logits, reqs, B)
-        toks = self._sample_modes(logits, reqs, B, frozenset())
+        return logits, self._sample_modes(logits, reqs, B, frozenset())
+
+    def _sample_sync(self, logits: jnp.ndarray, reqs: list[Request],
+                     B: int) -> np.ndarray:
+        n = len(reqs)
+        logits, toks = self._sample_enqueue(logits, reqs, B)
         if any(r.params.logprobs is not None for r in reqs):
             self._record_logprobs(logits, toks, reqs)
         with self._sync("sample"):
@@ -3384,6 +3447,63 @@ class Engine:
             # legacy substitution path: only rows WITHOUT a compiled FSM
             toks_np = self._apply_guided(logits, toks_np, reqs)
         return toks_np
+
+    def _defer_first(self, logits: jnp.ndarray, reqs: list[Request],
+                     B: int) -> list[RequestOutput]:
+        """A prefill's tail: enqueue the sampler (and the logprobs a row
+        asks for) behind the trunk and keep the results on the device, so
+        the host reads them behind the NEXT dispatch and the chip's queue
+        does not drain after every prefill.  The record of an earlier
+        prefill is read here, behind this one.  An engine that does not
+        pipeline, or a row whose token the host picks (guided
+        substitution), reads its own record at once: one read-and-emit
+        path either way."""
+        with PROF.phase("sample"):
+            logits, toks = self._sample_enqueue(logits, reqs, B)
+            lp = None
+            if any(r.params.logprobs is not None for r in reqs):
+                lp = self._logprobs_enqueue(logits, toks, reqs)
+        outputs = self._flush_first(deferred=True)
+        substituted = any(r.request_id in self._guided for r in reqs)
+        self._pending_first = PendingFirst(
+            reqs=list(reqs), toks=toks, lp=lp,
+            logits=logits if substituted else None)
+        if substituted or not self._pipeline_decode:
+            outputs += self._flush_first()
+        return outputs
+
+    def _flush_first(self, deferred: bool = False) -> list[RequestOutput]:
+        """Read the pending prefill's first tokens and emit them.
+        ``deferred``: a dispatch was enqueued since the record was made,
+        so the chip has work while the host waits here.  A request aborted
+        meanwhile is dropped, as a window's row is."""
+        p, self._pending_first = self._pending_first, None
+        if p is None:
+            return []
+        if deferred:
+            self.stats.prefill_first_token_deferred += len(p.reqs)
+        else:
+            self.stats.prefill_first_token_flushed_early += len(p.reqs)
+        with self._sync("sample"):
+            # tpulint: sync-ok(THE designated sync for a prefill's first tokens: one device_get a prefill, behind the next dispatch wherever the host can wait)
+            toks, lp = jax.device_get((p.toks, p.lp))
+        toks = np.array(toks[:len(p.reqs)])   # writable: guided picks in place
+        live = [i for i, r in enumerate(p.reqs) if not r.finished]
+        now = self.clock.monotonic()
+        for i in live:
+            r = p.reqs[i]
+            if r.first_token_time is None:   # not a re-prefill after preemption
+                r.first_token_time = now
+                self.stats.ttft_sum += now - r.arrival_time
+                self.stats.ttft_count += 1
+            if lp is not None and r.params.logprobs is not None:
+                self._append_logprob_entry(r, int(toks[i]), lp[0][i],
+                                           lp[1][i], lp[2][i])
+        if p.logits is not None:
+            # legacy substitution path: only rows WITHOUT a compiled FSM
+            toks = self._apply_guided(p.logits, toks, p.reqs)
+        return self._append_and_emit([p.reqs[i] for i in live], toks[live],
+                                     from_prefill=True)
 
     GUIDED_TOP_K = 32
 
@@ -3818,10 +3938,15 @@ class Engine:
             logits, jnp.asarray(out_tokens), jnp.asarray(mask),
             jnp.asarray(presence), jnp.asarray(frequency), jnp.asarray(repetition))
 
+    def _logprobs_enqueue(self, logits: jnp.ndarray, toks: jnp.ndarray,
+                          reqs: list[Request]) -> tuple:
+        top_n = min(max(r.params.logprobs or 0 for r in reqs) or 1, self.MAX_LOGPROBS)
+        return sampling_ops.compute_logprobs(logits, toks, top_n)
+
     def _record_logprobs(self, logits: jnp.ndarray, toks: jnp.ndarray,
                          reqs: list[Request]) -> None:
-        top_n = min(max(r.params.logprobs or 0 for r in reqs) or 1, self.MAX_LOGPROBS)
-        chosen_lp, top_ids, top_lps = sampling_ops.compute_logprobs(logits, toks, top_n)
+        chosen_lp, top_ids, top_lps = self._logprobs_enqueue(logits, toks,
+                                                             reqs)
         chosen_lp = np.asarray(chosen_lp)
         top_ids = np.asarray(top_ids)
         top_lps = np.asarray(top_lps)
@@ -4277,6 +4402,26 @@ class Engine:
                  for r in range(blk, top + 1, blk)})]
             prefill_buckets = []
         decode_buckets = decode_buckets or [scfg.min_decode_bucket]
+        chunk = scfg.prefill_chunk_size
+        chunk_set = set(chunk_buckets)
+        if not scfg.allow_chunked_prefill:
+            chunk_set = set()     # no chunk route exists (pp engine)
+        if (self.max_seq_len > chunk and scfg.allow_chunked_prefill
+                and not scfg.mixed_batching):
+            # long prompts hit the chunked path; the full-chunk
+            # executable must be warm or the first long request stalls
+            # the loop on a compile.  chunk_buckets adds the padded
+            # tail shapes of non-multiple prompt lengths.
+            chunk_set.add(chunk)
+        # how many rows a prefill's pending first tokens can have
+        # (PendingFirst.toks): the packed route's one descriptor width,
+        # the (B, L) route's batches, 1 on the chunk route
+        first_sizes = {self._prefill_seqs for _, _, kind in ragged_warm
+                       if kind == "prefill"}
+        first_sizes |= {bk[0] if isinstance(bk, tuple) else 1
+                        for bk in prefill_buckets}
+        if chunk_set:
+            first_sizes.add(1)
         logits = None
         # Two rounds: round 1 compiles each executable against the cache
         # layouts it happens to see; the kv_cache arrays that come OUT may
@@ -4397,6 +4542,15 @@ class Engine:
                         jnp.zeros((B,), jnp.int32),
                         jnp.zeros((B,), jnp.int32),
                         jnp.zeros((B,), bool)))
+                    # and a window's rows take a pending prefill's first
+                    # tokens the same way, from a vector of each size a
+                    # prefill can leave, into every decode bucket
+                    for n in sorted(first_sizes - {B}):
+                        self._warm_tails.append(_select_tokens(
+                            jnp.zeros((n,), jnp.int32),
+                            jnp.zeros((B,), jnp.int32),
+                            jnp.zeros((B,), jnp.int32),
+                            jnp.zeros((B,), bool)))
                 if self._spec is not None:
                     # the speculative verify pass is its own executable;
                     # left cold, the first spec step stalls on its compile
@@ -4421,18 +4575,6 @@ class Engine:
                                 jnp.ones((B,), jnp.float32),
                                 jnp.zeros((B,), jnp.float32))
                         self._warm_tails.append(acc)
-            chunk = self.scheduler.cfg.prefill_chunk_size
-            chunk_set = set(chunk_buckets)
-            if not self.scheduler.cfg.allow_chunked_prefill:
-                chunk_set = set()     # no chunk route exists (pp engine)
-            if (self.max_seq_len > chunk
-                    and self.scheduler.cfg.allow_chunked_prefill
-                    and not self.scheduler.cfg.mixed_batching):
-                # long prompts hit the chunked path; the full-chunk
-                # executable must be warm or the first long request stalls
-                # the loop on a compile.  chunk_buckets adds the padded
-                # tail shapes of non-multiple prompt lengths.
-                chunk_set.add(chunk)
             for C in sorted(chunk_set):
                 tokens = jnp.zeros((1, C), jnp.int32)
                 slots = jnp.full((1, C), PAD_SLOT, jnp.int32)

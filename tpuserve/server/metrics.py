@@ -165,6 +165,20 @@ class ServerMetrics:
             "Batched prefills dispatched packed on one flat token axis "
             "through the ragged trunk (single chip, no mesh, pages in the "
             "model's dtype) rather than as a (batch x length) grid")
+        self.first_tokens_deferred = counter(
+            "tpuserve_prefill_first_tokens_deferred",
+            "Prefilled requests whose first token was still on the device "
+            "when the next dispatch was enqueued: the host read it behind "
+            "that dispatch, so the chip's queue did not drain after the "
+            "prefill (pipelined decode, runtime/engine.py _flush_first)")
+        self.first_tokens_flushed_early = counter(
+            "tpuserve_prefill_first_tokens_flushed_early",
+            "Prefilled requests whose first token the host read BEFORE "
+            "the next dispatch (penalties, an active min_tokens floor, "
+            "guided rows, a dispatch that is not a fused window, an engine "
+            "that does not pipeline); deferred / (deferred + flushed "
+            "early) is the share of prefills that no longer drain the "
+            "chip's queue")
         self.mixed_steps = counter(
             "tpuserve_mixed_steps",
             "Ragged mixed prefill+decode dispatches (scheduler mixed "

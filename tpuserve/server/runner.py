@@ -1002,6 +1002,10 @@ class AsyncEngineRunner:
                                   self.metrics.prefill_padded_tokens_total),
                                  ("prefill_packed_steps",
                                   self.metrics.prefill_packed_steps),
+                                 ("prefill_first_token_deferred",
+                                  self.metrics.first_tokens_deferred),
+                                 ("prefill_first_token_flushed_early",
+                                  self.metrics.first_tokens_flushed_early),
                                  ("num_mixed_steps",
                                   self.metrics.mixed_steps),
                                  ("kv_demoted_blocks",
