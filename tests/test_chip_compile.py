@@ -226,6 +226,96 @@ def test_the_state_update_kernel_compiles_for_v5e(rows, one_chip):
     assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
 
 
+# (rows, contraction, output columns) of the expert layer's grouped
+# products at Mellum2-12B-A2.5B's widths (64 experts of width 896 on a
+# hidden size of 2,304, 8 a token): the smallest and the largest decode
+# bucket, and packed prefills of 512 and 8,192 tokens (max_prefill_tokens)
+MOE_EXPERTS = 64
+MOE_SHAPES = {
+    "decode-8.up": (8 * 8, 2304, 896),
+    "decode-64.up": (64 * 8, 2304, 896),
+    "decode-64.down": (64 * 8, 896, 2304),
+    "prefill-512.up": (512 * 8, 2304, 896),
+    "prefill-8192.up": (8192 * 8, 2304, 896),
+    "prefill-8192.down": (8192 * 8, 896, 2304),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(MOE_SHAPES))
+def test_the_grouped_product_compiles_for_v5e(shape, one_chip):
+    """``_moe_grouped_matmul`` at the tiles ``tiling`` picks for each
+    regime: compiled (the blocks fit the VMEM limit the kernel asks for),
+    and named as the benchmark's ``moe.*`` readers match it."""
+    import re
+
+    from benchmark.layer_metrics import _moe_trace
+    from tpuserve.ops.pallas_moe_gmm import KERNEL_NAME, grouped_matmul
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert KERNEL_NAME == _moe_trace.KERNEL == "_moe_grouped_matmul"
+    m, k, n = MOE_SHAPES[shape]
+    text = jax.jit(lambda lhs, rhs, sizes: grouped_matmul(
+        lhs, rhs, sizes, interpret=False)).lower(
+            S((m, k), jnp.bfloat16), S((MOE_EXPERTS, k, n), jnp.bfloat16),
+            S((MOE_EXPERTS,), jnp.int32)).compile().as_text()
+    assert re.search(rf"%{KERNEL_NAME}(\.\d+)? = [^\n]*custom-call\([^\n]*"
+                     r"tpu_custom_call", text)
+
+
+# flat-token rungs a packed prefill of the benchmark's cells can take
+# (scheduler.packed_prefill_bucket: 13 rungs to max_prefill_tokens; all 13
+# and the whole 12-layer trunk were compiled once by hand, PR 35) and the
+# largest decode bucket
+MOE_TOKENS = [128, 512, 1024, 1536, 2048, 3072, 4096, 8192, 64]
+
+
+@pytest.mark.parametrize("tokens", MOE_TOKENS)
+def test_the_expert_layer_compiles_for_v5e_at_every_rung(tokens, one_chip,
+                                                         monkeypatch):
+    """The whole sparse expert layer (router, sort, the rows' gather, three
+    grouped products, the add-back) at Mellum2-12B-A2.5B's widths.  What
+    this guards: the TPU compiler refuses the PLAIN row gather of 1,536
+    tokens into 12,288 rows (scoped VMEM, by 0.4 MB; found on the chip,
+    PR 35) and no other rung; ``_gather_rows`` compiles at all of them."""
+    import dataclasses
+
+    from tpuserve.models import transformer
+    from tpuserve.models.config import get_model_config
+    from tpuserve.ops.pallas_moe_gmm import grouped_matmul
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    # the kernel's wrapper asks jax.default_backend(), which is the CPU
+    # here: steer it to the compiled kernel
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(
+        get_model_config("JetBrains/Mellum2-12B-A2.5B-Instruct"),
+        num_layers=1)
+    H, I, E = cfg.hidden_size, cfg.expert_intermediate_size, cfg.num_experts
+    bf16 = jnp.bfloat16
+    p = {"router": {"kernel": S((H, E), bf16)},
+         "experts": {"gate_proj": {"kernel": S((E, H, I), bf16)},
+                     "up_proj": {"kernel": S((E, H, I), bf16)},
+                     "down_proj": {"kernel": S((E, I, H), bf16)}}}
+    compiled = jax.jit(lambda x, p: transformer._moe_mlp(x, p, cfg)).lower(
+        S((tokens, H), bf16), p).compile()
+    text = compiled.as_text()
+    assert text.count("_moe_grouped_matmul") >= 3
+    # the experts' kernels go to the custom calls as they are: no copy
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 5 * tokens * 8 * H * 2 + (64 << 20)
+    if tokens == 1536:
+        k = cfg.num_experts_per_tok
+        with pytest.raises(Exception, match="vmem"):
+            jax.jit(lambda x, order, w, sizes:
+                    grouped_matmul(x[order // k], w, sizes, interpret=False)
+                    ).lower(S((tokens, H), bf16), S((tokens * k,), jnp.int32),
+                            S((E, H, I), bf16), S((E,), jnp.int32)).compile()
+
+
 @pytest.mark.parametrize("kernel", ["decode", "flash", "ragged"])
 def test_the_custom_call_carries_the_name_the_benchmark_matches(
         kernel, one_chip, monkeypatch):

@@ -75,16 +75,16 @@ def test_absorbed_decode_matches_naive_prefill_row():
     # full prefill of 6 tokens
     cache = create_kv_cache(cfg, cc)
     slots = jnp.asarray([[0, 1, 2, 3, 4, 5]], jnp.int32)
-    full_logits, _ = transformer.prefill(
+    full_logits, *_ = transformer.prefill(
         params, cfg, toks, jnp.asarray([6], jnp.int32), slots, cache)
 
     # prefill 5, then absorbed decode of token 6
     cache = create_kv_cache(cfg, cc)
-    logits5, cache = transformer.prefill(
+    logits5, cache, *_ = transformer.prefill(
         params, cfg, toks[:, :5].at[:, :].get().reshape(1, 5),
         jnp.asarray([5], jnp.int32), slots[:, :5], cache)
     bt = jnp.asarray([[0, 1, 0, 0, 0, 0, 0, 0]], jnp.int32)
-    dec_logits, _ = transformer.decode_step(
+    dec_logits, *_ = transformer.decode_step(
         params, cfg, toks[:, 5], jnp.asarray([5], jnp.int32),
         jnp.asarray([5], jnp.int32), bt, jnp.asarray([6], jnp.int32), cache)
     np.testing.assert_allclose(np.asarray(dec_logits),
@@ -231,12 +231,12 @@ def test_mla_under_tp_mesh():
     for b in range(B):
         for t in range(5):
             slots[b, t] = 2 * b * cc.block_size + t
-    logits, cache = transformer.prefill(params, cfg, toks, lens,
-                                        jnp.asarray(slots), cache)
+    logits, cache, *_ = transformer.prefill(params, cfg, toks, lens,
+                                            jnp.asarray(slots), cache)
     bt = np.zeros((B, 4), np.int32)
     for b in range(B):
         bt[b, 0], bt[b, 1] = 2 * b, 2 * b + 1
-    logits, cache = transformer.decode_step(
+    logits, cache, *_ = transformer.decode_step(
         params, cfg, jnp.ones((B,), jnp.int32),
         jnp.full((B,), 5, jnp.int32),
         jnp.asarray([(2 * b + 1) * cc.block_size for b in range(B)],

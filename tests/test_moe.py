@@ -134,10 +134,10 @@ def test_ep_sharded_decode_matches_single_device(cfg, params):
         for b in range(2):
             for t in range(int(lens[b])):
                 slots[b, t] = (2 * b) * 4 + t
-        logits_p, cache_in = transformer.prefill(
+        logits_p, cache_in, _ = transformer.prefill(
             params_in, cfg, tokens, lens, jnp.asarray(slots), cache_in)
         bt = jnp.asarray([[0, 1, 0, 0], [2, 3, 0, 0]], jnp.int32)
-        logits_d, _ = transformer.decode_step(
+        logits_d, _, _ = transformer.decode_step(
             params_in, cfg, jnp.asarray([9, 9], jnp.int32),
             jnp.asarray([4, 3], jnp.int32),
             jnp.asarray([1 * 4, 2 * 4 + 3], jnp.int32), bt,
@@ -200,3 +200,186 @@ def test_moe_config_rejects_interleaved_dense():
     cfg = config_from_hf_json("x", {**base, "mlp_only_layers": [],
                                     "decoder_sparse_step": 1})
     assert cfg.num_experts == 4 and cfg.moe_intermediate_size == 32
+
+
+# --------------------------------------------------------------------------
+# sparse dispatch (ops/pallas_moe_gmm.py) against the dense form
+# --------------------------------------------------------------------------
+
+def dense_oracle(x, p, cfg):
+    """The expert layer as it was before the sparse dispatch: every expert
+    on every token (``"th,ehi->tei"``), the unpicked ones weighted zero.
+    Kept here as the oracle."""
+    xt = x.reshape(-1, x.shape[-1])
+    T = xt.shape[0]
+    router = transformer._linear(xt, p["router"]).astype(jnp.float32)
+    scores = (jax.nn.sigmoid(router) if cfg.moe_scoring == "sigmoid"
+              else jax.nn.softmax(router, axis=-1))
+    choice = scores
+    if "router_bias" in p:
+        choice = choice + p["router_bias"]["bias"][None, :]
+    E = scores.shape[-1]
+    if cfg.moe_n_group > 1:
+        G = cfg.moe_n_group
+        grouped = choice.reshape(T, G, E // G)
+        if cfg.moe_scoring == "sigmoid":
+            group_scores = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        else:
+            group_scores = jnp.max(grouped, axis=-1)
+        _, gidx = jax.lax.top_k(group_scores, cfg.moe_topk_group)
+        gmask = jnp.zeros_like(group_scores).at[
+            jnp.arange(T)[:, None], gidx].set(1.0)
+        choice = jnp.where(gmask[..., None] > 0, grouped, 0.0).reshape(T, E)
+    _, topi = jax.lax.top_k(choice, cfg.num_experts_per_tok)
+    topv = jnp.take_along_axis(scores, topi, axis=-1)
+    if cfg.norm_topk_prob:
+        eps = 1e-20 if cfg.moe_scoring == "sigmoid" else 0.0
+        topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + eps)
+    topv = topv * cfg.moe_routed_scaling
+    combine = jnp.zeros_like(scores).at[
+        jnp.arange(T)[:, None], topi].set(topv)
+
+    def proj(spec, inp, ep):
+        y = jnp.einsum(spec, inp, ep["kernel"].astype(inp.dtype))
+        if "scale" in ep:
+            y = y * ep["scale"][None].astype(y.dtype)
+        return y
+
+    ek = p["experts"]
+    g = proj("th,ehi->tei", xt, ek["gate_proj"])
+    u = proj("th,ehi->tei", xt, ek["up_proj"])
+    o = proj("tei,eih->teh", jax.nn.silu(g) * u, ek["down_proj"])
+    y = jnp.einsum("teh,te->th", o, combine.astype(o.dtype))
+    if "shared" in p:
+        y = y + transformer._mlp(xt, p["shared"], cfg)
+    return y.reshape(x.shape), combine
+
+
+def _deepseek():
+    c = dataclasses.replace(get_model_config("tiny-deepseek"),
+                            dtype="float32")
+    return c, weights.init_params(c, seed=5)["layers"][1]    # layer 0 is dense
+
+
+def _case(name, cfg, params):
+    """``(cfg, one layer's params)`` of a named case."""
+    lp = dict(params["layers"][0])
+    if name == "renormalised":
+        return cfg, lp
+    if name == "not-renormalised":
+        return dataclasses.replace(cfg, norm_topk_prob=False), lp
+    if name == "every-token-to-one-expert":
+        # a router of zeros ties every score: top-1 takes expert 0
+        lp["router"] = {"kernel": jnp.zeros_like(lp["router"]["kernel"])}
+        return dataclasses.replace(cfg, num_experts_per_tok=1), lp
+    if name == "an-expert-with-no-row":
+        # expert 2's score is driven to nothing for every token
+        k = lp["router"]["kernel"]
+        lp["router"] = {"kernel": k.at[:, 2].set(0.0)}
+        lp["router_bias"] = {"bias": jnp.asarray([0.0, 0.0, -1e9, 0.0])}
+        return cfg, lp
+    if name == "int8-scales":
+        return cfg, weights.quantize_params_int8(params)["layers"][0]
+    if name == "shared-experts-sigmoid-grouped":
+        return _deepseek()
+    raise KeyError(name)
+
+
+CASES = ["renormalised", "not-renormalised", "every-token-to-one-expert",
+         "an-expert-with-no-row", "int8-scales",
+         "shared-experts-sigmoid-grouped"]
+
+
+@pytest.mark.parametrize("T", [1, 7, 64, 300])
+@pytest.mark.parametrize("case", CASES)
+def test_sparse_dispatch_is_the_dense_form(cfg, params, case, T):
+    """Sorted rows through the grouped products give what every expert on
+    every token gives, for every routing the presets use; the rows routed
+    to each expert are counted as the oracle's combine matrix has them;
+    the dense form the program keeps for a mesh agrees too."""
+    c, lp = _case(case, cfg, params)
+    x = jnp.asarray(np.random.default_rng(T).standard_normal(
+        (T, c.hidden_size)), jnp.float32)
+    want, combine = dense_oracle(x, lp, c)
+    tally = []
+    got = transformer._moe_mlp(x, lp, c, tally)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    picked = np.asarray(combine != 0).sum(0)
+    # (a weight of exactly zero cannot come out of a softmax or a sigmoid)
+    (sizes, picks), = tally
+    np.testing.assert_array_equal(np.asarray(sizes), picked)
+    assert int(np.asarray(sizes).sum()) == T * c.num_experts_per_tok
+    # and each token's picks are the columns of its row that weigh
+    assert picks.shape == (T, c.num_experts_per_tok)
+    np.testing.assert_array_equal(
+        np.sort(np.asarray(picks), axis=1),
+        np.stack([np.flatnonzero(row) for row in np.asarray(combine)]))
+    if case == "every-token-to-one-expert":
+        assert list(np.asarray(sizes)) == [T, 0, 0, 0]
+    if case == "an-expert-with-no-row":
+        assert int(sizes[2]) == 0
+    np.testing.assert_allclose(
+        np.asarray(transformer._moe_mlp(x, lp, c, None, True)),
+        np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("case", ["renormalised", "int8-scales"])
+def test_a_tokens_expert_output_does_not_depend_on_the_batch(cfg, params,
+                                                             case):
+    """The sparse layer in bfloat16: a token's output is the same bits
+    alone, among 6 others and among 299 others (as the dense form's is).
+    Rows are sorted into other places and the row tiles differ, but a
+    row's product with its expert's kernel reads that row only."""
+    c, lp = _case(case, cfg, params)
+    lp = jax.tree.map(lambda a: a.astype(jnp.bfloat16)
+                      if a.dtype == jnp.float32 else a, lp)
+    x = jnp.asarray(np.random.default_rng(3).standard_normal(
+        (300, c.hidden_size)), jnp.bfloat16)
+    whole = np.asarray(transformer._moe_mlp(x, lp, c).astype(jnp.float32))
+    for T in (1, 7):
+        part = transformer._moe_mlp(x[:T], lp, c).astype(jnp.float32)
+        np.testing.assert_array_equal(np.asarray(part), whole[:T])
+        dense = transformer._moe_mlp(x[:T], lp, c, None, True)
+        np.testing.assert_array_equal(
+            np.asarray(dense.astype(jnp.float32)),
+            np.asarray(transformer._moe_mlp(x, lp, c, None, True)
+                       .astype(jnp.float32))[:T])
+
+
+@pytest.mark.parametrize("m,k,n,tiles", [
+    (2, 64, 32, None),               # fewer rows than a tile: padded
+    (48, 64, 32, (16, 32, 32)),      # two contraction blocks
+    (48, 64, 32, (16, 48, 32)),      # a contraction block past the edge
+    (600, 64, 96, (128, 64, 96)),    # rows not a multiple of the tile
+    (64, 32, 256, (32, 32, 128)),    # two output blocks
+])
+def test_the_grouped_product_is_the_row_by_row_product(m, k, n, tiles):
+    """``_moe_grouped_matmul`` in interpret mode against one kernel a row,
+    with groups that are empty, that start inside a tile and that span
+    several, and with int8 kernels converted block by block."""
+    from tpuserve.ops.pallas_moe_gmm import (_grouped_matmul,
+                                             grouped_matmul_reference, tiling)
+
+    def grouped_matmul(lhs, rhs, sizes):
+        tm, tk, tn = tiles or tiling(m, k, n)
+        return _grouped_matmul(lhs, rhs, sizes, tm=tm, tk=tk, tn=tn,
+                               interpret=True)
+    rs = np.random.RandomState(m + n)
+    E = 8
+    sizes = np.bincount(rs.randint(0, E, m), minlength=E).astype(np.int32)
+    sizes[3] += sizes[5]
+    sizes[5] = 0                                    # an empty group
+    lhs = jnp.asarray(rs.randn(m, k), jnp.float32)
+    rhs = jnp.asarray(rs.randn(E, k, n), jnp.float32)
+    want = np.asarray(grouped_matmul_reference(lhs, rhs, jnp.asarray(sizes)))
+    got = grouped_matmul(lhs, rhs, jnp.asarray(sizes))
+    np.testing.assert_allclose(np.asarray(got), want, atol=1e-4, rtol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(jax.lax.ragged_dot(lhs, rhs, jnp.asarray(sizes))), want,
+        atol=1e-4, rtol=1e-5)
+    q = jnp.asarray(rs.randint(-127, 128, (E, k, n)), jnp.int8)
+    np.testing.assert_allclose(
+        np.asarray(grouped_matmul(lhs, q, jnp.asarray(sizes))),
+        np.asarray(grouped_matmul_reference(lhs, q, jnp.asarray(sizes))),
+        atol=2e-2, rtol=1e-5)

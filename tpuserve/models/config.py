@@ -73,6 +73,13 @@ class ModelConfig:
     # mscale, mscale_all_dim, original_max_position_embeddings) — see
     # ops/rope.py rope_freqs; mscale_all_dim also squares into attn_scale.
     rope_yarn: Optional[tuple] = None
+    # YaRN on the FULL-attention layers alone (Mellum 2: layers of two
+    # kinds with their own rotary tables, HF ``rope_parameters`` keyed by
+    # layer type): (factor, beta_fast, beta_slow,
+    # original_max_position_embeddings).  Windowed layers rotate by the
+    # plain table; the attention factor 0.1 ln(factor) + 1 multiplies the
+    # full layers' cos and sin, attn_scale is untouched — see layer_yarn().
+    rope_full_yarn: Optional[tuple] = None
     # Gemma2 traits: tanh softcaps on attention scores / final logits,
     # attention scale from query_pre_attn_scalar instead of head_dim, and
     # sandwich norms (post-attention + pre/post-feedforward layernorms).
@@ -218,6 +225,59 @@ class ModelConfig:
             return self.rope_local_base_freq, 1.0
         return self.rope_theta, self.rope_scaling_factor
 
+    def layer_yarn(self, layer_idx: int) -> Optional[tuple]:
+        """``ops/rope.rope_freqs``'s ``yarn_scaling`` for one layer's
+        table, beside layer_rope() — ONE function for every forward path
+        (``_qkv``).  ``rope_full_yarn`` applies to the layers that attend
+        the whole context; no mscale pair, so the attention factor is
+        0.1 ln(factor) + 1 on cos and sin.  (``rope_yarn`` is DeepSeek's
+        model-wide form and belongs to the MLA path.)"""
+        if (self.rope_full_yarn is None
+                or self.layer_window(layer_idx) is not None):
+            return None
+        factor, beta_fast, beta_slow, orig_max = self.rope_full_yarn
+        return (factor, beta_fast, beta_slow, 0, 0, orig_max)
+
+    # What a configuration file's keys are held to (benchmark/harness/
+    # plan.py compares with ``!=``: a JSON list or dict never equals a
+    # tuple), as config.json spells them.
+
+    @property
+    def layer_types(self) -> list:
+        """The running layers' kinds, as HF ``layer_types`` names them."""
+        return ["sliding_attention" if self.layer_window(i) is not None
+                else "full_attention" for i in range(self.num_layers)]
+
+    @property
+    def mlp_layer_types(self) -> list:
+        return ["sparse" if self.num_experts
+                and not self.moe_layer_is_dense(i) else "dense"
+                for i in range(self.num_layers)]
+
+    @property
+    def max_window_layers(self) -> int:
+        return self.full_attention_first_layers
+
+    @property
+    def rope_parameters(self) -> Optional[dict]:
+        """HF ``rope_parameters`` keyed by layer type, for a model whose
+        rotary tables differ by layer kind; None for any other."""
+        if self.rope_full_yarn is None:
+            return None
+        from tpuserve.ops.rope import yarn_mscale
+        factor, beta_fast, beta_slow, orig_max = self.rope_full_yarn
+        return {
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": self.rope_theta,
+                "factor": factor,
+                "original_max_position_embeddings": orig_max,
+                "beta_fast": beta_fast, "beta_slow": beta_slow,
+                "attention_factor": yarn_mscale(factor)},
+            "sliding_attention": {
+                "rope_type": "default",
+                "rope_theta": self.rope_local_base_freq or self.rope_theta},
+        }
+
     @property
     def uniform_window(self) -> bool:
         """True when EVERY layer is windowed — the rolling-buffer block
@@ -283,6 +343,13 @@ class ModelConfig:
     def cache_head_dim(self) -> int:
         """KV-cache per-head width: MLA stores the latent vector."""
         return self.mla_latent_dim if self.is_mla else self.head_dim
+
+    @property
+    def routes_experts(self) -> bool:
+        """True when some layer is an expert layer: its cache trunks then
+        return a dispatch's routing counts beside its result."""
+        return bool(self.num_experts) \
+            and self.moe_first_k_dense < self.num_layers
 
     def moe_layer_is_dense(self, layer_idx: int) -> bool:
         """DeepSeek first_k_dense_replace: the first k layers keep a dense
@@ -353,6 +420,8 @@ def config_from_hf_json(name: str, hf: dict) -> ModelConfig:
     )
     if family == "falcon_h1" or arch.startswith("falconh1"):
         return _falcon_h1_config(hf, common)
+    if family == "mellum":
+        return _mellum_config(hf, common)
     if "opt" in family:
         common["tie_word_embeddings"] = hf.get("tie_word_embeddings", True)
         return ModelConfig(
@@ -570,6 +639,70 @@ def config_from_hf_json(name: str, hf: dict) -> ModelConfig:
         rope_llama3_scaling=_rope_scaling(hf),
         **_sliding_window(hf, family),
         **moe,
+        **common,
+    )
+
+
+def _mellum_config(hf: dict, common: dict) -> ModelConfig:
+    """Mellum 2 (``model_type`` ``mellum``): a Llama-style GQA decoder whose
+    layers are of two kinds (``layer_types``: a sliding window with the
+    plain rotary table, or the whole context with a YaRN table;
+    ``rope_parameters`` keyed by kind) and whose MLPs are all routed
+    experts (softmax router, top-k renormalised, no shared expert).  What
+    this code does not implement rejects loudly."""
+    kinds = hf.get("layer_types")
+    if not kinds or len(kinds) != hf["num_hidden_layers"] or set(kinds) - {
+            "sliding_attention", "full_attention"}:
+        raise ValueError("mellum configs must carry layer_types, one of "
+                         "sliding_attention / full_attention a layer; got "
+                         f"{kinds!r}")
+    mlp_kinds = hf.get("mlp_layer_types") or ["sparse"] * len(kinds)
+    if set(mlp_kinds) != {"sparse"} or len(mlp_kinds) != len(kinds):
+        raise ValueError("mellum with dense MLP layers is not supported "
+                         f"(mlp_layer_types {mlp_kinds!r})")
+    if hf.get("max_window_layers"):
+        raise ValueError("mellum with max_window_layers "
+                         f"{hf['max_window_layers']!r}: layer_types decides "
+                         "the kinds here")
+    if not hf.get("use_sliding_window", True) or not hf.get("sliding_window"):
+        raise ValueError("mellum without a sliding window is not supported")
+    rp = hf.get("rope_parameters") or {}
+    full, local = rp.get("full_attention"), rp.get("sliding_attention")
+    if not full or not local or full.get("rope_type") != "yarn" \
+            or local.get("rope_type", "default") != "default":
+        raise ValueError("mellum rope_parameters must give full_attention "
+                         "a yarn table and sliding_attention the default "
+                         f"one; got {rp!r}")
+    from tpuserve.ops.rope import yarn_mscale
+    stated = full.get("attention_factor")
+    if stated is not None and abs(stated - yarn_mscale(full["factor"])) > 1e-9:
+        raise ValueError(f"mellum attention_factor {stated!r} is not "
+                         "0.1 ln(factor) + 1")
+    if full.get("mscale") or full.get("mscale_all_dim") \
+            or not full.get("truncate", True):
+        raise ValueError(f"unsupported yarn parameters {full!r}")
+    nh = hf["num_attention_heads"]
+    theta = float(full["rope_theta"])
+    local_theta = float(local.get("rope_theta", theta))
+    return ModelConfig(
+        intermediate_size=hf["intermediate_size"],
+        num_kv_heads=hf.get("num_key_value_heads", nh),
+        head_dim=hf.get("head_dim") or hf["hidden_size"] // nh,
+        norm_eps=hf.get("rms_norm_eps", 1e-6),
+        act=hf.get("hidden_act", "silu"),
+        attention_bias=hf.get("attention_bias", False),
+        rope_theta=theta,
+        rope_local_base_freq=None if local_theta == theta else local_theta,
+        rope_full_yarn=(full["factor"], full.get("beta_fast", 32),
+                        full.get("beta_slow", 1),
+                        full.get("original_max_position_embeddings",
+                                 common["max_position_embeddings"])),
+        sliding_window=hf["sliding_window"],
+        window_layers=tuple(t == "sliding_attention" for t in kinds),
+        num_experts=hf["num_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        norm_topk_prob=hf.get("norm_topk_prob", True),
         **common,
     )
 
@@ -855,6 +988,25 @@ register_model_config(ModelConfig(
     bos_token_id=1, eos_token_id=11,
 ), "falcon-h1-34b")
 
+# Mellum 2 (JetBrains): a GQA decoder whose layers are of two kinds, three
+# with a 1,024-token window and the plain rotary table to each one that
+# attends the whole context under a YaRN table, and whose MLPs are all 64
+# routed experts of width 896, eight a token.  The numbers are
+# config.json's; 12.15 B parameters, so one chip serves a cut of the
+# depth.  No q/k norm (no key states one); the MTP head is left out.
+register_model_config(ModelConfig(
+    name="JetBrains/Mellum2-12B-A2.5B-Instruct",
+    vocab_size=98304, hidden_size=2304, intermediate_size=7168,
+    num_layers=28, num_heads=32, num_kv_heads=4, head_dim=128,
+    max_position_embeddings=131072, rope_theta=500000.0, norm_eps=1e-6,
+    tie_word_embeddings=False,
+    sliding_window=1024,
+    window_layers=tuple(i % 4 != 3 for i in range(28)),   # S S S F
+    rope_full_yarn=(16, 32, 1, 8192),
+    num_experts=64, num_experts_per_tok=8, moe_intermediate_size=896,
+    norm_topk_prob=True,
+), "mellum2-12b")
+
 # Tiny configs for tests / CPU smoke (one per architectural family).
 register_model_config(ModelConfig(
     name="tiny-qwen3",
@@ -880,6 +1032,21 @@ register_model_config(ModelConfig(
     attention_out_multiplier=0.3, ssm_in_multiplier=0.5,
     ssm_out_multiplier=0.4, mlp_multipliers=(0.6, 0.2),
     ssm_multipliers=(0.7, 0.5, 0.35, 0.9, 0.6),
+))
+
+# Mellum 2 in small: two periods of S S S F, a window of 16, YaRN factor 4
+# over an original 32 on the full layers, 8 experts and 2 a token, 8
+# query heads on 2 KV heads.  float32 like tiny-mistral.
+register_model_config(ModelConfig(
+    name="tiny-mellum2",
+    vocab_size=256, hidden_size=64, intermediate_size=256,
+    num_layers=8, num_heads=8, num_kv_heads=2, head_dim=16,
+    max_position_embeddings=512, rope_theta=10000.0, norm_eps=1e-6,
+    tie_word_embeddings=False, eos_token_id=1, dtype="float32",
+    sliding_window=16, window_layers=tuple(i % 4 != 3 for i in range(8)),
+    rope_full_yarn=(4, 32, 1, 32),
+    num_experts=8, num_experts_per_tok=2, moe_intermediate_size=32,
+    norm_topk_prob=True,
 ))
 
 register_model_config(ModelConfig(

@@ -120,23 +120,26 @@ def _attn_residual(out: jnp.ndarray, lp: dict, cfg: ModelConfig,
 
 
 def _mlp_residual(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
-                  ad: jnp.ndarray | None = None) -> jnp.ndarray:
+                  ad: jnp.ndarray | None = None, tally: list | None = None,
+                  moe_dense: bool = False) -> jnp.ndarray:
     """Pre-norm MLP branch; under sandwich norms the pre-norm weights are
     the checkpoint's pre_feedforward_layernorm (mapped onto ``mlp_norm``)
-    and a post-feedforward layernorm wraps the output before the add."""
-    m = _mlp(_norm(h, lp["mlp_norm"], cfg), lp, cfg, ad)
+    and a post-feedforward layernorm wraps the output before the add.
+    ``tally`` and ``moe_dense`` are an expert layer's (:func:`_moe_mlp`)."""
+    m = _mlp(_norm(h, lp["mlp_norm"], cfg), lp, cfg, ad, tally, moe_dense)
     if cfg.sandwich_norms:
         m = _norm(m, lp["post_mlp_norm"], cfg)
     return m
 
 
 def _mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
-         ad: jnp.ndarray | None = None) -> jnp.ndarray:
+         ad: jnp.ndarray | None = None, tally: list | None = None,
+         moe_dense: bool = False) -> jnp.ndarray:
     # branch on the PARAMS, not cfg.num_experts: DeepSeek keeps the first
     # first_k_dense_replace layers dense inside an MoE model, so those
     # layers carry plain gated-MLP params (weights.init_params)
     if "experts" in p:
-        return _moe_mlp(x, p, cfg)
+        return _moe_mlp(x, p, cfg, tally, moe_dense)
     if cfg.mlp_style == "gated":
         gate_m, down_m = cfg.mlp_multipliers
         gate = _act(_scaled(_linear(x, p["gate_proj"], ad), gate_m), cfg.act)
@@ -145,26 +148,47 @@ def _mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
     return _linear(_act(_linear(x, p["fc1"], ad), cfg.act), p["fc2"], ad)
 
 
-def _moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig) -> jnp.ndarray:
-    """Mixture-of-experts MLP (Qwen3-MoE-style): softmax router picks
-    ``num_experts_per_tok`` experts per token; their gated-MLP outputs are
-    combined with the (optionally renormalised) router weights.
+def _moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
+             tally: list | None = None, dense: bool = False) -> jnp.ndarray:
+    """Mixture-of-experts MLP: the router scores every expert and picks
+    ``num_experts_per_tok`` a token; the picked experts' gated-MLP outputs
+    are combined with the (optionally renormalised) router weights.
 
-    Dispatch is DENSE: every expert runs on every token and non-selected
-    experts contribute with weight zero.  That is the XLA-friendly form —
-    static shapes, no ragged gather/scatter — and it makes expert
-    parallelism pure GSPMD: expert kernels are stacked (E, ...) and sharded
-    over the mesh 'ep' axis (parallel/sharding.py), so each shard computes
-    only its local experts and one psum combines the weighted outputs.
-    The compute overcost vs sparse dispatch is E/k on the MLP FLOPs; at
-    serving batch sizes the step stays HBM-bound reading the expert
-    weights, which EP divides by the axis size.  (Capacity-based one-hot
-    dispatch is the optimisation path when token count >> E.)
+    Dispatch is SPARSE: the ``T k`` (token, expert) pairs are ordered by
+    expert (a stable sort), their rows gathered, and gate/up and down run
+    as GROUPED products over the contiguous row groups with the per-expert
+    group sizes as data (``ops/pallas_moe_gmm.py``, the custom call
+    ``_moe_grouped_matmul``): always ``T k`` rows, an expert may get none,
+    no capacity, no dropped token, no padding to a per-expert maximum.
+    Each row is then weighted and a token's ``k`` rows summed.  That is
+    ``k / E`` of the dense form's operations, and in decode each TOUCHED
+    expert's kernels are read once.  One implementation for every preset
+    on one device (shared experts, the int8 ``scale`` path and DeepSeek's
+    routing included).
+
+    ``dense`` (static; what the engine observes under a mesh, where the
+    stacked kernels are sharded over the 'ep' axis and GSPMD cannot
+    partition a kernel): every expert runs on every token and the
+    unpicked ones weigh zero, so each shard computes its own experts and
+    one psum combines (parallel/sharding.py).  No expert-parallel
+    exchange exists yet (ROADMAP C9/M1).
+
+    ``tally``: a list the trunk collects routing in; this layer appends
+    ``(E,)`` int32 rows routed to each expert (padding rows of the
+    dispatch included: they are computed like any other) and its
+    ``(T, k)`` picks.
     """
     shape = x.shape
     xt = x.reshape(-1, shape[-1])                              # (T, H)
     T = xt.shape[0]
-    router = _linear(xt, p["router"]).astype(jnp.float32)      # (T, E)
+    if "scale" in p["router"]:
+        router = _linear(xt, p["router"]).astype(jnp.float32)  # (T, E)
+    else:
+        # scores in float32 from the product's own accumulator: a bf16
+        # output would round near-tied scores before the top-k reads
+        # them, and a flipped pick is another expert's output
+        router = jnp.matmul(xt, p["router"]["kernel"],
+                            preferred_element_type=jnp.float32)
     # DeepSeek-V3 scores experts with a sigmoid; selection adds the
     # auxiliary-loss-free correction bias and (optionally) restricts the
     # top-k to the best topk_group of n_group expert groups — but the
@@ -177,8 +201,8 @@ def _moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig) -> jnp.ndarray:
     choice = scores
     if "router_bias" in p:
         choice = choice + p["router_bias"]["bias"][None, :]
+    E = scores.shape[-1]
     if cfg.moe_n_group > 1:
-        E = scores.shape[-1]
         G = cfg.moe_n_group
         grouped = choice.reshape(T, G, E // G)
         # group score: V3 (sigmoid) sums the group's top-2 member scores;
@@ -204,31 +228,79 @@ def _moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig) -> jnp.ndarray:
         topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + eps)
     if cfg.moe_routed_scaling != 1.0:
         topv = topv * cfg.moe_routed_scaling
-    combine = jnp.zeros_like(scores).at[
-        jnp.arange(T)[:, None], topi].set(topv)                # (T, E)
     ek = p["experts"]
+    picks = topi.reshape(-1)                                   # (T k,)
+    sizes = jnp.sum(picks[:, None] == jnp.arange(E)[None, :], axis=0,
+                    dtype=jnp.int32)                           # (E,)
+    if tally is not None:
+        tally.append((sizes, topi))
+    if dense:
+        y = _moe_dense_experts(xt, ek, topi, topv, cfg)
+    else:
+        from tpuserve.ops.pallas_moe_gmm import grouped_matmul
+        order = jnp.argsort(picks, stable=True)     # rows by expert
+        rows = _gather_rows(xt, order // k)                    # (T k, H)
 
-    def expert_proj(spec: str, inp: jnp.ndarray, ep: dict) -> jnp.ndarray:
-        # int8 stacked expert kernels carry a per-expert-per-output-channel
-        # scale (E, out); as with _linear, XLA fuses the convert into the
-        # contraction so HBM reads int8 (weights.quantize_params_int8).
-        w = ep["kernel"]
-        y = jnp.einsum(spec, inp, w.astype(inp.dtype))
-        if "scale" in ep:
-            y = y * ep["scale"][None].astype(y.dtype)
-        return y
+        def expert_proj(inp: jnp.ndarray, ep: dict) -> jnp.ndarray:
+            # int8 stacked expert kernels carry a per-expert-per-output-
+            # channel scale (E, out), applied after the product as in
+            # _linear; the kernel converts the int8 blocks itself, so HBM
+            # reads int8 (weights.quantize_params_int8)
+            y = grouped_matmul(inp, ep["kernel"], sizes)
+            if "scale" in ep:
+                y = y * ep["scale"][picks[order]].astype(y.dtype)
+            return y
 
-    g = expert_proj("th,ehi->tei", xt, ek["gate_proj"])
-    u = expert_proj("th,ehi->tei", xt, ek["up_proj"])
-    h = _act(g, cfg.act) * u
-    o = expert_proj("tei,eih->teh", h, ek["down_proj"])
-    y = jnp.einsum("teh,te->th", o, combine.astype(o.dtype))
+        h = _act(expert_proj(rows, ek["gate_proj"]), cfg.act) \
+            * expert_proj(rows, ek["up_proj"])
+        o = expert_proj(h, ek["down_proj"])
+        # each row back beside its token's other picks
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        o = _gather_rows(o, back).reshape(T, k, -1)
+        # the router's weights stay float32 into the sum over a token's picks
+        y = jnp.einsum("tkh,tk->th", o, topv).astype(x.dtype)
     if "shared" in p:
         # DeepSeek shared experts: an always-on gated MLP beside the
         # routed ones (HF DeepseekV3MoE.shared_experts) — p["shared"] has
         # no "experts" key, so _mlp runs its plain gated branch
         y = y + _mlp(xt, p["shared"], cfg)
     return y.reshape(shape)
+
+
+def _gather_rows(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``x[idx]`` for rows of a whole number of 128-lane tiles, gathered
+    as ``(tiles, 128)`` slices: the plain 2-D row gather of a (1,536,
+    2,304) bf16 array into 12,288 rows is refused by the TPU compiler
+    (its memory-space assignment puts operand and result in VMEM and the
+    gather then lacks 0.4 MB of scoped VMEM; every other rung of the
+    packed-prefill ladder compiles), this form compiles at every rung
+    (tests/test_chip_compile.py holds both facts)."""
+    width = x.shape[-1]
+    if width % 128:
+        return x[idx]
+    return x.reshape(x.shape[0], width // 128, 128)[idx].reshape(
+        idx.shape[0], width)
+
+
+def _moe_dense_experts(xt, ek, topi, topv, cfg: ModelConfig):
+    """The dense form of the routed experts, for a mesh (see _moe_mlp):
+    static shapes and no gather, so expert parallelism is pure GSPMD."""
+    T = xt.shape[0]
+    E = ek["gate_proj"]["kernel"].shape[0]
+    combine = jnp.zeros((T, E), topv.dtype).at[
+        jnp.arange(T)[:, None], topi].set(topv)                # (T, E)
+
+    def expert_proj(spec: str, inp: jnp.ndarray, ep: dict) -> jnp.ndarray:
+        y = jnp.einsum(spec, inp, ep["kernel"].astype(inp.dtype))
+        if "scale" in ep:
+            y = y * ep["scale"][None].astype(y.dtype)
+        return y
+
+    g = expert_proj("th,ehi->tei", xt, ek["gate_proj"])
+    u = expert_proj("th,ehi->tei", xt, ek["up_proj"])
+    o = expert_proj("tei,eih->teh", _act(g, cfg.act) * u, ek["down_proj"])
+    return jnp.einsum("teh,te->th", o, combine.astype(o.dtype))
 
 
 # --------------------------------------------------------------------------
@@ -240,7 +312,9 @@ def _qkv(h: jnp.ndarray, lp: dict, cfg: ModelConfig, positions: jnp.ndarray,
     """h: (..., H) -> q (..., Hq, D), k/v (..., Hkv, D), with qk-norm and
     RoPE.  ``layer_idx`` selects per-layer rope (Gemma3: windowed layers
     rotate at the local base frequency unscaled; full layers at
-    rope_theta with the linear position scaling)."""
+    rope_theta with the linear position scaling.  Mellum 2: full layers
+    rotate by a YaRN table whose cos and sin carry the attention factor,
+    windowed layers by the plain one)."""
     h = _scaled(h, cfg.attention_in_multiplier)
     q = _linear(h, lp["q_proj"], ad).reshape(*h.shape[:-1], cfg.num_heads, cfg.head_dim)
     k = _linear(h, lp["k_proj"], ad).reshape(*h.shape[:-1], cfg.num_kv_heads, cfg.head_dim)
@@ -258,7 +332,8 @@ def _qkv(h: jnp.ndarray, lp: dict, cfg: ModelConfig, positions: jnp.ndarray,
         if scaling != 1.0:
             pos = positions.astype(jnp.float32) / scaling
         cos, sin = rope_ops.rope_freqs(pos, cfg.head_dim, theta, rotary_dim,
-                                       llama3_scaling=cfg.rope_llama3_scaling)
+                                       llama3_scaling=cfg.rope_llama3_scaling,
+                                       yarn_scaling=cfg.layer_yarn(layer_idx))
         q = rope_ops.apply_rope(q, cos, sin)
         k = rope_ops.apply_rope(k, cos, sin)
     return q, k, v
@@ -589,25 +664,65 @@ def _unembed(params: Params, cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
     return logits
 
 
-def _with_ssm(out, new_cache: list, ssm, new_ssm: list) -> tuple:
-    """A cache trunk's result: ``(out, kv_cache)``, and the seat pool third
-    where the model has one."""
-    if ssm is None:
-        return out, new_cache
-    return out, new_cache, new_ssm
+def _with_ssm(out, new_cache: list, ssm, new_ssm: list,
+              moe=None) -> tuple:
+    """A cache trunk's result: ``(out, kv_cache)``, then the seat pool
+    where the model has one, then the routing (:func:`_moe_routing`) where
+    it has expert layers."""
+    res = (out, new_cache)
+    if ssm is not None:
+        res += (new_ssm,)
+    if moe is not None:
+        res += (moe,)
+    return res
+
+
+def _moe_tally(cfg: ModelConfig) -> list | None:
+    """What a cache trunk collects its expert layers' routing counts in;
+    None for a model without experts, whose programs gain nothing."""
+    return [] if cfg.routes_experts else None
+
+
+def _moe_counts(tally: list):
+    """A dispatch's routing counts from its expert layers' ``(E,)`` group
+    sizes: ``(E + 1,)`` int32 — rows routed to each expert, summed over
+    the layers, then the expert-layers that got at least one row (each
+    touched expert's kernels are read once a layer)."""
+    sizes = jnp.stack([s for s, _ in tally])                   # (L, E)
+    return jnp.concatenate([jnp.sum(sizes, axis=0),
+                            jnp.sum(sizes > 0, dtype=jnp.int32)[None]])
+
+
+def _moe_routing(tally: list | None, rows: jnp.ndarray | None):
+    """What a cache trunk of a model with expert layers returns last:
+    ``(counts, picks, all picks)`` — :func:`_moe_counts`; the experts each
+    expert layer picked for the rows the trunk returns logits of (``rows``
+    (B,) indexes the layers' flat token axis): ``(B, expert layers, k)``
+    int32; and the same for every row of that axis ``(T, expert layers,
+    k)``, a prompt's positions, or None where the trunk returns logits of
+    every row (``rows`` None: decode).  They stay on the device unless a
+    request asked for logprobs, beside which the engine files them.
+    None where the trunk collected nothing."""
+    if tally is None:
+        return None
+    picks = jnp.stack([t for _, t in tally], axis=1)           # (T, L, k)
+    if rows is None:
+        return _moe_counts(tally), picks, None
+    return _moe_counts(tally), picks[rows], picks
 
 
 # --------------------------------------------------------------------------
 # Prefill: process full (padded) prompts, write KV cache, return last logits
 # --------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("cfg", "attn_impl", "mesh"),
+@partial(jax.jit, static_argnames=("cfg", "attn_impl", "mesh", "moe_dense"),
          donate_argnames=("kv_cache", "ssm"))
 def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             prompt_lens: jnp.ndarray, slot_ids: jnp.ndarray,
             kv_cache: list, ad: jnp.ndarray | None = None,
             ssm: list | None = None, seats: jnp.ndarray | None = None, *,
-            attn_impl: str = "reference", mesh=None):
+            attn_impl: str = "reference", mesh=None,
+            moe_dense: bool = False):
     """Run full prompts through the model.
 
     tokens: (B, T) right-padded prompts; prompt_lens: (B,); slot_ids: (B, T)
@@ -629,6 +744,7 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     scale = cfg.attn_scale
     new_cache = []
     new_ssm = []
+    tally = _moe_tally(cfg)
     for li, lp in enumerate(params["layers"]):
         sw = cfg.layer_window(li)
         hn = _norm(h, lp["attn_norm"], cfg)
@@ -644,7 +760,7 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                                    prompt_lens, scale)
             out = out.reshape(B, T, cfg.num_heads * cfg.mla_v_head_dim)
             h = h + _attn_residual(out, lp, cfg, ad)
-            h = h + _mlp_residual(h, lp, cfg, ad)
+            h = h + _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
             continue
         q, k, v = _qkv(hn, lp, cfg, positions, li, ad)
         # batched prefill attends over the FRESH k/v (full precision even
@@ -673,24 +789,26 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             new_ssm.append(entry)
             att = att + m
         h = h + att
-        h = h + _mlp_residual(h, lp, cfg, ad)
+        h = h + _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
     last_idx = jnp.maximum(prompt_lens - 1, 0)
     h_last = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]  # (B, H)
-    return _with_ssm(_unembed(params, cfg, h_last), new_cache, ssm, new_ssm)
+    return _with_ssm(_unembed(params, cfg, h_last), new_cache, ssm, new_ssm,
+                     _moe_routing(tally, jnp.arange(B) * T + last_idx))
 
 
 # --------------------------------------------------------------------------
 # Chunked prefill: one bounded chunk of a long prompt against the cache
 # --------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("cfg", "attn_impl", "mesh"),
+@partial(jax.jit, static_argnames=("cfg", "attn_impl", "mesh", "moe_dense"),
          donate_argnames=("kv_cache", "ssm"))
 def prefill_chunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                   ctx_lens: jnp.ndarray, chunk_lens: jnp.ndarray,
                   slot_ids: jnp.ndarray, block_tables: jnp.ndarray,
                   kv_cache: list, ad: jnp.ndarray | None = None,
                   ssm: list | None = None, seats: jnp.ndarray | None = None,
-                  *, attn_impl: str = "reference", mesh=None):
+                  *, attn_impl: str = "reference", mesh=None,
+                  moe_dense: bool = False):
     """Process one chunk of each prompt against the paged cache.
 
     Long prompts run as a sequence of fixed-size chunks (bounded memory and
@@ -710,12 +828,16 @@ def prefill_chunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     online-softmax einsum in ops/attention.py.  ``mesh``: static; when set
     with pallas, the kernel runs head-parallel over tp via shard_map.
     """
+    tally = _moe_tally(cfg)
     h, new_cache, new_ssm = _chunk_trunk(
         params, cfg, tokens, ctx_lens, chunk_lens, slot_ids, block_tables,
-        kv_cache, ad, ssm, seats, attn_impl=attn_impl, mesh=mesh)
+        kv_cache, ad, ssm, seats, attn_impl=attn_impl, mesh=mesh,
+        tally=tally, moe_dense=moe_dense)
     last_idx = jnp.maximum(chunk_lens - 1, 0)
     h_last = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]
-    return _with_ssm(_unembed(params, cfg, h_last), new_cache, ssm, new_ssm)
+    return _with_ssm(_unembed(params, cfg, h_last), new_cache, ssm, new_ssm,
+                     _moe_routing(tally, jnp.arange(h.shape[0]) * h.shape[1]
+                                  + last_idx))
 
 
 # --------------------------------------------------------------------------
@@ -832,7 +954,8 @@ def _chunk_trunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                  slot_ids: jnp.ndarray, block_tables: jnp.ndarray,
                  kv_cache: list, ad: jnp.ndarray | None = None,
                  ssm: list | None = None, seats: jnp.ndarray | None = None,
-                 *, attn_impl: str = "reference", mesh=None):
+                 *, attn_impl: str = "reference", mesh=None,
+                 tally: list | None = None, moe_dense: bool = False):
     """Shared layer loop for cache-relative windows: writes the window's KV
     and attends against cached context + causal-within-window.  Used by both
     prefill_chunk (last-row logits) and decode_verify (all-row argmax).
@@ -863,7 +986,7 @@ def _chunk_trunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             out = _mla_unabsorb(out, lp, cfg)
             out = out.reshape(B, C, cfg.num_heads * cfg.mla_v_head_dim)
             h = h + _attn_residual(out, lp, cfg, ad)
-            h = h + _mlp_residual(h, lp, cfg, ad)
+            h = h + _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
             continue
         q, k, v = _qkv(hn, lp, cfg, positions, li, ad)
         entry = attn_ops.write_kv_entry(kv_cache[li], k, v, slot_ids)
@@ -895,7 +1018,7 @@ def _chunk_trunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             new_ssm.append(entry)
             att = att + m
         h = h + att
-        h = h + _mlp_residual(h, lp, cfg, ad)
+        h = h + _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
     return h, new_cache, new_ssm or None
 
 
@@ -1074,16 +1197,18 @@ def _decode_body(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                  block_tables: jnp.ndarray, seq_lens: jnp.ndarray,
                  kv_cache: list, attn_impl: str, mesh,
                  ad: jnp.ndarray | None = None, ssm: list | None = None,
-                 seats: jnp.ndarray | None = None):
+                 seats: jnp.ndarray | None = None,
+                 moe_dense: bool = False):
     """Shared single-token decode trunk: write the token's KV, attend
     against the paged cache, return (logits (B, V), new kv_cache, seat
-    pool or None).  Used by :func:`decode_step` (one dispatch per token) and
+    pool or None, routing or None).  Used by :func:`decode_step` (one dispatch per token) and
     :func:`decode_multi` (scanned — one dispatch per window)."""
     B = tokens.shape[0]
     h = _embed(params, cfg, tokens, positions)                 # (B, H)
     scale = cfg.attn_scale
     new_cache = []
     new_ssm = []
+    tally = _moe_tally(cfg)
     for li, lp in enumerate(params["layers"]):
         sw = cfg.layer_window(li)
         hn = _norm(h, lp["attn_norm"], cfg)
@@ -1104,7 +1229,7 @@ def _decode_body(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             out = _mla_unabsorb(out, lp, cfg)
             out = out.reshape(B, cfg.num_heads * cfg.mla_v_head_dim)
             h = h + _attn_residual(out, lp, cfg, ad)
-            h = h + _mlp_residual(h, lp, cfg, ad)
+            h = h + _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
             continue
         q, k, v = _qkv(hn, lp, cfg, positions, li, ad)  # (B, Hq/Hkv, D)
         entry = attn_ops.write_kv_entry(kv_cache[li], k, v, slot_ids)
@@ -1137,18 +1262,20 @@ def _decode_body(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             new_ssm.append(entry)
             att = att + m
         h = h + att
-        h = h + _mlp_residual(h, lp, cfg, ad)
-    return _unembed(params, cfg, h), new_cache, new_ssm or None
+        h = h + _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+    return (_unembed(params, cfg, h), new_cache, new_ssm or None,
+            _moe_routing(tally, None))
 
 
-@partial(jax.jit, static_argnames=("cfg", "attn_impl", "mesh"),
+@partial(jax.jit, static_argnames=("cfg", "attn_impl", "mesh", "moe_dense"),
          donate_argnames=("kv_cache", "ssm"))
 def decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 positions: jnp.ndarray, slot_ids: jnp.ndarray,
                 block_tables: jnp.ndarray, seq_lens: jnp.ndarray,
                 kv_cache: list, ad: jnp.ndarray | None = None,
                 ssm: list | None = None, seats: jnp.ndarray | None = None,
-                *, attn_impl: str = "reference", mesh=None):
+                *, attn_impl: str = "reference", mesh=None,
+                moe_dense: bool = False):
     """One decode step for a batch of sequences.
 
     tokens/positions/slot_ids/seq_lens: (B,); block_tables: (B, max_blocks).
@@ -1157,15 +1284,16 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
     ``mesh``: static; see :func:`prefill` — head-parallel Pallas under tp.
     """
-    logits, new_cache, new_ssm = _decode_body(
+    logits, new_cache, new_ssm, moe = _decode_body(
         params, cfg, tokens, positions, slot_ids, block_tables, seq_lens,
-        kv_cache, attn_impl, mesh, ad=ad, ssm=ssm, seats=seats)
-    return _with_ssm(logits, new_cache, ssm, new_ssm)
+        kv_cache, attn_impl, mesh, ad=ad, ssm=ssm, seats=seats,
+        moe_dense=moe_dense)
+    return _with_ssm(logits, new_cache, ssm, new_ssm, moe)
 
 
 @partial(jax.jit,
          static_argnames=("cfg", "steps", "mode", "logprobs_n", "attn_impl",
-                          "mesh", "out_mesh"),
+                          "mesh", "out_mesh", "moe_dense"),
          donate_argnames=("kv_cache", "ssm"))
 def decode_multi(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                  positions: jnp.ndarray, block_tables: jnp.ndarray,
@@ -1189,7 +1317,8 @@ def decode_multi(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                  gmasks: jnp.ndarray | None = None,
                  gclass: jnp.ndarray | None = None,
                  gnext: jnp.ndarray | None = None,
-                 attn_impl: str = "reference", mesh=None, out_mesh=None):
+                 attn_impl: str = "reference", mesh=None, out_mesh=None,
+                 moe_dense: bool = False):
     """``steps`` fused decode+sample iterations in ONE dispatch.
 
     The sampled token feeds the next iteration entirely on device
@@ -1221,9 +1350,13 @@ def decode_multi(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     entirely into the scan.
 
     Returns (tokens (B, steps) int32, kv_cache[, logprobs][, gstate']
-    [, ssm]) — the logprobs triple when ``logprobs_n``, the final (B,) FSM
-    states when ``gstate`` was passed, the seat pool (a carry of the scan
-    like the cache, updated in place) when ``ssm`` was.
+    [, ssm][, moe]) — the logprobs triple when ``logprobs_n`` (with the
+    rows' picks ``(B, steps, expert layers, k)`` fourth for a model with
+    experts), the final (B,) FSM states when ``gstate`` was passed, the
+    seat pool (a carry of the scan like the cache, updated in place) when
+    ``ssm`` was, the routing (:func:`_moe_routing`: the counts summed over
+    the steps, and None for the picks, which ride with the logprobs here)
+    for a model with experts.
     """
     B = tokens.shape[0]
     block_size = kv_cache[0]["k"].shape[1]
@@ -1235,9 +1368,10 @@ def decode_multi(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         else:
             (toks, pos, lens, cache, cnt, pool), gst = carry, None
         slot = window_slot(block_tables, pos, active, block_size)
-        logits, cache, pool = _decode_body(
+        logits, cache, pool, moe = _decode_body(
             params, cfg, toks, pos, slot, block_tables, lens, cache,
-            attn_impl, mesh, ad=ad, ssm=pool, seats=seats)
+            attn_impl, mesh, ad=ad, ssm=pool, seats=seats,
+            moe_dense=moe_dense)
         # extras ordered before sampling AND before logprobs, exactly
         # like the per-step path (penalties -> bias -> floor); whichever
         # features aren't in play ride along as zeros so one executable
@@ -1261,6 +1395,10 @@ def decode_multi(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             # previously dropped them to per-token dispatches)
             from tpuserve.ops.sampling import compute_logprobs
             ys = (nxt, compute_logprobs(logits, nxt, logprobs_n))
+        if moe is not None:
+            # a step's routing counts ride out beside its tokens, and its
+            # rows' picks where their logprobs do
+            ys = (ys, moe[:2] if logprobs_n else moe[:1])
         new_carry = (nxt, pos + 1, lens + 1, cache, cnt, pool)
         if guided:
             new_carry += (gst,)
@@ -1272,9 +1410,16 @@ def decode_multi(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     final, outs = jax.lax.scan(
         one, carry, jnp.arange(steps, dtype=jnp.int32))
     kv_cache = final[3]
+    moe = None
+    if cfg.routes_experts:
+        outs, moe = outs
+        picks = moe[1:]                             # ((steps, B, L, k),)
+        moe = jnp.sum(moe[0], axis=0), None, None   # over the fused steps
     lp = None
     if logprobs_n:
         out, lp = window_unpack_lp(outs)
+        if moe is not None:
+            lp += (jnp.swapaxes(picks[0], 0, 1),)   # [row, step] like lp
     else:
         out = jnp.swapaxes(outs, 0, 1)                         # (B, steps)
     if out_mesh is not None:
@@ -1292,6 +1437,8 @@ def decode_multi(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         res += (final[6],)
     if ssm is not None:
         res += (final[5],)
+    if moe is not None:
+        res += (moe,)
     return res
 
 
@@ -1335,7 +1482,8 @@ def _ragged_reference_attn(q, ck, cv, block_tables, row_seq, row_lens,
 
 
 @partial(jax.jit,
-         static_argnames=("cfg", "ragged_blk", "attn_impl", "decode_rows"),
+         static_argnames=("cfg", "ragged_blk", "attn_impl", "decode_rows",
+                          "moe_dense"),
          donate_argnames=("kv_cache", "ssm"))
 def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                    positions: jnp.ndarray, slot_ids: jnp.ndarray,
@@ -1347,7 +1495,7 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                    ssm: list | None = None,
                    seats: jnp.ndarray | None = None, *,
                    ragged_blk: int = 8, attn_impl: str = "reference",
-                   decode_rows: bool = True):
+                   decode_rows: bool = True, moe_dense: bool = False):
     """One MIXED prefill+decode step over a flat token stream.
 
     The phase-split engine runs prefill batches and decode steps as
@@ -1392,6 +1540,7 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     row_lens = positions + 1
     new_cache = []
     new_ssm = []
+    tally = _moe_tally(cfg)
     for li, lp in enumerate(params["layers"]):
         sw = cfg.layer_window(li)
         hn = _norm(h, lp["attn_norm"], cfg)
@@ -1416,7 +1565,7 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             out = _mla_unabsorb(out, lp, cfg)
             out = out.reshape(T, cfg.num_heads * cfg.mla_v_head_dim)
             h = h + _attn_residual(out, lp, cfg, ad)
-            h = h + _mlp_residual(h, lp, cfg, ad)
+            h = h + _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
             continue
         q, k, v = _qkv(hn, lp, cfg, positions, li, ad)    # (T, H*, D)
         entry = attn_ops.write_kv_entry(kv_cache[li], k, v, slot_ids)
@@ -1447,9 +1596,10 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             new_ssm.append(entry)
             att = att + m
         h = h + att
-        h = h + _mlp_residual(h, lp, cfg, ad)
+        h = h + _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
     h_sel = h[last_rows]                                       # (B, H)
-    return _with_ssm(_unembed(params, cfg, h_sel), new_cache, ssm, new_ssm)
+    return _with_ssm(_unembed(params, cfg, h_sel), new_cache, ssm, new_ssm,
+                     _moe_routing(tally, last_rows))
 
 
 @partial(jax.jit, static_argnames=("cfg", "k"))
@@ -1509,7 +1659,10 @@ def draft_propose(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
 def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             seq_lens: jnp.ndarray | None = None) -> jnp.ndarray:
-    """Causal LM forward over (B, T) tokens -> (B, T, V) float32 logits."""
+    """Causal LM forward over (B, T) tokens -> (B, T, V) float32 logits.
+    What ``jax.grad`` differentiates (parallel/train.py), so an expert
+    layer runs its dense form here: the grouped-product kernel has no
+    derivative rule (and the serving trunks never come this way)."""
     B, T = tokens.shape
     if seq_lens is None:
         seq_lens = jnp.full((B,), T, jnp.int32)
@@ -1525,5 +1678,5 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                                          logit_softcap=cfg.attn_logit_softcapping)
         h = h + _attn_residual(out.reshape(B, T, cfg.attn_out_size), lp,
                                cfg) + _ssm_nocache(hn, lp, cfg, seq_lens)
-        h = h + _mlp_residual(h, lp, cfg)
+        h = h + _mlp_residual(h, lp, cfg, moe_dense=True)
     return _unembed(params, cfg, h)

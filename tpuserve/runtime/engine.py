@@ -311,6 +311,14 @@ class EngineStats:
     # pre-empted or salvaged sequence's state was dropped (no snapshot)
     ssm_state_resets: int = 0
     ssm_rebuilt_tokens: int = 0
+    # expert layers (models/transformer.py _moe_mlp): rows the sparse
+    # dispatch routed (summed over layers and fused steps, a dispatch's
+    # padding rows included), expert-layers that got at least one row,
+    # and the rows by expert — counted on the device, read with each
+    # dispatch's tokens (Engine._moe_note)
+    moe_routed_rows: int = 0
+    moe_expert_hits: int = 0
+    moe_expert_rows: Optional[np.ndarray] = None
     model_swaps: int = 0
     model_swaps_by_outcome: dict = dataclasses.field(default_factory=dict)
     swap_latencies: list = dataclasses.field(default_factory=list)
@@ -326,6 +334,7 @@ class PendingDecode:
     (append/detokenize/stop/emit) deferred to the next engine step."""
     reqs: list
     toks: jax.Array                  # (B,) int32, device-resident
+    seq: int = 0                     # the dispatching cycle's step record
 
 
 @dataclasses.dataclass
@@ -345,6 +354,7 @@ class PendingWindow:
     # device, exactly like toks[:, -1] chains the input tokens; the host
     # mirror advances at flush through the same table
     gstate: jax.Array | None = None
+    seq: int = 0                     # the dispatching cycle's step record
 
 
 @dataclasses.dataclass
@@ -360,6 +370,7 @@ class PendingFirst:
     # the sampled-from logits, kept only for rows on the guided
     # substitution path (read at once: the host picks their token)
     logits: jax.Array | None = None
+    seq: int = 0                     # the dispatching cycle's step record
 
 
 @jax.jit
@@ -528,6 +539,17 @@ class Engine:
                 shardings=cache_shardings(self.model_cfg, mesh))
         else:
             self.kv_cache = create_kv_cache(self.model_cfg, self.cache_cfg)
+        # a model with expert layers: its cache trunks return each
+        # dispatch's routing last (_keep_pool), and under a mesh they run
+        # the dense GSPMD form of the layer (_moe_mlp)
+        self._moe_counted = self.model_cfg.routes_experts
+        # the last dispatch's picks for the rows it returned logits of,
+        # on the device until a row's logprobs take them
+        # (_logprobs_enqueue), and for all its rows where it was a
+        # prefill (_note_prompt_picks)
+        self._moe_picks = self._moe_prompt_picks = None
+        self._moe_kw = ({"moe_dense": True}
+                        if self._moe_counted and mesh is not None else {})
         # the recurrent-state pool: NOT a leaf of self.kv_cache, which
         # stays "bytes a token" for everything that sizes or copies pages
         self.ssm_state = None
@@ -817,6 +839,10 @@ class Engine:
         self._pending: Optional[PendingDecode] = None
         self._pending_window: Optional[PendingWindow] = None
         self._pending_first: Optional[PendingFirst] = None
+        # (step seq, device (E + 1,) routing counts) of the dispatches of
+        # a model with expert layers whose tokens the host has not read
+        # yet: each is read with those tokens (_moe_due, _moe_note)
+        self._moe_inflight: list = []
         self._pipeline_decode = config.resolve_pipeline_decode()
         self._multi_step = config.resolve_multi_step()
         self._min_multi_step = min(max(1, config.min_multi_step),
@@ -1555,6 +1581,7 @@ class Engine:
         self._pending = None
         self._pending_window = None
         self._pending_first = None
+        self._moe_inflight.clear()    # a faulted dispatch's counts with it
         cohort = list(self.scheduler.running)
         self.scheduler.running.clear()
         seen = ({r.request_id for r in cohort}
@@ -1737,6 +1764,25 @@ class Engine:
             if r.num_prefilled > 0:
                 self.stats.released_blocks += bm.release_out_of_window(
                     r.request_id, max(0, r.num_prefilled - W))
+
+    def window_dead_tokens(self) -> int:
+        """Token-layers of KV held for windowed layers at positions more
+        than the window plus one block behind their sequence's end — no
+        step will read them again, and layers of two kinds release
+        nothing (above): what an allocator by layer kind would give back
+        (ROADMAP M3).  Host integers the scheduler already holds; zero
+        where every layer is windowed and released, or none is."""
+        cfg, bs = self.model_cfg, self.cache_cfg.block_size
+        W = cfg.sliding_window
+        if not W or (cfg.uniform_window and self.config.window_release):
+            return 0
+        windowed = sum(cfg.layer_window(i) is not None
+                       for i in range(cfg.num_layers))
+        dead = sum(max(0, r.num_tokens - W - bs)
+                   for r in self.scheduler.running)
+        dead += sum(max(0, r.num_prefilled - W - bs)
+                    for r in self.scheduler.waiting if r.num_prefilled > 0)
+        return dead * windowed
 
     # ---- tiered KV cache (runtime/kv_tiers.py) ------------------------
     # HBM -> host-DRAM -> PVC prefix offload: evictions of a prefix that
@@ -2119,19 +2165,87 @@ class Engine:
         operation cost the warm-up of the dense cells 28 % on the chip,
         PERF.md, PR 32.)"""
         if self.ssm_state is None:
-            return {}
+            return self._moe_kw
         if seats is None:
             seats = self._seat_ids([], rows)
-        return {"ssm": self.ssm_state, "seats": seats}
+        return {"ssm": self.ssm_state, "seats": seats, **self._moe_kw}
 
     def _keep_pool(self, res: tuple) -> tuple:
         """The trunk's result as every caller reads it: the updated seat
-        pool, which a model with recurrent state returns last, is kept
-        here."""
-        if self.ssm_state is None:
+        pool, which a model with recurrent state returns after them, is
+        kept here, and so is the routing that a model with expert layers
+        returns last (still on the device: the counts are read with the
+        dispatch's tokens, the logits rows' picks with their logprobs
+        where a request asked for those)."""
+        if self.ssm_state is None and not self._moe_counted:
             return res
-        *res, self.ssm_state = res
+        res = list(res)
+        if self._moe_counted:
+            counts, self._moe_picks, self._moe_prompt_picks = res.pop()
+            self._moe_inflight.append((self.flight.seq, counts))
+        if self.ssm_state is not None:
+            self.ssm_state = res.pop()
         return tuple(res)
+
+    def _moe_due(self, seq: int) -> tuple[list, list]:
+        """``(step seqs, device arrays)`` of the routing counts of the
+        dispatches up to step ``seq``, taken off the in-flight list: the
+        device runs dispatches in order, so they are ready when that
+        step's tokens are, and the read that fetches those tokens fetches
+        them too (no sync of their own)."""
+        n = 0
+        while n < len(self._moe_inflight) and self._moe_inflight[n][0] <= seq:
+            n += 1
+        due, self._moe_inflight = (self._moe_inflight[:n],
+                                   self._moe_inflight[n:])
+        return [s for s, _ in due], [a for _, a in due]
+
+    def _moe_note(self, seqs: list, counts: list) -> None:
+        """File routing counts the host has read: the totals behind
+        ``tpuserve_moe_*`` and each dispatch's step record."""
+        for seq, c in zip(seqs, counts):
+            c = np.asarray(c, np.int64)
+            rows, hits = int(c[:-1].sum()), int(c[-1])
+            self.stats.moe_routed_rows += rows
+            self.stats.moe_expert_hits += hits
+            by_expert = self.stats.moe_expert_rows
+            if by_expert is None or len(by_expert) != len(c) - 1:
+                # (a swapped-in model with another number of experts
+                # starts its own row)
+                by_expert = np.zeros(len(c) - 1, np.int64)
+            self.stats.moe_expert_rows = by_expert + c[:-1]
+            self.flight.note_moe(seq, rows, hits)
+
+    def _note_prompt_picks(self, req: Request, row: int, done: int,
+                           take: int) -> None:
+        """The prefill just enqueued computed positions ``done`` to
+        ``done + take`` of ``req``'s prompt in rows ``row`` on of its flat
+        token axis.  Where the request asked for logprobs (and the model
+        has expert layers) those rows' picks are kept, on the device,
+        for its first token's entry (:meth:`_file_prompt_picks`)."""
+        if self._moe_prompt_picks is not None \
+                and req.params.logprobs is not None:
+            req.prompt_picks.append(
+                (self._moe_prompt_picks, row, done, take))
+
+    @staticmethod
+    def _file_prompt_picks(r: Request) -> None:
+        """Beside ``r``'s first token's logprob entry, just appended: the
+        experts each expert layer picked for each position of the prompt,
+        ``(prompt tokens, expert layers, k)``, -1 where no prefill
+        computed a position (a prefix the cache already held).  With the
+        tokens' own ``routed_experts`` that is every pick the tokens'
+        logits went through.  A re-prefill after pre-emption files
+        nothing: its first entry has the prompt's."""
+        kept, r.prompt_picks = r.prompt_picks, []
+        if not kept or len(r.logprobs) != 1:
+            return
+        first = np.asarray(kept[0][0])
+        out = np.full((len(r.prompt_token_ids),) + first.shape[1:], -1,
+                      first.dtype)
+        for picks, row, done, take in kept:
+            out[done:done + take] = np.asarray(picks)[row:row + take]
+        r.logprobs[0]["prompt_routed_experts"] = out.tolist()
 
     def _note_seat_start(self, req: Request, n_tokens: int) -> None:
         """A sequence of a model with recurrent state starts (or starts
@@ -2359,6 +2473,12 @@ class Engine:
                 logits, self.kv_cache = self._exec_prefill(
                     jnp.asarray(tokens), jnp.asarray(prompt_lens),
                     jnp.asarray(slot_ids), **kw)
+        for i, req in enumerate(reqs):
+            if packed:
+                self._note_prompt_picks(req, int(arrays[6][i]),
+                                        *chunks[i][2:])
+            else:
+                self._note_prompt_picks(req, i * L, 0, int(prompt_lens[i]))
         self.scheduler.mark_running(reqs)
         self.stats.num_prefill_steps += 1
         self.stats.prefill_packed_steps += packed
@@ -2429,6 +2549,7 @@ class Engine:
                 jnp.asarray(np.asarray([done], np.int32)),
                 jnp.asarray(np.asarray([n], np.int32)),
                 jnp.asarray(slot_ids), jnp.asarray(block_tables), **kw)
+        self._note_prompt_picks(req, 0, done, n)
         req.num_prefilled = done + n
         self.stats.num_prefill_steps += 1
         self._note_step_tokens(n, C, done + n, prefill=True)
@@ -2626,7 +2747,9 @@ class Engine:
         # bookkeeping: chunk progress, requeue continuations, promote
         # completions to running BEFORE sampling/emit (finish() removes
         # from running; same order as _run_prefill_chunk)
-        for req, _, done, take in chunks:
+        for ci, (req, _, done, take) in enumerate(chunks):
+            self._note_prompt_picks(req, int(arrays[6][n_dec + ci]), done,
+                                    take)
             req.num_prefilled = done + take
         for req, _, _, _ in reversed(cont):
             self.scheduler.waiting.appendleft(req)
@@ -2926,13 +3049,15 @@ class Engine:
             outputs += self._flush_window() + self._flush_first(deferred=True)
             self._pending_window = PendingWindow(reqs=list(reqs), toks=toks,
                                                  steps=S, lp=window_lp,
-                                                 gstate=gstate_out)
+                                                 gstate=gstate_out,
+                                                 seq=self.flight.seq)
             return outputs
         # synchronous: flush the just-dispatched window immediately (one
         # code path for the KV-commit-before-emit and overrun invariants)
         self._pending_window = PendingWindow(reqs=list(reqs), toks=toks,
                                              steps=S, lp=window_lp,
-                                             gstate=gstate_out)
+                                             gstate=gstate_out,
+                                             seq=self.flight.seq)
         return outputs + self._flush_window()
 
     def _flush_window(self) -> list[RequestOutput]:
@@ -2950,9 +3075,12 @@ class Engine:
         # exactly what the salvage path expects to find.
         self.faults.check("window_flush",
                           tuple(r.request_id for r in p.reqs))
+        due, moe = self._moe_due(p.seq)
         with self._sync("window"):
             # tpulint: sync-ok(THE designated sync: one device_get per S-token window is the whole fused-window design)
-            toks_h = np.asarray(jax.device_get(p.toks))
+            toks_h, moe = jax.device_get((p.toks, moe))
+        toks_h = np.asarray(toks_h)
+        self._moe_note(due, moe)
         lp_h = None
         if p.lp is not None:
             with self._sync("window"):
@@ -2984,10 +3112,8 @@ class Engine:
                         # path: _record_logprobs then _append_and_emit), and
                         # only for CONSUMED tokens — overrun rows break out
                         # below before recording theirs
-                        chosen_lp, top_ids, top_lps = lp_h
                         self._append_logprob_entry(
-                            r, int(toks_h[i, s]), chosen_lp[i, s],
-                            top_ids[i, s], top_lps[i, s])
+                            r, int(toks_h[i, s]), *(a[i, s] for a in lp_h))
                     out = self._emit_one(r, int(toks_h[i, s]))
                     outputs.append(out)
                     if out.finished:
@@ -3075,11 +3201,9 @@ class Engine:
         if lp_h is not None and prm.logprobs is not None:
             # consumed tokens only, appended before the emit bookkeeping —
             # the per-token path's entry order
-            chosen_lp, top_ids, top_lps = lp_h
             for s in range(consumed):
                 self._append_logprob_entry(req, toks_list[s],
-                                           chosen_lp[li, s],
-                                           top_ids[li, s], top_lps[li, s])
+                                           *(a[li, s] for a in lp_h))
         req.output_token_ids.extend(toks_list)
         # progress resets the salvage budget, exactly like _emit_one
         req.num_salvages = 0
@@ -3223,7 +3347,8 @@ class Engine:
                 toks = self._sample_modes(logits, reqs, B, in_flight)
             # resolve the PREVIOUS step while this one runs on device
             outputs += self._flush_pending()
-            self._pending = PendingDecode(reqs=list(reqs), toks=toks)
+            self._pending = PendingDecode(reqs=list(reqs), toks=toks,
+                                          seq=self.flight.seq)
             return outputs
         new_tokens = self._sample(logits, reqs, B)
         return outputs + self._append_and_emit(reqs, new_tokens)
@@ -3393,9 +3518,12 @@ class Engine:
         p, self._pending = self._pending, None
         if p is None:
             return []
+        due, moe = self._moe_due(p.seq)
         with self._sync("decode"):
             # tpulint: sync-ok(the single-step pipeline's designated sync: resolves the PREVIOUS step while the next runs)
-            toks = np.asarray(jax.device_get(p.toks))
+            toks, moe = jax.device_get((p.toks, moe))
+        toks = np.asarray(toks)
+        self._moe_note(due, moe)
         reqs, vals = [], []
         for i, r in enumerate(p.reqs):
             if r.finished:                      # aborted while in flight
@@ -3440,9 +3568,12 @@ class Engine:
         logits, toks = self._sample_enqueue(logits, reqs, B)
         if any(r.params.logprobs is not None for r in reqs):
             self._record_logprobs(logits, toks, reqs)
+        due, moe = self._moe_due(self.flight.seq)
         with self._sync("sample"):
             # tpulint: sync-ok(the synchronous per-step path's one sync; the pipelined paths never call _sample)
-            toks_np = np.asarray(jax.device_get(toks))[:n].copy()
+            toks_np, moe = jax.device_get((toks, moe))
+        toks_np = np.asarray(toks_np)[:n].copy()
+        self._moe_note(due, moe)
         if any(r.request_id in self._guided for r in reqs):
             # legacy substitution path: only rows WITHOUT a compiled FSM
             toks_np = self._apply_guided(logits, toks_np, reqs)
@@ -3467,7 +3598,7 @@ class Engine:
         substituted = any(r.request_id in self._guided for r in reqs)
         self._pending_first = PendingFirst(
             reqs=list(reqs), toks=toks, lp=lp,
-            logits=logits if substituted else None)
+            logits=logits if substituted else None, seq=self.flight.seq)
         if substituted or not self._pipeline_decode:
             outputs += self._flush_first()
         return outputs
@@ -3484,9 +3615,16 @@ class Engine:
             self.stats.prefill_first_token_deferred += len(p.reqs)
         else:
             self.stats.prefill_first_token_flushed_early += len(p.reqs)
+        due, moe = self._moe_due(p.seq)
         with self._sync("sample"):
+            # (the prompts' picks of rows that asked for logprobs come
+            # to the host in the same read: _file_prompt_picks below finds
+            # the arrays' host copies made)
             # tpulint: sync-ok(THE designated sync for a prefill's first tokens: one device_get a prefill, behind the next dispatch wherever the host can wait)
-            toks, lp = jax.device_get((p.toks, p.lp))
+            toks, lp, moe, _ = jax.device_get(
+                (p.toks, p.lp, moe,
+                 [a for r in p.reqs for a, *_ in r.prompt_picks]))
+        self._moe_note(due, moe)
         toks = np.array(toks[:len(p.reqs)])   # writable: guided picks in place
         live = [i for i, r in enumerate(p.reqs) if not r.finished]
         now = self.clock.monotonic()
@@ -3497,8 +3635,9 @@ class Engine:
                 self.stats.ttft_sum += now - r.arrival_time
                 self.stats.ttft_count += 1
             if lp is not None and r.params.logprobs is not None:
-                self._append_logprob_entry(r, int(toks[i]), lp[0][i],
-                                           lp[1][i], lp[2][i])
+                self._append_logprob_entry(r, int(toks[i]),
+                                           *(a[i] for a in lp))
+                self._file_prompt_picks(r)
         if p.logits is not None:
             # legacy substitution path: only rows WITHOUT a compiled FSM
             toks = self._apply_guided(p.logits, toks, p.reqs)
@@ -3940,36 +4079,45 @@ class Engine:
 
     def _logprobs_enqueue(self, logits: jnp.ndarray, toks: jnp.ndarray,
                           reqs: list[Request]) -> tuple:
+        """``(chosen logprob, top ids, top logprobs)`` of the rows of
+        ``logits``, which the dispatch just enqueued returned; for a model
+        with expert layers the rows' picks ride fourth."""
         top_n = min(max(r.params.logprobs or 0 for r in reqs) or 1, self.MAX_LOGPROBS)
-        return sampling_ops.compute_logprobs(logits, toks, top_n)
+        lp = sampling_ops.compute_logprobs(logits, toks, top_n)
+        picks, self._moe_picks = self._moe_picks, None
+        return lp if picks is None else lp + (picks,)
 
     def _record_logprobs(self, logits: jnp.ndarray, toks: jnp.ndarray,
                          reqs: list[Request]) -> None:
-        chosen_lp, top_ids, top_lps = self._logprobs_enqueue(logits, toks,
-                                                             reqs)
-        chosen_lp = np.asarray(chosen_lp)
-        top_ids = np.asarray(top_ids)
-        top_lps = np.asarray(top_lps)
+        lp = [np.asarray(a) for a in self._logprobs_enqueue(logits, toks,
+                                                            reqs)]
         for i, r in enumerate(reqs):
             if r.params.logprobs is None:
                 continue
-            self._append_logprob_entry(r, int(toks[i]), chosen_lp[i],
-                                       top_ids[i], top_lps[i])
+            self._append_logprob_entry(r, int(toks[i]), *(a[i] for a in lp))
+            self._file_prompt_picks(r)
 
     @staticmethod
     def _append_logprob_entry(r: Request, tok: int, chosen_lp,
-                              top_ids, top_lps) -> None:
+                              top_ids, top_lps, experts=None) -> None:
         """ONE home for the per-token logprob record shape — shared by
         the per-step recorder and the fused-window flush so the two
         paths' response formats cannot drift.  ``top_ids``/``top_lps``
-        are 1-D, possibly wider than the request asked for."""
+        are 1-D, possibly wider than the request asked for.  ``experts``
+        (expert layers, k), a model with expert layers: the experts each
+        layer routed the position to whose logits the token was sampled
+        from — what an evaluation in another precision has to replay,
+        because a top-k pick at a near-tie is not a rounding error."""
         k = min(r.params.logprobs, len(top_ids))
-        r.logprobs.append({
+        entry = {
             "token_id": tok,
             "logprob": float(chosen_lp),
             "top": [(int(t), float(l)) for t, l in
                     zip(top_ids[:k], top_lps[:k])],
-        })
+        }
+        if experts is not None:
+            entry["routed_experts"] = experts.tolist()
+        r.logprobs.append(entry)
 
     # ---- bookkeeping --------------------------------------------------
 
@@ -4323,7 +4471,11 @@ class Engine:
         during startup compiles would fail the pod before it ever served —
         not the failure mode the injector exists to test."""
         with self.faults.suspended():
-            return self._warmup(*args, **kwargs)
+            try:
+                return self._warmup(*args, **kwargs)
+            finally:
+                # warm-up dispatches route dummy rows: not traffic
+                self._moe_inflight.clear()
 
     def _warmup(self, prefill_buckets: Sequence[int | tuple[int, int]] | None
                 = None,

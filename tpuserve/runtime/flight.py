@@ -139,6 +139,9 @@ class FlightRecorder:
         self.devprof = None
         # client-observable SLI reservoirs: (class, kind) -> bounded ring
         self._sli: dict = {}
+        # step seq -> (routed rows, expert hits) of a model with expert
+        # layers (note_moe): read from the device after the step's record
+        self._moe: dict = {}
         self.postmortems = 0
         self.last_postmortem: Optional[str] = None
 
@@ -196,6 +199,21 @@ class FlightRecorder:
         self._steps.append((self._clock.monotonic(), kind, rows, actual, padded,
                             round(dur_s * 1000, 4), phases or None, dev,
                             self.seq, ctx_tokens))
+
+    def note_moe(self, seq: int, rows: int, hits: int) -> None:
+        """The routing counts of step ``seq``'s dispatch (a model with
+        expert layers): rows the sparse dispatch routed, summed over the
+        layers and fused steps, and expert-layers that got at least one.
+        They come back with the dispatch's tokens, a cycle or more after
+        its step record was written, so they are kept beside the ring
+        (as many as it holds) and joined in ``steps_snapshot``; a step
+        with several dispatches (a prefill and a window) sums them."""
+        old = self._moe.get(seq)
+        if old is not None:
+            rows, hits = rows + old[0], hits + old[1]
+        self._moe[seq] = (rows, hits)
+        while len(self._moe) > self._steps._n:
+            del self._moe[next(iter(self._moe))]
 
     def note_engine_facts(self, **facts) -> None:
         """Engine configuration facts stamped into every bundle (model,
@@ -272,6 +290,9 @@ class FlightRecorder:
                 # device-time attribution deltas (runtime/devprof.py):
                 # device_ms / dispatch_ms / compiles for this step
                 rec["dev"] = dev
+            moe = self._moe.get(seq)
+            if moe is not None:
+                rec["moe_rows"], rec["moe_expert_hits"] = moe
             out.append(rec)
         return out
 

@@ -168,6 +168,10 @@ class Request:
     finish_time: Optional[float] = None
     # logprob of each generated token + top alternatives (when requested)
     logprobs: list[dict] = dataclasses.field(default_factory=list)
+    # a model with expert layers, logprobs requested: the prompt rows'
+    # picks of each prefill dispatch, still on the device, until the first
+    # token's entry takes them (engine._note_prompt_picks)
+    prompt_picks: list = dataclasses.field(default_factory=list)
     # chunked prefill progress: prompt tokens already written to the cache
     # (reset on preemption along with the cache itself)
     num_prefilled: int = 0

@@ -252,6 +252,42 @@ class ServerMetrics:
             "Tokens prefilled AGAIN (prompt plus everything generated) "
             "because a pre-empted or salvaged sequence's recurrent state "
             "was dropped: the price of having no state snapshot")
+        # expert layers (models/transformer.py _moe_mlp): what the sparse
+        # dispatch routed, counted on the device and read with each
+        # dispatch's tokens (Engine._moe_note); all zero and the
+        # per-expert family empty for a model without experts
+        self.moe_routed_rows = counter(
+            "tpuserve_moe_routed_rows",
+            "Rows the sparse expert dispatch routed: tokens of a dispatch "
+            "(its padding rows included) x experts a token, summed over "
+            "the expert layers and a window's fused steps")
+        self.moe_expert_rows = Counter(
+            "tpuserve_moe_expert_rows",
+            "The same rows by the expert they were routed to (one index "
+            "over every expert layer: expert e of each layer)",
+            ["model_name", "expert"], registry=self.registry)
+        self.moe_expert_load = gauge(
+            "tpuserve_moe_expert_load_max_over_mean",
+            "The busiest expert's DISPATCHED rows over the mean expert's, "
+            "since start: what the grouped product's weight traffic in "
+            "decode and its tile waste in prefill grow with.  Dispatch "
+            "load, not routing skew: a dispatch's padding rows all carry "
+            "token 0 and go to that token's experts, so evenly routed "
+            "traffic reads 1.4-1.5 at the usual padding share, not 1.0")
+        # windows by layer kind: what an allocator by layer kind would
+        # give back (ROADMAP M3)
+        self.kv_window_dead_tokens = gauge(
+            "tpuserve_kv_window_dead_tokens",
+            "Token-layers of KV held for WINDOWED layers at positions "
+            "more than the window plus one block behind their sequence's "
+            "end, which no step will read again: zero where every layer "
+            "is windowed (those blocks are released) or none is.  Over "
+            "tpuserve_kv_pool_tokens x layers it is the share of the pool "
+            "held for nothing")
+        self.kv_pool_tokens = gauge(
+            "tpuserve_kv_pool_tokens",
+            "Capacity of the paged KV pool in tokens (blocks x block "
+            "size): vllm_kv_cache_usage_perc is a fraction of it")
         self.kv_demote_waited = counter(
             "tpuserve_kv_blocks_demote_waited",
             "Demoted blocks whose device-to-host copy the engine loop had "

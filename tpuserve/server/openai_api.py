@@ -1314,12 +1314,26 @@ class _Handler(BaseHTTPRequestHandler):
 
     @staticmethod
     def _completions_logprobs(entries) -> dict:
-        """OpenAI completions logprobs shape (parallel lists)."""
-        return {
+        """OpenAI completions logprobs shape (parallel lists).  A model
+        with expert layers adds ``routed_experts`` beside them: for each
+        token the experts each expert layer picked for the position it was
+        sampled after (``Engine._append_logprob_entry``); and, where the
+        entries start at the first token, ``prompt_routed_experts``: the
+        same for each position of the prompt, -1 where the prefix cache
+        held it (``Engine._file_prompt_picks``).  Together they are every
+        pick the tokens' logits went through: what an evaluation of the
+        same weights in another precision replays."""
+        out = {
             "token_logprobs": [e["logprob"] for e in entries],
             "tokens": [e["token_id"] for e in entries],
             "top_logprobs": [dict(e["top"]) for e in entries],
         }
+        if entries and all("routed_experts" in e for e in entries):
+            out["routed_experts"] = [e["routed_experts"] for e in entries]
+            if "prompt_routed_experts" in entries[0]:
+                out["prompt_routed_experts"] = \
+                    entries[0]["prompt_routed_experts"]
+        return out
 
     def _chat_logprobs(self, entries) -> dict:
         """OpenAI chat logprobs shape: per-token content entries with
@@ -1331,7 +1345,10 @@ class _Handler(BaseHTTPRequestHandler):
         return {"content": [
             {"token": tok(e["token_id"]), "logprob": e["logprob"],
              "top_logprobs": [{"token": tok(t), "logprob": lp}
-                              for t, lp in e["top"]]}
+                              for t, lp in e["top"]],
+             # (a model with expert layers: see _completions_logprobs)
+             **{k: e[k] for k in ("routed_experts", "prompt_routed_experts")
+                if k in e}}
             for e in entries]}
 
     @staticmethod

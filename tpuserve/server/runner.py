@@ -127,6 +127,8 @@ class AsyncEngineRunner:
         self._out_queues: dict[str, queue.Queue] = {}
         self._req_started: dict[str, float] = {}
         self._last_token_time: dict[str, float] = {}
+        # routed rows at the last pass that wrote the per-expert counters
+        self._moe_rows_exported = 0.0
         self._stop = threading.Event()
         self._wake = threading.Event()
         self._thread = threading.Thread(target=self._loop, daemon=True,
@@ -956,6 +958,8 @@ class AsyncEngineRunner:
             total = sum(bm.num_blocks for bm in bms)
             free = sum(bm.num_free_blocks for bm in bms)
             self.metrics.kv_usage.set((total - free) / max(total, 1))
+            self.metrics.kv_pool_tokens.set(
+                sum(bm.num_blocks * bm.block_size for bm in bms))
             # direct attribute access (not getattr-by-string) so the
             # metrics-consistency lint can see these families are fed
             _advance_counter(self.metrics.prefix_hits,
@@ -1037,9 +1041,26 @@ class AsyncEngineRunner:
                                  ("ssm_state_resets",
                                   self.metrics.ssm_state_resets),
                                  ("ssm_rebuilt_tokens",
-                                  self.metrics.ssm_rebuilt_tokens)):
+                                  self.metrics.ssm_rebuilt_tokens),
+                                 ("moe_routed_rows",
+                                  self.metrics.moe_routed_rows)):
                 _advance_counter(
                     metric, sum(getattr(s, attr, 0) for s in stats_objs))
+            by_expert = [s.moe_expert_rows for s in stats_objs
+                         if getattr(s, "moe_expert_rows", None) is not None]
+            if by_expert and self.metrics.moe_routed_rows._value.get() \
+                    != self._moe_rows_exported:
+                # one labelled child an expert: only when rows were routed
+                # since the last pass
+                self._moe_rows_exported = \
+                    self.metrics.moe_routed_rows._value.get()
+                rows = sum(by_expert)
+                for e, n in enumerate(rows):
+                    _advance_counter(self.metrics.moe_expert_rows.labels(
+                        model_name=self.metrics.model_name, expert=str(e)),
+                        int(n))
+                self.metrics.moe_expert_load.set(
+                    float(rows.max() / rows.mean()))
             # last-step padding-waste gauges (the bucketing win's live
             # observability; sums across disagg halves like kv_usage)
             self.metrics.step_padded_tokens.set(
@@ -1081,6 +1102,10 @@ class AsyncEngineRunner:
                                        for bm in bms) if seats is not None))
         self.metrics.kv_tier_blocks.labels(tier="hbm", **label).set(
             sum(getattr(bm, "num_cached_blocks", 0) for bm in bms))
+        dead = [e.window_dead_tokens() for e in (inners or [eng])
+                if hasattr(e, "window_dead_tokens")]
+        if dead:
+            self.metrics.kv_window_dead_tokens.set(sum(dead))
         stores = [t for t in (getattr(e, "_kv_tiers", None)
                               for e in (inners or [eng])) if t is not None]
         self.metrics.kv_tier_blocks.labels(tier="host", **label).set(
