@@ -28,6 +28,10 @@ WIDTHS = {
     # two (the block-size clamps halve rows, never heads, so it needs no
     # rule of its own; these compiles are the check)
     "falcon-h1-34b": (20, 4, 128),
+    # eight query heads a KV head, nine layers in twelve behind a 1,024
+    # window: the decode kernel alone (its other kernels compile at these
+    # head counts inside the 12-layer trunks compiled by hand, PR 35)
+    "mellum2-12b": (32, 4, 128),
 }
 PAGE = 32            # server default --block-size
 NUM_BLOCKS = 2048    # server default --num-blocks
@@ -67,12 +71,13 @@ def _no_persistent_cache():
     cc.reset_cache()
 
 
-def _decode(S, hq, hkv, d, quantized):
+def _decode(S, hq, hkv, d, quantized, window=None):
     from tpuserve.ops.pallas_paged_attention import paged_decode_attention
     B = MAX_NUM_SEQS
     pages, scales = _cache(S, hkv, d, quantized)
     return (lambda q, k, v, bt, sl, *s: paged_decode_attention(
-        q, k, v, bt, sl, d ** -0.5, interpret=False, **_scales(s)),
+        q, k, v, bt, sl, d ** -0.5, interpret=False, sliding_window=window,
+        **_scales(s)),
         [S((B, hq, d), jnp.bfloat16), *pages, S((B, MAX_PAGES), jnp.int32),
          S((B,), jnp.int32), *scales])
 
@@ -94,6 +99,12 @@ def _window(S, hq, hkv, d, quantized, C=CHUNK):
         q, k, v, bt, cx, ck, d ** -0.5, interpret=False, **_scales(s)),
         [S((1, C, hq, d), jnp.bfloat16), *pages,
          S((1, MAX_PAGES), jnp.int32), lens, lens, *scales])
+
+
+def _decode_windowed(S, hq, hkv, d, quantized):
+    """The decode kernel behind Mellum 2's sliding window: pages before
+    the window are skipped by the same DMA chain."""
+    return _decode(S, hq, hkv, d, quantized, window=1024)
 
 
 def _tail(S, hq, hkv, d, quantized):
@@ -143,7 +154,9 @@ CASES = [(kernel, width, False)
          for width in WIDTHS
          # the ragged kernel has no tp wrapper (mixed steps run reference
          # attention under a mesh), so a tp shard never reaches it
-         if (kernel, width) != ("ragged", "llama-8b-tp4")]
+         if (kernel, width) != ("ragged", "llama-8b-tp4")
+         and (kernel == "decode" or width != "mellum2-12b")]
+CASES += [("decode-w1024", "mellum2-12b", False)]
 CASES += [(kernel, width, True)
           for kernel in ("decode", "window", "ragged")
           for width in ("qwen3-0.6b", "llama-8b")]
@@ -159,12 +172,20 @@ def test_kernel_compiles_for_v5e(kernel, width, quantized, one_chip,
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    build = {"decode": _decode, "flash": _flash, "window": _window,
-             "tail": _tail, "ragged": _ragged}[kernel]
+    build = {"decode": _decode, "decode-w1024": _decode_windowed,
+             "flash": _flash, "window": _window, "tail": _tail,
+             "ragged": _ragged}[kernel]
     extra = (monkeypatch,) if kernel == "ragged" else ()
     fn, args = build(S, *WIDTHS[width], quantized, *extra)
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    if kernel.startswith("decode"):
+        # the kernel reads a page as the (page x Hkv, D) slab it is stored
+        # as: the view is a bitcast of the cache, never a copy of it
+        import re
+        assert re.search(rf"\[{NUM_BLOCKS},{PAGE * WIDTHS[width][1]},128\]"
+                         r"[^\n]* bitcast\(", text)
+        assert not re.search(rf"\[{NUM_BLOCKS},[^\n]* copy\(", text)
 
 
 @pytest.mark.parametrize("width", ["qwen3-0.6b", "llama-8b",

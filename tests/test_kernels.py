@@ -34,6 +34,12 @@ def test_flash_prefill_matches_reference(B, T, Hq, Hkv, D, blk):
     (2, 4, 2, 16, 4, 16, 4),
     (3, 8, 8, 64, 16, 32, 8),
     (1, 16, 2, 128, 32, 64, 4),
+    # the benchmark cells' head shapes at the server's page size: 2, 4, 8
+    # and 5 query heads a KV head against a (page x Hkv, D) slab
+    (3, 16, 8, 128, 32, 64, 20),
+    (3, 32, 8, 128, 32, 64, 20),
+    (3, 32, 4, 128, 32, 64, 20),
+    (3, 20, 4, 128, 32, 64, 20),
 ])
 def test_paged_decode_matches_reference(B, Hq, Hkv, D, page, nb, mp):
     rng = np.random.default_rng(B + Hq)
@@ -83,6 +89,58 @@ def test_paged_decode_multi_seq_programs(B, seqs_pp):
     ref = ref_ops.paged_decode_attention(q, kc, vc, bt, sl, D ** -0.5)
     out = paged_decode_attention(q, kc, vc, bt, sl, D ** -0.5, interpret=True,
                                  pages_per_group=2, seqs_per_program=seqs_pp)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+# (query heads, KV heads) of the four benchmark cells
+CELL_HEADS = [(16, 8), (32, 8), (32, 4), (20, 4)]
+
+
+@pytest.mark.parametrize("Hq,Hkv", CELL_HEADS)
+@pytest.mark.parametrize("case", ["edges", "window", "int8", "odd_batch",
+                                  "stale_nan"])
+def test_paged_decode_at_the_cells_head_shapes(case, Hq, Hkv):
+    """The slab body at the cells' shapes (page 32, head size 128, the
+    default 512-token chunk, tiles of 2,048 key columns): lengths at every edge
+    of a page, a tile and a chunk; the 1,024 window with contexts on both
+    sides of it; an int8 cache; a batch that is not a whole number of
+    programs; and NaN in every cache slot no sequence attends, which must
+    not reach the output."""
+    from tpuserve.ops.attention import pad_scale_lanes, quantize_kv
+    D, page = 128, 32
+    lens = {"window": [500, 1024, 1025, 1500, 2100],
+            "stale_nan": [1, 31, 513, 1025, 1500]}.get(
+                case, [1, 31, 128, 129, 512, 513, 700])
+    window = 1024 if case in ("window", "stale_nan") else None
+    B, mp = len(lens), -(-max(lens) // page) + 2
+    nb = B * mp
+    rng = np.random.default_rng(Hq * Hkv + len(case))
+    q = jnp.asarray(rng.standard_normal((B, Hq, D)), jnp.float32)
+    kc = rng.standard_normal((nb, page, Hkv, D)).astype(np.float32)
+    vc = rng.standard_normal((nb, page, Hkv, D)).astype(np.float32)
+    bt = rng.permutation(nb).reshape(B, mp).astype(np.int32)
+    sl = jnp.asarray(lens, jnp.int32)
+    kw, kernel_kw = {}, {}
+    if case == "int8":
+        kq, ks = quantize_kv(jnp.asarray(kc))
+        vq, vs = quantize_kv(jnp.asarray(vc))
+        kw = dict(k_scale=pad_scale_lanes(ks), v_scale=pad_scale_lanes(vs))
+        kc, vc = kq, vq
+    if case == "odd_batch":
+        kernel_kw = dict(seqs_per_program=4)        # 7 rows: 4 + 3 + pad
+    ref = ref_ops.paged_decode_attention(
+        q, jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(bt), sl, D ** -0.5,
+        sliding_window=window, **kw)
+    if case == "stale_nan":
+        attended = np.zeros((nb, page), bool)
+        for b, n in enumerate(lens):
+            for pos in range(max(n - window, 0), n):
+                attended[bt[b, pos // page], pos % page] = True
+        kc = np.where(attended[:, :, None, None], kc, np.nan)
+        vc = np.where(attended[:, :, None, None], vc, np.nan)
+    out = paged_decode_attention(
+        q, jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(bt), sl, D ** -0.5,
+        interpret=True, sliding_window=window, **kw, **kernel_kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
@@ -152,7 +210,7 @@ def test_paged_decode_vmem_clamp():
                  num_q_heads=16, q_itemsize=4)
     pg, sp = _clamp_to_vmem_budget(64, 8, **shape)
     assert pg < 64
-    assert vmem_footprint(pg, sp, 1, **shape) <= VMEM_LIMIT_BYTES
+    assert vmem_footprint(pg, sp, 1, decode=True, **shape) <= VMEM_LIMIT_BYTES
     # in-budget knobs pass through untouched
     assert _clamp_to_vmem_budget(4, 8, 32, 8, 128, 2, 16, 2) == (4, 8)
 
@@ -175,7 +233,7 @@ def test_paged_decode_vmem_clamp_end_to_end(caplog):
     pages_per_group arg), warns, and the clamped kernel still matches the
     reference."""
     import logging
-    B, Hq, Hkv, D, page, nb, mp = 3, 4, 2, 128, 16, 512, 256
+    B, Hq, Hkv, D, page, nb, mp = 3, 4, 2, 128, 16, 512, 1024
     rng = np.random.default_rng(11)
     q = jnp.asarray(rng.standard_normal((B, Hq, D)), jnp.float32)
     kc = jnp.asarray(rng.standard_normal((nb, page, Hkv, D)), jnp.float32)
@@ -184,8 +242,10 @@ def test_paged_decode_vmem_clamp_end_to_end(caplog):
     sl = jnp.asarray(rng.integers(1, page * mp + 1, (B,)), jnp.int32)
     ref = ref_ops.paged_decode_attention(q, kc, vc, bt, sl, D ** -0.5)
     with caplog.at_level(logging.WARNING, "tpuserve.ops.paged_attention"):
+        # 1,024-page groups of fp32 slabs = 64 MiB of double-buffered
+        # scratch (a page lands unpadded: 256 would fit)
         out = paged_decode_attention(q, kc, vc, bt, sl, D ** -0.5,
-                                     interpret=True, pages_per_group=256)
+                                     interpret=True, pages_per_group=1024)
     assert any("clamped" in r.message for r in caplog.records)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 

@@ -287,8 +287,6 @@ DEFAULT_CONFIG: dict = {
             "TPUSERVE_RAGGED_BLOCK": "kernel tuning",
             "TPUSERVE_FLASH_BLK_Q": "kernel tuning",
             "TPUSERVE_FLASH_BLK_K": "kernel tuning",
-            "TPUSERVE_SEQS_PER_PROGRAM": "kernel tuning",
-            "TPUSERVE_PAGES_PER_GROUP": "kernel tuning",
             "TPUSERVE_FSM_MAX_STATES": "grammar-compile guard rail",
             "TPUSERVE_FSM_MAX_WALK_CHARS": "grammar-compile guard rail",
             "TPUSERVE_FSM_JSON_DEPTH": "grammar-compile guard rail",

@@ -1,45 +1,53 @@
 """Pallas TPU paged attention for single-token decode.
 
-Each grid program now handles ``seqs_per_program`` sequences (VERDICT r2
-weak #3 asked for multi-sequence programs): the per-(sequence, page-group)
-KV chunks are DMA'd from HBM into a double-buffered VMEM scratch using the
-block table (scalar-prefetched so page addresses are known before the
-kernel body runs), with the prefetch pipeline running *across sequence
-boundaries* — while sequence ``s``'s last group is contracting, sequence
-``s+1``'s first group is already in flight.  A single-sequence-per-program
-grid exposes the full first-group DMA latency once per sequence (for the
-decode-typical one-group case that is *every* sequence, i.e. zero overlap);
-the flattened pipeline keeps HBM reads continuous for the whole batch.
+One grid program walks up to ``MAX_SEQS_PER_PROGRAM`` sequences (a decode
+batch is one program): the per-(sequence, page-group) KV chunks are DMA'd
+from HBM into a double-buffered VMEM scratch using the block table
+(scalar-prefetched so page addresses are known before the kernel body
+runs), with the prefetch pipeline running *across sequence boundaries* —
+while sequence ``s``'s last chunk is contracting, sequence ``s+1``'s first
+chunk is already in flight, so HBM reads stay continuous for the batch.
 
 This is the TPU-native replacement for the CUDA paged-attention kernels
 inside the vLLM image the reference deploys (reference:
 kubernetes-single-node.yaml:14; SURVEY.md §2.2, §7 "hard parts" — see also
 PAPERS.md "Ragged Paged Attention").
 
-Why the occupancy lever is DMA, not the MXU (reasoning from shapes; not
-measured on the current code): decode reads each KV byte exactly once per
-step, so its arithmetic intensity is ~1 FLOP/byte — two orders of
-magnitude below the MXU's compute:bandwidth balance point.  The kernel is
-therefore
-bandwidth-bound by construction; padding the QK contraction to 128 q rows
-(e.g. cross-sequence block-diagonal packing) multiplies FLOPs by the
-packing factor for identical wall-clock at best.  What matters is (a)
-never letting the HBM pipe drain (the cross-sequence prefetch above) and
-(b) keeping the dots in the KV's stored dtype:
+**A page is contracted in the layout it is stored in** (PR 37).  The cache
+is ``(num_blocks, page, Hkv, D)``; a page is ``page x Hkv`` contiguous rows
+of ``D``, token-major.  The kernel sees it as that ``(page x Hkv, D)`` slab
+(a bitcast of the cache, never a copy), lands it with one linear DMA into
+full tiles of the scratch, and contracts ALL of a sequence's query heads
+against the slab's ``rows x Hkv`` keys in one dot.  Heads are told apart on
+the float32 score tile — a column whose key head is not the query row's is
+masked like a position past the sequence's end, its probability is exactly
+0 and it drops out of the ``P x V`` contraction over the same slab — not by
+moving K and V.  What is left per row is sized by what is valid: a chunk is
+contracted a tile of ``DECODE_TILE_COLUMNS`` key columns at a time, only
+tiles that hold an attended position run, and V is rewritten only in a
+sequence's first and last tile, the only ones that can hold a row outside
+the sequence.
+
+Why the stored layout (measured on a v5e at the benchmark cells' shapes,
+B = 64, mean context 770; PERF.md §6, PR 37 has the table): landing a page
+as ``(page, Hkv, D)`` pads 4 or 8 KV heads to bf16's 16 sublanes, and a dot
+batched over KV heads needs K and V relaid head-major in every group.  A
+body built that way reads 8.1-9.2 ns a cached token a layer where its DMAs
+alone take 6.2 (8 KV heads) and 3.55 (4): its time follows the rows it
+walks, not the bytes it reads.  This body reads 6.5 and 4.4-4.5; what
+bounds it is its DMAs (two descriptors a page: 658 and 576 GB/s alone).
 
 - **Native-dtype MXU dots.**  The QK and PV contractions consume q/k/v in
   their stored dtype (bf16 KV cache) with fp32 accumulation
   (``preferred_element_type``) — upcasting to fp32 *before* the dot runs
   the MXU at its slow fp32 rate for no accuracy gain.
-- **Page groups.**  Each loop iteration consumes ``G`` pages at once: one
-  (group, D) x (D, G*page) contraction instead of G skinny per-page dots,
-  amortising loop/relayout overhead.
+- **Chunks and tiles.**  A DMA chunk is ``TARGET_GROUP_ROWS`` tokens deep
+  (the prefetch distance); a compute tile ``DECODE_TILE_COLUMNS`` key
+  columns wide.  Both are
+  :func:`decode_tiling`'s, a static function of the shapes.
 
 Semantics match ``tpuserve.ops.attention.paged_decode_attention``; verified
 against it in interpret mode on CPU.
-
-Sweepable knobs (read from the environment, static at trace time):
-``TPUSERVE_PAGES_PER_GROUP`` and ``TPUSERVE_SEQS_PER_PROGRAM``.
 """
 
 from __future__ import annotations
@@ -53,7 +61,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpuserve.ops.attention import SCALE_LANES, dequantize_kv
+from tpuserve.ops.attention import SCALE_LANES
 
 #: what the kernel's custom call is called in a profiler trace (the HLO
 #: instruction's name); the benchmark's trace readers match it
@@ -86,26 +94,28 @@ def compiler_params(*dimension_semantics: str) -> pltpu.CompilerParams:
 def vmem_footprint(pages_g: int, block_rows: int, dot_rows: int,
                    page_size: int, num_kv_heads: int, head_dim: int,
                    kv_itemsize: int, num_q_heads: int, q_itemsize: int,
-                   quantized: bool = False) -> int:
+                   quantized: bool = False, decode: bool = False) -> int:
     """Upper bound on the scoped VMEM one program of a paged kernel needs.
 
     ``block_rows``: q rows in the pipelined q/out block (seqs_per_program
     for decode, blk_q for the window/ragged kernels); ``dot_rows``: q rows
-    contracted against one page group at a time (1 for decode, which walks
-    its block a sequence at a time; blk_q otherwise).
+    contracted against one page group at a time (blk_q for the window and
+    ragged kernels; ``decode`` walks its block a sequence at a time).
 
     Counts what Mosaic allocates, not dense bytes — the trailing two dims
-    of every VMEM array pad to the dtype's minimum tile, so narrow-head
-    caches cost far more than their element count:
-      - KV scratch: 2 slots (double buffer) x {K,V} x rows_g x
-        padded(Hkv) x D at the cache dtype — Hkv pads to 32 rows for
-        int8, 16 for bf16, 8 for f32, which is why an 8-kv-head int8
-        cache does NOT shrink scratch 2x;
+    of every VMEM array pad to the dtype's minimum tile:
+      - KV scratch: 2 slots (double buffer) x {K,V} x rows_g tokens.  The
+        window and ragged kernels land a page as (page, Hkv, D), whose
+        Hkv pads to 32 rows for int8, 16 for bf16, 8 for f32 — which is
+        why an 8-kv-head int8 cache does NOT shrink their scratch 2x.
+        ``decode`` lands it as the (page x Hkv, D) slab it is in HBM:
+        full tiles, no padding, a half (8 kv heads) or a quarter (4) of
+        the padded scratch in bf16;
       - int8 scale scratch: 2 x {K,V} x rows_g x SCALE_LANES f32;
       - q/out pipeline blocks: 2 buffers each (Pallas double-buffers
         grid-indexed blocks) x block_rows x padded(Hq) x D;
-      - what the body keeps live while it contracts one page group,
-        which the compiler places on the same scoped stack.  Mosaic
+      - what the body keeps live while it contracts, which the compiler
+        places on the same scoped stack.  Window / ragged: Mosaic
         walks the key axis a 128-lane tile at a time, so the live set is
         about eleven (Hkv, rows_q, 128) f32 slabs (score, exp, mask and
         key positions of the tile, the accumulator and its update) plus
@@ -116,16 +126,28 @@ def vmem_footprint(pages_g: int, block_rows: int, dot_rows: int,
         accepted over 54 shapes — Hq 4..64, GQA and MHA, pages_g 4..16,
         blk_q 32..128, bf16 and int8 — and bound every one from above
         (by 0..18 MiB).  This term, not the scratch, is what overflowed
-        the default scope."""
+        the default scope.  ``decode``: one tile of ``DECODE_TILE_COLUMNS``
+        key columns at a time — the (Hq, columns) f32 score tile, its
+        exponentials, the head mask and a spare, the K and V tiles as
+        values, and an int8 tile's f32 dequantization."""
     from tpuserve.utils import round_up
-    kv_rows = round_up(num_kv_heads, MIN_SUBLANES.get(kv_itemsize, 8))
-    q_heads = round_up(num_q_heads, MIN_SUBLANES.get(q_itemsize, 8))
     lanes = round_up(head_dim, 128)   # lane dim pads to the 128 width too
     rows_g = pages_g * page_size
-    rows_q = round_up(dot_rows * (num_q_heads // num_kv_heads), 8)
-    kv = 2 * 2 * rows_g * kv_rows * lanes * kv_itemsize
+    q_heads = round_up(num_q_heads, MIN_SUBLANES.get(q_itemsize, 8))
     scales = 2 * 2 * round_up(rows_g, 8) * SCALE_LANES * 4 if quantized else 0
     qo = 2 * 2 * block_rows * q_heads * lanes * q_itemsize
+    if decode:
+        slab_g = round_up(rows_g * num_kv_heads,
+                          MIN_SUBLANES.get(kv_itemsize, 8))
+        slab_t = min(rows_g * num_kv_heads, DECODE_TILE_COLUMNS)
+        kv = 2 * 2 * slab_g * lanes * kv_itemsize
+        score_tile = round_up(num_q_heads, 8) * round_up(slab_t, 128) * 4
+        kv_values = 2 * slab_t * lanes * (q_itemsize
+                                          + (2 * 4 if quantized else 0))
+        return kv + scales + qo + 4 * score_tile + kv_values
+    kv_rows = round_up(num_kv_heads, MIN_SUBLANES.get(kv_itemsize, 8))
+    rows_q = round_up(dot_rows * (num_q_heads // num_kv_heads), 8)
+    kv = 2 * 2 * rows_g * kv_rows * lanes * kv_itemsize
     slab = num_kv_heads * rows_q * 128 * 4
     score_tile = slab * round_up(rows_g, 128) // 128
     kv_values = num_kv_heads * rows_g * lanes * (
@@ -147,7 +169,8 @@ def _clamp_to_vmem_budget(pages_g: int, block_rows: int, page_size: int,
     def footprint(pg: int, br: int) -> int:
         return vmem_footprint(pg, br, br if rows_per_dot else 1, page_size,
                               num_kv_heads, head_dim, kv_itemsize,
-                              num_q_heads, q_itemsize, quantized)
+                              num_q_heads, q_itemsize, quantized,
+                              decode=not rows_per_dot)
 
     orig = (pages_g, block_rows)
     while footprint(pages_g, block_rows) > VMEM_LIMIT_BYTES and pages_g > 1:
@@ -163,58 +186,80 @@ def _clamp_to_vmem_budget(pages_g: int, block_rows: int, page_size: int,
     return pages_g, block_rows
 
 
-# Target K rows per compute iteration: G = ceil(TARGET_GROUP_ROWS / page).
-# 512 rows x 128 lanes is deep enough to amortise relayout/loop overhead
-# while 2 slots x (K+V) x 512 rows x 16 (8 kv heads padded to the bf16
-# tile) x 128 x 2B = 8 MiB stays inside VMEM_LIMIT_BYTES next to the
-# q/output blocks and the score tiles.
+# Target K rows per page group: G = ceil(TARGET_GROUP_ROWS / page).  The
+# window and ragged kernels contract a whole group in one dot; the decode
+# kernel lands a group in one DMA chunk and contracts it a tile at a time.
 TARGET_GROUP_ROWS = 512
 
-# Sequences per grid program: deep enough that the cross-sequence DMA
-# pipeline hides each first-group latency behind the previous sequence's
-# compute.  The grid stays sequential ("arbitrary" dimension semantics):
-# programs are in fact independent, but flipping to "parallel" megacore
-# partitioning for a manual-DMA kernel is an optimization to land WITH a
-# TPU measurement, not before one.
-DEFAULT_SEQS_PER_PROGRAM = 8
+# Key columns (cached tokens x KV heads) the decode kernel contracts at a
+# time, the width of its float32 score tile: 2,048 read fastest at 8 KV
+# heads (256 tokens) and at 4 (512) on a v5e; 1,024 reads 1-6 % slower,
+# 512 a third slower, 4,096 7-9 % slower (PERF.md §6, PR 37).
+DECODE_TILE_COLUMNS = 2048
+
+# Most sequences one decode program walks.  The cross-sequence DMA chain
+# restarts at every program, so a batch is one program where it fits
+# (64 rows in one program read 2 % faster than in eight).
+MAX_SEQS_PER_PROGRAM = 64
 
 
-def _env_int(name: str) -> int | None:
-    val = os.environ.get(name)
-    return int(val) if val else None
+def decode_tiling(page_size: int, num_kv_heads: int, max_pages: int,
+                  batch: int) -> tuple[int, int, int]:
+    """``(pages_g, pages_t, seqs_pp)`` of the decode kernel, a static
+    function of the shapes as measured on a v5e (PERF.md §6, PR 37): a DMA
+    chunk of ``TARGET_GROUP_ROWS`` tokens (256 and 1,024 read within 3 %
+    of it), compute tiles of ``DECODE_TILE_COLUMNS`` key columns (a whole
+    number of them a chunk), the whole batch in one program up to
+    ``MAX_SEQS_PER_PROGRAM`` rows."""
+    pages_g = min(max(1, -(-TARGET_GROUP_ROWS // page_size)), max_pages)
+    pages_t = max(1, DECODE_TILE_COLUMNS // (page_size * num_kv_heads))
+    return pages_g, _tile_pages(pages_g, pages_t), min(
+        batch, MAX_SEQS_PER_PROGRAM)
+
+
+def _tile_pages(pages_g: int, pages_t: int) -> int:
+    """The largest whole divisor of a chunk's pages up to ``pages_t``."""
+    pages_t = max(1, min(pages_t, pages_g))
+    while pages_g % pages_t:
+        pages_t -= 1
+    return pages_t
 
 
 def _scale_rows(scr, num_kv_heads: int):
     """One slot's landed scale pages (pages_g, page, SCALE_LANES) ->
-    (Hkv, rows_g): the kv heads sit in the first lanes of each row."""
+    (Hkv, rows_g): the kv heads sit in the first lanes of each row.  For
+    the window and ragged kernels, which relayout K and V head-major."""
     rows = scr.reshape(-1, scr.shape[-1])[:, :num_kv_heads]
     return jnp.swapaxes(rows, 0, 1)
 
 
 def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
                          k_scr, v_scr, sems, *, scale, page_size, pages_g,
-                         num_kv_heads, group, head_dim, seqs_pp,
+                         pages_t, num_kv_heads, group, head_dim, seqs_pp,
                          ks_hbm=None, vs_hbm=None, ks_scr=None, vs_scr=None,
                          sliding_window=None, logit_softcap=None):
-    """``ks_hbm``/``vs_hbm`` present = int8 cache: value pages DMA as int8
-    (half the HBM bytes — the whole point) alongside tiny per-page scale
-    blocks, and dequantize on the VPU after landing in VMEM.
+    """A page is contracted in the layout it is stored in: ``k_hbm`` /
+    ``v_hbm`` are the cache seen as ``(num_blocks, page x Hkv, D)`` slabs
+    (row ``t x Hkv + h``), a page lands in full tiles of the scratch as
+    one linear piece, and ALL query heads of a sequence meet ``rows x
+    Hkv`` key columns in one dot.  Heads are told apart on the score tile:
+    a column whose key head is not the query row's is masked like a
+    position past the sequence's end, its probability is exactly 0 and it
+    drops out of the ``P x V`` contraction over the same slab.
 
-    ``sliding_window`` (static): attend only the last W cached positions —
-    groups and pages entirely BEFORE the window are never DMA'd, so a 32k
-    context with a 4k window moves ~1/8 the KV bytes."""
+    ``ks_hbm``/``vs_hbm`` present = int8 cache: value pages DMA as int8
+    beside their scale pages and are dequantized in the slab layout.
+
+    ``sliding_window`` (static): attend only the last W cached positions;
+    pages entirely BEFORE the window are never DMA'd and tiles entirely
+    before it never contracted."""
     quantized = ks_hbm is not None
-    p = pl.program_id(0)
-    base = p * seqs_pp
-    rows_g = pages_g * page_size
-
-    def num_pages(s):
-        return pl.cdiv(sl_ref[base + s], page_size)
-
-    def num_groups(s):
-        # >= 1 so padded/empty sequences keep the chunk pipeline uniform
-        # (their zero pages mean no DMAs start and no waits happen).
-        return jnp.maximum(pl.cdiv(sl_ref[base + s], rows_g), 1)
+    base = pl.program_id(0) * seqs_pp
+    num_q_heads = num_kv_heads * group
+    rows_g = pages_g * page_size        # tokens a DMA chunk
+    rows_t = pages_t * page_size        # tokens a compute tile
+    slab_p = page_size * num_kv_heads   # slab rows a page
+    slab_t = rows_t * num_kv_heads      # slab rows (key columns) a tile
 
     def win_start(s):
         # first attended position (0 without a window)
@@ -222,17 +267,30 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
             return jnp.int32(0)
         return jnp.maximum(sl_ref[base + s] - sliding_window, 0)
 
+    def num_groups(s):
+        # >= 1 so padded/empty sequences keep the chunk pipeline uniform
+        # (their zero pages mean no DMAs start and no waits happen).
+        return jnp.maximum(pl.cdiv(sl_ref[base + s], rows_g), 1)
+
     def first_group(s):
-        if sliding_window is None:
-            return jnp.int32(0)
         return win_start(s) // rows_g
+
+    def page_range(s, g, lo, n):
+        """The pages of chunk ``g``'s slots ``[lo, lo + n)`` that hold an
+        attended position, as slot indices.  MUST be the same for start
+        and wait, or the semaphores desync."""
+        first = jnp.maximum(win_start(s) // page_size - g * pages_g, lo)
+        last = jnp.minimum(pl.cdiv(sl_ref[base + s], page_size)
+                           - g * pages_g, lo + n)
+        return first, last
 
     def _copies(s, g, slot, j):
         page = bt_ref[base + s, g * pages_g + j]
+        rows = pl.ds(pl.multiple_of(j * slab_p, slab_p), slab_p)
         copies = [
-            pltpu.make_async_copy(k_hbm.at[page], k_scr.at[slot, j],
+            pltpu.make_async_copy(k_hbm.at[page], k_scr.at[slot, rows],
                                   sems.at[0, slot, j]),
-            pltpu.make_async_copy(v_hbm.at[page], v_scr.at[slot, j],
+            pltpu.make_async_copy(v_hbm.at[page], v_scr.at[slot, rows],
                                   sems.at[1, slot, j]),
         ]
         if quantized:
@@ -244,55 +302,57 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
             ]
         return copies
 
-    def _page_needed(s, g, j):
-        """Inside the valid range AND not entirely before the window.
-        MUST be identical for start and wait or semaphores desync."""
-        pi = g * pages_g + j
-        needed = pi < num_pages(s)
-        if sliding_window is not None:
-            needed &= pi >= win_start(s) // page_size
-        return needed
+    def each_copy(s, g, slot, lo, n, act):
+        def one(j, _):
+            for c in _copies(s, g, slot, j):
+                act(c)
+            return 0
+        jax.lax.fori_loop(*page_range(s, g, lo, n), one, 0)
 
     def start_chunk(s, g, slot):
-        def copy_one(j, _):
-            @pl.when(_page_needed(s, g, j))
-            def _():
-                for c in _copies(s, g, slot, j):
-                    c.start()
-            return 0
-        jax.lax.fori_loop(0, pages_g, copy_one, 0)
+        each_copy(s, g, slot, 0, pages_g, lambda c: c.start())
 
-    def wait_chunk(s, g, slot):
-        def wait_one(j, _):
-            @pl.when(_page_needed(s, g, j))
-            def _():
-                for c in _copies(s, g, slot, j):
-                    c.wait()
-            return 0
-        jax.lax.fori_loop(0, pages_g, wait_one, 0)
+    # which key columns of a tile belong to which query row: column c of
+    # the slab is key head c % Hkv, query row i reads key head i // group
+    col_head = jax.lax.rem(jax.lax.broadcasted_iota(
+        jnp.int32, (num_q_heads, slab_t), 1), num_kv_heads)
+    row_head = jax.lax.broadcasted_iota(
+        jnp.int32, (num_q_heads, slab_t), 0) // group
+    own_head = col_head == row_head
+
+    def dequant(vals, scr, slot, t, keep=None):
+        """int8 slab rows of tile ``t`` x their (token, head) scales, in
+        the slab's own layout: (rows_t x Hkv, D) in q's dtype; zeros
+        where ``keep`` (slab rows, 1) is false."""
+        sc = scr[slot, pl.ds(t * pages_t, pages_t)]
+        sc = sc.reshape(rows_t, sc.shape[-1])[:, :num_kv_heads]
+        vals = vals.astype(jnp.float32).reshape(rows_t, num_kv_heads,
+                                                head_dim)
+        vals = (vals * sc[:, :, None]).reshape(slab_t, head_dim)
+        if keep is not None:
+            vals = jnp.where(keep, vals, 0.0)
+        return vals.astype(q_ref.dtype)
 
     start_chunk(0, first_group(0), 0)
 
     def seq_body(s, parity0):
         seq_len = sl_ref[base + s]
-        ng = num_groups(s)
         g0 = first_group(s)
-        neff = ng - g0                  # groups this sequence processes
+        neff = num_groups(s) - g0       # chunks this sequence lands
         ws = win_start(s)
-        q_r = q_ref[pl.ds(s, 1)].reshape(num_kv_heads, group, head_dim)
+        q = q_ref[s]                    # (Hq, D), stored dtype
 
-        m0 = jnp.full((num_kv_heads, group, 1), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((num_kv_heads, group, 1), jnp.float32)
-        acc0 = jnp.zeros((num_kv_heads, group, head_dim), jnp.float32)
+        m0 = jnp.full((num_q_heads, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((num_q_heads, 1), jnp.float32)
+        acc0 = jnp.zeros((num_q_heads, head_dim), jnp.float32)
 
-        def body(i, carry):
+        def chunk_body(i, carry):
             g = g0 + i
-            m_prev, l_prev, acc_prev = carry
             slot = jax.lax.rem(parity0 + i, 2)
 
             # Prefetch the pipeline's next chunk into the other slot:
-            # this sequence's next group, or the next sequence's first
-            # IN-WINDOW group.
+            # this sequence's next one, or the next sequence's first
+            # IN-WINDOW one.
             @pl.when(i + 1 < neff)
             def _prefetch_group():
                 start_chunk(s, g + 1, 1 - slot)
@@ -301,61 +361,72 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
             def _prefetch_seq():
                 start_chunk(s + 1, first_group(s + 1), 1 - slot)
 
-            wait_chunk(s, g, slot)
-            # (pages_g, page, Hkv, D) -> (Hkv, rows_g, D), stored dtype
-            k = jnp.swapaxes(
-                k_scr[slot].reshape(rows_g, num_kv_heads, head_dim), 0, 1)
-            v = jnp.swapaxes(
-                v_scr[slot].reshape(rows_g, num_kv_heads, head_dim), 0, 1)
-            if quantized:
-                # dequantize in VMEM: one VPU multiply per element, paid
-                # AFTER the halved DMA — results in q's dtype (bf16 on
-                # TPU) keep the dots on the fast MXU path
-                k = dequantize_kv(k, _scale_rows(ks_scr[slot], num_kv_heads),
-                                  q_ref.dtype)
-                v = dequantize_kv(v, _scale_rows(vs_scr[slot], num_kv_heads),
-                                  q_ref.dtype)
-            # Zero V rows outside [win_start, seq_len): pages that were
-            # never DMA'd hold unspecified scratch (possibly NaN), and
-            # 0 * NaN would poison the accumulator even though those
-            # probabilities are 0.
-            row_pos = g * rows_g + jax.lax.broadcasted_iota(
-                jnp.int32, (num_kv_heads, rows_g, 1), 1)
-            v_valid = row_pos < seq_len
-            if sliding_window is not None:
-                v_valid &= row_pos >= ws
-            v = jnp.where(v_valid, v, jnp.zeros_like(v))
-            # (Hkv, group, D) x (Hkv, rows, D) -> (Hkv, group, rows); bf16
-            # MXU inputs, fp32 accumulation; scale on the fp32 product.
-            sc = jax.lax.dot_general(q_r, k, (((2,), (2,)), ((0,), (0,))),
-                                     preferred_element_type=jnp.float32) * scale
-            if logit_softcap is not None:
-                sc = logit_softcap * jnp.tanh(sc / logit_softcap)
-            pos = g * rows_g + jax.lax.broadcasted_iota(
-                jnp.int32, (num_kv_heads, group, rows_g), 2)
-            s_valid = pos < seq_len
-            if sliding_window is not None:
-                s_valid &= pos >= ws
-            sc = jnp.where(s_valid, sc, NEG_INF)
+            # the chunk's attended positions, relative to its first
+            lo = jnp.maximum(ws - g * rows_g, 0)
+            hi = jnp.minimum(seq_len - g * rows_g, rows_g)
 
-            m_cur = jnp.max(sc, axis=2, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            pr = jnp.exp(sc - m_new)
-            correction = jnp.exp(m_prev - m_new)
-            l_new = l_prev * correction + jnp.sum(pr, axis=2, keepdims=True)
-            # Invalid rows have pr == 0 exactly, so stale scratch V cannot
-            # leak; pr in V's dtype keeps the second contraction on the
-            # fast MXU path.
-            pv = jax.lax.dot_general(pr.astype(v.dtype), v,
-                                     (((2,), (1,)), ((0,), (0,))),
-                                     preferred_element_type=jnp.float32)
-            acc_new = acc_prev * correction + pv
-            return m_new, l_new, acc_new
+            def tile_body(t, carry):
+                m_prev, l_prev, acc_prev = carry
+                each_copy(s, g, slot, t * pages_t, pages_t,
+                          lambda c: c.wait())
+                rows = pl.ds(pl.multiple_of(t * slab_t, slab_t), slab_t)
+                # attended key columns / slab rows of this tile
+                c_lo = (lo - t * rows_t) * num_kv_heads
+                c_hi = (hi - t * rows_t) * num_kv_heads
 
-        m, l, acc = jax.lax.fori_loop(0, neff, body, (m0, l0, acc0))
+                # A row outside [lo, hi) is a page that was never DMA'd
+                # (unspecified scratch) or a slot the cache never wrote:
+                # its probability is exactly 0, but 0 x NaN would poison
+                # the accumulator.  Only a sequence's first and last
+                # tile can hold one, so only they are rewritten (an int8
+                # tile is rewritten anyway: the select rides on that).
+                def attended_rows():
+                    r = jax.lax.broadcasted_iota(jnp.int32, (slab_t, 1), 0)
+                    return (r >= c_lo) & (r < c_hi)
+
+                if not quantized:
+                    @pl.when((c_lo > 0) | (c_hi < slab_t))
+                    def _clean_v():
+                        v_t = v_scr[slot, rows]
+                        v_scr[slot, rows] = jnp.where(attended_rows(), v_t,
+                                                      jnp.zeros_like(v_t))
+
+                k = k_scr[slot, rows]
+                v = v_scr[slot, rows]
+                if quantized:
+                    k = dequant(k, ks_scr, slot, t)
+                    v = dequant(v, vs_scr, slot, t, keep=attended_rows())
+                # (Hq, D) x (rows_t x Hkv, D) -> (Hq, rows_t x Hkv); MXU
+                # inputs in the stored dtype, fp32 accumulation; scale on
+                # the fp32 product.
+                sc = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if logit_softcap is not None:
+                    sc = logit_softcap * jnp.tanh(sc / logit_softcap)
+                c = jax.lax.broadcasted_iota(jnp.int32, (1, slab_t), 1)
+                sc = jnp.where(own_head & (c >= c_lo) & (c < c_hi),
+                               sc, NEG_INF)
+
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(sc, axis=1, keepdims=True))
+                pr = jnp.exp(sc - m_new)
+                correction = jnp.exp(m_prev - m_new)
+                l_new = l_prev * correction + jnp.sum(pr, axis=1,
+                                                      keepdims=True)
+                # masked columns have pr == 0 exactly; pr in V's dtype
+                # keeps the second contraction on the fast MXU path
+                pv = jax.lax.dot_general(pr.astype(v.dtype), v,
+                                         (((1,), (0,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                return m_new, l_new, acc_prev * correction + pv
+
+            return jax.lax.fori_loop(lo // rows_t, pl.cdiv(hi, rows_t),
+                                     tile_body, carry)
+
+        m, l, acc = jax.lax.fori_loop(0, neff, chunk_body, (m0, l0, acc0))
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        out = (acc / safe_l).reshape(1, num_kv_heads * group, head_dim)
-        o_ref[pl.ds(s, 1)] = out.astype(o_ref.dtype)
+        o_ref[s] = (acc / safe_l).astype(o_ref.dtype)
         return parity0 + neff
 
     jax.lax.fori_loop(0, seqs_pp, seq_body, 0)
@@ -380,49 +451,55 @@ def paged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     ``sliding_window``: attend only the last W positions; out-of-window
     pages are never DMA'd.
 
-    The env knobs are resolved HERE, outside jit, and passed as static
-    args — reading them inside the traced function would capture them at
-    first trace and silently ignore later changes (the jit cache key only
-    covers shapes and statics)."""
+    The block sizes are :func:`decode_tiling`'s, held to the VMEM budget;
+    ``pages_per_group`` / ``seqs_per_program`` override them for the
+    tests (a tile is then the largest whole divisor of the group up to
+    ``DECODE_TILE_COLUMNS`` key columns)."""
     page_size = k_cache.shape[1]
     max_pages = block_tables.shape[1]
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    pages_g = (pages_per_group or _env_int("TPUSERVE_PAGES_PER_GROUP")
-               or max(1, -(-TARGET_GROUP_ROWS // page_size)))
-    pages_g = min(pages_g, max_pages)
-    seqs_pp = (seqs_per_program or _env_int("TPUSERVE_SEQS_PER_PROGRAM")
-               or DEFAULT_SEQS_PER_PROGRAM)
-    seqs_pp = min(seqs_pp, q.shape[0])
+    pages_g, pages_t, seqs_pp = decode_tiling(page_size, k_cache.shape[2],
+                                              max_pages, q.shape[0])
+    if pages_per_group:
+        pages_g = min(pages_per_group, max_pages)
+    if seqs_per_program:
+        seqs_pp = min(seqs_per_program, q.shape[0])
     pages_g, seqs_pp = _clamp_to_vmem_budget(
         pages_g, seqs_pp, page_size, k_cache.shape[2], k_cache.shape[3],
         k_cache.dtype.itemsize, q.shape[1], q.dtype.itemsize,
         quantized=k_scale is not None)
+    pages_t = _tile_pages(pages_g, pages_t)
     scales = () if k_scale is None else (k_scale, v_scale)
     return _paged_decode_attention(q, k_cache, v_cache, block_tables,
                                    seq_lens, scales, scale=scale,
                                    interpret=interpret, pages_g=pages_g,
-                                   seqs_pp=seqs_pp,
+                                   pages_t=pages_t, seqs_pp=seqs_pp,
                                    sliding_window=sliding_window,
                                    logit_softcap=logit_softcap)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret",
-                                             "pages_g", "seqs_pp",
+                                             "pages_g", "pages_t", "seqs_pp",
                                              "sliding_window",
                                              "logit_softcap"))
 def _paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens,
                             scales, *, scale: float, interpret: bool,
-                            pages_g: int, seqs_pp: int,
+                            pages_g: int, pages_t: int, seqs_pp: int,
                             sliding_window: int | None = None,
                             logit_softcap: float | None = None) -> jnp.ndarray:
     B, Hq, D = q.shape
     num_blocks, page_size, Hkv, _ = k_cache.shape
     group = Hq // Hkv
     quantized = bool(scales)
+    # A page as it is stored: page x Hkv contiguous rows of D.  The same
+    # bytes in the same order, so XLA makes this a bitcast, not a copy
+    # (tests/test_chip_compile.py reads the compiled text for it).
+    k_cache = k_cache.reshape(num_blocks, page_size * Hkv, D)
+    v_cache = v_cache.reshape(num_blocks, page_size * Hkv, D)
 
     # Pad the batch to a whole number of programs; padded rows have
-    # seq_len 0 (no DMAs, masked scores) and are sliced off below.
+    # seq_len 0 (no DMAs, nothing contracted) and are sliced off below.
     Bp = -(-B // seqs_pp) * seqs_pp
     if Bp != B:
         pad = Bp - B
@@ -432,8 +509,8 @@ def _paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens,
 
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, page_size=page_size,
-        pages_g=pages_g, num_kv_heads=Hkv, group=group, head_dim=D,
-        seqs_pp=seqs_pp, sliding_window=sliding_window,
+        pages_g=pages_g, pages_t=pages_t, num_kv_heads=Hkv, group=group,
+        head_dim=D, seqs_pp=seqs_pp, sliding_window=sliding_window,
         logit_softcap=logit_softcap)
     if quantized:
         # operand order must mirror the extra in_specs/scratch below
@@ -450,9 +527,10 @@ def _paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens,
         pl.BlockSpec(memory_space=pl.ANY),      # k_cache stays in HBM
         pl.BlockSpec(memory_space=pl.ANY),      # v_cache stays in HBM
     ]
+    slab_g = pages_g * page_size * Hkv
     scratch = [
-        pltpu.VMEM((2, pages_g, page_size, Hkv, D), k_cache.dtype),
-        pltpu.VMEM((2, pages_g, page_size, Hkv, D), v_cache.dtype),
+        pltpu.VMEM((2, slab_g, D), k_cache.dtype),
+        pltpu.VMEM((2, slab_g, D), v_cache.dtype),
     ]
     if quantized:
         in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2   # scale pages
