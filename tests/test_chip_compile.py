@@ -366,3 +366,147 @@ def test_the_custom_call_carries_the_name_the_benchmark_matches(
     assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call\([^\n]*"
                      r"tpu_custom_call", text)
 
+
+
+# ---- every device operation of a decode window names its model part ------
+
+# operations that do work on the chip (a bitcast, a tuple, a parameter do
+# none), with the asynchronous halves the compiler splits a copy or a
+# slice into
+DEVICE_WORK = {"fusion", "convolution", "custom-call", "copy", "copy-start",
+               "copy-done", "slice", "slice-start", "slice-done",
+               "dynamic-slice", "dynamic-update-slice", "sort", "gather",
+               "scatter"}
+# the ONLY operations that carry an op_name and no part of the table: what
+# lax.scan itself emits around the window's body (its stacked outputs'
+# buffers and the write of a step's row into them), and one index clamp
+# of the expert layer's row gather that XLA names outside every path
+NO_PART = {
+    "jit(decode_multi)/decode/broadcast_in_dim",
+    "jit(decode_multi)/decode/while/body/broadcast_in_dim",
+    "jit(decode_multi)/decode/while/body/dynamic_update_slice",
+    "gather",
+}
+
+
+def _scheduled(text):
+    """The compiled module's instructions that run as operations of their
+    own (those of fused computations and reducers left out), by
+    computation, in schedule order: ``{computation: [(name, opcode,
+    op_name, operand names)]}``."""
+    import re
+    comps, cur, name = {}, None, None
+    for line in text.split("\n"):
+        if cur is None:
+            m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+            if m and not line.startswith(" "):
+                name, cur = m.group(1), []
+        elif line.startswith("}"):
+            comps[name], cur = cur, None
+        else:
+            cur.append(line)
+    inner = set()
+    for lines in comps.values():
+        for line in lines:
+            inner.update(re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", line))
+    inner -= {c for lines in comps.values() for line in lines
+              for c in re.findall(r"(?:body|condition)=%?([\w.\-]+)", line)}
+    out = {}
+    for comp, lines in comps.items():
+        if comp in inner:
+            continue
+        rows = []
+        for line in lines:
+            m = re.match(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\((.*)$",
+                         line)
+            if not m:
+                continue
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            rows.append((m.group(1), m.group(2),
+                         op_name.group(1) if op_name else "",
+                         set(re.findall(r"%([\w.\-]+)",
+                                        m.group(3).split("metadata=")[0]))))
+        out[comp] = rows
+    return out
+
+
+@pytest.mark.parametrize("model,kernels", [
+    ("Qwen/Qwen3-0.6B", {"_paged_decode_attention": "attn.kernel"}),
+    ("JetBrains/Mellum2-12B-A2.5B-Instruct",
+     {"_paged_decode_attention": "attn.kernel",
+      "_moe_grouped_matmul": "moe.experts"}),
+])
+def test_every_operation_of_a_decode_window_names_its_part(
+        model, kernels, one_chip, monkeypatch):
+    """``decode_multi`` at published widths, two layers, 64 rows, compiled
+    for the chip: whatever carries an ``op_name`` carries a part of the
+    scope table (``tpuserve/ops/scopes.py``), the exceptions listed above
+    by name, so that an unscoped operation cannot come back unseen.  What
+    the compiler makes itself carries no ``op_name`` at all; the benchmark
+    files it under the next operation of its program that names a PART
+    (``benchmark/layer_metrics/_scope_trace.py``), and here that rule is
+    held to the compiled text: such an operation is followed by one, and
+    for the wait on a prefetched weight slice (``slice-done``, the one
+    that costs time) the next operation with a part IS its consumer."""
+    import dataclasses
+
+    from test_scopes import scope_of, trunk_programs
+    from tpuserve.models.config import get_model_config
+    from tpuserve.ops import scopes
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def place(tree):
+        return jax.tree.map(lambda x: S(x.shape, x.dtype), tree)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get_model_config(model), num_layers=2)
+    fn, args, kwargs = trunk_programs(
+        cfg, S, place, rows=MAX_NUM_SEQS, steps=8, block_size=PAGE,
+        num_blocks=NUM_BLOCKS, max_blocks=MAX_PAGES,
+        attn_impl="pallas")["decode_multi"]
+    comps = _scheduled(fn.lower(*args, **kwargs).compile().as_text())
+    seen, unscoped, waits = set(), [], 0
+    for comp, rows in comps.items():
+        scoped = [scope_of(op_name)[1] and scope_of(op_name)
+                  for _, _, op_name, _ in rows]
+        users = {}
+        for i, (_, _, _, operands) in enumerate(rows):
+            for operand in operands:
+                users.setdefault(operand, []).append(i)
+
+        def consumers(i, depth=0):
+            found = set()
+            for j in users.get(rows[i][0], ()):
+                if j > i and scoped[j]:
+                    found.add(scoped[j])
+                elif j > i and depth < 6:
+                    found |= consumers(j, depth + 1)
+            return found
+
+        for i, (name, opcode, op_name, _) in enumerate(rows):
+            if opcode not in DEVICE_WORK:
+                continue
+            for kernel, part in kernels.items():
+                if name.split(".")[0] == kernel:
+                    assert scope_of(op_name) == (scopes.DECODE, part), op_name
+                    seen.add(kernel)
+            # (inside a pipelined loop the compiler names its waits after
+            # the loop itself: the reader takes those for the compiler's)
+            if op_name and not (op_name.endswith("/while")
+                                and opcode != "while"):
+                if not scoped[i] and op_name not in NO_PART:
+                    unscoped.append((name, op_name))
+                continue
+            nxt = next((s for s in scoped[i + 1:] if s), None)
+            if opcode == "slice-done":
+                waits += 1
+                assert nxt in consumers(i), (name, nxt, consumers(i))
+            elif not comp.startswith("main"):
+                # a loop body ends in scoped work; only the entry's own
+                # first and last copies have nothing scoped behind them
+                assert nxt or not consumers(i), name
+    assert not unscoped, unscoped
+    assert seen == set(kernels)
+    assert waits >= 8       # the layers' weight matrices are prefetched

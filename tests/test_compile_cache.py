@@ -36,11 +36,53 @@ def _probe(env_dir=None, cwd=ROOT):
 
 
 def test_env_set_means_code_sets_nothing(tmp_path):
-    """JAX reads the variable itself; configure() must not touch the config
-    (what JAX has after it is what JAX had before it)."""
+    """JAX reads the variable itself; configure() must not touch where the
+    cache lives (what JAX has after it is what JAX had before it)."""
     before, got, after, _ = _probe(env_dir=str(tmp_path))
     assert got == str(tmp_path)
     assert before == after == str(tmp_path)
+
+
+# Compiled twice in one fresh process, the second time after an edit.
+_TWICE = """
+import jax, jax.numpy as jnp
+from tpuserve.utils import compile_cache
+cache = compile_cache.configure()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+SRC = '''
+@jax.jit
+def trunk(x):
+    with jax.named_scope("SCOPE"):
+        return jnp.sin(x) @ x
+'''
+def build(src):
+    space = {"jax": jax, "jnp": jnp}
+    exec(compile(src, "trunk.py", "exec"), space)
+    space["trunk"](jnp.ones((8, 8))).block_until_ready()
+    return compile_cache.entries(cache)
+print(build(SRC.replace("SCOPE", "mlp")), build(EDITED))
+"""
+
+
+@pytest.mark.parametrize("edited,compiles_again", [
+    ('"\\n\\n" + SRC.replace("SCOPE", "mlp")', False),
+    ('SRC.replace("SCOPE", "attn.out")', True)],
+    ids=["a line moved", "a scope renamed"])
+def test_an_entry_is_found_by_its_scopes_and_not_by_its_lines(
+        edited, compiles_again, tmp_path):
+    """A program compiled under another scope name must not be found again
+    (its ``op_name``s would go into the trace); one whose source moved two
+    lines down must be (or every edit compiles a whole cell again)."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": ROOT,
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path)}
+    out = subprocess.run(
+        [sys.executable, "-c", _TWICE.replace("EDITED", edited)],
+        env=env, cwd=ROOT, capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    first, then = map(int, out.stdout.strip().splitlines()[-1].split())
+    assert first > 0
+    assert (then > first) == compiles_again
 
 
 def test_unset_means_one_fixed_path_in_the_checkout():

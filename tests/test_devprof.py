@@ -120,8 +120,6 @@ def test_ladder_registry_correctness(server):
     assert kinds <= {"prefill", "prefill_chunk", "decode", "decode_multi",
                      "verify", "verify_sampled", "draft", "mixed", "sample"}
     assert all(r["compile_ms"] > 0 for r in rows)
-    # activation estimate hint is wired from the model config
-    assert any(r["est_bytes"] > 0 for r in rows)
 
 
 def test_debug_engine_surfaces_compile_cache_stats(server):

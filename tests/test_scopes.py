@@ -1,0 +1,119 @@
+"""Every trunk names its device time (``tpuserve/ops/scopes.py``): the
+compiled programs of one tiny model of each family carry every scope of
+the table that applies to the family, each under its phase, and a scope
+changes no program's name.  (What the chip's compiler makes of them is
+``tests/test_chip_compile.py``; what the benchmark reads,
+``tests/benchmark/test_benchmark_scope_trace.py``.)"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from tpuserve.models import transformer
+from tpuserve.models.config import get_model_config
+from tpuserve.models.weights import init_params
+from tpuserve.ops import scopes
+from tpuserve.runtime.kv_cache import (CacheConfig, create_kv_cache,
+                                       create_ssm_state)
+
+COMMON = {scopes.EMBED, scopes.ATTN_QKV, scopes.ATTN_KV_WRITE,
+          scopes.ATTN_KERNEL, scopes.ATTN_OUT, scopes.MLP, scopes.HEAD}
+FAMILY = {
+    "tiny-qwen3": COMMON,
+    "tiny-falcon-h1": COMMON | {scopes.SSM_IN_PROJ, scopes.SSM_CONV,
+                                scopes.SSM_SCAN, scopes.SSM_OUT},
+    "tiny-mellum2": COMMON | {scopes.MOE_ROUTE, scopes.MOE_GATHER,
+                              scopes.MOE_EXPERTS, scopes.MOE_COMBINE},
+}
+# program -> (its phase, the parts only it has)
+PROGRAMS = {
+    "decode_multi": (scopes.DECODE, {scopes.SAMPLE, scopes.CARRY}),
+    "forward_ragged": (scopes.PREFILL, set()),
+    "prefill_chunk": (scopes.CHUNK, set()),
+}
+
+
+def scope_of(op_name: str) -> tuple:
+    """``(phase, part)`` of an ``op_name``: the first component that is a
+    phase, the last that is a part ("" for what it lacks)."""
+    comps = op_name.split("/")
+    return (next((c for c in comps if c in scopes.PHASES), ""),
+            next((c for c in reversed(comps) if c in scopes.PARTS), ""))
+
+
+def trunk_programs(cfg, S=jax.ShapeDtypeStruct, place=lambda tree: tree, *,
+                   rows=4, steps=4, tokens=64, blk=8, prompts=4, chunk=16,
+                   block_size=4, num_blocks=16, max_blocks=8,
+                   attn_impl="reference") -> dict:
+    """``{program: (jitted trunk, args, keyword args)}`` of shapes alone:
+    a fused decode window over ``rows`` rows, a packed prefill of
+    ``tokens`` flat tokens, one chunk of one prompt.  ``S`` makes a shape,
+    ``place`` puts a tree of shapes where the caller compiles for."""
+    i32 = jnp.int32
+    params = place(jax.eval_shape(lambda: init_params(cfg, 0)))
+    cache_cfg = CacheConfig(block_size=block_size, num_blocks=num_blocks,
+                            max_blocks_per_seq=max_blocks,
+                            dtype="bfloat16" if cfg.dtype == "bfloat16"
+                            else cfg.dtype)
+    kv = place(jax.eval_shape(lambda: create_kv_cache(cfg, cache_cfg)))
+
+    def tail(n):        # the seat pool and the seats, where there is one
+        if not cfg.has_ssm:
+            return ()
+        pool = place(jax.eval_shape(lambda: create_ssm_state(cfg, rows + 1)))
+        return (None, pool, S((n,), i32))
+
+    vec, seqs = S((rows,), i32), S((prompts,), i32)
+    return {
+        "decode_multi": (transformer.decode_multi, (
+            params, cfg, vec, vec, S((rows, max_blocks), i32), vec,
+            S((rows,), jnp.bool_), S((rows, 2), jnp.uint32),
+            S((rows,), jnp.float32), kv, *tail(rows)),
+            dict(steps=steps, mode="greedy", attn_impl=attn_impl)),
+        "forward_ragged": (transformer.forward_ragged, (
+            params, cfg, S((tokens,), i32), S((tokens,), i32),
+            S((tokens,), i32), S((tokens,), i32),
+            S((prompts, max_blocks), i32), seqs, seqs, seqs, S((2,), i32),
+            S((tokens // blk,), i32), seqs, kv, *tail(prompts)),
+            dict(ragged_blk=blk, attn_impl=attn_impl, decode_rows=False)),
+        "prefill_chunk": (transformer.prefill_chunk, (
+            params, cfg, S((1, chunk), i32), S((1,), i32), S((1,), i32),
+            S((1, chunk), i32), S((1, max_blocks), i32), kv, *tail(1)),
+            dict(attn_impl=attn_impl)),
+    }
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
+@pytest.mark.parametrize("model", sorted(FAMILY))
+def test_a_trunk_carries_every_scope_of_its_family(model, program):
+    fn, args, kwargs = trunk_programs(get_model_config(model))[program]
+    lowered = fn.lower(*args, **kwargs)
+    # the scope is inside the jitted function: the program is named as ever
+    assert re.search(rf"module @jit_{program}\b", lowered.as_text())
+    found = {scope_of(name) for name in re.findall(
+        r'op_name="([^"]*)"', lowered.compile().as_text())}
+    phase, own = PROGRAMS[program]
+    want = FAMILY[model] | own
+    assert {part for ph, part in found if ph == phase} >= want, \
+        sorted(want - {part for ph, part in found if ph == phase})
+    # and under no other phase: one trunk, one phase
+    assert {ph for ph, _ in found} <= {phase, ""}, found
+    # a part the family lacks is not invented
+    absent = set(scopes.PARTS) - want
+    assert not {part for _, part in found} & absent
+
+
+def test_the_table_is_one_place():
+    """Every name once, phases and parts apart, and no scope is opened by a
+    literal string anywhere in the program."""
+    import os
+    import subprocess
+    assert len(set(scopes.PHASES + scopes.PARTS)) \
+        == len(scopes.PHASES) + len(scopes.PARTS)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        ["grep", "-rnE", r"named_scope\([\"']", os.path.join(root, "tpuserve"),
+         "--include=*.py"], capture_output=True, text=True).stdout
+    assert out == "", out

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from tpuserve.models.config import ModelConfig
 from tpuserve.ops import attention as attn_ops
 from tpuserve.ops import rope as rope_ops
+from tpuserve.ops import scopes
 from tpuserve.ops import ssm as ssm_ops
 
 Params = Any  # nested dict/list pytree of jnp arrays
@@ -107,29 +108,37 @@ def _act(x: jnp.ndarray, name: str) -> jnp.ndarray:
     raise ValueError(f"unknown activation {name}")
 
 
-def _attn_residual(out: jnp.ndarray, lp: dict, cfg: ModelConfig,
-                   ad: jnp.ndarray | None = None) -> jnp.ndarray:
-    """Attention output projection; Gemma2 sandwich norms apply a
-    post-attention layernorm to the projected output before the residual
-    add."""
-    att = _scaled(_linear(out, lp["o_proj"], ad),
-                  cfg.attention_out_multiplier)
-    if cfg.sandwich_norms:
-        att = _norm(att, lp["post_attn_norm"], cfg)
-    return att
+def _attn_residual(h: jnp.ndarray, out: jnp.ndarray, lp: dict,
+                   cfg: ModelConfig, ad: jnp.ndarray | None = None,
+                   m: jnp.ndarray | None = None) -> jnp.ndarray:
+    """The residual stream ``h`` plus the attention heads' output ``out``
+    (..., heads, width) through the output projection; Gemma2 sandwich
+    norms apply a post-attention layernorm to the projected output before
+    the add.  ``m``: a state-space branch's term of the same layer, added
+    to the attention's before both join the stream."""
+    with jax.named_scope(scopes.ATTN_OUT):
+        att = _scaled(_linear(out.reshape(*out.shape[:-2], -1),
+                              lp["o_proj"], ad),
+                      cfg.attention_out_multiplier)
+        if cfg.sandwich_norms:
+            att = _norm(att, lp["post_attn_norm"], cfg)
+        return h + (att if m is None else att + m)
 
 
 def _mlp_residual(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
                   ad: jnp.ndarray | None = None, tally: list | None = None,
                   moe_dense: bool = False) -> jnp.ndarray:
-    """Pre-norm MLP branch; under sandwich norms the pre-norm weights are
-    the checkpoint's pre_feedforward_layernorm (mapped onto ``mlp_norm``)
-    and a post-feedforward layernorm wraps the output before the add.
+    """The residual stream plus its pre-norm MLP branch; under sandwich
+    norms the pre-norm weights are the checkpoint's
+    pre_feedforward_layernorm (mapped onto ``mlp_norm``) and a
+    post-feedforward layernorm wraps the output before the add.
     ``tally`` and ``moe_dense`` are an expert layer's (:func:`_moe_mlp`)."""
-    m = _mlp(_norm(h, lp["mlp_norm"], cfg), lp, cfg, ad, tally, moe_dense)
-    if cfg.sandwich_norms:
-        m = _norm(m, lp["post_mlp_norm"], cfg)
-    return m
+    with jax.named_scope(scopes.MLP):
+        m = _mlp(_norm(h, lp["mlp_norm"], cfg), lp, cfg, ad, tally,
+                 moe_dense)
+        if cfg.sandwich_norms:
+            m = _norm(m, lp["post_mlp_norm"], cfg)
+        return h + m
 
 
 def _mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
@@ -140,12 +149,14 @@ def _mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
     # layers carry plain gated-MLP params (weights.init_params)
     if "experts" in p:
         return _moe_mlp(x, p, cfg, tally, moe_dense)
-    if cfg.mlp_style == "gated":
-        gate_m, down_m = cfg.mlp_multipliers
-        gate = _act(_scaled(_linear(x, p["gate_proj"], ad), gate_m), cfg.act)
-        return _scaled(_linear(gate * _linear(x, p["up_proj"], ad),
-                               p["down_proj"], ad), down_m)
-    return _linear(_act(_linear(x, p["fc1"], ad), cfg.act), p["fc2"], ad)
+    with jax.named_scope(scopes.MLP):
+        if cfg.mlp_style == "gated":
+            gate_m, down_m = cfg.mlp_multipliers
+            gate = _act(_scaled(_linear(x, p["gate_proj"], ad), gate_m),
+                        cfg.act)
+            return _scaled(_linear(gate * _linear(x, p["up_proj"], ad),
+                                   p["down_proj"], ad), down_m)
+        return _linear(_act(_linear(x, p["fc1"], ad), cfg.act), p["fc2"], ad)
 
 
 def _moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
@@ -179,66 +190,69 @@ def _moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
     ``(T, k)`` picks.
     """
     shape = x.shape
-    xt = x.reshape(-1, shape[-1])                              # (T, H)
-    T = xt.shape[0]
-    if "scale" in p["router"]:
-        router = _linear(xt, p["router"]).astype(jnp.float32)  # (T, E)
-    else:
-        # scores in float32 from the product's own accumulator: a bf16
-        # output would round near-tied scores before the top-k reads
-        # them, and a flipped pick is another expert's output
-        router = jnp.matmul(xt, p["router"]["kernel"],
-                            preferred_element_type=jnp.float32)
-    # DeepSeek-V3 scores experts with a sigmoid; selection adds the
-    # auxiliary-loss-free correction bias and (optionally) restricts the
-    # top-k to the best topk_group of n_group expert groups — but the
-    # COMBINE weights always come from the unbiased scores (HF
-    # DeepseekV3TopkRouter.get_topk_indices/forward).
-    if cfg.moe_scoring == "sigmoid":
-        scores = jax.nn.sigmoid(router)
-    else:
-        scores = jax.nn.softmax(router, axis=-1)
-    choice = scores
-    if "router_bias" in p:
-        choice = choice + p["router_bias"]["bias"][None, :]
-    E = scores.shape[-1]
-    if cfg.moe_n_group > 1:
-        G = cfg.moe_n_group
-        grouped = choice.reshape(T, G, E // G)
-        # group score: V3 (sigmoid) sums the group's top-2 member scores;
-        # V2's group_limited_greedy (softmax) takes the single max (HF
-        # modeling_deepseek_v2 vs _v3 — using the wrong one silently
-        # routes full V2/V2.5 checkpoints to different expert groups)
-        if cfg.moe_scoring == "sigmoid":
-            group_scores = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
-        else:
-            group_scores = jnp.max(grouped, axis=-1)
-        _, gidx = jax.lax.top_k(group_scores, cfg.moe_topk_group)
-        gmask = jnp.zeros_like(group_scores).at[
-            jnp.arange(T)[:, None], gidx].set(1.0)             # (T, G)
-        # HF masks non-selected groups to 0.0, not -inf
-        choice = jnp.where(gmask[..., None] > 0, grouped,
-                           0.0).reshape(T, E)
     k = cfg.num_experts_per_tok
-    _, topi = jax.lax.top_k(choice, k)                         # (T, k)
-    topv = jnp.take_along_axis(scores, topi, axis=-1)          # unbiased
-    if cfg.norm_topk_prob:
-        # HF adds 1e-20 on the sigmoid path (sums are not 1 there)
-        eps = 1e-20 if cfg.moe_scoring == "sigmoid" else 0.0
-        topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + eps)
-    if cfg.moe_routed_scaling != 1.0:
-        topv = topv * cfg.moe_routed_scaling
+    with jax.named_scope(scopes.MOE_ROUTE):
+        xt = x.reshape(-1, shape[-1])                          # (T, H)
+        T = xt.shape[0]
+        if "scale" in p["router"]:
+            router = _linear(xt, p["router"]).astype(jnp.float32)  # (T, E)
+        else:
+            # scores in float32 from the product's own accumulator: a bf16
+            # output would round near-tied scores before the top-k reads
+            # them, and a flipped pick is another expert's output
+            router = jnp.matmul(xt, p["router"]["kernel"],
+                                preferred_element_type=jnp.float32)
+        # DeepSeek-V3 scores experts with a sigmoid; selection adds the
+        # auxiliary-loss-free correction bias and (optionally) restricts
+        # the top-k to the best topk_group of n_group expert groups — but
+        # the COMBINE weights always come from the unbiased scores (HF
+        # DeepseekV3TopkRouter.get_topk_indices/forward).
+        if cfg.moe_scoring == "sigmoid":
+            scores = jax.nn.sigmoid(router)
+        else:
+            scores = jax.nn.softmax(router, axis=-1)
+        choice = scores
+        if "router_bias" in p:
+            choice = choice + p["router_bias"]["bias"][None, :]
+        E = scores.shape[-1]
+        if cfg.moe_n_group > 1:
+            G = cfg.moe_n_group
+            grouped = choice.reshape(T, G, E // G)
+            # group score: V3 (sigmoid) sums the group's top-2 member
+            # scores; V2's group_limited_greedy (softmax) takes the single
+            # max (HF modeling_deepseek_v2 vs _v3 — using the wrong one
+            # silently routes full V2/V2.5 checkpoints to different expert
+            # groups)
+            if cfg.moe_scoring == "sigmoid":
+                group_scores = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+            else:
+                group_scores = jnp.max(grouped, axis=-1)
+            _, gidx = jax.lax.top_k(group_scores, cfg.moe_topk_group)
+            gmask = jnp.zeros_like(group_scores).at[
+                jnp.arange(T)[:, None], gidx].set(1.0)         # (T, G)
+            # HF masks non-selected groups to 0.0, not -inf
+            choice = jnp.where(gmask[..., None] > 0, grouped,
+                               0.0).reshape(T, E)
+        _, topi = jax.lax.top_k(choice, k)                     # (T, k)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)      # unbiased
+        if cfg.norm_topk_prob:
+            # HF adds 1e-20 on the sigmoid path (sums are not 1 there)
+            eps = 1e-20 if cfg.moe_scoring == "sigmoid" else 0.0
+            topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + eps)
+        if cfg.moe_routed_scaling != 1.0:
+            topv = topv * cfg.moe_routed_scaling
+        picks = topi.reshape(-1)                               # (T k,)
+        sizes = jnp.sum(picks[:, None] == jnp.arange(E)[None, :], axis=0,
+                        dtype=jnp.int32)                       # (E,)
+        if tally is not None:
+            tally.append((sizes, topi))
+        if not dense:
+            order = jnp.argsort(picks, stable=True)     # rows by expert
     ek = p["experts"]
-    picks = topi.reshape(-1)                                   # (T k,)
-    sizes = jnp.sum(picks[:, None] == jnp.arange(E)[None, :], axis=0,
-                    dtype=jnp.int32)                           # (E,)
-    if tally is not None:
-        tally.append((sizes, topi))
     if dense:
         y = _moe_dense_experts(xt, ek, topi, topv, cfg)
     else:
         from tpuserve.ops.pallas_moe_gmm import grouped_matmul
-        order = jnp.argsort(picks, stable=True)     # rows by expert
         rows = _gather_rows(xt, order // k)                    # (T k, H)
 
         def expert_proj(inp: jnp.ndarray, ep: dict) -> jnp.ndarray:
@@ -251,21 +265,25 @@ def _moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
                 y = y * ep["scale"][picks[order]].astype(y.dtype)
             return y
 
-        h = _act(expert_proj(rows, ek["gate_proj"]), cfg.act) \
-            * expert_proj(rows, ek["up_proj"])
-        o = expert_proj(h, ek["down_proj"])
-        # each row back beside its token's other picks
-        back = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=order.dtype))
-        o = _gather_rows(o, back).reshape(T, k, -1)
-        # the router's weights stay float32 into the sum over a token's picks
-        y = jnp.einsum("tkh,tk->th", o, topv).astype(x.dtype)
-    if "shared" in p:
-        # DeepSeek shared experts: an always-on gated MLP beside the
-        # routed ones (HF DeepseekV3MoE.shared_experts) — p["shared"] has
-        # no "experts" key, so _mlp runs its plain gated branch
-        y = y + _mlp(xt, p["shared"], cfg)
-    return y.reshape(shape)
+        with jax.named_scope(scopes.MOE_EXPERTS):
+            h = _act(expert_proj(rows, ek["gate_proj"]), cfg.act) \
+                * expert_proj(rows, ek["up_proj"])
+            o = expert_proj(h, ek["down_proj"])
+        with jax.named_scope(scopes.MOE_COMBINE):
+            # each row back beside its token's other picks
+            back = jnp.zeros_like(order).at[order].set(
+                jnp.arange(order.shape[0], dtype=order.dtype))
+            o = _gather_rows(o, back).reshape(T, k, -1)
+            # the router's weights stay float32 into the sum over a
+            # token's picks
+            y = jnp.einsum("tkh,tk->th", o, topv).astype(x.dtype)
+    with jax.named_scope(scopes.MOE_COMBINE):
+        if "shared" in p:
+            # DeepSeek shared experts: an always-on gated MLP beside the
+            # routed ones (HF DeepseekV3MoE.shared_experts) — p["shared"]
+            # has no "experts" key, so _mlp runs its plain gated branch
+            y = y + _mlp(xt, p["shared"], cfg)
+        return y.reshape(shape)
 
 
 def _gather_rows(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
@@ -277,10 +295,11 @@ def _gather_rows(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     packed-prefill ladder compiles), this form compiles at every rung
     (tests/test_chip_compile.py holds both facts)."""
     width = x.shape[-1]
-    if width % 128:
-        return x[idx]
-    return x.reshape(x.shape[0], width // 128, 128)[idx].reshape(
-        idx.shape[0], width)
+    with jax.named_scope(scopes.MOE_GATHER):
+        if width % 128:
+            return x[idx]
+        return x.reshape(x.shape[0], width // 128, 128)[idx].reshape(
+            idx.shape[0], width)
 
 
 def _moe_dense_experts(xt, ek, topi, topv, cfg: ModelConfig):
@@ -288,8 +307,6 @@ def _moe_dense_experts(xt, ek, topi, topv, cfg: ModelConfig):
     static shapes and no gather, so expert parallelism is pure GSPMD."""
     T = xt.shape[0]
     E = ek["gate_proj"]["kernel"].shape[0]
-    combine = jnp.zeros((T, E), topv.dtype).at[
-        jnp.arange(T)[:, None], topi].set(topv)                # (T, E)
 
     def expert_proj(spec: str, inp: jnp.ndarray, ep: dict) -> jnp.ndarray:
         y = jnp.einsum(spec, inp, ep["kernel"].astype(inp.dtype))
@@ -297,10 +314,15 @@ def _moe_dense_experts(xt, ek, topi, topv, cfg: ModelConfig):
             y = y * ep["scale"][None].astype(y.dtype)
         return y
 
-    g = expert_proj("th,ehi->tei", xt, ek["gate_proj"])
-    u = expert_proj("th,ehi->tei", xt, ek["up_proj"])
-    o = expert_proj("tei,eih->teh", _act(g, cfg.act) * u, ek["down_proj"])
-    return jnp.einsum("teh,te->th", o, combine.astype(o.dtype))
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        g = expert_proj("th,ehi->tei", xt, ek["gate_proj"])
+        u = expert_proj("th,ehi->tei", xt, ek["up_proj"])
+        o = expert_proj("tei,eih->teh", _act(g, cfg.act) * u,
+                        ek["down_proj"])
+    with jax.named_scope(scopes.MOE_COMBINE):
+        combine = jnp.zeros((T, E), topv.dtype).at[
+            jnp.arange(T)[:, None], topi].set(topv)            # (T, E)
+        return jnp.einsum("teh,te->th", o, combine.astype(o.dtype))
 
 
 # --------------------------------------------------------------------------
@@ -309,34 +331,42 @@ def _moe_dense_experts(xt, ek, topi, topv, cfg: ModelConfig):
 
 def _qkv(h: jnp.ndarray, lp: dict, cfg: ModelConfig, positions: jnp.ndarray,
          layer_idx: int, ad: jnp.ndarray | None = None):
-    """h: (..., H) -> q (..., Hq, D), k/v (..., Hkv, D), with qk-norm and
-    RoPE.  ``layer_idx`` selects per-layer rope (Gemma3: windowed layers
+    """The residual stream h: (..., H) through the layer's input norm ->
+    q (..., Hq, D), k/v (..., Hkv, D), with qk-norm and RoPE, and the
+    normed input itself (a state-space branch reads the same one).
+    ``layer_idx`` selects per-layer rope (Gemma3: windowed layers
     rotate at the local base frequency unscaled; full layers at
     rope_theta with the linear position scaling.  Mellum 2: full layers
     rotate by a YaRN table whose cos and sin carry the attention factor,
     windowed layers by the plain one)."""
-    h = _scaled(h, cfg.attention_in_multiplier)
-    q = _linear(h, lp["q_proj"], ad).reshape(*h.shape[:-1], cfg.num_heads, cfg.head_dim)
-    k = _linear(h, lp["k_proj"], ad).reshape(*h.shape[:-1], cfg.num_kv_heads, cfg.head_dim)
-    v = _linear(h, lp["v_proj"], ad).reshape(*h.shape[:-1], cfg.num_kv_heads, cfg.head_dim)
-    k = _scaled(k, cfg.key_multiplier)
-    if cfg.qk_norm:
-        q = rmsnorm(q, lp["q_norm"]["scale"], cfg.norm_eps,
-                    cfg.norm_weight_offset)
-        k = rmsnorm(k, lp["k_norm"]["scale"], cfg.norm_eps,
-                    cfg.norm_weight_offset)
-    if cfg.pos == "rope":
-        rotary_dim = int(cfg.head_dim * cfg.partial_rotary_factor)
-        theta, scaling = cfg.layer_rope(layer_idx)
-        pos = positions
-        if scaling != 1.0:
-            pos = positions.astype(jnp.float32) / scaling
-        cos, sin = rope_ops.rope_freqs(pos, cfg.head_dim, theta, rotary_dim,
-                                       llama3_scaling=cfg.rope_llama3_scaling,
-                                       yarn_scaling=cfg.layer_yarn(layer_idx))
-        q = rope_ops.apply_rope(q, cos, sin)
-        k = rope_ops.apply_rope(k, cos, sin)
-    return q, k, v
+    with jax.named_scope(scopes.ATTN_QKV):
+        hn = _norm(h, lp["attn_norm"], cfg)
+        h = _scaled(hn, cfg.attention_in_multiplier)
+        q = _linear(h, lp["q_proj"], ad).reshape(
+            *h.shape[:-1], cfg.num_heads, cfg.head_dim)
+        k = _linear(h, lp["k_proj"], ad).reshape(
+            *h.shape[:-1], cfg.num_kv_heads, cfg.head_dim)
+        v = _linear(h, lp["v_proj"], ad).reshape(
+            *h.shape[:-1], cfg.num_kv_heads, cfg.head_dim)
+        k = _scaled(k, cfg.key_multiplier)
+        if cfg.qk_norm:
+            q = rmsnorm(q, lp["q_norm"]["scale"], cfg.norm_eps,
+                        cfg.norm_weight_offset)
+            k = rmsnorm(k, lp["k_norm"]["scale"], cfg.norm_eps,
+                        cfg.norm_weight_offset)
+        if cfg.pos == "rope":
+            rotary_dim = int(cfg.head_dim * cfg.partial_rotary_factor)
+            theta, scaling = cfg.layer_rope(layer_idx)
+            pos = positions
+            if scaling != 1.0:
+                pos = positions.astype(jnp.float32) / scaling
+            cos, sin = rope_ops.rope_freqs(
+                pos, cfg.head_dim, theta, rotary_dim,
+                llama3_scaling=cfg.rope_llama3_scaling,
+                yarn_scaling=cfg.layer_yarn(layer_idx))
+            q = rope_ops.apply_rope(q, cos, sin)
+            k = rope_ops.apply_rope(k, cos, sin)
+        return q, k, v, hn
 
 
 # --------------------------------------------------------------------------
@@ -356,31 +386,35 @@ def _qkv(h: jnp.ndarray, lp: dict, cfg: ModelConfig, positions: jnp.ndarray,
 # output).  References: DeepSeek-V2 paper §2.1; HF modeling_deepseek_v3
 # (the naive form this must match numerically).
 
-def _mla_proj(hn: jnp.ndarray, lp: dict, cfg: ModelConfig,
+def _mla_proj(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
               positions: jnp.ndarray, ad: jnp.ndarray | None = None):
-    """q_nope (..., H, nope), roped q_rope (..., H, rope), and the
-    cache-ready latent (..., latent_dim) = rmsnorm(c_kv) ⊕ roped key."""
-    if "q_a_proj" in lp:
-        cq = rmsnorm(_linear(hn, lp["q_a_proj"], ad),
-                     lp["q_a_norm"]["scale"], cfg.norm_eps,
-                     cfg.norm_weight_offset)
-        q = _linear(cq, lp["q_b_proj"], ad)
-    else:
-        q = _linear(hn, lp["q_proj"], ad)
-    q = q.reshape(*hn.shape[:-1], cfg.num_heads, cfg.head_dim)
-    nope = cfg.mla_qk_nope_head_dim
-    q_nope, q_rope = q[..., :nope], q[..., nope:]
-    ckv = _linear(hn, lp["kv_a_proj"], ad)
-    c = rmsnorm(ckv[..., :cfg.mla_kv_lora_rank],
-                lp["kv_a_norm"]["scale"], cfg.norm_eps,
-                cfg.norm_weight_offset)
-    k_rope = ckv[..., cfg.mla_kv_lora_rank:]
-    cos, sin = rope_ops.rope_freqs(positions, cfg.mla_qk_rope_head_dim,
-                                   cfg.rope_theta,
-                                   yarn_scaling=cfg.rope_yarn)
-    q_rope = rope_ops.apply_rope(q_rope, cos, sin)
-    k_rope = rope_ops.apply_rope(k_rope[..., None, :], cos, sin)[..., 0, :]
-    return q_nope, q_rope, jnp.concatenate([c, k_rope], axis=-1)
+    """The residual stream through the layer's input norm -> q_nope
+    (..., H, nope), roped q_rope (..., H, rope), and the cache-ready
+    latent (..., latent_dim) = rmsnorm(c_kv) ⊕ roped key."""
+    with jax.named_scope(scopes.ATTN_QKV):
+        hn = _norm(h, lp["attn_norm"], cfg)
+        if "q_a_proj" in lp:
+            cq = rmsnorm(_linear(hn, lp["q_a_proj"], ad),
+                         lp["q_a_norm"]["scale"], cfg.norm_eps,
+                         cfg.norm_weight_offset)
+            q = _linear(cq, lp["q_b_proj"], ad)
+        else:
+            q = _linear(hn, lp["q_proj"], ad)
+        q = q.reshape(*hn.shape[:-1], cfg.num_heads, cfg.head_dim)
+        nope = cfg.mla_qk_nope_head_dim
+        q_nope, q_rope = q[..., :nope], q[..., nope:]
+        ckv = _linear(hn, lp["kv_a_proj"], ad)
+        c = rmsnorm(ckv[..., :cfg.mla_kv_lora_rank],
+                    lp["kv_a_norm"]["scale"], cfg.norm_eps,
+                    cfg.norm_weight_offset)
+        k_rope = ckv[..., cfg.mla_kv_lora_rank:]
+        cos, sin = rope_ops.rope_freqs(positions, cfg.mla_qk_rope_head_dim,
+                                       cfg.rope_theta,
+                                       yarn_scaling=cfg.rope_yarn)
+        q_rope = rope_ops.apply_rope(q_rope, cos, sin)
+        k_rope = rope_ops.apply_rope(k_rope[..., None, :], cos,
+                                     sin)[..., 0, :]
+        return q_nope, q_rope, jnp.concatenate([c, k_rope], axis=-1)
 
 
 def _mla_kv_b(lp: dict, cfg: ModelConfig, dtype) -> tuple:
@@ -399,24 +433,27 @@ def _mla_kv_b(lp: dict, cfg: ModelConfig, dtype) -> tuple:
 def _mla_decompress(latent, lp, cfg: ModelConfig, dtype):
     """Materialise per-head K (..., H, head_dim) and V (..., H, v_dim)
     from latents — the naive form for compute-bound full-sequence paths."""
-    w_uk, w_uv = _mla_kv_b(lp, cfg, dtype)
-    c = latent[..., :cfg.mla_kv_lora_rank]
-    k_nope = jnp.einsum("...tc,chn->...thn", c, w_uk)
-    v = jnp.einsum("...tc,chv->...thv", c, w_uv)
-    k_rope = jnp.broadcast_to(
-        latent[..., None, cfg.mla_kv_lora_rank:],
-        (*k_nope.shape[:-1], cfg.mla_qk_rope_head_dim))
-    return jnp.concatenate([k_nope, k_rope], axis=-1), v
+    with jax.named_scope(scopes.ATTN_QKV):
+        w_uk, w_uv = _mla_kv_b(lp, cfg, dtype)
+        c = latent[..., :cfg.mla_kv_lora_rank]
+        k_nope = jnp.einsum("...tc,chn->...thn", c, w_uk)
+        v = jnp.einsum("...tc,chv->...thv", c, w_uv)
+        k_rope = jnp.broadcast_to(
+            latent[..., None, cfg.mla_kv_lora_rank:],
+            (*k_nope.shape[:-1], cfg.mla_qk_rope_head_dim))
+        return jnp.concatenate([k_nope, k_rope], axis=-1), v
 
 
-def _mla_naive_qkv(hn, lp, cfg: ModelConfig, positions,
+def _mla_naive_qkv(h, lp, cfg: ModelConfig, positions,
                    ad: jnp.ndarray | None = None):
     """Drop-in _qkv analog for cache-free MLA paths: full q and
     decompressed per-head k/v (v is mla_v_head_dim wide — the shared
-    attention ops contract the value dim independently)."""
-    q_nope, q_rope, latent = _mla_proj(hn, lp, cfg, positions, ad)
+    attention ops contract the value dim independently); None where _qkv
+    hands back the normed input (no such model has a second branch)."""
+    q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
     k, v = _mla_decompress(latent, lp, cfg, q_nope.dtype)
-    return jnp.concatenate([q_nope, q_rope], axis=-1), k, v
+    with jax.named_scope(scopes.ATTN_QKV):
+        return jnp.concatenate([q_nope, q_rope], axis=-1), k, v, None
 
 
 def _mla_prefill_out(q_nope, q_rope, latent, lp, cfg: ModelConfig,
@@ -425,25 +462,28 @@ def _mla_prefill_out(q_nope, q_rope, latent, lp, cfg: ModelConfig,
     compute-bound, so materialising per-head K/V for the prompt costs
     little and reuses the masked prefill attention op unchanged."""
     k, v = _mla_decompress(latent, lp, cfg, q_nope.dtype)
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    with jax.named_scope(scopes.ATTN_QKV):
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
     return attn_ops.prefill_attention(q, k, v, prompt_lens, scale)
 
 
 def _mla_absorb_q(q_nope, q_rope, lp, cfg: ModelConfig) -> jnp.ndarray:
     """Fold W_UK into the query: scores against raw latents become exact
     (q_lat . c == q_nope . k_nope); the roped dims ride alongside."""
-    w_uk, _ = _mla_kv_b(lp, cfg, q_nope.dtype)
-    q_lat = jnp.einsum("...hn,chn->...hc", q_nope, w_uk)
-    return jnp.concatenate([q_lat, q_rope], axis=-1)
+    with jax.named_scope(scopes.ATTN_QKV):
+        w_uk, _ = _mla_kv_b(lp, cfg, q_nope.dtype)
+        q_lat = jnp.einsum("...hn,chn->...hc", q_nope, w_uk)
+        return jnp.concatenate([q_lat, q_rope], axis=-1)
 
 
 def _mla_unabsorb(out_lat, lp, cfg: ModelConfig) -> jnp.ndarray:
     """Latent-space attention output -> per-head values via W_UV.  The
     paged op returned p @ [c ⊕ k_rope]; only the first kv_lora_rank
     columns are the value contraction, the rope tail is discarded."""
-    _, w_uv = _mla_kv_b(lp, cfg, out_lat.dtype)
-    return jnp.einsum("...hc,chv->...hv",
-                      out_lat[..., :cfg.mla_kv_lora_rank], w_uv)
+    with jax.named_scope(scopes.ATTN_OUT):
+        _, w_uv = _mla_kv_b(lp, cfg, out_lat.dtype)
+        return jnp.einsum("...hc,chv->...hv",
+                          out_lat[..., :cfg.mla_kv_lora_rank], w_uv)
 
 
 # --------------------------------------------------------------------------
@@ -467,14 +507,15 @@ def _ssm_project(hn: jnp.ndarray, sp: dict, cfg: ModelConfig):
     """hn (..., hidden) -> gate z (..., d_ssm), convolution input xBC
     (..., conv_dim), raw step dt (..., heads): the input projection under
     its input multiplier and the five per-slice multipliers."""
-    p = _linear(_scaled(hn, cfg.ssm_in_multiplier), sp["in_proj"])
-    if any(m != 1.0 for m in cfg.ssm_multipliers):
-        p = p * jnp.concatenate([
-            jnp.full((w,), m, p.dtype) for w, m
-            in zip(cfg.mamba_proj_widths, cfg.ssm_multipliers)])
-    d = cfg.mamba_d_ssm
-    return p[..., :d], p[..., d:d + cfg.mamba_conv_dim], \
-        p[..., d + cfg.mamba_conv_dim:]
+    with jax.named_scope(scopes.SSM_IN_PROJ):
+        p = _linear(_scaled(hn, cfg.ssm_in_multiplier), sp["in_proj"])
+        if any(m != 1.0 for m in cfg.ssm_multipliers):
+            p = p * jnp.concatenate([
+                jnp.full((w,), m, p.dtype) for w, m
+                in zip(cfg.mamba_proj_widths, cfg.ssm_multipliers)])
+        d = cfg.mamba_d_ssm
+        return p[..., :d], p[..., d:d + cfg.mamba_conv_dim], \
+            p[..., d + cfg.mamba_conv_dim:]
 
 
 def _ssm_inputs(conv_out: jnp.ndarray, dt_raw: jnp.ndarray, sp: dict,
@@ -484,36 +525,38 @@ def _ssm_inputs(conv_out: jnp.ndarray, dt_raw: jnp.ndarray, sp: dict,
     zeros: a padding row's input may be anything (the paged kernels leave
     such rows unspecified), and the scan SUMS over a chunk's rows, where
     a NaN times a zero step is still a NaN."""
-    xbc = jnp.where(valid[..., None], jax.nn.silu(conv_out), 0.0)
-    # activations in the model's dtype, as everywhere else in the trunk;
-    # the scan and the state update widen what they accumulate
-    xbc = xbc.astype(dt_raw.dtype)
-    d, gn = cfg.mamba_d_ssm, cfg.mamba_n_groups * cfg.mamba_d_state
-    lead = xbc.shape[:-1]
-    x = xbc[..., :d].reshape(*lead, cfg.mamba_n_heads, cfg.mamba_d_head)
-    bm = xbc[..., d:d + gn].reshape(*lead, cfg.mamba_n_groups,
-                                    cfg.mamba_d_state)
-    cm = xbc[..., d + gn:].reshape(*lead, cfg.mamba_n_groups,
-                                   cfg.mamba_d_state)
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + sp["dt_bias"])
-    dt = jnp.where(valid[..., None], dt, 0.0)
-    return x, bm, cm, dt, -jnp.exp(sp["A_log"].astype(jnp.float32))
+    with jax.named_scope(scopes.SSM_CONV):
+        xbc = jnp.where(valid[..., None], jax.nn.silu(conv_out), 0.0)
+        # activations in the model's dtype, as everywhere else in the
+        # trunk; the scan and the state update widen what they accumulate
+        xbc = xbc.astype(dt_raw.dtype)
+        d, gn = cfg.mamba_d_ssm, cfg.mamba_n_groups * cfg.mamba_d_state
+        lead = xbc.shape[:-1]
+        x = xbc[..., :d].reshape(*lead, cfg.mamba_n_heads, cfg.mamba_d_head)
+        bm = xbc[..., d:d + gn].reshape(*lead, cfg.mamba_n_groups,
+                                        cfg.mamba_d_state)
+        cm = xbc[..., d + gn:].reshape(*lead, cfg.mamba_n_groups,
+                                       cfg.mamba_d_state)
+        dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + sp["dt_bias"])
+        dt = jnp.where(valid[..., None], dt, 0.0)
+        return x, bm, cm, dt, -jnp.exp(sp["A_log"].astype(jnp.float32))
 
 
 def _ssm_output(y: jnp.ndarray, x: jnp.ndarray, z: jnp.ndarray, sp: dict,
                 cfg: ModelConfig) -> jnp.ndarray:
     """Scan output y and its input x (..., H, P), gate z (..., d_ssm) ->
     the branch's contribution to the residual (..., hidden)."""
-    y = y + sp["D"].astype(jnp.float32)[:, None] * x
-    y = y.reshape(*y.shape[:-2], cfg.mamba_d_ssm)
-    if cfg.mamba_rms_norm:
-        y = ssm_ops.gated_group_norm(y, z, sp["norm"]["scale"], cfg.norm_eps,
-                                     cfg.mamba_n_groups,
-                                     cfg.mamba_norm_before_gate)
-    else:
-        y = y * jax.nn.silu(z.astype(jnp.float32))
-    return _scaled(_linear(y.astype(z.dtype), sp["out_proj"]),
-                   cfg.ssm_out_multiplier)
+    with jax.named_scope(scopes.SSM_OUT):
+        y = y + sp["D"].astype(jnp.float32)[:, None] * x
+        y = y.reshape(*y.shape[:-2], cfg.mamba_d_ssm)
+        if cfg.mamba_rms_norm:
+            y = ssm_ops.gated_group_norm(
+                y, z, sp["norm"]["scale"], cfg.norm_eps, cfg.mamba_n_groups,
+                cfg.mamba_norm_before_gate)
+        else:
+            y = y * jax.nn.silu(z.astype(jnp.float32))
+        return _scaled(_linear(y.astype(z.dtype), sp["out_proj"]),
+                       cfg.ssm_out_multiplier)
 
 
 def _ssm_window(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
@@ -535,8 +578,11 @@ def _ssm_window(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
     tail = jnp.zeros((B, W - 1, cfg.mamba_conv_dim), xbc.dtype)
     if fresh is not None:
         keep = ~fresh
-        s0 = jnp.where(keep[:, None, None, None], entry["state"][seats], s0)
-        tail = jnp.where(keep[:, None, None], entry["conv"][seats], tail)
+        with jax.named_scope(scopes.SSM_SCAN):
+            s0 = jnp.where(keep[:, None, None, None], entry["state"][seats],
+                           s0)
+        with jax.named_scope(scopes.SSM_CONV):
+            tail = jnp.where(keep[:, None, None], entry["conv"][seats], tail)
     conv_out, rows = ssm_ops.causal_conv(xbc, tail, sp["conv"]["kernel"],
                                          sp["conv"].get("bias"))
     valid = jnp.arange(L)[None, :] < lens[:, None]
@@ -551,10 +597,12 @@ def _ssm_window(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
     m = _ssm_output(y.reshape(B, L, H, P), x, z, sp, cfg)
     if entry is None:
         return m, None
-    return m, {"state": entry["state"].at[seats].set(finals),
-               "conv": entry["conv"].at[seats].set(
-                   ssm_ops.next_tail(rows, lens, W).astype(
-                       entry["conv"].dtype))}
+    with jax.named_scope(scopes.SSM_SCAN):
+        state = entry["state"].at[seats].set(finals)
+    with jax.named_scope(scopes.SSM_CONV):
+        conv = entry["conv"].at[seats].set(
+            ssm_ops.next_tail(rows, lens, W).astype(entry["conv"].dtype))
+    return m, {"state": state, "conv": conv}
 
 
 def _ssm_nocache(hn: jnp.ndarray, lp: dict, cfg: ModelConfig,
@@ -596,14 +644,16 @@ def _ssm_packed(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
         jnp.zeros((n_seq, H, P, cfg.mamba_d_state), jnp.float32),
         jnp.repeat(blk_seq, blk // Q), chunk=Q)
     m = _ssm_output(y, x, z, sp, cfg)
-    # each prompt's last W - 1 inputs (zeros before its first row)
-    back = jnp.arange(W - 1)[None, :] - (W - 1)                 # -3 .. -1
-    idx = (q_starts + q_lens)[:, None] + back
-    tails = jnp.where((q_lens[:, None] + back >= 0)[..., None],
-                      xbc[jnp.clip(idx, 0, T - 1)], 0)
-    return m, {"state": entry["state"].at[seats].set(finals),
-               "conv": entry["conv"].at[seats].set(
-                   tails.astype(entry["conv"].dtype))}
+    with jax.named_scope(scopes.SSM_SCAN):
+        state = entry["state"].at[seats].set(finals)
+    with jax.named_scope(scopes.SSM_CONV):
+        # each prompt's last W - 1 inputs (zeros before its first row)
+        back = jnp.arange(W - 1)[None, :] - (W - 1)             # -3 .. -1
+        idx = (q_starts + q_lens)[:, None] + back
+        tails = jnp.where((q_lens[:, None] + back >= 0)[..., None],
+                          xbc[jnp.clip(idx, 0, T - 1)], 0)
+        conv = entry["conv"].at[seats].set(tails.astype(entry["conv"].dtype))
+    return m, {"state": state, "conv": conv}
 
 
 def _ssm_decode(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
@@ -615,53 +665,68 @@ def _ssm_decode(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
     same formula in ``jax.numpy`` otherwise.  Padding rows (not ``valid``)
     carry the trash seat.  Returns (m (B, hidden), entry)."""
     z, xbc, dt_raw = _ssm_project(hn, sp, cfg)
+    with jax.named_scope(scopes.SSM_CONV):
+        tail = entry["conv"][seats]
     conv_out, rows = ssm_ops.causal_conv(
-        xbc[:, None], entry["conv"][seats], sp["conv"]["kernel"],
-        sp["conv"].get("bias"))
+        xbc[:, None], tail, sp["conv"]["kernel"], sp["conv"].get("bias"))
     x, bm, cm, dt, a = _ssm_inputs(conv_out[:, 0], dt_raw, sp, cfg, valid)
-    x = x.astype(jnp.float32)
     from tpuserve.ops import pallas_ssm_update as upd
     update = (upd.ssm_state_update if attn_impl == "pallas"
               else upd.ssm_state_update_reference)
-    y, state = update(entry["state"], seats, jnp.exp(dt * a),
-                      dt[..., None] * x, bm, cm)
+    with jax.named_scope(scopes.SSM_SCAN):
+        x = x.astype(jnp.float32)
+        y, state = update(entry["state"], seats, jnp.exp(dt * a),
+                          dt[..., None] * x, bm, cm)
     m = _ssm_output(y, x, z, sp, cfg)
-    return m, {"state": state,
-               "conv": entry["conv"].at[seats].set(
-                   rows[:, 1:].astype(entry["conv"].dtype))}
+    with jax.named_scope(scopes.SSM_CONV):
+        conv = entry["conv"].at[seats].set(
+            rows[:, 1:].astype(entry["conv"].dtype))
+    return m, {"state": state, "conv": conv}
 
 
 def _embed(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
            positions: jnp.ndarray) -> jnp.ndarray:
-    h = params["embed"]["weight"][tokens]
-    if "scale" in params["embed"]:        # int8 embed: per-vocab-row scale
-        dtype = jnp.dtype(cfg.dtype)
-        h = (h.astype(dtype)
-             * params["embed"]["scale"][tokens][..., None].astype(dtype))
-    if cfg.embed_scale_by_sqrt_dim:       # Gemma: normalizer in h's dtype,
-        h = h * jnp.asarray(cfg.hidden_size ** 0.5, h.dtype)  # like HF
-    h = _scaled(h, cfg.embedding_multiplier)            # Falcon-H1
-    if cfg.pos == "learned":
-        h = h + params["pos_embed"]["weight"][positions + cfg.learned_pos_offset]
-    return h
+    with jax.named_scope(scopes.EMBED):
+        h = params["embed"]["weight"][tokens]
+        if "scale" in params["embed"]:    # int8 embed: per-vocab-row scale
+            dtype = jnp.dtype(cfg.dtype)
+            h = (h.astype(dtype)
+                 * params["embed"]["scale"][tokens][..., None].astype(dtype))
+        if cfg.embed_scale_by_sqrt_dim:   # Gemma: normalizer in h's dtype,
+            h = h * jnp.asarray(cfg.hidden_size ** 0.5, h.dtype)  # like HF
+        h = _scaled(h, cfg.embedding_multiplier)            # Falcon-H1
+        if cfg.pos == "learned":
+            h = h + params["pos_embed"]["weight"][
+                positions + cfg.learned_pos_offset]
+        return h
 
 
-def _unembed(params: Params, cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
-    if cfg.final_layernorm:
-        h = _norm(h, params["final_norm"], cfg)
-    if cfg.tie_word_embeddings:
-        ew = params["embed"]
-        if "scale" in ew:                 # tied int8: scale per logit column
-            logits = (h @ ew["weight"].T.astype(h.dtype)) * ew["scale"][None, :]
+def _unembed(params: Params, cfg: ModelConfig, h: jnp.ndarray,
+             at: jnp.ndarray | None = None) -> jnp.ndarray:
+    """Final norm and head over ``h`` (..., H) -> float32 logits.  ``at``
+    (B,): only these rows: of each sequence's axis 1 where ``h`` is (B, T,
+    H), of the flat axis where it is (T, H)."""
+    with jax.named_scope(scopes.HEAD):
+        if at is not None and h.ndim == 3:
+            h = jnp.take_along_axis(h, at[:, None, None], axis=1)[:, 0]
+        elif at is not None:
+            h = h[at]
+        if cfg.final_layernorm:
+            h = _norm(h, params["final_norm"], cfg)
+        if cfg.tie_word_embeddings:
+            ew = params["embed"]
+            if "scale" in ew:             # tied int8: scale per logit column
+                logits = (h @ ew["weight"].T.astype(h.dtype)) \
+                    * ew["scale"][None, :]
+            else:
+                logits = h @ ew["weight"].T
         else:
-            logits = h @ ew["weight"].T
-    else:
-        logits = _linear(h, params["lm_head"])
-    logits = _scaled(logits.astype(jnp.float32), cfg.lm_head_multiplier)
-    if cfg.final_logit_softcapping:
-        cap = cfg.final_logit_softcapping
-        logits = cap * jnp.tanh(logits / cap)
-    return logits
+            logits = _linear(h, params["lm_head"])
+        logits = _scaled(logits.astype(jnp.float32), cfg.lm_head_multiplier)
+        if cfg.final_logit_softcapping:
+            cap = cfg.final_logit_softcapping
+            logits = cap * jnp.tanh(logits / cap)
+        return logits
 
 
 def _with_ssm(out, new_cache: list, ssm, new_ssm: list,
@@ -688,9 +753,10 @@ def _moe_counts(tally: list):
     sizes: ``(E + 1,)`` int32 — rows routed to each expert, summed over
     the layers, then the expert-layers that got at least one row (each
     touched expert's kernels are read once a layer)."""
-    sizes = jnp.stack([s for s, _ in tally])                   # (L, E)
-    return jnp.concatenate([jnp.sum(sizes, axis=0),
-                            jnp.sum(sizes > 0, dtype=jnp.int32)[None]])
+    with jax.named_scope(scopes.MOE_ROUTE):
+        sizes = jnp.stack([s for s, _ in tally])               # (L, E)
+        return jnp.concatenate([jnp.sum(sizes, axis=0),
+                                jnp.sum(sizes > 0, dtype=jnp.int32)[None]])
 
 
 def _moe_routing(tally: list | None, rows: jnp.ndarray | None):
@@ -705,10 +771,11 @@ def _moe_routing(tally: list | None, rows: jnp.ndarray | None):
     None where the trunk collected nothing."""
     if tally is None:
         return None
-    picks = jnp.stack([t for _, t in tally], axis=1)           # (T, L, k)
-    if rows is None:
-        return _moe_counts(tally), picks, None
-    return _moe_counts(tally), picks[rows], picks
+    with jax.named_scope(scopes.MOE_ROUTE):
+        picks = jnp.stack([t for _, t in tally], axis=1)       # (T, L, k)
+        if rows is None:
+            return _moe_counts(tally), picks, None
+        return _moe_counts(tally), picks[rows], picks
 
 
 # --------------------------------------------------------------------------
@@ -738,62 +805,59 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     returns the pool third: this holds for every trunk below that takes
     the KV cache.
     """
-    B, T = tokens.shape
-    positions = jnp.arange(T)[None, :].repeat(B, axis=0)
-    h = _embed(params, cfg, tokens, positions)
-    scale = cfg.attn_scale
-    new_cache = []
-    new_ssm = []
-    tally = _moe_tally(cfg)
-    for li, lp in enumerate(params["layers"]):
-        sw = cfg.layer_window(li)
-        hn = _norm(h, lp["attn_norm"], cfg)
-        if cfg.is_mla:
-            # MLA prefill: cache the latent, attend naively (decompressed)
-            # over the fresh prompt K/V — reference impl only; the Pallas
-            # kernels assume materialised per-head K/V pages
-            q_nope, q_rope, latent = _mla_proj(hn, lp, cfg, positions, ad)
-            new_cache.append(attn_ops.write_mla_entry(
-                kv_cache[li], latent, slot_ids,
-                latent_split=cfg.mla_kv_lora_rank))
-            out = _mla_prefill_out(q_nope, q_rope, latent, lp, cfg,
-                                   prompt_lens, scale)
-            out = out.reshape(B, T, cfg.num_heads * cfg.mla_v_head_dim)
-            h = h + _attn_residual(out, lp, cfg, ad)
-            h = h + _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
-            continue
-        q, k, v = _qkv(hn, lp, cfg, positions, li, ad)
-        # batched prefill attends over the FRESH k/v (full precision even
-        # when the cache stores int8 — only cache READS see quantization)
-        new_cache.append(attn_ops.write_kv_entry(kv_cache[li], k, v,
-                                                 slot_ids))
-        if attn_impl == "pallas" and mesh is not None:
-            from tpuserve.ops.pallas_tp import flash_prefill_attention_tp
-            out = flash_prefill_attention_tp(q, k, v, prompt_lens, scale,
-                                             mesh, sliding_window=sw,
-                                             logit_softcap=cfg.attn_logit_softcapping)
-        elif attn_impl == "pallas":
-            from tpuserve.ops.pallas_flash_attention import flash_prefill_attention
-            out = flash_prefill_attention(q, k, v, prompt_lens, scale,
-                                          sliding_window=sw,
-                                          logit_softcap=cfg.attn_logit_softcapping)
-        else:
-            out = attn_ops.prefill_attention(q, k, v, prompt_lens, scale,
-                                             sliding_window=sw,
-                                             logit_softcap=cfg.attn_logit_softcapping)
-        out = out.reshape(B, T, cfg.q_size)
-        att = _attn_residual(out, lp, cfg, ad)
-        if ssm is not None:
-            m, entry = _ssm_window(hn, lp["ssm"], cfg, prompt_lens, ssm[li],
-                                   seats)
-            new_ssm.append(entry)
-            att = att + m
-        h = h + att
-        h = h + _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
-    last_idx = jnp.maximum(prompt_lens - 1, 0)
-    h_last = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]  # (B, H)
-    return _with_ssm(_unembed(params, cfg, h_last), new_cache, ssm, new_ssm,
-                     _moe_routing(tally, jnp.arange(B) * T + last_idx))
+    with jax.named_scope(scopes.PREFILL):
+        B, T = tokens.shape
+        positions = jnp.arange(T)[None, :].repeat(B, axis=0)
+        h = _embed(params, cfg, tokens, positions)
+        scale = cfg.attn_scale
+        new_cache = []
+        new_ssm = []
+        tally = _moe_tally(cfg)
+        for li, lp in enumerate(params["layers"]):
+            sw = cfg.layer_window(li)
+            if cfg.is_mla:
+                # MLA prefill: cache the latent, attend naively (decompressed)
+                # over the fresh prompt K/V — reference impl only; the Pallas
+                # kernels assume materialised per-head K/V pages
+                q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
+                new_cache.append(attn_ops.write_mla_entry(
+                    kv_cache[li], latent, slot_ids,
+                    latent_split=cfg.mla_kv_lora_rank))
+                out = _mla_prefill_out(q_nope, q_rope, latent, lp, cfg,
+                                       prompt_lens, scale)
+                h = _attn_residual(h, out, lp, cfg, ad)
+                h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+                continue
+            q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)
+            # batched prefill attends over the FRESH k/v (full precision even
+            # when the cache stores int8 — only cache READS see quantization)
+            new_cache.append(attn_ops.write_kv_entry(kv_cache[li], k, v,
+                                                     slot_ids))
+            if attn_impl == "pallas" and mesh is not None:
+                from tpuserve.ops.pallas_tp import flash_prefill_attention_tp
+                out = flash_prefill_attention_tp(q, k, v, prompt_lens, scale,
+                                                 mesh, sliding_window=sw,
+                                                 logit_softcap=cfg.attn_logit_softcapping)
+            elif attn_impl == "pallas":
+                from tpuserve.ops.pallas_flash_attention import flash_prefill_attention
+                out = flash_prefill_attention(q, k, v, prompt_lens, scale,
+                                              sliding_window=sw,
+                                              logit_softcap=cfg.attn_logit_softcapping)
+            else:
+                out = attn_ops.prefill_attention(q, k, v, prompt_lens, scale,
+                                                 sliding_window=sw,
+                                                 logit_softcap=cfg.attn_logit_softcapping)
+            m = None
+            if ssm is not None:
+                m, entry = _ssm_window(hn, lp["ssm"], cfg, prompt_lens, ssm[li],
+                                       seats)
+                new_ssm.append(entry)
+            h = _attn_residual(h, out, lp, cfg, ad, m)
+            h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+        last_idx = jnp.maximum(prompt_lens - 1, 0)
+        return _with_ssm(_unembed(params, cfg, h, last_idx), new_cache, ssm,
+                         new_ssm,
+                         _moe_routing(tally, jnp.arange(B) * T + last_idx))
 
 
 # --------------------------------------------------------------------------
@@ -828,16 +892,17 @@ def prefill_chunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     online-softmax einsum in ops/attention.py.  ``mesh``: static; when set
     with pallas, the kernel runs head-parallel over tp via shard_map.
     """
-    tally = _moe_tally(cfg)
-    h, new_cache, new_ssm = _chunk_trunk(
-        params, cfg, tokens, ctx_lens, chunk_lens, slot_ids, block_tables,
-        kv_cache, ad, ssm, seats, attn_impl=attn_impl, mesh=mesh,
-        tally=tally, moe_dense=moe_dense)
-    last_idx = jnp.maximum(chunk_lens - 1, 0)
-    h_last = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]
-    return _with_ssm(_unembed(params, cfg, h_last), new_cache, ssm, new_ssm,
-                     _moe_routing(tally, jnp.arange(h.shape[0]) * h.shape[1]
-                                  + last_idx))
+    with jax.named_scope(scopes.CHUNK):
+        tally = _moe_tally(cfg)
+        h, new_cache, new_ssm = _chunk_trunk(
+            params, cfg, tokens, ctx_lens, chunk_lens, slot_ids, block_tables,
+            kv_cache, ad, ssm, seats, attn_impl=attn_impl, mesh=mesh,
+            tally=tally, moe_dense=moe_dense)
+        last_idx = jnp.maximum(chunk_lens - 1, 0)
+        return _with_ssm(_unembed(params, cfg, h, last_idx), new_cache, ssm,
+                         new_ssm,
+                         _moe_routing(tally, jnp.arange(h.shape[0]) * h.shape[1]
+                                      + last_idx))
 
 
 # --------------------------------------------------------------------------
@@ -856,34 +921,35 @@ def embed_forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     final norm, pools over valid positions ("mean" or "last"), and returns
     L2-normalised float32 (B, H).
     """
-    B, T = tokens.shape
-    positions = jnp.arange(T)[None, :].repeat(B, axis=0)
-    h = _embed(params, cfg, tokens, positions)
-    scale = cfg.attn_scale
-    for li, lp in enumerate(params["layers"]):
-        sw = cfg.layer_window(li)
-        hn = _norm(h, lp["attn_norm"], cfg)
-        q, k, v = (_mla_naive_qkv(hn, lp, cfg, positions) if cfg.is_mla
-                   else _qkv(hn, lp, cfg, positions, li))
-        out = attn_ops.prefill_attention(q, k, v, prompt_lens, scale,
-                                         sliding_window=sw,
-                                         logit_softcap=cfg.attn_logit_softcapping)
-        out = out.reshape(B, T, cfg.attn_out_size)
-        h = h + _attn_residual(out, lp, cfg) + _ssm_nocache(hn, lp, cfg,
-                                                            prompt_lens)
-        h = h + _mlp_residual(h, lp, cfg)
-    if cfg.final_layernorm:
-        h = _norm(h, params["final_norm"], cfg)
-    h = h.astype(jnp.float32)
-    if pooling == "last":
-        last_idx = jnp.maximum(prompt_lens - 1, 0)
-        pooled = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]
-    else:                                  # masked mean over valid positions
-        mask = (jnp.arange(T)[None, :] < prompt_lens[:, None])[..., None]
-        pooled = jnp.sum(h * mask, axis=1) / \
-            jnp.maximum(prompt_lens[:, None], 1).astype(jnp.float32)
-    return pooled / jnp.maximum(
-        jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-12)
+    with jax.named_scope(scopes.SCORE):
+        B, T = tokens.shape
+        positions = jnp.arange(T)[None, :].repeat(B, axis=0)
+        h = _embed(params, cfg, tokens, positions)
+        scale = cfg.attn_scale
+        for li, lp in enumerate(params["layers"]):
+            sw = cfg.layer_window(li)
+            q, k, v, hn = (_mla_naive_qkv(h, lp, cfg, positions) if cfg.is_mla
+                           else _qkv(h, lp, cfg, positions, li))
+            out = attn_ops.prefill_attention(q, k, v, prompt_lens, scale,
+                                             sliding_window=sw,
+                                             logit_softcap=cfg.attn_logit_softcapping)
+            h = _attn_residual(h, out, lp, cfg) + _ssm_nocache(hn, lp, cfg,
+                                                               prompt_lens)
+            h = _mlp_residual(h, lp, cfg)
+        with jax.named_scope(scopes.HEAD):      # this trunk's head: a pool
+            if cfg.final_layernorm:
+                h = _norm(h, params["final_norm"], cfg)
+            h = h.astype(jnp.float32)
+            if pooling == "last":
+                last_idx = jnp.maximum(prompt_lens - 1, 0)
+                pooled = jnp.take_along_axis(h, last_idx[:, None, None],
+                                             axis=1)[:, 0]
+            else:                              # masked mean over valid positions
+                mask = (jnp.arange(T)[None, :] < prompt_lens[:, None])[..., None]
+                pooled = jnp.sum(h * mask, axis=1) / \
+                    jnp.maximum(prompt_lens[:, None], 1).astype(jnp.float32)
+            return pooled / jnp.maximum(
+                jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-12)
 
 
 @partial(jax.jit, static_argnames=("cfg", "top_n", "chunk"))
@@ -904,45 +970,48 @@ def score_prompt(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     FULL-VOCAB rank (vLLM's prompt_logprobs contract) — callers shift by
     one (the first prompt token has no conditional).
     """
-    B, T = tokens.shape
-    positions = jnp.arange(T)[None, :].repeat(B, axis=0)
-    h = _embed(params, cfg, tokens, positions)
-    scale = cfg.attn_scale
-    for li, lp in enumerate(params["layers"]):
-        sw = cfg.layer_window(li)
-        hn = _norm(h, lp["attn_norm"], cfg)
-        q, k, v = (_mla_naive_qkv(hn, lp, cfg, positions) if cfg.is_mla
-                   else _qkv(hn, lp, cfg, positions, li))
-        out = attn_ops.prefill_attention(q, k, v, prompt_lens, scale,
-                                         sliding_window=sw,
-                                         logit_softcap=cfg.attn_logit_softcapping)
-        h = h + _attn_residual(out.reshape(B, T, cfg.attn_out_size), lp,
-                               cfg) + _ssm_nocache(hn, lp, cfg, prompt_lens)
-        h = h + _mlp_residual(h, lp, cfg)
-    # next-token targets: position i scores tokens[i+1]
-    nxt = jnp.concatenate([tokens[:, 1:], jnp.zeros((B, 1), tokens.dtype)],
-                          axis=1)
-    n_chunks = T // chunk
-    hs = h.reshape(B, n_chunks, chunk, -1).swapaxes(0, 1)
-    ns = nxt.reshape(B, n_chunks, chunk).swapaxes(0, 1)
-    k_eff = min(top_n, cfg.vocab_size) if top_n else 0
+    with jax.named_scope(scopes.SCORE):
+        B, T = tokens.shape
+        positions = jnp.arange(T)[None, :].repeat(B, axis=0)
+        h = _embed(params, cfg, tokens, positions)
+        scale = cfg.attn_scale
+        for li, lp in enumerate(params["layers"]):
+            sw = cfg.layer_window(li)
+            q, k, v, hn = (_mla_naive_qkv(h, lp, cfg, positions) if cfg.is_mla
+                           else _qkv(h, lp, cfg, positions, li))
+            out = attn_ops.prefill_attention(q, k, v, prompt_lens, scale,
+                                             sliding_window=sw,
+                                             logit_softcap=cfg.attn_logit_softcapping)
+            h = _attn_residual(h, out, lp, cfg) + _ssm_nocache(hn, lp, cfg,
+                                                               prompt_lens)
+            h = _mlp_residual(h, lp, cfg)
+        # next-token targets: position i scores tokens[i+1]
+        nxt = jnp.concatenate([tokens[:, 1:], jnp.zeros((B, 1), tokens.dtype)],
+                              axis=1)
+        n_chunks = T // chunk
+        hs = h.reshape(B, n_chunks, chunk, -1).swapaxes(0, 1)
+        ns = nxt.reshape(B, n_chunks, chunk).swapaxes(0, 1)
+        k_eff = min(top_n, cfg.vocab_size) if top_n else 0
 
-    def one(args):
-        hc, nc = args                            # (B, chunk, H), (B, chunk)
-        lps = jax.nn.log_softmax(_unembed(params, cfg, hc), axis=-1)
-        chosen = jnp.take_along_axis(lps, nc[..., None], axis=-1)[..., 0]
-        rank = (jnp.sum(lps > chosen[..., None], axis=-1)
-                .astype(jnp.int32) + 1)          # 1-based full-vocab rank
-        if k_eff:
-            tl, ti = jax.lax.top_k(lps, k_eff)
-        else:
-            ti = jnp.zeros(nc.shape + (0,), jnp.int32)
-            tl = jnp.zeros(nc.shape + (0,), jnp.float32)
-        return chosen, rank, ti.astype(jnp.int32), tl
+        def one(args):
+            hc, nc = args                            # (B, chunk, H), (B, chunk)
+            logits = _unembed(params, cfg, hc)
+            with jax.named_scope(scopes.SAMPLE):
+                lps = jax.nn.log_softmax(logits, axis=-1)
+                chosen = jnp.take_along_axis(lps, nc[..., None],
+                                             axis=-1)[..., 0]
+                rank = (jnp.sum(lps > chosen[..., None], axis=-1)
+                        .astype(jnp.int32) + 1)      # 1-based full-vocab rank
+                if k_eff:
+                    tl, ti = jax.lax.top_k(lps, k_eff)
+                else:
+                    ti = jnp.zeros(nc.shape + (0,), jnp.int32)
+                    tl = jnp.zeros(nc.shape + (0,), jnp.float32)
+                return chosen, rank, ti.astype(jnp.int32), tl
 
-    chosen, ranks, top_ids, top_lps = jax.lax.map(one, (hs, ns))
-    merge = lambda x: x.swapaxes(0, 1).reshape((B, T) + x.shape[3:])
-    return merge(chosen), merge(ranks), merge(top_ids), merge(top_lps)
+        chosen, ranks, top_ids, top_lps = jax.lax.map(one, (hs, ns))
+        merge = lambda x: x.swapaxes(0, 1).reshape((B, T) + x.shape[3:])
+        return merge(chosen), merge(ranks), merge(top_ids), merge(top_lps)
 
 
 # --------------------------------------------------------------------------
@@ -960,19 +1029,17 @@ def _chunk_trunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     and attends against cached context + causal-within-window.  Used by both
     prefill_chunk (last-row logits) and decode_verify (all-row argmax).
     Returns (h, kv_cache, seat pool or None)."""
-    B, C = tokens.shape
-    positions = ctx_lens[:, None] + jnp.arange(C)[None, :]
+    positions = ctx_lens[:, None] + jnp.arange(tokens.shape[1])[None, :]
     h = _embed(params, cfg, tokens, positions)
     scale = cfg.attn_scale
     new_cache = []
     new_ssm = []
     for li, lp in enumerate(params["layers"]):
         sw = cfg.layer_window(li)
-        hn = _norm(h, lp["attn_norm"], cfg)
         if cfg.is_mla:
             # MLA window: write the latent, attend ABSORBED against the
             # latent pages (k == v == latent; value = first kv_lora cols)
-            q_nope, q_rope, latent = _mla_proj(hn, lp, cfg, positions, ad)
+            q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
             entry = attn_ops.write_mla_entry(kv_cache[li], latent, slot_ids,
                                              latent_split=cfg.mla_kv_lora_rank)
             new_cache.append(entry)
@@ -984,11 +1051,10 @@ def _chunk_trunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 scale_slices=(cfg.mla_kv_lora_rank,
                               cfg.mla_qk_rope_head_dim))
             out = _mla_unabsorb(out, lp, cfg)
-            out = out.reshape(B, C, cfg.num_heads * cfg.mla_v_head_dim)
-            h = h + _attn_residual(out, lp, cfg, ad)
-            h = h + _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+            h = _attn_residual(h, out, lp, cfg, ad)
+            h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
             continue
-        q, k, v = _qkv(hn, lp, cfg, positions, li, ad)
+        q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)
         entry = attn_ops.write_kv_entry(kv_cache[li], k, v, slot_ids)
         new_cache.append(entry)
         ck, cv = entry["k"], entry["v"]
@@ -1010,15 +1076,13 @@ def _chunk_trunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 q, ck, cv, block_tables, ctx_lens, chunk_lens, scale,
                 k_scale=ks, v_scale=vs, sliding_window=sw,
                 logit_softcap=cfg.attn_logit_softcapping)
-        out = out.reshape(B, C, cfg.q_size)
-        att = _attn_residual(out, lp, cfg, ad)
+        m = None
         if ssm is not None:
             m, entry = _ssm_window(hn, lp["ssm"], cfg, chunk_lens, ssm[li],
                                    seats, fresh=ctx_lens == 0)
             new_ssm.append(entry)
-            att = att + m
-        h = h + att
-        h = h + _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+        h = _attn_residual(h, out, lp, cfg, ad, m)
+        h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
     return h, new_cache, new_ssm or None
 
 
@@ -1040,11 +1104,13 @@ def decode_verify(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     cache before the window; chunk_lens: (B,) valid rows; slot_ids: (B, K);
     block_tables: (B, max_blocks).  Returns (pred (B, K) int32, kv_cache).
     """
-    h, new_cache, _ = _chunk_trunk(params, cfg, tokens, ctx_lens,
-                                   chunk_lens, slot_ids, block_tables,
-                                   kv_cache, attn_impl=attn_impl, mesh=mesh)
-    logits = _unembed(params, cfg, h)                       # (B, K, V)
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_cache
+    with jax.named_scope(scopes.VERIFY):
+        h, new_cache, _ = _chunk_trunk(params, cfg, tokens, ctx_lens,
+                                       chunk_lens, slot_ids, block_tables,
+                                       kv_cache, attn_impl=attn_impl, mesh=mesh)
+        logits = _unembed(params, cfg, h)                       # (B, K, V)
+        with jax.named_scope(scopes.SAMPLE):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_cache
 
 
 @partial(jax.jit, static_argnames=("cfg", "attn_impl", "mesh"),
@@ -1066,15 +1132,16 @@ def decode_verify_sampled(params: Params, cfg: ModelConfig,
     (``tokens[:, 1:]``).  temperature <= 0 rows degenerate to exact
     greedy acceptance.  Returns (accept (B, K-1) bool, pred (B, K) int32,
     kv_cache)."""
-    from tpuserve.ops.sampling import spec_accept_sampled
-    h, new_cache, _ = _chunk_trunk(params, cfg, tokens, ctx_lens,
-                                   chunk_lens, slot_ids, block_tables,
-                                   kv_cache, attn_impl=attn_impl, mesh=mesh)
-    logits = _unembed(params, cfg, h)                       # (B, K, V)
-    accept, pred = spec_accept_sampled(logits, tokens[:, 1:], chunk_lens,
-                                       keys, temperature, top_k, top_p,
-                                       min_p)
-    return accept, pred, new_cache
+    with jax.named_scope(scopes.VERIFY):
+        from tpuserve.ops.sampling import spec_accept_sampled
+        h, new_cache, _ = _chunk_trunk(params, cfg, tokens, ctx_lens,
+                                       chunk_lens, slot_ids, block_tables,
+                                       kv_cache, attn_impl=attn_impl, mesh=mesh)
+        logits = _unembed(params, cfg, h)                       # (B, K, V)
+        accept, pred = spec_accept_sampled(logits, tokens[:, 1:], chunk_lens,
+                                           keys, temperature, top_k, top_p,
+                                           min_p)
+        return accept, pred, new_cache
 
 
 # --------------------------------------------------------------------------
@@ -1087,10 +1154,12 @@ def window_slot(block_tables: jnp.ndarray, pos: jnp.ndarray,
     shared by :func:`decode_multi` and the pipelined
     parallel.pipeline.pp_decode_multi so the two window implementations
     can't drift.  Inactive (padding) rows write to PAD_SLOT (dropped)."""
-    slot = (jnp.take_along_axis(block_tables,
-                                (pos // block_size)[:, None], axis=1)[:, 0]
-            * block_size + pos % block_size)
-    return jnp.where(active, slot, attn_ops.PAD_SLOT)
+    with jax.named_scope(scopes.CARRY):
+        slot = (jnp.take_along_axis(block_tables,
+                                    (pos // block_size)[:, None],
+                                    axis=1)[:, 0]
+                * block_size + pos % block_size)
+        return jnp.where(active, slot, attn_ops.PAD_SLOT)
 
 
 def window_extras(logits: jnp.ndarray, s: jnp.ndarray, cnt, presence,
@@ -1106,14 +1175,15 @@ def window_extras(logits: jnp.ndarray, s: jnp.ndarray, cnt, presence,
     if cnt is None:
         return logits
     from tpuserve.ops.sampling import penalize_from_counts
-    logits = penalize_from_counts(logits, cnt, presence, frequency,
-                                  repetition)
-    if bias is not None:
-        logits = logits + bias
-    if floor_bias is not None:
-        logits = logits + jnp.where(
-            (s < floor_remaining)[:, None], floor_bias, 0.0)
-    return logits
+    with jax.named_scope(scopes.SAMPLE):
+        logits = penalize_from_counts(logits, cnt, presence, frequency,
+                                      repetition)
+        if bias is not None:
+            logits = logits + bias
+        if floor_bias is not None:
+            logits = logits + jnp.where(
+                (s < floor_remaining)[:, None], floor_bias, 0.0)
+        return logits
 
 
 def window_count_update(cnt, nxt):
@@ -1122,7 +1192,8 @@ def window_count_update(cnt, nxt):
     contract, shared like :func:`window_extras`."""
     if cnt is None:
         return None
-    return cnt.at[jnp.arange(cnt.shape[0]), nxt].add(1.0)
+    with jax.named_scope(scopes.CARRY):
+        return cnt.at[jnp.arange(cnt.shape[0]), nxt].add(1.0)
 
 
 def window_unpack_lp(outs):
@@ -1149,8 +1220,9 @@ def window_guided_mask(logits: jnp.ndarray, gstate: jnp.ndarray,
     bias -> floor -> grammar mask -> sample), so the two paths stay
     token-identical."""
     from tpuserve.ops.sampling import apply_token_mask
-    rows = gmasks[jnp.clip(gstate, 0, gmasks.shape[0] - 1)]
-    return apply_token_mask(logits, rows, gstate >= 0)
+    with jax.named_scope(scopes.SAMPLE):
+        rows = gmasks[jnp.clip(gstate, 0, gmasks.shape[0] - 1)]
+        return apply_token_mask(logits, rows, gstate >= 0)
 
 
 def window_guided_advance(gstate: jnp.ndarray, nxt: jnp.ndarray,
@@ -1162,8 +1234,9 @@ def window_guided_advance(gstate: jnp.ndarray, nxt: jnp.ndarray,
     delta).  Unguided rows (-1) stay -1.  The host replays the SAME
     table at window flush (engine._emit_one), so host mirror and device
     carry cannot drift."""
-    ns = gnext[jnp.clip(gstate, 0, gnext.shape[0] - 1), gclass[nxt]]
-    return jnp.where(gstate >= 0, ns, gstate)
+    with jax.named_scope(scopes.CARRY):
+        ns = gnext[jnp.clip(gstate, 0, gnext.shape[0] - 1), gclass[nxt]]
+        return jnp.where(gstate >= 0, ns, gstate)
 
 
 def window_sample(logits: jnp.ndarray, keys: jnp.ndarray,
@@ -1177,20 +1250,21 @@ def window_sample(logits: jnp.ndarray, keys: jnp.ndarray,
     falling to per-token dispatches).  The per-row key's step word folds
     by +s, matching the engine's host-side per-step key construction.
     One source of truth for both window implementations."""
-    if mode == "greedy":
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     from tpuserve.ops import sampling as sampling_ops
-    B = logits.shape[0]
-    step_key = jnp.array([0, 1], jnp.uint32)[None, :]
-    stepped = keys + step_key * s.astype(jnp.uint32)
-    if mode == "temperature":
+    with jax.named_scope(scopes.SAMPLE):
+        if mode == "greedy":
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        B = logits.shape[0]
+        step_key = jnp.array([0, 1], jnp.uint32)[None, :]
+        stepped = keys + step_key * s.astype(jnp.uint32)
+        if mode == "temperature":
+            return sampling_ops.sample_tokens(
+                logits, stepped, temperature,
+                jnp.zeros((B,), jnp.int32), jnp.ones((B,), jnp.float32),
+                mode="temperature")
         return sampling_ops.sample_tokens(
-            logits, stepped, temperature,
-            jnp.zeros((B,), jnp.int32), jnp.ones((B,), jnp.float32),
-            mode="temperature")
-    return sampling_ops.sample_tokens(
-        logits, stepped, temperature, top_k, top_p, min_p=min_p,
-        mode="full")
+            logits, stepped, temperature, top_k, top_p, min_p=min_p,
+            mode="full")
 
 def _decode_body(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                  positions: jnp.ndarray, slot_ids: jnp.ndarray,
@@ -1203,7 +1277,6 @@ def _decode_body(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     against the paged cache, return (logits (B, V), new kv_cache, seat
     pool or None, routing or None).  Used by :func:`decode_step` (one dispatch per token) and
     :func:`decode_multi` (scanned — one dispatch per window)."""
-    B = tokens.shape[0]
     h = _embed(params, cfg, tokens, positions)                 # (B, H)
     scale = cfg.attn_scale
     new_cache = []
@@ -1211,12 +1284,11 @@ def _decode_body(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     tally = _moe_tally(cfg)
     for li, lp in enumerate(params["layers"]):
         sw = cfg.layer_window(li)
-        hn = _norm(h, lp["attn_norm"], cfg)
         if cfg.is_mla:
             # MLA decode: absorbed attention straight against the latent
             # pages — the step reads mla_latent_dim bytes per cached token
             # instead of 2 * Hkv * head_dim (the ~10x KV-bandwidth win)
-            q_nope, q_rope, latent = _mla_proj(hn, lp, cfg, positions, ad)
+            q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
             entry = attn_ops.write_mla_entry(kv_cache[li], latent, slot_ids,
                                              latent_split=cfg.mla_kv_lora_rank)
             new_cache.append(entry)
@@ -1227,11 +1299,10 @@ def _decode_body(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 scale_slices=(cfg.mla_kv_lora_rank,
                               cfg.mla_qk_rope_head_dim))
             out = _mla_unabsorb(out, lp, cfg)
-            out = out.reshape(B, cfg.num_heads * cfg.mla_v_head_dim)
-            h = h + _attn_residual(out, lp, cfg, ad)
-            h = h + _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+            h = _attn_residual(h, out, lp, cfg, ad)
+            h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
             continue
-        q, k, v = _qkv(hn, lp, cfg, positions, li, ad)  # (B, Hq/Hkv, D)
+        q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)  # (B, Hq/Hkv, D)
         entry = attn_ops.write_kv_entry(kv_cache[li], k, v, slot_ids)
         new_cache.append(entry)
         ck, cv = entry["k"], entry["v"]
@@ -1253,16 +1324,14 @@ def _decode_body(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                                                   k_scale=ks, v_scale=vs,
                                                   sliding_window=sw,
                                                   logit_softcap=cfg.attn_logit_softcapping)
-        out = out.reshape(B, cfg.q_size)
-        att = _attn_residual(out, lp, cfg, ad)
+        m = None
         if ssm is not None:
             m, entry = _ssm_decode(hn, lp["ssm"], cfg,
                                    slot_ids != attn_ops.PAD_SLOT, ssm[li],
                                    seats, attn_impl)
             new_ssm.append(entry)
-            att = att + m
-        h = h + att
-        h = h + _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+        h = _attn_residual(h, out, lp, cfg, ad, m)
+        h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
     return (_unembed(params, cfg, h), new_cache, new_ssm or None,
             _moe_routing(tally, None))
 
@@ -1284,11 +1353,12 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
     ``mesh``: static; see :func:`prefill` — head-parallel Pallas under tp.
     """
-    logits, new_cache, new_ssm, moe = _decode_body(
-        params, cfg, tokens, positions, slot_ids, block_tables, seq_lens,
-        kv_cache, attn_impl, mesh, ad=ad, ssm=ssm, seats=seats,
-        moe_dense=moe_dense)
-    return _with_ssm(logits, new_cache, ssm, new_ssm, moe)
+    with jax.named_scope(scopes.DECODE):
+        logits, new_cache, new_ssm, moe = _decode_body(
+            params, cfg, tokens, positions, slot_ids, block_tables, seq_lens,
+            kv_cache, attn_impl, mesh, ad=ad, ssm=ssm, seats=seats,
+            moe_dense=moe_dense)
+        return _with_ssm(logits, new_cache, ssm, new_ssm, moe)
 
 
 @partial(jax.jit,
@@ -1358,88 +1428,92 @@ def decode_multi(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     the steps, and None for the picks, which ride with the logprobs here)
     for a model with experts.
     """
-    B = tokens.shape[0]
-    block_size = kv_cache[0]["k"].shape[1]
-    guided = gstate is not None
+    with jax.named_scope(scopes.DECODE):
+        B = tokens.shape[0]
+        block_size = kv_cache[0]["k"].shape[1]
+        guided = gstate is not None
 
-    def one(carry, s):
+        def one(carry, s):
+            if guided:
+                toks, pos, lens, cache, cnt, pool, gst = carry
+            else:
+                (toks, pos, lens, cache, cnt, pool), gst = carry, None
+            slot = window_slot(block_tables, pos, active, block_size)
+            logits, cache, pool, moe = _decode_body(
+                params, cfg, toks, pos, slot, block_tables, lens, cache,
+                attn_impl, mesh, ad=ad, ssm=pool, seats=seats,
+                moe_dense=moe_dense)
+            # extras ordered before sampling AND before logprobs, exactly
+            # like the per-step path (penalties -> bias -> floor); whichever
+            # features aren't in play ride along as zeros so one executable
+            # family covers them all
+            logits = window_extras(logits, s, cnt, presence, frequency,
+                                   repetition, bias, floor_bias,
+                                   floor_remaining)
+            if guided:
+                # grammar-FSM mask LAST, like the per-step path: the sampler
+                # renormalises over exactly the legal token set
+                logits = window_guided_mask(logits, gst, gmasks)
+            nxt = window_sample(logits, keys, temperature, s, mode,
+                                top_k=top_k, top_p=top_p, min_p=min_p)
+            if guided:
+                gst = window_guided_advance(gst, nxt, gclass, gnext)
+            cnt = window_count_update(cnt, nxt)
+            ys = nxt
+            if logprobs_n:
+                # sampled-token + top-N logprobs computed in-window, so
+                # logprobs requests keep fused-window throughput (the engine
+                # previously dropped them to per-token dispatches)
+                from tpuserve.ops.sampling import compute_logprobs
+                ys = (nxt, compute_logprobs(logits, nxt, logprobs_n))
+            if moe is not None:
+                # a step's routing counts ride out beside its tokens, and its
+                # rows' picks where their logprobs do
+                ys = (ys, moe[:2] if logprobs_n else moe[:1])
+            with jax.named_scope(scopes.CARRY):
+                new_carry = (nxt, pos + 1, lens + 1, cache, cnt, pool)
+            if guided:
+                new_carry += (gst,)
+            return new_carry, ys
+
+        carry = (tokens, positions, seq_lens, kv_cache, counts, ssm)
         if guided:
-            toks, pos, lens, cache, cnt, pool, gst = carry
-        else:
-            (toks, pos, lens, cache, cnt, pool), gst = carry, None
-        slot = window_slot(block_tables, pos, active, block_size)
-        logits, cache, pool, moe = _decode_body(
-            params, cfg, toks, pos, slot, block_tables, lens, cache,
-            attn_impl, mesh, ad=ad, ssm=pool, seats=seats,
-            moe_dense=moe_dense)
-        # extras ordered before sampling AND before logprobs, exactly
-        # like the per-step path (penalties -> bias -> floor); whichever
-        # features aren't in play ride along as zeros so one executable
-        # family covers them all
-        logits = window_extras(logits, s, cnt, presence, frequency,
-                               repetition, bias, floor_bias,
-                               floor_remaining)
-        if guided:
-            # grammar-FSM mask LAST, like the per-step path: the sampler
-            # renormalises over exactly the legal token set
-            logits = window_guided_mask(logits, gst, gmasks)
-        nxt = window_sample(logits, keys, temperature, s, mode,
-                            top_k=top_k, top_p=top_p, min_p=min_p)
-        if guided:
-            gst = window_guided_advance(gst, nxt, gclass, gnext)
-        cnt = window_count_update(cnt, nxt)
-        ys = nxt
+            carry += (gstate,)
+        final, outs = jax.lax.scan(
+            one, carry, jnp.arange(steps, dtype=jnp.int32))
+        kv_cache = final[3]
+        moe = None
+        lp = None
+        # what the window hands back, laid out [row, step]
+        with jax.named_scope(scopes.CARRY):
+            if cfg.routes_experts:
+                outs, moe = outs
+                picks = moe[1:]                         # ((steps, B, L, k),)
+                moe = jnp.sum(moe[0], axis=0), None, None   # over the steps
+            if logprobs_n:
+                out, lp = window_unpack_lp(outs)
+                if moe is not None:
+                    lp += (jnp.swapaxes(picks[0], 0, 1),)   # like lp
+            else:
+                out = jnp.swapaxes(outs, 0, 1)                     # (B, steps)
+        if out_mesh is not None:
+            # Multi-host lockstep device_gets the window on the coordinator;
+            # force the small token matrix to be fully replicated/addressable.
+            # ``out_mesh`` is the engine's full mesh — distinct from ``mesh``,
+            # which is only set when the Pallas kernels are head-partitionable.
+            from jax.sharding import NamedSharding, PartitionSpec
+            out = jax.lax.with_sharding_constraint(
+                out, NamedSharding(out_mesh, PartitionSpec()))
+        res = (out, kv_cache)
         if logprobs_n:
-            # sampled-token + top-N logprobs computed in-window, so
-            # logprobs requests keep fused-window throughput (the engine
-            # previously dropped them to per-token dispatches)
-            from tpuserve.ops.sampling import compute_logprobs
-            ys = (nxt, compute_logprobs(logits, nxt, logprobs_n))
-        if moe is not None:
-            # a step's routing counts ride out beside its tokens, and its
-            # rows' picks where their logprobs do
-            ys = (ys, moe[:2] if logprobs_n else moe[:1])
-        new_carry = (nxt, pos + 1, lens + 1, cache, cnt, pool)
+            res += (lp,)
         if guided:
-            new_carry += (gst,)
-        return new_carry, ys
-
-    carry = (tokens, positions, seq_lens, kv_cache, counts, ssm)
-    if guided:
-        carry += (gstate,)
-    final, outs = jax.lax.scan(
-        one, carry, jnp.arange(steps, dtype=jnp.int32))
-    kv_cache = final[3]
-    moe = None
-    if cfg.routes_experts:
-        outs, moe = outs
-        picks = moe[1:]                             # ((steps, B, L, k),)
-        moe = jnp.sum(moe[0], axis=0), None, None   # over the fused steps
-    lp = None
-    if logprobs_n:
-        out, lp = window_unpack_lp(outs)
+            res += (final[6],)
+        if ssm is not None:
+            res += (final[5],)
         if moe is not None:
-            lp += (jnp.swapaxes(picks[0], 0, 1),)   # [row, step] like lp
-    else:
-        out = jnp.swapaxes(outs, 0, 1)                         # (B, steps)
-    if out_mesh is not None:
-        # Multi-host lockstep device_gets the window on the coordinator;
-        # force the small token matrix to be fully replicated/addressable.
-        # ``out_mesh`` is the engine's full mesh — distinct from ``mesh``,
-        # which is only set when the Pallas kernels are head-partitionable.
-        from jax.sharding import NamedSharding, PartitionSpec
-        out = jax.lax.with_sharding_constraint(
-            out, NamedSharding(out_mesh, PartitionSpec()))
-    res = (out, kv_cache)
-    if logprobs_n:
-        res += (lp,)
-    if guided:
-        res += (final[6],)
-    if ssm is not None:
-        res += (final[5],)
-    if moe is not None:
-        res += (moe,)
-    return res
+            res += (moe,)
+        return res
 
 
 # --------------------------------------------------------------------------
@@ -1462,23 +1536,24 @@ def _ragged_reference_attn(q, ck, cv, block_tables, row_seq, row_lens,
       ``decode_rows=False`` (a packed batched prefill: ``meta`` is zero)
       leaves the overlay out.
     """
-    T = q.shape[0]
-    out = attn_ops.ragged_blocked_attention(
-        q, ck, cv, block_tables[jnp.clip(blk_seq, 0, None)], row_lens,
-        blk, scale, k_scale=ks, v_scale=vs, sliding_window=sw,
-        logit_softcap=softcap, scale_slices=scale_slices)
-    if not decode_rows:
-        return out
-    # static head slice: decode rows r < meta[0] are rows r themselves,
-    # and meta[0] <= max_num_seqs <= block_tables.shape[0]
-    Bc = min(block_tables.shape[0], T)
-    head = attn_ops.paged_decode_attention(
-        q[:Bc], ck, cv, block_tables[row_seq[:Bc]], row_lens[:Bc], scale,
-        k_scale=ks, v_scale=vs, sliding_window=sw, logit_softcap=softcap,
-        scale_slices=scale_slices)
-    head = jnp.pad(head, ((0, T - Bc), (0, 0), (0, 0)))
-    is_dec = (jnp.arange(T) < meta[0])[:, None, None]
-    return jnp.where(is_dec, head, out)
+    with jax.named_scope(scopes.ATTN_KERNEL):
+        T = q.shape[0]
+        out = attn_ops.ragged_blocked_attention(
+            q, ck, cv, block_tables[jnp.clip(blk_seq, 0, None)], row_lens,
+            blk, scale, k_scale=ks, v_scale=vs, sliding_window=sw,
+            logit_softcap=softcap, scale_slices=scale_slices)
+        if not decode_rows:
+            return out
+        # static head slice: decode rows r < meta[0] are rows r themselves,
+        # and meta[0] <= max_num_seqs <= block_tables.shape[0]
+        Bc = min(block_tables.shape[0], T)
+        head = attn_ops.paged_decode_attention(
+            q[:Bc], ck, cv, block_tables[row_seq[:Bc]], row_lens[:Bc], scale,
+            k_scale=ks, v_scale=vs, sliding_window=sw, logit_softcap=softcap,
+            scale_slices=scale_slices)
+        head = jnp.pad(head, ((0, T - Bc), (0, 0), (0, 0)))
+        is_dec = (jnp.arange(T) < meta[0])[:, None, None]
+        return jnp.where(is_dec, head, out)
 
 
 @partial(jax.jit,
@@ -1531,75 +1606,70 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     (the engine keeps mixed steps and the prefix cache off for such a
     model).
     """
-    T = tokens.shape[0]
-    if ssm is not None and decode_rows:
-        raise ValueError("a model with recurrent state takes the ragged "
-                         "trunk for packed prefills only (decode_rows=False)")
-    h = _embed(params, cfg, tokens, positions)                 # (T, H)
-    scale = cfg.attn_scale
-    row_lens = positions + 1
-    new_cache = []
-    new_ssm = []
-    tally = _moe_tally(cfg)
-    for li, lp in enumerate(params["layers"]):
-        sw = cfg.layer_window(li)
-        hn = _norm(h, lp["attn_norm"], cfg)
-        if cfg.is_mla:
-            # MLA: absorbed attention against the latent pages, like the
-            # chunk/decode trunks (reference path only — the Pallas
-            # kernels assume materialised per-head pages, same gate as
-            # the rest of the engine)
-            q_nope, q_rope, latent = _mla_proj(hn, lp, cfg, positions, ad)
-            entry = attn_ops.write_mla_entry(
-                kv_cache[li], latent, slot_ids,
-                latent_split=cfg.mla_kv_lora_rank)
+    with jax.named_scope(scopes.PREFILL):
+        if ssm is not None and decode_rows:
+            raise ValueError("a model with recurrent state takes the ragged "
+                             "trunk for packed prefills only (decode_rows=False)")
+        h = _embed(params, cfg, tokens, positions)                 # (T, H)
+        scale = cfg.attn_scale
+        row_lens = positions + 1
+        new_cache = []
+        new_ssm = []
+        tally = _moe_tally(cfg)
+        for li, lp in enumerate(params["layers"]):
+            sw = cfg.layer_window(li)
+            if cfg.is_mla:
+                # MLA: absorbed attention against the latent pages, like the
+                # chunk/decode trunks (reference path only — the Pallas
+                # kernels assume materialised per-head pages, same gate as
+                # the rest of the engine)
+                q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
+                entry = attn_ops.write_mla_entry(
+                    kv_cache[li], latent, slot_ids,
+                    latent_split=cfg.mla_kv_lora_rank)
+                new_cache.append(entry)
+                q_eff = _mla_absorb_q(q_nope, q_rope, lp, cfg)
+                out = _ragged_reference_attn(
+                    q_eff, entry["k"], entry["k"], block_tables, row_seq,
+                    row_lens, blk_seq, meta, ragged_blk, scale,
+                    entry.get("ks"), entry.get("ks"), None, None,
+                    scale_slices=(cfg.mla_kv_lora_rank,
+                                  cfg.mla_qk_rope_head_dim),
+                    decode_rows=decode_rows)
+                out = _mla_unabsorb(out, lp, cfg)
+                h = _attn_residual(h, out, lp, cfg, ad)
+                h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+                continue
+            q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)  # (T, H*, D)
+            entry = attn_ops.write_kv_entry(kv_cache[li], k, v, slot_ids)
             new_cache.append(entry)
-            q_eff = _mla_absorb_q(q_nope, q_rope, lp, cfg)
-            out = _ragged_reference_attn(
-                q_eff, entry["k"], entry["k"], block_tables, row_seq,
-                row_lens, blk_seq, meta, ragged_blk, scale,
-                entry.get("ks"), entry.get("ks"), None, None,
-                scale_slices=(cfg.mla_kv_lora_rank,
-                              cfg.mla_qk_rope_head_dim),
-                decode_rows=decode_rows)
-            out = _mla_unabsorb(out, lp, cfg)
-            out = out.reshape(T, cfg.num_heads * cfg.mla_v_head_dim)
-            h = h + _attn_residual(out, lp, cfg, ad)
-            h = h + _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
-            continue
-        q, k, v = _qkv(hn, lp, cfg, positions, li, ad)    # (T, H*, D)
-        entry = attn_ops.write_kv_entry(kv_cache[li], k, v, slot_ids)
-        new_cache.append(entry)
-        ck, cv = entry["k"], entry["v"]
-        ks, vs = entry.get("ks"), entry.get("vs")
-        if attn_impl == "pallas":
-            from tpuserve.ops.pallas_ragged_attention import \
-                ragged_paged_attention
-            out = ragged_paged_attention(
-                q, ck, cv, block_tables, kv_lens, q_starts, q_lens,
-                meta, blk_seq, scale, blk_q=ragged_blk, k_scale=ks,
-                v_scale=vs, sliding_window=sw,
-                logit_softcap=cfg.attn_logit_softcapping,
-                decode_rows=decode_rows)
-        else:
-            out = _ragged_reference_attn(
-                q, ck, cv, block_tables, row_seq, row_lens, blk_seq,
-                meta, ragged_blk, scale, ks, vs, sw,
-                cfg.attn_logit_softcapping, decode_rows=decode_rows)
-        out = out.reshape(T, cfg.q_size)
-        att = _attn_residual(out, lp, cfg, ad)
-        if ssm is not None:
-            m, entry = _ssm_packed(hn, lp["ssm"], cfg, positions,
-                                   slot_ids != attn_ops.PAD_SLOT, blk_seq,
-                                   q_starts, q_lens, ragged_blk, ssm[li],
-                                   seats)
-            new_ssm.append(entry)
-            att = att + m
-        h = h + att
-        h = h + _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
-    h_sel = h[last_rows]                                       # (B, H)
-    return _with_ssm(_unembed(params, cfg, h_sel), new_cache, ssm, new_ssm,
-                     _moe_routing(tally, last_rows))
+            ck, cv = entry["k"], entry["v"]
+            ks, vs = entry.get("ks"), entry.get("vs")
+            if attn_impl == "pallas":
+                from tpuserve.ops.pallas_ragged_attention import \
+                    ragged_paged_attention
+                out = ragged_paged_attention(
+                    q, ck, cv, block_tables, kv_lens, q_starts, q_lens,
+                    meta, blk_seq, scale, blk_q=ragged_blk, k_scale=ks,
+                    v_scale=vs, sliding_window=sw,
+                    logit_softcap=cfg.attn_logit_softcapping,
+                    decode_rows=decode_rows)
+            else:
+                out = _ragged_reference_attn(
+                    q, ck, cv, block_tables, row_seq, row_lens, blk_seq,
+                    meta, ragged_blk, scale, ks, vs, sw,
+                    cfg.attn_logit_softcapping, decode_rows=decode_rows)
+            m = None
+            if ssm is not None:
+                m, entry = _ssm_packed(hn, lp["ssm"], cfg, positions,
+                                       slot_ids != attn_ops.PAD_SLOT, blk_seq,
+                                       q_starts, q_lens, ragged_blk, ssm[li],
+                                       seats)
+                new_ssm.append(entry)
+            h = _attn_residual(h, out, lp, cfg, ad, m)
+            h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+        return _with_ssm(_unembed(params, cfg, h, last_rows), new_cache, ssm,
+                         new_ssm, _moe_routing(tally, last_rows))
 
 
 @partial(jax.jit, static_argnames=("cfg", "k"))
@@ -1619,38 +1689,38 @@ def draft_propose(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
     Returns (B, k) int32 proposals.
     """
-    B, T = tokens.shape
+    with jax.named_scope(scopes.DRAFT):
+        B, T = tokens.shape
 
-    positions = jnp.arange(T)[None, :].repeat(B, axis=0)
-    scale = cfg.attn_scale
+        positions = jnp.arange(T)[None, :].repeat(B, axis=0)
+        scale = cfg.attn_scale
 
-    def one(carry, j):
-        toks, cur = carry
-        h = _embed(params, cfg, toks, positions)
-        for li, lp in enumerate(params["layers"]):
-            hn = _norm(h, lp["attn_norm"], cfg)
-            q, kk, v = (_mla_naive_qkv(hn, lp, cfg, positions)
-                        if cfg.is_mla
-                        else _qkv(hn, lp, cfg, positions, li))
-            out = attn_ops.prefill_attention(
-                q, kk, v, cur, scale, sliding_window=cfg.layer_window(li),
-                logit_softcap=cfg.attn_logit_softcapping)
-            h = h + _attn_residual(out.reshape(B, T, cfg.attn_out_size),
-                                   lp, cfg) + _ssm_nocache(hn, lp, cfg, cur)
-            h = h + _mlp_residual(h, lp, cfg)
-        # unembed ONLY each row's last position — the full (B, T, V)
-        # logits would be GBs at serving batch sizes
-        h_last = jnp.take_along_axis(h, (cur - 1)[:, None, None],
-                                     axis=1)[:, 0]
-        nxt = jnp.argmax(_unembed(params, cfg, h_last),
-                         axis=-1).astype(jnp.int32)
-        toks = jnp.where(
-            jnp.arange(T)[None, :] == cur[:, None], nxt[:, None], toks)
-        return (toks, cur + 1), nxt
+        def one(carry, j):
+            toks, cur = carry
+            h = _embed(params, cfg, toks, positions)
+            for li, lp in enumerate(params["layers"]):
+                q, kk, v, hn = (_mla_naive_qkv(h, lp, cfg, positions)
+                                if cfg.is_mla
+                                else _qkv(h, lp, cfg, positions, li))
+                out = attn_ops.prefill_attention(
+                    q, kk, v, cur, scale, sliding_window=cfg.layer_window(li),
+                    logit_softcap=cfg.attn_logit_softcapping)
+                h = _attn_residual(h, out, lp, cfg) \
+                    + _ssm_nocache(hn, lp, cfg, cur)
+                h = _mlp_residual(h, lp, cfg)
+            # unembed ONLY each row's last position — the full (B, T, V)
+            # logits would be GBs at serving batch sizes
+            logits = _unembed(params, cfg, h, cur - 1)
+            with jax.named_scope(scopes.SAMPLE):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope(scopes.CARRY):
+                toks = jnp.where(jnp.arange(T)[None, :] == cur[:, None],
+                                 nxt[:, None], toks)
+                return (toks, cur + 1), nxt
 
-    (_, _), outs = jax.lax.scan(one, (tokens, lens),
-                                jnp.arange(k, dtype=jnp.int32))
-    return jnp.swapaxes(outs, 0, 1)                      # (B, k)
+        (_, _), outs = jax.lax.scan(one, (tokens, lens),
+                                    jnp.arange(k, dtype=jnp.int32))
+        return jnp.swapaxes(outs, 0, 1)                      # (B, k)
 
 
 # --------------------------------------------------------------------------
@@ -1670,13 +1740,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     h = _embed(params, cfg, tokens, positions)
     scale = cfg.attn_scale
     for li, lp in enumerate(params["layers"]):
-        hn = _norm(h, lp["attn_norm"], cfg)
-        q, k, v = (_mla_naive_qkv(hn, lp, cfg, positions) if cfg.is_mla
-                   else _qkv(hn, lp, cfg, positions, li))
+        q, k, v, hn = (_mla_naive_qkv(h, lp, cfg, positions) if cfg.is_mla
+                       else _qkv(h, lp, cfg, positions, li))
         out = attn_ops.prefill_attention(q, k, v, seq_lens, scale,
                                          sliding_window=cfg.layer_window(li),
                                          logit_softcap=cfg.attn_logit_softcapping)
-        h = h + _attn_residual(out.reshape(B, T, cfg.attn_out_size), lp,
-                               cfg) + _ssm_nocache(hn, lp, cfg, seq_lens)
-        h = h + _mlp_residual(h, lp, cfg, moe_dense=True)
+        h = _attn_residual(h, out, lp, cfg) + _ssm_nocache(hn, lp, cfg,
+                                                           seq_lens)
+        h = _mlp_residual(h, lp, cfg, moe_dense=True)
     return _unembed(params, cfg, h)

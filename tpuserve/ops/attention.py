@@ -11,6 +11,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from tpuserve.ops import scopes
+
 NEG_INF = -1e30
 
 
@@ -43,22 +45,23 @@ def prefill_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     ``sliding_window``: Mistral-style — row p attends keys in (p - W, p].
     Returns (B, T, Hq, D) in q.dtype.  Softmax in float32.
     """
-    B, T, Hq, D = q.shape
-    n_rep = Hq // k.shape[2]
-    k = repeat_kv(k, n_rep)
-    v = repeat_kv(v, n_rep)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
-    scores = _softcap(scores, logit_softcap)
-    pos = jnp.arange(T)
-    causal = pos[None, :] <= pos[:, None]                      # (Tq, Tk)
-    if sliding_window is not None:
-        causal &= pos[None, :] > pos[:, None] - sliding_window
-    valid = pos[None, :] < prompt_lens[:, None]                # (B, Tk)
-    mask = causal[None, None, :, :] & valid[:, None, None, :]
-    scores = jnp.where(mask, scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
-    return out.astype(q.dtype)
+    with jax.named_scope(scopes.ATTN_KERNEL):
+        B, T, Hq, D = q.shape
+        n_rep = Hq // k.shape[2]
+        k = repeat_kv(k, n_rep)
+        v = repeat_kv(v, n_rep)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
+        scores = _softcap(scores, logit_softcap)
+        pos = jnp.arange(T)
+        causal = pos[None, :] <= pos[:, None]                      # (Tq, Tk)
+        if sliding_window is not None:
+            causal &= pos[None, :] > pos[:, None] - sliding_window
+        valid = pos[None, :] < prompt_lens[:, None]                # (B, Tk)
+        mask = causal[None, None, :, :] & valid[:, None, None, :]
+        scores = jnp.where(mask, scores, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
+        return out.astype(q.dtype)
 
 
 def _dequant_gathered(k, v, k_scale, v_scale, block_tables, B, S, Hkv,
@@ -109,28 +112,29 @@ def paged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     scales over the channel axis.  ``sliding_window``: attend only
     the last W cached positions.  Returns (B, Hq, D).
     """
-    B, Hq, D = q.shape
-    _, block_size, Hkv, _ = k_cache.shape
-    max_blocks = block_tables.shape[1]
-    S = max_blocks * block_size
-    # Gather pages: (B, max_blocks, block_size, Hkv, D) -> (B, S, Hkv, D)
-    k = k_cache[block_tables].reshape(B, S, Hkv, D)
-    v = v_cache[block_tables].reshape(B, S, Hkv, D)
-    k, v = _dequant_gathered(k, v, k_scale, v_scale, block_tables, B, S,
-                             Hkv, q.dtype, scale_slices)
-    n_rep = Hq // Hkv
-    k = repeat_kv(k, n_rep)
-    v = repeat_kv(v, n_rep)
-    scores = jnp.einsum("bhd,bkhd->bhk", q, k, preferred_element_type=jnp.float32) * scale
-    scores = _softcap(scores, logit_softcap)
-    valid = jnp.arange(S)[None, :] < seq_lens[:, None]         # (B, S)
-    if sliding_window is not None:
-        valid &= (jnp.arange(S)[None, :]
-                  >= seq_lens[:, None] - sliding_window)
-    scores = jnp.where(valid[:, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhk,bkhd->bhd", probs.astype(v.dtype), v)
-    return out.astype(q.dtype)
+    with jax.named_scope(scopes.ATTN_KERNEL):
+        B, Hq, D = q.shape
+        _, block_size, Hkv, _ = k_cache.shape
+        max_blocks = block_tables.shape[1]
+        S = max_blocks * block_size
+        # Gather pages: (B, max_blocks, block_size, Hkv, D) -> (B, S, Hkv, D)
+        k = k_cache[block_tables].reshape(B, S, Hkv, D)
+        v = v_cache[block_tables].reshape(B, S, Hkv, D)
+        k, v = _dequant_gathered(k, v, k_scale, v_scale, block_tables, B, S,
+                                 Hkv, q.dtype, scale_slices)
+        n_rep = Hq // Hkv
+        k = repeat_kv(k, n_rep)
+        v = repeat_kv(v, n_rep)
+        scores = jnp.einsum("bhd,bkhd->bhk", q, k, preferred_element_type=jnp.float32) * scale
+        scores = _softcap(scores, logit_softcap)
+        valid = jnp.arange(S)[None, :] < seq_lens[:, None]         # (B, S)
+        if sliding_window is not None:
+            valid &= (jnp.arange(S)[None, :]
+                      >= seq_lens[:, None] - sliding_window)
+        scores = jnp.where(valid[:, None, :], scores, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bhk,bkhd->bhd", probs.astype(v.dtype), v)
+        return out.astype(q.dtype)
 
 
 def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
@@ -161,56 +165,57 @@ def ragged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     :func:`paged_decode_attention`'s math; for prefill-chunk rows to
     :func:`chunked_prefill_attention`'s.  Returns (T, Hq, D).
     """
-    T, Hq, D = q.shape
-    _, bs, Hkv, Dk = k_cache.shape
-    mb = row_block_tables.shape[1]
-    G = Hq // Hkv
-    pg = max(1, seg_size // bs)                # pages per segment
-    n_seg = -(-mb // pg)
-    pad = n_seg * pg - mb
-    bt = row_block_tables
-    if pad:
-        # padded columns index block 0 but their key positions are
-        # >= mb*bs >= any row_lens, so the mask drops them
-        bt = jnp.pad(bt, ((0, 0), (0, pad)))
-    bt = bt.reshape(T, n_seg, pg).transpose(1, 0, 2)     # (n_seg, T, pg)
+    with jax.named_scope(scopes.ATTN_KERNEL):
+        T, Hq, D = q.shape
+        _, bs, Hkv, Dk = k_cache.shape
+        mb = row_block_tables.shape[1]
+        G = Hq // Hkv
+        pg = max(1, seg_size // bs)                # pages per segment
+        n_seg = -(-mb // pg)
+        pad = n_seg * pg - mb
+        bt = row_block_tables
+        if pad:
+            # padded columns index block 0 but their key positions are
+            # >= mb*bs >= any row_lens, so the mask drops them
+            bt = jnp.pad(bt, ((0, 0), (0, pad)))
+        bt = bt.reshape(T, n_seg, pg).transpose(1, 0, 2)     # (n_seg, T, pg)
 
-    q_r = (q.astype(jnp.float32) * scale).reshape(T, Hkv, G, D)
+        q_r = (q.astype(jnp.float32) * scale).reshape(T, Hkv, G, D)
 
-    def body(carry, bt_seg):
-        o, m, l, c0 = carry
-        R = pg * bs
-        k = k_cache[bt_seg].reshape(T, R, Hkv, Dk)
-        v = v_cache[bt_seg].reshape(T, R, Hkv, Dk)
-        k, v = _dequant_gathered(k, v, k_scale, v_scale, bt_seg, T, R,
-                                 Hkv, q.dtype, scale_slices)
-        scores = jnp.einsum("thgd,tkhd->thgk", q_r, k,
+        def body(carry, bt_seg):
+            o, m, l, c0 = carry
+            R = pg * bs
+            k = k_cache[bt_seg].reshape(T, R, Hkv, Dk)
+            v = v_cache[bt_seg].reshape(T, R, Hkv, Dk)
+            k, v = _dequant_gathered(k, v, k_scale, v_scale, bt_seg, T, R,
+                                     Hkv, q.dtype, scale_slices)
+            scores = jnp.einsum("thgd,tkhd->thgk", q_r, k,
+                                preferred_element_type=jnp.float32)
+            scores = scores.reshape(T, Hq, R)
+            scores = _softcap(scores, logit_softcap)
+            j = c0 * bs + jnp.arange(R)[None, :]             # key positions
+            mask = j < row_lens[:, None]
+            if sliding_window is not None:
+                mask &= j >= row_lens[:, None] - sliding_window
+            scores = jnp.where(mask[:, None, :], scores, NEG_INF)
+            m_cur = jnp.max(scores, axis=-1)
+            m_new = jnp.maximum(m, m_cur)
+            p = jnp.where(mask[:, None, :],
+                          jnp.exp(scores - m_new[..., None]), 0.0)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=-1)
+            pv = jnp.einsum("thgk,tkhd->thgd",
+                            p.reshape(T, Hkv, G, R).astype(v.dtype), v,
                             preferred_element_type=jnp.float32)
-        scores = scores.reshape(T, Hq, R)
-        scores = _softcap(scores, logit_softcap)
-        j = c0 * bs + jnp.arange(R)[None, :]             # key positions
-        mask = j < row_lens[:, None]
-        if sliding_window is not None:
-            mask &= j >= row_lens[:, None] - sliding_window
-        scores = jnp.where(mask[:, None, :], scores, NEG_INF)
-        m_cur = jnp.max(scores, axis=-1)
-        m_new = jnp.maximum(m, m_cur)
-        p = jnp.where(mask[:, None, :],
-                      jnp.exp(scores - m_new[..., None]), 0.0)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1)
-        pv = jnp.einsum("thgk,tkhd->thgd",
-                        p.reshape(T, Hkv, G, R).astype(v.dtype), v,
-                        preferred_element_type=jnp.float32)
-        o = o * alpha[..., None] + pv.reshape(T, Hq, Dk)
-        return (o, m_new, l, c0 + pg), None
+            o = o * alpha[..., None] + pv.reshape(T, Hq, Dk)
+            return (o, m_new, l, c0 + pg), None
 
-    o0 = jnp.zeros((T, Hq, Dk), jnp.float32)
-    m0 = jnp.full((T, Hq), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((T, Hq), jnp.float32)
-    (o, _, l, _), _ = jax.lax.scan(body, (o0, m0, l0, jnp.int32(0)), bt)
-    out = o / jnp.where(l == 0.0, 1.0, l)[..., None]
-    return out.astype(q.dtype)
+        o0 = jnp.zeros((T, Hq, Dk), jnp.float32)
+        m0 = jnp.full((T, Hq), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((T, Hq), jnp.float32)
+        (o, _, l, _), _ = jax.lax.scan(body, (o0, m0, l0, jnp.int32(0)), bt)
+        out = o / jnp.where(l == 0.0, 1.0, l)[..., None]
+        return out.astype(q.dtype)
 
 
 def ragged_blocked_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
@@ -234,28 +239,29 @@ def ragged_blocked_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     is finite but unspecified, and ``forward_ragged`` overlays the per-row
     dense result for decode rows (bit-identical to the decode trunk).
     """
-    T, Hq, D = q.shape
-    _, bs, Hkv, Dk = k_cache.shape
-    nblk = T // blk
-    S = blk_bt.shape[1] * bs
-    G = Hq // Hkv
-    k = k_cache[blk_bt].reshape(nblk, S, Hkv, Dk)
-    v = v_cache[blk_bt].reshape(nblk, S, Hkv, Dk)
-    k, v = _dequant_gathered(k, v, k_scale, v_scale, blk_bt, nblk, S,
-                             Hkv, q.dtype, scale_slices)
-    q_r = q.reshape(nblk, blk, Hkv, G, D)
-    scores = jnp.einsum("nbhgd,nkhd->nhgbk", q_r, k,
-                        preferred_element_type=jnp.float32) * scale
-    scores = _softcap(scores, logit_softcap)
-    j = jnp.arange(S)[None, None, :]                  # key positions
-    lens = row_lens.reshape(nblk, blk)[:, :, None]    # (nblk, blk, 1)
-    mask = j < lens
-    if sliding_window is not None:
-        mask &= j >= lens - sliding_window
-    scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("nhgbk,nkhd->nbhgd", probs.astype(v.dtype), v)
-    return out.reshape(T, Hq, Dk).astype(q.dtype)
+    with jax.named_scope(scopes.ATTN_KERNEL):
+        T, Hq, D = q.shape
+        _, bs, Hkv, Dk = k_cache.shape
+        nblk = T // blk
+        S = blk_bt.shape[1] * bs
+        G = Hq // Hkv
+        k = k_cache[blk_bt].reshape(nblk, S, Hkv, Dk)
+        v = v_cache[blk_bt].reshape(nblk, S, Hkv, Dk)
+        k, v = _dequant_gathered(k, v, k_scale, v_scale, blk_bt, nblk, S,
+                                 Hkv, q.dtype, scale_slices)
+        q_r = q.reshape(nblk, blk, Hkv, G, D)
+        scores = jnp.einsum("nbhgd,nkhd->nhgbk", q_r, k,
+                            preferred_element_type=jnp.float32) * scale
+        scores = _softcap(scores, logit_softcap)
+        j = jnp.arange(S)[None, None, :]                  # key positions
+        lens = row_lens.reshape(nblk, blk)[:, :, None]    # (nblk, blk, 1)
+        mask = j < lens
+        if sliding_window is not None:
+            mask &= j >= lens - sliding_window
+        scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("nhgbk,nkhd->nbhgd", probs.astype(v.dtype), v)
+        return out.reshape(T, Hq, Dk).astype(q.dtype)
 
 
 def chunked_prefill_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
@@ -283,68 +289,69 @@ def chunked_prefill_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     cache BEFORE this chunk; chunk_lens: (B,) valid tokens in this chunk.
     Returns (B, C, Hq, D).
     """
-    B, C, Hq, D = q.shape
-    _, block_size, Hkv, _ = k_cache.shape
-    S = block_tables.shape[1] * block_size
-    G = Hq // Hkv
-    # K/V stay in cache dtype with Hkv heads until inside the scan body —
-    # expanding to Hq heads / fp32 up front would build an n_rep x 2 larger
-    # transient than the cache itself at long context.
-    k = k_cache[block_tables].reshape(B, S, Hkv, D)
-    v = v_cache[block_tables].reshape(B, S, Hkv, D)
-    # reference/CPU path: dequantize the gathered window up front (the
-    # Pallas kernel dequantizes per-segment in VMEM instead)
-    k, v = _dequant_gathered(k, v, k_scale, v_scale, block_tables, B, S,
-                             Hkv, q.dtype, scale_slices)
+    with jax.named_scope(scopes.ATTN_KERNEL):
+        B, C, Hq, D = q.shape
+        _, block_size, Hkv, _ = k_cache.shape
+        S = block_tables.shape[1] * block_size
+        G = Hq // Hkv
+        # K/V stay in cache dtype with Hkv heads until inside the scan body —
+        # expanding to Hq heads / fp32 up front would build an n_rep x 2 larger
+        # transient than the cache itself at long context.
+        k = k_cache[block_tables].reshape(B, S, Hkv, D)
+        v = v_cache[block_tables].reshape(B, S, Hkv, D)
+        # reference/CPU path: dequantize the gathered window up front (the
+        # Pallas kernel dequantizes per-segment in VMEM instead)
+        k, v = _dequant_gathered(k, v, k_scale, v_scale, block_tables, B, S,
+                                 Hkv, q.dtype, scale_slices)
 
-    seg = min(seg_size, S)
-    n_seg = -(-S // seg)
-    pad = n_seg * seg - S
-    if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    k = k.reshape(B, n_seg, seg, Hkv, D)
-    v = v.reshape(B, n_seg, seg, Hkv, D)
+        seg = min(seg_size, S)
+        n_seg = -(-S // seg)
+        pad = n_seg * seg - S
+        if pad:
+            k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        k = k.reshape(B, n_seg, seg, Hkv, D)
+        v = v.reshape(B, n_seg, seg, Hkv, D)
 
-    # grouped-query layout: (B, C, Hkv, G, D) so the einsum contracts per
-    # kv-head without materializing repeated K/V
-    q_r = (q.astype(jnp.float32) * scale).reshape(B, C, Hkv, G, D)
-    qi = jnp.arange(C)[None, :, None]                    # query chunk index
-    q_valid = qi < chunk_lens[:, None, None]             # (B, C, 1)
+        # grouped-query layout: (B, C, Hkv, G, D) so the einsum contracts per
+        # kv-head without materializing repeated K/V
+        q_r = (q.astype(jnp.float32) * scale).reshape(B, C, Hkv, G, D)
+        qi = jnp.arange(C)[None, :, None]                    # query chunk index
+        q_valid = qi < chunk_lens[:, None, None]             # (B, C, 1)
 
-    def body(carry, seg_kv):
-        o, m, l, s0 = carry
-        ks, vs = seg_kv                                  # (B, seg, Hkv, D)
-        scores = jnp.einsum("bqhgd,bkhd->bhgqk", q_r, ks,
+        def body(carry, seg_kv):
+            o, m, l, s0 = carry
+            ks, vs = seg_kv                                  # (B, seg, Hkv, D)
+            scores = jnp.einsum("bqhgd,bkhd->bhgqk", q_r, ks,
+                                preferred_element_type=jnp.float32)
+            scores = scores.reshape(B, Hq, C, seg)
+            scores = _softcap(scores, logit_softcap)
+            j = s0 + jnp.arange(seg)[None, None, :]          # global key position
+            mask = (j <= ctx_lens[:, None, None] + qi) & q_valid & (j < S)
+            if sliding_window is not None:
+                # query at global pos ctx+qi attends keys in (pos - W, pos]
+                mask &= j > ctx_lens[:, None, None] + qi - sliding_window
+            mask = mask[:, None, :, :]                       # (B, 1, C, seg)
+            scores = jnp.where(mask, scores, NEG_INF)
+            m_cur = jnp.max(scores, axis=-1)
+            m_new = jnp.maximum(m, m_cur)
+            p = jnp.where(mask, jnp.exp(scores - m_new[..., None]), 0.0)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=-1)
+            pv = jnp.einsum("bhgqk,bkhd->bhgqd",
+                            p.reshape(B, Hkv, G, C, seg), vs,
                             preferred_element_type=jnp.float32)
-        scores = scores.reshape(B, Hq, C, seg)
-        scores = _softcap(scores, logit_softcap)
-        j = s0 + jnp.arange(seg)[None, None, :]          # global key position
-        mask = (j <= ctx_lens[:, None, None] + qi) & q_valid & (j < S)
-        if sliding_window is not None:
-            # query at global pos ctx+qi attends keys in (pos - W, pos]
-            mask &= j > ctx_lens[:, None, None] + qi - sliding_window
-        mask = mask[:, None, :, :]                       # (B, 1, C, seg)
-        scores = jnp.where(mask, scores, NEG_INF)
-        m_cur = jnp.max(scores, axis=-1)
-        m_new = jnp.maximum(m, m_cur)
-        p = jnp.where(mask, jnp.exp(scores - m_new[..., None]), 0.0)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1)
-        pv = jnp.einsum("bhgqk,bkhd->bhgqd",
-                        p.reshape(B, Hkv, G, C, seg), vs,
-                        preferred_element_type=jnp.float32)
-        o = o * alpha[..., None] + pv.reshape(B, Hq, C, D)
-        return (o, m_new, l, s0 + seg), None
+            o = o * alpha[..., None] + pv.reshape(B, Hq, C, D)
+            return (o, m_new, l, s0 + seg), None
 
-    o0 = jnp.zeros((B, Hq, C, D), jnp.float32)
-    m0 = jnp.full((B, Hq, C), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, Hq, C), jnp.float32)
-    (o, m, l, _), _ = jax.lax.scan(
-        body, (o0, m0, l0, jnp.int32(0)),
-        (k.transpose(1, 0, 2, 3, 4), v.transpose(1, 0, 2, 3, 4)))
-    out = o / jnp.where(l == 0.0, 1.0, l)[..., None]
-    return out.transpose(0, 2, 1, 3).astype(q.dtype)     # (B, C, Hq, D)
+        o0 = jnp.zeros((B, Hq, C, D), jnp.float32)
+        m0 = jnp.full((B, Hq, C), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((B, Hq, C), jnp.float32)
+        (o, m, l, _), _ = jax.lax.scan(
+            body, (o0, m0, l0, jnp.int32(0)),
+            (k.transpose(1, 0, 2, 3, 4), v.transpose(1, 0, 2, 3, 4)))
+        out = o / jnp.where(l == 0.0, 1.0, l)[..., None]
+        return out.transpose(0, 2, 1, 3).astype(q.dtype)     # (B, C, Hq, D)
 
 
 # --------------------------------------------------------------------------
@@ -435,18 +442,19 @@ def write_kv_entry(entry: dict, k: jnp.ndarray, v: jnp.ndarray,
     quantized on write and the scales scattered alongside.  Plain entries
     store in the cache dtype unchanged.  ONE switch point for every model
     trunk (prefill / chunk / verify / decode)."""
-    if "ks" in entry:
-        qk, sk = quantize_kv(k)
-        qv, sv = quantize_kv(v)
-        groups = entry["ks"].shape[-1] // SCALE_LANES
-        return {"k": write_kv_cache(entry["k"], qk, slots),
-                "v": write_kv_cache(entry["v"], qv, slots),
-                "ks": write_kv_scales(entry["ks"],
-                                      pad_scale_lanes(sk, groups), slots),
-                "vs": write_kv_scales(entry["vs"],
-                                      pad_scale_lanes(sv, groups), slots)}
-    return {"k": write_kv_cache(entry["k"], k, slots),
-            "v": write_kv_cache(entry["v"], v, slots)}
+    with jax.named_scope(scopes.ATTN_KV_WRITE):
+        if "ks" in entry:
+            qk, sk = quantize_kv(k)
+            qv, sv = quantize_kv(v)
+            groups = entry["ks"].shape[-1] // SCALE_LANES
+            return {"k": write_kv_cache(entry["k"], qk, slots),
+                    "v": write_kv_cache(entry["v"], qv, slots),
+                    "ks": write_kv_scales(entry["ks"],
+                                          pad_scale_lanes(sk, groups), slots),
+                    "vs": write_kv_scales(entry["vs"],
+                                          pad_scale_lanes(sv, groups), slots)}
+        return {"k": write_kv_cache(entry["k"], k, slots),
+                "v": write_kv_cache(entry["v"], v, slots)}
 
 
 def write_mla_entry(entry: dict, latent: jnp.ndarray,
@@ -469,18 +477,19 @@ def write_mla_entry(entry: dict, latent: jnp.ndarray,
     scale cache is (num_blocks, block_size, 2); readers expand it back to
     channel granularity via ``scale_slices`` (:func:`expand_slice_scales`).
     """
-    lat = latent[..., None, :]                     # add the 1-head axis
-    if "ks" in entry:
-        if latent_split is None:
-            raise ValueError("int8 MLA cache requires latent_split (the "
-                             "kv_lora_rank) for per-slice scales")
-        q1, s1 = quantize_kv(lat[..., :latent_split])
-        q2, s2 = quantize_kv(lat[..., latent_split:])
-        q = jnp.concatenate([q1, q2], axis=-1)
-        s = jnp.concatenate([s1, s2], axis=-1)     # (..., 2): latent, rope
-        return {"k": write_kv_cache(entry["k"], q, slots),
-                "ks": write_kv_scales(entry["ks"], s, slots)}
-    return {"k": write_kv_cache(entry["k"], lat, slots)}
+    with jax.named_scope(scopes.ATTN_KV_WRITE):
+        lat = latent[..., None, :]                     # add the 1-head axis
+        if "ks" in entry:
+            if latent_split is None:
+                raise ValueError("int8 MLA cache requires latent_split (the "
+                                 "kv_lora_rank) for per-slice scales")
+            q1, s1 = quantize_kv(lat[..., :latent_split])
+            q2, s2 = quantize_kv(lat[..., latent_split:])
+            q = jnp.concatenate([q1, q2], axis=-1)
+            s = jnp.concatenate([s1, s2], axis=-1)     # (..., 2): latent, rope
+            return {"k": write_kv_cache(entry["k"], q, slots),
+                    "ks": write_kv_scales(entry["ks"], s, slots)}
+        return {"k": write_kv_cache(entry["k"], lat, slots)}
 
 
 def expand_slice_scales(scales: jnp.ndarray,

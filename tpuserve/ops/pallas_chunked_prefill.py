@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpuserve.ops import scopes
 from tpuserve.ops.attention import SCALE_LANES, dequantize_kv
 from tpuserve.ops.pallas_paged_attention import (TARGET_GROUP_ROWS,
                                                  _clamp_to_vmem_budget,
@@ -211,68 +212,69 @@ def paged_window_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     never reads them; a caller that needs deterministic padding rows must
     mask on ``i < chunk_lens[b]`` itself.
     """
-    B, C, Hq, D = q.shape
-    num_blocks, page_size, Hkv, _ = k_cache.shape
-    max_pages = block_tables.shape[1]
-    group = Hq // Hkv
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    blk_q = min(blk_q, C)
-    pages_g = pages_per_group or max(1, -(-TARGET_GROUP_ROWS // page_size))
-    pages_g = min(pages_g, max_pages)
-    # Same VMEM clamp as the decode kernel, with the whole q block in one
-    # contraction: the (Hkv, blk_q*G, rows_g) f32 score tiles are the
-    # largest term here, so many-q-head models get a shorter page group
-    # (then a smaller q block) by rule.
-    pages_g, blk_q = _clamp_to_vmem_budget(
-        pages_g, blk_q, page_size, Hkv, D, k_cache.dtype.itemsize,
-        Hq, q.dtype.itemsize, quantized=k_scale is not None,
-        rows_per_dot=True)
+    with jax.named_scope(scopes.ATTN_KERNEL):
+        B, C, Hq, D = q.shape
+        num_blocks, page_size, Hkv, _ = k_cache.shape
+        max_pages = block_tables.shape[1]
+        group = Hq // Hkv
+        if interpret is None:
+            interpret = jax.default_backend() != "tpu"
+        blk_q = min(blk_q, C)
+        pages_g = pages_per_group or max(1, -(-TARGET_GROUP_ROWS // page_size))
+        pages_g = min(pages_g, max_pages)
+        # Same VMEM clamp as the decode kernel, with the whole q block in one
+        # contraction: the (Hkv, blk_q*G, rows_g) f32 score tiles are the
+        # largest term here, so many-q-head models get a shorter page group
+        # (then a smaller q block) by rule.
+        pages_g, blk_q = _clamp_to_vmem_budget(
+            pages_g, blk_q, page_size, Hkv, D, k_cache.dtype.itemsize,
+            Hq, q.dtype.itemsize, quantized=k_scale is not None,
+            rows_per_dot=True)
 
-    quantized = k_scale is not None
-    kernel = functools.partial(
-        _window_kernel, scale=scale, page_size=page_size, pages_g=pages_g,
-        num_kv_heads=Hkv, group=group, head_dim=D, blk_q=blk_q,
-        sliding_window=sliding_window, logit_softcap=logit_softcap)
-    if quantized:
-        base_kernel = kernel
+        quantized = k_scale is not None
+        kernel = functools.partial(
+            _window_kernel, scale=scale, page_size=page_size, pages_g=pages_g,
+            num_kv_heads=Hkv, group=group, head_dim=D, blk_q=blk_q,
+            sliding_window=sliding_window, logit_softcap=logit_softcap)
+        if quantized:
+            base_kernel = kernel
 
-        def kernel(bt, cx, ck, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
-                   k_scr, v_scr, ks_scr, vs_scr, sems):
-            return base_kernel(bt, cx, ck, q_ref, k_hbm, v_hbm, o_ref,
-                               k_scr, v_scr, sems, ks_hbm=ks_hbm,
-                               vs_hbm=vs_hbm, ks_scr=ks_scr, vs_scr=vs_scr)
+            def kernel(bt, cx, ck, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
+                       k_scr, v_scr, ks_scr, vs_scr, sems):
+                return base_kernel(bt, cx, ck, q_ref, k_hbm, v_hbm, o_ref,
+                                   k_scr, v_scr, sems, ks_hbm=ks_hbm,
+                                   vs_hbm=vs_hbm, ks_scr=ks_scr, vs_scr=vs_scr)
 
-    in_specs = [
-        pl.BlockSpec((1, blk_q, Hq, D),
-                     lambda b, qi, bt, cx, ck: (b, qi, 0, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),   # k_cache stays in HBM
-        pl.BlockSpec(memory_space=pl.ANY),   # v_cache stays in HBM
-    ]
-    scratch = [
-        pltpu.VMEM((2, pages_g, page_size, Hkv, D), k_cache.dtype),
-        pltpu.VMEM((2, pages_g, page_size, Hkv, D), v_cache.dtype),
-    ]
-    scales = ()
-    if quantized:
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
-        scratch += [pltpu.VMEM((2, pages_g, page_size, SCALE_LANES),
-                               jnp.float32)] * 2
-        scales = (k_scale, v_scale)
-    scratch.append(pltpu.SemaphoreType.DMA((4 if quantized else 2,
-                                            2, pages_g)))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, pl.cdiv(C, blk_q)),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, blk_q, Hq, D),
-                               lambda b, qi, bt, cx, ck: (b, qi, 0, 0)),
-        scratch_shapes=scratch,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=compiler_params("arbitrary", "arbitrary"),
-        interpret=interpret,
-    )(block_tables, ctx_lens, chunk_lens, q, k_cache, v_cache, *scales)
+        in_specs = [
+            pl.BlockSpec((1, blk_q, Hq, D),
+                         lambda b, qi, bt, cx, ck: (b, qi, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),   # k_cache stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # v_cache stays in HBM
+        ]
+        scratch = [
+            pltpu.VMEM((2, pages_g, page_size, Hkv, D), k_cache.dtype),
+            pltpu.VMEM((2, pages_g, page_size, Hkv, D), v_cache.dtype),
+        ]
+        scales = ()
+        if quantized:
+            in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+            scratch += [pltpu.VMEM((2, pages_g, page_size, SCALE_LANES),
+                                   jnp.float32)] * 2
+            scales = (k_scale, v_scale)
+        scratch.append(pltpu.SemaphoreType.DMA((4 if quantized else 2,
+                                                2, pages_g)))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, pl.cdiv(C, blk_q)),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, blk_q, Hq, D),
+                                   lambda b, qi, bt, cx, ck: (b, qi, 0, 0)),
+            scratch_shapes=scratch,
+        )
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            compiler_params=compiler_params("arbitrary", "arbitrary"),
+            interpret=interpret,
+        )(block_tables, ctx_lens, chunk_lens, q, k_cache, v_cache, *scales)

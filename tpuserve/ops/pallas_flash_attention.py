@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpuserve.ops import scopes
+
 #: what the kernel's custom call is called in a profiler trace (the HLO
 #: instruction's name); the benchmark's trace readers match it
 KERNEL_NAME = "_flash_prefill_attention"
@@ -119,16 +121,17 @@ def flash_prefill_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     read per PROCESS: serving jits this inside the engine's prefill
     executable, so changing it mid-process is ignored — a sweep needs
     a fresh process per value."""
-    import os
-    if blk_q is None:
-        blk_q = int(os.environ.get("TPUSERVE_FLASH_BLK_Q") or 128)
-    if blk_k is None:
-        blk_k = int(os.environ.get("TPUSERVE_FLASH_BLK_K") or 128)
-    return _flash_prefill_attention(q, k, v, prompt_lens, scale=scale,
-                                    blk_q=blk_q, blk_k=blk_k,
-                                    interpret=interpret,
-                                    sliding_window=sliding_window,
-                                    logit_softcap=logit_softcap)
+    with jax.named_scope(scopes.ATTN_KERNEL):
+        import os
+        if blk_q is None:
+            blk_q = int(os.environ.get("TPUSERVE_FLASH_BLK_Q") or 128)
+        if blk_k is None:
+            blk_k = int(os.environ.get("TPUSERVE_FLASH_BLK_K") or 128)
+        return _flash_prefill_attention(q, k, v, prompt_lens, scale=scale,
+                                        blk_q=blk_q, blk_k=blk_k,
+                                        interpret=interpret,
+                                        sliding_window=sliding_window,
+                                        logit_softcap=logit_softcap)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "blk_q", "blk_k",

@@ -61,6 +61,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpuserve.ops import scopes
 from tpuserve.ops.attention import SCALE_LANES
 
 #: what the kernel's custom call is called in a profiler trace (the HLO
@@ -455,28 +456,29 @@ def paged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     ``pages_per_group`` / ``seqs_per_program`` override them for the
     tests (a tile is then the largest whole divisor of the group up to
     ``DECODE_TILE_COLUMNS`` key columns)."""
-    page_size = k_cache.shape[1]
-    max_pages = block_tables.shape[1]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    pages_g, pages_t, seqs_pp = decode_tiling(page_size, k_cache.shape[2],
-                                              max_pages, q.shape[0])
-    if pages_per_group:
-        pages_g = min(pages_per_group, max_pages)
-    if seqs_per_program:
-        seqs_pp = min(seqs_per_program, q.shape[0])
-    pages_g, seqs_pp = _clamp_to_vmem_budget(
-        pages_g, seqs_pp, page_size, k_cache.shape[2], k_cache.shape[3],
-        k_cache.dtype.itemsize, q.shape[1], q.dtype.itemsize,
-        quantized=k_scale is not None)
-    pages_t = _tile_pages(pages_g, pages_t)
-    scales = () if k_scale is None else (k_scale, v_scale)
-    return _paged_decode_attention(q, k_cache, v_cache, block_tables,
-                                   seq_lens, scales, scale=scale,
-                                   interpret=interpret, pages_g=pages_g,
-                                   pages_t=pages_t, seqs_pp=seqs_pp,
-                                   sliding_window=sliding_window,
-                                   logit_softcap=logit_softcap)
+    with jax.named_scope(scopes.ATTN_KERNEL):
+        page_size = k_cache.shape[1]
+        max_pages = block_tables.shape[1]
+        if interpret is None:
+            interpret = jax.default_backend() != "tpu"
+        pages_g, pages_t, seqs_pp = decode_tiling(page_size, k_cache.shape[2],
+                                                  max_pages, q.shape[0])
+        if pages_per_group:
+            pages_g = min(pages_per_group, max_pages)
+        if seqs_per_program:
+            seqs_pp = min(seqs_per_program, q.shape[0])
+        pages_g, seqs_pp = _clamp_to_vmem_budget(
+            pages_g, seqs_pp, page_size, k_cache.shape[2], k_cache.shape[3],
+            k_cache.dtype.itemsize, q.shape[1], q.dtype.itemsize,
+            quantized=k_scale is not None)
+        pages_t = _tile_pages(pages_g, pages_t)
+        scales = () if k_scale is None else (k_scale, v_scale)
+        return _paged_decode_attention(q, k_cache, v_cache, block_tables,
+                                       seq_lens, scales, scale=scale,
+                                       interpret=interpret, pages_g=pages_g,
+                                       pages_t=pages_t, seqs_pp=seqs_pp,
+                                       sliding_window=sliding_window,
+                                       logit_softcap=logit_softcap)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret",

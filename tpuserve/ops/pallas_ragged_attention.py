@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpuserve.ops import scopes
 from tpuserve.ops.attention import SCALE_LANES, dequantize_kv
 from tpuserve.ops.pallas_paged_attention import (TARGET_GROUP_ROWS,
                                                  _clamp_to_vmem_budget,
@@ -374,82 +375,83 @@ def ragged_paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     decode part: half the kernel to trace, lower and compile for each
     rung of that route's ladder.
     """
-    T, Hq, D = q.shape
-    num_blocks, page_size, Hkv, _ = k_cache.shape
-    max_pages = block_tables.shape[1]
-    group = Hq // Hkv
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    blk = ragged_block(blk_q)
-    if T % blk:
-        raise ValueError(f"flat token count {T} is not a multiple of the "
-                         f"ragged block {blk} (engine layout contract)")
-    pages_g = pages_per_group or max(1, -(-TARGET_GROUP_ROWS // page_size))
-    pages_g = min(pages_g, max_pages)
-    # blk is a layout contract with the host packing — only pages_g may
-    # shrink to fit VMEM (it only shortens the DMA pipeline).  If the
-    # clamp wanted to shrink blk itself (many-query-head models whose
-    # q/out blocks alone bust the budget), fail LOUDLY: silently running
-    # over budget crashes Mosaic allocation with a much worse message.
-    pages_g, blk_clamped = _clamp_to_vmem_budget(
-        pages_g, blk, page_size, Hkv, D, k_cache.dtype.itemsize,
-        Hq, q.dtype.itemsize, quantized=k_scale is not None,
-        rows_per_dot=True)
-    if blk_clamped != blk:
-        raise ValueError(
-            f"ragged block {blk} needs more VMEM than the budget allows "
-            f"for this model shape (Hq={Hq}, D={D}); set "
-            f"TPUSERVE_RAGGED_BLOCK={blk_clamped} (power of two) so the "
-            "engine packs the flat stream at a size that fits")
+    with jax.named_scope(scopes.ATTN_KERNEL):
+        T, Hq, D = q.shape
+        num_blocks, page_size, Hkv, _ = k_cache.shape
+        max_pages = block_tables.shape[1]
+        group = Hq // Hkv
+        if interpret is None:
+            interpret = jax.default_backend() != "tpu"
+        blk = ragged_block(blk_q)
+        if T % blk:
+            raise ValueError(f"flat token count {T} is not a multiple of the "
+                             f"ragged block {blk} (engine layout contract)")
+        pages_g = pages_per_group or max(1, -(-TARGET_GROUP_ROWS // page_size))
+        pages_g = min(pages_g, max_pages)
+        # blk is a layout contract with the host packing — only pages_g may
+        # shrink to fit VMEM (it only shortens the DMA pipeline).  If the
+        # clamp wanted to shrink blk itself (many-query-head models whose
+        # q/out blocks alone bust the budget), fail LOUDLY: silently running
+        # over budget crashes Mosaic allocation with a much worse message.
+        pages_g, blk_clamped = _clamp_to_vmem_budget(
+            pages_g, blk, page_size, Hkv, D, k_cache.dtype.itemsize,
+            Hq, q.dtype.itemsize, quantized=k_scale is not None,
+            rows_per_dot=True)
+        if blk_clamped != blk:
+            raise ValueError(
+                f"ragged block {blk} needs more VMEM than the budget allows "
+                f"for this model shape (Hq={Hq}, D={D}); set "
+                f"TPUSERVE_RAGGED_BLOCK={blk_clamped} (power of two) so the "
+                "engine packs the flat stream at a size that fits")
 
-    quantized = k_scale is not None
-    kernel = functools.partial(
-        _ragged_kernel, scale=scale, page_size=page_size, pages_g=pages_g,
-        num_kv_heads=Hkv, group=group, head_dim=D, blk_q=blk,
-        sliding_window=sliding_window, logit_softcap=logit_softcap,
-        decode_rows=decode_rows)
-    if quantized:
-        base_kernel = kernel
+        quantized = k_scale is not None
+        kernel = functools.partial(
+            _ragged_kernel, scale=scale, page_size=page_size, pages_g=pages_g,
+            num_kv_heads=Hkv, group=group, head_dim=D, blk_q=blk,
+            sliding_window=sliding_window, logit_softcap=logit_softcap,
+            decode_rows=decode_rows)
+        if quantized:
+            base_kernel = kernel
 
-        def kernel(bt, kl, qs, ql, mt, bs_, q_ref, k_hbm, v_hbm, ks_hbm,
-                   vs_hbm, o_ref, k_scr, v_scr, ks_scr, vs_scr, sems):
-            return base_kernel(bt, kl, qs, ql, mt, bs_, q_ref, k_hbm,
-                               v_hbm, o_ref, k_scr, v_scr, sems,
-                               ks_hbm=ks_hbm, vs_hbm=vs_hbm,
-                               ks_scr=ks_scr, vs_scr=vs_scr)
+            def kernel(bt, kl, qs, ql, mt, bs_, q_ref, k_hbm, v_hbm, ks_hbm,
+                       vs_hbm, o_ref, k_scr, v_scr, ks_scr, vs_scr, sems):
+                return base_kernel(bt, kl, qs, ql, mt, bs_, q_ref, k_hbm,
+                                   v_hbm, o_ref, k_scr, v_scr, sems,
+                                   ks_hbm=ks_hbm, vs_hbm=vs_hbm,
+                                   ks_scr=ks_scr, vs_scr=vs_scr)
 
-    in_specs = [
-        pl.BlockSpec((blk, Hq, D),
-                     lambda p, bt, kl, qs, ql, mt, bs_: (p, 0, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),   # k_cache stays in HBM
-        pl.BlockSpec(memory_space=pl.ANY),   # v_cache stays in HBM
-    ]
-    scratch = [
-        pltpu.VMEM((2, pages_g, page_size, Hkv, D), k_cache.dtype),
-        pltpu.VMEM((2, pages_g, page_size, Hkv, D), v_cache.dtype),
-    ]
-    scales = ()
-    if quantized:
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
-        scratch += [pltpu.VMEM((2, pages_g, page_size, SCALE_LANES),
-                               jnp.float32)] * 2
-        scales = (k_scale, v_scale)
-    scratch.append(pltpu.SemaphoreType.DMA((4 if quantized else 2,
-                                            2, pages_g)))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(T // blk,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (blk, Hq, D), lambda p, bt, kl, qs, ql, mt, bs_: (p, 0, 0)),
-        scratch_shapes=scratch,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=compiler_params("arbitrary"),
-        interpret=interpret,
-        name=KERNEL_NAME,
-    )(block_tables, kv_lens, q_starts, q_lens, meta, blk_seq,
-      q, k_cache, v_cache, *scales)
+        in_specs = [
+            pl.BlockSpec((blk, Hq, D),
+                         lambda p, bt, kl, qs, ql, mt, bs_: (p, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),   # k_cache stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # v_cache stays in HBM
+        ]
+        scratch = [
+            pltpu.VMEM((2, pages_g, page_size, Hkv, D), k_cache.dtype),
+            pltpu.VMEM((2, pages_g, page_size, Hkv, D), v_cache.dtype),
+        ]
+        scales = ()
+        if quantized:
+            in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+            scratch += [pltpu.VMEM((2, pages_g, page_size, SCALE_LANES),
+                                   jnp.float32)] * 2
+            scales = (k_scale, v_scale)
+        scratch.append(pltpu.SemaphoreType.DMA((4 if quantized else 2,
+                                                2, pages_g)))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(T // blk,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (blk, Hq, D), lambda p, bt, kl, qs, ql, mt, bs_: (p, 0, 0)),
+            scratch_shapes=scratch,
+        )
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            compiler_params=compiler_params("arbitrary"),
+            interpret=interpret,
+            name=KERNEL_NAME,
+        )(block_tables, kv_lens, q_starts, q_lens, meta, blk_seq,
+          q, k_cache, v_cache, *scales)

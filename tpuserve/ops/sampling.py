@@ -16,6 +16,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from tpuserve.ops import scopes
+
 NEG_INF = -1e30
 
 
@@ -204,10 +206,11 @@ def compute_logprobs(logits: jnp.ndarray, chosen: jnp.ndarray, top_n: int):
     logits: (B, V); chosen: (B,) int32.  Returns (chosen_lp (B,),
     top_ids (B, top_n), top_lps (B, top_n)).
     """
-    lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    chosen_lp = jnp.take_along_axis(lp, chosen[:, None].astype(jnp.int32), axis=-1)[:, 0]
-    top_lps, top_ids = jax.lax.top_k(lp, top_n)
-    return chosen_lp, top_ids.astype(jnp.int32), top_lps
+    with jax.named_scope(scopes.SAMPLE):
+        lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        chosen_lp = jnp.take_along_axis(lp, chosen[:, None].astype(jnp.int32), axis=-1)[:, 0]
+        top_lps, top_ids = jax.lax.top_k(lp, top_n)
+        return chosen_lp, top_ids.astype(jnp.int32), top_lps
 
 
 def spec_accept_sampled(logits: jnp.ndarray, draft_next: jnp.ndarray,
@@ -242,51 +245,52 @@ def spec_accept_sampled(logits: jnp.ndarray, draft_next: jnp.ndarray,
     whose draft list is shorter, at its own chunk end, which the host
     indexes by its known draft length).
     """
-    B, K, V = logits.shape
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)       # (B, K)
-    temp = jnp.maximum(temperature, 1e-6)[:, None, None]
-    masked = truncated_scaled_logits(
-        logits.astype(jnp.float32) / temp,
-        jnp.broadcast_to(top_k[:, None], (B, K)),
-        jnp.broadcast_to(top_p[:, None], (B, K)),
-        None if min_p is None
-        else jnp.broadcast_to(min_p[:, None], (B, K)))           # (B, K, V)
-    p = jax.nn.softmax(masked, axis=-1)
+    with jax.named_scope(scopes.SAMPLE):
+        B, K, V = logits.shape
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)       # (B, K)
+        temp = jnp.maximum(temperature, 1e-6)[:, None, None]
+        masked = truncated_scaled_logits(
+            logits.astype(jnp.float32) / temp,
+            jnp.broadcast_to(top_k[:, None], (B, K)),
+            jnp.broadcast_to(top_p[:, None], (B, K)),
+            None if min_p is None
+            else jnp.broadcast_to(min_p[:, None], (B, K)))           # (B, K, V)
+        p = jax.nn.softmax(masked, axis=-1)
 
-    # fold the row position into each key (window_sample's convention),
-    # then DISTINCT subkeys per (row, position) for the acceptance
-    # uniform and the resample gumbel — sharing one key would correlate
-    # the accept decision with the replacement draw
-    def row_keys(key):
-        return jax.vmap(lambda s: jax.random.fold_in(key, s))(jnp.arange(K))
-    keys2 = jax.vmap(row_keys)(keys)                             # (B, K, 2)
-    u_keys = jax.vmap(jax.vmap(lambda k: jax.random.fold_in(k, 0)))(keys2)
-    g_keys = jax.vmap(jax.vmap(lambda k: jax.random.fold_in(k, 1)))(keys2)
-    u = jax.vmap(jax.vmap(lambda k: jax.random.uniform(k, ())))(u_keys)
-    gumbel = -jnp.log(-jnp.log(jax.vmap(jax.vmap(
-        lambda k: jax.random.uniform(k, (V,), jnp.float32,
-                                     minval=1e-7, maxval=1.0)))(g_keys)))
+        # fold the row position into each key (window_sample's convention),
+        # then DISTINCT subkeys per (row, position) for the acceptance
+        # uniform and the resample gumbel — sharing one key would correlate
+        # the accept decision with the replacement draw
+        def row_keys(key):
+            return jax.vmap(lambda s: jax.random.fold_in(key, s))(jnp.arange(K))
+        keys2 = jax.vmap(row_keys)(keys)                             # (B, K, 2)
+        u_keys = jax.vmap(jax.vmap(lambda k: jax.random.fold_in(k, 0)))(keys2)
+        g_keys = jax.vmap(jax.vmap(lambda k: jax.random.fold_in(k, 1)))(keys2)
+        u = jax.vmap(jax.vmap(lambda k: jax.random.uniform(k, ())))(u_keys)
+        gumbel = -jnp.log(-jnp.log(jax.vmap(jax.vmap(
+            lambda k: jax.random.uniform(k, (V,), jnp.float32,
+                                         minval=1e-7, maxval=1.0)))(g_keys)))
 
-    # acceptance: u < p̃(d) at positions 0..K-2
-    d = draft_next.astype(jnp.int32)
-    p_draft = jnp.take_along_axis(p[:, :-1, :], d[..., None],
-                                  axis=-1)[..., 0]               # (B, K-1)
-    accept = u[:, :-1] < p_draft
+        # acceptance: u < p̃(d) at positions 0..K-2
+        d = draft_next.astype(jnp.int32)
+        p_draft = jnp.take_along_axis(p[:, :-1, :], d[..., None],
+                                      axis=-1)[..., 0]               # (B, K-1)
+        accept = u[:, :-1] < p_draft
 
-    # resample: p̃ with the draft token's mass removed — but ONLY at real
-    # draft positions (j < chunk_len-1).  Padding rows' zero-filled
-    # "draft" would otherwise zero token id 0's mass in the bonus
-    # distribution at every chunk end (round-5 review).  Gumbel-max over
-    # masked logits == categorical over the renormalised distribution.
-    is_draft = (jnp.arange(K - 1)[None, :]
-                < (chunk_lens - 1)[:, None])                     # (B, K-1)
-    drop = jnp.zeros((B, K, V), bool).at[
-        jnp.arange(B)[:, None], jnp.arange(K - 1)[None, :], d].set(
-        is_draft)
-    resample_logits = jnp.where(drop, NEG_INF, masked)
-    sampled = jnp.argmax(resample_logits + gumbel, axis=-1).astype(jnp.int32)
-    # degenerate rows: temperature <= 0 → greedy acceptance + greedy pred
-    greedy_row = (temperature <= 0.0)[:, None]
-    accept = jnp.where(greedy_row, d == greedy[:, :-1], accept)
-    pred = jnp.where(greedy_row, greedy, sampled)
-    return accept, pred
+        # resample: p̃ with the draft token's mass removed — but ONLY at real
+        # draft positions (j < chunk_len-1).  Padding rows' zero-filled
+        # "draft" would otherwise zero token id 0's mass in the bonus
+        # distribution at every chunk end (round-5 review).  Gumbel-max over
+        # masked logits == categorical over the renormalised distribution.
+        is_draft = (jnp.arange(K - 1)[None, :]
+                    < (chunk_lens - 1)[:, None])                     # (B, K-1)
+        drop = jnp.zeros((B, K, V), bool).at[
+            jnp.arange(B)[:, None], jnp.arange(K - 1)[None, :], d].set(
+            is_draft)
+        resample_logits = jnp.where(drop, NEG_INF, masked)
+        sampled = jnp.argmax(resample_logits + gumbel, axis=-1).astype(jnp.int32)
+        # degenerate rows: temperature <= 0 → greedy acceptance + greedy pred
+        greedy_row = (temperature <= 0.0)[:, None]
+        accept = jnp.where(greedy_row, d == greedy[:, :-1], accept)
+        pred = jnp.where(greedy_row, greedy, sampled)
+        return accept, pred

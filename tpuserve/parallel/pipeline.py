@@ -179,16 +179,14 @@ def _decode_layer(h, lp, entry, cfg, positions, slots, block_tables,
     (reference attention; Pallas-under-pp is future work — the kernel
     call sites are shared, so it slots in here)."""
     sw = cfg.layer_window(0)
-    hn = tf._norm(h, lp["attn_norm"], cfg)
-    q, k, v = tf._qkv(hn, lp, cfg, positions, 0)
+    q, k, v, _ = tf._qkv(h, lp, cfg, positions, 0)
     entry = attn_ops.write_kv_entry(entry, k, v, slots)
     out = attn_ops.paged_decode_attention(
         q, entry["k"], entry["v"], block_tables, seq_lens, cfg.attn_scale,
         k_scale=entry.get("ks"), v_scale=entry.get("vs"),
         sliding_window=sw, logit_softcap=cfg.attn_logit_softcapping)
-    out = out.reshape(h.shape[0], cfg.q_size)
-    h = h + tf._attn_residual(out, lp, cfg)
-    h = h + tf._mlp_residual(h, lp, cfg)
+    h = tf._attn_residual(h, out, lp, cfg)
+    h = tf._mlp_residual(h, lp, cfg)
     return h, entry
 
 
@@ -196,15 +194,13 @@ def _prefill_layer(h, lp, entry, cfg, positions, prompt_lens, slots):
     """One prefill layer: write the prompt's KV, attend causally within
     the (micro)batch — transformer.prefill's inner loop."""
     sw = cfg.layer_window(0)
-    hn = tf._norm(h, lp["attn_norm"], cfg)
-    q, k, v = tf._qkv(hn, lp, cfg, positions, 0)
+    q, k, v, _ = tf._qkv(h, lp, cfg, positions, 0)
     entry = attn_ops.write_kv_entry(entry, k, v, slots)
     out = attn_ops.prefill_attention(
         q, k, v, prompt_lens, cfg.attn_scale, sliding_window=sw,
         logit_softcap=cfg.attn_logit_softcapping)
-    out = out.reshape(*h.shape[:-1], cfg.q_size)
-    h = h + tf._attn_residual(out, lp, cfg)
-    h = h + tf._mlp_residual(h, lp, cfg)
+    h = tf._attn_residual(h, out, lp, cfg)
+    h = tf._mlp_residual(h, lp, cfg)
     return h, entry
 
 

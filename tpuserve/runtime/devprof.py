@@ -76,14 +76,11 @@ class DeviceProfiler:
         # kind — the measurable device time of the pipelined design
         self.sync_s: dict[str, float] = defaultdict(float)
         self.sync_counts: dict[str, int] = defaultdict(int)
-        # (kind, bucket key) -> [compile_ms, hits, est_bytes]
+        # (kind, bucket key) -> [compile_ms, hits]
         self.ladder: dict[tuple, list] = {}
         self.compiles = 0
         self.compile_s = 0.0
         self.cycles = 0
-        # per-token activation-bytes hint (set_model_hints); 0 = no
-        # estimate, ladder rows carry est_bytes=0
-        self._act_bytes_per_token = 0
         # HBM watermark (set_hbm): static reconciliation of weights /
         # KV reservation / backend memory stats, refreshed at engine
         # construction (the reservation is static by design — paged KV
@@ -117,8 +114,7 @@ class DeviceProfiler:
             # first dispatch of this (kind, bucket): the blocking XLA
             # compile ran inside this bracket — that wall IS the
             # compile cost
-            self.ladder[lk] = [round(dt * 1000, 3), 1,
-                               self.estimate_bytes(lk[1])]
+            self.ladder[lk] = [round(dt * 1000, 3), 1]
             self.compiles += 1
             self.compile_s += dt
         else:
@@ -136,26 +132,6 @@ class DeviceProfiler:
         self.cycles += 1
 
     # ---- facts (engine construction / capture paths) -------------------
-
-    def set_model_hints(self, *, act_bytes_per_token: int) -> None:
-        """Per-padded-token activation-bytes estimate for ladder rows —
-        a hint, not an XLA memory analysis (which jit does not expose
-        per cached executable); good enough to rank which buckets are
-        worth retiring."""
-        self._act_bytes_per_token = max(0, int(act_bytes_per_token))
-
-    def estimate_bytes(self, key: tuple) -> int:
-        """Estimated live-activation bytes for a bucket key whose first
-        element is the primary dispatch shape (rows x tokens...)."""
-        if not self._act_bytes_per_token or not key:
-            return 0
-        shape = key[0]
-        if not isinstance(shape, tuple):
-            return 0
-        n = 1
-        for d in shape:
-            n *= max(1, int(d))
-        return n * self._act_bytes_per_token
 
     def set_hbm(self, *, weights: int, kv_reserved: int, limit: int,
                 num_blocks: int, block_bytes: int,
@@ -228,8 +204,7 @@ class DeviceProfiler:
         items = sorted(self.ladder.items(),
                        key=lambda kv: kv[1][1], reverse=True)
         rows = [{"kind": kind, "bucket": repr(key),
-                 "compile_ms": ent[0], "hits": ent[1],
-                 "est_bytes": ent[2]}
+                 "compile_ms": ent[0], "hits": ent[1]}
                 for (kind, key), ent in items[:MAX_LADDER_SNAPSHOT]]
         return {
             "retained": len(self.ladder),

@@ -1045,11 +1045,6 @@ class Engine:
                              limit=self._device_hbm_limit(),
                              num_blocks=self.cache_cfg.num_blocks,
                              block_bytes=block_bytes, in_use=in_use)
-        # ladder footprint estimates: activations scale with tokens ×
-        # hidden; 3 transient buffers of f32 hidden per token is a
-        # deliberately rough upper-ish bound (documented as an estimate)
-        self.devprof.set_model_hints(
-            act_bytes_per_token=int(self.model_cfg.hidden_size) * 4 * 3)
 
     def _auto_num_blocks(self, mesh) -> int:
         """Size the paged KV cache to the device memory the weights left

@@ -96,13 +96,6 @@ class HostPhaseProfiler:
     """Accumulates wall seconds per span name; ``cycles`` is bumped once
     per engine cycle (the denominator for ms-per-cycle)."""
 
-    # canonical phase names, in report order
-    PHASES = ("schedule", "block", "dispatch", "detokenize", "flush")
-    # the phases that are PURE host time (dispatch covers array build +
-    # async dispatch; flush is the device->host syncs, i.e. mostly device
-    # wait) — "host_ms_per_cycle" sums only these
-    HOST_PHASES = ("schedule", "block", "detokenize")
-
     def __init__(self):
         self.seconds: dict[str, float] = defaultdict(float)
         self.counts: dict[str, int] = defaultdict(int)
@@ -122,27 +115,6 @@ class HostPhaseProfiler:
         self.seconds.clear()
         self.counts.clear()
         self.cycles = 0
-
-    def report(self) -> dict:
-        """Per-span breakdown: ms per engine cycle plus totals.  Spans
-        nest, so the rows do not add up to a cycle."""
-        cycles = max(self.cycles, 1)
-        names = list(self.PHASES) + sorted(set(self.seconds)
-                                           - set(self.PHASES))
-        phases = {name: {
-            "ms_per_cycle": round(1000 * self.seconds[name] / cycles, 4),
-            "total_ms": round(1000 * self.seconds[name], 2),
-            "calls": self.counts[name],
-        } for name in names}
-        host = sum(self.seconds[p] for p in self.HOST_PHASES
-                   if p in self.seconds)
-        return {
-            "cycles": self.cycles,
-            # schedule + block accounting + detokenize/emit — the phases
-            # the native/batched host path migrated off per-request Python
-            "host_ms_per_cycle": round(1000 * host / cycles, 4),
-            "phases": phases,
-        }
 
 
 # module singleton: the engine loop is single-threaded, and profile runs
