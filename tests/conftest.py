@@ -63,6 +63,25 @@ def fp32_tiny_opt():
     return dataclasses.replace(get_model_config("tiny-opt"), dtype="float32")
 
 
+@pytest.fixture
+def row_scatter_only(monkeypatch):
+    """Call it to force every prefill trunk back onto the row scatter
+    (tests only: the program has no such switch: ops/attention.py
+    kv_stream_by_page decides from what is static).  Programs traced
+    before are dropped, and none traced that way is left behind."""
+    from tpuserve.ops import attention
+    from tpuserve.runtime import engine
+
+    def force():
+        for mod in (attention, engine):
+            monkeypatch.setattr(mod, "kv_stream_by_page",
+                                lambda *a, **kw: False)
+        jax.clear_caches()
+    yield force
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
 # PR 35's test pins its four ``per_layer`` entries to the END of the list,
 # and the benchmark's contract puts every later PR's entries after them.
 # The file is the benchmark's and is not a program PR's to edit, and entries

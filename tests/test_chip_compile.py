@@ -247,6 +247,42 @@ def test_the_state_update_kernel_compiles_for_v5e(rows, one_chip):
     assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
 
 
+@pytest.mark.parametrize("rows", [128, 2048, 8192])
+@pytest.mark.parametrize("kv_heads", [8, 4])
+def test_the_page_writer_compiles_for_v5e(kv_heads, rows, one_chip):
+    """``_paged_kv_write`` at the four configurations' KV widths (8 and 4
+    heads of 128), from one ragged block to the packed ladder's top and at
+    the chunk size: compiled, named, and in place: both caches' bytes are
+    aliased from input to output and the program holds no other buffer of
+    their size (the benchmark's caches fill the chip: a copy cannot
+    exist), and the stream's rows reach the kernel without a relayout."""
+    import re
+
+    from tpuserve.ops.pallas_kv_write import KERNEL_NAME, paged_kv_write
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert KERNEL_NAME == "_paged_kv_write"
+    bf16 = jnp.bfloat16
+    page = S((NUM_BLOCKS, PAGE, kv_heads, 128), bf16)
+    new = S((rows, kv_heads, 128), bf16)
+    compiled = jax.jit(
+        lambda kc, vc, k, v, slots: paged_kv_write(kc, vc, k, v, slots,
+                                                   interpret=False),
+        donate_argnums=(0, 1)).lower(
+            page, page, new, new, S((rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert re.search(rf"%{KERNEL_NAME}(\.\d+)? = [^\n]*custom-call\([^\n]*"
+                     r"tpu_custom_call", text)
+    assert "scatter" not in text
+    cache_bytes = 2 * NUM_BLOCKS * PAGE * kv_heads * 128 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == cache_bytes
+    # what else the program holds: the zeroed rows, never a page array
+    assert mem.temp_size_in_bytes <= 2 * rows * kv_heads * 128 * 2 + 65536
+
+
 # (rows, contraction, output columns) of the expert layer's grouped
 # products at Mellum2-12B-A2.5B's widths (64 experts of width 896 on a
 # hidden size of 2,304, 8 a token): the smallest and the largest decode

@@ -217,3 +217,31 @@ def test_preempted_request_reprefills_from_cache(fp32_cfg):
     for r in outs:
         assert len(r.output_token_ids) == 10
     assert eng.block_manager.num_seqs() == 0
+
+
+def test_a_chunked_prompt_by_page_serves_the_row_scatters_tokens(
+        fp32_cfg, row_scatter_only):
+    """A prompt of two chunks (the second ends mid-page) and eight decode
+    steps, Pallas kernels on: greedy tokens with the chunks' K and V
+    written a page at a time and with the row scatter are the same."""
+    prompt = np.random.default_rng(5).integers(1, 200, size=27).tolist()
+    params = SamplingParams(max_tokens=9, temperature=0.0, ignore_eos=True)
+    runs = []
+    for by_page in (True, False):
+        if not by_page:
+            row_scatter_only()
+        eng = Engine(
+            EngineConfig(model="tiny-qwen3", attn_impl="pallas",
+                         cache=CacheConfig(block_size=4, num_blocks=128,
+                                           max_blocks_per_seq=24,
+                                           dtype="float32"),
+                         scheduler=SchedulerConfig(max_num_seqs=4,
+                                                   prefill_chunk_size=16),
+                         enable_prefix_caching=False),
+            model_cfg=fp32_cfg)
+        out = eng.generate([prompt], params)[0]
+        assert eng.stats.num_prefill_steps == 2             # 16 + 11
+        assert eng.stats.prefill_tokens_total == 27
+        assert eng.stats.prefill_kv_tokens_paged_total == 27 * by_page
+        runs.append(out.output_token_ids)
+    assert len(runs[0]) == 9 and runs[0] == runs[1]

@@ -305,3 +305,27 @@ def test_bucket_ladder():
         assert t >= r and t % 128 == 0
         # the ladder's own padding is under a third of a dispatch
         assert (t - -(-r // 128) * 128) * 3 < t or r <= 128
+
+
+# ---- the K and V of a packed prefill go out a page at a time ------------
+
+def test_the_page_write_serves_the_row_scatters_tokens(row_scatter_only):
+    """A packed batch of three prompts (one ends mid-page, one fills its
+    pages exactly) and eight decode steps over the written pages: greedy
+    tokens with the page write and with the row scatter are the same."""
+    params = dataclasses.replace(GREEDY, max_tokens=9)
+    runs = []
+    for by_page in (True, False):
+        if not by_page:
+            row_scatter_only()
+        eng = _engine(True, attn_impl="pallas")
+        for p in _prompts(np.random.default_rng(11), [21, 8, 34]):
+            eng.add_request(prompt_token_ids=p, params=params)
+        outs = _drain(eng)
+        assert eng.stats.prefill_packed_steps == 1
+        assert eng.stats.prefill_tokens_total == 21 + 8 + 34
+        # the counter: every prompt token by page, or none
+        assert eng.stats.prefill_kv_tokens_paged_total == 63 * by_page
+        runs.append(list(outs.values()))
+    assert all(len(toks) == 9 for toks in runs[0])
+    assert runs[0] == runs[1]

@@ -894,10 +894,15 @@ def prefill_chunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     """
     with jax.named_scope(scopes.CHUNK):
         tally = _moe_tally(cfg)
+        # a chunk starts at a whole number of cached blocks (the engine's
+        # chunks before it were full ones): where its bucket is whole
+        # pages, its K and V go out a page at a time
         h, new_cache, new_ssm = _chunk_trunk(
             params, cfg, tokens, ctx_lens, chunk_lens, slot_ids, block_tables,
             kv_cache, ad, ssm, seats, attn_impl=attn_impl, mesh=mesh,
-            tally=tally, moe_dense=moe_dense)
+            tally=tally, moe_dense=moe_dense,
+            aligned=attn_ops.kv_stream_by_page(kv_cache[0], tokens.shape[1],
+                                               attn_impl, mesh))
         last_idx = jnp.maximum(chunk_lens - 1, 0)
         return _with_ssm(_unembed(params, cfg, h, last_idx), new_cache, ssm,
                          new_ssm,
@@ -1024,11 +1029,13 @@ def _chunk_trunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                  kv_cache: list, ad: jnp.ndarray | None = None,
                  ssm: list | None = None, seats: jnp.ndarray | None = None,
                  *, attn_impl: str = "reference", mesh=None,
-                 tally: list | None = None, moe_dense: bool = False):
+                 tally: list | None = None, moe_dense: bool = False,
+                 aligned: bool = False):
     """Shared layer loop for cache-relative windows: writes the window's KV
     and attends against cached context + causal-within-window.  Used by both
-    prefill_chunk (last-row logits) and decode_verify (all-row argmax).
-    Returns (h, kv_cache, seat pool or None)."""
+    prefill_chunk (last-row logits; ``aligned``: its rows are whole pages
+    in order, ops/attention.py write_kv_entry) and decode_verify (all-row
+    argmax).  Returns (h, kv_cache, seat pool or None)."""
     positions = ctx_lens[:, None] + jnp.arange(tokens.shape[1])[None, :]
     h = _embed(params, cfg, tokens, positions)
     scale = cfg.attn_scale
@@ -1055,7 +1062,7 @@ def _chunk_trunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
             continue
         q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)
-        entry = attn_ops.write_kv_entry(kv_cache[li], k, v, slot_ids)
+        entry = attn_ops.write_kv_entry(kv_cache[li], k, v, slot_ids, aligned)
         new_cache.append(entry)
         ck, cv = entry["k"], entry["v"]
         ks, vs = entry.get("ks"), entry.get("vs")
@@ -1616,6 +1623,11 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         new_cache = []
         new_ssm = []
         tally = _moe_tally(cfg)
+        # a packed prefill starts every prompt on a ragged-block boundary
+        # at a whole number of cached blocks (Engine._pack_ragged): where
+        # that block is whole pages, its K and V go out a page at a time
+        aligned = not decode_rows and attn_ops.kv_stream_by_page(
+            kv_cache[0], ragged_blk, attn_impl)
         for li, lp in enumerate(params["layers"]):
             sw = cfg.layer_window(li)
             if cfg.is_mla:
@@ -1641,7 +1653,8 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
                 continue
             q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)  # (T, H*, D)
-            entry = attn_ops.write_kv_entry(kv_cache[li], k, v, slot_ids)
+            entry = attn_ops.write_kv_entry(kv_cache[li], k, v, slot_ids,
+                                            aligned)
             new_cache.append(entry)
             ck, cv = entry["k"], entry["v"]
             ks, vs = entry.get("ks"), entry.get("vs")

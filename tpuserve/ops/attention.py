@@ -434,15 +434,41 @@ def write_kv_scales(scale_cache: jnp.ndarray, scales: jnp.ndarray,
     return flat.reshape(nb, bs, lanes)
 
 
+def kv_stream_by_page(entry: dict, unit: int, attn_impl: str,
+                      mesh=None) -> bool:
+    """Whether a prefill stream goes into ``entry`` a page at a time
+    (:func:`write_kv_entry` with ``aligned=True``), from what is static at
+    trace time: the Pallas kernels are on and unsharded, the entry is
+    plain (no ``ks``/``vs`` scale arrays: int8 pages quantize a row at a
+    time) with K and V pages (not MLA's latent), and ``unit`` — the rows
+    between two places where the stream may start a prompt or end — is
+    whole pages.  The trunks ask it for the write, the engine for its
+    counter (``tpuserve_prefill_kv_tokens_paged_total``)."""
+    return (attn_impl == "pallas" and mesh is None and "ks" not in entry
+            and "v" in entry and unit % entry["k"].shape[1] == 0)
+
+
 def write_kv_entry(entry: dict, k: jnp.ndarray, v: jnp.ndarray,
-                   slots: jnp.ndarray) -> dict:
+                   slots: jnp.ndarray, aligned: bool = False) -> dict:
     """Write one layer's new K/V into its cache entry.
 
     An entry carrying ``ks``/``vs`` scale arrays stores int8: values are
     quantized on write and the scales scattered alongside.  Plain entries
     store in the cache dtype unchanged.  ONE switch point for every model
-    trunk (prefill / chunk / verify / decode)."""
+    trunk (prefill / chunk / verify / decode).
+
+    ``aligned`` (static; :func:`kv_stream_by_page` decides it) is the
+    word of a trunk that owns a page-aligned stream — the packed prefill
+    and the prefill chunk: every ``block_size`` consecutive rows of
+    ``slots`` are one cache page in order, or padding — and sends the rows
+    out a page a copy (ops/pallas_kv_write.py) instead of a scatter index
+    a row.  Decode's rows go one to a page; they, verify, draft, mixed
+    steps and the (B, L) grid keep the scatter."""
     with jax.named_scope(scopes.ATTN_KV_WRITE):
+        if aligned:
+            from tpuserve.ops.pallas_kv_write import paged_kv_write
+            ck, cv = paged_kv_write(entry["k"], entry["v"], k, v, slots)
+            return {"k": ck, "v": cv}
         if "ks" in entry:
             qk, sk = quantize_kv(k)
             qv, sv = quantize_kv(v)

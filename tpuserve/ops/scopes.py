@@ -35,7 +35,10 @@ The table (scope -> where it opens -> which metric reads it):
     embed           _embed                                    trunk.decode_glue_ms
     attn.qkv        _qkv, _mla_proj, _mla_decompress, _mla_absorb_q: the layer's
                     input norm, projections, q/k norm, rotary  trunk.decode_proj_ms
-    attn.kv_write   ops/attention.py write_kv_entry, write_mla_entry            trunk.decode_glue_ms
+    attn.kv_write   ops/attention.py write_kv_entry (the row scatter, or for
+                    a page-aligned prefill stream ops/pallas_kv_write.py's
+                    copy a page), write_mla_entry              trunk.decode_glue_ms (decode/),
+                                                              kv.prefill_write_device_share (prefill/, chunk/)
     attn.kernel     each Pallas or reference attention call (ops/attention.py,
                     ops/pallas_*attention*.py, _ragged_reference_attn); under
                     decode/ the paged decode kernel's calls over the attention

@@ -31,7 +31,7 @@ from tpuserve.models.config import ModelConfig, get_model_config
 from tpuserve.models.tokenizer import IncrementalDetokenizer, load_tokenizer
 from tpuserve.models.weights import load_or_init, param_dtype
 from tpuserve.ops import sampling as sampling_ops
-from tpuserve.ops.attention import PAD_SLOT
+from tpuserve.ops.attention import PAD_SLOT, kv_stream_by_page
 from tpuserve.runtime.block_manager import BlockManager, create_block_manager
 from tpuserve.runtime.hostprof import PROF
 from tpuserve.runtime.kv_cache import CacheConfig, create_kv_cache
@@ -229,6 +229,11 @@ class EngineStats:
     prefill_tokens_total: int = 0
     prefill_padded_tokens_total: int = 0
     prefill_packed_steps: int = 0
+    # of prefill_tokens_total, the tokens whose K and V went into the
+    # cache a page at a time (ops/pallas_kv_write.py) and not a scatter
+    # row each: the packed prefills and whole-page chunks of an engine
+    # whose Pallas kernels are on (ops/attention.py kv_stream_by_page)
+    prefill_kv_tokens_paged_total: int = 0
     # requests whose first token was still on the device when the next
     # dispatch was enqueued (read behind it), against those whose record
     # had to be read before it (Engine._flush_first): deferred /
@@ -1986,7 +1991,8 @@ class Engine:
         self._restores.clear()
 
     def _note_step_tokens(self, actual: int, padded: int,
-                          ctx_tokens: int, prefill: bool = False) -> None:
+                          ctx_tokens: int, prefill: bool = False,
+                          kv_by_page: bool = False) -> None:
         """Record one dispatch's real vs padded token counts (the
         padding-waste observability behind the
         ``tpuserve_step_padded/actual_tokens`` gauges) — ONE home so the
@@ -1996,10 +2002,12 @@ class Engine:
         window — at its first step — and verify; context + chunk length
         for prefill, chunk and mixed), from host-known integers.
         ``prefill``: a batched-prefill or prefill-chunk dispatch, counted
-        in the prefill-only pair as well."""
+        in the prefill-only pair as well; ``kv_by_page``: its trunk wrote
+        the tokens' K and V a page at a time."""
         if prefill:
             self.stats.prefill_tokens_total += actual
             self.stats.prefill_padded_tokens_total += padded
+            self.stats.prefill_kv_tokens_paged_total += actual * kv_by_page
         self.stats.step_actual_tokens = actual
         self.stats.step_padded_tokens = padded
         self.stats.step_ctx_tokens = ctx_tokens
@@ -2477,7 +2485,11 @@ class Engine:
         self.scheduler.mark_running(reqs)
         self.stats.num_prefill_steps += 1
         self.stats.prefill_packed_steps += packed
-        self._note_step_tokens(n_tok, padded, ctx_tok, prefill=True)
+        # the trunk's own static test (forward_ragged, decode_rows=False)
+        self._note_step_tokens(
+            n_tok, padded, ctx_tok, prefill=True,
+            kv_by_page=packed and kv_stream_by_page(
+                self.kv_cache[0], self._ragged_blk, self._ragged_attn))
         return self._defer_first(logits, reqs, B)
 
     def _prefill_tokens(self, req: Request) -> list[int]:
@@ -2537,6 +2549,13 @@ class Engine:
                                 np.int32)
         block_tables[0, :len(bt)] = bt
         kw = self._row_kw([req], 1)
+        # the trunk's own static test (prefill_chunk): a chunk of whole
+        # pages is written by page, on the word that it STARTS on one —
+        # lookup_prefix returns whole blocks and every chunk before the
+        # last is a full one
+        by_page = kv_stream_by_page(self.kv_cache[0], C, self.attn_impl,
+                                    self._attn_mesh)
+        assert not by_page or done % self.cache_cfg.block_size == 0, done
         self._demote_evicted()
         with PROF.phase("dispatch"):
             logits, self.kv_cache = self._exec_prefill_chunk(
@@ -2547,7 +2566,8 @@ class Engine:
         self._note_prompt_picks(req, 0, done, n)
         req.num_prefilled = done + n
         self.stats.num_prefill_steps += 1
-        self._note_step_tokens(n, C, done + n, prefill=True)
+        self._note_step_tokens(n, C, done + n, prefill=True,
+                               kv_by_page=by_page)
         if req.num_prefilled < len(ids):
             # more chunks to go: back to the head of the queue (an earlier
             # prefill's first tokens are read behind this chunk)

@@ -165,6 +165,12 @@ class ServerMetrics:
             "Batched prefills dispatched packed on one flat token axis "
             "through the ragged trunk (single chip, no mesh, pages in the "
             "model's dtype) rather than as a (batch x length) grid")
+        self.prefill_kv_tokens_paged = counter(
+            "tpuserve_prefill_kv_tokens_paged_total",
+            "Of tpuserve_prefill_tokens_total, the prompt tokens whose K "
+            "and V went into the paged cache one copy a page (packed "
+            "prefills and whole-page chunks with the Pallas kernels on) "
+            "rather than one scatter row a token")
         self.first_tokens_deferred = counter(
             "tpuserve_prefill_first_tokens_deferred",
             "Prefilled requests whose first token was still on the device "

@@ -1006,6 +1006,8 @@ class AsyncEngineRunner:
                                   self.metrics.prefill_padded_tokens_total),
                                  ("prefill_packed_steps",
                                   self.metrics.prefill_packed_steps),
+                                 ("prefill_kv_tokens_paged_total",
+                                  self.metrics.prefill_kv_tokens_paged),
                                  ("prefill_first_token_deferred",
                                   self.metrics.first_tokens_deferred),
                                  ("prefill_first_token_flushed_early",
