@@ -373,6 +373,100 @@ def test_the_expert_layer_compiles_for_v5e_at_every_rung(tokens, one_chip,
                             S((E, H, I), bf16), S((E,), jnp.int32)).compile()
 
 
+# K-EXAONE-236B-A23B's share of the benchmark's cell: 16 of 128 experts of
+# width 2,048 on a hidden size of 6,144, 8 a token; the flat-token rungs
+# of its packed prefills (64-row ragged blocks, so multiples of 128 up to
+# 1,024, of 512 up to 2,048, of 1,024 above) and the largest decode bucket
+HELD_TOKENS = [64, 128, 512, 1024, 1536, 2048, 3072, 4096, 8192]
+
+
+def _k_exaone_share(**cut):
+    import dataclasses
+
+    from tpuserve.models.config import get_model_config
+    return dataclasses.replace(
+        get_model_config("LGAI-EXAONE/K-EXAONE-236B-A23B"),
+        moe_experts_held=16, vocab_size=19200, **cut)
+
+
+@pytest.mark.parametrize("tokens", HELD_TOKENS)
+def test_the_expert_layer_under_a_share_compiles_for_v5e_at_every_rung(
+        tokens, one_chip, monkeypatch):
+    """The whole expert layer told it holds 16 of 128 experts (router over
+    all 128, the sort of the picks, the loop over pieces with the rows'
+    gather, three grouped products and the add to the tokens, the shared
+    expert) at K-EXAONE's widths.  What this guards: the layer's transient
+    memory follows what lands here.  A buffer of ``T k`` rows of 6,144
+    bf16 values is 805 MB at the top rung, and a layer that moved every
+    pick would hold three of them (the gathered rows, the products' output,
+    that output back in token order) and the activations between; the
+    whole layer here stays about ONE such buffer at every rung (820 MB at
+    the top: a piece's rows, their output and its weighted float32 copy,
+    and the float32 sum over the tokens)."""
+    from tpuserve.models import transformer
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _k_exaone_share(num_layers=2)
+    H, I, E = cfg.hidden_size, cfg.expert_intermediate_size, cfg.num_experts
+    held, bf16 = cfg.moe_experts_held, jnp.bfloat16
+    p = {"router": {"kernel": S((H, E), bf16)},
+         "router_bias": {"bias": S((E,), jnp.float32)},
+         "experts": {"gate_proj": {"kernel": S((held, H, I), bf16)},
+                     "up_proj": {"kernel": S((held, H, I), bf16)},
+                     "down_proj": {"kernel": S((held, I, H), bf16)}},
+         "shared": {"gate_proj": {"kernel": S((H, I), bf16)},
+                    "up_proj": {"kernel": S((H, I), bf16)},
+                    "down_proj": {"kernel": S((I, H), bf16)}}}
+    compiled = jax.jit(lambda x, p: transformer._moe_mlp(x, p, cfg)).lower(
+        S((tokens, H), bf16), p).compile()
+    text = compiled.as_text()
+    assert text.count("_moe_grouped_matmul") >= 3
+    assert " while(" in text            # the pieces: a trip count from data
+    every_pick = tokens * cfg.num_experts_per_tok * H * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.25 * every_pick + (16 << 20), (temp, every_pick)
+
+
+@pytest.mark.parametrize("program,tokens", [("decode_multi", 0),
+                                            ("forward_ragged", 8192)])
+def test_the_k_exaone_cell_fits_the_chip(program, tokens, one_chip,
+                                         monkeypatch):
+    """The cell's whole trunks at the published widths: 8 layers, 16 of
+    128 experts, 19,200 vocabulary rows, a fused decode window of 64 rows
+    and the top rung of the packed-prefill ladder, beside a pool of 3,072
+    pages of 32 tokens (what 0.9 of the chip leaves after 11.96 GB of
+    weights).  The chip's compiler refuses what does not fit 16 GB; 64
+    query heads take a ragged block of 64 rows, as the engine finds."""
+    from test_scopes import trunk_programs
+    from tpuserve.ops.pallas_ragged_attention import ragged_block_for
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def place(tree):
+        return jax.tree.map(lambda x: S(x.shape, x.dtype), tree)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _k_exaone_share(num_layers=8)
+    blk = ragged_block_for(cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+                           PAGE, 2, 2)
+    assert blk == 64
+    # and the accepted cells' shapes keep their 128 rows
+    for hq, hkv, d in WIDTHS.values():
+        assert ragged_block_for(hq, hkv, d, PAGE, 2, 2) == 128
+    fn, args, kwargs = trunk_programs(
+        cfg, S, place, rows=MAX_NUM_SEQS, steps=8, tokens=tokens or blk,
+        blk=blk, prompts=PREFILL_SEQS, block_size=PAGE, num_blocks=3072,
+        max_blocks=MAX_PAGES, attn_impl="pallas")[program]
+    mem = fn.lower(*args, **kwargs).compile().memory_analysis()
+    weights = mem.argument_size_in_bytes - mem.alias_size_in_bytes
+    assert 11.9e9 < weights < 12.1e9, weights
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.5e9
+
+
 @pytest.mark.parametrize("kernel", ["decode", "flash", "ragged"])
 def test_the_custom_call_carries_the_name_the_benchmark_matches(
         kernel, one_chip, monkeypatch):
@@ -471,6 +565,11 @@ def _scheduled(text):
     ("JetBrains/Mellum2-12B-A2.5B-Instruct",
      {"_paged_decode_attention": "attn.kernel",
       "_moe_grouped_matmul": "moe.experts"}),
+    # a dense layer, then an expert layer told its share (the loop over
+    # pieces is a computation of its own inside the window's)
+    ("LGAI-EXAONE/K-EXAONE-236B-A23B+share",
+     {"_paged_decode_attention": "attn.kernel",
+      "_moe_grouped_matmul": "moe.experts"}),
 ])
 def test_every_operation_of_a_decode_window_names_its_part(
         model, kernels, one_chip, monkeypatch):
@@ -497,7 +596,8 @@ def test_every_operation_of_a_decode_window_names_its_part(
         return jax.tree.map(lambda x: S(x.shape, x.dtype), tree)
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = dataclasses.replace(get_model_config(model), num_layers=2)
+    cfg = dataclasses.replace(get_model_config(model), num_layers=2) \
+        if "+share" not in model else _k_exaone_share(num_layers=2)
     fn, args, kwargs = trunk_programs(
         cfg, S, place, rows=MAX_NUM_SEQS, steps=8, block_size=PAGE,
         num_blocks=NUM_BLOCKS, max_blocks=MAX_PAGES,

@@ -307,7 +307,8 @@ def test_sparse_dispatch_is_the_dense_form(cfg, params, case, T):
                                atol=2e-5, rtol=2e-5)
     picked = np.asarray(combine != 0).sum(0)
     # (a weight of exactly zero cannot come out of a softmax or a sigmoid)
-    (sizes, picks), = tally
+    (sizes, picks, landed), = tally
+    assert landed is None               # every expert held: no share
     np.testing.assert_array_equal(np.asarray(sizes), picked)
     assert int(np.asarray(sizes).sum()) == T * c.num_experts_per_tok
     # and each token's picks are the columns of its row that weigh

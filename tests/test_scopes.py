@@ -5,6 +5,7 @@ changes no program's name.  (What the chip's compiler makes of them is
 ``tests/test_chip_compile.py``; what the benchmark reads,
 ``tests/benchmark/test_benchmark_scope_trace.py``.)"""
 
+import dataclasses
 import re
 
 import jax
@@ -26,7 +27,21 @@ FAMILY = {
                                 scopes.SSM_SCAN, scopes.SSM_OUT},
     "tiny-mellum2": COMMON | {scopes.MOE_ROUTE, scopes.MOE_GATHER,
                               scopes.MOE_EXPERTS, scopes.MOE_COMBINE},
+    # a share of the experts (8 of 32) beside a shared expert
+    "tiny-k-exaone+share": COMMON | {scopes.MOE_ROUTE, scopes.MOE_GATHER,
+                                     scopes.MOE_EXPERTS, scopes.MOE_COMBINE,
+                                     scopes.MOE_SHARED},
 }
+ALL_PARTS = scopes.PARTS + scopes.LATER_PARTS
+
+
+def family_config(model: str):
+    """The registered model; ``+share``: told it holds a quarter of its
+    experts."""
+    name, _, share = model.partition("+")
+    cfg = get_model_config(name)
+    return dataclasses.replace(
+        cfg, moe_experts_held=cfg.num_experts // 4) if share else cfg
 # program -> (its phase, the parts only it has)
 PROGRAMS = {
     "decode_multi": (scopes.DECODE, {scopes.SAMPLE, scopes.CARRY}),
@@ -40,7 +55,7 @@ def scope_of(op_name: str) -> tuple:
     phase, the last that is a part ("" for what it lacks)."""
     comps = op_name.split("/")
     return (next((c for c in comps if c in scopes.PHASES), ""),
-            next((c for c in reversed(comps) if c in scopes.PARTS), ""))
+            next((c for c in reversed(comps) if c in ALL_PARTS), ""))
 
 
 def trunk_programs(cfg, S=jax.ShapeDtypeStruct, place=lambda tree: tree, *,
@@ -88,7 +103,7 @@ def trunk_programs(cfg, S=jax.ShapeDtypeStruct, place=lambda tree: tree, *,
 @pytest.mark.parametrize("program", sorted(PROGRAMS))
 @pytest.mark.parametrize("model", sorted(FAMILY))
 def test_a_trunk_carries_every_scope_of_its_family(model, program):
-    fn, args, kwargs = trunk_programs(get_model_config(model))[program]
+    fn, args, kwargs = trunk_programs(family_config(model))[program]
     lowered = fn.lower(*args, **kwargs)
     # the scope is inside the jitted function: the program is named as ever
     assert re.search(rf"module @jit_{program}\b", lowered.as_text())
@@ -101,7 +116,7 @@ def test_a_trunk_carries_every_scope_of_its_family(model, program):
     # and under no other phase: one trunk, one phase
     assert {ph for ph, _ in found} <= {phase, ""}, found
     # a part the family lacks is not invented
-    absent = set(scopes.PARTS) - want
+    absent = set(ALL_PARTS) - want
     assert not {part for _, part in found} & absent
 
 
@@ -110,8 +125,8 @@ def test_the_table_is_one_place():
     literal string anywhere in the program."""
     import os
     import subprocess
-    assert len(set(scopes.PHASES + scopes.PARTS)) \
-        == len(scopes.PHASES) + len(scopes.PARTS)
+    assert len(set(scopes.PHASES + ALL_PARTS)) \
+        == len(scopes.PHASES) + len(ALL_PARTS)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run(
         ["grep", "-rnE", r"named_scope\([\"']", os.path.join(root, "tpuserve"),

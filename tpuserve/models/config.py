@@ -80,6 +80,10 @@ class ModelConfig:
     # plain table; the attention factor 0.1 ln(factor) + 1 multiplies the
     # full layers' cos and sin, attn_scale is untouched — see layer_yarn().
     rope_full_yarn: Optional[tuple] = None
+    # Rotary on the WINDOWED layers alone (K-EXAONE, after EXAONE 4.0's
+    # hybrid attention): a layer that attends the whole context carries no
+    # positional rotation at all — see layer_rotates().
+    rope_windowed_only: bool = False
     # Gemma2 traits: tanh softcaps on attention scores / final logits,
     # attention scale from query_pre_attn_scalar instead of head_dim, and
     # sandwich norms (post-attention + pre/post-feedforward layernorms).
@@ -113,6 +117,16 @@ class ModelConfig:
     moe_routed_scaling: float = 1.0
     moe_shared_experts: int = 0      # shared-expert width multiplier
     moe_first_k_dense: int = 0       # first_k_dense_replace
+    # One chip's share of a deployment whose chips share each expert layer
+    # (expert parallelism): this process holds ``moe_experts_held``
+    # contiguous experts from ``moe_first_expert`` on, of the
+    # ``num_experts`` the router is wide.  The router, the top-k and the
+    # combine weights are over all of them; the layer computes the held
+    # experts' part for the rows routed to them and leaves the rest out
+    # (models/transformer.py _moe_held_experts), the shared expert whole.
+    # 0 = every expert is held, and the layer is the one it always was.
+    moe_experts_held: int = 0
+    moe_first_expert: int = 0
     # Multi-head latent attention (DeepSeek MLA): K/V are compressed to a
     # kv_lora_rank latent + one shared roped key per token, so the cache
     # stores ONE (kv_lora_rank + qk_rope_head_dim)-wide "head" per token
@@ -164,6 +178,15 @@ class ModelConfig:
     ssm_out_multiplier: float = 1.0
     mlp_multipliers: tuple = (1.0, 1.0)               # (gate, down)
     ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)  # z, x, B, C, dt
+
+    def __post_init__(self):
+        if self.moe_experts_held or self.moe_first_expert:
+            held, first = self.moe_experts_held, self.moe_first_expert
+            if not (0 < held and 0 <= first
+                    and first + held <= self.num_experts):
+                raise ValueError(
+                    f"{self.name}: cannot hold experts {first} to "
+                    f"{first + held - 1} of {self.num_experts}")
 
     @property
     def has_ssm(self) -> bool:
@@ -238,6 +261,14 @@ class ModelConfig:
         factor, beta_fast, beta_slow, orig_max = self.rope_full_yarn
         return (factor, beta_fast, beta_slow, 0, 0, orig_max)
 
+    def layer_rotates(self, layer_idx: int) -> bool:
+        """Whether one layer's q and k are rotated at all, beside
+        layer_rope() and layer_yarn() — read by ``_qkv`` alone.  False
+        only on the full layers of a model whose windowed layers alone
+        carry positions (``rope_windowed_only``)."""
+        return not (self.rope_windowed_only
+                    and self.layer_window(layer_idx) is None)
+
     # What a configuration file's keys are held to (benchmark/harness/
     # plan.py compares with ``!=``: a JSON list or dict never equals a
     # tuple), as config.json spells them.
@@ -260,8 +291,11 @@ class ModelConfig:
 
     @property
     def rope_parameters(self) -> Optional[dict]:
-        """HF ``rope_parameters`` keyed by layer type, for a model whose
-        rotary tables differ by layer kind; None for any other."""
+        """HF ``rope_parameters``: keyed by layer type for a model whose
+        rotary tables differ by layer kind, flat (one plain table) for
+        one whose windowed layers alone rotate; None for any other."""
+        if self.rope_windowed_only:
+            return {"rope_theta": self.rope_theta, "rope_type": "default"}
         if self.rope_full_yarn is None:
             return None
         from tpuserve.ops.rope import yarn_mscale
@@ -277,6 +311,25 @@ class ModelConfig:
                 "rope_type": "default",
                 "rope_theta": self.rope_local_base_freq or self.rope_theta},
         }
+
+    @property
+    def sliding_windows(self) -> list:
+        """Each running layer's window, 0 where it attends the whole
+        context (K-EXAONE's ``sliding_windows``)."""
+        return [self.layer_window(i) or 0 for i in range(self.num_layers)]
+
+    @property
+    def sliding_window_pattern(self) -> str:
+        """The shortest period of the running layers' kinds, ``L`` a
+        windowed layer and ``G`` a full one (K-EXAONE's spelling)."""
+        kinds = "".join("L" if w else "G" for w in self.sliding_windows)
+        return next(kinds[:p] for p in range(1, len(kinds) + 1)
+                    if kinds == (kinds[:p] * len(kinds))[:len(kinds)])
+
+    @property
+    def moe_local_experts(self) -> int:
+        """Experts whose kernels this process holds."""
+        return self.moe_experts_held or self.num_experts
 
     @property
     def uniform_window(self) -> bool:
@@ -362,8 +415,8 @@ class ModelConfig:
         h, i, l, v = self.hidden_size, self.intermediate_size, self.num_layers, self.vocab_size
         attn = h * self.q_size + 2 * h * self.kv_size + self.q_size * h
         if self.num_experts:
-            mlp = (self.num_experts * 3 * h * self.expert_intermediate_size
-                   + h * self.num_experts)
+            mlp = (self.moe_local_experts * 3 * h
+                   * self.expert_intermediate_size + h * self.num_experts)
         else:
             mlp = (3 if self.mlp_style == "gated" else 2) * h * i
         embed = v * h * (1 if self.tie_word_embeddings else 2)
@@ -422,6 +475,8 @@ def config_from_hf_json(name: str, hf: dict) -> ModelConfig:
         return _falcon_h1_config(hf, common)
     if family == "mellum":
         return _mellum_config(hf, common)
+    if family == "exaone_moe":
+        return _exaone_moe_config(hf, common)
     if "opt" in family:
         common["tie_word_embeddings"] = hf.get("tie_word_embeddings", True)
         return ModelConfig(
@@ -705,6 +760,78 @@ def _mellum_config(hf: dict, common: dict) -> ModelConfig:
         norm_topk_prob=hf.get("norm_topk_prob", True),
         **common,
     )
+
+
+def _exaone_moe_config(hf: dict, common: dict) -> ModelConfig:
+    """K-EXAONE (``model_type`` ``exaone_moe``): a GQA decoder with a
+    per-head q/k norm whose layers are of two kinds (``layer_types``: a
+    sliding window with the plain rotary table, or the whole context with
+    NO rotation) and whose MLPs, after ``first_k_dense_replace`` dense
+    ones, are routed experts beside a shared one behind DeepSeek-V3's
+    router (sigmoid scores, a selection bias, the top-k renormalised and
+    scaled).  ``config.json`` states neither the q/k norm, nor which
+    layers rotate, nor the selection bias: they are the family's
+    convention (benchmark/configs/k-exaone-236b-ep8-l8.json ``assumed``).
+    The multi-token-prediction layers (``num_nextn_predict_layers``) are
+    no part of the next-token forward pass and are not built.  What this
+    code does not implement rejects loudly."""
+    layers = hf["num_hidden_layers"]
+    kinds = hf.get("layer_types")
+    if not kinds or len(kinds) != layers or set(kinds) - {
+            "sliding_attention", "full_attention"}:
+        raise ValueError("exaone_moe configs must carry layer_types, one of "
+                         "sliding_attention / full_attention a layer; got "
+                         f"{kinds!r}")
+    window = hf.get("sliding_window")
+    if not window:
+        raise ValueError("exaone_moe without a sliding window is not "
+                         "supported")
+    windows = [window if t == "sliding_attention" else 0 for t in kinds]
+    if hf.get("sliding_windows", windows) != windows:
+        raise ValueError(f"exaone_moe sliding_windows "
+                         f"{hf['sliding_windows']!r} disagree with "
+                         "layer_types and sliding_window")
+    if (hf.get("n_group") or 1) != 1 or (hf.get("topk_group") or 1) != 1:
+        raise ValueError("exaone_moe with grouped routing (n_group "
+                         f"{hf.get('n_group')!r}, topk_group "
+                         f"{hf.get('topk_group')!r}) is not supported")
+    dense = hf.get("first_k_dense_replace", 0)
+    mlp_kinds = ["dense"] * dense + ["sparse"] * (layers - dense)
+    if hf.get("mlp_layer_types", mlp_kinds) != mlp_kinds:
+        raise ValueError("exaone_moe mlp_layer_types must be "
+                         "first_k_dense_replace dense layers and then sparse "
+                         f"ones; got {hf['mlp_layer_types']!r}")
+    rp = hf.get("rope_parameters") or {}
+    if rp.get("rope_type", "default") != "default":
+        raise ValueError(f"unsupported exaone_moe rope_parameters {rp!r}")
+    nh = hf["num_attention_heads"]
+    cfg = ModelConfig(
+        intermediate_size=hf["intermediate_size"],
+        num_kv_heads=hf.get("num_key_value_heads", nh),
+        head_dim=hf.get("head_dim") or hf["hidden_size"] // nh,
+        norm_eps=hf.get("rms_norm_eps", 1e-5),
+        act=hf.get("hidden_act", "silu"),
+        attention_bias=hf.get("attention_bias", False),
+        rope_theta=float(rp.get("rope_theta", hf.get("rope_theta", 1e6))),
+        rope_windowed_only=True, qk_norm=True,
+        sliding_window=window,
+        window_layers=tuple(t == "sliding_attention" for t in kinds),
+        num_experts=hf["num_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        norm_topk_prob=hf.get("norm_topk_prob", True),
+        moe_scoring=hf.get("scoring_func", "sigmoid"),
+        moe_router_bias=True,
+        moe_routed_scaling=hf.get("routed_scaling_factor", 1.0),
+        moe_shared_experts=hf.get("num_shared_experts") or 0,
+        moe_first_k_dense=dense,
+        **common,
+    )
+    pattern = hf.get("sliding_window_pattern", cfg.sliding_window_pattern)
+    if pattern != cfg.sliding_window_pattern:
+        raise ValueError(f"exaone_moe sliding_window_pattern {pattern!r}: "
+                         f"layer_types repeat {cfg.sliding_window_pattern!r}")
+    return cfg
 
 
 def _falcon_h1_config(hf: dict, common: dict) -> ModelConfig:
@@ -1007,6 +1134,29 @@ register_model_config(ModelConfig(
     norm_topk_prob=True,
 ), "mellum2-12b")
 
+# K-EXAONE-236B-A23B (LG AI Research): 48 layers of 64 query heads on 8 KV
+# heads with a per-head q/k norm, three with a 128-token window and the
+# plain rotary table to each one that attends the whole context unrotated;
+# one dense layer of width 18,432, then 128 routed experts of width 2,048,
+# eight a token by sigmoid scores, beside one shared expert.  The numbers
+# are config.json's; what it leaves open is in
+# benchmark/configs/k-exaone-236b-ep8-l8.json (``assumed``).  236 B
+# parameters: a chip serves its share of a cut of the depth
+# (``moe_experts_held``).  The multi-token-prediction layer is not built.
+register_model_config(ModelConfig(
+    name="LGAI-EXAONE/K-EXAONE-236B-A23B",
+    vocab_size=153600, hidden_size=6144, intermediate_size=18432,
+    num_layers=48, num_heads=64, num_kv_heads=8, head_dim=128,
+    max_position_embeddings=262144, rope_theta=1e6, norm_eps=1e-5,
+    tie_word_embeddings=False, qk_norm=True,
+    sliding_window=128,
+    window_layers=tuple(i % 4 != 3 for i in range(48)),   # L L L G
+    rope_windowed_only=True,
+    num_experts=128, num_experts_per_tok=8, moe_intermediate_size=2048,
+    norm_topk_prob=True, moe_scoring="sigmoid", moe_router_bias=True,
+    moe_routed_scaling=2.5, moe_shared_experts=1, moe_first_k_dense=1,
+), "k-exaone-236b")
+
 # Tiny configs for tests / CPU smoke (one per architectural family).
 register_model_config(ModelConfig(
     name="tiny-qwen3",
@@ -1047,6 +1197,26 @@ register_model_config(ModelConfig(
     rope_full_yarn=(4, 32, 1, 32),
     num_experts=8, num_experts_per_tok=2, moe_intermediate_size=32,
     norm_topk_prob=True,
+))
+
+# K-EXAONE in small: two periods of L L L G with a window of 8 and no
+# rotation on the G layers, 16 query heads on 2 KV heads (the family's
+# group of eight) under a q/k norm, a dense layer first, then 32 experts,
+# 4 a token by sigmoid scores scaled 2.5, beside a shared one.  Every
+# expert held; a test takes a share with dataclasses.replace.  float32
+# like tiny-mistral.
+register_model_config(ModelConfig(
+    name="tiny-k-exaone",
+    vocab_size=256, hidden_size=64, intermediate_size=192,
+    num_layers=8, num_heads=16, num_kv_heads=2, head_dim=16,
+    max_position_embeddings=512, rope_theta=1e6, norm_eps=1e-5,
+    tie_word_embeddings=False, eos_token_id=1, dtype="float32",
+    qk_norm=True, sliding_window=8,
+    window_layers=tuple(i % 4 != 3 for i in range(8)),
+    rope_windowed_only=True,
+    num_experts=32, num_experts_per_tok=4, moe_intermediate_size=32,
+    norm_topk_prob=True, moe_scoring="sigmoid", moe_router_bias=True,
+    moe_routed_scaling=2.5, moe_shared_experts=1, moe_first_k_dense=1,
 ))
 
 register_model_config(ModelConfig(

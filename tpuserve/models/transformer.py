@@ -22,6 +22,7 @@ from tpuserve.ops import attention as attn_ops
 from tpuserve.ops import rope as rope_ops
 from tpuserve.ops import scopes
 from tpuserve.ops import ssm as ssm_ops
+from tpuserve.utils import round_up
 
 Params = Any  # nested dict/list pytree of jnp arrays
 
@@ -184,10 +185,18 @@ def _moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
     one psum combines (parallel/sharding.py).  No expert-parallel
     exchange exists yet (ROADMAP C9/M1).
 
+    A SHARE (``cfg.moe_experts_held``; what a configuration observes of
+    its deployment, not an option of the call): this process holds that
+    many of the ``E`` experts.  Router, top-k, renormalisation over all
+    ``k`` picks and scaling are as ever, over all ``E``; then
+    :func:`_moe_held_experts` computes the held experts' part for the
+    picks that fall on them and the absent experts' part is left out, the
+    shared expert added whole.  That partial sum goes on to the next layer.
+
     ``tally``: a list the trunk collects routing in; this layer appends
     ``(E,)`` int32 rows routed to each expert (padding rows of the
-    dispatch included: they are computed like any other) and its
-    ``(T, k)`` picks.
+    dispatch included: they are computed like any other), its ``(T, k)``
+    picks and, under a share, what landed here (None without one).
     """
     shape = x.shape
     k = cfg.num_experts_per_tok
@@ -244,12 +253,17 @@ def _moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
         picks = topi.reshape(-1)                               # (T k,)
         sizes = jnp.sum(picks[:, None] == jnp.arange(E)[None, :], axis=0,
                         dtype=jnp.int32)                       # (E,)
-        if tally is not None:
-            tally.append((sizes, topi))
-        if not dense:
+        if not dense and not cfg.moe_experts_held:
             order = jnp.argsort(picks, stable=True)     # rows by expert
     ek = p["experts"]
-    if dense:
+    landed = None
+    if cfg.moe_experts_held:
+        if dense:
+            raise ValueError(
+                f"{cfg.name}: the dense form of the expert layer runs ALL "
+                "experts under a mesh; a share of them has no such form")
+        y, landed = _moe_held_experts(xt, ek, topi, topv, cfg)
+    elif dense:
         y = _moe_dense_experts(xt, ek, topi, topv, cfg)
     else:
         from tpuserve.ops.pallas_moe_gmm import grouped_matmul
@@ -277,13 +291,112 @@ def _moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
             # the router's weights stay float32 into the sum over a
             # token's picks
             y = jnp.einsum("tkh,tk->th", o, topv).astype(x.dtype)
-    with jax.named_scope(scopes.MOE_COMBINE):
-        if "shared" in p:
+    if tally is not None:
+        tally.append((sizes, topi, landed))
+    if "shared" in p:
+        with jax.named_scope(scopes.MOE_SHARED):
             # DeepSeek shared experts: an always-on gated MLP beside the
-            # routed ones (HF DeepseekV3MoE.shared_experts) — p["shared"]
-            # has no "experts" key, so _mlp runs its plain gated branch
-            y = y + _mlp(xt, p["shared"], cfg)
+            # routed ones (HF DeepseekV3MoE.shared_experts), written out
+            # here so that its time reads under its own part and not the
+            # dense MLP's
+            sp = p["shared"]
+            y = y + _linear(_act(_linear(xt, sp["gate_proj"]), cfg.act)
+                            * _linear(xt, sp["up_proj"]), sp["down_proj"])
+    with jax.named_scope(scopes.MOE_COMBINE):
+        # (the rows back in the caller's shape, named as it always was: an
+        # operation's name is part of its program's key in the compile
+        # cache)
         return y.reshape(shape)
+
+
+def held_piece_rows(pairs: int, held: int, experts: int) -> int:
+    """Rows of one piece of :func:`_moe_held_experts`'s buffer, for a
+    dispatch of ``pairs`` (token, expert) picks over ``experts`` experts
+    of which ``held`` are here: what lands here when the router spreads
+    its picks evenly, ``pairs held / experts``, plus three standard
+    deviations of that count, rounded up to the row tile the grouped
+    product takes at that size (``ops/pallas_moe_gmm.tiling``: a piece is
+    never padded there).  A static function of shapes; never more than
+    every pick."""
+    mean = pairs * held / experts
+    want = math.ceil(mean + 3.0 * math.sqrt(mean * (1.0 - held / experts)))
+    tile = 16 if want <= 128 else 128 if want <= 1024 else 256
+    return min(round_up(want, tile), round_up(pairs, 16))
+
+
+def _moe_held_experts(xt, ek, topi, topv, cfg: ModelConfig):
+    """The held experts' part of an expert layer under a share (see
+    :func:`_moe_mlp`): ``xt`` (T, H) rows, ``ek`` the HELD experts'
+    stacked kernels ``(held, ., .)``, ``topi`` / ``topv`` (T, k) the
+    router's picks over all experts and their final weights.  Returns the
+    weighted sum over each token's picks that fall on held experts
+    (T, H) in ``xt``'s dtype, zero for a token none of whose picks do,
+    and ``(4,)`` int32: the picks that landed here, the held experts that
+    got at least one, the rows of buffer moved for them and the pieces
+    they were moved in.
+
+    **The buffer follows what lands here, not ``T k``.**  The picks are
+    ordered held-first by expert (one stable sort of ``T k`` small
+    integers; no row moves yet), and the rows of the held ones are
+    gathered, multiplied (the grouped products of the layer that holds
+    every expert, over ``held`` groups) and added back to their tokens a
+    PIECE at a time: :func:`held_piece_rows` rows, as many pieces as the
+    count needs (a ``fori_loop`` whose trip count is data).  One piece in
+    the usual case; at the skew that sends EVERY pick here, ``T k /
+    piece`` of them and still no pick dropped.  Rows of the last piece
+    past the count belong to no group: the kernel visits no tile for
+    them, and what it leaves there is masked out before the add."""
+    from tpuserve.ops.pallas_moe_gmm import grouped_matmul
+    T, k = topi.shape
+    held, first = cfg.moe_experts_held, cfg.moe_first_expert
+    piece = held_piece_rows(T * k, held, cfg.num_experts)
+    with jax.named_scope(scopes.MOE_ROUTE):
+        local = topi.reshape(-1) - first                       # (T k,)
+        local = jnp.where((local >= 0) & (local < held), local, held)
+        sizes = jnp.sum(local[:, None] == jnp.arange(held)[None, :], axis=0,
+                        dtype=jnp.int32)                       # (held,)
+        ends = jnp.cumsum(sizes)
+        starts, landed = ends - sizes, ends[-1]
+        pieces = (landed + piece - 1) // piece
+        # held picks first, by expert; padded so that every piece is whole
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        order = jnp.pad(order, (0, -(T * k) % piece))
+        weights = topv.reshape(-1)
+
+    def one_piece(i, y):
+        lo = i * piece
+        with jax.named_scope(scopes.MOE_ROUTE):
+            idx = jax.lax.dynamic_slice(order, (lo,), (piece,))
+            live = lo + jnp.arange(piece, dtype=jnp.int32) < landed
+            tok = idx // k
+            group = jnp.clip(ends - lo, 0, piece) \
+                - jnp.clip(starts - lo, 0, piece)
+        rows = _gather_rows(xt, tok)                           # (piece, H)
+
+        def expert_proj(inp: jnp.ndarray, ep: dict) -> jnp.ndarray:
+            out = grouped_matmul(inp, ep["kernel"], group)
+            if "scale" in ep:           # int8 kernels, as in _moe_mlp
+                out = out * ep["scale"][
+                    jnp.minimum(local[idx], held - 1)].astype(out.dtype)
+            return out
+
+        with jax.named_scope(scopes.MOE_EXPERTS):
+            h = _act(expert_proj(rows, ek["gate_proj"]), cfg.act) \
+                * expert_proj(rows, ek["up_proj"])
+            o = expert_proj(h, ek["down_proj"])
+        with jax.named_scope(scopes.MOE_COMBINE):
+            # the router's weights stay float32 into the sum over a
+            # token's picks, as where every expert is held
+            o = jnp.where(live[:, None],
+                          o.astype(jnp.float32) * weights[idx][:, None], 0.0)
+            return y.at[tok].add(o)
+
+    y = jax.lax.fori_loop(0, pieces, one_piece,
+                          jnp.zeros(xt.shape, jnp.float32))
+    with jax.named_scope(scopes.MOE_ROUTE):
+        stats = jnp.stack([landed, jnp.sum(sizes > 0, dtype=jnp.int32),
+                           pieces * piece, pieces])
+    return y.astype(xt.dtype), stats
 
 
 def _gather_rows(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
@@ -338,7 +451,8 @@ def _qkv(h: jnp.ndarray, lp: dict, cfg: ModelConfig, positions: jnp.ndarray,
     rotate at the local base frequency unscaled; full layers at
     rope_theta with the linear position scaling.  Mellum 2: full layers
     rotate by a YaRN table whose cos and sin carry the attention factor,
-    windowed layers by the plain one)."""
+    windowed layers by the plain one.  K-EXAONE: full layers do not
+    rotate at all)."""
     with jax.named_scope(scopes.ATTN_QKV):
         hn = _norm(h, lp["attn_norm"], cfg)
         h = _scaled(hn, cfg.attention_in_multiplier)
@@ -354,7 +468,7 @@ def _qkv(h: jnp.ndarray, lp: dict, cfg: ModelConfig, positions: jnp.ndarray,
                         cfg.norm_weight_offset)
             k = rmsnorm(k, lp["k_norm"]["scale"], cfg.norm_eps,
                         cfg.norm_weight_offset)
-        if cfg.pos == "rope":
+        if cfg.pos == "rope" and cfg.layer_rotates(layer_idx):
             rotary_dim = int(cfg.head_dim * cfg.partial_rotary_factor)
             theta, scaling = cfg.layer_rope(layer_idx)
             pos = positions
@@ -752,11 +866,18 @@ def _moe_counts(tally: list):
     """A dispatch's routing counts from its expert layers' ``(E,)`` group
     sizes: ``(E + 1,)`` int32 — rows routed to each expert, summed over
     the layers, then the expert-layers that got at least one row (each
-    touched expert's kernels are read once a layer)."""
+    touched expert's kernels are read once a layer).  Under a share
+    (:func:`_moe_held_experts`) four more, summed over the layers: the
+    rows that landed on held experts, the held expert-layers that got at
+    least one, the rows of buffer moved for them and the pieces moved."""
     with jax.named_scope(scopes.MOE_ROUTE):
-        sizes = jnp.stack([s for s, _ in tally])               # (L, E)
-        return jnp.concatenate([jnp.sum(sizes, axis=0),
-                                jnp.sum(sizes > 0, dtype=jnp.int32)[None]])
+        sizes = jnp.stack([s for s, _, _ in tally])            # (L, E)
+        counts = [jnp.sum(sizes, axis=0),
+                  jnp.sum(sizes > 0, dtype=jnp.int32)[None]]
+        if tally[0][2] is not None:
+            counts.append(jnp.sum(jnp.stack([h for _, _, h in tally]),
+                                  axis=0))
+        return jnp.concatenate(counts)
 
 
 def _moe_routing(tally: list | None, rows: jnp.ndarray | None):
@@ -772,7 +893,7 @@ def _moe_routing(tally: list | None, rows: jnp.ndarray | None):
     if tally is None:
         return None
     with jax.named_scope(scopes.MOE_ROUTE):
-        picks = jnp.stack([t for _, t in tally], axis=1)       # (T, L, k)
+        picks = jnp.stack([t for _, t, _ in tally], axis=1)    # (T, L, k)
         if rows is None:
             return _moe_counts(tally), picks, None
         return _moe_counts(tally), picks[rows], picks

@@ -147,10 +147,13 @@ def _init_layer(key, cfg: ModelConfig, dense_mlp: bool, mesh=None) -> Params:
         lp["post_mlp_norm"] = d.norm(h)
     if cfg.num_experts and not dense_mlp:
         ei = cfg.expert_intermediate_size
+        # the router is as wide as the model has experts; the stacks hold
+        # the experts this process holds (a share: cfg.moe_experts_held)
         E = cfg.num_experts
 
         def experts(n_in, n_out):
-            return {"kernel": d.normal((E, n_in, n_out), n_in ** -0.5)}
+            return {"kernel": d.normal((cfg.moe_local_experts, n_in, n_out),
+                                       n_in ** -0.5)}
         lp["router"] = d.dense(h, E, False)
         if cfg.moe_router_bias:
             # e_score_correction_bias: selection-only, stays f32
@@ -403,7 +406,9 @@ def _load_llama_family(cfg: ModelConfig, raw: dict, dtype) -> Params:
             lp["experts"] = {
                 proj: {"kernel": jnp.stack([
                     _t(get(pre + f"mlp.experts.{e}.{proj}.weight"), dtype)
-                    for e in range(cfg.num_experts)])}
+                    for e in range(cfg.moe_first_expert,
+                                   cfg.moe_first_expert
+                                   + cfg.moe_local_experts)])}
                 for proj in ("gate_proj", "up_proj", "down_proj")}
             if cfg.moe_shared_experts:
                 lp["shared"] = {
@@ -423,6 +428,16 @@ def _load_llama_family(cfg: ModelConfig, raw: dict, dtype) -> Params:
             lp["down_proj"] = dense(pre + mlp + "down_proj.weight")
         layers.append(lp)
 
+    if cfg.moe_experts_held and \
+            raw["model.embed_tokens.weight"].shape[0] != cfg.vocab_size:
+        # a share takes its experts above; a slice of the vocabulary is
+        # stated as a smaller vocabulary (benchmark/harness/plan.py
+        # SHARE), which does not say WHICH rows
+        raise ValueError(
+            f"{cfg.name}: the checkpoint has "
+            f"{raw['model.embed_tokens.weight'].shape[0]} vocabulary rows, "
+            f"the configuration {cfg.vocab_size}; a sliced vocabulary runs "
+            "on random weights only")
     params = {
         "embed": {"weight": jnp.asarray(get("model.embed_tokens.weight"), dtype=dtype)},
         "layers": layers,

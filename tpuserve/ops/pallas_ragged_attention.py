@@ -44,9 +44,11 @@ from jax.experimental.pallas import tpu as pltpu
 from tpuserve.ops import scopes
 from tpuserve.ops.attention import SCALE_LANES, dequantize_kv
 from tpuserve.ops.pallas_paged_attention import (TARGET_GROUP_ROWS,
+                                                 VMEM_LIMIT_BYTES,
                                                  _clamp_to_vmem_budget,
                                                  _scale_rows,
-                                                 compiler_params)
+                                                 compiler_params,
+                                                 vmem_footprint)
 
 #: what the kernel's custom call is called in a profiler trace (the HLO
 #: instruction's name), as the decode and flash kernels have theirs
@@ -79,6 +81,25 @@ def ragged_block(blk_q: int | None = None) -> int:
                 "(the flat-token bucket ladder is power-of-two)")
         return n
     return DEFAULT_BLOCK_Q if jax.default_backend() == "tpu" else 8
+
+
+def ragged_block_for(num_q_heads: int, num_kv_heads: int, head_dim: int,
+                     page_size: int, kv_itemsize: int, q_itemsize: int,
+                     quantized: bool = False) -> int:
+    """:func:`ragged_block` for a model's shape: halved until the kernel's
+    q and out blocks and its live tiles fit the VMEM budget beside ONE
+    page of K and V (the page group may shrink inside the kernel, the
+    block may not: it is the layout contract with the host packing).  128
+    rows at up to 32 query heads of 128; 64 at 64 heads on 8 KV heads.
+    An explicit ``TPUSERVE_RAGGED_BLOCK`` stands as it is."""
+    blk = ragged_block()
+    if os.environ.get("TPUSERVE_RAGGED_BLOCK"):
+        return blk
+    while blk > 8 and vmem_footprint(
+            1, blk, blk, page_size, num_kv_heads, head_dim, kv_itemsize,
+            num_q_heads, q_itemsize, quantized) > VMEM_LIMIT_BYTES:
+        blk //= 2
+    return blk
 
 
 def _ragged_kernel(bt_ref, kv_ref, qs_ref, ql_ref, meta_ref, bseq_ref,

@@ -53,7 +53,13 @@ The table (scope -> where it opens -> which metric reads it):
     moe.experts     _moe_mlp: the three grouped products and
                     what lies between; _moe_dense_experts      (moe.gmm_* read the kernel by its name)
     moe.combine     _moe_mlp: the add-back's permutation, the
-                    weighted sum over a token's picks          moe.around_gmm_device_share
+                    weighted sum over a token's picks (under a share,
+                    _moe_held_experts: the weighted add to
+                    each row's token)                          moe.around_gmm_device_share
+    moe.shared      _moe_mlp: the shared expert beside the
+                    routed ones (LATER_PARTS: the accepted
+                    reader files it under mlp, which encloses
+                    it)                                        moe.shared_device_share
     ssm.in_proj     _ssm_project                              trunk.decode_proj_ms
     ssm.conv        ops/ssm.py causal_conv, next_tail; _ssm_inputs;
                     the memory's gather and shift              ssm.prefill_scan_device_share (prefill/, chunk/)
@@ -102,6 +108,12 @@ SSM_OUT = "ssm.out"
 HEAD = "head"
 SAMPLE = "sample"
 CARRY = "carry"
+MOE_SHARED = "moe.shared"
+# the parts ``_scope_trace.py`` files time under: its copy is the
+# benchmark's, and only a PR to the benchmark changes it
 PARTS = (EMBED, ATTN_QKV, ATTN_KV_WRITE, ATTN_KERNEL, ATTN_OUT, MLP,
          MOE_ROUTE, MOE_GATHER, MOE_EXPERTS, MOE_COMBINE, SSM_IN_PROJ,
          SSM_CONV, SSM_SCAN, SSM_OUT, HEAD, SAMPLE, CARRY)
+# parts opened since that copy was taken, each read by a reader of its
+# own; that copy files their time under the part that encloses them
+LATER_PARTS = (MOE_SHARED,)

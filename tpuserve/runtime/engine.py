@@ -324,6 +324,15 @@ class EngineStats:
     moe_routed_rows: int = 0
     moe_expert_hits: int = 0
     moe_expert_rows: Optional[np.ndarray] = None
+    # under a share (ModelConfig.moe_experts_held; zero without one): of
+    # the routed rows those that landed on the experts held here, the held
+    # expert-layers that got at least one, the rows of buffer the layer
+    # gathered and multiplied for them and the pieces it moved them in,
+    # each three grouped products (_moe_held_experts)
+    moe_held_rows: int = 0
+    moe_held_hits: int = 0
+    moe_buffer_rows: int = 0
+    moe_held_pieces: int = 0
     model_swaps: int = 0
     model_swaps_by_outcome: dict = dataclasses.field(default_factory=dict)
     swap_latencies: list = dataclasses.field(default_factory=list)
@@ -414,6 +423,14 @@ class Engine:
         self.mesh = mesh
         from tpuserve.parallel.mesh import AXIS_PP
         self._pp = mesh.shape.get(AXIS_PP, 1) if mesh is not None else 1
+        if self.model_cfg.moe_experts_held and mesh is not None:
+            raise ValueError(
+                f"{self.model_cfg.name} holds "
+                f"{self.model_cfg.moe_experts_held} of "
+                f"{self.model_cfg.num_experts} experts, which is one "
+                "device's share: under a mesh the expert layer runs its "
+                "dense form over ALL experts, and the exchange that would "
+                "join the shares does not exist yet")
         # Recurrent state (state-space layers beside attention): one slot
         # of state a running sequence, in a pool beside the paged KV cache
         # (self.ssm_state, below).  Nothing snapshots a slot, so every
@@ -671,8 +688,15 @@ class Engine:
         # of truth, ops/pallas_ragged_attention.ragged_block) and the
         # FIXED descriptor width, so the flat-token bucket is the ONLY
         # varying dimension across mixed executables.
-        from tpuserve.ops.pallas_ragged_attention import ragged_block
-        self._ragged_blk = ragged_block()
+        # The block follows the model's shape (64 query heads do not fit
+        # 128 rows of them in the kernel's VMEM budget): observed, no option.
+        from tpuserve.ops.pallas_ragged_attention import ragged_block_for
+        self._ragged_blk = ragged_block_for(
+            self.model_cfg.num_heads, self.model_cfg.cache_kv_heads,
+            self.model_cfg.cache_head_dim, self.cache_cfg.block_size,
+            jnp.dtype(self.cache_cfg.dtype).itemsize,
+            jnp.dtype(self.model_cfg.dtype).itemsize,
+            self.cache_cfg.quantized)
         self._ragged_seqs = next_power_of_2(sched_cfg.max_num_seqs)
         # Packed batched prefill: a prefill batch laid out on ONE flat
         # token axis through the same ragged trunk (zero decode rows),
@@ -2206,18 +2230,25 @@ class Engine:
     def _moe_note(self, seqs: list, counts: list) -> None:
         """File routing counts the host has read: the totals behind
         ``tpuserve_moe_*`` and each dispatch's step record."""
+        E = self.model_cfg.num_experts
         for seq, c in zip(seqs, counts):
             c = np.asarray(c, np.int64)
-            rows, hits = int(c[:-1].sum()), int(c[-1])
+            rows, hits = int(c[:E].sum()), int(c[E])
             self.stats.moe_routed_rows += rows
             self.stats.moe_expert_hits += hits
             by_expert = self.stats.moe_expert_rows
-            if by_expert is None or len(by_expert) != len(c) - 1:
+            if by_expert is None or len(by_expert) != E:
                 # (a swapped-in model with another number of experts
                 # starts its own row)
-                by_expert = np.zeros(len(c) - 1, np.int64)
-            self.stats.moe_expert_rows = by_expert + c[:-1]
-            self.flight.note_moe(seq, rows, hits)
+                by_expert = np.zeros(E, np.int64)
+            self.stats.moe_expert_rows = by_expert + c[:E]
+            held = tuple(int(n) for n in c[E + 1:])   # a share's four
+            if held:
+                self.stats.moe_held_rows += held[0]
+                self.stats.moe_held_hits += held[1]
+                self.stats.moe_buffer_rows += held[2]
+                self.stats.moe_held_pieces += held[3]
+            self.flight.note_moe(seq, rows, hits, *held)
 
     def _note_prompt_picks(self, req: Request, row: int, done: int,
                            take: int) -> None:

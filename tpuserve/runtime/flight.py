@@ -73,6 +73,10 @@ SLI_KINDS = ("ttft", "itl", "e2e")
 # bound post-mortem disk usage: a fault storm must not convert the model
 # PVC into a bundle dump
 MAX_POSTMORTEMS = 32
+# a step record's routing counts, in the order note_moe() takes them: the
+# first two for every model with expert layers, all six under a share
+MOE_FIELDS = ("moe_rows", "moe_expert_hits", "moe_held_rows",
+              "moe_held_hits", "moe_buffer_rows", "moe_held_pieces")
 
 
 class _Ring:
@@ -200,18 +204,22 @@ class FlightRecorder:
                             round(dur_s * 1000, 4), phases or None, dev,
                             self.seq, ctx_tokens))
 
-    def note_moe(self, seq: int, rows: int, hits: int) -> None:
+    def note_moe(self, seq: int, *counts: int) -> None:
         """The routing counts of step ``seq``'s dispatch (a model with
         expert layers): rows the sparse dispatch routed, summed over the
-        layers and fused steps, and expert-layers that got at least one.
+        layers and fused steps, and expert-layers that got at least one;
+        where the model holds a share of its experts, four more (the
+        rows that landed on held experts, the held expert-layers hit,
+        the rows of buffer moved and the pieces they moved in:
+        ``MOE_FIELDS``).
         They come back with the dispatch's tokens, a cycle or more after
         its step record was written, so they are kept beside the ring
         (as many as it holds) and joined in ``steps_snapshot``; a step
         with several dispatches (a prefill and a window) sums them."""
         old = self._moe.get(seq)
         if old is not None:
-            rows, hits = rows + old[0], hits + old[1]
-        self._moe[seq] = (rows, hits)
+            counts = tuple(a + b for a, b in zip(counts, old))
+        self._moe[seq] = counts
         while len(self._moe) > self._steps._n:
             del self._moe[next(iter(self._moe))]
 
@@ -290,9 +298,7 @@ class FlightRecorder:
                 # device-time attribution deltas (runtime/devprof.py):
                 # device_ms / dispatch_ms / compiles for this step
                 rec["dev"] = dev
-            moe = self._moe.get(seq)
-            if moe is not None:
-                rec["moe_rows"], rec["moe_expert_hits"] = moe
+            rec.update(zip(MOE_FIELDS, self._moe.get(seq, ())))
             out.append(rec)
         return out
 

@@ -280,6 +280,25 @@ class ServerMetrics:
             "load, not routing skew: a dispatch's padding rows all carry "
             "token 0 and go to that token's experts, so evenly routed "
             "traffic reads 1.4-1.5 at the usual padding share, not 1.0")
+        # a share of the experts (ModelConfig.moe_experts_held): all zero
+        # for a model that holds every expert
+        self.moe_held_rows = counter(
+            "tpuserve_moe_held_rows",
+            "Of tpuserve_moe_routed_rows, the rows that landed on the "
+            "experts this process holds; the rest went to experts other "
+            "chips of the deployment hold and are left out here")
+        self.moe_held_hits = counter(
+            "tpuserve_moe_held_hits",
+            "Held expert-layers that got at least one row in a dispatch "
+            "step: each reads its three kernels once")
+        self.moe_buffer_rows = counter(
+            "tpuserve_moe_buffer_rows",
+            "Rows of buffer the expert layer gathered and multiplied for "
+            "the held rows (whole pieces: over tpuserve_moe_held_rows by "
+            "the last piece's slack)")
+        self.moe_experts_held = gauge(
+            "tpuserve_moe_experts_held",
+            "Experts of each expert layer this process holds (0: all)")
         # windows by layer kind: what an allocator by layer kind would
         # give back (ROADMAP M3)
         self.kv_window_dead_tokens = gauge(

@@ -1045,7 +1045,13 @@ class AsyncEngineRunner:
                                  ("ssm_rebuilt_tokens",
                                   self.metrics.ssm_rebuilt_tokens),
                                  ("moe_routed_rows",
-                                  self.metrics.moe_routed_rows)):
+                                  self.metrics.moe_routed_rows),
+                                 ("moe_held_rows",
+                                  self.metrics.moe_held_rows),
+                                 ("moe_held_hits",
+                                  self.metrics.moe_held_hits),
+                                 ("moe_buffer_rows",
+                                  self.metrics.moe_buffer_rows)):
                 _advance_counter(
                     metric, sum(getattr(s, attr, 0) for s in stats_objs))
             by_expert = [s.moe_expert_rows for s in stats_objs
@@ -1104,6 +1110,8 @@ class AsyncEngineRunner:
                                        for bm in bms) if seats is not None))
         self.metrics.kv_tier_blocks.labels(tier="hbm", **label).set(
             sum(getattr(bm, "num_cached_blocks", 0) for bm in bms))
+        self.metrics.moe_experts_held.set(
+            getattr(getattr(eng, "model_cfg", None), "moe_experts_held", 0))
         dead = [e.window_dead_tokens() for e in (inners or [eng])
                 if hasattr(e, "window_dead_tokens")]
         if dead:
