@@ -321,11 +321,12 @@ def test_the_grouped_product_compiles_for_v5e(shape, one_chip):
                      r"tpu_custom_call", text)
 
 
-# flat-token rungs a packed prefill of the benchmark's cells can take
+# EVERY flat-token rung a packed prefill of the benchmark's cells can take
 # (scheduler.packed_prefill_bucket: 13 rungs to max_prefill_tokens; all 13
-# and the whole 12-layer trunk were compiled once by hand, PR 35) and the
-# largest decode bucket
-MOE_TOKENS = [128, 512, 1024, 1536, 2048, 3072, 4096, 8192, 64]
+# and the chunk program were also compiled as whole 12-layer trunks by
+# hand, PRs 35 and 42) and the largest decode bucket
+MOE_TOKENS = [128, 256, 512, 768, 1024, 1280, 1536, 1792, 2048, 3072, 4096,
+              6144, 8192, 64]
 
 
 @pytest.mark.parametrize("tokens", MOE_TOKENS)
@@ -334,9 +335,15 @@ def test_the_expert_layer_compiles_for_v5e_at_every_rung(tokens, one_chip,
     """The whole sparse expert layer (router, sort, the rows' gather, three
     grouped products, the add-back) at Mellum2-12B-A2.5B's widths.  What
     this guards: the TPU compiler refuses the PLAIN row gather of 1,536
-    tokens into 12,288 rows (scoped VMEM, by 0.4 MB; found on the chip,
-    PR 35) and no other rung; ``_gather_rows`` compiles at all of them."""
+    tokens into 12,288 rows for the grouped product (scoped VMEM, by
+    0.4 MB; found on the chip, PR 35) and no other rung; what
+    ``_gather_rows`` chooses compiles at all of them: the rows go into
+    expert order plain from 16,384 rows (then no ``(rows, 18, 128)``
+    array and none of its relayout copies is left in the program) and as
+    ``(tiles, 128)`` slices under that, and come back plain wherever a
+    prefill permutes them (PR 42)."""
     import dataclasses
+    import re
 
     from tpuserve.models import transformer
     from tpuserve.models.config import get_model_config
@@ -364,6 +371,11 @@ def test_the_expert_layer_compiles_for_v5e_at_every_rung(tokens, one_chip,
     # the experts' kernels go to the custom calls as they are: no copy
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 5 * tokens * 8 * H * 2 + (64 << 20)
+    rows = tokens * cfg.num_experts_per_tok
+    into, back = transformer.moe_plain_moves(cfg, tokens)
+    assert (into, back) == (rows >= 16384, rows > 1024)
+    sliced = len(re.findall(rf"= bf16\[{rows},18,128\][^\n]* fusion\(", text))
+    assert sliced == (not into) + (not back), (sliced, into, back)
     if tokens == 1536:
         k = cfg.num_experts_per_tok
         with pytest.raises(Exception, match="vmem"):

@@ -399,20 +399,65 @@ def _moe_held_experts(xt, ek, topi, topv, cfg: ModelConfig):
     return y.astype(xt.dtype), stats
 
 
+# rows from which a gather that REPEATS rows goes by the plain form: where
+# it pulls ahead of the sliced one (2,304 bf16 a row, ns a row sliced /
+# plain: 14.9 / 17.1 at 8 k rows, 14.0 / 7.8 at 16 k, 31 / 8.7 at 32 k;
+# PERF.md §6, PR 42), and past the one rung the compiler refuses it at
+_REPEATS_PLAIN_ROWS = 16384
+
+
+def _gathers_plain(rows: int, source_rows: int, width: int) -> bool:
+    """Whether :func:`_gather_rows` takes ``rows`` rows of ``width`` values
+    out of ``source_rows`` by the plain form: a static function of shapes.
+    A width that is no whole number of 128-lane tiles has no other form; a
+    decode window's rows (``pallas_moe_gmm.DECODE_ROWS`` and fewer) keep
+    the sliced one, whose programs are what they were (the two read within
+    a tenth there); above that a PERMUTATION (as many rows out as in: the
+    add-back's, whose result XLA's own sum reads) goes plain, and a gather
+    that repeats rows (into expert order, for the grouped product's custom
+    call) from ``_REPEATS_PLAIN_ROWS`` rows."""
+    from tpuserve.ops.pallas_moe_gmm import DECODE_ROWS
+    return bool(width % 128) or (rows > DECODE_ROWS and (
+        rows == source_rows or rows >= _REPEATS_PLAIN_ROWS))
+
+
+def moe_plain_moves(cfg: ModelConfig, tokens: int) -> tuple[bool, bool]:
+    """Of an expert layer's two row moves in a dispatch of ``tokens`` rows
+    (into expert order; back beside each token's other picks), which go
+    by the plain gather: what the engine counts beside the routed rows
+    (``EngineStats.moe_row_moves_plain``), from shapes alone.  Under a
+    share one piece's rows are gathered and nothing is gathered back."""
+    pairs, width = tokens * cfg.num_experts_per_tok, cfg.hidden_size
+    if cfg.moe_experts_held:
+        piece = held_piece_rows(pairs, cfg.moe_experts_held, cfg.num_experts)
+        return _gathers_plain(piece, tokens, width), False
+    return (_gathers_plain(pairs, tokens, width),
+            _gathers_plain(pairs, pairs, width))
+
+
 def _gather_rows(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    """``x[idx]`` for rows of a whole number of 128-lane tiles, gathered
-    as ``(tiles, 128)`` slices: the plain 2-D row gather of a (1,536,
-    2,304) bf16 array into 12,288 rows is refused by the TPU compiler
-    (its memory-space assignment puts operand and result in VMEM and the
-    gather then lacks 0.4 MB of scoped VMEM; every other rung of the
-    packed-prefill ladder compiles), this form compiles at every rung
-    (tests/test_chip_compile.py holds both facts)."""
-    width = x.shape[-1]
+    """``x[idx]``, in the form that is faster on the chip AT THESE SHAPES
+    (:func:`_gathers_plain`; the forms agree bit for bit: rows are moved,
+    not computed).  Measured with ``tools/moe_row_move_probe.py`` (PERF.md
+    §6, PR 42):
+
+    * the plain row gather reads a source that fits VMEM from there and
+      writes rows in the layout their consumer reads: 8-17 ns a row of
+      4,608 bytes (54 once a source of 32 k rows no longer fits);
+    * the same gather of ``(tiles, 128)`` slices pays two relayout passes
+      over the result (and two over a source as large): 14-61 ns a row.
+      It exists because the compiler REFUSES the plain form at one rung of
+      the packed-prefill ladder: 1,536 tokens into 12,288 rows for the
+      grouped product's custom call, where memory-space assignment puts
+      operand and result in VMEM and the gather then lacks 0.4 MB of
+      scoped VMEM (tests/test_chip_compile.py holds both facts, and that
+      what is chosen here compiles at every rung)."""
+    rows, width = idx.shape[0], x.shape[-1]
     with jax.named_scope(scopes.MOE_GATHER):
-        if width % 128:
+        if _gathers_plain(rows, x.shape[0], width):
             return x[idx]
         return x.reshape(x.shape[0], width // 128, 128)[idx].reshape(
-            idx.shape[0], width)
+            rows, width)
 
 
 def _moe_dense_experts(xt, ek, topi, topv, cfg: ModelConfig):

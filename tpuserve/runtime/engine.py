@@ -29,6 +29,7 @@ import numpy as np
 from tpuserve.models import transformer
 from tpuserve.models.config import ModelConfig, get_model_config
 from tpuserve.models.tokenizer import IncrementalDetokenizer, load_tokenizer
+from tpuserve.models.transformer import moe_plain_moves
 from tpuserve.models.weights import load_or_init, param_dtype
 from tpuserve.ops import sampling as sampling_ops
 from tpuserve.ops.attention import PAD_SLOT, kv_stream_by_page
@@ -324,6 +325,13 @@ class EngineStats:
     moe_routed_rows: int = 0
     moe_expert_hits: int = 0
     moe_expert_rows: Optional[np.ndarray] = None
+    # of a routed row's two moves around the grouped product (into expert
+    # order, and back beside its token's other picks; under a share one
+    # move of a buffer row), those the layer made by the plain row gather:
+    # a static choice from each dispatch's shapes (transformer.
+    # moe_plain_moves, no dispatch: a function of shapes), so a host integer.  Over 2 x moe_routed_rows: the
+    # share of moves that took the fast form
+    moe_row_moves_plain: int = 0
     # under a share (ModelConfig.moe_experts_held; zero without one): of
     # the routed rows those that landed on the experts held here, the held
     # expert-layers that got at least one, the rows of buffer the layer
@@ -2197,31 +2205,38 @@ class Engine:
             seats = self._seat_ids([], rows)
         return {"ssm": self.ssm_state, "seats": seats, **self._moe_kw}
 
-    def _keep_pool(self, res: tuple) -> tuple:
+    def _keep_pool(self, res: tuple, tokens: int) -> tuple:
         """The trunk's result as every caller reads it: the updated seat
         pool, which a model with recurrent state returns after them, is
         kept here, and so is the routing that a model with expert layers
         returns last (still on the device: the counts are read with the
         dispatch's tokens, the logits rows' picks with their logprobs
-        where a request asked for those)."""
+        where a request asked for those).  ``tokens``: the rows of the
+        trunk's flat token axis, from which the expert layers chose how
+        to move their rows."""
         if self.ssm_state is None and not self._moe_counted:
             return res
         res = list(res)
         if self._moe_counted:
             counts, self._moe_picks, self._moe_prompt_picks = res.pop()
-            self._moe_inflight.append((self.flight.seq, counts))
+            # (the dense form a mesh takes gathers nothing)
+            moves = 0 if self._moe_kw else sum(
+                moe_plain_moves(self.model_cfg, tokens))
+            self._moe_inflight.append(((self.flight.seq, moves), counts))
         if self.ssm_state is not None:
             self.ssm_state = res.pop()
         return tuple(res)
 
     def _moe_due(self, seq: int) -> tuple[list, list]:
-        """``(step seqs, device arrays)`` of the routing counts of the
+        """``(step seqs, each with the plain moves of a row its dispatch
+        moved; device arrays)`` of the routing counts of the
         dispatches up to step ``seq``, taken off the in-flight list: the
         device runs dispatches in order, so they are ready when that
         step's tokens are, and the read that fetches those tokens fetches
         them too (no sync of their own)."""
         n = 0
-        while n < len(self._moe_inflight) and self._moe_inflight[n][0] <= seq:
+        while n < len(self._moe_inflight) \
+                and self._moe_inflight[n][0][0] <= seq:
             n += 1
         due, self._moe_inflight = (self._moe_inflight[:n],
                                    self._moe_inflight[n:])
@@ -2231,7 +2246,7 @@ class Engine:
         """File routing counts the host has read: the totals behind
         ``tpuserve_moe_*`` and each dispatch's step record."""
         E = self.model_cfg.num_experts
-        for seq, c in zip(seqs, counts):
+        for (seq, moves), c in zip(seqs, counts):
             c = np.asarray(c, np.int64)
             rows, hits = int(c[:E].sum()), int(c[E])
             self.stats.moe_routed_rows += rows
@@ -2248,7 +2263,10 @@ class Engine:
                 self.stats.moe_held_hits += held[1]
                 self.stats.moe_buffer_rows += held[2]
                 self.stats.moe_held_pieces += held[3]
-            self.flight.note_moe(seq, rows, hits, *held)
+            # (a share moves its buffer's rows, a whole layer every row)
+            plain = (held[2] if held else rows) * moves
+            self.stats.moe_row_moves_plain += plain
+            self.flight.note_moe(seq, rows, hits, plain, *held)
 
     def _note_prompt_picks(self, req: Request, row: int, done: int,
                            take: int) -> None:
@@ -2305,7 +2323,7 @@ class Engine:
                 self.params, self.model_cfg, tokens, prompt_lens, slot_ids,
                 self.kv_cache, ad, attn_impl=self.attn_impl,
                 mesh=self._attn_mesh,
-                **self._pool_kw(seats, tokens.shape[0])))
+                **self._pool_kw(seats, tokens.shape[0])), tokens.size)
 
     def _exec_decode(self, tokens, positions, slot_ids, block_tables,
                      seq_lens, ad=None, seats=None):
@@ -2321,7 +2339,7 @@ class Engine:
                 self.params, self.model_cfg, tokens, positions, slot_ids,
                 block_tables, seq_lens, self.kv_cache, ad,
                 attn_impl=self.attn_impl, mesh=self._attn_mesh,
-                **self._pool_kw(seats, tokens.shape[0])))
+                **self._pool_kw(seats, tokens.shape[0])), tokens.size)
 
     def _exec_prefill_chunk(self, tokens, ctx_lens, chunk_lens, slot_ids,
                             block_tables, ad=None, seats=None):
@@ -2334,7 +2352,7 @@ class Engine:
                 self.params, self.model_cfg, tokens, ctx_lens, chunk_lens,
                 slot_ids, block_tables, self.kv_cache, ad,
                 attn_impl=self.attn_impl, mesh=self._attn_mesh,
-                **self._pool_kw(seats, tokens.shape[0])))
+                **self._pool_kw(seats, tokens.shape[0])), tokens.size)
 
     def _exec_decode_verify(self, tokens, ctx_lens, chunk_lens, slot_ids,
                             block_tables):
@@ -2406,7 +2424,7 @@ class Engine:
                 gmasks=gmasks, gclass=gclass, gnext=gnext,
                 attn_impl=self.attn_impl,
                 mesh=self._attn_mesh, out_mesh=self.mesh,
-                **self._pool_kw(seats, tokens.shape[0])))
+                **self._pool_kw(seats, tokens.shape[0])), tokens.size)
 
     def _exec_forward_ragged(self, tokens, positions, slot_ids, row_seq,
                              block_tables, kv_lens, q_starts, q_lens,
@@ -2430,7 +2448,7 @@ class Engine:
                 blk_seq, last_rows, self.kv_cache, ad,
                 ragged_blk=self._ragged_blk, attn_impl=self._ragged_attn,
                 decode_rows=kind != "prefill",
-                **self._pool_kw(seats, q_lens.shape[0])))
+                **self._pool_kw(seats, q_lens.shape[0])), tokens.size)
 
     def _exec_sample(self, logits, keys, temperature, top_k, top_p, *,
                      min_p=None, mode):

@@ -74,9 +74,10 @@ SLI_KINDS = ("ttft", "itl", "e2e")
 # PVC into a bundle dump
 MAX_POSTMORTEMS = 32
 # a step record's routing counts, in the order note_moe() takes them: the
-# first two for every model with expert layers, all six under a share
-MOE_FIELDS = ("moe_rows", "moe_expert_hits", "moe_held_rows",
-              "moe_held_hits", "moe_buffer_rows", "moe_held_pieces")
+# first three for every model with expert layers, all seven under a share
+MOE_FIELDS = ("moe_rows", "moe_expert_hits", "moe_moves_plain",
+              "moe_held_rows", "moe_held_hits", "moe_buffer_rows",
+              "moe_held_pieces")
 
 
 class _Ring:
@@ -207,7 +208,8 @@ class FlightRecorder:
     def note_moe(self, seq: int, *counts: int) -> None:
         """The routing counts of step ``seq``'s dispatch (a model with
         expert layers): rows the sparse dispatch routed, summed over the
-        layers and fused steps, and expert-layers that got at least one;
+        layers and fused steps, expert-layers that got at least one, and
+        the row moves around the kernel that went by the plain gather;
         where the model holds a share of its experts, four more (the
         rows that landed on held experts, the held expert-layers hit,
         the rows of buffer moved and the pieces they moved in:

@@ -267,6 +267,12 @@ class ServerMetrics:
             "Rows the sparse expert dispatch routed: tokens of a dispatch "
             "(its padding rows included) x experts a token, summed over "
             "the expert layers and a window's fused steps")
+        self.moe_row_moves_plain = counter(
+            "tpuserve_moe_row_moves_plain",
+            "Of the two moves of each of tpuserve_moe_routed_rows around "
+            "the grouped product (into expert order, and back), those made "
+            "by the plain row gather, chosen from each dispatch's shapes; "
+            "over twice that counter: the share of moves by the fast form")
         self.moe_expert_rows = Counter(
             "tpuserve_moe_expert_rows",
             "The same rows by the expert they were routed to (one index "

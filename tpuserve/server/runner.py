@@ -1046,6 +1046,8 @@ class AsyncEngineRunner:
                                   self.metrics.ssm_rebuilt_tokens),
                                  ("moe_routed_rows",
                                   self.metrics.moe_routed_rows),
+                                 ("moe_row_moves_plain",
+                                  self.metrics.moe_row_moves_plain),
                                  ("moe_held_rows",
                                   self.metrics.moe_held_rows),
                                  ("moe_held_hits",
