@@ -32,6 +32,12 @@ WIDTHS = {
     # window: the decode kernel alone (its other kernels compile at these
     # head counts inside the 12-layer trunks compiled by hand, PR 35)
     "mellum2-12b": (32, 4, 128),
+    # thirty KV heads of ONE query head each (plain multi-head attention),
+    # which reach the kernels as 32 and 32 (ModelConfig.cache_kv_heads: a
+    # page row of 30 heads is not whole sublane tiles): a page of 32
+    # tokens is a (1024, 128) slab, 262 KB a side, where the widths above
+    # have 4 to 8 KV heads of 2 to 8 query heads each
+    "olmo-hybrid-7b": (32, 32, 128),
 }
 PAGE = 32            # server default --block-size
 NUM_BLOCKS = 2048    # server default --num-blocks
@@ -160,7 +166,7 @@ CASES += [("decode-w1024", "mellum2-12b", False)]
 CASES += [(kernel, width, True)
           for kernel in ("decode", "window", "ragged")
           for width in ("qwen3-0.6b", "llama-8b")]
-# the hybrid model never takes a mesh, an int8 cache is not in its cell
+# the hybrid models never take a mesh, an int8 cache is not in their cells
 CASES = [c for c in CASES if c[1] != "falcon-h1-34b" or not c[2]]
 
 
@@ -189,7 +195,7 @@ def test_kernel_compiles_for_v5e(kernel, width, quantized, one_chip,
 
 
 @pytest.mark.parametrize("width", ["qwen3-0.6b", "llama-8b",
-                                   "falcon-h1-34b"])
+                                   "falcon-h1-34b", "olmo-hybrid-7b"])
 @pytest.mark.parametrize("rows", [128, 1792, 8192])
 def test_ragged_kernel_compiles_at_the_packed_prefill_ladder(
         rows, width, one_chip, monkeypatch):
@@ -247,10 +253,156 @@ def test_the_state_update_kernel_compiles_for_v5e(rows, one_chip):
     assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
 
 
+def _olmo_hybrid(**cut):
+    import dataclasses
+
+    from tpuserve.models.config import get_model_config
+    return dataclasses.replace(get_model_config("allenai/Olmo-Hybrid-7B"),
+                               **cut)
+
+
+@pytest.mark.parametrize("rows", [4, MAX_NUM_SEQS])
+def test_the_gdn_state_update_kernel_compiles_for_v5e(rows, one_chip):
+    """``_gdn_state_update`` at Olmo-Hybrid-7B's sizes (30 heads of 96 x
+    192, two a slab) at the smallest and the largest decode bucket:
+    compiled, named as the benchmark's ``lin.*`` readers match it, and in
+    place -- the pool's bytes are aliased from input to output, not copied
+    -- and the pool holds no padding: 65 seats x 2,211,840 B."""
+    import re
+
+    from tpuserve.ops.pallas_gdn_update import (KERNEL_NAME, gdn_state_update,
+                                                heads_per_slab)
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert KERNEL_NAME == "_gdn_state_update"
+    H, dk, dv, f32 = 30, 96, 192, jnp.float32
+    hp = heads_per_slab(H, dv)
+    pool = S((MAX_NUM_SEQS + 1, H // hp, dk, hp * dv), f32)
+    compiled = jax.jit(
+        lambda pool, seats, q, k, v, g, b: gdn_state_update(
+            pool, seats, q, k, v, g, b, interpret=False),
+        donate_argnums=(0,)).lower(
+            pool, S((rows,), jnp.int32), S((rows, H, dk), f32),
+            S((rows, H, dk), f32), S((rows, H, dv), f32), S((rows, H), f32),
+            S((rows, H), f32)).compile()
+    assert re.search(rf"%{KERNEL_NAME}(\.\d+)? = [^\n]*custom-call\([^\n]*"
+                     r"tpu_custom_call", compiled.as_text())
+    pool_bytes = (MAX_NUM_SEQS + 1) * H * dk * dv * 4
+    assert pool_bytes == 65 * 2_211_840
+    assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
+
+
+# the flat-token rungs of a packed prefill at 128-row ragged blocks
+# (scheduler.packed_prefill_bucket: every rung to the budget of 8,192)
+LIN_TOKENS = [128, 256, 512, 768, 1024, 1280, 1536, 1792, 2048, 3072, 4096,
+              6144, 8192]
+
+
+@pytest.mark.parametrize("tokens", LIN_TOKENS)
+def test_a_linear_layer_compiles_for_v5e_at_every_rung(tokens, one_chip):
+    """One linear-attention layer of Olmo-Hybrid-7B at the published
+    widths over a packed prefill of ``tokens`` flat rows, eight prompts:
+    its projections, the convolution, the chunked scan (chunk 64: the
+    triangular solve a chunk and the ``lax.scan`` over chunks), the gated
+    norm and the write of the seats' state and memory into the pool,
+    which stays in place."""
+    from tpuserve.models import transformer
+    from tpuserve.models.weights import init_params
+    from tpuserve.runtime.kv_cache import create_ssm_state
+    from tpuserve.runtime.scheduler import packed_prefill_bucket
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def place(tree):
+        return jax.tree.map(lambda x: S(x.shape, x.dtype), tree)
+
+    assert packed_prefill_bucket(tokens, 128) == tokens
+    cfg = _olmo_hybrid(num_layers=1)
+    lp = place(jax.eval_shape(lambda: init_params(cfg, 0))["layers"][0])
+    assert "lin" in lp and "q_proj" not in lp and "o_proj" not in lp
+    entry = place(jax.eval_shape(
+        lambda: create_ssm_state(cfg, MAX_NUM_SEQS))[0])
+    i32, seqs = jnp.int32, S((PREFILL_SEQS,), jnp.int32)
+
+    def layer(h, lp, positions, slots, blk_seq, q_starts, q_lens, entry,
+              seats):
+        h, entry = transformer._lin_packed(h, lp, cfg, positions, slots,
+                                           blk_seq, q_starts, q_lens, 128,
+                                           entry, seats)
+        return transformer._mlp_residual(h, lp, cfg), entry
+
+    compiled = jax.jit(layer, donate_argnums=(7,)).lower(
+        S((tokens, cfg.hidden_size), jnp.bfloat16), lp, S((tokens,), i32),
+        S((tokens,), i32), S((tokens // 128,), i32), seqs, seqs, entry,
+        seqs).compile()
+    mem = compiled.memory_analysis()
+    # (the chip lays the convolution's three rows a seat out in tiles: a
+    # tenth more than their 69,120 B, half a per cent of the pool)
+    pool_bytes = 65 * (2_211_840 + 3 * 11520 * 4)
+    assert pool_bytes <= mem.alias_size_in_bytes < 1.01 * pool_bytes
+    # what the layer holds beside its weights and the pool: activations a
+    # few times the stream's q, k, v in float32, never a copy of the pool
+    assert mem.temp_size_in_bytes < 40 * tokens * 11520 * 4 + (64 << 20)
+
+
+@pytest.mark.parametrize("program,tokens", [("decode_multi", 0),
+                                            ("forward_ragged", 8192),
+                                            ("prefill_chunk", 0)])
+def test_the_olmo_hybrid_cell_fits_the_chip(program, tokens, one_chip,
+                                            monkeypatch):
+    """The cell's whole trunks at the published widths: 16 layers (12
+    linear, 4 full), a fused decode window of 64 rows, the top rung of the
+    packed-prefill ladder and a chunk, beside a pool of 2,560 pages of 32
+    tokens for the 4 attention layers (what 0.9 of the chip leaves after
+    8.2 GB of weights and 1.83 GB of state).  The chip's compiler refuses
+    what does not fit 16 GB; 30 query heads on 30 KV heads reach the
+    kernels as 32 on 32 and keep the 128-row ragged block."""
+    from test_scopes import trunk_programs
+    from tpuserve.ops.pallas_ragged_attention import ragged_block_for
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def place(tree):
+        return jax.tree.map(lambda x: S(x.shape, x.dtype), tree)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _olmo_hybrid(num_layers=16)
+    assert (cfg.cache_q_heads, cfg.cache_kv_heads) == (32, 32)
+    blk = ragged_block_for(cfg.cache_q_heads, cfg.cache_kv_heads,
+                           cfg.head_dim, PAGE, 2, 2)
+    assert blk == 128
+    fn, args, kwargs = trunk_programs(
+        cfg, S, place, rows=MAX_NUM_SEQS, steps=8, tokens=tokens or blk,
+        blk=blk, prompts=PREFILL_SEQS, chunk=CHUNK, block_size=PAGE,
+        num_blocks=2560, max_blocks=MAX_PAGES, attn_impl="pallas")[program]
+    compiled = fn.lower(*args, **kwargs).compile()
+    mem = compiled.memory_analysis()
+    weights = mem.argument_size_in_bytes - mem.alias_size_in_bytes
+    assert 8.1e9 < weights < 8.3e9, weights
+    # pages and pool stay in place: 4 layers' pages and 12 layers' seats
+    pages = 4 * 2 * 2560 * PAGE * 32 * 128 * 2
+    pool = 12 * 65 * (2_211_840 + 3 * 11520 * 4)
+    assert pages + pool <= mem.alias_size_in_bytes < 1.01 * (pages + pool)
+    # beside them what a dispatch holds of its own stays under the tenth
+    # of the chip the cache's sizer leaves free
+    assert mem.temp_size_in_bytes < 1.4e9, mem.temp_size_in_bytes
+    # 16.91 GB less the runtime's own 0.27: what the compiler itself
+    # holds a program to (2,560 pages here; the sizer gives ~2,470)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.6e9
+    text = compiled.as_text()
+    if program == "decode_multi":
+        assert "_gdn_state_update" in text
+        assert "_paged_decode_attention" in text
+
+
 @pytest.mark.parametrize("rows", [128, 2048, 8192])
-@pytest.mark.parametrize("kv_heads", [8, 4])
+@pytest.mark.parametrize("kv_heads", [8, 4, 32])
 def test_the_page_writer_compiles_for_v5e(kv_heads, rows, one_chip):
-    """``_paged_kv_write`` at the four configurations' KV widths (8 and 4
+    """``_paged_kv_write`` at the configurations' KV widths (8, 4 and 32
     heads of 128), from one ragged block to the packed ladder's top and at
     the chunk size: compiled, named, and in place: both caches' bytes are
     aliased from input to output and the program holds no other buffer of
@@ -582,6 +734,10 @@ def _scheduled(text):
     ("LGAI-EXAONE/K-EXAONE-236B-A23B+share",
      {"_paged_decode_attention": "attn.kernel",
       "_moe_grouped_matmul": "moe.experts"}),
+    # a linear-attention layer, then a full one (the last two of a period)
+    ("allenai/Olmo-Hybrid-7B+last2",
+     {"_paged_decode_attention": "attn.kernel",
+      "_gdn_state_update": "ssm.scan"}),
 ])
 def test_every_operation_of_a_decode_window_names_its_part(
         model, kernels, one_chip, monkeypatch):
@@ -608,14 +764,18 @@ def test_every_operation_of_a_decode_window_names_its_part(
         return jax.tree.map(lambda x: S(x.shape, x.dtype), tree)
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = dataclasses.replace(get_model_config(model), num_layers=2) \
-        if "+share" not in model else _k_exaone_share(num_layers=2)
+    if "+share" in model:
+        cfg = _k_exaone_share(num_layers=2)
+    elif "+last2" in model:
+        cfg = _olmo_hybrid(num_layers=2, linear_layers=(True, False))
+    else:
+        cfg = dataclasses.replace(get_model_config(model), num_layers=2)
     fn, args, kwargs = trunk_programs(
         cfg, S, place, rows=MAX_NUM_SEQS, steps=8, block_size=PAGE,
         num_blocks=NUM_BLOCKS, max_blocks=MAX_PAGES,
         attn_impl="pallas")["decode_multi"]
     comps = _scheduled(fn.lower(*args, **kwargs).compile().as_text())
-    seen, unscoped, waits = set(), [], 0
+    seen, unscoped, waits, ahead = set(), [], 0, 0
     for comp, rows in comps.items():
         scoped = [scope_of(op_name)[1] and scope_of(op_name)
                   for _, _, op_name, _ in rows]
@@ -648,7 +808,16 @@ def test_every_operation_of_a_decode_window_names_its_part(
                     unscoped.append((name, op_name))
                 continue
             nxt = next((s for s in scoped[i + 1:] if s), None)
-            if opcode == "slice-done":
+            if opcode == "slice-done" and not consumers(i):
+                # a POOL the window carries (the convolution's memory of a
+                # model with linear layers, 9 MB a layer) that the
+                # compiler keeps in its faster memory space from step to
+                # step, copied there in slices at the end of the loop's
+                # body: its consumer is the NEXT step (the carry), so the
+                # reader files the wait with whatever part follows it
+                # (PERF.md §7 row 24): counted, and held to those few
+                ahead += 1
+            elif opcode == "slice-done":
                 waits += 1
                 assert nxt in consumers(i), (name, nxt, consumers(i))
             elif not comp.startswith("main"):
@@ -658,3 +827,4 @@ def test_every_operation_of_a_decode_window_names_its_part(
     assert not unscoped, unscoped
     assert seen == set(kernels)
     assert waits >= 8       # the layers' weight matrices are prefetched
+    assert ahead <= (4 if "_gdn_state_update" in kernels else 0), ahead

@@ -27,6 +27,10 @@ FAMILY = {
                                 scopes.SSM_SCAN, scopes.SSM_OUT},
     "tiny-mellum2": COMMON | {scopes.MOE_ROUTE, scopes.MOE_GATHER,
                               scopes.MOE_EXPERTS, scopes.MOE_COMBINE},
+    # linear-attention layers IN PLACE OF attention in six layers of eight:
+    # the recurrent mixer's four parts by role, and attention's in the rest
+    "tiny-olmo-hybrid": COMMON | {scopes.SSM_IN_PROJ, scopes.SSM_CONV,
+                                  scopes.SSM_SCAN, scopes.SSM_OUT},
     # a share of the experts (8 of 32) beside a shared expert
     "tiny-k-exaone+share": COMMON | {scopes.MOE_ROUTE, scopes.MOE_GATHER,
                                      scopes.MOE_EXPERTS, scopes.MOE_COMBINE,
@@ -75,7 +79,7 @@ def trunk_programs(cfg, S=jax.ShapeDtypeStruct, place=lambda tree: tree, *,
     kv = place(jax.eval_shape(lambda: create_kv_cache(cfg, cache_cfg)))
 
     def tail(n):        # the seat pool and the seats, where there is one
-        if not cfg.has_ssm:
+        if not cfg.has_state:
             return ()
         pool = place(jax.eval_shape(lambda: create_ssm_state(cfg, rows + 1)))
         return (None, pool, S((n,), i32))

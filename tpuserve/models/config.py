@@ -16,6 +16,11 @@ import json
 import os
 from typing import Optional
 
+# ModelConfig.layer_mixer()'s answers
+MIXER_ATTENTION = "attention"
+MIXER_LINEAR = "linear_attention"
+MIXER_BOTH = "attention+ssm"
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
@@ -33,7 +38,7 @@ class ModelConfig:
     norm_eps: float = 1e-6
     act: str = "silu"                # "silu" | "gelu" | "relu"
     mlp_style: str = "gated"         # "gated" (SwiGLU-style) | "mlp" (2-layer)
-    pos: str = "rope"                # "rope" | "learned"
+    pos: str = "rope"                # "rope" | "learned" | "none"
     rope_theta: float = 10000.0
     partial_rotary_factor: float = 1.0
     qk_norm: bool = False            # Qwen3 per-head RMSNorm on q/k
@@ -178,6 +183,34 @@ class ModelConfig:
     ssm_out_multiplier: float = 1.0
     mlp_multipliers: tuple = (1.0, 1.0)               # (gate, down)
     ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)  # z, x, B, C, dt
+    # Layers that differ in their KIND OF MIXER (Olmo-Hybrid): True where
+    # a layer is a gated delta-rule linear-attention layer, which holds a
+    # matrix state a SEQUENCE (lin_num_value_heads x lin_key_head_dim x
+    # lin_value_head_dim, float32) and the last lin_conv_kernel - 1 inputs
+    # of its short convolution, and no K/V pages at all; False where it
+    # is an attention layer, which holds pages and no state.  One entry a
+    # PUBLISHED layer (a cut of the depth keeps the first num_layers, as
+    # window_layers does); None: every layer attends -- see layer_mixer().
+    # ``lin_allow_neg_eigval`` doubles the step size beta, so a state's
+    # transition alpha (I - beta k k^T) may reflect (arXiv:2411.12537);
+    # prefill evaluates the recurrence lin_chunk_size rows at a time
+    # (ops/gated_delta.py).
+    linear_layers: Optional[tuple] = None
+    lin_num_key_heads: int = 0
+    lin_num_value_heads: int = 0
+    lin_key_head_dim: int = 0
+    lin_value_head_dim: int = 0
+    lin_conv_kernel: int = 4
+    lin_allow_neg_eigval: bool = False
+    lin_chunk_size: int = 64
+    # Where a layer's two norms stand: "pre" on each branch's INPUT (every
+    # family above), "post" on its OUTPUT before the add to the residual
+    # stream (the OLMo 2 / 3 family: x + norm(mixer(x)), x + norm(mlp(x))).
+    norm_placement: str = "pre"
+    # The q/k norm (``qk_norm``) over the WHOLE projection, all heads at
+    # once, before the split into heads (OLMo 2 / 3) -- not a head at a
+    # time (Qwen3).
+    qk_norm_whole: bool = False
 
     def __post_init__(self):
         if self.moe_experts_held or self.moe_first_expert:
@@ -187,13 +220,69 @@ class ModelConfig:
                 raise ValueError(
                     f"{self.name}: cannot hold experts {first} to "
                     f"{first + held - 1} of {self.num_experts}")
+        if self.linear_layers is not None:
+            if len(self.linear_layers) < self.num_layers \
+                    or not self.lin_num_value_heads:
+                raise ValueError(
+                    f"{self.name}: linear_layers states "
+                    f"{len(self.linear_layers)} layers of {self.num_layers} "
+                    "(or no lin_* sizes)")
+            if self.lin_num_key_heads != self.lin_num_value_heads:
+                raise ValueError(
+                    f"{self.name}: {self.lin_num_key_heads} key heads under "
+                    f"{self.lin_num_value_heads} value heads in the linear "
+                    "layers: one key head a value head is what runs")
+            if self.mamba_d_ssm:
+                raise ValueError(f"{self.name}: linear-attention layers "
+                                 "and state-space heads in one model")
 
     @property
     def has_ssm(self) -> bool:
-        """True when every layer carries a recurrent state: what the
-        engine observes to build the seat pool and to close the routes
-        that would need a snapshot of it."""
+        """True when every layer runs Mamba-2 heads BESIDE its attention
+        (Falcon-H1): the weights' layout and names ask this.  Whether a
+        layer holds a recurrent state is :meth:`layer_mixer`'s answer."""
         return self.mamba_d_ssm > 0
+
+    def layer_mixer(self, layer_idx: int) -> str:
+        """One layer's kind of mixer, beside layer_window() and
+        layer_rotates() -- ONE function for every forward path, the cache
+        and the seat pool: ``MIXER_ATTENTION`` (K/V pages, no state),
+        ``MIXER_LINEAR`` (a gated delta-rule state, no pages) or
+        ``MIXER_BOTH`` (Falcon-H1: state-space heads beside attention
+        heads, pages and a state)."""
+        if self.mamba_d_ssm:
+            return MIXER_BOTH
+        if self.linear_layers is not None and self.linear_layers[layer_idx]:
+            return MIXER_LINEAR
+        return MIXER_ATTENTION
+
+    @property
+    def kv_layers(self) -> tuple:
+        """The running layers that hold K/V pages, in order: the paged
+        cache has one entry each (runtime/kv_cache.create_kv_cache), and a
+        layer's entry is at its place in this tuple."""
+        return tuple(i for i in range(self.num_layers)
+                     if self.layer_mixer(i) != MIXER_LINEAR)
+
+    @property
+    def state_layers(self) -> tuple:
+        """The running layers that hold a recurrent state a sequence: the
+        seat pool's entries (runtime/kv_cache.create_ssm_state)."""
+        return tuple(i for i in range(self.num_layers)
+                     if self.layer_mixer(i) != MIXER_ATTENTION)
+
+    @property
+    def has_state(self) -> bool:
+        """True when ANY layer holds a recurrent state: what the engine
+        observes to build the seat pool and to close the routes that
+        would need a snapshot of it."""
+        return bool(self.state_layers)
+
+    @property
+    def lin_conv_dim(self) -> int:
+        """Channels of a linear layer's short convolution: q, k, then v."""
+        return (2 * self.lin_num_key_heads * self.lin_key_head_dim
+                + self.lin_num_value_heads * self.lin_value_head_dim)
 
     @property
     def mamba_conv_dim(self) -> int:
@@ -276,7 +365,8 @@ class ModelConfig:
     @property
     def layer_types(self) -> list:
         """The running layers' kinds, as HF ``layer_types`` names them."""
-        return ["sliding_attention" if self.layer_window(i) is not None
+        return ["linear_attention" if self.layer_mixer(i) == MIXER_LINEAR
+                else "sliding_attention" if self.layer_window(i) is not None
                 else "full_attention" for i in range(self.num_layers)]
 
     @property
@@ -293,7 +383,10 @@ class ModelConfig:
     def rope_parameters(self) -> Optional[dict]:
         """HF ``rope_parameters``: keyed by layer type for a model whose
         rotary tables differ by layer kind, flat (one plain table) for
-        one whose windowed layers alone rotate; None for any other."""
+        one whose windowed layers alone rotate, a null base for one that
+        rotates nothing (``pos`` "none"); None for any other."""
+        if self.pos == "none":
+            return {"rope_theta": None}
         if self.rope_windowed_only:
             return {"rope_theta": self.rope_theta, "rope_type": "default"}
         if self.rope_full_yarn is None:
@@ -389,8 +482,26 @@ class ModelConfig:
 
     @property
     def cache_kv_heads(self) -> int:
-        """KV-cache head count: MLA stores one latent "head"."""
-        return 1 if self.is_mla else self.num_kv_heads
+        """KV-cache head count: MLA stores one latent "head".  More than
+        one sublane tile of heads that is not whole tiles (30) is stored
+        as whole tiles (32): the chip lays a page's ``(heads, head size)``
+        rows out in tiles of 8 either way, and only whole tiles make a
+        page the contiguous ``(page x heads, head size)`` slab the paged
+        decode kernel lands in one copy (ops/pallas_paged_attention.py).
+        The heads past ``num_kv_heads`` hold zeros (``_qkv`` pads q, k and
+        v, ``_attn_residual`` drops their output)."""
+        if self.is_mla:
+            return 1
+        hkv = self.num_kv_heads
+        return hkv if hkv <= 8 else -(-hkv // 8) * 8
+
+    @property
+    def cache_q_heads(self) -> int:
+        """Query heads as the attention kernels see them: each KV head's
+        group, over :attr:`cache_kv_heads`."""
+        if self.is_mla:
+            return self.num_heads
+        return self.cache_kv_heads * (self.num_heads // self.num_kv_heads)
 
     @property
     def cache_head_dim(self) -> int:
@@ -425,7 +536,20 @@ class ModelConfig:
             ssm = (h * self.mamba_proj_size + self.mamba_d_ssm * h
                    + self.mamba_conv_dim * (self.mamba_d_conv + 1)
                    + 3 * self.mamba_n_heads + self.mamba_d_ssm)
-        return l * (attn + mlp + ssm) + embed
+        linear = len(self.state_layers) if not self.has_ssm else 0
+        return ((l - linear) * attn + linear * self.lin_layer_params
+                + l * (mlp + ssm) + embed)
+
+    @property
+    def lin_layer_params(self) -> int:
+        """Parameters of one linear-attention mixer: q, k, v, the output
+        gate and the output projection, the two scalars a head (step size
+        and decay) with ``A_log`` and ``dt_bias``, the convolution."""
+        h, heads = self.hidden_size, self.lin_num_value_heads
+        d_v = heads * self.lin_value_head_dim
+        return (h * (self.lin_conv_dim + d_v) + d_v * h + 2 * h * heads
+                + 2 * heads + self.lin_conv_kernel * self.lin_conv_dim
+                + self.lin_value_head_dim)
 
 
 _REGISTRY: dict[str, ModelConfig] = {}
@@ -477,6 +601,8 @@ def config_from_hf_json(name: str, hf: dict) -> ModelConfig:
         return _mellum_config(hf, common)
     if family == "exaone_moe":
         return _exaone_moe_config(hf, common)
+    if family == "olmo_hybrid":
+        return _olmo_hybrid_config(hf, common)
     if "opt" in family:
         common["tie_word_embeddings"] = hf.get("tie_word_embeddings", True)
         return ModelConfig(
@@ -834,6 +960,55 @@ def _exaone_moe_config(hf: dict, common: dict) -> ModelConfig:
     return cfg
 
 
+def _olmo_hybrid_config(hf: dict, common: dict) -> ModelConfig:
+    """Olmo-Hybrid (``model_type`` ``olmo_hybrid``): a dense decoder whose
+    layers differ in their kind of mixer (``layer_types``): gated
+    delta-rule linear attention (arXiv:2412.06464, the step size doubled
+    under ``linear_allow_neg_eigval``, arXiv:2411.12537) or full causal
+    attention.  ``config.json`` sizes both; what it leaves open is set
+    HERE, one statement a point, and listed with its reason in
+    benchmark/configs/olmo-hybrid-7b-l16.json (``assumed``): the OLMo 2 /
+    3 family's norm on each branch's output and q/k norm over the whole
+    projection, no rotation at all (``rope_parameters.rope_theta`` is
+    null: the recurrent layers carry order), a head of hidden / heads.
+    What this code does not implement rejects loudly."""
+    kinds = hf.get("layer_types")
+    if not kinds or len(kinds) != hf["num_hidden_layers"] or set(kinds) - {
+            "linear_attention", "full_attention"}:
+        raise ValueError("olmo_hybrid configs must carry layer_types, one "
+                         "of linear_attention / full_attention a layer; got "
+                         f"{kinds!r}")
+    rp = hf.get("rope_parameters") or {}
+    if rp.get("rope_theta") is not None or hf.get("rope_theta") is not None \
+            or hf.get("rope_scaling"):
+        raise ValueError("olmo_hybrid with a rotary base is not supported "
+                         f"(rope_parameters {rp!r}): its attention layers "
+                         "are built unrotated")
+    if hf.get("sliding_window"):
+        raise ValueError("olmo_hybrid with a sliding window is not "
+                         "supported")
+    nh = hf["num_attention_heads"]
+    return ModelConfig(
+        intermediate_size=hf["intermediate_size"],
+        num_kv_heads=hf.get("num_key_value_heads", nh),
+        head_dim=hf.get("head_dim") or hf["hidden_size"] // nh,
+        norm_eps=hf.get("rms_norm_eps", 1e-6),
+        act=hf.get("hidden_act", "silu"),
+        attention_bias=hf.get("attention_bias", False),
+        pos="none",
+        norm_placement="post",
+        qk_norm=True, qk_norm_whole=True,
+        linear_layers=tuple(t == "linear_attention" for t in kinds),
+        lin_num_key_heads=hf["linear_num_key_heads"],
+        lin_num_value_heads=hf["linear_num_value_heads"],
+        lin_key_head_dim=hf["linear_key_head_dim"],
+        lin_value_head_dim=hf["linear_value_head_dim"],
+        lin_conv_kernel=hf.get("linear_conv_kernel_dim", 4),
+        lin_allow_neg_eigval=hf.get("linear_allow_neg_eigval", False),
+        **common,
+    )
+
+
 def _falcon_h1_config(hf: dict, common: dict) -> ModelConfig:
     """Falcon-H1 (HF ``modeling_falcon_h1``): attention heads and a Mamba-2
     mixer side by side in every layer.  What this code does not implement
@@ -1157,6 +1332,26 @@ register_model_config(ModelConfig(
     moe_routed_scaling=2.5, moe_shared_experts=1, moe_first_k_dense=1,
 ), "k-exaone-236b")
 
+# Olmo-Hybrid-7B (Ai2): 32 layers of hidden 3,840, three gated delta-rule
+# linear-attention layers (30 heads, keys of 96, values of 192, a causal
+# convolution of 4 in front) to each full-attention layer (30 heads of
+# 128, unrotated, q/k norm over the whole projection), norms on each
+# branch's output, a gated SiLU MLP of 11,008, untied 100,352 vocabulary.
+# The numbers are config.json's; what it leaves open is in
+# benchmark/configs/olmo-hybrid-7b-l16.json (``assumed``).  7.43 B
+# parameters: one chip serves a cut of the depth.
+register_model_config(ModelConfig(
+    name="allenai/Olmo-Hybrid-7B",
+    vocab_size=100352, hidden_size=3840, intermediate_size=11008,
+    num_layers=32, num_heads=30, num_kv_heads=30, head_dim=128,
+    max_position_embeddings=65536, norm_eps=1e-6,
+    tie_word_embeddings=False, pos="none", norm_placement="post",
+    qk_norm=True, qk_norm_whole=True,
+    linear_layers=tuple(i % 4 != 3 for i in range(32)),    # L L L F
+    lin_num_key_heads=30, lin_num_value_heads=30, lin_key_head_dim=96,
+    lin_value_head_dim=192, lin_conv_kernel=4, lin_allow_neg_eigval=True,
+), "olmo-hybrid-7b")
+
 # Tiny configs for tests / CPU smoke (one per architectural family).
 register_model_config(ModelConfig(
     name="tiny-qwen3",
@@ -1217,6 +1412,25 @@ register_model_config(ModelConfig(
     num_experts=32, num_experts_per_tok=4, moe_intermediate_size=32,
     norm_topk_prob=True, moe_scoring="sigmoid", moe_router_bias=True,
     moe_routed_scaling=2.5, moe_shared_experts=1, moe_first_k_dense=1,
+))
+
+# Olmo-Hybrid in small: two periods of L L L F, 6 linear heads with keys
+# of 24 and values of 48 (NOT multiples of a lane tile or of each other's
+# tile, so a padding fault shows on the CPU), a scan chunk of 8, 10
+# attention heads of 16 (cached as 16: cache_kv_heads) unrotated under a
+# whole-projection q/k norm, norms on each branch's output.  float32 like
+# tiny-mistral.
+register_model_config(ModelConfig(
+    name="tiny-olmo-hybrid",
+    vocab_size=256, hidden_size=96, intermediate_size=160,
+    num_layers=8, num_heads=10, num_kv_heads=10, head_dim=16,
+    max_position_embeddings=512, norm_eps=1e-6,
+    tie_word_embeddings=False, eos_token_id=1, dtype="float32",
+    pos="none", norm_placement="post", qk_norm=True, qk_norm_whole=True,
+    linear_layers=tuple(i % 4 != 3 for i in range(8)),
+    lin_num_key_heads=6, lin_num_value_heads=6, lin_key_head_dim=24,
+    lin_value_head_dim=48, lin_conv_kernel=4, lin_allow_neg_eigval=True,
+    lin_chunk_size=8,
 ))
 
 register_model_config(ModelConfig(

@@ -17,8 +17,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from tpuserve.models.config import ModelConfig
+from tpuserve.models.config import MIXER_LINEAR, ModelConfig
 from tpuserve.ops import attention as attn_ops
+from tpuserve.ops import gated_delta as gdn_ops
 from tpuserve.ops import rope as rope_ops
 from tpuserve.ops import scopes
 from tpuserve.ops import ssm as ssm_ops
@@ -51,15 +52,34 @@ def layernorm(x: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray, eps: float)
     return (out * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(dtype)
 
 
+def _post_norm(m: jnp.ndarray, p: dict, cfg: ModelConfig) -> jnp.ndarray:
+    """A branch's output under its norm where the norm stands on the
+    OUTPUT (``norm_placement`` "post"), in float32: added to the residual
+    stream it makes the stream float32 from the first layer on.  No norm
+    stands between such a stream and the next add, so a stream kept in
+    bfloat16 is rounded at every one of its adds, two a layer, and each
+    branch carries what the adds before it lost on to the next (the
+    linear-attention mixer twice over: it sums where attention averages);
+    the branches round their own input once (``h.astype(cfg.dtype)``)."""
+    return _norm(m.astype(jnp.float32), p, cfg)
+
+
 def _norm(x: jnp.ndarray, p: dict, cfg: ModelConfig) -> jnp.ndarray:
     if cfg.norm == "rmsnorm":
         return rmsnorm(x, p["scale"], cfg.norm_eps, cfg.norm_weight_offset)
     return layernorm(x, p["scale"], p["bias"], cfg.norm_eps)
 
 
-def _linear(x: jnp.ndarray, p: dict, ad: jnp.ndarray | None = None) -> jnp.ndarray:
+def _linear(x: jnp.ndarray, p: dict, ad: jnp.ndarray | None = None,
+            out=None) -> jnp.ndarray:
+    """``out``: the dtype the product leaves in (None: its inputs'); the
+    MXU accumulates in float32 either way, so float32 here keeps what a
+    bfloat16 result would round away, at the price of the result's
+    bytes."""
     w = p["kernel"]
-    if "scale" in p:
+    if out is not None and "scale" not in p:
+        y = jnp.dot(x, w, preferred_element_type=out)
+    elif "scale" in p:
         # int8 weight-only quantization (models/weights.py
         # quantize_params_int8): XLA fuses the convert into the matmul
         # loop, so HBM reads int8 while the MXU runs at its bf16 rate; the
@@ -118,6 +138,12 @@ def _attn_residual(h: jnp.ndarray, out: jnp.ndarray, lp: dict,
     the add.  ``m``: a state-space branch's term of the same layer, added
     to the attention's before both join the stream."""
     with jax.named_scope(scopes.ATTN_OUT):
+        if out.shape[-2] != cfg.num_heads:      # cfg.cache_q_heads' zeros
+            out = out[..., :cfg.num_heads, :]
+        if cfg.norm_placement == "post":
+            return h + _post_norm(
+                _linear(out.reshape(*out.shape[:-2], -1), lp["o_proj"], ad,
+                        jnp.float32), lp["post_attn_norm"], cfg)
         att = _scaled(_linear(out.reshape(*out.shape[:-2], -1),
                               lp["o_proj"], ad),
                       cfg.attention_out_multiplier)
@@ -135,11 +161,31 @@ def _mlp_residual(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
     post-feedforward layernorm wraps the output before the add.
     ``tally`` and ``moe_dense`` are an expert layer's (:func:`_moe_mlp`)."""
     with jax.named_scope(scopes.MLP):
+        if cfg.norm_placement == "post":        # the norm on the OUTPUT
+            def branch(rows):
+                return _post_norm(
+                    _mlp(rows.astype(cfg.dtype), lp, cfg, ad, tally,
+                         moe_dense), lp["post_mlp_norm"], cfg)
+
+            T = h.shape[0]
+            if h.ndim == 2 and T > POST_MLP_ROWS and not T % POST_MLP_ROWS \
+                    and tally is None:
+                # a packed prefill: the MLP is a function of a row, so its
+                # float32 intermediates need hold a block of rows, not
+                # 8,192 of them (0.7 GB at this family's widths, beside
+                # weights, state and pages that fill the chip)
+                return h + jax.lax.map(branch, h.reshape(
+                    -1, POST_MLP_ROWS, h.shape[-1])).reshape(h.shape)
+            return h + branch(h)
         m = _mlp(_norm(h, lp["mlp_norm"], cfg), lp, cfg, ad, tally,
                  moe_dense)
         if cfg.sandwich_norms:
             m = _norm(m, lp["post_mlp_norm"], cfg)
         return h + m
+
+
+#: rows of a packed prefill a post-norm MLP takes at once
+POST_MLP_ROWS = 1024
 
 
 def _mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
@@ -151,6 +197,13 @@ def _mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
     if "experts" in p:
         return _moe_mlp(x, p, cfg, tally, moe_dense)
     with jax.named_scope(scopes.MLP):
+        if cfg.mlp_style == "gated" and cfg.norm_placement == "post":
+            # every product leaves in float32 and is rounded once, where
+            # it enters the next product (_post_norm has the reason)
+            f32 = jnp.float32
+            gate = _act(_linear(x, p["gate_proj"], ad, f32), cfg.act)
+            return _linear((gate * _linear(x, p["up_proj"], ad, f32)
+                            ).astype(x.dtype), p["down_proj"], ad, f32)
         if cfg.mlp_style == "gated":
             gate_m, down_m = cfg.mlp_multipliers
             gate = _act(_scaled(_linear(x, p["gate_proj"], ad), gate_m),
@@ -499,16 +552,21 @@ def _qkv(h: jnp.ndarray, lp: dict, cfg: ModelConfig, positions: jnp.ndarray,
     windowed layers by the plain one.  K-EXAONE: full layers do not
     rotate at all)."""
     with jax.named_scope(scopes.ATTN_QKV):
-        hn = _norm(h, lp["attn_norm"], cfg)
+        hn = h.astype(cfg.dtype) if cfg.norm_placement == "post" \
+            else _norm(h, lp["attn_norm"], cfg)
         h = _scaled(hn, cfg.attention_in_multiplier)
-        q = _linear(h, lp["q_proj"], ad).reshape(
-            *h.shape[:-1], cfg.num_heads, cfg.head_dim)
-        k = _linear(h, lp["k_proj"], ad).reshape(
-            *h.shape[:-1], cfg.num_kv_heads, cfg.head_dim)
+        q = _linear(h, lp["q_proj"], ad)
+        if cfg.qk_norm_whole:               # over all heads at once
+            q = rmsnorm(q, lp["q_norm"]["scale"], cfg.norm_eps)
+        q = q.reshape(*h.shape[:-1], cfg.num_heads, cfg.head_dim)
+        k = _linear(h, lp["k_proj"], ad)
+        if cfg.qk_norm_whole:
+            k = rmsnorm(k, lp["k_norm"]["scale"], cfg.norm_eps)
+        k = k.reshape(*h.shape[:-1], cfg.num_kv_heads, cfg.head_dim)
         v = _linear(h, lp["v_proj"], ad).reshape(
             *h.shape[:-1], cfg.num_kv_heads, cfg.head_dim)
         k = _scaled(k, cfg.key_multiplier)
-        if cfg.qk_norm:
+        if cfg.qk_norm and not cfg.qk_norm_whole:
             q = rmsnorm(q, lp["q_norm"]["scale"], cfg.norm_eps,
                         cfg.norm_weight_offset)
             k = rmsnorm(k, lp["k_norm"]["scale"], cfg.norm_eps,
@@ -525,6 +583,14 @@ def _qkv(h: jnp.ndarray, lp: dict, cfg: ModelConfig, positions: jnp.ndarray,
                 yarn_scaling=cfg.layer_yarn(layer_idx))
             q = rope_ops.apply_rope(q, cos, sin)
             k = rope_ops.apply_rope(k, cos, sin)
+        if cfg.cache_kv_heads != cfg.num_kv_heads:
+            # heads as the cache stores them: zeros past the model's own
+            pad = [(0, 0)] * (k.ndim - 2)
+            extra = cfg.cache_kv_heads - cfg.num_kv_heads
+            q = jnp.pad(q, pad + [(0, cfg.cache_q_heads - cfg.num_heads),
+                                  (0, 0)])
+            k = jnp.pad(k, pad + [(0, extra), (0, 0)])
+            v = jnp.pad(v, pad + [(0, extra), (0, 0)])
         return q, k, v, hn
 
 
@@ -843,6 +909,224 @@ def _ssm_decode(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
     return m, {"state": state, "conv": conv}
 
 
+# --------------------------------------------------------------------------
+# Gated delta-rule linear attention IN PLACE OF attention (Olmo-Hybrid)
+# --------------------------------------------------------------------------
+#
+# A layer whose mixer is ``MIXER_LINEAR`` (ModelConfig.layer_mixer) runs no
+# attention and writes no K/V page: it keeps, per sequence, a float32
+# matrix state a head (key size x value size) and the last
+# ``lin_conv_kernel - 1`` inputs of a short convolution, in the same seat
+# pool as Falcon-H1's mixer (``ssm``: one entry a layer that holds a
+# state, at the layer's place among them; the state in slabs of heads,
+# ops/pallas_gdn_update.py) under the same rules: a window that starts at
+# position 0 starts from zeros, padding rows change nothing.  The helpers
+# open the scope of their ROLE in a recurrent mixer (``ssm.*``): the
+# readers by scope read both mixers alike, the kernels differ by name.
+# Equations: arXiv:2412.06464 (ops/gated_delta.py), the step size doubled
+# under ``lin_allow_neg_eigval``; benchmark/reference/olmo_hybrid.py
+# writes the layer out.
+
+def _lin_project(h: jnp.ndarray, lp: dict, cfg: ModelConfig):
+    """The residual stream h (..., hidden) -> the convolution's input
+    [q | k | v] (..., conv_dim), the output gate (..., H dv), the raw
+    decay and the raw step size (..., H)."""
+    sp = lp["lin"]
+    with jax.named_scope(scopes.SSM_IN_PROJ):
+        u = h.astype(cfg.dtype) if cfg.norm_placement == "post" \
+            else _norm(h, lp["attn_norm"], cfg)
+        # every product leaves in float32 (_post_norm has the reason; the
+        # two scalars a head most of all: a rounding of the decay's
+        # exponent is a rounding of every later row's read of the state)
+        return tuple(_linear(u, sp[name], out=jnp.float32) for name in
+                     ("qkv_proj", "g_proj", "a_proj", "b_proj"))
+
+
+def _lin_qkv(x: jnp.ndarray, cfg: ModelConfig):
+    """Rows of convolved, activated [q | k | v] channels (..., conv_dim)
+    -> q, k (..., H, dk) with each head's vector normalised (q also
+    scaled dk^-1/2) and v (..., H, dv), f32."""
+    H, dk, dv = (cfg.lin_num_value_heads, cfg.lin_key_head_dim,
+                 cfg.lin_value_head_dim)
+    x = x.astype(jnp.float32)
+    lead = x.shape[:-1]
+
+    def unit(y):
+        return y * jax.lax.rsqrt(
+            jnp.sum(jnp.square(y), axis=-1, keepdims=True) + 1e-6)
+
+    return (unit(x[..., :H * dk].reshape(*lead, H, dk)) * dk ** -0.5,
+            unit(x[..., H * dk:2 * H * dk].reshape(*lead, H, dk)),
+            x[..., 2 * H * dk:].reshape(*lead, H, dv))
+
+
+def _lin_inputs(conv_out: jnp.ndarray, a_raw: jnp.ndarray,
+                b_raw: jnp.ndarray, sp: dict, cfg: ModelConfig,
+                valid: jnp.ndarray):
+    """Convolved [q | k | v] (f32), raw decay and step size -> the
+    activated channels x (..., conv_dim) that :func:`_lin_qkv` splits,
+    the log of the decay g <= 0 and the step size beta in [0, 2) (..., H)
+    f32.  Rows that are not ``valid`` come out as zeros (``_ssm_inputs``
+    has the reason), which neither decay nor write the state."""
+    with jax.named_scope(scopes.SSM_CONV):
+        x = jnp.where(valid[..., None], jax.nn.silu(conv_out), 0.0)
+        # activations in the model's dtype, as everywhere else in the
+        # trunk; the scan and the state update widen what they accumulate
+        x = x.astype(cfg.dtype)
+        beta = jax.nn.sigmoid(b_raw.astype(jnp.float32))
+        if cfg.lin_allow_neg_eigval:
+            beta = 2.0 * beta
+        g = -jnp.exp(sp["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+            a_raw.astype(jnp.float32) + sp["dt_bias"])
+        return x, jnp.where(valid[..., None], g, 0.0), \
+            jnp.where(valid[..., None], beta, 0.0)
+
+
+def _lin_output(o: jnp.ndarray, gate: jnp.ndarray, h: jnp.ndarray, lp: dict,
+                cfg: ModelConfig) -> jnp.ndarray:
+    """The heads' output o (..., H, dv) under the per-head norm and the
+    gate (..., H dv), through the output projection, added to the
+    residual stream h."""
+    sp = lp["lin"]
+    with jax.named_scope(scopes.SSM_OUT):
+        o = o.astype(jnp.float32)
+        o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+                              + cfg.norm_eps)
+        o = o * sp["norm"]["scale"].astype(jnp.float32)
+        y = o.reshape(gate.shape) * jax.nn.silu(gate.astype(jnp.float32))
+        m = _linear(y.astype(cfg.dtype), sp["o_proj"], out=jnp.float32)
+        if cfg.norm_placement == "post":
+            return h + _post_norm(m, lp["post_attn_norm"], cfg)
+        return h + m.astype(h.dtype)
+
+
+def _lin_slabs(entry: dict, cfg: ModelConfig) -> int:
+    """Heads side by side in a slab of the pool's state."""
+    return cfg.lin_num_value_heads // entry["state"].shape[1]
+
+
+def _lin_keep(entry: dict, seats: jnp.ndarray, finals: jnp.ndarray,
+              tails: jnp.ndarray, cfg: ModelConfig) -> dict:
+    """The pool's entry with the seats' states (n, H, dk, dv) and
+    convolution memories (n, W - 1, conv_dim) after a prefill."""
+    from tpuserve.ops.pallas_gdn_update import to_slabs
+    with jax.named_scope(scopes.SSM_SCAN):
+        state = entry["state"].at[seats].set(
+            to_slabs(finals, _lin_slabs(entry, cfg)))
+    with jax.named_scope(scopes.SSM_CONV):
+        conv = entry["conv"].at[seats].set(tails.astype(entry["conv"].dtype))
+    return {"state": state, "conv": conv}
+
+
+def _lin_window(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
+                lens: jnp.ndarray, entry: dict | None = None,
+                seats: jnp.ndarray | None = None,
+                fresh: jnp.ndarray | None = None):
+    """A linear layer's mixer over a window of rows a sequence: h (B, L,
+    hidden), the first ``lens`` (B,) rows of each valid; ``entry``,
+    ``seats`` and ``fresh`` as :func:`_ssm_window` takes them.  Returns
+    (the residual stream after the mixer, entry with the seats' state and
+    memory after the window -- None in, None out)."""
+    from tpuserve.ops.pallas_gdn_update import from_slabs
+    B, L, _ = h.shape
+    H, dk, dv = (cfg.lin_num_value_heads, cfg.lin_key_head_dim,
+                 cfg.lin_value_head_dim)
+    W, sp = cfg.lin_conv_kernel, lp["lin"]
+    qkv, gate, a_raw, b_raw = _lin_project(h, lp, cfg)
+    s0 = jnp.zeros((B, H, dk, dv), jnp.float32)
+    tail = jnp.zeros((B, W - 1, cfg.lin_conv_dim), qkv.dtype)
+    if fresh is not None:
+        keep = ~fresh
+        with jax.named_scope(scopes.SSM_SCAN):
+            s0 = jnp.where(keep[:, None, None, None], from_slabs(
+                entry["state"][seats], _lin_slabs(entry, cfg)), s0)
+        with jax.named_scope(scopes.SSM_CONV):
+            tail = jnp.where(keep[:, None, None], entry["conv"][seats], tail)
+    conv_out, rows = ssm_ops.causal_conv(qkv, tail, sp["conv"]["kernel"],
+                                         None)
+    valid = jnp.arange(L)[None, :] < lens[:, None]
+    x, g, beta = _lin_inputs(conv_out, a_raw, b_raw, sp, cfg, valid)
+    # the scan's chunk: as _ssm_window chooses it
+    Q = math.gcd(cfg.lin_chunk_size, L)
+    o, finals = gdn_ops.gated_delta_chunk_scan(
+        x.reshape(B * L, -1), g.reshape(B * L, H), beta.reshape(B * L, H),
+        s0, jnp.repeat(jnp.arange(B, dtype=jnp.int32), L // Q), chunk=Q,
+        split=partial(_lin_qkv, cfg=cfg), out_dtype=x.dtype)
+    h = _lin_output(o.reshape(B, L, H, dv), gate, h, lp, cfg)
+    if entry is None:
+        return h, None
+    return h, _lin_keep(entry, seats, finals,
+                        ssm_ops.next_tail(rows, lens, W), cfg)
+
+
+def _lin_packed(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
+                positions: jnp.ndarray, slot_ids: jnp.ndarray,
+                blk_seq: jnp.ndarray, q_starts: jnp.ndarray,
+                q_lens: jnp.ndarray, blk: int, entry: dict,
+                seats: jnp.ndarray):
+    """A linear layer's mixer over a PACKED prefill: h (T, hidden), laid
+    out as :func:`_ssm_packed` says (padding rows carry PAD_SLOT as their
+    cache slot); every prompt starts from zeros.
+    Returns (the residual stream after the mixer (T, hidden), entry)."""
+    T = h.shape[0]
+    H, dk, dv = (cfg.lin_num_value_heads, cfg.lin_key_head_dim,
+                 cfg.lin_value_head_dim)
+    W, sp = cfg.lin_conv_kernel, lp["lin"]
+    qkv, gate, a_raw, b_raw = _lin_project(h, lp, cfg)
+    with jax.named_scope(scopes.SSM_CONV):
+        valid = slot_ids != attn_ops.PAD_SLOT
+        # the flat axis as one row of the convolution, a tap that would
+        # reach back past its prompt's first row reading zero
+        conv_out, _ = ssm_ops.causal_conv(
+            qkv[None], jnp.zeros((1, W - 1, qkv.shape[-1]), qkv.dtype),
+            sp["conv"]["kernel"], None, positions=positions[None])
+        conv_out = conv_out[0]
+    x, g, beta = _lin_inputs(conv_out, a_raw, b_raw, sp, cfg, valid)
+    Q = math.gcd(cfg.lin_chunk_size, blk)
+    o, finals = gdn_ops.gated_delta_chunk_scan(
+        x, g, beta, jnp.zeros((q_lens.shape[0], H, dk, dv), jnp.float32),
+        jnp.repeat(blk_seq, blk // Q), chunk=Q,
+        split=partial(_lin_qkv, cfg=cfg), out_dtype=x.dtype)
+    h = _lin_output(o, gate, h, lp, cfg)
+    with jax.named_scope(scopes.SSM_CONV):
+        # each prompt's last W - 1 inputs (zeros before its first row)
+        back = jnp.arange(W - 1)[None, :] - (W - 1)
+        idx = (q_starts + q_lens)[:, None] + back
+        tails = jnp.where((q_lens[:, None] + back >= 0)[..., None],
+                          qkv[jnp.clip(idx, 0, T - 1)], 0)
+    return h, _lin_keep(entry, seats, finals, tails, cfg)
+
+
+def _lin_decode(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
+                slot_ids: jnp.ndarray, entry: dict, seats: jnp.ndarray,
+                attn_impl: str):
+    """One token a row: h (B, hidden).  The convolution's memory shifts
+    by one; the state is updated in place on the pool -- by the Pallas
+    kernel under ``attn_impl="pallas"`` (ops/pallas_gdn_update.py), by the
+    same formula in ``jax.numpy`` otherwise.  Padding rows (PAD_SLOT as
+    their cache slot) carry the trash seat.  Returns (the residual stream
+    after the mixer (B, hidden), entry)."""
+    sp = lp["lin"]
+    qkv, gate, a_raw, b_raw = _lin_project(h, lp, cfg)
+    with jax.named_scope(scopes.SSM_CONV):
+        valid = slot_ids != attn_ops.PAD_SLOT
+        tail = entry["conv"][seats]
+        conv_out, rows = ssm_ops.causal_conv(qkv[:, None], tail,
+                                             sp["conv"]["kernel"], None)
+        conv_out = conv_out[:, 0]
+    x, g, beta = _lin_inputs(conv_out, a_raw, b_raw, sp, cfg, valid)
+    from tpuserve.ops import pallas_gdn_update as upd
+    update = (upd.gdn_state_update if attn_impl == "pallas"
+              else upd.gdn_state_update_reference)
+    with jax.named_scope(scopes.SSM_SCAN):
+        o, state = update(entry["state"], seats, *_lin_qkv(x, cfg), g, beta)
+    h = _lin_output(o, gate, h, lp, cfg)
+    with jax.named_scope(scopes.SSM_CONV):
+        conv = entry["conv"].at[seats].set(
+            rows[:, 1:].astype(entry["conv"].dtype))
+    return h, {"state": state, "conv": conv}
+
+
 def _embed(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
            positions: jnp.ndarray) -> jnp.ndarray:
     with jax.named_scope(scopes.EMBED):
@@ -872,6 +1156,8 @@ def _unembed(params: Params, cfg: ModelConfig, h: jnp.ndarray,
             h = h[at]
         if cfg.final_layernorm:
             h = _norm(h, params["final_norm"], cfg)
+        if cfg.norm_placement == "post":            # a float32 stream
+            h = h.astype(cfg.dtype)
         if cfg.tie_word_embeddings:
             ew = params["embed"]
             if "scale" in ew:             # tied int8: scale per logit column
@@ -981,13 +1267,19 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         tally = _moe_tally(cfg)
         for li, lp in enumerate(params["layers"]):
             sw = cfg.layer_window(li)
+            if cfg.layer_mixer(li) == MIXER_LINEAR:
+                h, entry = _lin_window(h, lp, cfg, prompt_lens,
+                                       ssm[len(new_ssm)], seats)
+                new_ssm.append(entry)
+                h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+                continue
             if cfg.is_mla:
                 # MLA prefill: cache the latent, attend naively (decompressed)
                 # over the fresh prompt K/V — reference impl only; the Pallas
                 # kernels assume materialised per-head K/V pages
                 q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
                 new_cache.append(attn_ops.write_mla_entry(
-                    kv_cache[li], latent, slot_ids,
+                    kv_cache[len(new_cache)], latent, slot_ids,
                     latent_split=cfg.mla_kv_lora_rank))
                 out = _mla_prefill_out(q_nope, q_rope, latent, lp, cfg,
                                        prompt_lens, scale)
@@ -997,7 +1289,7 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)
             # batched prefill attends over the FRESH k/v (full precision even
             # when the cache stores int8 — only cache READS see quantization)
-            new_cache.append(attn_ops.write_kv_entry(kv_cache[li], k, v,
+            new_cache.append(attn_ops.write_kv_entry(kv_cache[len(new_cache)], k, v,
                                                      slot_ids))
             if attn_impl == "pallas" and mesh is not None:
                 from tpuserve.ops.pallas_tp import flash_prefill_attention_tp
@@ -1014,8 +1306,8 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                                                  sliding_window=sw,
                                                  logit_softcap=cfg.attn_logit_softcapping)
             m = None
-            if ssm is not None:
-                m, entry = _ssm_window(hn, lp["ssm"], cfg, prompt_lens, ssm[li],
+            if ssm is not None and cfg.has_ssm:
+                m, entry = _ssm_window(hn, lp["ssm"], cfg, prompt_lens, ssm[len(new_ssm)],
                                        seats)
                 new_ssm.append(entry)
             h = _attn_residual(h, out, lp, cfg, ad, m)
@@ -1099,6 +1391,10 @@ def embed_forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         scale = cfg.attn_scale
         for li, lp in enumerate(params["layers"]):
             sw = cfg.layer_window(li)
+            if cfg.layer_mixer(li) == MIXER_LINEAR:
+                h = _mlp_residual(_lin_window(h, lp, cfg, prompt_lens)[0],
+                                  lp, cfg)
+                continue
             q, k, v, hn = (_mla_naive_qkv(h, lp, cfg, positions) if cfg.is_mla
                            else _qkv(h, lp, cfg, positions, li))
             out = attn_ops.prefill_attention(q, k, v, prompt_lens, scale,
@@ -1148,6 +1444,10 @@ def score_prompt(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         scale = cfg.attn_scale
         for li, lp in enumerate(params["layers"]):
             sw = cfg.layer_window(li)
+            if cfg.layer_mixer(li) == MIXER_LINEAR:
+                h = _mlp_residual(_lin_window(h, lp, cfg, prompt_lens)[0],
+                                  lp, cfg)
+                continue
             q, k, v, hn = (_mla_naive_qkv(h, lp, cfg, positions) if cfg.is_mla
                            else _qkv(h, lp, cfg, positions, li))
             out = attn_ops.prefill_attention(q, k, v, prompt_lens, scale,
@@ -1209,11 +1509,17 @@ def _chunk_trunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     new_ssm = []
     for li, lp in enumerate(params["layers"]):
         sw = cfg.layer_window(li)
+        if cfg.layer_mixer(li) == MIXER_LINEAR:
+            h, entry = _lin_window(h, lp, cfg, chunk_lens, ssm[len(new_ssm)],
+                                   seats, fresh=ctx_lens == 0)
+            new_ssm.append(entry)
+            h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+            continue
         if cfg.is_mla:
             # MLA window: write the latent, attend ABSORBED against the
             # latent pages (k == v == latent; value = first kv_lora cols)
             q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
-            entry = attn_ops.write_mla_entry(kv_cache[li], latent, slot_ids,
+            entry = attn_ops.write_mla_entry(kv_cache[len(new_cache)], latent, slot_ids,
                                              latent_split=cfg.mla_kv_lora_rank)
             new_cache.append(entry)
             q_eff = _mla_absorb_q(q_nope, q_rope, lp, cfg)
@@ -1228,7 +1534,7 @@ def _chunk_trunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
             continue
         q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)
-        entry = attn_ops.write_kv_entry(kv_cache[li], k, v, slot_ids, aligned)
+        entry = attn_ops.write_kv_entry(kv_cache[len(new_cache)], k, v, slot_ids, aligned)
         new_cache.append(entry)
         ck, cv = entry["k"], entry["v"]
         ks, vs = entry.get("ks"), entry.get("vs")
@@ -1250,8 +1556,8 @@ def _chunk_trunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 k_scale=ks, v_scale=vs, sliding_window=sw,
                 logit_softcap=cfg.attn_logit_softcapping)
         m = None
-        if ssm is not None:
-            m, entry = _ssm_window(hn, lp["ssm"], cfg, chunk_lens, ssm[li],
+        if ssm is not None and cfg.has_ssm:
+            m, entry = _ssm_window(hn, lp["ssm"], cfg, chunk_lens, ssm[len(new_ssm)],
                                    seats, fresh=ctx_lens == 0)
             new_ssm.append(entry)
         h = _attn_residual(h, out, lp, cfg, ad, m)
@@ -1457,12 +1763,18 @@ def _decode_body(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     tally = _moe_tally(cfg)
     for li, lp in enumerate(params["layers"]):
         sw = cfg.layer_window(li)
+        if cfg.layer_mixer(li) == MIXER_LINEAR:
+            h, entry = _lin_decode(h, lp, cfg, slot_ids, ssm[len(new_ssm)],
+                                   seats, attn_impl)
+            new_ssm.append(entry)
+            h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+            continue
         if cfg.is_mla:
             # MLA decode: absorbed attention straight against the latent
             # pages — the step reads mla_latent_dim bytes per cached token
             # instead of 2 * Hkv * head_dim (the ~10x KV-bandwidth win)
             q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
-            entry = attn_ops.write_mla_entry(kv_cache[li], latent, slot_ids,
+            entry = attn_ops.write_mla_entry(kv_cache[len(new_cache)], latent, slot_ids,
                                              latent_split=cfg.mla_kv_lora_rank)
             new_cache.append(entry)
             q_eff = _mla_absorb_q(q_nope, q_rope, lp, cfg)
@@ -1476,7 +1788,7 @@ def _decode_body(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
             continue
         q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)  # (B, Hq/Hkv, D)
-        entry = attn_ops.write_kv_entry(kv_cache[li], k, v, slot_ids)
+        entry = attn_ops.write_kv_entry(kv_cache[len(new_cache)], k, v, slot_ids)
         new_cache.append(entry)
         ck, cv = entry["k"], entry["v"]
         ks, vs = entry.get("ks"), entry.get("vs")
@@ -1498,9 +1810,9 @@ def _decode_body(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                                                   sliding_window=sw,
                                                   logit_softcap=cfg.attn_logit_softcapping)
         m = None
-        if ssm is not None:
+        if ssm is not None and cfg.has_ssm:
             m, entry = _ssm_decode(hn, lp["ssm"], cfg,
-                                   slot_ids != attn_ops.PAD_SLOT, ssm[li],
+                                   slot_ids != attn_ops.PAD_SLOT, ssm[len(new_ssm)],
                                    seats, attn_impl)
             new_ssm.append(entry)
         h = _attn_residual(h, out, lp, cfg, ad, m)
@@ -1796,6 +2108,13 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             kv_cache[0], ragged_blk, attn_impl)
         for li, lp in enumerate(params["layers"]):
             sw = cfg.layer_window(li)
+            if cfg.layer_mixer(li) == MIXER_LINEAR:
+                h, entry = _lin_packed(h, lp, cfg, positions, slot_ids,
+                                       blk_seq, q_starts, q_lens, ragged_blk,
+                                       ssm[len(new_ssm)], seats)
+                new_ssm.append(entry)
+                h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+                continue
             if cfg.is_mla:
                 # MLA: absorbed attention against the latent pages, like the
                 # chunk/decode trunks (reference path only — the Pallas
@@ -1803,7 +2122,7 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 # the rest of the engine)
                 q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
                 entry = attn_ops.write_mla_entry(
-                    kv_cache[li], latent, slot_ids,
+                    kv_cache[len(new_cache)], latent, slot_ids,
                     latent_split=cfg.mla_kv_lora_rank)
                 new_cache.append(entry)
                 q_eff = _mla_absorb_q(q_nope, q_rope, lp, cfg)
@@ -1819,7 +2138,7 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
                 continue
             q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)  # (T, H*, D)
-            entry = attn_ops.write_kv_entry(kv_cache[li], k, v, slot_ids,
+            entry = attn_ops.write_kv_entry(kv_cache[len(new_cache)], k, v, slot_ids,
                                             aligned)
             new_cache.append(entry)
             ck, cv = entry["k"], entry["v"]
@@ -1839,10 +2158,10 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                     meta, ragged_blk, scale, ks, vs, sw,
                     cfg.attn_logit_softcapping, decode_rows=decode_rows)
             m = None
-            if ssm is not None:
+            if ssm is not None and cfg.has_ssm:
                 m, entry = _ssm_packed(hn, lp["ssm"], cfg, positions,
                                        slot_ids != attn_ops.PAD_SLOT, blk_seq,
-                                       q_starts, q_lens, ragged_blk, ssm[li],
+                                       q_starts, q_lens, ragged_blk, ssm[len(new_ssm)],
                                        seats)
                 new_ssm.append(entry)
             h = _attn_residual(h, out, lp, cfg, ad, m)
@@ -1878,6 +2197,10 @@ def draft_propose(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             toks, cur = carry
             h = _embed(params, cfg, toks, positions)
             for li, lp in enumerate(params["layers"]):
+                if cfg.layer_mixer(li) == MIXER_LINEAR:
+                    h = _mlp_residual(_lin_window(h, lp, cfg, cur)[0], lp,
+                                      cfg)
+                    continue
                 q, kk, v, hn = (_mla_naive_qkv(h, lp, cfg, positions)
                                 if cfg.is_mla
                                 else _qkv(h, lp, cfg, positions, li))
@@ -1919,6 +2242,10 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     h = _embed(params, cfg, tokens, positions)
     scale = cfg.attn_scale
     for li, lp in enumerate(params["layers"]):
+        if cfg.layer_mixer(li) == MIXER_LINEAR:
+            h = _mlp_residual(_lin_window(h, lp, cfg, seq_lens)[0], lp, cfg,
+                              moe_dense=True)
+            continue
         q, k, v, hn = (_mla_naive_qkv(h, lp, cfg, positions) if cfg.is_mla
                        else _qkv(h, lp, cfg, positions, li))
         out = attn_ops.prefill_attention(q, k, v, seq_lens, scale,

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpuserve.models.config import ModelConfig
+from tpuserve.models.config import MIXER_LINEAR, ModelConfig
 
 Params = Any
 
@@ -93,12 +93,16 @@ def _shard(tree, cfg: ModelConfig, mesh):
         tree, param_shardings(tree, cfg, mesh))
 
 
-@partial(jax.jit, static_argnames=("cfg", "dense_mlp", "mesh"))
-def _init_layer(key, cfg: ModelConfig, dense_mlp: bool, mesh=None) -> Params:
-    """One transformer layer; ``dense_mlp``: an MoE model's dense layer."""
+@partial(jax.jit, static_argnames=("cfg", "dense_mlp", "mesh", "linear"))
+def _init_layer(key, cfg: ModelConfig, dense_mlp: bool, mesh=None,
+                linear: bool = False) -> Params:
+    """One transformer layer; ``dense_mlp``: an MoE model's dense layer;
+    ``linear``: a linear-attention layer (ModelConfig.layer_mixer)."""
     d = _Draw(cfg, key)
     h = cfg.hidden_size
-    if cfg.is_mla:
+    if linear:
+        lp = {"lin": _init_lin(d, cfg)}
+    elif cfg.is_mla:
         # DeepSeek MLA: low-rank q (optional), compressed-KV latent +
         # shared roped key, per-head up-projections packed in kv_b_proj
         lp = {
@@ -137,12 +141,17 @@ def _init_layer(key, cfg: ModelConfig, dense_mlp: bool, mesh=None) -> Params:
         }
     if cfg.has_ssm:
         lp["ssm"] = _init_ssm(d, cfg)
-    if cfg.qk_norm:
-        lp["q_norm"] = {"scale": jnp.full((cfg.head_dim,), d.norm_init,
-                                          d.dtype)}
-        lp["k_norm"] = {"scale": jnp.full((cfg.head_dim,), d.norm_init,
-                                          d.dtype)}
-    if cfg.sandwich_norms:
+    if cfg.qk_norm and not linear:
+        # a head's width, or the whole projection's (qk_norm_whole)
+        qn, kn = ((cfg.q_size, cfg.kv_size) if cfg.qk_norm_whole
+                  else (cfg.head_dim, cfg.head_dim))
+        lp["q_norm"] = {"scale": jnp.full((qn,), d.norm_init, d.dtype)}
+        lp["k_norm"] = {"scale": jnp.full((kn,), d.norm_init, d.dtype)}
+    if cfg.norm_placement == "post":
+        # each branch's norm stands on its output alone
+        lp.pop("attn_norm", None)
+        lp.pop("mlp_norm", None)
+    if cfg.sandwich_norms or cfg.norm_placement == "post":
         lp["post_attn_norm"] = d.norm(h)
         lp["post_mlp_norm"] = d.norm(h)
     if cfg.num_experts and not dense_mlp:
@@ -212,6 +221,48 @@ def _init_ssm(d: _Draw, cfg: ModelConfig) -> Params:
     }
 
 
+def _init_lin(d: _Draw, cfg: ModelConfig) -> Params:
+    """The gated delta-rule mixer of one linear-attention layer: the q, k
+    and v projections as one matrix, separate gate, decay (``a_proj``) and
+    step-size (``b_proj``) projections, one depthwise convolution over
+    [q | k | v], a per-head
+    norm weight shared by the heads.  ``A_log`` and ``dt_bias`` as the
+    published gated delta-rule code draws them (A in [0, 16], the step dt
+    log-uniform in [1e-3, 1e-1]); the convolution random, so that no term
+    of the layer is a no-op under random weights."""
+    h, hs = cfg.hidden_size, cfg.lin_num_value_heads
+    dk, dv = cfg.lin_key_head_dim, cfg.lin_value_head_dim
+    # the two scalars a head read the residual stream AS IT IS where the
+    # norms stand on the branches' outputs, and every layer adds two terms
+    # of unit size to it: drawn for a stream of the last layer's size, so
+    # that the decay's exponent and the step size's logit stay of order
+    # one.  At the plain scale a deep layer's decay wipes a head's state on
+    # one row in a few (exp(-16 x softplus(5))): its output is then one
+    # row's (k . q) v, near zero as often as not, the per-head norm blows
+    # the rounding of a bf16 trunk up, and no comparison of
+    # log-probabilities can tell a right trunk from a wrong one
+    stream = (2 * cfg.num_layers) ** 0.5 \
+        if cfg.norm_placement == "post" else 1.0
+    d.n += 1
+    ka, kd = jax.random.split(jax.random.fold_in(d.key, d.n))
+    a = jax.random.uniform(ka, (hs,), jnp.float32, 1e-2, 16.0)
+    dt = jnp.exp(jax.random.uniform(kd, (hs,), jnp.float32,
+                                    jnp.log(1e-3), jnp.log(1e-1)))
+    return {
+        # Wq, Wk, Wv side by side, [q | k | v] as the convolution takes them
+        "qkv_proj": d.dense(h, cfg.lin_conv_dim, False),
+        "g_proj": d.dense(h, hs * dv, False),
+        "a_proj": d.dense(h, hs, False, stream),
+        "b_proj": d.dense(h, hs, False, stream),
+        "conv": {"kernel": d.normal((cfg.lin_conv_kernel, cfg.lin_conv_dim),
+                                    cfg.lin_conv_kernel ** -0.5)},
+        "A_log": jnp.log(a),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),    # softplus^-1(dt)
+        "norm": {"scale": jnp.full((dv,), d.norm_init, d.dtype)},
+        "o_proj": d.dense(hs * dv, h, False),
+    }
+
+
 @partial(jax.jit, static_argnames=("cfg", "mesh"))
 def _init_head(key, cfg: ModelConfig, mesh=None) -> Params:
     """Everything outside the layer stack: embeddings, final norm, head."""
@@ -240,9 +291,13 @@ def init_params(cfg: ModelConfig, seed: int = 0, mesh=None) -> Params:
     seed)`` alone, not on the placement."""
     key = jax.random.key(seed)
     params = _init_head(jax.random.fold_in(key, cfg.num_layers), cfg, mesh)
+    # (``linear`` is passed only where it is set, so that a model without
+    # such layers calls, and caches, the program it always did)
     params["layers"] = [
         _init_layer(jax.random.fold_in(key, li), cfg,
-                    cfg.moe_layer_is_dense(li), mesh)
+                    cfg.moe_layer_is_dense(li), mesh,
+                    **({"linear": True}
+                       if cfg.layer_mixer(li) == MIXER_LINEAR else {}))
         for li in range(cfg.num_layers)]
     return params
 
@@ -302,7 +357,60 @@ def load_hf_checkpoint(cfg: ModelConfig, ckpt_dir: str) -> Params:
     dtype = param_dtype(cfg)
     if cfg.pos == "learned":
         return _load_opt(cfg, raw, dtype)
+    if cfg.linear_layers is not None:
+        return _load_olmo_hybrid(cfg, raw, dtype)
     return _load_llama_family(cfg, raw, dtype)
+
+
+def _load_olmo_hybrid(cfg: ModelConfig, raw: dict, dtype) -> Params:
+    """Olmo-Hybrid.  The tensor names are ASSUMED (the hybrid's own code
+    is unseen: benchmark/configs/olmo-hybrid-7b-l16.json ``assumed``):
+    the attention layers and the MLP as HF ``modeling_olmo2`` names them
+    (norms on each branch's output: ``post_attention_layernorm``,
+    ``post_feedforward_layernorm``; ``self_attn.q_norm`` / ``k_norm`` over
+    the whole projection), the linear layers under ``linear_attn.`` as the
+    published gated delta-rule layer names its own (a convolution each for
+    q, k and v, ``(channels, 1, width)``, the last tap weighing the row
+    itself: joined here into the one the forward pass runs)."""
+    def dense(name):
+        return {"kernel": _t(raw[name + ".weight"], dtype)}
+
+    def scale(name):
+        return {"scale": jnp.asarray(raw[name + ".weight"], dtype=dtype)}
+
+    layers = []
+    for i in range(cfg.num_layers):
+        pre = f"model.layers.{i}."
+        lp = {"post_attn_norm": scale(pre + "post_attention_layernorm"),
+              "post_mlp_norm": scale(pre + "post_feedforward_layernorm"),
+              **{p: dense(pre + "mlp." + p)
+                 for p in ("gate_proj", "up_proj", "down_proj")}}
+        if cfg.layer_mixer(i) == MIXER_LINEAR:
+            la = pre + "linear_attn."
+            lp["lin"] = {
+                "qkv_proj": {"kernel": jnp.concatenate([
+                    dense(la + p)["kernel"]
+                    for p in ("q_proj", "k_proj", "v_proj")], axis=1)},
+                **{p: dense(la + p)
+                   for p in ("g_proj", "a_proj", "b_proj", "o_proj")},
+                "conv": {"kernel": jnp.concatenate([
+                    jnp.asarray(raw[la + c + "_conv1d.weight"],
+                                dtype=dtype)[:, 0, :].T
+                    for c in ("q", "k", "v")], axis=1)},
+                "A_log": jnp.asarray(raw[la + "A_log"], jnp.float32),
+                "dt_bias": jnp.asarray(raw[la + "dt_bias"], jnp.float32),
+                "norm": scale(la + "o_norm")}
+        else:
+            sa = pre + "self_attn."
+            lp.update({p: dense(sa + p) for p in ("q_proj", "k_proj",
+                                                  "v_proj", "o_proj")})
+            lp["q_norm"], lp["k_norm"] = scale(sa + "q_norm"), \
+                scale(sa + "k_norm")
+        layers.append(lp)
+    return {"embed": {"weight": jnp.asarray(raw["model.embed_tokens.weight"],
+                                            dtype=dtype)},
+            "layers": layers, "final_norm": scale("model.norm"),
+            "lm_head": dense("lm_head")}
 
 
 def _load_falcon_h1_ssm(raw: dict, pre: str, dtype) -> Params:

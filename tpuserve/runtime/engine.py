@@ -444,7 +444,7 @@ class Engine:
         # (self.ssm_state, below).  Nothing snapshots a slot, so every
         # route that would need the state at some EARLIER token is closed
         # here or observed off below, each with its sentence — no option.
-        recurrent = self.model_cfg.has_ssm
+        recurrent = self.model_cfg.has_state
         if recurrent:
             name = self.model_cfg.name
             if mesh is not None or jax.process_count() > 1:
@@ -700,7 +700,7 @@ class Engine:
         # 128 rows of them in the kernel's VMEM budget): observed, no option.
         from tpuserve.ops.pallas_ragged_attention import ragged_block_for
         self._ragged_blk = ragged_block_for(
-            self.model_cfg.num_heads, self.model_cfg.cache_kv_heads,
+            self.model_cfg.cache_q_heads, self.model_cfg.cache_kv_heads,
             self.model_cfg.cache_head_dim, self.cache_cfg.block_size,
             jnp.dtype(self.cache_cfg.dtype).itemsize,
             jnp.dtype(self.model_cfg.dtype).itemsize,

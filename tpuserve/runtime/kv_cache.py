@@ -44,9 +44,11 @@ class CacheConfig:
 
 def bytes_per_block(model_cfg: ModelConfig, cache_cfg: CacheConfig,
                     head_shards: int = 1) -> int:
-    """Bytes one block takes across all layers and all ``head_shards``
-    shards of the kv-head axis — what :func:`create_kv_cache` allocates,
-    lane padding of the int8 scale pages included."""
+    """Bytes one block takes across all layers that hold K/V pages
+    (``ModelConfig.kv_layers``: a linear-attention layer holds none) and
+    all ``head_shards`` shards of the kv-head axis — what
+    :func:`create_kv_cache` allocates, lane padding of the int8 scale
+    pages included."""
     itemsize = jnp.dtype(cache_cfg.dtype).itemsize
     per_token = (model_cfg.cache_kv_heads * model_cfg.cache_head_dim
                  * itemsize)
@@ -58,12 +60,26 @@ def bytes_per_block(model_cfg: ModelConfig, cache_cfg: CacheConfig,
     # MLA stores ONE latent array (no V pages) — that asymmetry is the
     # ~10x cache-capacity win (models/transformer.py MLA section)
     kv_arrays = 1 if model_cfg.is_mla else 2
-    return (kv_arrays * model_cfg.num_layers * cache_cfg.block_size
+    return (kv_arrays * len(model_cfg.kv_layers) * cache_cfg.block_size
             * per_token)
 
 
 def _ssm_layer(c: ModelConfig, num_seats: int) -> dict:
-    """One layer of the recurrent-state pool, as shapes."""
+    """One layer of the recurrent-state pool, as shapes: Mamba-2 heads'
+    (Falcon-H1), or a linear-attention layer's matrix states in slabs of
+    heads whose lane axis is whole tiles (ops/pallas_gdn_update.py)."""
+    if c.linear_layers is not None:
+        from tpuserve.ops.pallas_gdn_update import heads_per_slab
+        hp = heads_per_slab(c.lin_num_value_heads, c.lin_value_head_dim)
+        return {"state": jax.ShapeDtypeStruct(
+                    (num_seats + 1, c.lin_num_value_heads // hp,
+                     c.lin_key_head_dim, hp * c.lin_value_head_dim),
+                    jnp.float32),
+                # float32 like the products it is cut from
+                # (models/transformer.py _lin_project)
+                "conv": jax.ShapeDtypeStruct(
+                    (num_seats + 1, c.lin_conv_kernel - 1, c.lin_conv_dim),
+                    jnp.float32)}
     return {"state": jax.ShapeDtypeStruct(
                 (num_seats + 1, c.mamba_n_heads, c.mamba_d_head,
                  c.mamba_d_state), jnp.float32),
@@ -75,24 +91,26 @@ def _ssm_layer(c: ModelConfig, num_seats: int) -> dict:
 def ssm_state_bytes(model_cfg: ModelConfig, num_seats: int) -> int:
     """Bytes :func:`create_ssm_state` allocates for ``num_seats`` seats
     (the trash seat counted): zero for a model without recurrent state."""
-    if not model_cfg.has_ssm:
+    if not model_cfg.has_state:
         return 0
-    return model_cfg.num_layers * sum(
+    return len(model_cfg.state_layers) * sum(
         math.prod(x.shape) * x.dtype.itemsize
         for x in _ssm_layer(model_cfg, num_seats).values())
 
 
 def create_ssm_state(model_cfg: ModelConfig, num_seats: int) -> list[dict]:
-    """Zero-initialised recurrent state of a model with state-space layers
-    (Falcon-H1), per layer ``{"state": (seats + 1, H, P, N) float32,
-    "conv": (seats + 1, W - 1, channels)}``: one slot a running sequence —
+    """Zero-initialised recurrent state, one entry a layer that holds one
+    (``ModelConfig.state_layers``, in order: every layer of Falcon-H1, the
+    linear-attention layers of Olmo-Hybrid), each ``{"state": (seats + 1,
+    H, P, N) float32 (a linear layer: :func:`_ssm_layer`), "conv": (seats
+    + 1, W - 1, channels)}``: one slot a running sequence —
     NOT a page a token like the KV cache beside it — and a last one that
     padding rows read and write (``SeatPool.trash``).  Float32 state: the
     recurrence accumulates over every token of a sequence.  The trunks
     update it in place (models/transformer.py, donated like the cache)."""
     return [{k: jnp.zeros(x.shape, x.dtype)
              for k, x in _ssm_layer(model_cfg, num_seats).items()}
-            for _ in range(model_cfg.num_layers)]
+            for _ in model_cfg.state_layers]
 
 
 def num_blocks_for_budget(model_cfg: ModelConfig, cache_cfg: CacheConfig,
@@ -238,7 +256,8 @@ def _kv_head_shards(sharding) -> int:
 
 def create_kv_cache(model_cfg: ModelConfig, cache_cfg: CacheConfig,
                     shardings=None) -> list[dict]:
-    """Zero-initialised per-layer [{"k","v"}] paged cache.
+    """Zero-initialised [{"k","v"}] paged cache, one entry a layer that
+    holds pages (``ModelConfig.kv_layers``, in order).
 
     ``shardings``: a single NamedSharding, or a per-layer [{"k","v"}] pytree
     (as from ``tpuserve.parallel.cache_shardings``).  Each buffer is created
@@ -269,7 +288,7 @@ def create_kv_cache(model_cfg: ModelConfig, cache_cfg: CacheConfig,
                      (*shape[:2], groups * SCALE_LANES), jnp.float32)
 
     cache = []
-    for li in range(model_cfg.num_layers):
+    for li in range(len(model_cfg.kv_layers)):
         if shardings is None:
             k_sh = v_sh = None
         elif isinstance(shardings, list):

@@ -258,6 +258,19 @@ class ServerMetrics:
             "Tokens prefilled AGAIN (prompt plus everything generated) "
             "because a pre-empted or salvaged sequence's recurrent state "
             "was dropped: the price of having no state snapshot")
+        # what kv_bytes_per_token and the state pool's bytes were counted
+        # over (ModelConfig.kv_layers / state_layers): a linear-attention
+        # layer holds a state and no pages, an attention layer pages and
+        # no state, Falcon-H1's every layer both
+        self.kv_page_layers = gauge(
+            "tpuserve_kv_page_layers",
+            "Layers of the running model that hold K/V pages: what the "
+            "paged cache's bytes a token are counted over")
+        self.state_layers = gauge(
+            "tpuserve_state_layers",
+            "Layers of the running model that hold a recurrent state a "
+            "sequence: what the seat pool's bytes are counted over; 0 for "
+            "a model without such layers")
         # expert layers (models/transformer.py _moe_mlp): what the sparse
         # dispatch routed, counted on the device and read with each
         # dispatch's tokens (Engine._moe_note); all zero and the
@@ -564,6 +577,11 @@ class ServerMetrics:
         self.request_success.labels(model_name=self.model_name,
                                     finished_reason=reason).inc()
         self.request_duration.observe(duration_s)
+
+    def set_layer_kinds(self, model_cfg) -> None:
+        """The two gauges of layers by the memory they hold."""
+        self.kv_page_layers.set(len(model_cfg.kv_layers))
+        self.state_layers.set(len(model_cfg.state_layers))
 
     def render(self) -> bytes:
         return generate_latest(self.registry)

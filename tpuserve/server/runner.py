@@ -1114,6 +1114,8 @@ class AsyncEngineRunner:
             sum(getattr(bm, "num_cached_blocks", 0) for bm in bms))
         self.metrics.moe_experts_held.set(
             getattr(getattr(eng, "model_cfg", None), "moe_experts_held", 0))
+        if getattr(eng, "model_cfg", None) is not None:
+            self.metrics.set_layer_kinds(eng.model_cfg)
         dead = [e.window_dead_tokens() for e in (inners or [eng])
                 if hasattr(e, "window_dead_tokens")]
         if dead:
