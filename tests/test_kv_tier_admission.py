@@ -20,6 +20,7 @@ from tier_drive import tiny_engine as _mk_engine
 
 SHARED = list(range(2, 26))      # 24 tokens = 6 full blocks at block_size 4
 PARAMS = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
+PROBE = 84     # clear of a tie in all six tokens (tests/test_kv_tiers.py)
 
 
 def _pages(nbytes=64):
@@ -95,7 +96,7 @@ def test_a_first_eviction_is_declined_and_touches_nothing(monkeypatch):
     assert isinstance(eng.block_manager, BlockManager)
     eng.generate([SHARED + [30 + i] for i in range(2)], PARAMS)
     eng.generate(CHURN, PARAMS)         # the shared prefix leaves HBM
-    chain = eng.block_manager.prefix_chain(SHARED + [77])
+    chain = eng.block_manager.prefix_chain(SHARED + [PROBE])
     assert chain and not any(eng.block_manager.prefix_resolvable(h)
                              for h in chain)
     assert eng.stats.kv_demote_declined_blocks >= len(chain)
@@ -106,9 +107,9 @@ def test_a_first_eviction_is_declined_and_touches_nothing(monkeypatch):
     assert all(h in store._seen for h in chain)
     # the prefix is gone as with no tier: recomputed, and the same tokens
     # (strict mode: a remembered hash now resolvable in HBM is in one tier)
-    tiered = eng.generate([SHARED + [77]], PARAMS)[0]
+    tiered = eng.generate([SHARED + [PROBE]], PARAMS)[0]
     assert eng.stats.kv_restores == 0
-    cold = _mk_engine(False).generate([SHARED + [77]], PARAMS)[0]
+    cold = _mk_engine(False).generate([SHARED + [PROBE]], PARAMS)[0]
     assert tiered.output_token_ids == cold.output_token_ids
     eng._check_block_integrity()
     # its second time cold it is demoted
@@ -134,12 +135,12 @@ def test_a_prefix_cold_twice_restores_token_identically(monkeypatch, dtype,
     assert eng.stats.kv_demoted_blocks > 0
     assert eng.stats.kv_demote_declined_blocks > 0
     assert eng.stats.kv_restores == 0
-    chain = eng.block_manager.prefix_chain(SHARED + [77])
+    chain = eng.block_manager.prefix_chain(SHARED + [PROBE])
     assert all(eng._kv_tiers.has(h) for h in chain)
-    tiered = eng.generate([SHARED + [77]], PARAMS)[0]      # third arrival
+    tiered = eng.generate([SHARED + [PROBE]], PARAMS)[0]      # third arrival
     assert eng.stats.kv_restores == 1
     assert eng.stats.kv_restored_blocks == len(chain)
-    cold = _mk_engine(False, dtype).generate([SHARED + [77]], PARAMS)[0]
+    cold = _mk_engine(False, dtype).generate([SHARED + [PROBE]], PARAMS)[0]
     assert tiered.output_token_ids == cold.output_token_ids
 
 

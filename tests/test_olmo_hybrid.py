@@ -563,53 +563,56 @@ def test_the_gauges_say_what_each_memory_was_counted_over():
 
 # sha256 (first 16 hex digits) of each trunk's lowered text, operation
 # names included and source lines left out (as the compile cache keys a
-# program: tpuserve/utils/compile_cache.py), taken from the commit before
-# this model (efe1553) for the tiny model of each accepted configuration's
-# family.  A layer's kind is a static branch of the layer loops, so a model
-# without linear layers must lower to the text it had: a scope renamed, an
-# operation moved or added in a shared helper shows here before it costs
-# the five accepted cells a cold compile (or their speed) on the chip.
-# A PR that MEANS to change a trunk replaces the pins it changes.
+# program: tpuserve/utils/compile_cache.py) for the tiny model of each
+# accepted configuration's family.  A layer's kind is a static branch of
+# the layer bodies, so a model without linear layers must lower to the text
+# it had: a scope renamed, an operation moved or added in a shared helper
+# shows here before it costs the accepted cells a cold compile (or their
+# speed) on the chip.  A PR that MEANS to change a trunk replaces the pins
+# it changes: first taken from the commit before this model (efe1553), all
+# replaced by PR 44, which put every trunk's per-layer body under its own
+# ``jax.jit`` (one private function a kind of layer in each module) and
+# rounds each half of a rotated vector where it is made (ops/rope.py).
 LOWERED = {
     "tiny-qwen3": {
-        ("pallas", "decode_multi"): "b64550c9b6a96587",
-        ("pallas", "forward_ragged"): "83ead1cba0e48e35",
-        ("pallas", "prefill_chunk"): "4189a80d2ac8586b",
-        ("reference", "decode_multi"): "4780fa0c6fd3cb56",
-        ("reference", "forward_ragged"): "82703914716d8c0d",
-        ("reference", "prefill_chunk"): "e1bd6fa0c610c59d",
+        ("pallas", "decode_multi"): "816a20b23bf8ff09",
+        ("pallas", "forward_ragged"): "4f7735134122b74d",
+        ("pallas", "prefill_chunk"): "0fe020fc804219d1",
+        ("reference", "decode_multi"): "9a87c60d8a2dbf74",
+        ("reference", "forward_ragged"): "4514f95a67f44bbb",
+        ("reference", "prefill_chunk"): "c5e20e1507e0601d",
     },
     "tiny-mistral": {
-        ("pallas", "decode_multi"): "624db63830a424bb",
-        ("pallas", "forward_ragged"): "8c1758605845e647",
-        ("pallas", "prefill_chunk"): "038e4d50b894222d",
-        ("reference", "decode_multi"): "d1f4fdbc54c55247",
-        ("reference", "forward_ragged"): "d0fc3f202f3e3430",
-        ("reference", "prefill_chunk"): "85f5d59c6f27967c",
+        ("pallas", "decode_multi"): "da80b79a288c3ec2",
+        ("pallas", "forward_ragged"): "e793e870bfd7edca",
+        ("pallas", "prefill_chunk"): "070480809ba7171d",
+        ("reference", "decode_multi"): "cb49c1759a45a69d",
+        ("reference", "forward_ragged"): "ea7b0f5c2ce6e69b",
+        ("reference", "prefill_chunk"): "9c27a385d9768d22",
     },
     "tiny-falcon-h1": {
-        ("pallas", "decode_multi"): "56cb7f326e32308f",
-        ("pallas", "forward_ragged"): "07ee8d532cbf7d52",
-        ("pallas", "prefill_chunk"): "1a721fc2b1b587f2",
-        ("reference", "decode_multi"): "fc96ee9d9d3a3c92",
-        ("reference", "forward_ragged"): "6e275b20193cacf1",
-        ("reference", "prefill_chunk"): "b2947336343b90e1",
+        ("pallas", "decode_multi"): "2e3d67b6d75b2fc1",
+        ("pallas", "forward_ragged"): "e225f21e666f6f6f",
+        ("pallas", "prefill_chunk"): "026498feb498f6ac",
+        ("reference", "decode_multi"): "90fc2cc2622b5843",
+        ("reference", "forward_ragged"): "6b5cf5b11a9ef444",
+        ("reference", "prefill_chunk"): "3a82802b4f024d89",
     },
     "tiny-mellum2": {
-        ("pallas", "decode_multi"): "41f991163d1048f1",
-        ("pallas", "forward_ragged"): "004cfe770d5a96df",
-        ("pallas", "prefill_chunk"): "c0630d674b786e0a",
-        ("reference", "decode_multi"): "33fc07f973335e29",
-        ("reference", "forward_ragged"): "e57582ced8a91e13",
-        ("reference", "prefill_chunk"): "6cde3f8e3a064920",
+        ("pallas", "decode_multi"): "885b81fb59a5e229",
+        ("pallas", "forward_ragged"): "840cda028cc82f7d",
+        ("pallas", "prefill_chunk"): "0441359e89149443",
+        ("reference", "decode_multi"): "3bf7981e3b6915ec",
+        ("reference", "forward_ragged"): "e4df6a5e4658e49b",
+        ("reference", "prefill_chunk"): "ebf7b47fe0a4f1a0",
     },
     "tiny-k-exaone+share": {
-        ("pallas", "decode_multi"): "7740e8f7cca8db4d",
-        ("pallas", "forward_ragged"): "310177b2925457d5",
-        ("pallas", "prefill_chunk"): "b4d175706d966c7f",
-        ("reference", "decode_multi"): "2bb23160d0d19751",
-        ("reference", "forward_ragged"): "aff435fc86315f98",
-        ("reference", "prefill_chunk"): "6369680f3d8b6cca",
+        ("pallas", "decode_multi"): "858b4baaa33a367e",
+        ("pallas", "forward_ragged"): "c922e55edf29688d",
+        ("pallas", "prefill_chunk"): "6711998584e1329f",
+        ("reference", "decode_multi"): "2bd76c32b7874656",
+        ("reference", "forward_ragged"): "925f32cdc424f297",
+        ("reference", "prefill_chunk"): "1e18c77450bd2dec",
     },
 }
 

@@ -12,6 +12,7 @@ per-family presets plus loading from a HuggingFace ``config.json``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import os
 from typing import Optional
@@ -358,6 +359,23 @@ class ModelConfig:
         return not (self.rope_windowed_only
                     and self.layer_window(layer_idx) is None)
 
+    def layer_like(self, layer_idx: int) -> int:
+        """The lowest layer index that answers every per-layer question of
+        this configuration as ``layer_idx`` does: layer_mixer(),
+        layer_window(), layer_rotates(), layer_rope(), layer_yarn() and
+        moe_layer_is_dense().  Layers of one KIND share it, and a trunk
+        hands it to its layer's body in place of the layer's own index
+        (models/transformer.py: one trace and one lowered function a
+        kind, not a layer).  A per-layer question added to this class
+        belongs in ``_layer_kind`` too."""
+        return _layer_likes(self)[layer_idx]
+
+    def _layer_kind(self, layer_idx: int) -> tuple:
+        return (self.layer_mixer(layer_idx), self.layer_window(layer_idx),
+                self.layer_rotates(layer_idx), self.layer_rope(layer_idx),
+                self.layer_yarn(layer_idx),
+                self.moe_layer_is_dense(layer_idx))
+
     # What a configuration file's keys are held to (benchmark/harness/
     # plan.py compares with ``!=``: a JSON list or dict never equals a
     # tuple), as config.json spells them.
@@ -553,6 +571,15 @@ class ModelConfig:
 
 
 _REGISTRY: dict[str, ModelConfig] = {}
+
+
+@functools.lru_cache(maxsize=64)
+def _layer_likes(cfg: ModelConfig) -> tuple:
+    """``cfg.layer_like`` of every layer: asked once a layer of every
+    program a trunk traces, so kept by configuration."""
+    first: dict = {}
+    return tuple(first.setdefault(cfg._layer_kind(i), i)
+                 for i in range(cfg.num_layers))
 
 
 def register_model_config(cfg: ModelConfig, *aliases: str) -> ModelConfig:

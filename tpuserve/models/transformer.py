@@ -17,7 +17,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from tpuserve.models.config import MIXER_LINEAR, ModelConfig
+from tpuserve.models.config import (MIXER_ATTENTION, MIXER_LINEAR,
+                                    ModelConfig)
 from tpuserve.ops import attention as attn_ops
 from tpuserve.ops import gated_delta as gdn_ops
 from tpuserve.ops import rope as rope_ops
@@ -1231,6 +1232,340 @@ def _moe_routing(tally: list | None, rows: jnp.ndarray | None):
 
 
 # --------------------------------------------------------------------------
+# One layer of each trunk, traced once a KIND
+# --------------------------------------------------------------------------
+#
+# A trunk walks ``params["layers"]`` in a Python loop.  Were the layer's
+# body written out in that loop, every program would trace, and then lower
+# to StableHLO, as many copies of it as the model has layers: most of a
+# program's host time, a hundred programs a warm start (PERF.md §6, PR 44).
+# So each trunk's per-layer body is a function under its OWN ``jax.jit``.
+# Called inside a trace, such a function is traced once per (static
+# arguments, abstract arguments) and lowered once, as one private function
+# that the module calls; XLA inlines the calls before it optimises, so the
+# device's program is what it was.  The static layer index is
+# ``cfg.layer_like(li)``, the first layer that answers every per-layer
+# question of the configuration as layer ``li`` does, so layers of one
+# kind share a trace; layers whose weights differ in shape, dtype or tree
+# (a dense layer beside sparse ones, int8 beside bfloat16) part by
+# ``jax.jit``'s own key.  Nothing is donated here (the outer program
+# donates).  The phase scope stays with the trunk and the part scopes with
+# the helpers, so an operation's path gains a ``jit(_<body>_layer)``
+# component and keeps its phase and part.  A cache body opens its trunk's
+# phase ONCE MORE around itself: the chip's compiler rebuilds some
+# operations (decode's K/V row scatter) under the name they have INSIDE
+# the called function, without the call's prefix, and a scatter filed
+# under no phase is time gone from ``trunk.decode_glue_ms``.  (What that
+# compiler makes itself inside a called function it names after the CALL,
+# ``.../jit(_decode_layer)``: a phase and no part, where in a flat module
+# it carries no name at all.  tests/test_chip_compile.py holds both.)
+#
+# A cache trunk's body takes and returns its layer's entry of the paged
+# cache and of the seat pool (None where the layer holds none) and returns
+# its expert layer's routing (:func:`_moe_mlp`'s ``tally`` entry) or None.
+#
+# Two host counts a body, read by ``Engine.warmup``'s closing log line and
+# ``/metrics``: bodies TRACED (the first statement of each body: it runs
+# only when JAX traces it) and calls made (at the call sites).
+
+LAYER_BODIES = ("prefill", "chunk", "decode", "ragged", "nocache")
+LAYER_TRACES = dict.fromkeys(LAYER_BODIES, 0)
+LAYER_CALLS = dict.fromkeys(LAYER_BODIES, 0)
+
+
+def _walk_layers(body: str, layer, params: Params, cfg: ModelConfig, h,
+                 kv_cache: list, ssm: list | None, tally: list | None,
+                 *rows, **static):
+    """A cache trunk's loop: ``layer`` (the jitted body counted under
+    ``body``) once a layer, on the residual stream, the layer's weights,
+    its entry of the paged cache and of the seat pool (None where it holds
+    none: ``ModelConfig.layer_mixer``), then ``rows``, the dispatch's own
+    arrays.  Returns (h, kv_cache, seat pool) and collects the expert
+    layers' routing in ``tally``."""
+    new_cache, new_ssm = [], []
+    for li, lp in enumerate(params["layers"]):
+        LAYER_CALLS[body] += 1
+        mixer = cfg.layer_mixer(li)
+        entry = None if mixer == MIXER_LINEAR else kv_cache[len(new_cache)]
+        pool = None if ssm is None or mixer == MIXER_ATTENTION \
+            else ssm[len(new_ssm)]
+        h, entry, pool, routed = layer(h, lp, entry, pool, *rows, cfg=cfg,
+                                       li=cfg.layer_like(li), **static)
+        if entry is not None:
+            new_cache.append(entry)
+        if pool is not None:
+            new_ssm.append(pool)
+        if routed is not None and tally is not None:
+            tally.append(routed)
+    return h, new_cache, new_ssm
+
+
+def _walk_nocache(params: Params, cfg: ModelConfig, h, positions, lens,
+                  moe_dense: bool = False):
+    """A cache-less trunk's loop (embeddings, prompt scoring, the draft
+    proposer, the plain forward): :func:`_nocache_layer` once a layer."""
+    for li, lp in enumerate(params["layers"]):
+        LAYER_CALLS["nocache"] += 1
+        h = _nocache_layer(h, lp, positions, lens, cfg=cfg,
+                           li=cfg.layer_like(li), moe_dense=moe_dense)
+    return h
+
+
+@partial(jax.jit, static_argnames=("cfg", "li", "attn_impl", "mesh",
+                                   "moe_dense"))
+def _prefill_layer(h, lp, entry, pool, positions, prompt_lens, slot_ids, ad,
+                   seats, *, cfg: ModelConfig, li: int, attn_impl: str, mesh,
+                   moe_dense: bool):
+    """One layer of :func:`prefill`: h (B, T, hidden)."""
+    LAYER_TRACES["prefill"] += 1
+    with jax.named_scope(scopes.PREFILL):
+        tally = _moe_tally(cfg)
+        scale, sw = cfg.attn_scale, cfg.layer_window(li)
+        if cfg.layer_mixer(li) == MIXER_LINEAR:
+            h, pool = _lin_window(h, lp, cfg, prompt_lens, pool, seats)
+        elif cfg.is_mla:
+            # MLA prefill: cache the latent, attend naively (decompressed)
+            # over the fresh prompt K/V — reference impl only; the Pallas
+            # kernels assume materialised per-head K/V pages
+            q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
+            entry = attn_ops.write_mla_entry(
+                entry, latent, slot_ids, latent_split=cfg.mla_kv_lora_rank)
+            out = _mla_prefill_out(q_nope, q_rope, latent, lp, cfg,
+                                   prompt_lens, scale)
+            h = _attn_residual(h, out, lp, cfg, ad)
+        else:
+            q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)
+            # batched prefill attends over the FRESH k/v (full precision even
+            # when the cache stores int8 — only cache READS see quantization)
+            entry = attn_ops.write_kv_entry(entry, k, v, slot_ids)
+            cap = cfg.attn_logit_softcapping
+            if attn_impl == "pallas" and mesh is not None:
+                from tpuserve.ops.pallas_tp import flash_prefill_attention_tp
+                out = flash_prefill_attention_tp(
+                    q, k, v, prompt_lens, scale, mesh, sliding_window=sw,
+                    logit_softcap=cap)
+            elif attn_impl == "pallas":
+                from tpuserve.ops.pallas_flash_attention import \
+                    flash_prefill_attention
+                out = flash_prefill_attention(
+                    q, k, v, prompt_lens, scale, sliding_window=sw,
+                    logit_softcap=cap)
+            else:
+                out = attn_ops.prefill_attention(
+                    q, k, v, prompt_lens, scale, sliding_window=sw,
+                    logit_softcap=cap)
+            m = None
+            if pool is not None:
+                m, pool = _ssm_window(hn, lp["ssm"], cfg, prompt_lens, pool,
+                                      seats)
+            h = _attn_residual(h, out, lp, cfg, ad, m)
+        h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+        return h, entry, pool, tally[0] if tally else None
+
+
+@partial(jax.jit, static_argnames=("cfg", "li", "moe_dense"))
+def _nocache_layer(h, lp, positions, lens, *, cfg: ModelConfig, li: int,
+                   moe_dense: bool):
+    """One layer of the cache-less trunks: h (B, T, hidden), plain causal
+    attention over each row's first ``lens`` tokens, every recurrent state
+    from zeros, nothing kept."""
+    LAYER_TRACES["nocache"] += 1
+    if cfg.layer_mixer(li) == MIXER_LINEAR:
+        return _mlp_residual(_lin_window(h, lp, cfg, lens)[0], lp, cfg,
+                             moe_dense=moe_dense)
+    q, k, v, hn = (_mla_naive_qkv(h, lp, cfg, positions) if cfg.is_mla
+                   else _qkv(h, lp, cfg, positions, li))
+    out = attn_ops.prefill_attention(q, k, v, lens, cfg.attn_scale,
+                                     sliding_window=cfg.layer_window(li),
+                                     logit_softcap=cfg.attn_logit_softcapping)
+    h = _attn_residual(h, out, lp, cfg) + _ssm_nocache(hn, lp, cfg, lens)
+    return _mlp_residual(h, lp, cfg, moe_dense=moe_dense)
+
+
+@partial(jax.jit, static_argnames=("cfg", "li", "phase", "attn_impl",
+                                   "mesh", "moe_dense", "aligned"))
+def _chunk_layer(h, lp, entry, pool, positions, ctx_lens, chunk_lens,
+                 slot_ids, block_tables, ad, seats, *, cfg: ModelConfig,
+                 li: int, phase: str, attn_impl: str, mesh, moe_dense: bool,
+                 aligned: bool):
+    """One layer of :func:`_chunk_trunk`: h (B, C, hidden), a window of
+    rows a sequence against its cached context."""
+    LAYER_TRACES["chunk"] += 1
+    with jax.named_scope(phase):
+        tally = _moe_tally(cfg)
+        scale, sw = cfg.attn_scale, cfg.layer_window(li)
+        if cfg.layer_mixer(li) == MIXER_LINEAR:
+            h, pool = _lin_window(h, lp, cfg, chunk_lens, pool, seats,
+                                  fresh=ctx_lens == 0)
+        elif cfg.is_mla:
+            # MLA window: write the latent, attend ABSORBED against the
+            # latent pages (k == v == latent; value = first kv_lora cols)
+            q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
+            entry = attn_ops.write_mla_entry(entry, latent, slot_ids,
+                                             latent_split=cfg.mla_kv_lora_rank)
+            q_eff = _mla_absorb_q(q_nope, q_rope, lp, cfg)
+            out = attn_ops.chunked_prefill_attention(
+                q_eff, entry["k"], entry["k"], block_tables, ctx_lens,
+                chunk_lens, scale, k_scale=entry.get("ks"),
+                v_scale=entry.get("ks"),
+                scale_slices=(cfg.mla_kv_lora_rank,
+                              cfg.mla_qk_rope_head_dim))
+            out = _mla_unabsorb(out, lp, cfg)
+            h = _attn_residual(h, out, lp, cfg, ad)
+        else:
+            q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)
+            entry = attn_ops.write_kv_entry(entry, k, v, slot_ids, aligned)
+            ck, cv = entry["k"], entry["v"]
+            ks, vs = entry.get("ks"), entry.get("vs")
+            if attn_impl == "pallas" and mesh is not None:
+                from tpuserve.ops.pallas_tp import paged_window_attention_tp
+                out = paged_window_attention_tp(
+                    q, ck, cv, block_tables, ctx_lens, chunk_lens, scale, mesh,
+                    k_scale=ks, v_scale=vs, sliding_window=sw,
+                    logit_softcap=cfg.attn_logit_softcapping)
+            elif attn_impl == "pallas":
+                from tpuserve.ops.pallas_chunked_prefill import \
+                    paged_window_attention
+                out = paged_window_attention(
+                    q, ck, cv, block_tables, ctx_lens, chunk_lens, scale,
+                    k_scale=ks, v_scale=vs, sliding_window=sw,
+                    logit_softcap=cfg.attn_logit_softcapping)
+            else:
+                out = attn_ops.chunked_prefill_attention(
+                    q, ck, cv, block_tables, ctx_lens, chunk_lens, scale,
+                    k_scale=ks, v_scale=vs, sliding_window=sw,
+                    logit_softcap=cfg.attn_logit_softcapping)
+            m = None
+            if pool is not None:
+                m, pool = _ssm_window(hn, lp["ssm"], cfg, chunk_lens, pool,
+                                      seats, fresh=ctx_lens == 0)
+            h = _attn_residual(h, out, lp, cfg, ad, m)
+        h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+        return h, entry, pool, tally[0] if tally else None
+
+
+@partial(jax.jit, static_argnames=("cfg", "li", "attn_impl", "mesh",
+                                   "moe_dense"))
+def _decode_layer(h, lp, entry, pool, positions, slot_ids, block_tables,
+                  seq_lens, ad, seats, *, cfg: ModelConfig, li: int,
+                  attn_impl: str, mesh, moe_dense: bool):
+    """One layer of :func:`_decode_body`: h (B, hidden), one token a row."""
+    LAYER_TRACES["decode"] += 1
+    with jax.named_scope(scopes.DECODE):
+        tally = _moe_tally(cfg)
+        scale, sw = cfg.attn_scale, cfg.layer_window(li)
+        if cfg.layer_mixer(li) == MIXER_LINEAR:
+            h, pool = _lin_decode(h, lp, cfg, slot_ids, pool, seats, attn_impl)
+        elif cfg.is_mla:
+            # MLA decode: absorbed attention straight against the latent
+            # pages — the step reads mla_latent_dim bytes per cached token
+            # instead of 2 * Hkv * head_dim (the ~10x KV-bandwidth win)
+            q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
+            entry = attn_ops.write_mla_entry(entry, latent, slot_ids,
+                                             latent_split=cfg.mla_kv_lora_rank)
+            q_eff = _mla_absorb_q(q_nope, q_rope, lp, cfg)
+            out = attn_ops.paged_decode_attention(
+                q_eff, entry["k"], entry["k"], block_tables, seq_lens,
+                scale, k_scale=entry.get("ks"), v_scale=entry.get("ks"),
+                scale_slices=(cfg.mla_kv_lora_rank,
+                              cfg.mla_qk_rope_head_dim))
+            out = _mla_unabsorb(out, lp, cfg)
+            h = _attn_residual(h, out, lp, cfg, ad)
+        else:
+            q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)  # (B, Hq/Hkv, D)
+            entry = attn_ops.write_kv_entry(entry, k, v, slot_ids)
+            ck, cv = entry["k"], entry["v"]
+            ks, vs = entry.get("ks"), entry.get("vs")
+            if attn_impl == "pallas" and mesh is not None:
+                from tpuserve.ops.pallas_tp import paged_decode_attention_tp
+                out = paged_decode_attention_tp(
+                    q, ck, cv, block_tables, seq_lens, scale, mesh,
+                    k_scale=ks, v_scale=vs, sliding_window=sw,
+                    logit_softcap=cfg.attn_logit_softcapping)
+            else:
+                if attn_impl == "pallas":
+                    from tpuserve.ops.pallas_paged_attention import \
+                        paged_decode_attention as impl
+                else:
+                    impl = attn_ops.paged_decode_attention
+                out = impl(q, ck, cv, block_tables, seq_lens, scale,
+                           k_scale=ks, v_scale=vs, sliding_window=sw,
+                           logit_softcap=cfg.attn_logit_softcapping)
+            m = None
+            if pool is not None:
+                m, pool = _ssm_decode(
+                    hn, lp["ssm"], cfg, slot_ids != attn_ops.PAD_SLOT, pool,
+                    seats, attn_impl)
+            h = _attn_residual(h, out, lp, cfg, ad, m)
+        h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+        return h, entry, pool, tally[0] if tally else None
+
+
+@partial(jax.jit, static_argnames=("cfg", "li", "ragged_blk", "attn_impl",
+                                   "decode_rows", "moe_dense", "aligned"))
+def _ragged_layer(h, lp, entry, pool, positions, slot_ids, row_seq, row_lens,
+                  block_tables, kv_lens, q_starts, q_lens, meta, blk_seq, ad,
+                  seats, *, cfg: ModelConfig, li: int, ragged_blk: int,
+                  attn_impl: str, decode_rows: bool, moe_dense: bool,
+                  aligned: bool):
+    """One layer of :func:`forward_ragged`: h (T, hidden), one flat token
+    stream."""
+    LAYER_TRACES["ragged"] += 1
+    with jax.named_scope(scopes.PREFILL):
+        tally = _moe_tally(cfg)
+        scale, sw = cfg.attn_scale, cfg.layer_window(li)
+        if cfg.layer_mixer(li) == MIXER_LINEAR:
+            h, pool = _lin_packed(h, lp, cfg, positions, slot_ids, blk_seq,
+                                  q_starts, q_lens, ragged_blk, pool, seats)
+        elif cfg.is_mla:
+            # MLA: absorbed attention against the latent pages, like the
+            # chunk/decode trunks (reference path only — the Pallas
+            # kernels assume materialised per-head pages, same gate as
+            # the rest of the engine)
+            q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
+            entry = attn_ops.write_mla_entry(
+                entry, latent, slot_ids, latent_split=cfg.mla_kv_lora_rank)
+            q_eff = _mla_absorb_q(q_nope, q_rope, lp, cfg)
+            out = _ragged_reference_attn(
+                q_eff, entry["k"], entry["k"], block_tables, row_seq,
+                row_lens, blk_seq, meta, ragged_blk, scale,
+                entry.get("ks"), entry.get("ks"), None, None,
+                scale_slices=(cfg.mla_kv_lora_rank,
+                              cfg.mla_qk_rope_head_dim),
+                decode_rows=decode_rows)
+            out = _mla_unabsorb(out, lp, cfg)
+            h = _attn_residual(h, out, lp, cfg, ad)
+        else:
+            q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)  # (T, H*, D)
+            entry = attn_ops.write_kv_entry(entry, k, v, slot_ids, aligned)
+            ck, cv = entry["k"], entry["v"]
+            ks, vs = entry.get("ks"), entry.get("vs")
+            if attn_impl == "pallas":
+                from tpuserve.ops.pallas_ragged_attention import \
+                    ragged_paged_attention
+                out = ragged_paged_attention(
+                    q, ck, cv, block_tables, kv_lens, q_starts, q_lens,
+                    meta, blk_seq, scale, blk_q=ragged_blk, k_scale=ks,
+                    v_scale=vs, sliding_window=sw,
+                    logit_softcap=cfg.attn_logit_softcapping,
+                    decode_rows=decode_rows)
+            else:
+                out = _ragged_reference_attn(
+                    q, ck, cv, block_tables, row_seq, row_lens, blk_seq,
+                    meta, ragged_blk, scale, ks, vs, sw,
+                    cfg.attn_logit_softcapping, decode_rows=decode_rows)
+            m = None
+            if pool is not None:
+                m, pool = _ssm_packed(
+                    hn, lp["ssm"], cfg, positions,
+                    slot_ids != attn_ops.PAD_SLOT, blk_seq, q_starts, q_lens,
+                    ragged_blk, pool, seats)
+            h = _attn_residual(h, out, lp, cfg, ad, m)
+        h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+        return h, entry, pool, tally[0] if tally else None
+
+
+# --------------------------------------------------------------------------
 # Prefill: process full (padded) prompts, write KV cache, return last logits
 # --------------------------------------------------------------------------
 
@@ -1261,57 +1596,11 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         B, T = tokens.shape
         positions = jnp.arange(T)[None, :].repeat(B, axis=0)
         h = _embed(params, cfg, tokens, positions)
-        scale = cfg.attn_scale
-        new_cache = []
-        new_ssm = []
         tally = _moe_tally(cfg)
-        for li, lp in enumerate(params["layers"]):
-            sw = cfg.layer_window(li)
-            if cfg.layer_mixer(li) == MIXER_LINEAR:
-                h, entry = _lin_window(h, lp, cfg, prompt_lens,
-                                       ssm[len(new_ssm)], seats)
-                new_ssm.append(entry)
-                h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
-                continue
-            if cfg.is_mla:
-                # MLA prefill: cache the latent, attend naively (decompressed)
-                # over the fresh prompt K/V — reference impl only; the Pallas
-                # kernels assume materialised per-head K/V pages
-                q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
-                new_cache.append(attn_ops.write_mla_entry(
-                    kv_cache[len(new_cache)], latent, slot_ids,
-                    latent_split=cfg.mla_kv_lora_rank))
-                out = _mla_prefill_out(q_nope, q_rope, latent, lp, cfg,
-                                       prompt_lens, scale)
-                h = _attn_residual(h, out, lp, cfg, ad)
-                h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
-                continue
-            q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)
-            # batched prefill attends over the FRESH k/v (full precision even
-            # when the cache stores int8 — only cache READS see quantization)
-            new_cache.append(attn_ops.write_kv_entry(kv_cache[len(new_cache)], k, v,
-                                                     slot_ids))
-            if attn_impl == "pallas" and mesh is not None:
-                from tpuserve.ops.pallas_tp import flash_prefill_attention_tp
-                out = flash_prefill_attention_tp(q, k, v, prompt_lens, scale,
-                                                 mesh, sliding_window=sw,
-                                                 logit_softcap=cfg.attn_logit_softcapping)
-            elif attn_impl == "pallas":
-                from tpuserve.ops.pallas_flash_attention import flash_prefill_attention
-                out = flash_prefill_attention(q, k, v, prompt_lens, scale,
-                                              sliding_window=sw,
-                                              logit_softcap=cfg.attn_logit_softcapping)
-            else:
-                out = attn_ops.prefill_attention(q, k, v, prompt_lens, scale,
-                                                 sliding_window=sw,
-                                                 logit_softcap=cfg.attn_logit_softcapping)
-            m = None
-            if ssm is not None and cfg.has_ssm:
-                m, entry = _ssm_window(hn, lp["ssm"], cfg, prompt_lens, ssm[len(new_ssm)],
-                                       seats)
-                new_ssm.append(entry)
-            h = _attn_residual(h, out, lp, cfg, ad, m)
-            h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+        h, new_cache, new_ssm = _walk_layers(
+            "prefill", _prefill_layer, params, cfg, h, kv_cache, ssm, tally,
+            positions, prompt_lens, slot_ids, ad, seats,
+            attn_impl=attn_impl, mesh=mesh, moe_dense=moe_dense)
         last_idx = jnp.maximum(prompt_lens - 1, 0)
         return _with_ssm(_unembed(params, cfg, h, last_idx), new_cache, ssm,
                          new_ssm,
@@ -1357,8 +1646,8 @@ def prefill_chunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         # pages, its K and V go out a page at a time
         h, new_cache, new_ssm = _chunk_trunk(
             params, cfg, tokens, ctx_lens, chunk_lens, slot_ids, block_tables,
-            kv_cache, ad, ssm, seats, attn_impl=attn_impl, mesh=mesh,
-            tally=tally, moe_dense=moe_dense,
+            kv_cache, ad, ssm, seats, phase=scopes.CHUNK,
+            attn_impl=attn_impl, mesh=mesh, tally=tally, moe_dense=moe_dense,
             aligned=attn_ops.kv_stream_by_page(kv_cache[0], tokens.shape[1],
                                                attn_impl, mesh))
         last_idx = jnp.maximum(chunk_lens - 1, 0)
@@ -1388,21 +1677,7 @@ def embed_forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         B, T = tokens.shape
         positions = jnp.arange(T)[None, :].repeat(B, axis=0)
         h = _embed(params, cfg, tokens, positions)
-        scale = cfg.attn_scale
-        for li, lp in enumerate(params["layers"]):
-            sw = cfg.layer_window(li)
-            if cfg.layer_mixer(li) == MIXER_LINEAR:
-                h = _mlp_residual(_lin_window(h, lp, cfg, prompt_lens)[0],
-                                  lp, cfg)
-                continue
-            q, k, v, hn = (_mla_naive_qkv(h, lp, cfg, positions) if cfg.is_mla
-                           else _qkv(h, lp, cfg, positions, li))
-            out = attn_ops.prefill_attention(q, k, v, prompt_lens, scale,
-                                             sliding_window=sw,
-                                             logit_softcap=cfg.attn_logit_softcapping)
-            h = _attn_residual(h, out, lp, cfg) + _ssm_nocache(hn, lp, cfg,
-                                                               prompt_lens)
-            h = _mlp_residual(h, lp, cfg)
+        h = _walk_nocache(params, cfg, h, positions, prompt_lens)
         with jax.named_scope(scopes.HEAD):      # this trunk's head: a pool
             if cfg.final_layernorm:
                 h = _norm(h, params["final_norm"], cfg)
@@ -1441,21 +1716,7 @@ def score_prompt(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         B, T = tokens.shape
         positions = jnp.arange(T)[None, :].repeat(B, axis=0)
         h = _embed(params, cfg, tokens, positions)
-        scale = cfg.attn_scale
-        for li, lp in enumerate(params["layers"]):
-            sw = cfg.layer_window(li)
-            if cfg.layer_mixer(li) == MIXER_LINEAR:
-                h = _mlp_residual(_lin_window(h, lp, cfg, prompt_lens)[0],
-                                  lp, cfg)
-                continue
-            q, k, v, hn = (_mla_naive_qkv(h, lp, cfg, positions) if cfg.is_mla
-                           else _qkv(h, lp, cfg, positions, li))
-            out = attn_ops.prefill_attention(q, k, v, prompt_lens, scale,
-                                             sliding_window=sw,
-                                             logit_softcap=cfg.attn_logit_softcapping)
-            h = _attn_residual(h, out, lp, cfg) + _ssm_nocache(hn, lp, cfg,
-                                                               prompt_lens)
-            h = _mlp_residual(h, lp, cfg)
+        h = _walk_nocache(params, cfg, h, positions, prompt_lens)
         # next-token targets: position i scores tokens[i+1]
         nxt = jnp.concatenate([tokens[:, 1:], jnp.zeros((B, 1), tokens.dtype)],
                               axis=1)
@@ -1494,74 +1755,21 @@ def _chunk_trunk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                  slot_ids: jnp.ndarray, block_tables: jnp.ndarray,
                  kv_cache: list, ad: jnp.ndarray | None = None,
                  ssm: list | None = None, seats: jnp.ndarray | None = None,
-                 *, attn_impl: str = "reference", mesh=None,
+                 *, phase: str, attn_impl: str = "reference", mesh=None,
                  tally: list | None = None, moe_dense: bool = False,
                  aligned: bool = False):
     """Shared layer loop for cache-relative windows: writes the window's KV
     and attends against cached context + causal-within-window.  Used by both
     prefill_chunk (last-row logits; ``aligned``: its rows are whole pages
     in order, ops/attention.py write_kv_entry) and decode_verify (all-row
-    argmax).  Returns (h, kv_cache, seat pool or None)."""
+    argmax); ``phase`` is the scope its caller opened.  Returns (h,
+    kv_cache, seat pool or None)."""
     positions = ctx_lens[:, None] + jnp.arange(tokens.shape[1])[None, :]
     h = _embed(params, cfg, tokens, positions)
-    scale = cfg.attn_scale
-    new_cache = []
-    new_ssm = []
-    for li, lp in enumerate(params["layers"]):
-        sw = cfg.layer_window(li)
-        if cfg.layer_mixer(li) == MIXER_LINEAR:
-            h, entry = _lin_window(h, lp, cfg, chunk_lens, ssm[len(new_ssm)],
-                                   seats, fresh=ctx_lens == 0)
-            new_ssm.append(entry)
-            h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
-            continue
-        if cfg.is_mla:
-            # MLA window: write the latent, attend ABSORBED against the
-            # latent pages (k == v == latent; value = first kv_lora cols)
-            q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
-            entry = attn_ops.write_mla_entry(kv_cache[len(new_cache)], latent, slot_ids,
-                                             latent_split=cfg.mla_kv_lora_rank)
-            new_cache.append(entry)
-            q_eff = _mla_absorb_q(q_nope, q_rope, lp, cfg)
-            out = attn_ops.chunked_prefill_attention(
-                q_eff, entry["k"], entry["k"], block_tables, ctx_lens,
-                chunk_lens, scale, k_scale=entry.get("ks"),
-                v_scale=entry.get("ks"),
-                scale_slices=(cfg.mla_kv_lora_rank,
-                              cfg.mla_qk_rope_head_dim))
-            out = _mla_unabsorb(out, lp, cfg)
-            h = _attn_residual(h, out, lp, cfg, ad)
-            h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
-            continue
-        q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)
-        entry = attn_ops.write_kv_entry(kv_cache[len(new_cache)], k, v, slot_ids, aligned)
-        new_cache.append(entry)
-        ck, cv = entry["k"], entry["v"]
-        ks, vs = entry.get("ks"), entry.get("vs")
-        if attn_impl == "pallas" and mesh is not None:
-            from tpuserve.ops.pallas_tp import paged_window_attention_tp
-            out = paged_window_attention_tp(
-                q, ck, cv, block_tables, ctx_lens, chunk_lens, scale, mesh,
-                k_scale=ks, v_scale=vs, sliding_window=sw,
-                logit_softcap=cfg.attn_logit_softcapping)
-        elif attn_impl == "pallas":
-            from tpuserve.ops.pallas_chunked_prefill import paged_window_attention
-            out = paged_window_attention(
-                q, ck, cv, block_tables, ctx_lens, chunk_lens, scale,
-                k_scale=ks, v_scale=vs, sliding_window=sw,
-                logit_softcap=cfg.attn_logit_softcapping)
-        else:
-            out = attn_ops.chunked_prefill_attention(
-                q, ck, cv, block_tables, ctx_lens, chunk_lens, scale,
-                k_scale=ks, v_scale=vs, sliding_window=sw,
-                logit_softcap=cfg.attn_logit_softcapping)
-        m = None
-        if ssm is not None and cfg.has_ssm:
-            m, entry = _ssm_window(hn, lp["ssm"], cfg, chunk_lens, ssm[len(new_ssm)],
-                                   seats, fresh=ctx_lens == 0)
-            new_ssm.append(entry)
-        h = _attn_residual(h, out, lp, cfg, ad, m)
-        h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+    h, new_cache, new_ssm = _walk_layers(
+        "chunk", _chunk_layer, params, cfg, h, kv_cache, ssm, tally, positions,
+        ctx_lens, chunk_lens, slot_ids, block_tables, ad, seats, phase=phase,
+        attn_impl=attn_impl, mesh=mesh, moe_dense=moe_dense, aligned=aligned)
     return h, new_cache, new_ssm or None
 
 
@@ -1586,7 +1794,8 @@ def decode_verify(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     with jax.named_scope(scopes.VERIFY):
         h, new_cache, _ = _chunk_trunk(params, cfg, tokens, ctx_lens,
                                        chunk_lens, slot_ids, block_tables,
-                                       kv_cache, attn_impl=attn_impl, mesh=mesh)
+                                       kv_cache, phase=scopes.VERIFY,
+                                       attn_impl=attn_impl, mesh=mesh)
         logits = _unembed(params, cfg, h)                       # (B, K, V)
         with jax.named_scope(scopes.SAMPLE):
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_cache
@@ -1615,7 +1824,8 @@ def decode_verify_sampled(params: Params, cfg: ModelConfig,
         from tpuserve.ops.sampling import spec_accept_sampled
         h, new_cache, _ = _chunk_trunk(params, cfg, tokens, ctx_lens,
                                        chunk_lens, slot_ids, block_tables,
-                                       kv_cache, attn_impl=attn_impl, mesh=mesh)
+                                       kv_cache, phase=scopes.VERIFY,
+                                       attn_impl=attn_impl, mesh=mesh)
         logits = _unembed(params, cfg, h)                       # (B, K, V)
         accept, pred = spec_accept_sampled(logits, tokens[:, 1:], chunk_lens,
                                            keys, temperature, top_k, top_p,
@@ -1757,66 +1967,11 @@ def _decode_body(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     pool or None, routing or None).  Used by :func:`decode_step` (one dispatch per token) and
     :func:`decode_multi` (scanned — one dispatch per window)."""
     h = _embed(params, cfg, tokens, positions)                 # (B, H)
-    scale = cfg.attn_scale
-    new_cache = []
-    new_ssm = []
     tally = _moe_tally(cfg)
-    for li, lp in enumerate(params["layers"]):
-        sw = cfg.layer_window(li)
-        if cfg.layer_mixer(li) == MIXER_LINEAR:
-            h, entry = _lin_decode(h, lp, cfg, slot_ids, ssm[len(new_ssm)],
-                                   seats, attn_impl)
-            new_ssm.append(entry)
-            h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
-            continue
-        if cfg.is_mla:
-            # MLA decode: absorbed attention straight against the latent
-            # pages — the step reads mla_latent_dim bytes per cached token
-            # instead of 2 * Hkv * head_dim (the ~10x KV-bandwidth win)
-            q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
-            entry = attn_ops.write_mla_entry(kv_cache[len(new_cache)], latent, slot_ids,
-                                             latent_split=cfg.mla_kv_lora_rank)
-            new_cache.append(entry)
-            q_eff = _mla_absorb_q(q_nope, q_rope, lp, cfg)
-            out = attn_ops.paged_decode_attention(
-                q_eff, entry["k"], entry["k"], block_tables, seq_lens,
-                scale, k_scale=entry.get("ks"), v_scale=entry.get("ks"),
-                scale_slices=(cfg.mla_kv_lora_rank,
-                              cfg.mla_qk_rope_head_dim))
-            out = _mla_unabsorb(out, lp, cfg)
-            h = _attn_residual(h, out, lp, cfg, ad)
-            h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
-            continue
-        q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)  # (B, Hq/Hkv, D)
-        entry = attn_ops.write_kv_entry(kv_cache[len(new_cache)], k, v, slot_ids)
-        new_cache.append(entry)
-        ck, cv = entry["k"], entry["v"]
-        ks, vs = entry.get("ks"), entry.get("vs")
-        if attn_impl == "pallas" and mesh is not None:
-            from tpuserve.ops.pallas_tp import paged_decode_attention_tp
-            out = paged_decode_attention_tp(q, ck, cv, block_tables, seq_lens,
-                                            scale, mesh, k_scale=ks,
-                                            v_scale=vs, sliding_window=sw,
-                                            logit_softcap=cfg.attn_logit_softcapping)
-        elif attn_impl == "pallas":
-            from tpuserve.ops.pallas_paged_attention import paged_decode_attention as impl
-            out = impl(q, ck, cv, block_tables, seq_lens, scale,
-                       k_scale=ks, v_scale=vs, sliding_window=sw,
-                       logit_softcap=cfg.attn_logit_softcapping)
-        else:
-            out = attn_ops.paged_decode_attention(q, ck, cv, block_tables,
-                                                  seq_lens, scale,
-                                                  k_scale=ks, v_scale=vs,
-                                                  sliding_window=sw,
-                                                  logit_softcap=cfg.attn_logit_softcapping)
-        m = None
-        if ssm is not None and cfg.has_ssm:
-            m, entry = _ssm_decode(hn, lp["ssm"], cfg,
-                                   slot_ids != attn_ops.PAD_SLOT, ssm[len(new_ssm)],
-                                   seats, attn_impl)
-            new_ssm.append(entry)
-        h = _attn_residual(h, out, lp, cfg, ad, m)
-        h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+    h, new_cache, new_ssm = _walk_layers(
+        "decode", _decode_layer, params, cfg, h, kv_cache, ssm, tally,
+        positions, slot_ids, block_tables, seq_lens, ad, seats,
+        attn_impl=attn_impl, mesh=mesh, moe_dense=moe_dense)
     return (_unembed(params, cfg, h), new_cache, new_ssm or None,
             _moe_routing(tally, None))
 
@@ -2096,76 +2251,19 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             raise ValueError("a model with recurrent state takes the ragged "
                              "trunk for packed prefills only (decode_rows=False)")
         h = _embed(params, cfg, tokens, positions)                 # (T, H)
-        scale = cfg.attn_scale
         row_lens = positions + 1
-        new_cache = []
-        new_ssm = []
         tally = _moe_tally(cfg)
         # a packed prefill starts every prompt on a ragged-block boundary
         # at a whole number of cached blocks (Engine._pack_ragged): where
         # that block is whole pages, its K and V go out a page at a time
         aligned = not decode_rows and attn_ops.kv_stream_by_page(
             kv_cache[0], ragged_blk, attn_impl)
-        for li, lp in enumerate(params["layers"]):
-            sw = cfg.layer_window(li)
-            if cfg.layer_mixer(li) == MIXER_LINEAR:
-                h, entry = _lin_packed(h, lp, cfg, positions, slot_ids,
-                                       blk_seq, q_starts, q_lens, ragged_blk,
-                                       ssm[len(new_ssm)], seats)
-                new_ssm.append(entry)
-                h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
-                continue
-            if cfg.is_mla:
-                # MLA: absorbed attention against the latent pages, like the
-                # chunk/decode trunks (reference path only — the Pallas
-                # kernels assume materialised per-head pages, same gate as
-                # the rest of the engine)
-                q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
-                entry = attn_ops.write_mla_entry(
-                    kv_cache[len(new_cache)], latent, slot_ids,
-                    latent_split=cfg.mla_kv_lora_rank)
-                new_cache.append(entry)
-                q_eff = _mla_absorb_q(q_nope, q_rope, lp, cfg)
-                out = _ragged_reference_attn(
-                    q_eff, entry["k"], entry["k"], block_tables, row_seq,
-                    row_lens, blk_seq, meta, ragged_blk, scale,
-                    entry.get("ks"), entry.get("ks"), None, None,
-                    scale_slices=(cfg.mla_kv_lora_rank,
-                                  cfg.mla_qk_rope_head_dim),
-                    decode_rows=decode_rows)
-                out = _mla_unabsorb(out, lp, cfg)
-                h = _attn_residual(h, out, lp, cfg, ad)
-                h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
-                continue
-            q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)  # (T, H*, D)
-            entry = attn_ops.write_kv_entry(kv_cache[len(new_cache)], k, v, slot_ids,
-                                            aligned)
-            new_cache.append(entry)
-            ck, cv = entry["k"], entry["v"]
-            ks, vs = entry.get("ks"), entry.get("vs")
-            if attn_impl == "pallas":
-                from tpuserve.ops.pallas_ragged_attention import \
-                    ragged_paged_attention
-                out = ragged_paged_attention(
-                    q, ck, cv, block_tables, kv_lens, q_starts, q_lens,
-                    meta, blk_seq, scale, blk_q=ragged_blk, k_scale=ks,
-                    v_scale=vs, sliding_window=sw,
-                    logit_softcap=cfg.attn_logit_softcapping,
-                    decode_rows=decode_rows)
-            else:
-                out = _ragged_reference_attn(
-                    q, ck, cv, block_tables, row_seq, row_lens, blk_seq,
-                    meta, ragged_blk, scale, ks, vs, sw,
-                    cfg.attn_logit_softcapping, decode_rows=decode_rows)
-            m = None
-            if ssm is not None and cfg.has_ssm:
-                m, entry = _ssm_packed(hn, lp["ssm"], cfg, positions,
-                                       slot_ids != attn_ops.PAD_SLOT, blk_seq,
-                                       q_starts, q_lens, ragged_blk, ssm[len(new_ssm)],
-                                       seats)
-                new_ssm.append(entry)
-            h = _attn_residual(h, out, lp, cfg, ad, m)
-            h = _mlp_residual(h, lp, cfg, ad, tally, moe_dense)
+        h, new_cache, new_ssm = _walk_layers(
+            "ragged", _ragged_layer, params, cfg, h, kv_cache, ssm, tally,
+            positions, slot_ids, row_seq, row_lens, block_tables, kv_lens,
+            q_starts, q_lens, meta, blk_seq, ad, seats, ragged_blk=ragged_blk,
+            attn_impl=attn_impl, decode_rows=decode_rows, moe_dense=moe_dense,
+            aligned=aligned)
         return _with_ssm(_unembed(params, cfg, h, last_rows), new_cache, ssm,
                          new_ssm, _moe_routing(tally, last_rows))
 
@@ -2191,25 +2289,11 @@ def draft_propose(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         B, T = tokens.shape
 
         positions = jnp.arange(T)[None, :].repeat(B, axis=0)
-        scale = cfg.attn_scale
 
         def one(carry, j):
             toks, cur = carry
             h = _embed(params, cfg, toks, positions)
-            for li, lp in enumerate(params["layers"]):
-                if cfg.layer_mixer(li) == MIXER_LINEAR:
-                    h = _mlp_residual(_lin_window(h, lp, cfg, cur)[0], lp,
-                                      cfg)
-                    continue
-                q, kk, v, hn = (_mla_naive_qkv(h, lp, cfg, positions)
-                                if cfg.is_mla
-                                else _qkv(h, lp, cfg, positions, li))
-                out = attn_ops.prefill_attention(
-                    q, kk, v, cur, scale, sliding_window=cfg.layer_window(li),
-                    logit_softcap=cfg.attn_logit_softcapping)
-                h = _attn_residual(h, out, lp, cfg) \
-                    + _ssm_nocache(hn, lp, cfg, cur)
-                h = _mlp_residual(h, lp, cfg)
+            h = _walk_nocache(params, cfg, h, positions, cur)
             # unembed ONLY each row's last position — the full (B, T, V)
             # logits would be GBs at serving batch sizes
             logits = _unembed(params, cfg, h, cur - 1)
@@ -2240,18 +2324,5 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         seq_lens = jnp.full((B,), T, jnp.int32)
     positions = jnp.arange(T)[None, :].repeat(B, axis=0)
     h = _embed(params, cfg, tokens, positions)
-    scale = cfg.attn_scale
-    for li, lp in enumerate(params["layers"]):
-        if cfg.layer_mixer(li) == MIXER_LINEAR:
-            h = _mlp_residual(_lin_window(h, lp, cfg, seq_lens)[0], lp, cfg,
-                              moe_dense=True)
-            continue
-        q, k, v, hn = (_mla_naive_qkv(h, lp, cfg, positions) if cfg.is_mla
-                       else _qkv(h, lp, cfg, positions, li))
-        out = attn_ops.prefill_attention(q, k, v, seq_lens, scale,
-                                         sliding_window=cfg.layer_window(li),
-                                         logit_softcap=cfg.attn_logit_softcapping)
-        h = _attn_residual(h, out, lp, cfg) + _ssm_nocache(hn, lp, cfg,
-                                                           seq_lens)
-        h = _mlp_residual(h, lp, cfg, moe_dense=True)
+    h = _walk_nocache(params, cfg, h, positions, seq_lens, moe_dense=True)
     return _unembed(params, cfg, h)

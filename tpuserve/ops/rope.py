@@ -96,7 +96,11 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
     x2 = x[..., rotary_half:2 * rotary_half].astype(jnp.float32)
     rot1 = x1 * cos - x2 * sin
     rot2 = x2 * cos + x1 * sin
-    out = jnp.concatenate([rot1, rot2], axis=-1).astype(dtype)
+    # each half rounded where it is made, not after the join: the chip's
+    # compiler moves the rounding there anyway, and an operation it makes
+    # itself inside a called function (a trunk's layer body,
+    # models/transformer.py) is named after the call alone, under no part
+    out = jnp.concatenate([rot1.astype(dtype), rot2.astype(dtype)], axis=-1)
     if 2 * rotary_half < x.shape[-1]:
         out = jnp.concatenate([out, x[..., 2 * rotary_half:]], axis=-1)
     return out

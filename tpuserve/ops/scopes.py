@@ -14,6 +14,19 @@ computes.  Every operation's path is ``<phase>/<part>``:
   traced operations costs a fifth of a warm set-up (PERF.md §6, PR 32).
   Where parts nest (``mlp/moe.route``) the innermost one counts.
 
+A path may hold ``jit(...)`` components, which name neither: a trunk's
+per-layer body runs under its own ``jax.jit`` (``jit(_decode_layer)``,
+``_ragged_``, ``_chunk_``, ``_prefill_``, ``_nocache_``: models/
+transformer.py), as every Pallas kernel's wrapper does.  A cache body opens
+its trunk's PHASE once more around itself, so a path may name its phase
+twice (the first counts): the chip's compiler rebuilds the K/V row scatter
+under the name it has inside the called function, without the call's
+prefix.  And an operation that compiler makes itself inside a called
+function is named after the call alone (``.../jit(_decode_layer)``: its
+phase, no part; the reader counts it under ``trunk.unscoped_device_share``),
+where in a flat module it carries no name: tests/test_chip_compile.py holds
+how few there are.
+
 Read by ``benchmark/layer_metrics/_scope_trace.py``, which holds its own
 copy of these names as a benchmark holds a kernel's (it also has to read
 a program from before they existed);

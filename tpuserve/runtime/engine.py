@@ -29,7 +29,8 @@ import numpy as np
 from tpuserve.models import transformer
 from tpuserve.models.config import ModelConfig, get_model_config
 from tpuserve.models.tokenizer import IncrementalDetokenizer, load_tokenizer
-from tpuserve.models.transformer import moe_plain_moves
+from tpuserve.models.transformer import (LAYER_CALLS, LAYER_TRACES,
+                                         moe_plain_moves)
 from tpuserve.models.weights import load_or_init, param_dtype
 from tpuserve.ops import sampling as sampling_ops
 from tpuserve.ops.attention import PAD_SLOT, kv_stream_by_page
@@ -4863,9 +4864,15 @@ class Engine:
         jax.block_until_ready((self.kv_cache, self._warm_tails))
         self._warm_tails.clear()
         self._read_demote_budget()
+        # (a trunk traces its layer once a KIND under its own jax.jit,
+        # models/transformer.py: this process's counts, by body)
         logger.info("warmup complete: prefill buckets %s, ragged buckets %s, "
-                    "decode buckets %s", prefill_buckets,
-                    [(kind, t) for t, _, kind in ragged_warm], decode_buckets)
+                    "decode buckets %s; layer bodies traced %d for %d layer "
+                    "calls %s", prefill_buckets,
+                    [(kind, t) for t, _, kind in ragged_warm], decode_buckets,
+                    sum(LAYER_TRACES.values()), sum(LAYER_CALLS.values()),
+                    {body: (LAYER_TRACES[body], n)
+                     for body, n in LAYER_CALLS.items() if n})
 
     def _warm_sampling(self, logits: jnp.ndarray,
                        modes: Sequence[str]) -> None:

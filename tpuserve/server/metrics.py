@@ -518,6 +518,21 @@ class ServerMetrics:
             "design (an underestimate of raw device compute: overlapped "
             "work never blocks)",
             ["model_name", "kind"], registry=self.registry)
+        self.trunk_layer_traces = Counter(
+            "tpuserve_trunk_layer_traces",
+            "Layer bodies of the model's trunks that JAX traced in this "
+            "process, by body= prefill|chunk|decode|ragged|nocache: a "
+            "trunk hands its per-layer body to a function under its own "
+            "jax.jit (models/transformer.py), so a program traces and "
+            "lowers it once a KIND of layer, not once a layer",
+            ["model_name", "body"], registry=self.registry)
+        self.trunk_layer_calls = Counter(
+            "tpuserve_trunk_layer_calls",
+            "Calls of those bodies made while programs were traced, one "
+            "a layer a program; traces over calls is the share of a "
+            "start's layer tracing that was not saved (Qwen3-0.6B, 28 "
+            "layers of one kind: at most 1 in 28)",
+            ["model_name", "body"], registry=self.registry)
         self.exec_compiles = counter(
             "tpuserve_executable_compiles",
             "First-dispatch XLA compiles observed by the executable "

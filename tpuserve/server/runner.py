@@ -16,6 +16,7 @@ import time
 from collections import deque
 from typing import Optional, Sequence, Union
 
+from tpuserve.models import transformer
 from tpuserve.runtime.clock import MONOTONIC
 from tpuserve.runtime.engine import Engine
 from tpuserve.runtime.hostprof import PROF
@@ -129,6 +130,7 @@ class AsyncEngineRunner:
         self._last_token_time: dict[str, float] = {}
         # routed rows at the last pass that wrote the per-expert counters
         self._moe_rows_exported = 0.0
+        self._layer_calls_exported = 0
         self._stop = threading.Event()
         self._wake = threading.Event()
         self._thread = threading.Thread(target=self._loop, daemon=True,
@@ -1126,6 +1128,16 @@ class AsyncEngineRunner:
             sum(t.host_count for t in stores))
         self.metrics.kv_tier_blocks.labels(tier="spill", **label).set(
             sum(t.spill_count for t in stores))
+        # the trunks' layer bodies traced and called (a process's own
+        # counts, models/transformer.py; they move while programs compile)
+        calls = sum(transformer.LAYER_CALLS.values())
+        if calls != self._layer_calls_exported:
+            self._layer_calls_exported = calls
+            for body, n in transformer.LAYER_CALLS.items():
+                _advance_counter(self.metrics.trunk_layer_traces.labels(
+                    body=body, **label), transformer.LAYER_TRACES[body])
+                _advance_counter(self.metrics.trunk_layer_calls.labels(
+                    body=body, **label), n)
         # device telemetry (runtime/devprof.py): HBM watermark gauges,
         # per-sync-kind device seconds, ladder compile totals, capture
         # count.  Engines keep cumulative totals; counters advance by
