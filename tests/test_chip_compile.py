@@ -296,6 +296,47 @@ def test_the_gdn_state_update_kernel_compiles_for_v5e(rows, one_chip):
     assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
 
 
+# the convolution memory's decode step at both families' published sizes:
+# (channels, the pool's dtype, a bias or none)
+CONV_TAILS = {"olmo-hybrid-7b": (11520, jnp.float32, False),
+              "falcon-h1-34b": (5120, jnp.bfloat16, True)}
+
+
+@pytest.mark.parametrize("rows", [4, MAX_NUM_SEQS])
+@pytest.mark.parametrize("family", sorted(CONV_TAILS))
+def test_the_conv_tail_kernel_compiles_for_v5e(family, rows, one_chip):
+    """``_conv_tail_step`` at the smallest and the largest decode bucket:
+    compiled, named, in place -- the pool's bytes are aliased from input
+    to output, as the chip tiles them (90 sublanes of float32 stored as
+    96; 40 of bfloat16, two a word, as 40) -- and the pool operand is left
+    in HBM (no ``S(1)`` in its layout: the compiler stages a 9 MB operand
+    of a custom call through its faster memory otherwise)."""
+    from tpuserve.ops.pallas_conv_tail import (KERNEL_NAME, conv_tail_step,
+                                               tail_slab)
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert KERNEL_NAME == "_conv_tail_step"
+    C, dtype, biased = CONV_TAILS[family]
+    W = 4
+    pool = S((MAX_NUM_SEQS + 1, W - 1, *tail_slab(C)), dtype)
+    args = [pool, S((rows,), jnp.int32), S((rows, C), dtype),
+            S((W, C), jnp.bfloat16)] + ([S((C,), jnp.bfloat16)] * biased)
+    compiled = jax.jit(
+        lambda pool, seats, x, k, b=None: conv_tail_step(
+            pool, seats, x, k, b, interpret=False),
+        donate_argnums=(0,)).lower(*args).compile()
+    call = re.search(rf"%{KERNEL_NAME}(\.\d+)? = ([^\n]*)custom-call\([^\n]*"
+                     r"tpu_custom_call", compiled.as_text())
+    assert call
+    pool_out = re.findall(r"[a-z0-9]+\[65,3,\d+,128\]\{[^}]*\}", call.group(2))
+    assert pool_out and "S(1)" not in pool_out[0], call.group(2)
+    sublanes = {jnp.float32: 96, jnp.bfloat16: 40}[dtype]
+    assert compiled.memory_analysis().alias_size_in_bytes == (
+        65 * 3 * sublanes * 128 * jnp.dtype(dtype).itemsize)
+
+
 # the flat-token rungs of a packed prefill at 128-row ragged blocks
 # (scheduler.packed_prefill_bucket: every rung to the budget of 8,192)
 LIN_TOKENS = [128, 256, 512, 768, 1024, 1280, 1536, 1792, 2048, 3072, 4096,
@@ -341,27 +382,26 @@ def test_a_linear_layer_compiles_for_v5e_at_every_rung(tokens, one_chip):
         S((tokens,), i32), S((tokens // 128,), i32), seqs, seqs, entry,
         seqs).compile()
     mem = compiled.memory_analysis()
-    # (the chip lays the convolution's three rows a seat out in tiles: a
-    # tenth more than their 69,120 B, half a per cent of the pool)
-    pool_bytes = 65 * (2_211_840 + 3 * 11520 * 4)
-    assert pool_bytes <= mem.alias_size_in_bytes < 1.01 * pool_bytes
+    # (the convolution's three rows a seat, 90 sublanes of whole lane
+    # tiles each, are stored as 96)
+    pool_bytes = 65 * (2_211_840 + 3 * 96 * 128 * 4)
+    assert mem.alias_size_in_bytes == pool_bytes
     # what the layer holds beside its weights and the pool: activations a
     # few times the stream's q, k, v in float32, never a copy of the pool
     assert mem.temp_size_in_bytes < 40 * tokens * 11520 * 4 + (64 << 20)
 
 
-@pytest.mark.parametrize("program,tokens", [("decode_multi", 0),
-                                            ("forward_ragged", 8192),
-                                            ("prefill_chunk", 0)])
-def test_the_olmo_hybrid_cell_fits_the_chip(program, tokens, one_chip,
-                                            monkeypatch):
-    """The cell's whole trunks at the published widths: 16 layers (12
-    linear, 4 full), a fused decode window of 64 rows, the top rung of the
-    packed-prefill ladder and a chunk, beside a pool of 2,560 pages of 32
-    tokens for the 4 attention layers (what 0.9 of the chip leaves after
-    8.2 GB of weights and 1.83 GB of state).  The chip's compiler refuses
-    what does not fit 16 GB; 30 query heads on 30 KV heads reach the
-    kernels as 32 on 32 and keep the 128-row ragged block."""
+# a cell's three served trunks: (program, flat tokens; 0 = one ragged block)
+CELL_PROGRAMS = [("decode_multi", 0), ("forward_ragged", 8192),
+                 ("prefill_chunk", 0)]
+
+
+def _compile_cell_program(cfg, program, tokens, num_blocks, one_chip,
+                          monkeypatch):
+    """One served trunk of a cell at its published widths, compiled for
+    the described chip: a fused window of 64 rows and 8 steps, the top
+    rung of the packed prefill or a chunk, beside ``num_blocks`` pages of
+    32 tokens and the seat pool."""
     from test_scopes import trunk_programs
     from tpuserve.ops.pallas_ragged_attention import ragged_block_for
 
@@ -372,23 +412,41 @@ def test_the_olmo_hybrid_cell_fits_the_chip(program, tokens, one_chip,
         return jax.tree.map(lambda x: S(x.shape, x.dtype), tree)
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = _olmo_hybrid(num_layers=16)
-    assert (cfg.cache_q_heads, cfg.cache_kv_heads) == (32, 32)
     blk = ragged_block_for(cfg.cache_q_heads, cfg.cache_kv_heads,
                            cfg.head_dim, PAGE, 2, 2)
     assert blk == 128
     fn, args, kwargs = trunk_programs(
         cfg, S, place, rows=MAX_NUM_SEQS, steps=8, tokens=tokens or blk,
         blk=blk, prompts=PREFILL_SEQS, chunk=CHUNK, block_size=PAGE,
-        num_blocks=2560, max_blocks=MAX_PAGES, attn_impl="pallas")[program]
-    compiled = fn.lower(*args, **kwargs).compile()
+        num_blocks=num_blocks, max_blocks=MAX_PAGES,
+        attn_impl="pallas")[program]
+    return fn.lower(*args, **kwargs).compile()
+
+
+@pytest.mark.parametrize("program,tokens", CELL_PROGRAMS)
+def test_the_olmo_hybrid_cell_fits_the_chip(program, tokens, one_chip,
+                                            monkeypatch):
+    """The cell's whole trunks at the published widths: 16 layers (12
+    linear, 4 full), a fused decode window of 64 rows, the top rung of the
+    packed-prefill ladder and a chunk, beside a pool of 2,560 pages of 32
+    tokens for the 4 attention layers (what 0.9 of the chip leaves after
+    8.2 GB of weights and 1.83 GB of state).  The chip's compiler refuses
+    what does not fit 16 GB; 30 query heads on 30 KV heads reach the
+    kernels as 32 on 32 and keep the 128-row ragged block."""
+    cfg = _olmo_hybrid(num_layers=16)
+    assert (cfg.cache_q_heads, cfg.cache_kv_heads) == (32, 32)
+    compiled = _compile_cell_program(cfg, program, tokens, 2560, one_chip,
+                                     monkeypatch)
     mem = compiled.memory_analysis()
     weights = mem.argument_size_in_bytes - mem.alias_size_in_bytes
     assert 8.1e9 < weights < 8.3e9, weights
-    # pages and pool stay in place: 4 layers' pages and 12 layers' seats
+    # pages and pool stay in place, whole, in every program: 4 layers'
+    # pages and 12 layers' seats (trunk_programs gives each program the
+    # same pool: the window's 64 rows, one seat more and the trash seat;
+    # a seat's three convolution rows, 90 sublanes each, stored as 96)
     pages = 4 * 2 * 2560 * PAGE * 32 * 128 * 2
-    pool = 12 * 65 * (2_211_840 + 3 * 11520 * 4)
-    assert pages + pool <= mem.alias_size_in_bytes < 1.01 * (pages + pool)
+    seat = 2_211_840 + 3 * 96 * 128 * 4
+    assert mem.alias_size_in_bytes == pages + 12 * 66 * seat
     # beside them what a dispatch holds of its own stays under the tenth
     # of the chip the cache's sizer leaves free
     assert mem.temp_size_in_bytes < 1.4e9, mem.temp_size_in_bytes
@@ -397,8 +455,71 @@ def test_the_olmo_hybrid_cell_fits_the_chip(program, tokens, one_chip,
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.6e9
     text = compiled.as_text()
     if program == "decode_multi":
-        assert "_gdn_state_update" in text
         assert "_paged_decode_attention" in text
+        # the convolution's memory moves once a linear layer a step, by
+        # its kernel, as the state beside it does by its own ...
+        calls = {k: re.findall(rf"%{k}(?:\.\d+)? = ([^\n]*?)custom-call\(",
+                               text)
+                 for k in ("_conv_tail_step", "_gdn_state_update")}
+        assert len(calls["_conv_tail_step"]) == 12
+        assert len(calls["_gdn_state_update"]) == 12
+        # ... on a pool the compiler leaves in HBM: no operand or result of
+        # the call in its faster memory space, and no asynchronous copy of
+        # anything of the pool's shape (it staged each layer's 9 MB there
+        # and back around the gather and scatter this kernel replaced,
+        # every step: PERF.md §6, PR 46)
+        of_pool = r"f32\[6[456],3,(?:90,128|11520)\]"
+        for out in calls["_conv_tail_step"]:
+            assert not re.search(of_pool + r"\{[^}]*S\(1\)", out), out
+        staged = [line for line in text.split("\n")
+                  if re.search(r" (copy|slice)-start\(", line)
+                  and re.search(of_pool, line)]
+        assert not staged, staged[:2]
+
+
+# what the three trunks of ``falcon-h1-34b-l6.reason`` held at the parent of
+# PR 46 (the convolution's memory as ``(66, 3, 5120)``, stepped by XLA's
+# gather, taps and scatter), compiled as below: (argument, temporary) bytes
+FALCON_H1_BEFORE = {"decode_multi": (15_244_633_600, 55_074_304),
+                    "forward_ragged": (15_244_704_256, 1_630_251_008),
+                    "prefill_chunk": (15_244_616_704, 318_360_064)}
+
+
+@pytest.mark.parametrize("program,tokens", CELL_PROGRAMS)
+def test_the_falcon_h1_cell_holds_no_more_than_before(program, tokens,
+                                                      one_chip, monkeypatch):
+    """The served programs of the Falcon-H1 cell at the published widths
+    (6 layers, a fused window of 64 rows, the top rung of the packed
+    prefill and a chunk, beside the 7,785 pages the sizer gives the cell:
+    PERF.md §4) hold no more of the chip with the convolution's memory as
+    ``(seats, 3, 40, 128)`` than with ``(seats, 3, 5120)``: arguments and
+    temporaries at or under the parent's, pages and pool whole in place.
+    (The cell's ``memory_peak_bytes`` reads 381 MB higher since PR 46:
+    not in these programs, PERF.md §7 row 27.)"""
+    import dataclasses
+
+    from tpuserve.models.config import get_model_config
+    cfg = dataclasses.replace(
+        get_model_config("tiiuae/Falcon-H1-34B-Instruct"), num_layers=6)
+    compiled = _compile_cell_program(cfg, program, tokens, 7785, one_chip,
+                                     monkeypatch)
+    mem = compiled.memory_analysis()
+    argument, temp = FALCON_H1_BEFORE[program]
+    assert mem.argument_size_in_bytes <= argument
+    assert mem.temp_size_in_bytes <= temp
+    # 6 layers' pages (4 KV heads) and 6 layers' seats: 32 heads of 128 x
+    # 256 float32 and three rows of 40 bfloat16 sublanes, stored as 40
+    pages = 6 * 2 * 7785 * PAGE * 4 * 128 * 2
+    seat = 32 * 128 * 256 * 4 + 3 * 40 * 128 * 2
+    assert mem.alias_size_in_bytes == pages + 6 * 66 * seat
+    text = compiled.as_text()
+    staged = [line for line in text.split("\n")
+              if re.search(r" (copy|slice)-start\(", line)
+              and re.search(r"bf16\[6[456],3,(?:40,128|5120)\]", line)]
+    assert not staged, staged[:2]
+    if program == "decode_multi":
+        for kernel in ("_conv_tail_step", "_ssm_state_update"):
+            assert len(re.findall(rf"%{kernel}(?:\.\d+)? = ", text)) == 6
 
 
 @pytest.mark.parametrize("rows", [128, 2048, 8192])
@@ -763,7 +884,7 @@ LAYER_CALL = re.compile(r"/jit\(_(prefill|chunk|decode|ragged|nocache)"
       "_moe_grouped_matmul": "moe.experts"}, 0),
     ("allenai/Olmo-Hybrid-7B+last2",
      {"_paged_decode_attention": "attn.kernel",
-      "_gdn_state_update": "ssm.scan"}, 0),
+      "_gdn_state_update": "ssm.scan", "_conv_tail_step": "ssm.conv"}, 0),
 ])
 def test_every_operation_of_a_decode_window_names_its_part(
         model, kernels, by_call, one_chip, monkeypatch):
@@ -838,13 +959,14 @@ def test_every_operation_of_a_decode_window_names_its_part(
                 continue
             nxt = next((s for s in scoped[i + 1:] if s), None)
             if opcode == "slice-done" and not consumers(i):
-                # a POOL the window carries (the convolution's memory of a
-                # model with linear layers, 9 MB a layer) that the
-                # compiler keeps in its faster memory space from step to
-                # step, copied there in slices at the end of the loop's
-                # body: its consumer is the NEXT step (the carry), so the
-                # reader files the wait with whatever part follows it
-                # (PERF.md §7 row 24): counted, and held to those few
+                # a POOL the window carries that the compiler keeps in its
+                # faster memory space from step to step, copied there in
+                # slices at the end of the loop's body: its consumer is
+                # the NEXT step (the carry), so the reader would file the
+                # wait with whatever part follows it.  The convolution's
+                # memory of a model with linear layers was one (9 MB a
+                # layer, PERF.md §7 row 24) until its kernel declared the
+                # pool in HBM (ops/pallas_conv_tail.py): none is left
                 ahead += 1
             elif opcode == "slice-done":
                 waits += 1
@@ -859,7 +981,7 @@ def test_every_operation_of_a_decode_window_names_its_part(
     assert any("/jit(_decode_layer)/" in op_name for rows in comps.values()
                for _, _, op_name, _ in rows)
     assert waits >= 8       # the layers' weight matrices are prefetched
-    assert ahead <= (4 if "_gdn_state_update" in kernels else 0), ahead
+    assert ahead == 0, ahead
 
 
 # ---- a layer under its own jax.jit is inlined into the program -----------
@@ -875,7 +997,8 @@ LAYER_KERNELS = {
         {"_paged_decode_attention": 2},
         {"_ragged_paged_attention": 2, "_paged_kv_write": 2}),
     "tiiuae/Falcon-H1-34B-Instruct": (
-        {"_paged_decode_attention": 2, "_ssm_state_update": 2},
+        {"_paged_decode_attention": 2, "_ssm_state_update": 2,
+         "_conv_tail_step": 2},
         {"_ragged_paged_attention": 2, "_paged_kv_write": 2}),
     "JetBrains/Mellum2-12B-A2.5B-Instruct": (
         {"_paged_decode_attention": 2, "_moe_grouped_matmul": 6},
@@ -886,7 +1009,8 @@ LAYER_KERNELS = {
         {"_ragged_paged_attention": 2, "_paged_kv_write": 2,
          "_moe_grouped_matmul": 3}),
     "allenai/Olmo-Hybrid-7B+last2": (
-        {"_paged_decode_attention": 1, "_gdn_state_update": 1},
+        {"_paged_decode_attention": 1, "_gdn_state_update": 1,
+         "_conv_tail_step": 1},
         {"_ragged_paged_attention": 1, "_paged_kv_write": 1}),
 }
 

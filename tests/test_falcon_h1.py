@@ -320,6 +320,74 @@ def test_the_state_update_kernel_is_the_formula(shape):
                                   np.asarray(state)[untouched])
 
 
+def check_conv_tail_step(dtype, width, channels, biased):
+    """``_conv_tail_step`` in interpret mode, bit for bit: against its
+    reference (jitted, as the trunks run it: the CPU contracts a product
+    and a sum to one rounding inside a program and not between two) and
+    against the lines both mixers' decode steps held before it --
+    ``causal_conv`` over the gathered memory and the new row, then
+    ``rows[:, 1:]`` scattered back -- on the pool as ``(seats, W - 1,
+    C)``.  Seats shuffled, more seats than rows; the last two rows are
+    padding rows on the trash seat, which leave every real seat alone;
+    seats outside the batch keep their memory."""
+    from tpuserve.ops import pallas_conv_tail as tap
+    B, S = 6, 11
+    rs = np.random.RandomState(width * channels + biased)
+
+    def draw(*shape):
+        return jnp.asarray(rs.randn(*shape), jnp.float32).astype(dtype)
+
+    pool = draw(S + 1, width - 1, *tap.tail_slab(channels))
+    x, kernel = draw(B, channels), draw(width, channels)
+    bias = draw(channels) if biased else None
+    seats = np.append(rs.permutation(S)[:B - 2], [S, S]).astype(np.int32)
+    real = seats != S
+
+    @jax.jit
+    def before(flat, seats, x):
+        out, rows = ssm_ops.causal_conv(x[:, None], flat[seats], kernel, bias)
+        return out[:, 0], flat.at[seats].set(rows[:, 1:].astype(flat.dtype))
+
+    want_o, want_p = jax.jit(tap.conv_tail_step_reference)(
+        pool, seats, x, kernel, bias)
+    was_o, was_p = before(pool.reshape(S + 1, width - 1, channels), seats, x)
+    got_o, got_p = tap.conv_tail_step(pool + 0, jnp.asarray(seats), x, kernel,
+                                      bias, interpret=True)
+    assert got_o.dtype == jnp.float32 and got_p.dtype == pool.dtype
+    assert got_p.shape == pool.shape
+
+    def bits(a):
+        return np.asarray(a.astype(jnp.float32))
+
+    np.testing.assert_array_equal(bits(got_o)[real], bits(want_o)[real])
+    np.testing.assert_array_equal(bits(got_o)[real], bits(was_o)[real])
+    np.testing.assert_array_equal(bits(got_p)[:S], bits(want_p)[:S])
+    np.testing.assert_array_equal(bits(got_p)[:S].reshape(S, width - 1, -1),
+                                  bits(was_p)[:S])
+    # a real row's seat: the memory shifted by one, the new row last
+    flat = bits(got_p).reshape(S + 1, width - 1, channels)
+    for b in np.flatnonzero(real):
+        np.testing.assert_array_equal(
+            flat[seats[b], :-1],
+            bits(pool).reshape(S + 1, width - 1, channels)[seats[b], 1:])
+        np.testing.assert_array_equal(flat[seats[b], -1], bits(x)[b])
+    untouched = np.setdiff1d(np.arange(S), seats)
+    assert untouched.size
+    np.testing.assert_array_equal(bits(got_p)[untouched],
+                                  bits(pool)[untouched])
+
+
+@pytest.mark.parametrize("biased", [True, False])
+@pytest.mark.parametrize("width", [4, 3])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_conv_tail_kernel_is_the_lines_it_replaces(dtype, width, biased):
+    """The model's dtype in the pool, as Falcon-H1 keeps it (bfloat16 at
+    the published sizes, float32 in ``tiny-falcon-h1``), with the bias its
+    convolution has and without; a row of 256 channels is two lane tiles
+    down the sublanes."""
+    check_conv_tail_step(jnp.dtype(dtype), width, 256, biased)
+
+
 # --------------------------------------------------------------------------
 # no equation dropped: every multiplier and every term moves the logits
 # --------------------------------------------------------------------------
@@ -434,14 +502,32 @@ def test_served_greedy_tokens_are_the_references(multi_step, attn_impl):
     assert engine.block_manager.num_seqs() == 0
 
 
-def test_a_seat_given_to_a_new_sequence_starts_from_zero():
+@pytest.mark.parametrize("multi_step", [1, 4])
+def test_the_decode_kernels_serve_what_the_formulas_serve(multi_step):
+    """A packed prefill, then eight decode steps, one at a time or in fused
+    windows: the state update's and the convolution memory's kernels
+    (``attn_impl="pallas"``, interpret mode here) against the formulas in
+    ``jax.numpy``, token for token."""
+    prompts = prompts_of(7, 12, 19, seed=3)
+    got = {impl: serve(engine_for(multi_step=multi_step, attn_impl=impl),
+                       prompts, max_tokens=9)
+           for impl in ("pallas", "reference")}
+    assert got["pallas"] == got["reference"]
+    assert all(len(toks) == 9 for toks in got["pallas"])
+
+
+@pytest.mark.parametrize("attn_impl", ["reference", "pallas"])
+def test_a_seat_given_to_a_new_sequence_starts_from_zero(attn_impl):
     """One seat: the second sequence runs on the slot the first one left
-    its state in, and serves what an untouched engine serves."""
+    its state and its convolution's memory in, and serves what an
+    untouched engine serves."""
     prompts = prompts_of(9, 14, seed=2)
-    engine = engine_for(scheduler={"max_num_seqs": 1}, multi_step=4)
+    engine = engine_for(scheduler={"max_num_seqs": 1}, multi_step=4,
+                        attn_impl=attn_impl)
     first, second = (serve(engine, [p])[0] for p in prompts)
     pool = np.asarray(engine.ssm_state[0]["state"])
     assert np.abs(pool[0]).max() > 0            # the seat was used
+    assert np.abs(np.asarray(engine.ssm_state[0]["conv"])[0]).max() > 0
     assert second == serve(engine_for(multi_step=4), [prompts[1]])[0]
     assert second == ref_greedy(engine.params, engine.model_cfg,
                                 prompts[1], 10)

@@ -46,7 +46,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:            # ``benchmark`` is a package of the root
     sys.path.insert(0, ROOT)
 from benchmark.harness import plan  # noqa: E402
-from test_falcon_h1 import BLOCK, SEATS, Served, prompts_of  # noqa: E402
+from test_falcon_h1 import (BLOCK, SEATS, Served,  # noqa: E402
+                            check_conv_tail_step, prompts_of)
 
 ATOL = 5e-4
 MODEL = "tiny-olmo-hybrid"
@@ -278,6 +279,18 @@ def test_the_state_update_kernel_is_one_step_of_the_recurrence(shape):
     np.testing.assert_array_equal(np.asarray(got)[S], pool[S])
 
 
+@pytest.mark.parametrize("biased", [False, True])
+@pytest.mark.parametrize("channels", [256, 48])
+@pytest.mark.parametrize("width", [4, 2])
+def test_the_conv_tail_kernel_is_the_lines_it_replaces(width, channels,
+                                                       biased):
+    """The float32 pool a linear layer keeps (its products leave in
+    float32), at the published width of 4 and at 2; rows of whole lane
+    tiles and, as ``tiny-olmo-hybrid``'s 288 channels, one slab of lanes;
+    without the bias (the family has none) and with."""
+    check_conv_tail_step(jnp.float32, width, channels, biased)
+
+
 def test_the_pool_stores_whole_lane_tiles_at_the_published_sizes():
     """30 heads of 96 x 192: two heads a slab, 384 lanes = 3 tiles, 96
     sublanes = 12 tiles, so a seat's state is 2,211,840 B with no padding;
@@ -289,7 +302,9 @@ def test_the_pool_stores_whole_lane_tiles_at_the_published_sizes():
     assert [tuple(x["state"].shape) for x in pool] == [(65, 15, 96, 384)] * 3
     assert pool[0]["state"].shape[-1] % 128 == 0
     assert pool[0]["state"].shape[-2] % 8 == 0
-    assert tuple(pool[0]["conv"].shape) == (65, 3, 11520)
+    # the convolution's memory beside it: a row of 11,520 channels as 90
+    # sublanes of whole lane tiles (ops/pallas_conv_tail.py tail_slab)
+    assert tuple(pool[0]["conv"].shape) == (65, 3, 90, 128)
     x = jnp.arange(2 * 6 * 4 * 5, dtype=jnp.float32).reshape(2, 6, 4, 5)
     np.testing.assert_array_equal(upd.from_slabs(upd.to_slabs(x, 2), 2), x)
     assert upd.to_slabs(x, 2).shape == (2, 3, 4, 10)
@@ -444,14 +459,32 @@ def test_served_greedy_tokens_are_the_references(multi_step, attn_impl):
     assert engine.block_manager.num_seqs() == 0
 
 
-def test_a_seat_given_to_a_new_sequence_starts_from_zero():
+@pytest.mark.parametrize("multi_step", [1, 4])
+def test_the_decode_kernels_serve_what_the_formulas_serve(multi_step):
+    """A packed prefill, then eight decode steps, one at a time or in fused
+    windows: the state update's and the convolution memory's kernels
+    (``attn_impl="pallas"``, interpret mode here) against the formulas in
+    ``jax.numpy``, token for token."""
+    prompts = prompts_of(7, 12, 19, seed=3)
+    got = {impl: serve(engine_for(multi_step=multi_step, attn_impl=impl),
+                       prompts, max_tokens=9)
+           for impl in ("pallas", "reference")}
+    assert got["pallas"] == got["reference"]
+    assert all(len(toks) == 9 for toks in got["pallas"])
+
+
+@pytest.mark.parametrize("attn_impl", ["reference", "pallas"])
+def test_a_seat_given_to_a_new_sequence_starts_from_zero(attn_impl):
     """One seat: the second sequence runs on the slot the first one left
-    its state in, and serves what an untouched engine serves."""
+    its state and its convolution's memory in, and serves what an
+    untouched engine serves."""
     prompts = prompts_of(9, 14, seed=2)
-    engine = engine_for(scheduler={"max_num_seqs": 1}, multi_step=4)
+    engine = engine_for(scheduler={"max_num_seqs": 1}, multi_step=4,
+                        attn_impl=attn_impl)
     first, second = (serve(engine, [p])[0] for p in prompts)
     pool = np.asarray(engine.ssm_state[0]["state"])
     assert np.abs(pool[0]).max() > 0            # the seat was used
+    assert np.abs(np.asarray(engine.ssm_state[0]["conv"])[0]).max() > 0
     assert second == serve(engine_for(multi_step=4), [prompts[1]])[0]
     assert second == ref_greedy(engine.params, engine.model_cfg,
                                 prompts[1], 10)
@@ -590,13 +623,15 @@ LOWERED = {
         ("reference", "forward_ragged"): "ea7b0f5c2ce6e69b",
         ("reference", "prefill_chunk"): "9c27a385d9768d22",
     },
+    # (PR 46: the convolution's memory as whole lane tiles, stepped in
+    # place by its own kernel: every trunk of this family means to change)
     "tiny-falcon-h1": {
-        ("pallas", "decode_multi"): "2e3d67b6d75b2fc1",
-        ("pallas", "forward_ragged"): "e225f21e666f6f6f",
-        ("pallas", "prefill_chunk"): "026498feb498f6ac",
-        ("reference", "decode_multi"): "90fc2cc2622b5843",
-        ("reference", "forward_ragged"): "6b5cf5b11a9ef444",
-        ("reference", "prefill_chunk"): "3a82802b4f024d89",
+        ("pallas", "decode_multi"): "b7e68e5c59b9cb6e",
+        ("pallas", "forward_ragged"): "734831dffba86e08",
+        ("pallas", "prefill_chunk"): "3617877d2c533f38",
+        ("reference", "decode_multi"): "21d3d3d5e21c4bca",
+        ("reference", "forward_ragged"): "ecdf47368374148d",
+        ("reference", "prefill_chunk"): "c787ab1df7673b9a",
     },
     "tiny-mellum2": {
         ("pallas", "decode_multi"): "885b81fb59a5e229",

@@ -124,6 +124,26 @@ def test_a_trunk_carries_every_scope_of_its_family(model, program):
     assert not {part for _, part in found} & absent
 
 
+@pytest.mark.parametrize("model", ["tiny-falcon-h1", "tiny-olmo-hybrid"])
+def test_a_decode_step_files_each_memory_kernel_under_its_part(model):
+    """Under ``attn_impl="pallas"`` a decode step moves a recurrent
+    layer's two memories by a kernel each, and a traced run files them by
+    the scope their caller opens: the convolution's
+    (``_conv_tail_step``) under ``decode/ssm.conv``, the state's under
+    ``decode/ssm.scan`` -- whatever lies between in the path (the layer's
+    and the kernel's own ``jit(...)``)."""
+    fn, args, kwargs = trunk_programs(
+        family_config(model), attn_impl="pallas")["decode_multi"]
+    names = re.findall(r'op_name="([^"]*)"',
+                       fn.lower(*args, **kwargs).compile().as_text())
+    for kernel, part in ((r"jit\(_conv_tail_step\)", scopes.SSM_CONV),
+                         (r"jit\(_(gdn|ssm)_state_update\)",
+                          scopes.SSM_SCAN)):
+        under = [n for n in names if re.search(kernel, n)]
+        assert under, kernel
+        assert {scope_of(n) for n in under} == {(scopes.DECODE, part)}
+
+
 def test_the_table_is_one_place():
     """Every name once, phases and parts apart, and no scope is opened by a
     literal string anywhere in the program."""

@@ -727,7 +727,12 @@ def _mla_unabsorb(out_lat, lp, cfg: ModelConfig) -> jnp.ndarray:
 # beside the KV cache.  A window that starts at position 0 starts from
 # zeros, whatever its seat held: that is how a seat is cleared for its
 # next sequence.  Rows with PAD_SLOT as their cache slot are padding and
-# change nothing.  Equations: HF modeling_falcon_h1 (FalconH1Mixer).
+# change nothing.  A decode step touches each memory once, in place, by a
+# kernel of its own: the state (ops/pallas_ssm_update.py) and the
+# convolution's inputs (ops/pallas_conv_tail.py, which also says how the
+# pool lays them out: a row of channels as (channels / 128, 128); a
+# prefill reshapes from and to it, _read_tails / _keep_tails).
+# Equations: HF modeling_falcon_h1 (FalconH1Mixer).
 
 def _ssm_project(hn: jnp.ndarray, sp: dict, cfg: ModelConfig):
     """hn (..., hidden) -> gate z (..., d_ssm), convolution input xBC
@@ -785,6 +790,26 @@ def _ssm_output(y: jnp.ndarray, x: jnp.ndarray, z: jnp.ndarray, sp: dict,
                        cfg.ssm_out_multiplier)
 
 
+# The pool keeps a seat's convolution memory in the layout of the decode
+# step's kernel (ops/pallas_conv_tail.py ``tail_slab``); a prefill reads
+# and writes it as (n, W - 1, channels), through these two alone.
+
+def _read_tails(conv: jnp.ndarray, seats: jnp.ndarray,
+                shape: tuple) -> jnp.ndarray:
+    """The seats' tails as ``shape`` (n, W - 1, channels), in the pool's
+    dtype."""
+    return conv[seats].reshape(shape)
+
+
+def _keep_tails(conv: jnp.ndarray, seats: jnp.ndarray,
+                tails: jnp.ndarray) -> jnp.ndarray:
+    """The pool's convolution memory with the seats' tails (n, W - 1,
+    channels) after a prefill."""
+    with jax.named_scope(scopes.SSM_CONV):
+        return conv.at[seats].set(
+            tails.astype(conv.dtype).reshape(-1, *conv.shape[1:]))
+
+
 def _ssm_window(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
                 lens: jnp.ndarray, entry: dict | None = None,
                 seats: jnp.ndarray | None = None,
@@ -808,7 +833,8 @@ def _ssm_window(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
             s0 = jnp.where(keep[:, None, None, None], entry["state"][seats],
                            s0)
         with jax.named_scope(scopes.SSM_CONV):
-            tail = jnp.where(keep[:, None, None], entry["conv"][seats], tail)
+            tail = jnp.where(keep[:, None, None], _read_tails(
+                entry["conv"], seats, tail.shape), tail)
     conv_out, rows = ssm_ops.causal_conv(xbc, tail, sp["conv"]["kernel"],
                                          sp["conv"].get("bias"))
     valid = jnp.arange(L)[None, :] < lens[:, None]
@@ -825,10 +851,9 @@ def _ssm_window(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
         return m, None
     with jax.named_scope(scopes.SSM_SCAN):
         state = entry["state"].at[seats].set(finals)
-    with jax.named_scope(scopes.SSM_CONV):
-        conv = entry["conv"].at[seats].set(
-            ssm_ops.next_tail(rows, lens, W).astype(entry["conv"].dtype))
-    return m, {"state": state, "conv": conv}
+    return m, {"state": state,
+               "conv": _keep_tails(entry["conv"], seats,
+                                   ssm_ops.next_tail(rows, lens, W))}
 
 
 def _ssm_nocache(hn: jnp.ndarray, lp: dict, cfg: ModelConfig,
@@ -878,35 +903,35 @@ def _ssm_packed(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
         idx = (q_starts + q_lens)[:, None] + back
         tails = jnp.where((q_lens[:, None] + back >= 0)[..., None],
                           xbc[jnp.clip(idx, 0, T - 1)], 0)
-        conv = entry["conv"].at[seats].set(tails.astype(entry["conv"].dtype))
-    return m, {"state": state, "conv": conv}
+    return m, {"state": state,
+               "conv": _keep_tails(entry["conv"], seats, tails)}
 
 
 def _ssm_decode(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
                 valid: jnp.ndarray, entry: dict, seats: jnp.ndarray,
                 attn_impl: str):
-    """One token a row: hn (B, hidden).  The convolution's memory shifts
-    by one; the state is updated in place on the pool — by the Pallas
-    kernel under ``attn_impl="pallas"`` (ops/pallas_ssm_update.py), by the
-    same formula in ``jax.numpy`` otherwise.  Padding rows (not ``valid``)
+    """One token a row: hn (B, hidden).  The convolution's memory taps
+    the new row and shifts by one, the state is updated, both in place on
+    the pool — each by its Pallas kernel under ``attn_impl="pallas"``
+    (ops/pallas_conv_tail.py, ops/pallas_ssm_update.py), by the same
+    formulas in ``jax.numpy`` otherwise.  Padding rows (not ``valid``)
     carry the trash seat.  Returns (m (B, hidden), entry)."""
     z, xbc, dt_raw = _ssm_project(hn, sp, cfg)
-    with jax.named_scope(scopes.SSM_CONV):
-        tail = entry["conv"][seats]
-    conv_out, rows = ssm_ops.causal_conv(
-        xbc[:, None], tail, sp["conv"]["kernel"], sp["conv"].get("bias"))
-    x, bm, cm, dt, a = _ssm_inputs(conv_out[:, 0], dt_raw, sp, cfg, valid)
+    from tpuserve.ops import pallas_conv_tail as tap
     from tpuserve.ops import pallas_ssm_update as upd
-    update = (upd.ssm_state_update if attn_impl == "pallas"
-              else upd.ssm_state_update_reference)
+    pallas = attn_impl == "pallas"
+    with jax.named_scope(scopes.SSM_CONV):
+        conv_out, conv = (tap.conv_tail_step if pallas
+                          else tap.conv_tail_step_reference)(
+            entry["conv"], seats, xbc, sp["conv"]["kernel"],
+            sp["conv"].get("bias"))
+    x, bm, cm, dt, a = _ssm_inputs(conv_out, dt_raw, sp, cfg, valid)
     with jax.named_scope(scopes.SSM_SCAN):
         x = x.astype(jnp.float32)
-        y, state = update(entry["state"], seats, jnp.exp(dt * a),
-                          dt[..., None] * x, bm, cm)
+        y, state = (upd.ssm_state_update if pallas
+                    else upd.ssm_state_update_reference)(
+            entry["state"], seats, jnp.exp(dt * a), dt[..., None] * x, bm, cm)
     m = _ssm_output(y, x, z, sp, cfg)
-    with jax.named_scope(scopes.SSM_CONV):
-        conv = entry["conv"].at[seats].set(
-            rows[:, 1:].astype(entry["conv"].dtype))
     return m, {"state": state, "conv": conv}
 
 
@@ -920,7 +945,8 @@ def _ssm_decode(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
 # ``lin_conv_kernel - 1`` inputs of a short convolution, in the same seat
 # pool as Falcon-H1's mixer (``ssm``: one entry a layer that holds a
 # state, at the layer's place among them; the state in slabs of heads,
-# ops/pallas_gdn_update.py) under the same rules: a window that starts at
+# ops/pallas_gdn_update.py; the convolution's inputs as Falcon-H1's,
+# stepped by the same kernel) under the same rules: a window that starts at
 # position 0 starts from zeros, padding rows change nothing.  The helpers
 # open the scope of their ROLE in a recurrent mixer (``ssm.*``): the
 # readers by scope read both mixers alike, the kernels differ by name.
@@ -1014,9 +1040,7 @@ def _lin_keep(entry: dict, seats: jnp.ndarray, finals: jnp.ndarray,
     with jax.named_scope(scopes.SSM_SCAN):
         state = entry["state"].at[seats].set(
             to_slabs(finals, _lin_slabs(entry, cfg)))
-    with jax.named_scope(scopes.SSM_CONV):
-        conv = entry["conv"].at[seats].set(tails.astype(entry["conv"].dtype))
-    return {"state": state, "conv": conv}
+    return {"state": state, "conv": _keep_tails(entry["conv"], seats, tails)}
 
 
 def _lin_window(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
@@ -1042,7 +1066,8 @@ def _lin_window(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
             s0 = jnp.where(keep[:, None, None, None], from_slabs(
                 entry["state"][seats], _lin_slabs(entry, cfg)), s0)
         with jax.named_scope(scopes.SSM_CONV):
-            tail = jnp.where(keep[:, None, None], entry["conv"][seats], tail)
+            tail = jnp.where(keep[:, None, None], _read_tails(
+                entry["conv"], seats, tail.shape), tail)
     conv_out, rows = ssm_ops.causal_conv(qkv, tail, sp["conv"]["kernel"],
                                          None)
     valid = jnp.arange(L)[None, :] < lens[:, None]
@@ -1101,30 +1126,29 @@ def _lin_packed(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
 def _lin_decode(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
                 slot_ids: jnp.ndarray, entry: dict, seats: jnp.ndarray,
                 attn_impl: str):
-    """One token a row: h (B, hidden).  The convolution's memory shifts
-    by one; the state is updated in place on the pool -- by the Pallas
-    kernel under ``attn_impl="pallas"`` (ops/pallas_gdn_update.py), by the
-    same formula in ``jax.numpy`` otherwise.  Padding rows (PAD_SLOT as
-    their cache slot) carry the trash seat.  Returns (the residual stream
-    after the mixer (B, hidden), entry)."""
+    """One token a row: h (B, hidden).  The convolution's memory taps
+    the new row and shifts by one, the state is updated, both in place on
+    the pool -- each by its Pallas kernel under ``attn_impl="pallas"``
+    (ops/pallas_conv_tail.py, ops/pallas_gdn_update.py), by the same
+    formulas in ``jax.numpy`` otherwise.  Padding rows (PAD_SLOT as their
+    cache slot) carry the trash seat.  Returns (the residual stream after
+    the mixer (B, hidden), entry)."""
     sp = lp["lin"]
     qkv, gate, a_raw, b_raw = _lin_project(h, lp, cfg)
+    from tpuserve.ops import pallas_conv_tail as tap
+    from tpuserve.ops import pallas_gdn_update as upd
+    pallas = attn_impl == "pallas"
     with jax.named_scope(scopes.SSM_CONV):
         valid = slot_ids != attn_ops.PAD_SLOT
-        tail = entry["conv"][seats]
-        conv_out, rows = ssm_ops.causal_conv(qkv[:, None], tail,
-                                             sp["conv"]["kernel"], None)
-        conv_out = conv_out[:, 0]
+        conv_out, conv = (tap.conv_tail_step if pallas
+                          else tap.conv_tail_step_reference)(
+            entry["conv"], seats, qkv, sp["conv"]["kernel"], None)
     x, g, beta = _lin_inputs(conv_out, a_raw, b_raw, sp, cfg, valid)
-    from tpuserve.ops import pallas_gdn_update as upd
-    update = (upd.gdn_state_update if attn_impl == "pallas"
-              else upd.gdn_state_update_reference)
     with jax.named_scope(scopes.SSM_SCAN):
-        o, state = update(entry["state"], seats, *_lin_qkv(x, cfg), g, beta)
+        o, state = (upd.gdn_state_update if pallas
+                    else upd.gdn_state_update_reference)(
+            entry["state"], seats, *_lin_qkv(x, cfg), g, beta)
     h = _lin_output(o, gate, h, lp, cfg)
-    with jax.named_scope(scopes.SSM_CONV):
-        conv = entry["conv"].at[seats].set(
-            rows[:, 1:].astype(entry["conv"].dtype))
     return h, {"state": state, "conv": conv}
 
 

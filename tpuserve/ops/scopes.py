@@ -74,8 +74,13 @@ The table (scope -> where it opens -> which metric reads it):
                     reader files it under mlp, which encloses
                     it)                                        moe.shared_device_share
     ssm.in_proj     _ssm_project                              trunk.decode_proj_ms
-    ssm.conv        ops/ssm.py causal_conv, next_tail; _ssm_inputs;
-                    the memory's gather and shift              ssm.prefill_scan_device_share (prefill/, chunk/)
+    ssm.conv        ops/ssm.py causal_conv, next_tail; _ssm_inputs,
+                    _lin_inputs; a prefill's read and write of the
+                    memory (_keep_tails); under decode/ the memory's
+                    step in place on the pool, ops/pallas_conv_tail.py
+                    (the custom call _conv_tail_step, one a layer that
+                    holds a state a step; no reader matches it by
+                    name)                                      ssm.prefill_scan_device_share (prefill/, chunk/)
     ssm.scan        ops/ssm.py ssd_chunk_scan (body included); in
                     _ssm_decode the state update and its inputs;
                     the state's gather and write-back          ssm.prefill_scan_device_share (prefill/, chunk/)

@@ -67,7 +67,12 @@ def bytes_per_block(model_cfg: ModelConfig, cache_cfg: CacheConfig,
 def _ssm_layer(c: ModelConfig, num_seats: int) -> dict:
     """One layer of the recurrent-state pool, as shapes: Mamba-2 heads'
     (Falcon-H1), or a linear-attention layer's matrix states in slabs of
-    heads whose lane axis is whole tiles (ops/pallas_gdn_update.py)."""
+    heads whose lane axis is whole tiles (ops/pallas_gdn_update.py).
+    Beside either, the short convolution's last ``W - 1`` inputs, each row
+    of channels as whole 128-lane tiles down the sublanes
+    (ops/pallas_conv_tail.py ``tail_slab``: a seat's memory is one
+    contiguous piece that the decode step's kernel moves in place)."""
+    from tpuserve.ops.pallas_conv_tail import tail_slab
     if c.linear_layers is not None:
         from tpuserve.ops.pallas_gdn_update import heads_per_slab
         hp = heads_per_slab(c.lin_num_value_heads, c.lin_value_head_dim)
@@ -78,14 +83,14 @@ def _ssm_layer(c: ModelConfig, num_seats: int) -> dict:
                 # float32 like the products it is cut from
                 # (models/transformer.py _lin_project)
                 "conv": jax.ShapeDtypeStruct(
-                    (num_seats + 1, c.lin_conv_kernel - 1, c.lin_conv_dim),
-                    jnp.float32)}
+                    (num_seats + 1, c.lin_conv_kernel - 1,
+                     *tail_slab(c.lin_conv_dim)), jnp.float32)}
     return {"state": jax.ShapeDtypeStruct(
                 (num_seats + 1, c.mamba_n_heads, c.mamba_d_head,
                  c.mamba_d_state), jnp.float32),
             "conv": jax.ShapeDtypeStruct(
-                (num_seats + 1, c.mamba_d_conv - 1, c.mamba_conv_dim),
-                jnp.dtype(c.dtype))}
+                (num_seats + 1, c.mamba_d_conv - 1,
+                 *tail_slab(c.mamba_conv_dim)), jnp.dtype(c.dtype))}
 
 
 def ssm_state_bytes(model_cfg: ModelConfig, num_seats: int) -> int:
@@ -103,7 +108,7 @@ def create_ssm_state(model_cfg: ModelConfig, num_seats: int) -> list[dict]:
     (``ModelConfig.state_layers``, in order: every layer of Falcon-H1, the
     linear-attention layers of Olmo-Hybrid), each ``{"state": (seats + 1,
     H, P, N) float32 (a linear layer: :func:`_ssm_layer`), "conv": (seats
-    + 1, W - 1, channels)}``: one slot a running sequence —
+    + 1, W - 1, channels / 128, 128)}``: one slot a running sequence —
     NOT a page a token like the KV cache beside it — and a last one that
     padding rows read and write (``SeatPool.trash``).  Float32 state: the
     recurrence accumulates over every token of a sequence.  The trunks
