@@ -1,0 +1,170 @@
+"""Whole cells fit the chip: each served trunk of a benchmark cell at its
+published widths and the depth the cell runs, beside its pages and its
+seat pool (see ``tests/test_chip_compile.py`` and ``tests/chip_v5e.py``)."""
+
+import re
+
+import jax
+import pytest
+
+from chip_v5e import (CHUNK, MAX_NUM_SEQS, MAX_PAGES, PAGE, PREFILL_SEQS,
+                      WIDTHS, k_exaone_share, olmo_hybrid, shapes_on)
+from chip_v5e import (  # noqa: F401  (fixtures, found by name)
+    _no_persistent_cache, one_chip, topo)
+
+# a cell's three served trunks: (program, flat tokens; 0 = one ragged block)
+CELL_PROGRAMS = [("decode_multi", 0), ("forward_ragged", 8192),
+                 ("prefill_chunk", 0)]
+
+
+def _compile_cell_program(cfg, program, tokens, num_blocks, one_chip,
+                          monkeypatch):
+    """One served trunk of a cell at its published widths, compiled for
+    the described chip: a fused window of 64 rows and 8 steps, the top
+    rung of the packed prefill or a chunk, beside ``num_blocks`` pages of
+    32 tokens and the seat pool."""
+    from test_scopes import trunk_programs
+    from tpuserve.ops.pallas_ragged_attention import ragged_block_for
+
+    S, place = shapes_on(one_chip)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    blk = ragged_block_for(cfg.cache_q_heads, cfg.cache_kv_heads,
+                           cfg.head_dim, PAGE, 2, 2)
+    assert blk == 128
+    fn, args, kwargs = trunk_programs(
+        cfg, S, place, rows=MAX_NUM_SEQS, steps=8, tokens=tokens or blk,
+        blk=blk, prompts=PREFILL_SEQS, chunk=CHUNK, block_size=PAGE,
+        num_blocks=num_blocks, max_blocks=MAX_PAGES,
+        attn_impl="pallas")[program]
+    return fn.lower(*args, **kwargs).compile()
+
+
+@pytest.mark.parametrize("program,tokens", CELL_PROGRAMS)
+def test_the_olmo_hybrid_cell_fits_the_chip(program, tokens, one_chip,
+                                            monkeypatch):
+    """The cell's whole trunks at the published widths: 16 layers (12
+    linear, 4 full), a fused decode window of 64 rows, the top rung of the
+    packed-prefill ladder and a chunk, beside a pool of 2,560 pages of 32
+    tokens for the 4 attention layers (what 0.9 of the chip leaves after
+    8.2 GB of weights and 1.83 GB of state).  The chip's compiler refuses
+    what does not fit 16 GB; 30 query heads on 30 KV heads reach the
+    kernels as 32 on 32 and keep the 128-row ragged block."""
+    cfg = olmo_hybrid(num_layers=16)
+    assert (cfg.cache_q_heads, cfg.cache_kv_heads) == (32, 32)
+    compiled = _compile_cell_program(cfg, program, tokens, 2560, one_chip,
+                                     monkeypatch)
+    mem = compiled.memory_analysis()
+    weights = mem.argument_size_in_bytes - mem.alias_size_in_bytes
+    assert 8.1e9 < weights < 8.3e9, weights
+    # pages and pool stay in place, whole, in every program: 4 layers'
+    # pages and 12 layers' seats (trunk_programs gives each program the
+    # same pool: the window's 64 rows, one seat more and the trash seat;
+    # a seat's three convolution rows, 90 sublanes each, stored as 96)
+    pages = 4 * 2 * 2560 * PAGE * 32 * 128 * 2
+    seat = 2_211_840 + 3 * 96 * 128 * 4
+    assert mem.alias_size_in_bytes == pages + 12 * 66 * seat
+    # beside them what a dispatch holds of its own stays under the tenth
+    # of the chip the cache's sizer leaves free
+    assert mem.temp_size_in_bytes < 1.4e9, mem.temp_size_in_bytes
+    # 16.91 GB less the runtime's own 0.27: what the compiler itself
+    # holds a program to (2,560 pages here; the sizer gives ~2,470)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.6e9
+    text = compiled.as_text()
+    if program == "decode_multi":
+        assert "_paged_decode_attention" in text
+        # the convolution's memory moves once a linear layer a step, by
+        # its kernel, as the state beside it does by its own ...
+        calls = {k: re.findall(rf"%{k}(?:\.\d+)? = ([^\n]*?)custom-call\(",
+                               text)
+                 for k in ("_conv_tail_step", "_gdn_state_update")}
+        assert len(calls["_conv_tail_step"]) == 12
+        assert len(calls["_gdn_state_update"]) == 12
+        # ... on a pool the compiler leaves in HBM: no operand or result of
+        # the call in its faster memory space, and no asynchronous copy of
+        # anything of the pool's shape (it staged each layer's 9 MB there
+        # and back around the gather and scatter this kernel replaced,
+        # every step: PERF.md §6, PR 46)
+        of_pool = r"f32\[6[456],3,(?:90,128|11520)\]"
+        for out in calls["_conv_tail_step"]:
+            assert not re.search(of_pool + r"\{[^}]*S\(1\)", out), out
+        staged = [line for line in text.split("\n")
+                  if re.search(r" (copy|slice)-start\(", line)
+                  and re.search(of_pool, line)]
+        assert not staged, staged[:2]
+
+
+# what the three trunks of ``falcon-h1-34b-l6.reason`` held at the parent of
+# PR 46 (the convolution's memory as ``(66, 3, 5120)``, stepped by XLA's
+# gather, taps and scatter), compiled as below: (argument, temporary) bytes
+FALCON_H1_BEFORE = {"decode_multi": (15_244_633_600, 55_074_304),
+                    "forward_ragged": (15_244_704_256, 1_630_251_008),
+                    "prefill_chunk": (15_244_616_704, 318_360_064)}
+
+
+@pytest.mark.parametrize("program,tokens", CELL_PROGRAMS)
+def test_the_falcon_h1_cell_holds_no_more_than_before(program, tokens,
+                                                      one_chip, monkeypatch):
+    """The served programs of the Falcon-H1 cell at the published widths
+    (6 layers, a fused window of 64 rows, the top rung of the packed
+    prefill and a chunk, beside the 7,785 pages the sizer gives the cell:
+    PERF.md §4) hold no more of the chip with the convolution's memory as
+    ``(seats, 3, 40, 128)`` than with ``(seats, 3, 5120)``: arguments and
+    temporaries at or under the parent's, pages and pool whole in place.
+    (The cell's ``memory_peak_bytes`` reads 381 MB higher since PR 46:
+    not in these programs, PERF.md §7 row 27.)"""
+    import dataclasses
+
+    from tpuserve.models.config import get_model_config
+    cfg = dataclasses.replace(
+        get_model_config("tiiuae/Falcon-H1-34B-Instruct"), num_layers=6)
+    compiled = _compile_cell_program(cfg, program, tokens, 7785, one_chip,
+                                     monkeypatch)
+    mem = compiled.memory_analysis()
+    argument, temp = FALCON_H1_BEFORE[program]
+    assert mem.argument_size_in_bytes <= argument
+    assert mem.temp_size_in_bytes <= temp
+    # 6 layers' pages (4 KV heads) and 6 layers' seats: 32 heads of 128 x
+    # 256 float32 and three rows of 40 bfloat16 sublanes, stored as 40
+    pages = 6 * 2 * 7785 * PAGE * 4 * 128 * 2
+    seat = 32 * 128 * 256 * 4 + 3 * 40 * 128 * 2
+    assert mem.alias_size_in_bytes == pages + 6 * 66 * seat
+    text = compiled.as_text()
+    staged = [line for line in text.split("\n")
+              if re.search(r" (copy|slice)-start\(", line)
+              and re.search(r"bf16\[6[456],3,(?:40,128|5120)\]", line)]
+    assert not staged, staged[:2]
+    if program == "decode_multi":
+        for kernel in ("_conv_tail_step", "_ssm_state_update"):
+            assert len(re.findall(rf"%{kernel}(?:\.\d+)? = ", text)) == 6
+
+
+@pytest.mark.parametrize("program,tokens", [("decode_multi", 0),
+                                            ("forward_ragged", 8192)])
+def test_the_k_exaone_cell_fits_the_chip(program, tokens, one_chip,
+                                         monkeypatch):
+    """The cell's whole trunks at the published widths: 8 layers, 16 of
+    128 experts, 19,200 vocabulary rows, a fused decode window of 64 rows
+    and the top rung of the packed-prefill ladder, beside a pool of 3,072
+    pages of 32 tokens (what 0.9 of the chip leaves after 11.96 GB of
+    weights).  The chip's compiler refuses what does not fit 16 GB; 64
+    query heads take a ragged block of 64 rows, as the engine finds."""
+    from test_scopes import trunk_programs
+    from tpuserve.ops.pallas_ragged_attention import ragged_block_for
+
+    S, place = shapes_on(one_chip)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = k_exaone_share(num_layers=8)
+    blk = ragged_block_for(cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+                           PAGE, 2, 2)
+    assert blk == 64
+    # and the accepted cells' shapes keep their 128 rows
+    for hq, hkv, d in WIDTHS.values():
+        assert ragged_block_for(hq, hkv, d, PAGE, 2, 2) == 128
+    fn, args, kwargs = trunk_programs(
+        cfg, S, place, rows=MAX_NUM_SEQS, steps=8, tokens=tokens or blk,
+        blk=blk, prompts=PREFILL_SEQS, block_size=PAGE, num_blocks=3072,
+        max_blocks=MAX_PAGES, attn_impl="pallas")[program]
+    mem = fn.lower(*args, **kwargs).compile().memory_analysis()
+    weights = mem.argument_size_in_bytes - mem.alias_size_in_bytes
+    assert 11.9e9 < weights < 12.1e9, weights
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.5e9

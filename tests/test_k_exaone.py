@@ -26,34 +26,28 @@ fault).
 import dataclasses
 import json
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from family_routes import (BLOCK, FAMILIES, Served, engine_for, plan,
+                           prompts_of, ref_greedy, ref_logits, run_route)
 from tpuserve.models import transformer
 from tpuserve.models.config import (ModelConfig, config_from_hf_json,
                                     get_model_config)
 from tpuserve.models.weights import init_params
 from tpuserve.runtime import CacheConfig, Engine, EngineConfig, SamplingParams
-from tpuserve.runtime.kv_cache import create_kv_cache
-from tpuserve.runtime.scheduler import SchedulerConfig
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:            # ``benchmark`` is a package of the root
-    sys.path.insert(0, ROOT)
-from benchmark.harness import plan  # noqa: E402
-from test_mellum2 import BLOCK, Served as _Served  # noqa: E402
-
-ATOL = 2e-4
-MODEL = "tiny-k-exaone"
+FAMILY = FAMILIES["k_exaone"]
+ATOL = FAMILY.atol
+MODEL = FAMILY.model
 PUBLISHED = "LGAI-EXAONE/K-EXAONE-236B-A23B"
 HELD = 8                # experts of the tiny model's 32 one share holds
 SHARES = 4
 
-ref = plan.load_reference({"reference": "k_exaone"})
+ref = FAMILY.ref
 
 
 def catalog_config() -> dict:
@@ -110,25 +104,6 @@ def shared(whole):
     """The share the cell holds: the first experts."""
     return share_of(*whole, 0)
 
-
-def prompts_of(*lengths, seed=0):
-    rs = np.random.RandomState(seed)
-    return [[int(t) for t in rs.randint(2, 256, n)] for n in lengths]
-
-
-def ref_logits(params, cfg, seq, positions):
-    """Reference logits after each of ``positions`` of one sequence."""
-    return np.asarray(ref.logits_at(
-        params, cfg, np.asarray([seq], np.int32),
-        [(0, p) for p in positions]))
-
-
-def ref_greedy(params, cfg, prompt, n):
-    seq = list(prompt)
-    for _ in range(n):
-        seq.append(int(np.argmax(ref_logits(params, cfg, seq,
-                                            [len(seq) - 1])[0])))
-    return seq[len(prompt):]
 
 
 def rows_of(n, seed=1, hidden=64):
@@ -245,97 +220,6 @@ def test_the_buffer_follows_the_share_not_every_pick():
 # (c) every route through the paged cache, against the full forward pass
 # --------------------------------------------------------------------------
 
-class Served(_Served):
-    """Mellum 2's hand-driven paged cache (sequence ``i`` owns the blocks
-    ``[i * mb, (i + 1) * mb)``; its ``prefill``, ``packed``, ``chunks``
-    and ``decode``), with this family's routing counts summed in
-    ``counts`` (their length depends on the share) and a window that
-    knows the dense layer names no pick."""
-
-    def __init__(self, cfg, params, n_seqs, attn_impl="reference",
-                 dtype="float32"):
-        super().__init__(cfg, params, n_seqs, attn_impl)
-        if dtype != "float32":
-            self.kv = create_kv_cache(cfg, CacheConfig(
-                block_size=BLOCK, num_blocks=n_seqs * self.mb,
-                max_blocks_per_seq=self.mb, dtype=dtype))
-        self.counts = 0
-
-    def _keep(self, res):
-        out, self.kv, moe = res[0], res[1], res[-1]
-        self.counts = self.counts + np.asarray(moe[0], np.int64)
-        return out
-
-    def window(self, seqs, steps):
-        """A fused greedy window with one padding row: tokens and the
-        chosen tokens' log-probabilities, (B, steps) each."""
-        B = len(seqs) + 1
-        n = np.ones((B,), np.int32)
-        n[:len(seqs)] = [len(s) for s in seqs]
-        tokens = np.zeros((B,), np.int32)
-        tokens[:len(seqs)] = [s[-1] for s in seqs]
-        tables = np.zeros((B, self.mb), np.int32)
-        tables[:len(seqs)] = self.tables[:len(seqs)]
-        active = np.arange(B) < len(seqs)
-        toks, self.kv, lp, moe = transformer.decode_multi(
-            self.params, self.cfg, jnp.asarray(tokens), jnp.asarray(n - 1),
-            jnp.asarray(tables), jnp.asarray(n), jnp.asarray(active),
-            jnp.zeros((B, 2), jnp.uint32), jnp.zeros((B,), jnp.float32),
-            self.kv, steps=steps, mode="greedy", logprobs_n=1,
-            attn_impl=self.attn_impl)
-        # the rows' picks ride fourth with the logprobs, [row, step], one
-        # entry an EXPERT layer (the dense layer has none)
-        sparse = self.cfg.num_layers - self.cfg.moe_first_k_dense
-        assert lp[3].shape == (B, steps, sparse,
-                               self.cfg.num_experts_per_tok)
-        self.counts = self.counts + np.asarray(moe[0], np.int64)
-        return np.asarray(toks)[:len(seqs)], np.asarray(lp[0])[:len(seqs)]
-
-
-def then_decode(served, params, cfg, seqs, first_logits, atol=ATOL):
-    """After any prefill route: its logits, three decode steps and a fused
-    window of four, each against the reference's full forward."""
-    seqs = [list(s) for s in seqs]
-    for i, s in enumerate(seqs):
-        np.testing.assert_allclose(
-            first_logits[i], ref_logits(params, cfg, s, [len(s) - 1])[0],
-            atol=atol)
-        s.append(int(np.argmax(first_logits[i])))
-    for _ in range(3):
-        logits = served.decode(seqs)
-        for i, s in enumerate(seqs):
-            np.testing.assert_allclose(
-                logits[i], ref_logits(params, cfg, s, [len(s) - 1])[0],
-                atol=atol)
-            s.append(int(np.argmax(logits[i])))
-    toks, lps = served.window(seqs, 4)
-    for i, s in enumerate(seqs):
-        assert list(toks[i]) == ref_greedy(params, cfg, s, 4)
-        full = s + list(toks[i])
-        rows = np.asarray(jax.nn.log_softmax(ref_logits(
-            params, cfg, full, range(len(s) - 1, len(full) - 1))))
-        np.testing.assert_allclose(
-            lps[i], rows[np.arange(4), toks[i]], atol=atol)
-
-
-def run_route(cfg, params, route, attn_impl, atol=ATOL):
-    if route == "chunks":
-        seqs = prompts_of(40)
-        served = Served(cfg, params, 1, attn_impl)
-        per_chunk = served.chunks(seqs[0])
-        for logits, upto in zip(per_chunk, (16, 32, 40)):
-            np.testing.assert_allclose(
-                logits, ref_logits(params, cfg, seqs[0], [upto - 1])[0],
-                atol=atol)
-        first = [per_chunk[-1]]
-    else:
-        seqs = prompts_of(40, 6, 29)
-        served = Served(cfg, params, 3, attn_impl)
-        first = served.prefill(seqs) if route == "prefill" \
-            else served.packed(seqs)
-    then_decode(served, params, cfg, seqs, first, atol)
-    return served
-
 
 @pytest.mark.parametrize("attn_impl", ["reference", "pallas"])
 @pytest.mark.parametrize("route", ["prefill", "packed", "chunks"])
@@ -348,7 +232,7 @@ def test_every_route_matches_the_reference_under_a_share(
     crosses the window while it decodes.  ``pallas``: the paged attention
     kernels in interpret mode (the grouped product is a kernel on both)."""
     cfg, params = shared
-    served = run_route(cfg, params, route, attn_impl)
+    served = run_route(FAMILY, cfg, params, route, attn_impl)
     E, per = cfg.num_experts, cfg.num_experts_per_tok * 7   # expert layers
     assert served.counts.shape == (E + 5,)
     assert served.counts[:E].sum() % per == 0
@@ -364,7 +248,7 @@ def test_every_route_matches_the_reference_with_every_expert_held(
         whole, route):
     """The family without a share: the layer that holds every expert."""
     cfg, params = whole
-    served = run_route(cfg, params, route, "reference")
+    served = run_route(FAMILY, cfg, params, route, "reference")
     assert served.counts.shape == (cfg.num_experts + 1,)
 
 
@@ -379,11 +263,11 @@ def test_bfloat16_where_float32_is_stated_fails(shared):
         if x.dtype == jnp.float32 and x.ndim > 1 else x, params)
     low_cfg = dataclasses.replace(cfg, dtype="bfloat16")
     seqs = prompts_of(40, 6, 29)
-    served = Served(low_cfg, low, 3, dtype="bfloat16")
+    served = Served(FAMILY, low_cfg, low, 3, dtype="bfloat16")
     first = served.packed(seqs)
     off = max(np.max(np.abs(
         first[i].astype(np.float32)
-        - ref_logits(low, cfg, s, [len(s) - 1])[0]))
+        - ref_logits(FAMILY, low, cfg, s, [len(s) - 1])[0]))
         for i, s in enumerate(seqs))
     assert off > 50 * ATOL, off
 
@@ -395,7 +279,7 @@ def test_the_share_the_layer_kinds_and_the_scaling_are_live(shared):
     is not vacuous."""
     cfg, params = shared
     seq = prompts_of(40, seed=3)[0]
-    want = ref_logits(params, cfg, seq, [39])[0]
+    want = ref_logits(FAMILY, params, cfg, seq, [39])[0]
     broken = {
         "every layer full": dataclasses.replace(
             cfg, window_layers=(False,) * 8),
@@ -438,26 +322,19 @@ def test_a_full_layer_carries_no_position_and_a_windowed_one_does(shared):
 # the engine
 # --------------------------------------------------------------------------
 
-def engine_for(params, cfg, **kw):
-    return Engine(EngineConfig(
-        model=MODEL, attn_impl=kw.pop("attn_impl", "reference"),
-        cache=CacheConfig(block_size=BLOCK, num_blocks=96,
-                          max_blocks_per_seq=24, dtype="float32"),
-        scheduler=SchedulerConfig(min_prefill_bucket=8, min_decode_bucket=2),
-        **kw), params=params, model_cfg=cfg)
-
 
 @pytest.mark.parametrize("multi_step,attn_impl", [
     (1, "reference"), (4, "reference"), (4, "pallas")])
 def test_served_greedy_tokens_are_the_references(shared, multi_step,
                                                  attn_impl):
     cfg, params = shared
-    eng = engine_for(params, cfg, multi_step=multi_step, attn_impl=attn_impl)
+    eng = engine_for(FAMILY, params, cfg, multi_step=multi_step,
+                     attn_impl=attn_impl)
     prompts = prompts_of(40, 9, seed=5)
     outs = eng.generate(prompts, SamplingParams(
         max_tokens=10, temperature=0.0, ignore_eos=True))
     for p, o in zip(prompts, outs):
-        assert o.output_token_ids == ref_greedy(params, cfg, p, 10)
+        assert o.output_token_ids == ref_greedy(FAMILY, params, cfg, p, 10)
     assert eng.block_manager.num_seqs() == 0
 
 
@@ -468,7 +345,7 @@ def test_what_landed_here_comes_back_with_the_tokens(shared, whole):
     ``tpuserve_moe_held_rows`` exports; a model that holds every expert
     has none of the four."""
     cfg, params = shared
-    eng = engine_for(params, cfg, multi_step=4)
+    eng = engine_for(FAMILY, params, cfg, multi_step=4)
     eng.generate(prompts_of(21, 6, seed=9), SamplingParams(
         max_tokens=9, temperature=0.0, ignore_eos=True))
     steps = [s for s in eng.flight.steps_snapshot(limit=1 << 20)
@@ -490,7 +367,7 @@ def test_what_landed_here_comes_back_with_the_tokens(shared, whole):
     assert eng._moe_inflight == []
 
     cfg, params = whole
-    eng = engine_for(params, cfg, multi_step=4)
+    eng = engine_for(FAMILY, params, cfg, multi_step=4)
     eng.generate(prompts_of(21, seed=9), SamplingParams(
         max_tokens=5, temperature=0.0, ignore_eos=True))
     assert eng.stats.moe_routed_rows > 0 and eng.stats.moe_held_rows == 0
@@ -503,7 +380,7 @@ def test_logprobs_name_the_picks_of_the_expert_layers(shared):
     routed a position to (7 of the 8 layers), over all 32 experts whether
     held or not: what the reference replays."""
     cfg, params = shared
-    eng = engine_for(params, cfg, multi_step=4)
+    eng = engine_for(FAMILY, params, cfg, multi_step=4)
     prompt = prompts_of(19, seed=7)[0]
     (out,) = eng.generate([prompt], SamplingParams(
         max_tokens=5, temperature=0.0, ignore_eos=True, logprobs=2))

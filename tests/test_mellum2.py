@@ -13,42 +13,33 @@ heads) under seeded random weights.  Logits, not tokens.
 Tolerances: both sides are float32 on the CPU, so what separates them is
 the ORDER of the same sums (grouped products over sorted rows against a
 loop over experts, blocked attention against a dense softmax): a few 1e-6
-on logits of size ~1-3.  ``ATOL`` 2e-4 leaves two orders of magnitude over
-that; a flipped expert, a table of the wrong kind or a window ignored
-moves logits by over 1e-2 (``tests/benchmark/
+on logits of size ~1-3.  The tolerance (``FAMILIES``' 2e-4) leaves two
+orders of magnitude over that; a flipped expert, a table of the wrong kind
+or a window ignored moves logits by over 1e-2 (``tests/benchmark/
 test_benchmark_mellum2_rehearsal.py`` has each as a fault).
 """
 
 import dataclasses
 import math
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpuserve.models import transformer
+from family_routes import (BLOCK, FAMILIES, engine_for, plan, prompts_of,
+                           ref_greedy, ref_logits, run_route)
 from tpuserve.models.config import config_from_hf_json, get_model_config
 from tpuserve.models.weights import init_params
 from tpuserve.ops import rope as rope_ops
-from tpuserve.ops.attention import PAD_SLOT
 from tpuserve.runtime import CacheConfig, Engine, EngineConfig, SamplingParams
-from tpuserve.runtime.kv_cache import create_kv_cache
 from tpuserve.runtime.scheduler import SchedulerConfig
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:            # ``benchmark`` is a package of the root
-    sys.path.insert(0, ROOT)
-from benchmark.harness import plan  # noqa: E402
-
-ATOL = 2e-4
-MODEL = "tiny-mellum2"
+FAMILY = FAMILIES["mellum2"]
+MODEL = FAMILY.model
 PUBLISHED = "JetBrains/Mellum2-12B-A2.5B-Instruct"
-BLOCK = 4               # KV block size of the hand-driven caches
 
-ref = plan.load_reference({"reference": "mellum2"})
+ref = FAMILY.ref
 
 # the catalog's ``config`` of the model (model-configs guide,
 # architectures.jsonl), verbatim
@@ -84,185 +75,9 @@ def params(cfg):
     return init_params(cfg, seed=11)
 
 
-def prompts_of(*lengths, seed=0):
-    rs = np.random.RandomState(seed)
-    return [[int(t) for t in rs.randint(2, 256, n)] for n in lengths]
-
-
-def ref_logits(params, cfg, seq, positions):
-    """Reference logits after each of ``positions`` of one sequence."""
-    return np.asarray(ref.logits_at(
-        params, cfg, np.asarray([seq], np.int32),
-        [(0, p) for p in positions]))
-
-
-def ref_greedy(params, cfg, prompt, n):
-    seq = list(prompt)
-    for _ in range(n):
-        seq.append(int(np.argmax(ref_logits(params, cfg, seq,
-                                            [len(seq) - 1])[0])))
-    return seq[len(prompt):]
-
-
 # --------------------------------------------------------------------------
 # the trunks, driven by hand: logits against the reference
 # --------------------------------------------------------------------------
-
-class Served:
-    """A paged cache driven by hand: sequence ``i`` owns the blocks
-    ``[i * mb, (i + 1) * mb)``.  ``routed`` sums the routing counts every
-    trunk returns last."""
-
-    mb = 20                                     # blocks a sequence
-
-    def __init__(self, cfg, params, n_seqs, attn_impl="reference"):
-        self.cfg, self.params, self.attn_impl = cfg, params, attn_impl
-        cc = CacheConfig(block_size=BLOCK, num_blocks=n_seqs * self.mb,
-                         max_blocks_per_seq=self.mb, dtype="float32")
-        self.kv = create_kv_cache(cfg, cc)
-        self.tables = np.arange(n_seqs * self.mb, dtype=np.int32).reshape(
-            n_seqs, self.mb)
-        self.routed = np.zeros((cfg.num_experts + 1,), np.int64)
-
-    def _keep(self, res):
-        out, self.kv, moe = res[0], res[1], res[-1]
-        self.routed += np.asarray(moe[0])
-        return out
-
-    def slots(self, i, start, n):
-        t = np.arange(start, start + n)
-        return (self.tables[i, t // BLOCK] * BLOCK + t % BLOCK).astype(
-            np.int32)
-
-    def prefill(self, prompts):
-        B, L = len(prompts), 64
-        tokens = np.zeros((B, L), np.int32)
-        slot_ids = np.full((B, L), PAD_SLOT, np.int32)
-        for i, p in enumerate(prompts):
-            tokens[i, :len(p)] = p
-            slot_ids[i, :len(p)] = self.slots(i, 0, len(p))
-        return np.asarray(self._keep(transformer.prefill(
-            self.params, self.cfg, jnp.asarray(tokens),
-            jnp.asarray([len(p) for p in prompts], jnp.int32),
-            jnp.asarray(slot_ids), self.kv, attn_impl=self.attn_impl)))
-
-    def packed(self, prompts, blk=8):
-        """Several prompts on one flat token axis, each starting on a
-        ``blk``-row boundary, as Engine._pack_ragged lays them out."""
-        starts, cursor = [], 0
-        for p in prompts:
-            starts.append(cursor)
-            cursor += -(-len(p) // blk) * blk
-        T, B = cursor + blk, 4                  # a padding block, a spare row
-        tokens = np.zeros((T,), np.int32)
-        positions = np.zeros((T,), np.int32)
-        slot_ids = np.full((T,), PAD_SLOT, np.int32)
-        row_seq = np.zeros((T,), np.int32)
-        kv_lens, q_lens = np.zeros((B,), np.int32), np.zeros((B,), np.int32)
-        q_starts = np.full((B,), T, np.int32)
-        last_rows = np.zeros((B,), np.int32)
-        tables = np.zeros((B, self.mb), np.int32)
-        blk_seq = np.full((T // blk,), -1, np.int32)
-        for i, (p, s) in enumerate(zip(prompts, starts)):
-            n = len(p)
-            tokens[s:s + n], positions[s:s + n] = p, np.arange(n)
-            slot_ids[s:s + n], row_seq[s:s + n] = self.slots(i, 0, n), i
-            kv_lens[i] = q_lens[i] = n
-            q_starts[i], last_rows[i] = s, s + n - 1
-            tables[i] = self.tables[i]
-            blk_seq[s // blk:(s + -(-n // blk) * blk) // blk] = i
-        logits = self._keep(transformer.forward_ragged(
-            self.params, self.cfg, *map(jnp.asarray, (
-                tokens, positions, slot_ids, row_seq, tables, kv_lens,
-                q_starts, q_lens, np.zeros((2,), np.int32), blk_seq,
-                last_rows)), self.kv, ragged_blk=blk,
-            attn_impl=self.attn_impl, decode_rows=False))
-        return np.asarray(logits)[:len(prompts)]
-
-    def chunks(self, prompt, C=16):
-        """One prompt, ``C`` rows a dispatch; the logits after each."""
-        out = []
-        for done in range(0, len(prompt), C):
-            part = prompt[done:done + C]
-            tokens = np.zeros((1, C), np.int32)
-            tokens[0, :len(part)] = part
-            slot_ids = np.full((1, C), PAD_SLOT, np.int32)
-            slot_ids[0, :len(part)] = self.slots(0, done, len(part))
-            out.append(np.asarray(self._keep(transformer.prefill_chunk(
-                self.params, self.cfg, jnp.asarray(tokens),
-                jnp.asarray([done], jnp.int32),
-                jnp.asarray([len(part)], jnp.int32), jnp.asarray(slot_ids),
-                jnp.asarray(self.tables[:1]), self.kv,
-                attn_impl=self.attn_impl)))[0])
-        return out
-
-    def decode(self, seqs):
-        """One token a row: ``seqs[i]`` ends in the token to decode."""
-        B = len(seqs)
-        n = np.asarray([len(s) for s in seqs], np.int32)
-        return np.asarray(self._keep(transformer.decode_step(
-            self.params, self.cfg,
-            jnp.asarray([s[-1] for s in seqs], jnp.int32),
-            jnp.asarray(n - 1),
-            jnp.asarray([self.slots(i, n[i] - 1, 1)[0] for i in range(B)]),
-            jnp.asarray(self.tables[:B]), jnp.asarray(n), self.kv,
-            attn_impl=self.attn_impl)))
-
-    def window(self, seqs, steps):
-        """A fused greedy window with one padding row: tokens and the
-        chosen tokens' log-probabilities, (B, steps) each."""
-        B = len(seqs) + 1
-        n = np.ones((B,), np.int32)
-        n[:len(seqs)] = [len(s) for s in seqs]
-        tokens = np.zeros((B,), np.int32)
-        tokens[:len(seqs)] = [s[-1] for s in seqs]
-        tables = np.zeros((B, self.mb), np.int32)
-        tables[:len(seqs)] = self.tables[:len(seqs)]
-        active = np.arange(B) < len(seqs)
-        toks, self.kv, lp, moe = transformer.decode_multi(
-            self.params, self.cfg, jnp.asarray(tokens), jnp.asarray(n - 1),
-            jnp.asarray(tables), jnp.asarray(n), jnp.asarray(active),
-            jnp.zeros((B, 2), jnp.uint32), jnp.zeros((B,), jnp.float32),
-            self.kv, steps=steps, mode="greedy", logprobs_n=1,
-            attn_impl=self.attn_impl)
-        # the rows' picks ride fourth with the logprobs, [row, step]
-        assert lp[3].shape == (B, steps, self.cfg.num_layers,
-                               self.cfg.num_experts_per_tok)
-        assert moe[1:] == (None, None)
-        moe = np.asarray(moe[0])
-        # B rows, k picks, every layer, every fused step
-        assert moe[:-1].sum() == (B * self.cfg.num_experts_per_tok
-                                  * self.cfg.num_layers * steps)
-        assert 0 < moe[-1] <= (self.cfg.num_experts * self.cfg.num_layers
-                               * steps)
-        return np.asarray(toks)[:len(seqs)], np.asarray(lp[0])[:len(seqs)]
-
-
-def then_decode(served, params, cfg, seqs, first_logits):
-    """After any prefill route: its logits, three decode steps and a fused
-    window of four, each against the reference's full forward."""
-    seqs = [list(s) for s in seqs]
-    for i, s in enumerate(seqs):
-        np.testing.assert_allclose(
-            first_logits[i], ref_logits(params, cfg, s, [len(s) - 1])[0],
-            atol=ATOL)
-        s.append(int(np.argmax(first_logits[i])))
-    for _ in range(3):
-        logits = served.decode(seqs)
-        for i, s in enumerate(seqs):
-            np.testing.assert_allclose(
-                logits[i], ref_logits(params, cfg, s, [len(s) - 1])[0],
-                atol=ATOL)
-            s.append(int(np.argmax(logits[i])))
-    toks, lps = served.window(seqs, 4)
-    for i, s in enumerate(seqs):
-        assert list(toks[i]) == ref_greedy(params, cfg, s, 4)
-        full = s + list(toks[i])
-        rows = np.asarray(jax.nn.log_softmax(ref_logits(
-            params, cfg, full, range(len(s) - 1, len(full) - 1))))
-        np.testing.assert_allclose(
-            lps[i], rows[np.arange(4), toks[i]], atol=ATOL)
-
 
 @pytest.mark.parametrize("attn_impl", ["reference", "pallas"])
 @pytest.mark.parametrize("route", ["prefill", "packed", "chunks"])
@@ -274,33 +89,19 @@ def test_every_route_matches_the_reference_across_the_window(
     crosses the window of 16 while it decodes.  ``pallas``: the paged
     attention kernels in interpret mode (the grouped product is a kernel
     on both)."""
-    if route == "chunks":
-        seqs = prompts_of(48)
-        served = Served(cfg, params, 1, attn_impl)
-        per_chunk = served.chunks(seqs[0])
-        for logits, upto in zip(per_chunk, (16, 32, 48)):
-            np.testing.assert_allclose(
-                logits, ref_logits(params, cfg, seqs[0], [upto - 1])[0],
-                atol=ATOL)
-        first = [per_chunk[-1]]
-    else:
-        seqs = prompts_of(48, 14, 29)
-        served = Served(cfg, params, 3, attn_impl)
-        first = served.prefill(seqs) if route == "prefill" \
-            else served.packed(seqs)
-    then_decode(served, params, cfg, seqs, first)
+    served = run_route(FAMILY, cfg, params, route, attn_impl)
     # every dispatch counted k rows a slot a layer, padding included
-    assert served.routed[:-1].sum() % (cfg.num_experts_per_tok
+    assert served.counts[:-1].sum() % (cfg.num_experts_per_tok
                                        * cfg.num_layers) == 0
-    assert served.routed[-1] > 0
+    assert served.counts[-1] > 0
 
 
 def test_both_layer_kinds_and_their_tables_are_live(cfg, params):
     """A window ignored, or the full layers' table taken for the plain
     one, moves the reference's logits at a position past the window by far
-    more than ATOL: the agreement above is not vacuous."""
+    more than the tolerance: the agreement above is not vacuous."""
     seq = prompts_of(40, seed=3)[0]
-    want = ref_logits(params, cfg, seq, [39])[0]
+    want = ref_logits(FAMILY, params, cfg, seq, [39])[0]
     all_full = dataclasses.replace(cfg, window_layers=(False,) * 8)
     plain = dataclasses.replace(cfg, rope_full_yarn=(1.0000001, 32, 1, 32))
     for broken in (all_full, plain):
@@ -352,25 +153,18 @@ def test_the_reference_replays_a_named_pick_only_at_a_near_tie(cfg, params):
 # the engine
 # --------------------------------------------------------------------------
 
-def engine_for(params, cfg, **kw):
-    return Engine(EngineConfig(
-        model=MODEL, attn_impl=kw.pop("attn_impl", "reference"),
-        cache=CacheConfig(block_size=BLOCK, num_blocks=96,
-                          max_blocks_per_seq=24, dtype="float32"),
-        scheduler=SchedulerConfig(min_prefill_bucket=8, min_decode_bucket=2),
-        **kw), params=params, model_cfg=cfg)
-
 
 @pytest.mark.parametrize("multi_step,attn_impl", [
     (1, "reference"), (4, "reference"), (4, "pallas")])
 def test_served_greedy_tokens_are_the_references(cfg, params, multi_step,
                                                  attn_impl):
-    eng = engine_for(params, cfg, multi_step=multi_step, attn_impl=attn_impl)
+    eng = engine_for(FAMILY, params, cfg, multi_step=multi_step,
+                     attn_impl=attn_impl)
     prompts = prompts_of(40, 9, seed=5)
     outs = eng.generate(prompts, SamplingParams(
         max_tokens=10, temperature=0.0, ignore_eos=True))
     for p, o in zip(prompts, outs):
-        assert o.output_token_ids == ref_greedy(params, cfg, p, 10)
+        assert o.output_token_ids == ref_greedy(FAMILY, params, cfg, p, 10)
     assert eng.block_manager.num_seqs() == 0
 
 
@@ -379,7 +173,7 @@ def test_routing_counts_come_back_with_the_tokens(cfg, params):
     ``moe_expert_hits``; the engine's totals are their sums; nothing is
     left in flight once the engine is drained; a model without experts
     has none of it."""
-    eng = engine_for(params, cfg, multi_step=4)
+    eng = engine_for(FAMILY, params, cfg, multi_step=4)
     eng.generate(prompts_of(21, 6, seed=9), SamplingParams(
         max_tokens=9, temperature=0.0, ignore_eos=True))
     steps = [s for s in eng.flight.steps_snapshot(limit=1 << 20)
@@ -486,7 +280,7 @@ def test_window_dead_tokens_are_what_no_step_will_read(cfg, params):
     behind its sequence's end: 6 of the 8 layers are windowed, so a
     sequence of n tokens holds 6 * max(0, n - 16 - BLOCK) of them; layers
     of two kinds release nothing, so they stay held."""
-    eng = engine_for(params, cfg, multi_step=4)
+    eng = engine_for(FAMILY, params, cfg, multi_step=4)
     assert eng.window_dead_tokens() == 0
     eng.add_request("a", prompts_of(40, seed=2)[0], SamplingParams(
         max_tokens=6, temperature=0.0, ignore_eos=True))

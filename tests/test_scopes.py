@@ -6,6 +6,7 @@ changes no program's name.  (What the chip's compiler makes of them is
 ``tests/benchmark/test_benchmark_scope_trace.py``.)"""
 
 import dataclasses
+import hashlib
 import re
 
 import jax
@@ -46,6 +47,8 @@ def family_config(model: str):
     cfg = get_model_config(name)
     return dataclasses.replace(
         cfg, moe_experts_held=cfg.num_experts // 4) if share else cfg
+
+
 # program -> (its phase, the parts only it has)
 PROGRAMS = {
     "decode_multi": (scopes.DECODE, {scopes.SAMPLE, scopes.CARRY}),
@@ -156,3 +159,85 @@ def test_the_table_is_one_place():
         ["grep", "-rnE", r"named_scope\([\"']", os.path.join(root, "tpuserve"),
          "--include=*.py"], capture_output=True, text=True).stdout
     assert out == "", out
+
+
+# --------------------------------------------------------------------------
+# the accepted families' trunks are the programs they were
+# --------------------------------------------------------------------------
+
+# sha256 (first 16 hex digits) of each trunk's lowered text, operation
+# names included and source lines left out (as the compile cache keys a
+# program: tpuserve/utils/compile_cache.py) for the tiny model of each
+# accepted configuration's family.  A layer's kind is a static branch of
+# the layer bodies, so a model without linear layers must lower to the text
+# it had: a scope renamed, an operation moved or added in a shared helper
+# shows here before it costs the accepted cells a cold compile (or their
+# speed) on the chip.  A PR that MEANS to change a trunk replaces the pins
+# it changes: first taken from the commit before this model (efe1553), all
+# replaced by PR 44, which put every trunk's per-layer body under its own
+# ``jax.jit`` (one private function a kind of layer in each module) and
+# rounds each half of a rotated vector where it is made (ops/rope.py).
+LOWERED = {
+    "tiny-qwen3": {
+        ("pallas", "decode_multi"): "816a20b23bf8ff09",
+        ("pallas", "forward_ragged"): "4f7735134122b74d",
+        ("pallas", "prefill_chunk"): "0fe020fc804219d1",
+        ("reference", "decode_multi"): "9a87c60d8a2dbf74",
+        ("reference", "forward_ragged"): "4514f95a67f44bbb",
+        ("reference", "prefill_chunk"): "c5e20e1507e0601d",
+    },
+    "tiny-mistral": {
+        ("pallas", "decode_multi"): "da80b79a288c3ec2",
+        ("pallas", "forward_ragged"): "e793e870bfd7edca",
+        ("pallas", "prefill_chunk"): "070480809ba7171d",
+        ("reference", "decode_multi"): "cb49c1759a45a69d",
+        ("reference", "forward_ragged"): "ea7b0f5c2ce6e69b",
+        ("reference", "prefill_chunk"): "9c27a385d9768d22",
+    },
+    # (PR 46: the convolution's memory as whole lane tiles, stepped in
+    # place by its own kernel: every trunk of this family means to change)
+    "tiny-falcon-h1": {
+        ("pallas", "decode_multi"): "b7e68e5c59b9cb6e",
+        ("pallas", "forward_ragged"): "734831dffba86e08",
+        ("pallas", "prefill_chunk"): "3617877d2c533f38",
+        ("reference", "decode_multi"): "21d3d3d5e21c4bca",
+        ("reference", "forward_ragged"): "ecdf47368374148d",
+        ("reference", "prefill_chunk"): "c787ab1df7673b9a",
+    },
+    "tiny-mellum2": {
+        ("pallas", "decode_multi"): "885b81fb59a5e229",
+        ("pallas", "forward_ragged"): "840cda028cc82f7d",
+        ("pallas", "prefill_chunk"): "0441359e89149443",
+        ("reference", "decode_multi"): "3bf7981e3b6915ec",
+        ("reference", "forward_ragged"): "e4df6a5e4658e49b",
+        ("reference", "prefill_chunk"): "ebf7b47fe0a4f1a0",
+    },
+    "tiny-k-exaone+share": {
+        ("pallas", "decode_multi"): "858b4baaa33a367e",
+        ("pallas", "forward_ragged"): "c922e55edf29688d",
+        ("pallas", "prefill_chunk"): "6711998584e1329f",
+        ("reference", "decode_multi"): "2bd76c32b7874656",
+        ("reference", "forward_ragged"): "925f32cdc424f297",
+        ("reference", "prefill_chunk"): "1e18c77450bd2dec",
+    },
+}
+
+
+@pytest.mark.parametrize("model", sorted(LOWERED))
+def test_the_accepted_trunks_lower_to_the_text_they_had(model):
+    was = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    # a trunk this module lowered before holds its locations as they were
+    # made: the text's hash depends on the limit set above
+    jax.clear_caches()
+    try:
+        got = {}
+        for attn_impl in ("reference", "pallas"):
+            for program, (fn, args, kwargs) in trunk_programs(
+                    family_config(model), attn_impl=attn_impl).items():
+                text = fn.lower(*args, **kwargs).as_text(debug_info=True)
+                got[attn_impl, program] = hashlib.sha256(
+                    text.encode()).hexdigest()[:16]
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", was)
+    assert got == LOWERED[model]

@@ -283,10 +283,6 @@ DEFAULT_CONFIG: dict = {
         # The reason string is the documentation.
         "env_debug_only": {
             "TPUSERVE_HBM_BYTES": "test HBM budget override",
-            "TPUSERVE_VMEM_BUDGET_MB": "kernel tuning",
-            "TPUSERVE_RAGGED_BLOCK": "kernel tuning",
-            "TPUSERVE_FLASH_BLK_Q": "kernel tuning",
-            "TPUSERVE_FLASH_BLK_K": "kernel tuning",
             "TPUSERVE_FSM_MAX_STATES": "grammar-compile guard rail",
             "TPUSERVE_FSM_MAX_WALK_CHARS": "grammar-compile guard rail",
             "TPUSERVE_FSM_JSON_DEPTH": "grammar-compile guard rail",

@@ -504,8 +504,8 @@ def _gather_rows(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
       the packed-prefill ladder: 1,536 tokens into 12,288 rows for the
       grouped product's custom call, where memory-space assignment puts
       operand and result in VMEM and the gather then lacks 0.4 MB of
-      scoped VMEM (tests/test_chip_compile.py holds both facts, and that
-      what is chosen here compiles at every rung)."""
+      scoped VMEM (tests/test_chip_compile_experts.py holds both facts,
+      and that what is chosen here compiles at every rung)."""
     rows, width = idx.shape[0], x.shape[-1]
     with jax.named_scope(scopes.MOE_GATHER):
         if _gathers_plain(rows, x.shape[0], width):
@@ -1282,7 +1282,8 @@ def _moe_routing(tally: list | None, rows: jnp.ndarray | None):
 # under no phase is time gone from ``trunk.decode_glue_ms``.  (What that
 # compiler makes itself inside a called function it names after the CALL,
 # ``.../jit(_decode_layer)``: a phase and no part, where in a flat module
-# it carries no name at all.  tests/test_chip_compile.py holds both.)
+# it carries no name at all.  tests/test_chip_compile_programs.py holds
+# both.)
 #
 # A cache trunk's body takes and returns its layer's entry of the paged
 # cache and of the seat pool (None where the layer holds none) and returns

@@ -49,9 +49,8 @@ lanes.  A prefill reshapes from and to it (models/transformer.py
 The taps are summed in float32 in the order ``causal_conv`` sums them and
 the new row is rounded to the pool's dtype as ``rows[:, 1:].astype(...)``
 rounds it: bit for bit :func:`conv_tail_step_reference` in interpret mode
-(tests/test_olmo_hybrid.py, tests/test_falcon_h1.py).  The custom call is
-named ``_conv_tail_step``; compiled for the chip in
-tests/test_chip_compile.py.
+(tests/test_seat_pool.py).  The custom call is named ``_conv_tail_step``;
+compiled for the chip in tests/test_chip_compile_recurrent.py.
 """
 
 from __future__ import annotations

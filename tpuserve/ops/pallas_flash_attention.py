@@ -104,8 +104,7 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 
 def flash_prefill_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                             prompt_lens: jnp.ndarray, scale: float,
-                            blk_q: int | None = None,
-                            blk_k: int | None = None,
+                            blk_q: int = 128, blk_k: int = 128,
                             interpret: bool | None = None,
                             sliding_window: int | None = None,
                             logit_softcap: float | None = None) -> jnp.ndarray:
@@ -115,18 +114,9 @@ def flash_prefill_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     attend to the valid keys (same as the reference impl) — the engine only
     reads the row at prompt_len - 1, so their values are never consumed.
 
-    ``TPUSERVE_FLASH_BLK_Q``/``_K`` fill the block split when the caller
-    leaves the default (sweepable on silicon — prefill bounds TTFT); an
-    explicit argument always wins so tests pin their shapes.  The env is
-    read per PROCESS: serving jits this inside the engine's prefill
-    executable, so changing it mid-process is ignored — a sweep needs
-    a fresh process per value."""
+    ``blk_q`` / ``blk_k``: the block split, 128 rows a side in every cell;
+    tests pin smaller shapes."""
     with jax.named_scope(scopes.ATTN_KERNEL):
-        import os
-        if blk_q is None:
-            blk_q = int(os.environ.get("TPUSERVE_FLASH_BLK_Q") or 128)
-        if blk_k is None:
-            blk_k = int(os.environ.get("TPUSERVE_FLASH_BLK_K") or 128)
         return _flash_prefill_attention(q, k, v, prompt_lens, scale=scale,
                                         blk_q=blk_q, blk_k=blk_k,
                                         interpret=interpret,

@@ -37,7 +37,7 @@ The custom call is named ``_gdn_state_update``: the benchmark's trace
 readers match it (``benchmark/layer_metrics/lin.*``).  Verified against
 :func:`gdn_state_update_reference` in interpret mode
 (tests/test_olmo_hybrid.py) and compiled for the chip in
-tests/test_chip_compile.py.
+tests/test_chip_compile_recurrent.py.
 """
 
 from __future__ import annotations

@@ -33,7 +33,7 @@ trace time.
 The custom call is named ``_moe_grouped_matmul``: the benchmark's trace
 readers match it (``benchmark/layer_metrics/moe.*``).  Verified against
 :func:`grouped_matmul_reference` in interpret mode (tests/test_moe.py)
-and compiled for the chip in tests/test_chip_compile.py.
+and compiled for the chip in tests/test_chip_compile_experts.py.
 """
 
 from __future__ import annotations

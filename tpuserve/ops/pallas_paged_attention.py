@@ -54,7 +54,6 @@ from __future__ import annotations
 
 import functools
 import logging
-import os
 
 import jax
 import jax.numpy as jnp
@@ -79,8 +78,8 @@ NEG_INF = -1e30
 # not say what it needs gets the compiler's 16 MiB default scope — which
 # the chunk-window and ragged kernels overflow at Qwen3-0.6B widths
 # through their f32 score tiles (tests/test_chip_compile.py pins the
-# compiles).  Env-overridable for sweeps that probe the cliff.
-VMEM_LIMIT_BYTES = int(os.environ.get("TPUSERVE_VMEM_BUDGET_MB", "32")) * 2**20
+# compiles).
+VMEM_LIMIT_BYTES = 32 * 2**20
 
 MIN_SUBLANES = {1: 32, 2: 16, 4: 8}   # Mosaic min tile rows by itemsize
 

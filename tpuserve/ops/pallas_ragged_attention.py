@@ -34,7 +34,6 @@ parity gates without a chip.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -69,17 +68,6 @@ def ragged_block(blk_q: int | None = None) -> int:
     host-side packing — drift would desync ``blk_seq`` from the grid."""
     if blk_q:
         return blk_q
-    env = os.environ.get("TPUSERVE_RAGGED_BLOCK")
-    if env:
-        n = int(env)
-        if n < 1 or n & (n - 1):
-            # the engine buckets T to powers of two; a non-power-of-two
-            # block would make T % blk != 0 and fail the layout check on
-            # the first mixed step — reject at startup instead
-            raise ValueError(
-                f"TPUSERVE_RAGGED_BLOCK={env} must be a power of two "
-                "(the flat-token bucket ladder is power-of-two)")
-        return n
     return DEFAULT_BLOCK_Q if jax.default_backend() == "tpu" else 8
 
 
@@ -90,11 +78,8 @@ def ragged_block_for(num_q_heads: int, num_kv_heads: int, head_dim: int,
     q and out blocks and its live tiles fit the VMEM budget beside ONE
     page of K and V (the page group may shrink inside the kernel, the
     block may not: it is the layout contract with the host packing).  128
-    rows at up to 32 query heads of 128; 64 at 64 heads on 8 KV heads.
-    An explicit ``TPUSERVE_RAGGED_BLOCK`` stands as it is."""
+    rows at up to 32 query heads of 128; 64 at 64 heads on 8 KV heads."""
     blk = ragged_block()
-    if os.environ.get("TPUSERVE_RAGGED_BLOCK"):
-        return blk
     while blk > 8 and vmem_footprint(
             1, blk, blk, page_size, num_kv_heads, head_dim, kv_itemsize,
             num_q_heads, q_itemsize, quantized) > VMEM_LIMIT_BYTES:
@@ -421,9 +406,8 @@ def ragged_paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         if blk_clamped != blk:
             raise ValueError(
                 f"ragged block {blk} needs more VMEM than the budget allows "
-                f"for this model shape (Hq={Hq}, D={D}); set "
-                f"TPUSERVE_RAGGED_BLOCK={blk_clamped} (power of two) so the "
-                "engine packs the flat stream at a size that fits")
+                f"for this model shape (Hq={Hq}, D={D}); pack the flat "
+                f"stream at ragged_block_for's {blk_clamped} rows, which fit")
 
         quantized = k_scale is not None
         kernel = functools.partial(

@@ -23,7 +23,7 @@ The custom call is named ``_ssm_state_update``: the benchmark's trace
 readers match it (``benchmark/layer_metrics/ssm.*``).  Verified against
 :func:`ssm_state_update_reference` in interpret mode
 (tests/test_falcon_h1.py) and compiled for the chip in
-tests/test_chip_compile.py.
+tests/test_chip_compile_recurrent.py.
 """
 
 from __future__ import annotations

@@ -24,8 +24,8 @@ under the name it has inside the called function, without the call's
 prefix.  And an operation that compiler makes itself inside a called
 function is named after the call alone (``.../jit(_decode_layer)``: its
 phase, no part; the reader counts it under ``trunk.unscoped_device_share``),
-where in a flat module it carries no name: tests/test_chip_compile.py holds
-how few there are.
+where in a flat module it carries no name:
+tests/test_chip_compile_programs.py holds how few there are.
 
 Read by ``benchmark/layer_metrics/_scope_trace.py``, which holds its own
 copy of these names as a benchmark holds a kernel's (it also has to read
