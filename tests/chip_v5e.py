@@ -5,7 +5,7 @@ published configurations more than one of those files cuts.
 Not a test file.  The topology is described inside a fixture, by whichever
 xdist worker is given a file that uses it: ``tests/conftest.py`` lets several
 processes load the TPU's library at once (``ALLOW_MULTIPLE_LIBTPU_LOAD``), so
-the five files run on as many workers.  A file imports the fixtures it uses
+the six files run on as many workers.  A file imports the fixtures it uses
 by name.
 """
 
@@ -36,6 +36,10 @@ WIDTHS = {
     # have 4 to 8 KV heads of 2 to 8 query heads each
     "olmo-hybrid-7b": (32, 32, 128),
 }
+# latent attention (openPangu-Ultra-MoE-718B, DeepSeek-V3): 128 query heads
+# on ONE cached vector of 512 + 64 values a token, stored as 640 lanes; V is
+# the first 512 lanes of the K page (tests/test_chip_compile_latent.py)
+LATENT = (128, 640, 512)
 PAGE = 32            # server default --block-size
 NUM_BLOCKS = 2048    # server default --num-blocks
 MAX_PAGES = 128      # 4096-token sequences
@@ -103,4 +107,13 @@ def k_exaone_share(**cut):
     from tpuserve.models.config import get_model_config
     return dataclasses.replace(
         get_model_config("LGAI-EXAONE/K-EXAONE-236B-A23B"),
+        moe_experts_held=16, vocab_size=19200, **cut)
+
+
+def openpangu_share(**cut):
+    """openPangu-Ultra-MoE-718B's share of the benchmark's cell: 16 of 256
+    experts, an eighth of the vocabulary."""
+    from tpuserve.models.config import get_model_config
+    return dataclasses.replace(
+        get_model_config("FreedomIntelligence/openPangu-Ultra-MoE-718B"),
         moe_experts_held=16, vocab_size=19200, **cut)

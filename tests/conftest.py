@@ -14,7 +14,7 @@ import os
 import time
 
 os.environ["JAX_PLATFORMS"] = "cpu"
-# the AOT compiles for the chip (tests/test_chip_compile*.py) are five files
+# the AOT compiles for the chip (tests/test_chip_compile*.py) are six files
 # on as many xdist workers, each of which loads the TPU's library to describe
 # a v5e; no test attaches a chip, so the lock that keeps two processes off one
 # chip guards nothing here

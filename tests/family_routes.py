@@ -90,6 +90,10 @@ FAMILIES = {
                         (5, 19, 12), 43, _SEATED),
     "olmo_hybrid": Family("tiny-olmo-hybrid", "olmo_hybrid", 5e-4, "pool",
                           (5, 19, 12), 43, _SEATED),
+    # latent attention: none a multiple of a ragged block of 8; the prompt
+    # of 40 is two and a half chunks of 16 (a chunk against cached latents)
+    "openpangu": Family("tiny-pangu", "openpangu_moe", 2e-4, "counts",
+                        (40, 6, 29), 40, _EXPERTS),
 }
 
 

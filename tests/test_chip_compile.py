@@ -9,9 +9,9 @@ flagship; Llama-3.1-8B, the registered model that needs tp) and at the
 sizes the engine dispatches, with ``interpret=False``.  Nothing runs: a
 compile that passes says nothing about results or times.
 
-The AOT compiles are five files by what they compile (this one: the
+The AOT compiles are six files by what they compile (this one: the
 attention kernels and the page writer; ``_experts``, ``_recurrent``,
-``_cells``, ``_programs``), on as many xdist workers: ``tests/chip_v5e.py``
+``_cells``, ``_programs``, ``_latent``), on as many xdist workers: ``tests/chip_v5e.py``
 holds what they share.
 """
 

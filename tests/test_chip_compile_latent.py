@@ -1,0 +1,135 @@
+"""Latent attention on the chip's compiler: the paged kernels' latent entry
+at the published widths (128 query heads on one cached vector of 512 + 64
+values a token, stored as 640 lanes) and the openPangu-Ultra-MoE cell's
+three served trunks, compiled for a described v5e (see
+``tests/test_chip_compile.py`` and ``tests/chip_v5e.py``)."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from chip_v5e import (CHUNK, LATENT, MAX_PAGES, PAGE, PREFILL_SEQS,
+                      openpangu_share, shapes_on)
+from chip_v5e import (  # noqa: F401  (fixtures, found by name)
+    _no_persistent_cache, one_chip, topo)
+
+ROWS = 128              # the cell's decode seats (--max-num-seqs 128)
+# pages of 32 tokens the server sizes (--num-blocks 0): 0.9 of the chip's
+# 15.75 GiB less 12.32 GB of weights, over 286,720 B a page: 2.90 GB
+POOL = 10104
+SCALE = 192 ** -0.5
+
+
+def _decode_call(one_chip, lanes):
+    from tpuserve.ops.pallas_paged_attention import paged_decode_attention
+    S, _ = shapes_on(one_chip)
+    hq, _, v_lanes = LATENT
+
+    def call(q, pages, tables, lens):
+        return paged_decode_attention(q, pages, None, tables, lens, SCALE,
+                                      interpret=False, v_lanes=v_lanes)
+    return jax.jit(call).lower(
+        S((ROWS, hq, lanes), jnp.bfloat16),
+        S((POOL, PAGE, 1, lanes), jnp.bfloat16),
+        S((ROWS, MAX_PAGES), jnp.int32), S((ROWS,), jnp.int32))
+
+
+def test_the_latent_decode_call_compiles_under_its_name(one_chip):
+    """128 rows of 128 query heads against one 640-lane head: the call is
+    the decode kernel's (the benchmark's readers count fused steps by its
+    name), the pool reaches it as a bitcast and never as a copy, and what
+    comes back is 512 lanes wide: V was read off the landed K page."""
+    hq, lanes, v_lanes = LATENT
+    text = _decode_call(one_chip, lanes).compile().as_text()
+    assert re.search(rf"%_paged_decode_attention(?:\.\d+)? = bf16"
+                     rf"\[{ROWS},{hq},{v_lanes}\]", text)
+    assert re.search(rf"bf16\[{POOL},{PAGE},{lanes}\]\S* bitcast\(", text)
+    assert not re.search(rf"bf16\[{POOL},\S* copy\(", text)
+
+
+def test_a_page_of_576_lanes_as_it_is_has_no_whole_tiles_to_copy(one_chip):
+    """Why the page is stored as 640 lanes: the chip lays a 576-wide row
+    out in five 128-lane tiles whatever its shape says (the HBM array IS
+    640 wide), and the kernel's copy of a page may take whole tiles only:
+    the compiler refuses the slice (step 0 of PR 50, PERF.md section 6)."""
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _decode_call(one_chip, 576).compile()
+
+
+def test_the_latent_ragged_kernel_compiles_at_its_block(one_chip,
+                                                        monkeypatch):
+    """The packed prefill's kernel at the top rung of its ladder: 128
+    query heads of a 640-lane latent take a ragged block of 8 rows (1,024
+    query rows a dot; 16 pass the kernel's fast memory), one KV head's
+    pages land as (page, 640) with no head row to pad, and the group of
+    pages stays 16 deep."""
+    from tpuserve.ops import pallas_ragged_attention as ragged
+    from tpuserve.ops.pallas_paged_attention import _clamp_to_vmem_budget
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    S, _ = shapes_on(one_chip)
+    hq, lanes, v_lanes = LATENT
+    blk = ragged.ragged_block_for(hq, 1, lanes, PAGE, 2, 2)
+    assert blk == 8
+    assert _clamp_to_vmem_budget(16, blk, PAGE, 1, lanes, 2, hq, 2,
+                                 rows_per_dot=True, flat_page=True) \
+        == (16, blk)
+    tokens, seqs = 8192, S((PREFILL_SEQS,), jnp.int32)
+
+    def call(q, pages, tables, kv, qs, ql, meta, blocks):
+        return ragged.ragged_paged_attention(
+            q, pages, None, tables, kv, qs, ql, meta, blocks, SCALE,
+            interpret=False, blk_q=blk, decode_rows=False, v_lanes=v_lanes)
+    text = jax.jit(call).lower(
+        S((tokens, hq, lanes), jnp.bfloat16),
+        S((POOL, PAGE, 1, lanes), jnp.bfloat16),
+        S((PREFILL_SEQS, MAX_PAGES), jnp.int32), seqs, seqs, seqs,
+        S((2,), jnp.int32), S((tokens // blk,), jnp.int32)
+    ).compile().as_text()
+    assert re.search(rf"%_ragged_paged_attention(?:\.\d+)? = bf16"
+                     rf"\[{tokens},{hq},{v_lanes}\]", text)
+    assert not re.search(rf"bf16\[{POOL},\S* copy\(", text)
+
+
+@pytest.mark.parametrize("program,tokens", [
+    ("decode_multi", 0), ("forward_ragged", 8192), ("prefill_chunk", 0)])
+def test_the_openpangu_cell_fits_the_chip(program, tokens, one_chip,
+                                          monkeypatch):
+    """The cell's whole trunks at the published widths: the first 7 layers
+    (3 dense, 4 of experts), 16 of 256 experts, 19,200 vocabulary rows; a
+    fused decode window of 128 rows, the top rung of the packed-prefill
+    ladder and a chunk against cached latents, beside a pool of 10,104
+    latent pages (2.90 GB: what 0.9 of the chip leaves after 12.32 GB of
+    weights).
+    The chip's compiler refuses what does not fit 16 GB.  The pool is
+    aliased in and out WHOLE and EXACTLY (2 B x 640 lanes x 32 tokens x 7
+    layers a page, no V pages, no copy), every attention call of the three
+    programs is a Pallas kernel's latent entry, and decode's keeps the
+    name the benchmark's readers count steps by, once a layer."""
+    from test_scopes import trunk_programs
+    from tpuserve.ops.pallas_ragged_attention import ragged_block_for
+
+    S, place = shapes_on(one_chip)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = openpangu_share(num_layers=7)
+    blk = ragged_block_for(cfg.cache_q_heads, cfg.cache_kv_heads,
+                           cfg.cache_head_dim, PAGE, 2, 2)
+    assert (blk, cfg.cache_head_dim) == (8, 640)
+    fn, args, kwargs = trunk_programs(
+        cfg, S, place, rows=ROWS, steps=8, tokens=tokens or blk, blk=blk,
+        prompts=PREFILL_SEQS, chunk=CHUNK, block_size=PAGE, num_blocks=POOL,
+        max_blocks=MAX_PAGES, attn_impl="pallas")[program]
+    compiled = fn.lower(*args, **kwargs).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == POOL * PAGE * 640 * 2 * 7
+    weights = mem.argument_size_in_bytes - mem.alias_size_in_bytes
+    assert 12.3e9 < weights < 12.4e9, weights
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.9e9
+    text = compiled.as_text()
+    kernel = {"decode_multi": "_paged_decode_attention"}.get(
+        program, "_ragged_paged_attention")
+    assert len(re.findall(rf"%{kernel}(?:\.\d+)? = ", text)) == 7
+    # no score tensor of XLA's reference attention: nothing float32 is as
+    # large as rows x heads x context would be
+    assert not re.search(r"f32\[\d+,128,\d{4,}\]", text)

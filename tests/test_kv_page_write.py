@@ -148,11 +148,48 @@ def test_the_static_gates_choose_the_write(gate):
     assert paged or "scatter" in text
 
 
-def test_the_gate_reads_the_entry():
-    plain = {"k": jnp.zeros((4, 8, 2, 16)), "v": jnp.zeros((4, 8, 2, 16))}
-    assert kv_stream_by_page(plain, 16, "pallas")
-    assert not kv_stream_by_page(plain, 12, "pallas")
-    assert not kv_stream_by_page(plain, 16, "reference")
-    assert not kv_stream_by_page(plain, 16, "pallas", mesh=object())
-    assert not kv_stream_by_page({**plain, "ks": 0, "vs": 0}, 16, "pallas")
-    assert not kv_stream_by_page({"k": plain["k"]}, 16, "pallas")    # MLA
+_PLAIN = {"k": jnp.zeros((4, 8, 2, 16)), "v": jnp.zeros((4, 8, 2, 16))}
+_LATENT = {"k": jnp.zeros((4, 8, 1, 256))}            # MLA: K pages alone
+
+
+@pytest.mark.parametrize("what,entry,unit,impl,mesh,by_page", [
+    ("whole pages of K and V", _PLAIN, 16, "pallas", None, True),
+    ("whole pages of a latent entry", _LATENT, 16, "pallas", None, True),
+    ("a unit that is no whole page", _PLAIN, 12, "pallas", None, False),
+    ("reference attention", _PLAIN, 16, "reference", None, False),
+    ("a mesh", _PLAIN, 16, "pallas", object(), False),
+    ("int8 pages", {**_PLAIN, "ks": 0, "vs": 0}, 16, "pallas", None, False),
+    ("an int8 latent entry", {**_LATENT, "ks": 0}, 16, "pallas", None,
+     False),
+])
+def test_the_gate_reads_the_entry(what, entry, unit, impl, mesh, by_page):
+    """What streams by page and what is still refused, each by what the
+    entry and the dispatch hold: int8 pages quantize a row at a time
+    (a latent entry's two slice scales too), a mesh has no such kernel."""
+    assert kv_stream_by_page(entry, unit, impl, mesh=mesh) is by_page, what
+
+
+@pytest.mark.parametrize("bs", (16, 32))
+def test_latent_pages_hold_what_the_row_scatter_writes(bs):
+    """A latent entry (K pages alone, one 576-value vector a token stored
+    as 640 lanes) through the page copy: bit for bit the row scatter's on
+    every slot written, zeros on a written page's padding rows and past
+    the latent's own width, and no V page comes back."""
+    from tpuserve.ops.attention import write_mla_entry
+    T, seqs = _stream("padding_between", bs)
+    rng = np.random.default_rng(bs)
+    slots = np.full((T,), PAD_SLOT, np.int32)
+    for first, pos, rows, blocks in seqs:
+        at = pos + np.arange(rows)
+        slots[first:first + rows] = np.asarray(blocks)[at // bs] * bs \
+            + at % bs
+    latent = jnp.asarray(rng.standard_normal((T, 576)), jnp.bfloat16)
+    entry = {"k": jnp.zeros((NUM_BLOCKS, bs, 1, 640), jnp.bfloat16)}
+    by_row = write_mla_entry(entry, latent, jnp.asarray(slots))
+    by_page = write_mla_entry(entry, latent, jnp.asarray(slots),
+                              aligned=True)
+    assert set(by_page) == {"k"}
+    np.testing.assert_array_equal(np.asarray(by_page["k"], np.float32),
+                                  np.asarray(by_row["k"], np.float32))
+    assert float(jnp.abs(by_page["k"][..., 576:]).max()) == 0.0
+    assert float(jnp.abs(by_page["k"]).max()) > 0.0

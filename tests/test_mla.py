@@ -93,14 +93,14 @@ def test_absorbed_decode_matches_naive_prefill_row():
 
 # --------------------------------------------------- engine integration
 
-def _engine(**kw):
+def _engine(mesh=None, cache=None, **kw):
     return Engine(EngineConfig(
         model="tiny-deepseek",
-        cache=CacheConfig(block_size=4, num_blocks=256,
-                          max_blocks_per_seq=64),
+        cache=cache or CacheConfig(block_size=4, num_blocks=256,
+                                   max_blocks_per_seq=64),
         scheduler=SchedulerConfig(max_num_seqs=4, min_prefill_bucket=8,
                                   min_decode_bucket=2,
-                                  max_prefill_tokens=32), **kw))
+                                  max_prefill_tokens=32), **kw), mesh=mesh)
 
 
 def test_engine_decode_multistep_parity():
@@ -182,18 +182,49 @@ def test_disagg_matches_colocated():
     assert d.output_token_ids == c.output_token_ids
 
 
-def test_pallas_request_downgrades_to_reference(monkeypatch, caplog):
-    """MLA cannot run the Pallas kernels: asking for them by name is an
-    error, while "auto" (resolving to pallas as it does on a TPU) serves
+def _tp_mesh():
+    from tpuserve.parallel import MeshConfig, make_mesh
+    return make_mesh(MeshConfig(dp=1, tp=2))
+
+
+@pytest.mark.parametrize("what,kw,why", [
+    ("the model's own dtype on one device", {}, None),
+    ("an int8 latent cache", {"cache_dtype": "int8"}, "slice scales"),
+    ("a mesh", {"mesh": _tp_mesh}, "ONE kv head"),
+])
+def test_pallas_request_downgrades_to_reference(monkeypatch, caplog, what,
+                                                kw, why):
+    """The Pallas kernels read latent pages in the model's own dtype on
+    one device: asked for by name that engine runs them and serves the
+    reference path's tokens.  What they still cannot do (an int8 latent
+    cache, a mesh) is an error when asked for by name, each with its
+    reason, while "auto" (resolving to pallas as it does on a TPU) serves
     on the reference path and says so."""
-    with pytest.raises(ValueError, match="attn_impl='pallas' was requested"):
-        _engine(attn_impl="pallas")
+    kw = dict(kw)
+    cache = CacheConfig(block_size=4, num_blocks=256, max_blocks_per_seq=64,
+                        dtype=kw.pop("cache_dtype", "bfloat16"))
+    if "mesh" in kw:
+        if jax.device_count() < 2:
+            pytest.skip("needs the 8-virtual-device conftest mesh")
+        kw["mesh"] = kw["mesh"]()
+    p = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
+    if why is None:
+        eng = _engine(attn_impl="pallas", cache=cache, **kw)
+        assert eng.attn_impl == "pallas" and eng._packed_prefill
+        want = _engine(attn_impl="reference").generate(["hello world"], p)
+        got = eng.generate(["hello world"], p)
+        assert got[0].output_token_ids == want[0].output_token_ids
+        return
+    with pytest.raises(ValueError, match="attn_impl='pallas' was requested"
+                                         f".*{why}"):
+        _engine(attn_impl="pallas", cache=cache, **kw)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setenv("TPUSERVE_HBM_BYTES", str(1 << 30))
     with caplog.at_level("WARNING", "tpuserve.engine"):
-        eng = _engine(attn_impl="auto", multi_step=1, pipeline_decode=False)
+        eng = _engine(attn_impl="auto", multi_step=1, pipeline_decode=False,
+                      cache=cache, **kw)
     assert eng.attn_impl == "reference"
-    assert any("using reference attention" in r.message
+    assert any("using reference attention" in r.message and why in r.message
                for r in caplog.records)
 
 

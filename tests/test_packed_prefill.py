@@ -268,7 +268,9 @@ def _small_engine(model="tiny-qwen3", cache_dtype="bfloat16", **mesh):
     ({}, True),
     ({"tp": 2}, False),
     ({"pp": 2}, False),
-    ({"model": "tiny-deepseek"}, False),
+    # latent attention packs too since PR 50: the packed route attends
+    # the latent pages it just wrote, in the absorbed form
+    ({"model": "tiny-deepseek"}, True),
     ({"cache_dtype": "int8"}, False),
     # float32 weights on bf16 pages: the (B, L) route attends the fresh
     # f32 K/V, the pages hold them rounded

@@ -36,6 +36,11 @@ FAMILY = {
     "tiny-k-exaone+share": COMMON | {scopes.MOE_ROUTE, scopes.MOE_GATHER,
                                      scopes.MOE_EXPERTS, scopes.MOE_COMBINE,
                                      scopes.MOE_SHARED},
+    # latent attention: its projections, the absorption and the latent's
+    # write each under one of attention's five parts, nothing new
+    "tiny-pangu+share": COMMON | {scopes.MOE_ROUTE, scopes.MOE_GATHER,
+                                  scopes.MOE_EXPERTS, scopes.MOE_COMBINE,
+                                  scopes.MOE_SHARED},
 }
 ALL_PARTS = scopes.PARTS + scopes.LATER_PARTS
 

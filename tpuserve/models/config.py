@@ -523,8 +523,18 @@ class ModelConfig:
 
     @property
     def cache_head_dim(self) -> int:
-        """KV-cache per-head width: MLA stores the latent vector."""
-        return self.mla_latent_dim if self.is_mla else self.head_dim
+        """KV-cache per-head width: MLA stores the latent vector.  More
+        than one lane tile of it that is not whole tiles (576) is stored
+        as whole tiles (640), as :attr:`cache_kv_heads` stores heads: the
+        chip lays a row out in 128-lane tiles either way (a 576-wide
+        array IS 640 wide in HBM), and the paged kernels may copy whole
+        tiles only.  The lanes past ``mla_latent_dim`` hold zeros
+        (``write_mla_entry`` pads the latent, ``_mla_absorb_q`` the
+        query), so they add nothing to a score and no value reads them."""
+        if not self.is_mla:
+            return self.head_dim
+        d = self.mla_latent_dim
+        return d if d <= 128 else -(-d // 128) * 128
 
     @property
     def routes_experts(self) -> bool:
@@ -630,6 +640,8 @@ def config_from_hf_json(name: str, hf: dict) -> ModelConfig:
         return _exaone_moe_config(hf, common)
     if family == "olmo_hybrid":
         return _olmo_hybrid_config(hf, common)
+    if family == "pangu_ultra_moe":
+        return _pangu_ultra_moe_config(hf, common)
     if "opt" in family:
         common["tie_word_embeddings"] = hf.get("tie_word_embeddings", True)
         return ModelConfig(
@@ -985,6 +997,60 @@ def _exaone_moe_config(hf: dict, common: dict) -> ModelConfig:
         raise ValueError(f"exaone_moe sliding_window_pattern {pattern!r}: "
                          f"layer_types repeat {cfg.sliding_window_pattern!r}")
     return cfg
+
+
+def _pangu_ultra_moe_config(hf: dict, common: dict) -> ModelConfig:
+    """openPangu-Ultra-MoE (``model_type`` ``pangu_ultra_moe``): latent
+    attention at DeepSeek-V3's sizes (a query latent, one cached
+    ``kv_lora_rank + qk_rope_head_dim`` vector a token, the plain rotary
+    table over the rope features) under SANDWICH norms (``sandwich_norm``:
+    a norm on each branch's output before its add, beside the two
+    pre-norms), ``first_k_dense_replace`` dense layers and then routed
+    experts beside shared ones.  ``config.json`` names no scoring
+    function, expert groups or selection bias: the router is the sigmoid
+    recipe its ``norm_topk_prob`` and ``routed_scaling_factor`` belong to,
+    over all experts at once, with NO selection bias
+    (benchmark/configs/openpangu-ultra-718b-ep16-l7.json ``assumed``).
+    The multi-token-prediction layers (``num_nextn_predict_layers``) are
+    no part of the next-token forward pass and are not built.  What this
+    code does not implement rejects loudly."""
+    if hf.get("rope_scaling"):
+        raise ValueError("unsupported pangu_ultra_moe rope_scaling "
+                         f"{hf['rope_scaling']!r}")
+    for key in ("n_group", "topk_group"):
+        if (hf.get(key) or 1) != 1:
+            raise ValueError(f"pangu_ultra_moe with grouped routing "
+                             f"({key} {hf[key]!r}) is not supported")
+    if hf.get("scoring_func", "sigmoid") != "sigmoid" or hf.get(
+            "topk_method", "greedy") not in ("greedy", "noaux_tc"):
+        raise ValueError("unsupported pangu_ultra_moe router: scoring_func "
+                         f"{hf.get('scoring_func')!r}, topk_method "
+                         f"{hf.get('topk_method')!r}")
+    return ModelConfig(
+        intermediate_size=hf["intermediate_size"],
+        num_kv_heads=hf["num_attention_heads"],
+        head_dim=hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"],
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        norm_eps=hf.get("rms_norm_eps", 1e-5),
+        act=hf.get("hidden_act", "silu"),
+        attention_bias=hf.get("attention_bias", False),
+        sandwich_norms=bool(hf.get("sandwich_norm", False)),
+        mla_kv_lora_rank=hf["kv_lora_rank"],
+        mla_q_lora_rank=hf.get("q_lora_rank"),
+        mla_qk_rope_head_dim=hf["qk_rope_head_dim"],
+        mla_v_head_dim=hf["v_head_dim"],
+        mla_rope_interleave=hf.get("rope_interleave", True),
+        num_experts=hf["n_routed_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        norm_topk_prob=hf.get("norm_topk_prob", True),
+        moe_scoring="sigmoid",
+        moe_router_bias=hf.get("topk_method") == "noaux_tc",
+        moe_routed_scaling=hf.get("routed_scaling_factor", 1.0),
+        moe_shared_experts=hf.get("n_shared_experts") or 0,
+        moe_first_k_dense=hf.get("first_k_dense_replace", 0),
+        **common,
+    )
 
 
 def _olmo_hybrid_config(hf: dict, common: dict) -> ModelConfig:
@@ -1359,6 +1425,30 @@ register_model_config(ModelConfig(
     moe_routed_scaling=2.5, moe_shared_experts=1, moe_first_k_dense=1,
 ), "k-exaone-236b")
 
+# openPangu-Ultra-MoE-718B: 61 layers of hidden 7,680 under sandwich norms;
+# latent attention with 128 heads (a query latent of 1,536, one cached
+# vector of 512 + 64 a token, 128 + 64 wide keys, values of 128, the plain
+# rotary table at theta 25.6e6); three dense layers of width 18,432, then
+# 256 routed experts of width 2,048, eight a token by sigmoid scores over
+# all of them at once with no selection bias, renormalised and scaled 2.5,
+# beside one shared expert.  The numbers are config.json's; what it leaves
+# open is in benchmark/configs/openpangu-ultra-718b-ep16-l7.json
+# (``assumed``).  718 B parameters: a chip serves its share of a cut of the
+# depth (``moe_experts_held``).  The multi-token-prediction layer is not
+# built.
+register_model_config(ModelConfig(
+    name="FreedomIntelligence/openPangu-Ultra-MoE-718B",
+    vocab_size=153600, hidden_size=7680, intermediate_size=18432,
+    num_layers=61, num_heads=128, num_kv_heads=128, head_dim=192,
+    max_position_embeddings=131072, rope_theta=25600000.0, norm_eps=1e-5,
+    tie_word_embeddings=False, sandwich_norms=True,
+    mla_kv_lora_rank=512, mla_q_lora_rank=1536,
+    mla_qk_rope_head_dim=64, mla_v_head_dim=128,
+    num_experts=256, num_experts_per_tok=8, moe_intermediate_size=2048,
+    norm_topk_prob=True, moe_scoring="sigmoid", moe_routed_scaling=2.5,
+    moe_shared_experts=1, moe_first_k_dense=3,
+), "openpangu-ultra-718b")
+
 # Olmo-Hybrid-7B (Ai2): 32 layers of hidden 3,840, three gated delta-rule
 # linear-attention layers (30 heads, keys of 96, values of 192, a causal
 # convolution of 4 in front) to each full-attention layer (30 heads of
@@ -1439,6 +1529,28 @@ register_model_config(ModelConfig(
     num_experts=32, num_experts_per_tok=4, moe_intermediate_size=32,
     norm_topk_prob=True, moe_scoring="sigmoid", moe_router_bias=True,
     moe_routed_scaling=2.5, moe_shared_experts=1, moe_first_k_dense=1,
+))
+
+# openPangu-Ultra-MoE in small: latent attention with a query latent under
+# sandwich norms, two dense layers, then 16 experts, 4 a token by sigmoid
+# scores over all of them with no selection bias, scaled 2.5, beside a
+# shared one.  The cached vector is 136 + 12 = 148 wide: NOT a multiple of
+# a lane tile nor of a sublane tile, as 576 is not of 128, and stored as
+# 256 (cache_head_dim), so a fault in the page's zero lanes shows on the
+# CPU.  Every expert held; a test takes a share with dataclasses.replace.
+# float32 like tiny-mistral.
+register_model_config(ModelConfig(
+    name="tiny-pangu",
+    vocab_size=256, hidden_size=64, intermediate_size=160,
+    num_layers=5, num_heads=8, num_kv_heads=8, head_dim=28,
+    max_position_embeddings=512, rope_theta=25600000.0, norm_eps=1e-5,
+    tie_word_embeddings=False, eos_token_id=1, dtype="float32",
+    sandwich_norms=True,
+    mla_kv_lora_rank=136, mla_q_lora_rank=40,
+    mla_qk_rope_head_dim=12, mla_v_head_dim=16,
+    num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32,
+    norm_topk_prob=True, moe_scoring="sigmoid", moe_routed_scaling=2.5,
+    moe_shared_experts=1, moe_first_k_dense=2,
 ))
 
 # Olmo-Hybrid in small: two periods of L L L F, 6 linear heads with keys

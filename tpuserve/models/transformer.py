@@ -24,7 +24,7 @@ from tpuserve.ops import gated_delta as gdn_ops
 from tpuserve.ops import rope as rope_ops
 from tpuserve.ops import scopes
 from tpuserve.ops import ssm as ssm_ops
-from tpuserve.utils import round_up
+from tpuserve.utils import next_power_of_2, round_up
 
 Params = Any  # nested dict/list pytree of jnp arrays
 
@@ -612,35 +612,57 @@ def _qkv(h: jnp.ndarray, lp: dict, cfg: ModelConfig, positions: jnp.ndarray,
 # output).  References: DeepSeek-V2 paper §2.1; HF modeling_deepseek_v3
 # (the naive form this must match numerically).
 
+def _mla_rope(x: jnp.ndarray, cfg: ModelConfig,
+              positions: jnp.ndarray) -> jnp.ndarray:
+    """x (..., heads, rope) rotated at ``positions`` over the rope
+    features: the queries' rope part, and the one key all heads share."""
+    cos, sin = rope_ops.rope_freqs(positions, cfg.mla_qk_rope_head_dim,
+                                   cfg.rope_theta, yarn_scaling=cfg.rope_yarn)
+    return rope_ops.apply_rope(x, cos, sin)
+
+
+def _mla_latents(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
+                 positions: jnp.ndarray, ad: jnp.ndarray | None = None):
+    """The residual stream through the layer's input norm -> what the
+    queries are made from (the normed query latent; the normed input
+    itself where the model has no query latent) and the cache-ready
+    latent (..., latent_dim) = rmsnorm(c_kv) ⊕ roped key: everything of
+    the layer's input side that is narrow."""
+    with jax.named_scope(scopes.ATTN_QKV):
+        hn = _norm(h, lp["attn_norm"], cfg)
+        cq = hn
+        if "q_a_proj" in lp:
+            cq = rmsnorm(_linear(hn, lp["q_a_proj"], ad),
+                         lp["q_a_norm"]["scale"], cfg.norm_eps,
+                         cfg.norm_weight_offset)
+        ckv = _linear(hn, lp["kv_a_proj"], ad)
+        c = rmsnorm(ckv[..., :cfg.mla_kv_lora_rank],
+                    lp["kv_a_norm"]["scale"], cfg.norm_eps,
+                    cfg.norm_weight_offset)
+        k_rope = _mla_rope(ckv[..., None, cfg.mla_kv_lora_rank:], cfg,
+                           positions)[..., 0, :]
+        return cq, jnp.concatenate([c, k_rope], axis=-1)
+
+
+def _mla_queries(cq: jnp.ndarray, lp: dict, cfg: ModelConfig,
+                 positions: jnp.ndarray, ad: jnp.ndarray | None = None):
+    """:func:`_mla_latents`' first result -> q_nope (..., H, nope) and
+    roped q_rope (..., H, rope): the wide side (128 heads of 192 a row at
+    the published sizes)."""
+    with jax.named_scope(scopes.ATTN_QKV):
+        q = _linear(cq, lp["q_b_proj" if "q_a_proj" in lp else "q_proj"], ad)
+        q = q.reshape(*cq.shape[:-1], cfg.num_heads, cfg.head_dim)
+        nope = cfg.mla_qk_nope_head_dim
+        return q[..., :nope], _mla_rope(q[..., nope:], cfg, positions)
+
+
 def _mla_proj(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
               positions: jnp.ndarray, ad: jnp.ndarray | None = None):
     """The residual stream through the layer's input norm -> q_nope
     (..., H, nope), roped q_rope (..., H, rope), and the cache-ready
     latent (..., latent_dim) = rmsnorm(c_kv) ⊕ roped key."""
-    with jax.named_scope(scopes.ATTN_QKV):
-        hn = _norm(h, lp["attn_norm"], cfg)
-        if "q_a_proj" in lp:
-            cq = rmsnorm(_linear(hn, lp["q_a_proj"], ad),
-                         lp["q_a_norm"]["scale"], cfg.norm_eps,
-                         cfg.norm_weight_offset)
-            q = _linear(cq, lp["q_b_proj"], ad)
-        else:
-            q = _linear(hn, lp["q_proj"], ad)
-        q = q.reshape(*hn.shape[:-1], cfg.num_heads, cfg.head_dim)
-        nope = cfg.mla_qk_nope_head_dim
-        q_nope, q_rope = q[..., :nope], q[..., nope:]
-        ckv = _linear(hn, lp["kv_a_proj"], ad)
-        c = rmsnorm(ckv[..., :cfg.mla_kv_lora_rank],
-                    lp["kv_a_norm"]["scale"], cfg.norm_eps,
-                    cfg.norm_weight_offset)
-        k_rope = ckv[..., cfg.mla_kv_lora_rank:]
-        cos, sin = rope_ops.rope_freqs(positions, cfg.mla_qk_rope_head_dim,
-                                       cfg.rope_theta,
-                                       yarn_scaling=cfg.rope_yarn)
-        q_rope = rope_ops.apply_rope(q_rope, cos, sin)
-        k_rope = rope_ops.apply_rope(k_rope[..., None, :], cos,
-                                     sin)[..., 0, :]
-        return q_nope, q_rope, jnp.concatenate([c, k_rope], axis=-1)
+    cq, latent = _mla_latents(h, lp, cfg, positions, ad)
+    return (*_mla_queries(cq, lp, cfg, positions, ad), latent)
 
 
 def _mla_kv_b(lp: dict, cfg: ModelConfig, dtype) -> tuple:
@@ -695,11 +717,92 @@ def _mla_prefill_out(q_nope, q_rope, latent, lp, cfg: ModelConfig,
 
 def _mla_absorb_q(q_nope, q_rope, lp, cfg: ModelConfig) -> jnp.ndarray:
     """Fold W_UK into the query: scores against raw latents become exact
-    (q_lat . c == q_nope . k_nope); the roped dims ride alongside."""
+    (q_lat . c == q_nope . k_nope); the roped dims ride alongside, then
+    zeros over the lanes a page holds past the latent
+    (``ModelConfig.cache_head_dim``)."""
     with jax.named_scope(scopes.ATTN_QKV):
         w_uk, _ = _mla_kv_b(lp, cfg, q_nope.dtype)
         q_lat = jnp.einsum("...hn,chn->...hc", q_nope, w_uk)
-        return jnp.concatenate([q_lat, q_rope], axis=-1)
+        return attn_ops.pad_lanes(
+            jnp.concatenate([q_lat, q_rope], axis=-1), cfg.cache_head_dim)
+
+
+#: rows of a packed prefill whose queries stand at once: 128 heads of 192
+#: are 49 KB a row and 164 KB absorbed into the 640-lane latent, 0.4 and
+#: 1.25 GB at the ladder's top rung of 8,192 rows (and as much again folded
+#: out of the latent), beside weights and pages that fill the chip
+MLA_PACKED_ROWS = 1024
+
+
+def _mla_packed_attention(h, cq, positions, lp, cfg: ModelConfig, ad,
+                          latent_pages, block_tables, kv_lens, q_starts,
+                          q_lens, meta, blk_seq, scale: float, blk: int,
+                          decode_rows: bool):
+    """A flat stream's attention in the ragged kernel's latent entry, from
+    the narrow side (:func:`_mla_latents`' ``cq``, the latent pages the
+    layer just wrote) to the residual stream: the queries, ``W_uk`` folded
+    into them, the kernel, ``W_uv`` folded out, the output projection and
+    the add.  A packed prefill (no decode rows, no adapter rows) longer
+    than ``MLA_PACKED_ROWS`` goes ``MLA_PACKED_ROWS`` rows at a time: all
+    of that is a function of a row and its sequence's pages, so a piece
+    is the same program on a slice of the stream with the sequences'
+    first rows counted from the slice's own (``q_starts`` less its
+    offset), and the wide queries of 8,192 rows never stand at once."""
+    from tpuserve.ops.pallas_ragged_attention import ragged_paged_attention
+
+    def attend(h, cq, positions, q_starts, blk_seq):
+        q_nope, q_rope = _mla_queries(cq, lp, cfg, positions, ad)
+        out = ragged_paged_attention(
+            _mla_absorb_q(q_nope, q_rope, lp, cfg), latent_pages, None,
+            block_tables, kv_lens, q_starts, q_lens, meta, blk_seq, scale,
+            blk_q=blk, decode_rows=decode_rows,
+            v_lanes=cfg.mla_kv_lora_rank)
+        return _attn_residual(h, _mla_unabsorb(out, lp, cfg), lp, cfg, ad)
+
+    T, C = h.shape[0], MLA_PACKED_ROWS
+    if decode_rows or ad is not None or T <= C:
+        return attend(h, cq, positions, q_starts, blk_seq)
+    n = -(-T // C)
+
+    def pieces(x, per, fill=0):
+        pad = ((0, n * per - x.shape[0]),) + ((0, 0),) * (x.ndim - 1)
+        return jnp.pad(x, pad, constant_values=fill).reshape(
+            n, per, *x.shape[1:])
+
+    out = jax.lax.map(
+        lambda a: attend(a[0], a[1], a[2], q_starts - a[3], a[4]),
+        (pieces(h, C), pieces(cq, C), pieces(positions, C),
+         jnp.arange(n, dtype=jnp.int32) * C, pieces(blk_seq, C // blk, -1)))
+    return out.reshape(n * C, h.shape[-1])[:T]
+
+
+def _mla_window_attention(q, latent_pages, block_tables, ctx_lens,
+                          chunk_lens, scale: float, v_lanes: int):
+    """A (B, C) window of absorbed queries against the latent pages in the
+    ragged kernel: each sequence's window is one prefill chunk of the flat
+    stream that kernel serves, so the chunk, verify and draft trunks need
+    no latent kernel of their own.  Rows past ``chunk_lens`` come back
+    zero (their blocks are skipped)."""
+    from tpuserve.ops.pallas_ragged_attention import (ragged_block_for,
+                                                      ragged_paged_attention)
+    B, C, Hq, D = q.shape
+    blk = min(ragged_block_for(
+        Hq, 1, D, latent_pages.shape[1], latent_pages.dtype.itemsize,
+        q.dtype.itemsize), next_power_of_2(C))
+    Cp = -(-C // blk) * blk
+    q = jnp.pad(q, ((0, 0), (0, Cp - C), (0, 0), (0, 0)))
+    row = jnp.arange(Cp, dtype=jnp.int32)
+    live = row[None, :] < chunk_lens[:, None]                     # (B, Cp)
+    seq = jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None], (B, Cp))
+    out = ragged_paged_attention(
+        q.reshape(B * Cp, Hq, D), latent_pages, None, block_tables,
+        (ctx_lens + chunk_lens).astype(jnp.int32),
+        jnp.arange(B, dtype=jnp.int32) * Cp, chunk_lens.astype(jnp.int32),
+        jnp.zeros((2,), jnp.int32),
+        jnp.where(live, seq, -1)[:, ::blk].reshape(-1), scale, blk_q=blk,
+        decode_rows=False, v_lanes=v_lanes)
+    out = out.reshape(B, Cp, Hq, v_lanes)
+    return jnp.where(live[:, :, None, None], out, 0)[:, :C]
 
 
 def _mla_unabsorb(out_lat, lp, cfg: ModelConfig) -> jnp.ndarray:
@@ -1349,9 +1452,15 @@ def _prefill_layer(h, lp, entry, pool, positions, prompt_lens, slot_ids, ad,
         if cfg.layer_mixer(li) == MIXER_LINEAR:
             h, pool = _lin_window(h, lp, cfg, prompt_lens, pool, seats)
         elif cfg.is_mla:
-            # MLA prefill: cache the latent, attend naively (decompressed)
-            # over the fresh prompt K/V — reference impl only; the Pallas
-            # kernels assume materialised per-head K/V pages
+            # MLA prefill on the (B, L) grid: cache the latent, attend
+            # naively (decompressed) over the fresh prompt K/V in XLA.
+            # The engine sends an MLA model's batched prefills here only
+            # where it runs no Pallas kernel (a mesh, int8 pages); with
+            # them it packs the batch through :func:`forward_ragged`
+            if attn_impl == "pallas":
+                raise NotImplementedError(
+                    "an MLA model's (B, L) prefill has no Pallas form: its "
+                    "batched prefills go out packed (Engine._packed_prefill)")
             q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
             entry = attn_ops.write_mla_entry(
                 entry, latent, slot_ids, latent_split=cfg.mla_kv_lora_rank)
@@ -1426,15 +1535,21 @@ def _chunk_layer(h, lp, entry, pool, positions, ctx_lens, chunk_lens,
             # MLA window: write the latent, attend ABSORBED against the
             # latent pages (k == v == latent; value = first kv_lora cols)
             q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
-            entry = attn_ops.write_mla_entry(entry, latent, slot_ids,
-                                             latent_split=cfg.mla_kv_lora_rank)
+            entry = attn_ops.write_mla_entry(
+                entry, latent, slot_ids, latent_split=cfg.mla_kv_lora_rank,
+                aligned=aligned)
             q_eff = _mla_absorb_q(q_nope, q_rope, lp, cfg)
-            out = attn_ops.chunked_prefill_attention(
-                q_eff, entry["k"], entry["k"], block_tables, ctx_lens,
-                chunk_lens, scale, k_scale=entry.get("ks"),
-                v_scale=entry.get("ks"),
-                scale_slices=(cfg.mla_kv_lora_rank,
-                              cfg.mla_qk_rope_head_dim))
+            if attn_impl == "pallas":
+                out = _mla_window_attention(
+                    q_eff, entry["k"], block_tables, ctx_lens, chunk_lens,
+                    scale, cfg.mla_kv_lora_rank)
+            else:
+                out = attn_ops.chunked_prefill_attention(
+                    q_eff, entry["k"], entry["k"], block_tables, ctx_lens,
+                    chunk_lens, scale, k_scale=entry.get("ks"),
+                    v_scale=entry.get("ks"),
+                    scale_slices=(cfg.mla_kv_lora_rank,
+                                  cfg.mla_qk_rope_head_dim))
             out = _mla_unabsorb(out, lp, cfg)
             h = _attn_residual(h, out, lp, cfg, ad)
         else:
@@ -1483,17 +1598,25 @@ def _decode_layer(h, lp, entry, pool, positions, slot_ids, block_tables,
             h, pool = _lin_decode(h, lp, cfg, slot_ids, pool, seats, attn_impl)
         elif cfg.is_mla:
             # MLA decode: absorbed attention straight against the latent
-            # pages — the step reads mla_latent_dim bytes per cached token
-            # instead of 2 * Hkv * head_dim (the ~10x KV-bandwidth win)
+            # pages — the step reads one latent page row per cached token
+            # instead of 2 * Hkv * head_dim (the ~10x KV-bandwidth win);
+            # the Pallas kernel reads V off the K page it landed
             q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
             entry = attn_ops.write_mla_entry(entry, latent, slot_ids,
                                              latent_split=cfg.mla_kv_lora_rank)
             q_eff = _mla_absorb_q(q_nope, q_rope, lp, cfg)
-            out = attn_ops.paged_decode_attention(
-                q_eff, entry["k"], entry["k"], block_tables, seq_lens,
-                scale, k_scale=entry.get("ks"), v_scale=entry.get("ks"),
-                scale_slices=(cfg.mla_kv_lora_rank,
-                              cfg.mla_qk_rope_head_dim))
+            if attn_impl == "pallas":
+                from tpuserve.ops.pallas_paged_attention import \
+                    paged_decode_attention
+                out = paged_decode_attention(
+                    q_eff, entry["k"], None, block_tables, seq_lens, scale,
+                    v_lanes=cfg.mla_kv_lora_rank)
+            else:
+                out = attn_ops.paged_decode_attention(
+                    q_eff, entry["k"], entry["k"], block_tables, seq_lens,
+                    scale, k_scale=entry.get("ks"), v_scale=entry.get("ks"),
+                    scale_slices=(cfg.mla_kv_lora_rank,
+                                  cfg.mla_qk_rope_head_dim))
             out = _mla_unabsorb(out, lp, cfg)
             h = _attn_residual(h, out, lp, cfg, ad)
         else:
@@ -1543,23 +1666,28 @@ def _ragged_layer(h, lp, entry, pool, positions, slot_ids, row_seq, row_lens,
             h, pool = _lin_packed(h, lp, cfg, positions, slot_ids, blk_seq,
                                   q_starts, q_lens, ragged_blk, pool, seats)
         elif cfg.is_mla:
-            # MLA: absorbed attention against the latent pages, like the
-            # chunk/decode trunks (reference path only — the Pallas
-            # kernels assume materialised per-head pages, same gate as
-            # the rest of the engine)
-            q_nope, q_rope, latent = _mla_proj(h, lp, cfg, positions, ad)
+            # MLA: absorbed attention against the latent pages just
+            # written, like the chunk/decode trunks
+            cq, latent = _mla_latents(h, lp, cfg, positions, ad)
             entry = attn_ops.write_mla_entry(
-                entry, latent, slot_ids, latent_split=cfg.mla_kv_lora_rank)
-            q_eff = _mla_absorb_q(q_nope, q_rope, lp, cfg)
-            out = _ragged_reference_attn(
-                q_eff, entry["k"], entry["k"], block_tables, row_seq,
-                row_lens, blk_seq, meta, ragged_blk, scale,
-                entry.get("ks"), entry.get("ks"), None, None,
-                scale_slices=(cfg.mla_kv_lora_rank,
-                              cfg.mla_qk_rope_head_dim),
-                decode_rows=decode_rows)
-            out = _mla_unabsorb(out, lp, cfg)
-            h = _attn_residual(h, out, lp, cfg, ad)
+                entry, latent, slot_ids, latent_split=cfg.mla_kv_lora_rank,
+                aligned=aligned)
+            if attn_impl == "pallas":
+                h = _mla_packed_attention(
+                    h, cq, positions, lp, cfg, ad, entry["k"], block_tables,
+                    kv_lens, q_starts, q_lens, meta, blk_seq, scale,
+                    ragged_blk, decode_rows)
+            else:
+                q_nope, q_rope = _mla_queries(cq, lp, cfg, positions, ad)
+                out = _mla_unabsorb(_ragged_reference_attn(
+                    _mla_absorb_q(q_nope, q_rope, lp, cfg), entry["k"],
+                    entry["k"], block_tables, row_seq, row_lens, blk_seq,
+                    meta, ragged_blk, scale, entry.get("ks"),
+                    entry.get("ks"), None, None,
+                    scale_slices=(cfg.mla_kv_lora_rank,
+                                  cfg.mla_qk_rope_head_dim),
+                    decode_rows=decode_rows), lp, cfg)
+                h = _attn_residual(h, out, lp, cfg, ad)
         else:
             q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)  # (T, H*, D)
             entry = attn_ops.write_kv_entry(entry, k, v, slot_ids, aligned)

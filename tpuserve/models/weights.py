@@ -460,12 +460,15 @@ def _load_llama_family(cfg: ModelConfig, raw: dict, dtype) -> Params:
         if cfg.sandwich_norms:
             # Gemma2: post_attention_layernorm wraps the ATTENTION OUTPUT;
             # the MLP pre-norm is pre_feedforward_layernorm
+            # (openPangu spells the last two pre_mlp_layernorm and
+            # post_mlp_layernorm: whichever pair the checkpoint holds)
             lp["post_attn_norm"] = norm_scale(
                 pre + "post_attention_layernorm.weight")
-            lp["mlp_norm"] = norm_scale(
-                pre + "pre_feedforward_layernorm.weight")
-            lp["post_mlp_norm"] = norm_scale(
-                pre + "post_feedforward_layernorm.weight")
+            ff = ("pre_mlp_layernorm", "post_mlp_layernorm") \
+                if pre + "pre_mlp_layernorm.weight" in raw else (
+                    "pre_feedforward_layernorm", "post_feedforward_layernorm")
+            lp["mlp_norm"] = norm_scale(pre + ff[0] + ".weight")
+            lp["post_mlp_norm"] = norm_scale(pre + ff[1] + ".weight")
         elif cfg.has_ssm:                                       # Falcon-H1
             lp["mlp_norm"] = norm_scale(pre + "pre_ff_layernorm.weight")
             lp["ssm"] = _load_falcon_h1_ssm(raw, pre + "mamba.", dtype)

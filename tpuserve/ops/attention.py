@@ -78,9 +78,11 @@ def _dequant_gathered(k, v, k_scale, v_scale, block_tables, B, S, Hkv,
     if scale_slices is not None:
         n = len(scale_slices)
         ksc = expand_slice_scales(
-            k_scale[block_tables].reshape(B, S, n), scale_slices)
+            k_scale[block_tables].reshape(B, S, n), scale_slices,
+            k.shape[-1])
         vsc = expand_slice_scales(
-            v_scale[block_tables].reshape(B, S, n), scale_slices)
+            v_scale[block_tables].reshape(B, S, n), scale_slices,
+            v.shape[-1])
         return ((k.astype(jnp.float32) * ksc).astype(dtype),
                 (v.astype(jnp.float32) * vsc).astype(dtype))
 
@@ -440,12 +442,12 @@ def kv_stream_by_page(entry: dict, unit: int, attn_impl: str,
     (:func:`write_kv_entry` with ``aligned=True``), from what is static at
     trace time: the Pallas kernels are on and unsharded, the entry is
     plain (no ``ks``/``vs`` scale arrays: int8 pages quantize a row at a
-    time) with K and V pages (not MLA's latent), and ``unit`` — the rows
-    between two places where the stream may start a prompt or end — is
-    whole pages.  The trunks ask it for the write, the engine for its
+    time; K and V pages, or MLA's latent pages alone), and ``unit`` — the
+    rows between two places where the stream may start a prompt or end —
+    is whole pages.  The trunks ask it for the write, the engine for its
     counter (``tpuserve_prefill_kv_tokens_paged_total``)."""
     return (attn_impl == "pallas" and mesh is None and "ks" not in entry
-            and "v" in entry and unit % entry["k"].shape[1] == 0)
+            and unit % entry["k"].shape[1] == 0)
 
 
 def write_kv_entry(entry: dict, k: jnp.ndarray, v: jnp.ndarray,
@@ -483,9 +485,16 @@ def write_kv_entry(entry: dict, k: jnp.ndarray, v: jnp.ndarray,
                 "v": write_kv_cache(entry["v"], v, slots)}
 
 
+def pad_lanes(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """``x`` with zeros after its last axis up to ``width`` lanes (a latent
+    page is whole lane tiles: ``ModelConfig.cache_head_dim``)."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
 def write_mla_entry(entry: dict, latent: jnp.ndarray,
                     slots: jnp.ndarray,
-                    latent_split: int | None = None) -> dict:
+                    latent_split: int | None = None,
+                    aligned: bool = False) -> dict:
     """Write MLA latent vectors into a k-only cache entry.
 
     MLA (DeepSeek) caches ONE (latent ⊕ roped-key) vector per token —
@@ -502,30 +511,42 @@ def write_mla_entry(entry: dict, latent: jnp.ndarray,
     large rope channel crush latent precision (ADVICE r4).  The paired
     scale cache is (num_blocks, block_size, 2); readers expand it back to
     channel granularity via ``scale_slices`` (:func:`expand_slice_scales`).
+
+    ``aligned`` (static; :func:`kv_stream_by_page` decides it): a
+    page-aligned stream's rows go out a page a copy, as
+    :func:`write_kv_entry`'s do.
     """
     with jax.named_scope(scopes.ATTN_KV_WRITE):
         lat = latent[..., None, :]                     # add the 1-head axis
+        lanes = entry["k"].shape[-1]        # the page's, zeros past the latent
         if "ks" in entry:
             if latent_split is None:
                 raise ValueError("int8 MLA cache requires latent_split (the "
                                  "kv_lora_rank) for per-slice scales")
             q1, s1 = quantize_kv(lat[..., :latent_split])
             q2, s2 = quantize_kv(lat[..., latent_split:])
-            q = jnp.concatenate([q1, q2], axis=-1)
+            q = pad_lanes(jnp.concatenate([q1, q2], axis=-1), lanes)
             s = jnp.concatenate([s1, s2], axis=-1)     # (..., 2): latent, rope
             return {"k": write_kv_cache(entry["k"], q, slots),
                     "ks": write_kv_scales(entry["ks"], s, slots)}
-        return {"k": write_kv_cache(entry["k"], lat, slots)}
+        if aligned:
+            from tpuserve.ops.pallas_kv_write import paged_kv_write
+            return {"k": paged_kv_write(entry["k"], None,
+                                        pad_lanes(lat, lanes), None, slots)[0]}
+        return {"k": write_kv_cache(entry["k"], pad_lanes(lat, lanes), slots)}
 
 
-def expand_slice_scales(scales: jnp.ndarray,
-                        scale_slices: tuple[int, ...]) -> jnp.ndarray:
+def expand_slice_scales(scales: jnp.ndarray, scale_slices: tuple[int, ...],
+                        width: int | None = None) -> jnp.ndarray:
     """(..., n_slices) per-slice scales -> (..., 1, D) channel scales,
-    D = sum(scale_slices), broadcastable against (..., Hkv=1, D) pages."""
+    D = sum(scale_slices), broadcastable against (..., Hkv=1, D) pages;
+    ``width`` > D: the page's zero lanes past the slices, scale 0."""
     per_chan = jnp.concatenate(
         [jnp.broadcast_to(scales[..., i:i + 1],
                           (*scales.shape[:-1], w))
          for i, w in enumerate(scale_slices)], axis=-1)
+    if width:
+        per_chan = pad_lanes(per_chan, width)
     return per_chan[..., None, :]
 
 

@@ -43,14 +43,16 @@ KERNEL_NAME = "_paged_kv_write"
 INFLIGHT = 16
 
 
-def _kernel(pages_ref, k_new, v_new, _k_in, _v_in, k_out, v_out, sems, *,
-            n_pages: int, n_blocks: int, inflight: int):
+def _kernel(pages_ref, *refs, n_pages: int, n_blocks: int, inflight: int):
+    # refs: the fresh rows of each cache (K and V; a latent entry's K
+    # alone), the caches in (aliased, unread), the caches out, semaphores
+    n = (len(refs) - 1) // 3
+    new, out, sems = refs[:n], refs[2 * n:3 * n], refs[-1]
+
     def copies(i):
         slot, page = i % inflight, pages_ref[i]
-        return (pltpu.make_async_copy(k_new.at[i], k_out.at[page],
-                                      sems.at[0, slot]),
-                pltpu.make_async_copy(v_new.at[i], v_out.at[page],
-                                      sems.at[1, slot]))
+        return [pltpu.make_async_copy(new[a].at[i], out[a].at[page],
+                                      sems.at[a, slot]) for a in range(n)]
 
     def live(i):
         return pages_ref[i] < n_blocks
@@ -87,7 +89,9 @@ def paged_kv_write(k_cache: jnp.ndarray, v_cache: jnp.ndarray,
 
     k_cache / v_cache: (num_blocks, block_size, Hkv, D); k / v: the
     stream's rows, (..., Hkv, D) with a multiple of ``block_size`` rows in
-    all; slots: one flat slot a row (``PAD_SLOT`` on padding).  The
+    all (a LATENT entry holds K pages alone: ``v_cache`` and ``v`` None,
+    and None comes back for them); slots: one flat slot a row
+    (``PAD_SLOT`` on padding).  The
     caller's word: rows ``[j * block_size, (j + 1) * block_size)`` hold
     slots ``page * block_size + 0, 1, ...`` of one page, then padding to
     the end, or padding only.  Returns the two caches, equal to the row
@@ -115,20 +119,23 @@ def _paged_kv_write(k_cache, v_cache, k, v, slots, *, interpret: bool):
     n_pages = pages.shape[0]
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     inflight = min(INFLIGHT, n_pages)
-    k_out, v_out = pl.pallas_call(
+    caches = [k_cache] if v_cache is None else [k_cache, v_cache]
+    n = len(caches)
+    outs = pl.pallas_call(
         functools.partial(_kernel, n_pages=n_pages, n_blocks=nb,
                           inflight=inflight),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(1,),
-            in_specs=[any_spec] * 4, out_specs=[any_spec] * 2,
-            scratch_shapes=[pltpu.SemaphoreType.DMA((2, inflight))]),
-        out_shape=[jax.ShapeDtypeStruct((nb, bs * hkv, d), k_cache.dtype),
-                   jax.ShapeDtypeStruct((nb, bs * hkv, d), v_cache.dtype)],
-        # operands 3 and 4 (the caches; the scalar-prefetch operand
-        # counts) are the outputs: a call writes only its pages
-        input_output_aliases={3: 0, 4: 1},
+            in_specs=[any_spec] * (2 * n), out_specs=[any_spec] * n,
+            scratch_shapes=[pltpu.SemaphoreType.DMA((n, inflight))]),
+        out_shape=[jax.ShapeDtypeStruct((nb, bs * hkv, d), c.dtype)
+                   for c in caches],
+        # the caches (after the scalar-prefetch operand and the fresh
+        # rows) are the outputs: a call writes only its pages
+        input_output_aliases={1 + n + a: a for a in range(n)},
         interpret=interpret,
         name=KERNEL_NAME,
-    )(pages, paged(k, k_cache), paged(v, v_cache),
-      k_cache.reshape(nb, bs * hkv, d), v_cache.reshape(nb, bs * hkv, d))
-    return k_out.reshape(k_cache.shape), v_out.reshape(v_cache.shape)
+    )(pages, *(paged(x, c) for x, c in zip((k, v), caches)),
+      *(c.reshape(nb, bs * hkv, d) for c in caches))
+    outs = [o.reshape(k_cache.shape) for o in outs]
+    return outs[0], (outs[1] if n == 2 else None)

@@ -94,7 +94,8 @@ def compiler_params(*dimension_semantics: str) -> pltpu.CompilerParams:
 def vmem_footprint(pages_g: int, block_rows: int, dot_rows: int,
                    page_size: int, num_kv_heads: int, head_dim: int,
                    kv_itemsize: int, num_q_heads: int, q_itemsize: int,
-                   quantized: bool = False, decode: bool = False) -> int:
+                   quantized: bool = False, decode: bool = False,
+                   flat_page: bool = False) -> int:
     """Upper bound on the scoped VMEM one program of a paged kernel needs.
 
     ``block_rows``: q rows in the pipelined q/out block (seqs_per_program
@@ -107,7 +108,9 @@ def vmem_footprint(pages_g: int, block_rows: int, dot_rows: int,
       - KV scratch: 2 slots (double buffer) x {K,V} x rows_g tokens.  The
         window and ragged kernels land a page as (page, Hkv, D), whose
         Hkv pads to 32 rows for int8, 16 for bf16, 8 for f32 — which is
-        why an 8-kv-head int8 cache does NOT shrink their scratch 2x.
+        why an 8-kv-head int8 cache does NOT shrink their scratch 2x
+        (``flat_page``: the ragged kernel lands ONE KV head's pages as
+        (page, D), with no head row to pad).
         ``decode`` lands it as the (page x Hkv, D) slab it is in HBM:
         full tiles, no padding, a half (8 kv heads) or a quarter (4) of
         the padded scratch in bf16;
@@ -145,34 +148,49 @@ def vmem_footprint(pages_g: int, block_rows: int, dot_rows: int,
         kv_values = 2 * slab_t * lanes * (q_itemsize
                                           + (2 * 4 if quantized else 0))
         return kv + scales + qo + 4 * score_tile + kv_values
-    kv_rows = round_up(num_kv_heads, MIN_SUBLANES.get(kv_itemsize, 8))
+    kv_rows = 1 if flat_page else round_up(
+        num_kv_heads, MIN_SUBLANES.get(kv_itemsize, 8))
     rows_q = round_up(dot_rows * (num_q_heads // num_kv_heads), 8)
     kv = 2 * 2 * rows_g * kv_rows * lanes * kv_itemsize
     slab = num_kv_heads * rows_q * 128 * 4
     score_tile = slab * round_up(rows_g, 128) // 128
     kv_values = num_kv_heads * rows_g * lanes * (
         3 * q_itemsize + (2 * 4 if quantized else 0))
-    return kv + scales + qo + 11 * slab + score_tile // 2 + kv_values
+    # a head wider than one lane tile (a latent entry's 640): what the
+    # slabs above count once a tile, the accumulator, its update and the
+    # P x V product hold once a LANE TILE of the head, beside the
+    # relayouted q
+    wide = num_kv_heads * rows_q * (lanes - 128) * (3 * 4 + q_itemsize)
+    return kv + scales + qo + 11 * slab + score_tile // 2 + kv_values + wide
 
 
 def _clamp_to_vmem_budget(pages_g: int, block_rows: int, page_size: int,
                           num_kv_heads: int, head_dim: int,
                           kv_itemsize: int, num_q_heads: int,
                           q_itemsize: int, quantized: bool = False,
-                          rows_per_dot: bool = False) -> tuple[int, int]:
+                          rows_per_dot: bool = False,
+                          flat_page: bool = False) -> tuple[int, int]:
     """Shrink (pages_g, block_rows) until :func:`vmem_footprint` fits
     ``VMEM_LIMIT_BYTES``.  ``rows_per_dot``: the kernel contracts its
     whole q block at once (window/ragged) rather than a sequence at a
-    time (decode).  pages_g halves first (it dominates and shrinking it
-    only shortens the DMA pipeline), then block_rows — so wide models get
-    smaller blocks by rule, not by a per-model constant."""
+    time (decode).  Where the pipelined q and out blocks ALONE pass half
+    the budget (128 query heads of a 640-lane latent: 64 rows are 42 MiB)
+    block_rows halves first, until they do not; then pages_g halves (it
+    dominates at every other shape and shrinking it only shortens the DMA
+    pipeline), then block_rows — so wide models get smaller blocks by
+    rule, not by a per-model constant."""
     def footprint(pg: int, br: int) -> int:
         return vmem_footprint(pg, br, br if rows_per_dot else 1, page_size,
                               num_kv_heads, head_dim, kv_itemsize,
                               num_q_heads, q_itemsize, quantized,
-                              decode=not rows_per_dot)
+                              decode=not rows_per_dot, flat_page=flat_page)
 
     orig = (pages_g, block_rows)
+    from tpuserve.utils import round_up
+    row = 2 * 2 * round_up(num_q_heads, MIN_SUBLANES.get(q_itemsize, 8)) \
+        * round_up(head_dim, 128) * q_itemsize      # q and out, one row
+    while block_rows * row > VMEM_LIMIT_BYTES // 2 and block_rows > 1:
+        block_rows //= 2
     while footprint(pages_g, block_rows) > VMEM_LIMIT_BYTES and pages_g > 1:
         pages_g //= 2
     while footprint(pages_g, block_rows) > VMEM_LIMIT_BYTES and block_rows > 1:
@@ -237,7 +255,8 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
                          k_scr, v_scr, sems, *, scale, page_size, pages_g,
                          pages_t, num_kv_heads, group, head_dim, seqs_pp,
                          ks_hbm=None, vs_hbm=None, ks_scr=None, vs_scr=None,
-                         sliding_window=None, logit_softcap=None):
+                         sliding_window=None, logit_softcap=None,
+                         v_lanes=None):
     """A page is contracted in the layout it is stored in: ``k_hbm`` /
     ``v_hbm`` are the cache seen as ``(num_blocks, page x Hkv, D)`` slabs
     (row ``t x Hkv + h``), a page lands in full tiles of the scratch as
@@ -250,10 +269,15 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
     ``ks_hbm``/``vs_hbm`` present = int8 cache: value pages DMA as int8
     beside their scale pages and are dequantized in the slab layout.
 
+    ``v_lanes`` (static) set = a LATENT entry (``v_hbm`` and ``v_scr`` are
+    None): V is the first ``v_lanes`` lanes of the landed K page, so no V
+    page is read and the output is ``v_lanes`` wide.
+
     ``sliding_window`` (static): attend only the last W cached positions;
     pages entirely BEFORE the window are never DMA'd and tiles entirely
     before it never contracted."""
     quantized = ks_hbm is not None
+    latent = v_lanes is not None
     base = pl.program_id(0) * seqs_pp
     num_q_heads = num_kv_heads * group
     rows_g = pages_g * page_size        # tokens a DMA chunk
@@ -287,12 +311,12 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
     def _copies(s, g, slot, j):
         page = bt_ref[base + s, g * pages_g + j]
         rows = pl.ds(pl.multiple_of(j * slab_p, slab_p), slab_p)
-        copies = [
-            pltpu.make_async_copy(k_hbm.at[page], k_scr.at[slot, rows],
-                                  sems.at[0, slot, j]),
-            pltpu.make_async_copy(v_hbm.at[page], v_scr.at[slot, rows],
-                                  sems.at[1, slot, j]),
-        ]
+        copies = [pltpu.make_async_copy(k_hbm.at[page], k_scr.at[slot, rows],
+                                        sems.at[0, slot, j])]
+        if not latent:
+            copies.append(
+                pltpu.make_async_copy(v_hbm.at[page], v_scr.at[slot, rows],
+                                      sems.at[1, slot, j]))
         if quantized:
             copies += [
                 pltpu.make_async_copy(ks_hbm.at[page], ks_scr.at[slot, j],
@@ -314,11 +338,14 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     # which key columns of a tile belong to which query row: column c of
     # the slab is key head c % Hkv, query row i reads key head i // group
-    col_head = jax.lax.rem(jax.lax.broadcasted_iota(
-        jnp.int32, (num_q_heads, slab_t), 1), num_kv_heads)
-    row_head = jax.lax.broadcasted_iota(
-        jnp.int32, (num_q_heads, slab_t), 0) // group
-    own_head = col_head == row_head
+    # (one KV head: every column is every row's)
+    own_head = True
+    if num_kv_heads > 1:
+        col_head = jax.lax.rem(jax.lax.broadcasted_iota(
+            jnp.int32, (num_q_heads, slab_t), 1), num_kv_heads)
+        row_head = jax.lax.broadcasted_iota(
+            jnp.int32, (num_q_heads, slab_t), 0) // group
+        own_head = col_head == row_head
 
     def dequant(vals, scr, slot, t, keep=None):
         """int8 slab rows of tile ``t`` x their (token, head) scales, in
@@ -344,7 +371,7 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         m0 = jnp.full((num_q_heads, 1), NEG_INF, jnp.float32)
         l0 = jnp.zeros((num_q_heads, 1), jnp.float32)
-        acc0 = jnp.zeros((num_q_heads, head_dim), jnp.float32)
+        acc0 = jnp.zeros((num_q_heads, v_lanes or head_dim), jnp.float32)
 
         def chunk_body(i, carry):
             g = g0 + i
@@ -379,7 +406,8 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
                 # its probability is exactly 0, but 0 x NaN would poison
                 # the accumulator.  Only a sequence's first and last
                 # tile can hold one, so only they are rewritten (an int8
-                # tile is rewritten anyway: the select rides on that).
+                # tile is rewritten anyway: the select rides on that).  A
+                # latent entry's V is its K page: that is the one cleaned.
                 def attended_rows():
                     r = jax.lax.broadcasted_iota(jnp.int32, (slab_t, 1), 0)
                     return (r >= c_lo) & (r < c_hi)
@@ -387,12 +415,13 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
                 if not quantized:
                     @pl.when((c_lo > 0) | (c_hi < slab_t))
                     def _clean_v():
-                        v_t = v_scr[slot, rows]
-                        v_scr[slot, rows] = jnp.where(attended_rows(), v_t,
-                                                      jnp.zeros_like(v_t))
+                        scr = k_scr if latent else v_scr
+                        v_t = scr[slot, rows]
+                        scr[slot, rows] = jnp.where(attended_rows(), v_t,
+                                                    jnp.zeros_like(v_t))
 
                 k = k_scr[slot, rows]
-                v = v_scr[slot, rows]
+                v = k[:, :v_lanes] if latent else v_scr[slot, rows]
                 if quantized:
                     k = dequant(k, ks_scr, slot, t)
                     v = dequant(v, vs_scr, slot, t, keep=attended_rows())
@@ -441,9 +470,14 @@ def paged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                            k_scale: jnp.ndarray | None = None,
                            v_scale: jnp.ndarray | None = None,
                            sliding_window: int | None = None,
-                           logit_softcap: float | None = None) -> jnp.ndarray:
+                           logit_softcap: float | None = None,
+                           v_lanes: int | None = None) -> jnp.ndarray:
     """q: (B, Hq, D); k_cache/v_cache: (num_blocks, page, Hkv, D);
     block_tables: (B, max_pages) int32; seq_lens: (B,). -> (B, Hq, D).
+    A LATENT entry (MLA's absorbed form) hands ``v_cache=None`` and
+    ``v_lanes``: V is the first ``v_lanes`` lanes of the K page as it
+    landed, no V page exists or is read, and the result is
+    (B, Hq, v_lanes).
     ``k_scale``/``v_scale``: (num_blocks, page, SCALE_LANES) f32 when the
     cache stores int8 (ops/attention.py pad_scale_lanes) — value pages
     then move over HBM at half the bytes and dequantize on the VPU inside
@@ -472,32 +506,40 @@ def paged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
             quantized=k_scale is not None)
         pages_t = _tile_pages(pages_g, pages_t)
         scales = () if k_scale is None else (k_scale, v_scale)
+        if (v_cache is None) != (v_lanes is not None) or (
+                v_lanes and scales):
+            raise ValueError("a latent entry is v_cache=None with v_lanes "
+                             "set, and has no int8 form in this kernel")
         return _paged_decode_attention(q, k_cache, v_cache, block_tables,
                                        seq_lens, scales, scale=scale,
                                        interpret=interpret, pages_g=pages_g,
                                        pages_t=pages_t, seqs_pp=seqs_pp,
                                        sliding_window=sliding_window,
-                                       logit_softcap=logit_softcap)
+                                       logit_softcap=logit_softcap,
+                                       v_lanes=v_lanes)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret",
                                              "pages_g", "pages_t", "seqs_pp",
                                              "sliding_window",
-                                             "logit_softcap"))
+                                             "logit_softcap", "v_lanes"))
 def _paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens,
                             scales, *, scale: float, interpret: bool,
                             pages_g: int, pages_t: int, seqs_pp: int,
                             sliding_window: int | None = None,
-                            logit_softcap: float | None = None) -> jnp.ndarray:
+                            logit_softcap: float | None = None,
+                            v_lanes: int | None = None) -> jnp.ndarray:
     B, Hq, D = q.shape
     num_blocks, page_size, Hkv, _ = k_cache.shape
     group = Hq // Hkv
     quantized = bool(scales)
     # A page as it is stored: page x Hkv contiguous rows of D.  The same
     # bytes in the same order, so XLA makes this a bitcast, not a copy
-    # (tests/test_chip_compile.py reads the compiled text for it).
-    k_cache = k_cache.reshape(num_blocks, page_size * Hkv, D)
-    v_cache = v_cache.reshape(num_blocks, page_size * Hkv, D)
+    # (tests/test_chip_compile.py reads the compiled text for it).  A
+    # latent entry (v_lanes) has the K pages alone.
+    pages = [c.reshape(num_blocks, page_size * Hkv, D)
+             for c in ((k_cache,) if v_lanes else (k_cache, v_cache))]
+    Dv = v_lanes or D
 
     # Pad the batch to a whole number of programs; padded rows have
     # seq_len 0 (no DMAs, nothing contracted) and are sliced off below.
@@ -512,27 +554,25 @@ def _paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens,
         _paged_decode_kernel, scale=scale, page_size=page_size,
         pages_g=pages_g, pages_t=pages_t, num_kv_heads=Hkv, group=group,
         head_dim=D, seqs_pp=seqs_pp, sliding_window=sliding_window,
-        logit_softcap=logit_softcap)
+        logit_softcap=logit_softcap, v_lanes=v_lanes)
+    # operand order must mirror the in_specs/scratch below
+    base_kernel = kernel
     if quantized:
-        # operand order must mirror the extra in_specs/scratch below
-        base_kernel = kernel
-
         def kernel(bt, sl, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
                    k_scr, v_scr, ks_scr, vs_scr, sems):
             return base_kernel(bt, sl, q_ref, k_hbm, v_hbm, o_ref,
                                k_scr, v_scr, sems, ks_hbm=ks_hbm,
                                vs_hbm=vs_hbm, ks_scr=ks_scr, vs_scr=vs_scr)
+    elif v_lanes:
+        def kernel(bt, sl, q_ref, k_hbm, o_ref, k_scr, sems):
+            return base_kernel(bt, sl, q_ref, k_hbm, None, o_ref, k_scr,
+                               None, sems)
 
-    in_specs = [
-        pl.BlockSpec((seqs_pp, Hq, D), lambda p, bt, sl: (p, 0, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),      # k_cache stays in HBM
-        pl.BlockSpec(memory_space=pl.ANY),      # v_cache stays in HBM
-    ]
+    # the caches stay in HBM
+    in_specs = [pl.BlockSpec((seqs_pp, Hq, D), lambda p, bt, sl: (p, 0, 0))
+                ] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pages)
     slab_g = pages_g * page_size * Hkv
-    scratch = [
-        pltpu.VMEM((2, slab_g, D), k_cache.dtype),
-        pltpu.VMEM((2, slab_g, D), v_cache.dtype),
-    ]
+    scratch = [pltpu.VMEM((2, slab_g, D), c.dtype) for c in pages]
     if quantized:
         in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2   # scale pages
         scratch += [pltpu.VMEM((2, pages_g, page_size, SCALE_LANES),
@@ -543,15 +583,15 @@ def _paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens,
         num_scalar_prefetch=2,
         grid=(Bp // seqs_pp,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((seqs_pp, Hq, D), lambda p, bt, sl: (p, 0, 0)),
+        out_specs=pl.BlockSpec((seqs_pp, Hq, Dv), lambda p, bt, sl: (p, 0, 0)),
         scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, Hq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((Bp, Hq, Dv), q.dtype),
         compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
         name=KERNEL_NAME,
-    )(block_tables, seq_lens, q, k_cache, v_cache, *scales)
+    )(block_tables, seq_lens, q, *pages, *scales)
     return out[:B]

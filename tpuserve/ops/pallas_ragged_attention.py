@@ -82,7 +82,8 @@ def ragged_block_for(num_q_heads: int, num_kv_heads: int, head_dim: int,
     blk = ragged_block()
     while blk > 8 and vmem_footprint(
             1, blk, blk, page_size, num_kv_heads, head_dim, kv_itemsize,
-            num_q_heads, q_itemsize, quantized) > VMEM_LIMIT_BYTES:
+            num_q_heads, q_itemsize, quantized,
+            flat_page=num_kv_heads == 1) > VMEM_LIMIT_BYTES:
         blk //= 2
     return blk
 
@@ -92,13 +93,20 @@ def _ragged_kernel(bt_ref, kv_ref, qs_ref, ql_ref, meta_ref, bseq_ref,
                    scale, page_size, pages_g, num_kv_heads, group,
                    head_dim, blk_q, ks_hbm=None, vs_hbm=None, ks_scr=None,
                    vs_scr=None, sliding_window=None, logit_softcap=None,
-                   decode_rows=True):
+                   decode_rows=True, v_lanes=None):
     """``ks_hbm``/``vs_hbm`` present = int8 cache (pages DMA as int8 with
-    per-page scale blocks, dequantized in VMEM).  ``sliding_window``
+    per-page scale blocks, dequantized in VMEM).  ``v_lanes`` (static)
+    set = a LATENT entry (``v_hbm`` and ``v_scr`` are None): V is the
+    first ``v_lanes`` lanes of the landed K page, no V page is read and
+    the output is ``v_lanes`` wide.  One KV head's pages are handed over
+    and landed as ``(page, D)``, not ``(page, 1, D)`` whose single row
+    would pad to a sublane tile.  ``sliding_window``
     (static): out-of-window pages are never DMA'd, in both parts.
     ``decode_rows`` (static) False: the caller dispatches no decode rows
     (``meta`` is zero), and the kernel is built without its decode part."""
     quantized = ks_hbm is not None
+    latent = v_lanes is not None
+    v_dim = v_lanes or head_dim
     p = pl.program_id(0)
     B = kv_ref.shape[0]
     num_decode = meta_ref[0]
@@ -107,12 +115,12 @@ def _ragged_kernel(bt_ref, kv_ref, qs_ref, ql_ref, meta_ref, bseq_ref,
 
     def _copies(seq, g, slot, j):
         page = bt_ref[seq, g * pages_g + j]
-        copies = [
-            pltpu.make_async_copy(k_hbm.at[page], k_scr.at[slot, j],
-                                  sems.at[0, slot, j]),
-            pltpu.make_async_copy(v_hbm.at[page], v_scr.at[slot, j],
-                                  sems.at[1, slot, j]),
-        ]
+        copies = [pltpu.make_async_copy(k_hbm.at[page], k_scr.at[slot, j],
+                                        sems.at[0, slot, j])]
+        if not latent:
+            copies.append(
+                pltpu.make_async_copy(v_hbm.at[page], v_scr.at[slot, j],
+                                      sems.at[1, slot, j]))
         if quantized:
             copies += [
                 pltpu.make_async_copy(ks_hbm.at[page], ks_scr.at[slot, j],
@@ -134,11 +142,17 @@ def _ragged_kernel(bt_ref, kv_ref, qs_ref, ql_ref, meta_ref, bseq_ref,
             return 0
         jax.lax.fori_loop(0, pages_g, one, 0)
 
+    def _head_major(scr, slot):
+        if num_kv_heads == 1:
+            return scr[slot].reshape(1, rows_g, head_dim)
+        return jnp.swapaxes(
+            scr[slot].reshape(rows_g, num_kv_heads, head_dim), 0, 1)
+
     def _dequant(slot):
-        k = jnp.swapaxes(
-            k_scr[slot].reshape(rows_g, num_kv_heads, head_dim), 0, 1)
-        v = jnp.swapaxes(
-            v_scr[slot].reshape(rows_g, num_kv_heads, head_dim), 0, 1)
+        k = _head_major(k_scr, slot)
+        if latent:
+            return k, k[..., :v_lanes]
+        v = _head_major(v_scr, slot)
         if quantized:
             k = dequantize_kv(k, _scale_rows(ks_scr[slot], num_kv_heads),
                               q_ref.dtype)
@@ -197,7 +211,7 @@ def _ragged_kernel(bt_ref, kv_ref, qs_ref, ql_ref, meta_ref, bseq_ref,
 
             m0 = jnp.full((num_kv_heads, group, 1), NEG_INF, jnp.float32)
             l0 = jnp.zeros((num_kv_heads, group, 1), jnp.float32)
-            acc0 = jnp.zeros((num_kv_heads, group, head_dim), jnp.float32)
+            acc0 = jnp.zeros((num_kv_heads, group, v_dim), jnp.float32)
 
             def body(i, carry):
                 g = g0 + i
@@ -248,7 +262,7 @@ def _ragged_kernel(bt_ref, kv_ref, qs_ref, ql_ref, meta_ref, bseq_ref,
 
             m, l, acc = jax.lax.fori_loop(0, neff, body, (m0, l0, acc0))
             safe_l = jnp.where(l == 0.0, 1.0, l)
-            out = (acc / safe_l).reshape(1, num_kv_heads * group, head_dim)
+            out = (acc / safe_l).reshape(1, num_kv_heads * group, v_dim)
             o_ref[pl.ds(j, 1)] = out.astype(o_ref.dtype)
             return parity0 + neff
 
@@ -293,7 +307,7 @@ def _ragged_kernel(bt_ref, kv_ref, qs_ref, ql_ref, meta_ref, bseq_ref,
 
         m0 = jnp.full((num_kv_heads, rows_q, 1), NEG_INF, jnp.float32)
         l0 = jnp.zeros((num_kv_heads, rows_q, 1), jnp.float32)
-        acc0 = jnp.zeros((num_kv_heads, rows_q, head_dim), jnp.float32)
+        acc0 = jnp.zeros((num_kv_heads, rows_q, v_dim), jnp.float32)
 
         def body(i, carry):
             g = g0 + i
@@ -337,16 +351,16 @@ def _ragged_kernel(bt_ref, kv_ref, qs_ref, ql_ref, meta_ref, bseq_ref,
         m, l, acc = jax.lax.fori_loop(0, num_groups - g0, body,
                                       (m0, l0, acc0))
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        out = (acc / safe_l).reshape(num_kv_heads, blk_q, group, head_dim)
+        out = (acc / safe_l).reshape(num_kv_heads, blk_q, group, v_dim)
         o_ref[...] = jnp.swapaxes(out, 0, 1).reshape(
-            blk_q, num_kv_heads * group, head_dim).astype(o_ref.dtype)
+            blk_q, num_kv_heads * group, v_dim).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "blk_q",
                                              "pages_per_group",
                                              "sliding_window",
                                              "logit_softcap",
-                                             "decode_rows"))
+                                             "decode_rows", "v_lanes"))
 def ragged_paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                            v_cache: jnp.ndarray, block_tables: jnp.ndarray,
                            kv_lens: jnp.ndarray, q_starts: jnp.ndarray,
@@ -359,9 +373,12 @@ def ragged_paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                            v_scale: jnp.ndarray | None = None,
                            sliding_window: int | None = None,
                            logit_softcap: float | None = None,
-                           decode_rows: bool = True) -> jnp.ndarray:
+                           decode_rows: bool = True,
+                           v_lanes: int | None = None) -> jnp.ndarray:
     """q: (T, Hq, D) flat mixed token stream; k_cache/v_cache: (num_blocks,
-    page, Hkv, D); block_tables: (B, max_pages) per SEQUENCE; kv_lens /
+    page, Hkv, D) (a LATENT entry, MLA's absorbed form: ``v_cache=None``
+    and ``v_lanes``, V the first ``v_lanes`` lanes of the K page as it
+    landed, the result (T, Hq, v_lanes)); block_tables: (B, max_pages) per SEQUENCE; kv_lens /
     q_starts / q_lens: (B,) per-sequence descriptors (cached tokens
     INCLUDING this window, flat row of the sequence's first query, rows in
     this window); meta: (2,) int32 [num_decode_rows, num_decode_blocks];
@@ -402,7 +419,7 @@ def ragged_paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         pages_g, blk_clamped = _clamp_to_vmem_budget(
             pages_g, blk, page_size, Hkv, D, k_cache.dtype.itemsize,
             Hq, q.dtype.itemsize, quantized=k_scale is not None,
-            rows_per_dot=True)
+            rows_per_dot=True, flat_page=Hkv == 1)
         if blk_clamped != blk:
             raise ValueError(
                 f"ragged block {blk} needs more VMEM than the budget allows "
@@ -410,31 +427,40 @@ def ragged_paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                 f"stream at ragged_block_for's {blk_clamped} rows, which fit")
 
         quantized = k_scale is not None
+        if (v_cache is None) != (v_lanes is not None) or (
+                v_lanes and quantized):
+            raise ValueError("a latent entry is v_cache=None with v_lanes "
+                             "set, and has no int8 form in this kernel")
+        Dv = v_lanes or D
         kernel = functools.partial(
             _ragged_kernel, scale=scale, page_size=page_size, pages_g=pages_g,
             num_kv_heads=Hkv, group=group, head_dim=D, blk_q=blk,
             sliding_window=sliding_window, logit_softcap=logit_softcap,
-            decode_rows=decode_rows)
+            decode_rows=decode_rows, v_lanes=v_lanes)
+        base_kernel = kernel
         if quantized:
-            base_kernel = kernel
-
             def kernel(bt, kl, qs, ql, mt, bs_, q_ref, k_hbm, v_hbm, ks_hbm,
                        vs_hbm, o_ref, k_scr, v_scr, ks_scr, vs_scr, sems):
                 return base_kernel(bt, kl, qs, ql, mt, bs_, q_ref, k_hbm,
                                    v_hbm, o_ref, k_scr, v_scr, sems,
                                    ks_hbm=ks_hbm, vs_hbm=vs_hbm,
                                    ks_scr=ks_scr, vs_scr=vs_scr)
+        elif v_lanes:
+            def kernel(bt, kl, qs, ql, mt, bs_, q_ref, k_hbm, o_ref, k_scr,
+                       sems):
+                return base_kernel(bt, kl, qs, ql, mt, bs_, q_ref, k_hbm,
+                                   None, o_ref, k_scr, None, sems)
 
-        in_specs = [
-            pl.BlockSpec((blk, Hq, D),
-                         lambda p, bt, kl, qs, ql, mt, bs_: (p, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),   # k_cache stays in HBM
-            pl.BlockSpec(memory_space=pl.ANY),   # v_cache stays in HBM
-        ]
-        scratch = [
-            pltpu.VMEM((2, pages_g, page_size, Hkv, D), k_cache.dtype),
-            pltpu.VMEM((2, pages_g, page_size, Hkv, D), v_cache.dtype),
-        ]
+        # one KV head: a page is (page, D) (a bitcast of the cache)
+        page_shape = (page_size, D) if Hkv == 1 else (page_size, Hkv, D)
+        pages = [c.reshape(num_blocks, *page_shape)
+                 for c in ((k_cache,) if v_lanes else (k_cache, v_cache))]
+        # the caches stay in HBM
+        in_specs = [pl.BlockSpec((blk, Hq, D),
+                                 lambda p, bt, kl, qs, ql, mt, bs_: (p, 0, 0))
+                    ] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pages)
+        scratch = [pltpu.VMEM((2, pages_g, *page_shape), c.dtype)
+                   for c in pages]
         scales = ()
         if quantized:
             in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
@@ -448,15 +474,15 @@ def ragged_paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
             grid=(T // blk,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
-                (blk, Hq, D), lambda p, bt, kl, qs, ql, mt, bs_: (p, 0, 0)),
+                (blk, Hq, Dv), lambda p, bt, kl, qs, ql, mt, bs_: (p, 0, 0)),
             scratch_shapes=scratch,
         )
         return pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            out_shape=jax.ShapeDtypeStruct((T, Hq, Dv), q.dtype),
             compiler_params=compiler_params("arbitrary"),
             interpret=interpret,
             name=KERNEL_NAME,
         )(block_tables, kv_lens, q_starts, q_lens, meta, blk_seq,
-          q, k_cache, v_cache, *scales)
+          q, *pages, *scales)

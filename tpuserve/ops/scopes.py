@@ -47,15 +47,21 @@ The table (scope -> where it opens -> which metric reads it):
     parts
     embed           _embed                                    trunk.decode_glue_ms
     attn.qkv        _qkv, _mla_proj, _mla_decompress, _mla_absorb_q: the layer's
-                    input norm, projections, q/k norm, rotary  trunk.decode_proj_ms
+                    input norm, projections, q/k norm, rotary  trunk.decode_proj_ms;
+                    (latent attention: the query's and the K/V's latents,
+                    W_uk folded into the query)                with attn.out under decode/,
+                                                              mla.proj_device_share
     attn.kv_write   ops/attention.py write_kv_entry (the row scatter, or for
                     a page-aligned prefill stream ops/pallas_kv_write.py's
-                    copy a page), write_mla_entry              trunk.decode_glue_ms (decode/),
+                    copy a page), write_mla_entry (the latent
+                    vector, either way)                        trunk.decode_glue_ms (decode/),
                                                               kv.prefill_write_device_share (prefill/, chunk/)
     attn.kernel     each Pallas or reference attention call (ops/attention.py,
                     ops/pallas_*attention*.py, _ragged_reference_attn); under
                     decode/ the paged decode kernel's calls over the attention
-                    layers ARE the span's fused decode steps
+                    layers ARE the span's fused decode steps (its latent
+                    entry too: mla.decode_attn_*; the ragged kernel's under
+                    prefill/ and chunk/, mla.prefill_attn_device_share)
     attn.out        _attn_residual (the heads' output projection and its
                     add to the residual stream), _mla_unabsorb  trunk.decode_proj_ms
     mlp             _mlp_residual, _mlp (its norms, the dense gated or

@@ -236,6 +236,10 @@ class EngineStats:
     # row each: the packed prefills and whole-page chunks of an engine
     # whose Pallas kernels are on (ops/attention.py kv_stream_by_page)
     prefill_kv_tokens_paged_total: int = 0
+    # context tokens that an MLA model's dispatches other than prefills
+    # (decode, a window at its first step, verify, mixed) attended against
+    # latent pages: the step records' ctx_tokens summed (host integers)
+    kv_latent_tokens_attended_total: int = 0
     # requests whose first token was still on the device when the next
     # dispatch was enqueued (read behind it), against those whose record
     # had to be read before it (Engine._flush_first): deferred /
@@ -423,12 +427,23 @@ class Engine:
         self.cache_cfg = config.cache
         self.attn_impl = config.resolve_attn_impl()
         if self.model_cfg.is_mla and self.attn_impl == "pallas":
-            # MLA attends in latent space against a 1-head latent cache;
-            # the Pallas kernels assume materialised per-head K/V pages.
-            # The XLA reference path still gets the MLA win (the ~10x
-            # smaller cache IS the bandwidth saving).
-            self._no_pallas("MLA models attend in latent space; the Pallas "
-                            "kernels need per-head K/V pages")
+            # MLA attends in latent space against a 1-head latent cache.
+            # The paged decode and ragged kernels read it in the absorbed
+            # form (V the first kv_lora_rank lanes of the K page they
+            # landed: ops/pallas_paged_attention.py ``v_lanes``) on one
+            # device and in the model's own dtype; what they cannot do is
+            # refused here, each by name.
+            if mesh is not None or jax.process_count() > 1:
+                self._no_pallas(
+                    "a latent cache is ONE kv head, which the Pallas "
+                    "kernels' head-parallel tp wrappers cannot split: MLA "
+                    "under a mesh attends on the reference path")
+            elif jnp.dtype(config.cache.dtype) != param_dtype(self.model_cfg):
+                self._no_pallas(
+                    f"the latent kernels read pages in the model's own "
+                    f"dtype: a {config.cache.dtype} latent cache is "
+                    "dequantized by slice scales (latent, rope) that only "
+                    "the reference path applies")
         self.mesh = mesh
         from tpuserve.parallel.mesh import AXIS_PP
         self._pp = mesh.shape.get(AXIS_PP, 1) if mesh is not None else 1
@@ -546,11 +561,12 @@ class Engine:
                     "every stage)")
             if self.model_cfg.is_mla or self.model_cfg.moe_first_k_dense:
                 raise ValueError(
-                    "pipeline parallelism is not supported for DeepSeek "
-                    "models yet: the staged trunk stacks homogeneous layer "
-                    "pytrees and materialised {'k','v'} pages, which MLA's "
-                    "latent cache and first_k_dense_replace's mixed layer "
-                    "structure both break — use tp instead")
+                    "pipeline parallelism is not supported for latent "
+                    "attention or leading dense layers yet: the staged trunk "
+                    "stacks homogeneous layer pytrees and materialised "
+                    "{'k','v'} pages, which MLA's latent cache and "
+                    "first_k_dense_replace's mixed layer structure both "
+                    "break — use tp instead")
             if self.attn_impl == "pallas":
                 self._no_pallas("the pipeline engine runs reference "
                                 "attention; Pallas-under-pp is future work")
@@ -714,15 +730,17 @@ class Engine:
         # tokens it was given.  Taken where the ragged kernel gives the
         # (B, L) route's result at speed, from what the engine observes
         # (no option): not under a mesh (the kernel has no tp wrapper —
-        # which also covers multi-host), not on the pipeline engine, not
-        # for MLA (the (B, L) route attends the decompressed fresh K/V)
+        # which also covers multi-host), not on the pipeline engine,
         # and only with pages in the model's own dtype — the (B, L)
         # route attends the FRESH K/V, and reading them back from int8 or
-        # narrower pages is a different result, not a faster one.  The
-        # descriptors have ONE fixed width for these dispatches too.
+        # narrower pages is a different result, not a faster one.  (An
+        # MLA model's packed prefill attends the latent pages it just
+        # wrote in the absorbed form, its (B, L) route the decompressed
+        # fresh K/V: the same numbers, and only the packed one has a
+        # Pallas form.)  The descriptors have ONE fixed width for these
+        # dispatches too.
         self._packed_prefill = (
             mesh is None and self._pp == 1 and jax.process_count() == 1
-            and not self.model_cfg.is_mla
             and jnp.dtype(self.cache_cfg.dtype) == param_dtype(self.model_cfg))
         self._prefill_seqs = next_power_of_2(sched_cfg.max_prefill_seqs)
         # Pallas-under-tp runs the phase-split kernels via shard_map
@@ -2041,6 +2059,8 @@ class Engine:
             self.stats.prefill_tokens_total += actual
             self.stats.prefill_padded_tokens_total += padded
             self.stats.prefill_kv_tokens_paged_total += actual * kv_by_page
+        elif self.model_cfg.is_mla:
+            self.stats.kv_latent_tokens_attended_total += ctx_tokens
         self.stats.step_actual_tokens = actual
         self.stats.step_padded_tokens = padded
         self.stats.step_ctx_tokens = ctx_tokens

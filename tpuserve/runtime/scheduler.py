@@ -34,10 +34,16 @@ def packed_prefill_bucket(rows: int, blk: int) -> int:
     T is the only dimension a packed dispatch varies, so a rung costs one
     executable (one to two seconds of warm-up each at the benchmark's
     sizes, which is what keeps the ladder this coarse above 2,048, where
-    few batches land).  Under a third of a dispatch is the ladder's padding."""
+    few batches land).  Under a third of a dispatch is the ladder's padding.
+    A step is never under an eighth of the rows' power of two: that leaves
+    the ladders of 64- and 128-row blocks as they are and keeps a SMALL
+    block's (8 rows: 128 query heads of a 640-lane latent) from growing
+    with the budget over the block (72 rungs of 128 rows to 8,192, each an
+    executable that stays loaded on the chip; 28 so)."""
     if rows <= blk:
         return blk
     step = (2 if rows <= 16 * blk else 8 if rows <= 32 * blk else 16) * blk
+    step = max(step, next_power_of_2(rows) // 8)
     return -(-rows // step) * step
 
 
@@ -47,7 +53,7 @@ class SchedulerConfig:
     # Per-step prefill token budget, charged by admission as (power-of-2
     # bucket of the batch's longest prompt) x (prompts picked) on every
     # route (block_manager.admit_prefill).  Only the (batch x length)
-    # route — mesh, pipeline, multi-host, MLA and quantized/narrower-KV
+    # route — mesh, pipeline, multi-host and quantized/narrower-KV
     # engines — also DISPATCHES that grid; the single-chip engine packs
     # the same batch on one flat token axis (Engine._packed_prefill),
     # whose ladder tops out at this budget.

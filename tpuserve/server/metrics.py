@@ -171,6 +171,13 @@ class ServerMetrics:
             "and V went into the paged cache one copy a page (packed "
             "prefills and whole-page chunks with the Pallas kernels on) "
             "rather than one scatter row a token")
+        self.kv_latent_tokens_attended = counter(
+            "tpuserve_kv_latent_tokens_attended_total",
+            "Context tokens that decode, window (at its first step), "
+            "verify and mixed dispatches attended against LATENT pages "
+            "(latent attention: one compressed K/V row a token a layer), "
+            "the step records' ctx_tokens summed; 0 for a model whose "
+            "cache holds K and V pages")
         self.first_tokens_deferred = counter(
             "tpuserve_prefill_first_tokens_deferred",
             "Prefilled requests whose first token was still on the device "

@@ -1010,6 +1010,8 @@ class AsyncEngineRunner:
                                   self.metrics.prefill_packed_steps),
                                  ("prefill_kv_tokens_paged_total",
                                   self.metrics.prefill_kv_tokens_paged),
+                                 ("kv_latent_tokens_attended_total",
+                                  self.metrics.kv_latent_tokens_attended),
                                  ("prefill_first_token_deferred",
                                   self.metrics.first_tokens_deferred),
                                  ("prefill_first_token_flushed_early",
