@@ -10,8 +10,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from chip_v5e import (CHUNK, LATENT, MAX_PAGES, PAGE, PREFILL_SEQS,
-                      openpangu_share, shapes_on)
+from chip_v5e import (CHUNK, LATENT, MAX_NUM_SEQS, MAX_PAGES, PAGE,
+                      PREFILL_SEQS, WIDTHS, openpangu_share, shapes_on)
 from chip_v5e import (  # noqa: F401  (fixtures, found by name)
     _no_persistent_cache, one_chip, topo)
 
@@ -20,6 +20,9 @@ ROWS = 128              # the cell's decode seats (--max-num-seqs 128)
 # 15.75 GiB less 12.32 GB of weights, over 286,720 B a page: 2.90 GB
 POOL = 10104
 SCALE = 192 ** -0.5
+# the latent decode call's (pages a chunk, pages a tile, sequences a
+# program) at 128 rows: a chunk of 512 tokens contracted whole, 16 rows
+LATENT_TILING = (16, 16, 16)
 
 
 def _decode_call(one_chip, lanes):
@@ -47,6 +50,67 @@ def test_the_latent_decode_call_compiles_under_its_name(one_chip):
                      rf"\[{ROWS},{hq},{v_lanes}\]", text)
     assert re.search(rf"bf16\[{POOL},{PAGE},{lanes}\]\S* bitcast\(", text)
     assert not re.search(rf"bf16\[{POOL},\S* copy\(", text)
+
+
+def test_the_latent_decode_call_fits_what_the_footprint_says(one_chip,
+                                                             monkeypatch):
+    """The latent entry's own kernel (three slots of K pages, every
+    chunk's pages started straight-line and waited for once) at the
+    tiling the rule gives, the triple written out: handed NO more scoped
+    VMEM than ``vmem_footprint`` counts, the chip's compiler still takes
+    it, so the clamp's bound is at or above what the compiler needs and
+    inside ``VMEM_LIMIT_BYTES``; under the call's name, the pool a
+    bitcast."""
+    from jax.experimental.pallas import tpu as pltpu
+    from tpuserve.ops import pallas_paged_attention as ppa
+    hq, lanes, v_lanes = LATENT
+    pages_g, pages_t, seqs_pp = ppa.decode_tiling(PAGE, 1, MAX_PAGES, ROWS)
+    pages_g, seqs_pp = ppa._clamp_to_vmem_budget(
+        pages_g, seqs_pp, PAGE, 1, lanes, 2, hq, 2, v_lanes=v_lanes)
+    assert (pages_g, ppa._tile_pages(pages_g, pages_t),
+            seqs_pp) == LATENT_TILING
+    bound = ppa.vmem_footprint(pages_g, seqs_pp, 1, PAGE, 1, lanes, 2, hq, 2,
+                               decode=True, v_lanes=v_lanes)
+    assert bound <= ppa.VMEM_LIMIT_BYTES
+    monkeypatch.setattr(
+        ppa, "compiler_params", lambda *semantics: pltpu.CompilerParams(
+            dimension_semantics=semantics, vmem_limit_bytes=bound))
+    text = _decode_call(one_chip, lanes).compile().as_text()
+    assert re.search(rf"%_paged_decode_attention(?:\.\d+)? = bf16"
+                     rf"\[{ROWS},{hq},{v_lanes}\]", text)
+    assert re.search(rf"bf16\[{POOL},{PAGE},{lanes}\]\S* bitcast\(", text)
+    # and a quarter less is refused: the bound is no loose one
+    monkeypatch.setattr(
+        ppa, "compiler_params", lambda *semantics: pltpu.CompilerParams(
+            dimension_semantics=semantics, vmem_limit_bytes=bound * 3 // 4))
+    jax.clear_caches()
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _decode_call(one_chip, lanes).compile()
+
+
+# (pages a chunk, pages a tile, sequences a program) of the K/V entry at
+# the widths the cells run, as they stood before the latent entry had a
+# kernel of its own (PR 51): a change to the latent rule must not move them
+KV_TILINGS = {
+    "qwen3-0.6b": (16, 8, 64),
+    "llama-8b": (16, 8, 64),
+    "llama-8b-tp4": (16, 16, 64),
+    "falcon-h1-34b": (16, 16, 64),
+    "mellum2-12b": (16, 16, 64),
+    "olmo-hybrid-7b": (16, 2, 64),
+}
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_the_kv_entrys_tiling_is_what_it_was(width):
+    from tpuserve.ops import pallas_paged_attention as ppa
+    hq, hkv, d = WIDTHS[width]
+    pages_g, pages_t, seqs_pp = ppa.decode_tiling(PAGE, hkv, MAX_PAGES,
+                                                  MAX_NUM_SEQS)
+    pages_g, seqs_pp = ppa._clamp_to_vmem_budget(pages_g, seqs_pp, PAGE, hkv,
+                                                 d, 2, hq, 2)
+    assert (pages_g, ppa._tile_pages(pages_g, pages_t),
+            seqs_pp) == KV_TILINGS[width]
 
 
 def test_a_page_of_576_lanes_as_it_is_has_no_whole_tiles_to_copy(one_chip):

@@ -144,6 +144,53 @@ def test_paged_decode_at_the_cells_head_shapes(case, Hq, Hkv):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+@pytest.mark.parametrize("Hq", [16, 128])
+@pytest.mark.parametrize("case", ["edges", "stale_nan", "window",
+                                  "chunk_of_32"])
+def test_the_latent_decode_entry_matches_reference(case, Hq, monkeypatch):
+    """The latent entry's own kernel (one KV head, V the first ``v_lanes``
+    lanes of the K page; every chunk starts all its pages and is waited
+    for once, two chunks ahead over three slots) at DeepSeek-V2-Lite's 16
+    heads and openPangu's 128, two programs a batch, chunks of 16 tokens:
+    rows of length 0 (a padded row: zeros), 1, exactly a chunk, exactly
+    two, two and one more, and 49, whose last chunk holds ONE attended row
+    beside slots the cache never wrote and pages that are not the
+    sequence's (``stale_nan``: NaN wherever no row attends, which must not
+    reach the output; the table's entries past a sequence's end name pages
+    of NaN).  ``window``: the last 20 positions, so whole chunks before
+    the window are never started.  ``chunk_of_32``: the chunk as asked for,
+    not cut to the tile."""
+    from tpuserve.ops import pallas_paged_attention as ppa
+    D, v_lanes, page, mp = 256, 128, 8, 8
+    if case != "chunk_of_32":
+        monkeypatch.setattr(ppa, "DECODE_TILE_COLUMNS", 16)
+    window = 20 if case == "window" else None
+    lens = [0, 1, 16, 32, 33, 49]
+    B, nb = len(lens), len(lens) * mp
+    rng = np.random.default_rng(Hq + len(case))
+    q = jnp.asarray(rng.standard_normal((B, Hq, D)), jnp.float32)
+    kc = rng.standard_normal((nb, page, 1, D)).astype(np.float32)
+    kc[..., 200:] = 0.0             # a page is whole lane tiles: zeros past
+    bt = rng.permutation(nb).reshape(B, mp).astype(np.int32)
+    sl = jnp.asarray(lens, jnp.int32)
+    ref = ref_ops.paged_decode_attention(
+        q, jnp.asarray(kc), jnp.asarray(kc), jnp.asarray(bt), sl,
+        D ** -0.5, sliding_window=window)[..., :v_lanes]
+    if case == "stale_nan":
+        attended = np.zeros((nb, page), bool)
+        for b, n in enumerate(lens):
+            for pos in range(n):
+                attended[bt[b, pos // page], pos % page] = True
+        kc = np.where(attended[:, :, None, None], kc, np.nan)
+    out = np.asarray(paged_decode_attention(
+        q, jnp.asarray(kc), None, jnp.asarray(bt), sl, D ** -0.5,
+        interpret=True, v_lanes=v_lanes, pages_per_group=4,
+        seqs_per_program=3, sliding_window=window))
+    assert out.shape == (B, Hq, v_lanes)
+    assert not out[0].any()
+    np.testing.assert_allclose(out[1:], np.asarray(ref)[1:], atol=2e-5)
+
+
 def test_paged_decode_int8_matches_reference():
     """int8 cache path: the Pallas kernel DMAs int8 pages + scale blocks
     and dequantizes in VMEM; must match the reference impl fed the same

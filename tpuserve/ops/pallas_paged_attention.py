@@ -46,6 +46,29 @@ bounds it is its DMAs (two descriptors a page: 658 and 576 GB/s alone).
   columns wide.  Both are
   :func:`decode_tiling`'s, a static function of the shapes.
 
+- **Which operand the MXU holds, in both entries: the cached tokens.**
+  A tile of K (then of V) is the held operand and the sequence's query
+  heads are the rows that stream past it, in the K/V entry (16-32 rows)
+  and in the LATENT entry (:func:`_latent_decode_kernel`: one KV head,
+  128 rows, V the first ``v_lanes`` lanes of the landed K page) alike.
+  Holding the query heads instead (``s^T = K q^T``, ``o^T = V^T p^T``) was
+  measured on a v5e at the latent cell's shape and is no faster: 5.97 ns
+  a cached token a layer against this form's 5.95, scores alone
+  transposed 7.17 (PERF.md section 6, PR 51): with no page copy at all
+  the products, the softmax and the per-sequence work take 3.87 ns in
+  this form (the two products ~2.2, against 1.81 at the MXU's peak for
+  the tokens a chunk pads to) and 4.19 in the transposed one, which pays
+  V's turn through the transposition unit.
+- **What the latent entry does not share is the page pipeline.**  The
+  K/V entry's (a loop over a chunk's attended pages to start them,
+  another to wait for them) is scalar code that runs BETWEEN the
+  products, not beside them: 3.0 ns a cached token a layer with no
+  product at all, which the K/V entry hides (its 16-32 query rows make
+  short products and its time is its copies') and the latent entry
+  pays in full on top of 3.9 of products.  So the latent entry starts a
+  chunk's pages straight-line, all of them, waits for a chunk once by
+  its bytes and runs two chunks ahead over three slots: 4.8 ns.
+
 Semantics match ``tpuserve.ops.attention.paged_decode_attention``; verified
 against it in interpret mode on CPU.
 """
@@ -95,7 +118,7 @@ def vmem_footprint(pages_g: int, block_rows: int, dot_rows: int,
                    page_size: int, num_kv_heads: int, head_dim: int,
                    kv_itemsize: int, num_q_heads: int, q_itemsize: int,
                    quantized: bool = False, decode: bool = False,
-                   flat_page: bool = False) -> int:
+                   flat_page: bool = False, v_lanes: int | None = None) -> int:
     """Upper bound on the scoped VMEM one program of a paged kernel needs.
 
     ``block_rows``: q rows in the pipelined q/out block (seqs_per_program
@@ -132,7 +155,10 @@ def vmem_footprint(pages_g: int, block_rows: int, dot_rows: int,
         the default scope.  ``decode``: one tile of ``DECODE_TILE_COLUMNS``
         key columns at a time — the (Hq, columns) f32 score tile, its
         exponentials, the head mask and a spare, the K and V tiles as
-        values, and an int8 tile's f32 dequantization."""
+        values, and an int8 tile's f32 dequantization.  ``decode`` with
+        ``v_lanes`` (a LATENT entry, :func:`_latent_decode_kernel`): the
+        same body on a whole chunk, over ``LATENT_SLOTS`` slots of K
+        pages and no V scratch."""
     from tpuserve.utils import round_up
     lanes = round_up(head_dim, 128)   # lane dim pads to the 128 width too
     rows_g = pages_g * page_size
@@ -144,6 +170,8 @@ def vmem_footprint(pages_g: int, block_rows: int, dot_rows: int,
                           MIN_SUBLANES.get(kv_itemsize, 8))
         slab_t = min(rows_g * num_kv_heads, DECODE_TILE_COLUMNS)
         kv = 2 * 2 * slab_g * lanes * kv_itemsize
+        if v_lanes:
+            kv = LATENT_SLOTS * slab_g * lanes * kv_itemsize
         score_tile = round_up(num_q_heads, 8) * round_up(slab_t, 128) * 4
         kv_values = 2 * slab_t * lanes * (q_itemsize
                                           + (2 * 4 if quantized else 0))
@@ -169,7 +197,8 @@ def _clamp_to_vmem_budget(pages_g: int, block_rows: int, page_size: int,
                           kv_itemsize: int, num_q_heads: int,
                           q_itemsize: int, quantized: bool = False,
                           rows_per_dot: bool = False,
-                          flat_page: bool = False) -> tuple[int, int]:
+                          flat_page: bool = False,
+                          v_lanes: int | None = None) -> tuple[int, int]:
     """Shrink (pages_g, block_rows) until :func:`vmem_footprint` fits
     ``VMEM_LIMIT_BYTES``.  ``rows_per_dot``: the kernel contracts its
     whole q block at once (window/ragged) rather than a sequence at a
@@ -183,7 +212,8 @@ def _clamp_to_vmem_budget(pages_g: int, block_rows: int, page_size: int,
         return vmem_footprint(pg, br, br if rows_per_dot else 1, page_size,
                               num_kv_heads, head_dim, kv_itemsize,
                               num_q_heads, q_itemsize, quantized,
-                              decode=not rows_per_dot, flat_page=flat_page)
+                              decode=not rows_per_dot, flat_page=flat_page,
+                              v_lanes=v_lanes)
 
     orig = (pages_g, block_rows)
     from tpuserve.utils import round_up
@@ -220,6 +250,10 @@ DECODE_TILE_COLUMNS = 2048
 # (64 rows in one program read 2 % faster than in eight).
 MAX_SEQS_PER_PROGRAM = 64
 
+# Slots of the latent entry's page pipeline: while one chunk is contracted
+# the next has landed or is landing, and the one after it is being started.
+LATENT_SLOTS = 3
+
 
 def decode_tiling(page_size: int, num_kv_heads: int, max_pages: int,
                   batch: int) -> tuple[int, int, int]:
@@ -228,7 +262,12 @@ def decode_tiling(page_size: int, num_kv_heads: int, max_pages: int,
     chunk of ``TARGET_GROUP_ROWS`` tokens (256 and 1,024 read within 3 %
     of it), compute tiles of ``DECODE_TILE_COLUMNS`` key columns (a whole
     number of them a chunk), the whole batch in one program up to
-    ``MAX_SEQS_PER_PROGRAM`` rows."""
+    ``MAX_SEQS_PER_PROGRAM`` rows.  One KV head (a latent entry) makes a
+    tile as wide as the chunk, which its kernel contracts whole: measured
+    at 128 query heads on 640 lanes with a wait a page, a chunk of 512
+    tokens reads 5.0 ns a cached token a layer, 256 and 1,024 tokens 5.3
+    and 5.4-5.5 (more chunks, or more pages started past a sequence's
+    end; PERF.md section 6, PR 51)."""
     pages_g = min(max(1, -(-TARGET_GROUP_ROWS // page_size)), max_pages)
     pages_t = max(1, DECODE_TILE_COLUMNS // (page_size * num_kv_heads))
     return pages_g, _tile_pages(pages_g, pages_t), min(
@@ -255,8 +294,7 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
                          k_scr, v_scr, sems, *, scale, page_size, pages_g,
                          pages_t, num_kv_heads, group, head_dim, seqs_pp,
                          ks_hbm=None, vs_hbm=None, ks_scr=None, vs_scr=None,
-                         sliding_window=None, logit_softcap=None,
-                         v_lanes=None):
+                         sliding_window=None, logit_softcap=None):
     """A page is contracted in the layout it is stored in: ``k_hbm`` /
     ``v_hbm`` are the cache seen as ``(num_blocks, page x Hkv, D)`` slabs
     (row ``t x Hkv + h``), a page lands in full tiles of the scratch as
@@ -269,15 +307,10 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
     ``ks_hbm``/``vs_hbm`` present = int8 cache: value pages DMA as int8
     beside their scale pages and are dequantized in the slab layout.
 
-    ``v_lanes`` (static) set = a LATENT entry (``v_hbm`` and ``v_scr`` are
-    None): V is the first ``v_lanes`` lanes of the landed K page, so no V
-    page is read and the output is ``v_lanes`` wide.
-
     ``sliding_window`` (static): attend only the last W cached positions;
     pages entirely BEFORE the window are never DMA'd and tiles entirely
     before it never contracted."""
     quantized = ks_hbm is not None
-    latent = v_lanes is not None
     base = pl.program_id(0) * seqs_pp
     num_q_heads = num_kv_heads * group
     rows_g = pages_g * page_size        # tokens a DMA chunk
@@ -312,11 +345,9 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
         page = bt_ref[base + s, g * pages_g + j]
         rows = pl.ds(pl.multiple_of(j * slab_p, slab_p), slab_p)
         copies = [pltpu.make_async_copy(k_hbm.at[page], k_scr.at[slot, rows],
-                                        sems.at[0, slot, j])]
-        if not latent:
-            copies.append(
-                pltpu.make_async_copy(v_hbm.at[page], v_scr.at[slot, rows],
-                                      sems.at[1, slot, j]))
+                                        sems.at[0, slot, j]),
+                  pltpu.make_async_copy(v_hbm.at[page], v_scr.at[slot, rows],
+                                        sems.at[1, slot, j])]
         if quantized:
             copies += [
                 pltpu.make_async_copy(ks_hbm.at[page], ks_scr.at[slot, j],
@@ -371,7 +402,7 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         m0 = jnp.full((num_q_heads, 1), NEG_INF, jnp.float32)
         l0 = jnp.zeros((num_q_heads, 1), jnp.float32)
-        acc0 = jnp.zeros((num_q_heads, v_lanes or head_dim), jnp.float32)
+        acc0 = jnp.zeros((num_q_heads, head_dim), jnp.float32)
 
         def chunk_body(i, carry):
             g = g0 + i
@@ -406,8 +437,7 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
                 # its probability is exactly 0, but 0 x NaN would poison
                 # the accumulator.  Only a sequence's first and last
                 # tile can hold one, so only they are rewritten (an int8
-                # tile is rewritten anyway: the select rides on that).  A
-                # latent entry's V is its K page: that is the one cleaned.
+                # tile is rewritten anyway: the select rides on that).
                 def attended_rows():
                     r = jax.lax.broadcasted_iota(jnp.int32, (slab_t, 1), 0)
                     return (r >= c_lo) & (r < c_hi)
@@ -415,13 +445,12 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
                 if not quantized:
                     @pl.when((c_lo > 0) | (c_hi < slab_t))
                     def _clean_v():
-                        scr = k_scr if latent else v_scr
-                        v_t = scr[slot, rows]
-                        scr[slot, rows] = jnp.where(attended_rows(), v_t,
-                                                    jnp.zeros_like(v_t))
+                        v_t = v_scr[slot, rows]
+                        v_scr[slot, rows] = jnp.where(attended_rows(), v_t,
+                                                      jnp.zeros_like(v_t))
 
                 k = k_scr[slot, rows]
-                v = k[:, :v_lanes] if latent else v_scr[slot, rows]
+                v = v_scr[slot, rows]
                 if quantized:
                     k = dequant(k, ks_scr, slot, t)
                     v = dequant(v, vs_scr, slot, t, keep=attended_rows())
@@ -459,6 +488,155 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
         return parity0 + neff
 
     jax.lax.fori_loop(0, seqs_pp, seq_body, 0)
+
+
+def _latent_decode_kernel(bt_ref, sl_ref, q_ref, k_hbm, o_ref, k_scr, sems, *,
+                          scale, page_size, pages_g, seqs_pp, v_lanes,
+                          sliding_window=None, logit_softcap=None):
+    """The LATENT entry (MLA's absorbed form): ONE KV head, all the query
+    heads (128 at the published widths) against it, V the first
+    ``v_lanes`` lanes of the K page as it landed, so no V page exists and
+    the output is ``v_lanes`` wide.  ``k_hbm`` is the cache seen as
+    ``(num_blocks, page, D)``.
+
+    The contraction is the K/V entry's (``s = q K^T``, ``o = p V``, bf16
+    MXU inputs, float32 accumulation, the scale on the float32 product)
+    with nothing to tell heads apart; the PAGE PIPELINE is this entry's
+    own, because at 128 query rows the products are as long as the page
+    copies' issue, and the K/V entry's pipeline (a loop over a chunk's
+    attended pages to start them, another to wait for them) runs as
+    scalar code between the products, which it does not overlap:
+
+    - a chunk starts ALL its ``pages_g`` pages, straight-line, and a
+      sequence's last chunk reads whatever pages the table names past its
+      end (their rows are masked like any position past the end, and V's
+      are zeroed before the product);
+    - every page of a chunk signals the slot's one semaphore and the
+      chunk is waited for ONCE, by its bytes;
+    - the pipeline is two chunks deep over ``LATENT_SLOTS`` slots, across
+      sequence boundaries, so a chunk's copies have two products to land
+      behind.
+
+    Measured on a v5e at the openPangu cell's shape (128 rows, contexts
+    256-3,072, PERF.md section 6, PR 51): 4.8 ns a cached token a layer
+    against the shared pipeline's 5.95; the same call with no copies at
+    all 3.8, with the copies alone 3.4."""
+    base = pl.program_id(0) * seqs_pp
+    num_q_heads = q_ref.shape[1]
+    rows_g = pages_g * page_size        # tokens a chunk
+    last_page, last_col = k_hbm.shape[0] - 1, bt_ref.shape[1] - 1
+
+    def win_start(s):
+        # first attended position (0 without a window)
+        if sliding_window is None:
+            return jnp.int32(0)
+        return jnp.maximum(sl_ref[base + s] - sliding_window, 0)
+
+    def first_chunk(s):
+        return win_start(s) // rows_g
+
+    def num_chunks(s):
+        # >= 1 so a padded or empty row keeps the pipeline uniform
+        return jnp.maximum(pl.cdiv(sl_ref[base + s], rows_g),
+                           1) - first_chunk(s)
+
+    def start(s, i, slot, straight_line=True):
+        first = (first_chunk(s) + i) * pages_g
+
+        def one(j, _=None):
+            page = bt_ref[base + s, jnp.minimum(first + j, last_col)]
+            rows = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            pltpu.make_async_copy(k_hbm.at[jnp.clip(page, 0, last_page)],
+                                  k_scr.at[slot, rows], sems.at[slot]).start()
+
+        if straight_line:
+            for j in range(pages_g):
+                one(j)
+        else:   # before a program's first product a loop is as fast, and
+            # the program smaller than the K/V entry's was (it lives in HBM)
+            jax.lax.fori_loop(0, pages_g, one, None)
+
+    def wait(slot):
+        # the semaphore counts bytes: one wait for the chunk's, whatever
+        # the descriptor's source says
+        pltpu.make_async_copy(k_scr.at[slot], k_scr.at[slot],
+                              sems.at[slot]).wait()
+
+    def succ(s, i):
+        """The chunk after chunk ``i`` of sequence ``s``; past the
+        program's last chunk, its last sequence's first again (started
+        like any other, waited for after the loop)."""
+        last = i + 1 >= num_chunks(s)
+        return (jnp.where(last, jnp.minimum(s + 1, seqs_pp - 1), s),
+                jnp.where(last, 0, i + 1))
+
+    chunk = (jnp.int32(0), jnp.int32(0))
+    for slot in range(LATENT_SLOTS - 1):
+        start(*chunk, slot, straight_line=False)
+        chunk = succ(*chunk)
+
+    def seq_body(s, count0):
+        seq_len = sl_ref[base + s]
+        ws = win_start(s)
+        g0, n = first_chunk(s), num_chunks(s)
+        q = q_ref[s]                    # (Hq, D), stored dtype
+
+        m0 = jnp.full((num_q_heads, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((num_q_heads, 1), jnp.float32)
+        acc0 = jnp.zeros((num_q_heads, v_lanes), jnp.float32)
+
+        def chunk_body(i, carry):
+            m_prev, l_prev, acc_prev = carry
+            slot = jax.lax.rem(count0 + i, LATENT_SLOTS)
+            wait(slot)
+            ahead = (s, i)
+            for _ in range(LATENT_SLOTS - 1):
+                ahead = succ(*ahead)
+            # the chunk's attended positions, relative to its first
+            g = g0 + i
+            lo = jnp.maximum(ws - g * rows_g, 0)
+            hi = jnp.minimum(seq_len - g * rows_g, rows_g)
+
+            # V is this page: a row outside [lo, hi) is a slot the cache
+            # never wrote or another sequence's, its probability is
+            # exactly 0, but 0 x NaN would poison the accumulator.  Only
+            # a sequence's first and last chunk can hold one.
+            @pl.when((lo > 0) | (hi < rows_g))
+            def _clean_v():
+                r = jax.lax.broadcasted_iota(jnp.int32, (rows_g, 1), 0)
+                k_c = k_scr[slot]
+                k_scr[slot] = jnp.where((r >= lo) & (r < hi), k_c,
+                                        jnp.zeros_like(k_c))
+
+            start(*ahead, jax.lax.rem(count0 + i + LATENT_SLOTS - 1,
+                                      LATENT_SLOTS))
+            k = k_scr[slot]
+            sc = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if logit_softcap is not None:
+                sc = logit_softcap * jnp.tanh(sc / logit_softcap)
+            c = jax.lax.broadcasted_iota(jnp.int32, (1, rows_g), 1)
+            sc = jnp.where((c >= lo) & (c < hi), sc, NEG_INF)
+
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+            pr = jnp.exp(sc - m_new)
+            correction = jnp.exp(m_prev - m_new)
+            l_new = l_prev * correction + jnp.sum(pr, axis=1, keepdims=True)
+            v = k[:, :v_lanes]
+            pv = jax.lax.dot_general(pr.astype(v.dtype), v,
+                                     (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            return m_new, l_new, acc_prev * correction + pv
+
+        m, l, acc = jax.lax.fori_loop(0, n, chunk_body, (m0, l0, acc0))
+        safe_l = jnp.where(l == 0.0, 1.0, l)
+        o_ref[s] = (acc / safe_l).astype(o_ref.dtype)
+        return count0 + n
+
+    count = jax.lax.fori_loop(0, seqs_pp, seq_body, 0)
+    for d in range(LATENT_SLOTS - 1):   # the starts past the last chunk
+        wait(jax.lax.rem(count + d, LATENT_SLOTS))
 
 
 def paged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
@@ -503,8 +681,10 @@ def paged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         pages_g, seqs_pp = _clamp_to_vmem_budget(
             pages_g, seqs_pp, page_size, k_cache.shape[2], k_cache.shape[3],
             k_cache.dtype.itemsize, q.shape[1], q.dtype.itemsize,
-            quantized=k_scale is not None)
+            quantized=k_scale is not None, v_lanes=v_lanes)
         pages_t = _tile_pages(pages_g, pages_t)
+        if v_lanes:     # the latent kernel contracts a chunk whole
+            pages_g = pages_t
         scales = () if k_scale is None else (k_scale, v_scale)
         if (v_cache is None) != (v_lanes is not None) or (
                 v_lanes and scales):
@@ -554,7 +734,7 @@ def _paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens,
         _paged_decode_kernel, scale=scale, page_size=page_size,
         pages_g=pages_g, pages_t=pages_t, num_kv_heads=Hkv, group=group,
         head_dim=D, seqs_pp=seqs_pp, sliding_window=sliding_window,
-        logit_softcap=logit_softcap, v_lanes=v_lanes)
+        logit_softcap=logit_softcap)
     # operand order must mirror the in_specs/scratch below
     base_kernel = kernel
     if quantized:
@@ -564,21 +744,26 @@ def _paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens,
                                k_scr, v_scr, sems, ks_hbm=ks_hbm,
                                vs_hbm=vs_hbm, ks_scr=ks_scr, vs_scr=vs_scr)
     elif v_lanes:
-        def kernel(bt, sl, q_ref, k_hbm, o_ref, k_scr, sems):
-            return base_kernel(bt, sl, q_ref, k_hbm, None, o_ref, k_scr,
-                               None, sems)
+        kernel = functools.partial(
+            _latent_decode_kernel, scale=scale, page_size=page_size,
+            pages_g=pages_g, seqs_pp=seqs_pp, v_lanes=v_lanes,
+            sliding_window=sliding_window, logit_softcap=logit_softcap)
 
     # the caches stay in HBM
     in_specs = [pl.BlockSpec((seqs_pp, Hq, D), lambda p, bt, sl: (p, 0, 0))
                 ] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pages)
     slab_g = pages_g * page_size * Hkv
-    scratch = [pltpu.VMEM((2, slab_g, D), c.dtype) for c in pages]
-    if quantized:
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2   # scale pages
-        scratch += [pltpu.VMEM((2, pages_g, page_size, SCALE_LANES),
-                               jnp.float32)] * 2
-    scratch.append(pltpu.SemaphoreType.DMA((4 if quantized else 2,
-                                            2, pages_g)))
+    if v_lanes:
+        scratch = [pltpu.VMEM((LATENT_SLOTS, slab_g, D), k_cache.dtype),
+                   pltpu.SemaphoreType.DMA((LATENT_SLOTS,))]
+    else:
+        scratch = [pltpu.VMEM((2, slab_g, D), c.dtype) for c in pages]
+        if quantized:
+            in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2  # scale pages
+            scratch += [pltpu.VMEM((2, pages_g, page_size, SCALE_LANES),
+                                   jnp.float32)] * 2
+        scratch.append(pltpu.SemaphoreType.DMA((4 if quantized else 2,
+                                                2, pages_g)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(Bp // seqs_pp,),
