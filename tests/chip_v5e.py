@@ -46,7 +46,7 @@ MAX_PAGES = 128      # 4096-token sequences
 MAX_NUM_SEQS = 64    # SchedulerConfig.max_num_seqs
 CHUNK = 2048         # SchedulerConfig.prefill_chunk_size
 MIN_BUCKET = 32      # SchedulerConfig.min_prefill_bucket
-MIXED_BUDGET = 512   # SchedulerConfig.mixed_token_budget
+MIXED_BUDGET = 2048  # SchedulerConfig.mixed_token_budget
 PREFILL_SEQS = 8     # SchedulerConfig.max_prefill_seqs
 
 

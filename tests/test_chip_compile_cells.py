@@ -2,13 +2,15 @@
 published widths and the depth the cell runs, beside its pages and its
 seat pool (see ``tests/test_chip_compile.py`` and ``tests/chip_v5e.py``)."""
 
+import dataclasses
 import re
 
 import jax
 import pytest
 
-from chip_v5e import (CHUNK, MAX_NUM_SEQS, MAX_PAGES, PAGE, PREFILL_SEQS,
-                      WIDTHS, k_exaone_share, olmo_hybrid, shapes_on)
+from chip_v5e import (CHUNK, MAX_NUM_SEQS, MAX_PAGES, MIXED_BUDGET, PAGE,
+                      PREFILL_SEQS, WIDTHS, k_exaone_share, olmo_hybrid,
+                      shapes_on)
 from chip_v5e import (  # noqa: F401  (fixtures, found by name)
     _no_persistent_cache, one_chip, topo)
 
@@ -168,3 +170,57 @@ def test_the_k_exaone_cell_fits_the_chip(program, tokens, one_chip,
     weights = mem.argument_size_in_bytes - mem.alias_size_in_bytes
     assert 11.9e9 < weights < 12.1e9, weights
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.5e9
+
+
+# ---- a mixed ragged step: the riding engines' rungs ----------------------
+
+def _riding(name):
+    """A riding cell's model at the smallest depth that keeps its layers'
+    pattern (a rung that compiles at one period compiles at every one: the
+    refusals are of shapes), and its engine's ragged block."""
+    from tpuserve.models.config import get_model_config
+    if name == "k-exaone-share":
+        return k_exaone_share(num_layers=4), 64
+    model, depth = {
+        "mistral-7b": ("mistralai/Mistral-7B-Instruct-v0.1", 2),
+        "mellum2-12b": ("JetBrains/Mellum2-12B-A2.5B-Instruct", 4)}[name]
+    return dataclasses.replace(get_model_config(model), num_layers=depth), 128
+
+
+# Engine.warmup's ladder for an engine that rides (the packed prefill's
+# rungs from the decode region and one block of prompt up to
+# SchedulerConfig.mixed_token_budget): its foot, its middle and its top
+@pytest.mark.parametrize("model,rung", [
+    *(("mistral-7b", t) for t in (256, 1024, MIXED_BUDGET)),
+    *(("mellum2-12b", t) for t in (256, 1024, MIXED_BUDGET)),
+    *(("k-exaone-share", t) for t in (128, 1024, MIXED_BUDGET))])
+def test_a_mixed_step_compiles_at_every_rung_a_riding_engine_warms(
+        model, rung, one_chip, monkeypatch):
+    """64 decode rows and prompt chunks on one flat token axis
+    (``forward_ragged(decode_rows=True)`` at the descriptor width of the
+    seats), at each rung of the mixed ladder, at the published widths:
+    the ragged kernel with its decode part, the decode region's K/V by
+    the row scatter and the chunks' by the page, the expert layer at the
+    rung's rows."""
+    from test_scopes import trunk_programs
+    from tpuserve.ops.pallas_ragged_attention import ragged_block_for
+
+    cfg, blk = _riding(model)
+    S, place = shapes_on(one_chip)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ragged_block_for(cfg.cache_q_heads, cfg.cache_kv_heads,
+                            cfg.cache_head_dim, PAGE, 2, 2) == blk
+    fn, args, kwargs = trunk_programs(
+        cfg, S, place, rows=MAX_NUM_SEQS, tokens=rung, blk=blk,
+        prompts=MAX_NUM_SEQS, block_size=PAGE, num_blocks=1024,
+        max_blocks=MAX_PAGES, attn_impl="pallas")["forward_ragged"]
+    text = fn.lower(*args, **{**kwargs, "decode_rows": True}
+                    ).compile().as_text()
+    for kernel in ("_ragged_paged_attention", "_paged_kv_write"):
+        assert len(re.findall(rf"%{kernel}(?:\.\d+)? = ", text)) \
+            == cfg.num_layers, kernel
+    from tpuserve.models.transformer import decode_region
+    from tpuserve.runtime.scheduler import packed_prefill_bucket
+    assert decode_region(MAX_NUM_SEQS, blk) == blk
+    assert rung in {packed_prefill_bucket(r, blk)
+                    for r in range(2 * blk, MIXED_BUDGET + 1, blk)}

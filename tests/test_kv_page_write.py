@@ -125,9 +125,11 @@ GATES = {
     # ... and every static gate sends a caller back to the row scatter
     "int8_entry": ("forward_ragged",
                    dict(block_size=8, blk=8, kv_dtype="int8"), False),
+    # (since PR 53 a mixed step's prompt chunks go by the page too, behind
+    # its decode region's rows, which keep the scatter: both are traced)
     "decode_rows": ("forward_ragged",
                     dict(block_size=8, blk=8,
-                         trunk_kw={"decode_rows": True}), False),
+                         trunk_kw={"decode_rows": True}), True),
     "ragged_block_under_a_page": ("forward_ragged",
                                   dict(block_size=16, blk=8), False),
     "chunk_under_a_page": ("prefill_chunk", dict(block_size=32, chunk=16),
@@ -144,8 +146,9 @@ def test_the_static_gates_choose_the_write(gate):
     program, kw, paged = GATES[gate]
     text = _traced(program, **kw)
     assert (KERNEL_NAME in text) is paged, gate
-    # the scatter is what every other caller traced
-    assert paged or "scatter" in text
+    # the scatter is what every other caller traced, and a mixed step's
+    # decode region
+    assert (paged and gate != "decode_rows") or "scatter" in text
 
 
 _PLAIN = {"k": jnp.zeros((4, 8, 2, 16)), "v": jnp.zeros((4, 8, 2, 16))}

@@ -279,3 +279,285 @@ def test_mixed_multilora_token_identical(tmp_path_factory):
     mix, eng = run(True)
     assert ref == mix
     assert eng.stats.num_mixed_steps > 0
+
+
+# ---- the route is observed: where a decode step is bound by its weights,
+# ---- the decode rows ride the prompt dispatches (ISSUE 53) ---------------
+
+# the pool each benchmark cell's engine sized on the chip (blocks of 32
+# tokens; its runs' "server built" lines), the seats it serves, and the
+# route its engine observes there, with the reason's first words
+CELL_ROUTES = [
+    ("qwen3-0.6b.batch", 3821, "phase_split", "a decode step is bound by its K/V"),
+    ("mistral-7b-l16.batch", 3678, "mixed", "a decode step is bound by its weights"),
+    ("falcon-h1-34b-l6.reason", 7785, "phase_split", "recurrent state"),
+    ("mellum2-12b-l12.batch", 5450, "mixed", "a decode step is bound by its weights"),
+    ("k-exaone-236b-ep8-l8.reason", 3108, "mixed", "a decode step is bound by its weights"),
+    ("olmo-hybrid-7b-l16.reason", 2471, "phase_split", "recurrent state"),
+    ("openpangu-ultra-718b-ep16-l7.reason", 10096, "phase_split", "latent attention"),
+]
+
+
+@pytest.mark.parametrize("cell,num_blocks,route,why", CELL_ROUTES,
+                         ids=[c[0] for c in CELL_ROUTES])
+def test_route_is_observed_not_configured(cell, num_blocks, route, why):
+    """Which of the benchmark's seven engines ride and which do not, and
+    why: the engine's own functions on each configuration AS IT RUNS
+    (every layer, the share of experts and vocabulary it holds; shapes
+    alone, nothing is allocated) beside the pool the chip left it."""
+    import jax
+
+    from benchmark.harness import plan, session
+    from tpuserve.models.weights import init_params
+    from tpuserve.runtime import engine as engine_mod
+    from tpuserve.runtime.kv_cache import create_kv_cache
+
+    loaded = plan.load_cell(cell, plan.load_benchmark())
+    cfg = get_model_config(session.register_configuration(loaded))
+    args = loaded.config["server_args"]
+    seats = (int(args[args.index("--max-num-seqs") + 1])
+             if "--max-num-seqs" in args else SchedulerConfig().max_num_seqs)
+    cache_cfg = CacheConfig(block_size=32, num_blocks=num_blocks,
+                            max_blocks_per_seq=128, dtype="bfloat16")
+    params = jax.eval_shape(lambda: init_params(cfg, 0))
+    pages = jax.eval_shape(lambda: create_kv_cache(cfg, cache_cfg))
+    weights, kv = engine_mod.decode_step_bytes(params, pages, cfg, cache_cfg,
+                                               seats)
+    got = engine_mod.decode_route(
+        weights, kv, excluded=engine_mod.route_excluded(
+            cfg, staged=False, mesh=False, packed=True))
+    assert (got["route"], got["rides"]) == (route, route == "mixed"), got
+    assert got["why"].startswith(why), got
+    assert (got["weight_bytes"], got["kv_bytes"]) == (weights, kv)
+    # no verdict stands on an edge: the two numbers are a factor of 1.5
+    # apart or more wherever they decide
+    if why.startswith("a decode step"):
+        assert max(weights, kv) > 1.5 * min(weights, kv), got
+        assert weights > engine_mod.HOST_BOUND_WEIGHT_BYTES
+
+
+def _bf16_engine(monkeypatch, *, floor=0, model="tiny-qwen3", num_blocks=64,
+                 max_blocks_per_seq=16, max_seqs=4, **kw):
+    """A tiny engine whose pages are in its model's dtype (what
+    ``Engine._packed_prefill`` asks).  ``floor``: the weights under which
+    a decode step counts as bound by the host; a tiny model's are, so the
+    tests of the observation steer that here, in the test."""
+    from tpuserve.runtime import engine as engine_mod
+    monkeypatch.setattr(engine_mod, "HOST_BOUND_WEIGHT_BYTES", floor)
+    sched = kw.pop("scheduler", {})
+    mesh = kw.pop("mesh", None)
+    if mesh:
+        from tpuserve.parallel.mesh import MeshConfig, make_mesh
+        mesh = make_mesh(MeshConfig(**mesh))
+    return Engine(EngineConfig(
+        model=model,
+        cache=CacheConfig(block_size=4, num_blocks=num_blocks,
+                          max_blocks_per_seq=max_blocks_per_seq,
+                          dtype=kw.pop("cache_dtype", "bfloat16")),
+        scheduler=SchedulerConfig(max_num_seqs=max_seqs,
+                                  mixed_token_budget=16, **sched), **kw),
+                  mesh=mesh)
+
+
+@pytest.mark.parametrize("build,rides,why", [
+    ({}, True, "a decode step is bound by its weights"),
+    # a pool whose seats can make a step read more K/V than weights
+    ({"num_blocks": 8192, "max_blocks_per_seq": 1024, "max_seqs": 8}, False,
+     "a decode step is bound by its K/V read"),
+    # weights read faster than a dispatch is launched (no steering: every
+    # tiny engine of this suite stays on the phase split for this reason)
+    ({"floor": 1 << 30}, False, "a decode step's weights are read in less"),
+    ({"model": "tiny-falcon-h1", "cache_dtype": "float32"}, False,
+     "recurrent state"),
+    ({"model": "tiny-pangu", "cache_dtype": "float32"}, False,
+     "latent attention"),
+    ({"cache_dtype": "int8"}, False, "pages narrower"),
+    ({"mesh": {"tp": 2}}, False, "the ragged kernel has no tp"),
+    ({"mesh": {"pp": 2}}, False, "the ragged trunk is neither"),
+    # the option still forces it, whatever was observed
+    ({"floor": 1 << 30, "scheduler": {"mixed_batching": True}}, True,
+     "forced by mixed_batching"),
+], ids=["weights-bound", "kv-bound", "host-bound", "recurrent", "mla",
+        "int8-kv", "tp-mesh", "pp", "forced"])
+def test_the_engine_builds_the_scheduler_it_observed(monkeypatch, build,
+                                                     rides, why):
+    eng = _bf16_engine(monkeypatch, **build)
+    route = eng._route
+    assert route["rides"] is rides and route["why"].startswith(why), route
+    assert eng.scheduler.cfg.mixed_batching is rides
+    # shown on /debug/engine, with its two numbers
+    shown = eng.flight.engine_snapshot()["engine"]
+    assert shown["decode_route"] == route
+    assert shown["mixed_batching"] is rides
+    if route["weight_bytes"] is not None:
+        assert route["weight_bytes"] > 0 and route["kv_bytes"] > 0
+
+
+def _drive(eng, prompts, max_tokens, temperature=0.0):
+    """Every request at once; ``(tokens by request, scheduled batches)``."""
+    batches = []
+    real = eng.scheduler.schedule
+
+    def schedule():
+        running = list(eng.scheduler.running)
+        batch = real()
+        if batch is not None:
+            batches.append((batch.kind, list(batch.requests), running,
+                            [(r.request_id, n)
+                             for r, n in batch.prefill_chunks]))
+        return batch
+    eng.scheduler.schedule = schedule
+    for i, (p, n) in enumerate(zip(prompts, max_tokens)):
+        eng.add_request(prompt_token_ids=p, request_id=f"r{i}",
+                        params=SamplingParams(
+                            max_tokens=n, temperature=temperature,
+                            seed=100 + i if temperature else None,
+                            ignore_eos=True))
+    got, steps = {}, 0
+    while eng.has_work():
+        for o in eng.step():
+            got.setdefault(o.request_id, []).extend(o.new_token_ids)
+        steps += 1
+        assert steps < 5000
+    assert eng.block_manager.num_seqs() == 0
+    return got, batches
+
+
+RIDE_LENS = [20, 33, 7, 5, 41, 12, 9, 27, 3, 18]
+RIDE_OUTS = [5, 9, 17, 3, 11, 8, 14, 6, 10, 7]
+
+
+def test_every_cycle_with_prompt_work_carries_every_running_row(monkeypatch):
+    """With the route on, a cycle that has admissible prompt work is ONE
+    mixed step that carries all running rows (never a prefill or a chunk
+    of the phase split), and the ridden tokens are counted."""
+    eng = _bf16_engine(monkeypatch)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, 200, size=n).tolist() for n in RIDE_LENS]
+    got, batches = _drive(eng, prompts, RIDE_OUTS)
+    assert {k: len(v) for k, v in got.items()} == {
+        f"r{i}": n for i, n in enumerate(RIDE_OUTS)}
+    assert {kind for kind, *_ in batches} == {"mixed", "decode"}
+    carried = 0
+    for kind, reqs, running, chunks in batches:
+        assert reqs == running           # all running rows, whichever kind
+        if kind == "mixed":
+            assert chunks
+            carried += sum(n for _, n in chunks)
+    assert carried == sum(RIDE_LENS)     # every prompt token rode a mixed step
+    st = eng.stats
+    assert 0 < st.decode_tokens_ridden < st.generated_tokens
+    mixed = [s for s in eng.flight.steps_snapshot(limit=1 << 20)
+             if s["kind"] == "mixed"]
+    assert len(mixed) == st.num_mixed_steps
+    assert sum(s["ridden_tokens"] for s in mixed) == st.decode_tokens_ridden
+    assert all("ridden_tokens" not in s
+               for s in eng.flight.steps_snapshot(limit=1 << 20)
+               if s["kind"] != "mixed")
+
+
+def test_a_recurrent_engine_never_schedules_a_mixed_step(monkeypatch):
+    eng = _bf16_engine(monkeypatch, model="tiny-falcon-h1",
+                       cache_dtype="float32")
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, 200, size=n).tolist() for n in RIDE_LENS[:6]]
+    _, batches = _drive(eng, prompts, RIDE_OUTS[:6])
+    assert "mixed" not in {kind for kind, *_ in batches}
+    assert eng.stats.num_mixed_steps == eng.stats.decode_tokens_ridden == 0
+
+
+def _family_engine(model, mixed, *, multi_step, pipeline, share=None,
+                   num_blocks=160):
+    """A float32 engine on float32 pages (the routes compared attend the
+    same K/V to the last bit), four seats, a budget of 16 rows."""
+    cfg = dataclasses.replace(get_model_config(model), dtype="float32")
+    if share:
+        cfg = dataclasses.replace(cfg, moe_experts_held=share)
+    return Engine(EngineConfig(
+        model=model,
+        cache=CacheConfig(block_size=4, num_blocks=num_blocks,
+                          max_blocks_per_seq=24, dtype="float32"),
+        scheduler=SchedulerConfig(max_num_seqs=4, mixed_batching=mixed,
+                                  mixed_token_budget=16),
+        multi_step=multi_step, pipeline_decode=pipeline), model_cfg=cfg)
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "seeded"])
+@pytest.mark.parametrize("multi_step,pipeline", [(1, False), (1, True),
+                                                 (4, True)],
+                         ids=["sync", "pipelined", "pipelined-windows"])
+@pytest.mark.parametrize("model,share", [
+    ("tiny-qwen3", None),
+    # windowed and full layers mixed, sparse experts in every layer
+    ("tiny-mellum2", None),
+    # a dense layer, windowed layers that rotate, a share of the experts
+    # held beside a shared one
+    ("tiny-k-exaone", 8)], ids=["dense", "mellum2-shaped",
+                                "k-exaone-share-shaped"])
+def test_riding_rows_get_the_phase_splits_tokens(model, share, multi_step,
+                                                 pipeline, temperature):
+    """Ten requests on four seats: prompts cut at the budget, decode rows
+    that ride them, rows that take their token on the device from a window
+    in flight, from an earlier mixed step and from a prompt just completed
+    (``pipelined``): the same tokens as the phase split, request by
+    request."""
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, 200, size=n).tolist() for n in RIDE_LENS]
+
+    def run(mixed):
+        eng = _family_engine(model, mixed, multi_step=multi_step,
+                             pipeline=pipeline, share=share)
+        return _drive(eng, prompts, RIDE_OUTS, temperature)[0], eng
+
+    ref, _ = run(False)
+    mix, eng = run(True)
+    assert mix == ref
+    assert eng.stats.num_mixed_steps > 0
+    assert eng.stats.decode_tokens_ridden > 0
+    if multi_step > 1:
+        # nothing was read before the dispatch that followed it (the
+        # single-step decode path reads a first token before it runs)
+        assert eng.stats.prefill_first_token_flushed_early == 0
+        assert eng.stats.prefill_first_token_deferred > 0
+
+
+def test_a_short_pool_reads_first_and_evicts_as_decode_does():
+    """Where the pool cannot hold a slot past what is in flight for every
+    row, the mixed step reads its records first and pre-empts: the same
+    tokens, later."""
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, 200, size=n).tolist() for n in RIDE_LENS]
+
+    def run(mixed, num_blocks):
+        eng = _family_engine("tiny-qwen3", mixed, multi_step=4,
+                             pipeline=True, num_blocks=num_blocks)
+        return _drive(eng, prompts, RIDE_OUTS)[0], eng
+
+    ref, _ = run(False, 160)
+    mix, eng = run(True, 20)
+    assert mix == ref
+    assert eng.stats.preemptions > 0
+
+
+def test_a_warm_riding_engine_compiles_nothing_in_service(monkeypatch):
+    """``compiles_in_window`` has a limit of 0: what a riding engine
+    dispatches (mixed rungs, the decode ladder, the samplers at the mixed
+    step's width, the selects that take a row's token on the device from
+    a window's tail, from a mixed step's tokens and from a completed
+    prompt's) is warmed by ``Engine.warmup`` itself."""
+    from benchmark.harness.meter import CompileMeter
+    meter = CompileMeter()
+    eng = _bf16_engine(monkeypatch, num_blocks=160, max_blocks_per_seq=24,
+                       multi_step=4, pipeline_decode=True)
+    assert eng._route["rides"]
+    # as session.build calls it: the phase split's shapes are handed over
+    # and a riding engine warms its own ladder in their place
+    eng.warmup(sample_modes=("greedy",), prefill_buckets=[(1, 32), (4, 64)],
+               chunk_buckets=[16], decode_buckets=[4])
+    before = meter.snapshot()["requests"]
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, 200, size=n).tolist() for n in RIDE_LENS]
+    _, batches = _drive(eng, prompts, RIDE_OUTS)
+    assert {kind for kind, *_ in batches} == {"mixed", "decode"}
+    assert eng.stats.prefill_first_token_flushed_early == 0
+    assert meter.snapshot()["requests"] == before

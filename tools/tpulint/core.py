@@ -187,6 +187,9 @@ DEFAULT_CONFIG: dict = {
             # /debug/engine ring bookkeeping + per-request detail
             "events_recorded", "steps_recorded", "requests",
             "steps", "postmortems", "last_postmortem",
+            # what the engine is: its sizes and the decode route it
+            # observed with the route's two numbers (note_engine_facts)
+            "engine",
             # SLI/controller scalars beyond what the autoscaler reads
             "n", "p50", "pressure",
             # burn-rate evaluator detail (objectives list, transition

@@ -1690,7 +1690,10 @@ def _ragged_layer(h, lp, entry, pool, positions, slot_ids, row_seq, row_lens,
                 h = _attn_residual(h, out, lp, cfg, ad)
         else:
             q, k, v, hn = _qkv(h, lp, cfg, positions, li, ad)  # (T, H*, D)
-            entry = attn_ops.write_kv_entry(entry, k, v, slot_ids, aligned)
+            entry = attn_ops.write_kv_entry(
+                entry, k, v, slot_ids, aligned,
+                head=decode_region(block_tables.shape[0], ragged_blk)
+                if decode_rows else 0)
             ck, cv = entry["k"], entry["v"]
             ks, vs = entry.get("ks"), entry.get("vs")
             if attn_impl == "pallas":
@@ -2349,6 +2352,16 @@ def _ragged_reference_attn(q, ck, cv, block_tables, row_seq, row_lens,
         return jnp.where(is_dec, head, out)
 
 
+def decode_region(seqs: int, ragged_blk: int) -> int:
+    """Rows at the head of a mixed step's flat stream that its decode rows
+    own, whatever their number: whole ragged blocks for the descriptor's
+    ``seqs`` sequences, one row each.  Static, so the prompt chunks behind
+    it start at the same row in every dispatch of an executable (the
+    engine packs them there, the scheduler charges the region, the K/V
+    write sends what lies behind it out by the page)."""
+    return -(-seqs // ragged_blk) * ragged_blk
+
+
 @partial(jax.jit,
          static_argnames=("cfg", "ragged_blk", "attn_impl", "decode_rows",
                           "moe_dense"),
@@ -2385,7 +2398,9 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     and for a prompt's final chunk — exactly the prefill_chunk contract).
     ``decode_rows=False`` (static): the stream holds prompts only (``meta``
     is zero: the engine's packed batched prefill), and the attention is
-    built without its decode part.
+    built without its decode part.  With decode rows the stream's first
+    ``decode_region(B, ragged_blk)`` rows are theirs (padding past the
+    last of them) and every prompt chunk starts behind that region.
 
     Semantics per row are exactly the cache-relative window semantics:
     each row's KV is written first, then the row attends its own
@@ -2406,11 +2421,15 @@ def forward_ragged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         h = _embed(params, cfg, tokens, positions)                 # (T, H)
         row_lens = positions + 1
         tally = _moe_tally(cfg)
-        # a packed prefill starts every prompt on a ragged-block boundary
-        # at a whole number of cached blocks (Engine._pack_ragged): where
-        # that block is whole pages, its K and V go out a page at a time
-        aligned = not decode_rows and attn_ops.kv_stream_by_page(
-            kv_cache[0], ragged_blk, attn_impl)
+        # a prompt (chunk) starts on a ragged-block boundary at a whole
+        # number of cached blocks (Engine._pack_ragged): where that block
+        # is whole pages, its K and V go out a page at a time; a mixed
+        # step's decode region (``decode_region``: its rows lie one to a
+        # page) stands before them and keeps the row scatter, and under
+        # latent attention its whole stream does
+        aligned = attn_ops.kv_stream_by_page(
+            kv_cache[0], ragged_blk, attn_impl) and not (
+                decode_rows and cfg.is_mla)
         h, new_cache, new_ssm = _walk_layers(
             "ragged", _ragged_layer, params, cfg, h, kv_cache, ssm, tally,
             positions, slot_ids, row_seq, row_lens, block_tables, kv_lens,

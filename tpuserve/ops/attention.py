@@ -451,7 +451,8 @@ def kv_stream_by_page(entry: dict, unit: int, attn_impl: str,
 
 
 def write_kv_entry(entry: dict, k: jnp.ndarray, v: jnp.ndarray,
-                   slots: jnp.ndarray, aligned: bool = False) -> dict:
+                   slots: jnp.ndarray, aligned: bool = False,
+                   head: int = 0) -> dict:
     """Write one layer's new K/V into its cache entry.
 
     An entry carrying ``ks``/``vs`` scale arrays stores int8: values are
@@ -464,12 +465,22 @@ def write_kv_entry(entry: dict, k: jnp.ndarray, v: jnp.ndarray,
     and the prefill chunk: every ``block_size`` consecutive rows of
     ``slots`` are one cache page in order, or padding — and sends the rows
     out a page a copy (ops/pallas_kv_write.py) instead of a scatter index
-    a row.  Decode's rows go one to a page; they, verify, draft, mixed
-    steps and the (B, L) grid keep the scatter."""
+    a row.  Decode's rows go one to a page; they, verify, draft and the
+    (B, L) grid keep the scatter.  ``head`` (static): the stream's first
+    ``head`` rows are a mixed step's decode region, one row a sequence
+    (whole pages of rows, so what follows is page-aligned as a packed
+    prefill is): those rows go by the scatter, the prompt chunks behind
+    them by the page."""
     with jax.named_scope(scopes.ATTN_KV_WRITE):
         if aligned:
             from tpuserve.ops.pallas_kv_write import paged_kv_write
-            ck, cv = paged_kv_write(entry["k"], entry["v"], k, v, slots)
+            ck, cv = entry["k"], entry["v"]
+            if head:
+                ck = write_kv_cache(ck, k[:head], slots[:head])
+                cv = write_kv_cache(cv, v[:head], slots[:head])
+            if k.shape[0] > head:
+                ck, cv = paged_kv_write(ck, cv, k[head:], v[head:],
+                                        slots[head:])
             return {"k": ck, "v": cv}
         if "ks" in entry:
             qk, sk = quantize_kv(k)

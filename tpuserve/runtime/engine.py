@@ -30,7 +30,7 @@ from tpuserve.models import transformer
 from tpuserve.models.config import ModelConfig, get_model_config
 from tpuserve.models.tokenizer import IncrementalDetokenizer, load_tokenizer
 from tpuserve.models.transformer import (LAYER_CALLS, LAYER_TRACES,
-                                         moe_plain_moves)
+                                         decode_region, moe_plain_moves)
 from tpuserve.models.weights import load_or_init, param_dtype
 from tpuserve.ops import sampling as sampling_ops
 from tpuserve.ops.attention import PAD_SLOT, kv_stream_by_page
@@ -213,6 +213,10 @@ class EngineStats:
     # ragged mixed prefill+decode dispatches (scheduler mixed mode); each
     # also counts once in num_decode_steps when it carried decode rows
     num_mixed_steps: int = 0
+    # answer tokens produced by a dispatch that also carried prompt tokens
+    # (a mixed step's decode rows): their weights were read with the
+    # prompt's; over generated_tokens, the share of decode that rode
+    decode_tokens_ridden: int = 0
     # padding-waste observability (the bucketing win is invisible without
     # it): the LAST dispatch's token count including padding vs its real
     # tokens (exported as the tpuserve_step_padded/actual_tokens gauges),
@@ -371,7 +375,9 @@ class PendingWindow:
     column, so the host sync that ends every window overlaps the next
     window's device time instead of serialising with it."""
     reqs: list
-    toks: jax.Array                  # (B, S) int32, device-resident
+    # (B, S) int32, device-resident; a mixed step's decode rows are a
+    # window of one step and keep the sampler's (B,) as it is
+    toks: jax.Array
     steps: int
     # in-window logprobs: (chosen_lp (B,S), top_ids (B,S,N), top_lps
     # (B,S,N)) device arrays when the window computed them, else None
@@ -382,6 +388,14 @@ class PendingWindow:
     # mirror advances at flush through the same table
     gstate: jax.Array | None = None
     seq: int = 0                     # the dispatching cycle's step record
+
+    @property
+    def tail(self) -> jax.Array:
+        """(B,): each row's newest token, the next dispatch's input."""
+        return self.toks if self.toks.ndim == 1 else self.toks[:, -1]
+
+    def rows(self) -> dict:
+        return {r.request_id: i for i, r in enumerate(self.reqs)}
 
 
 @dataclasses.dataclass
@@ -398,6 +412,118 @@ class PendingFirst:
     # substitution path (read at once: the host picks their token)
     logits: jax.Array | None = None
     seq: int = 0                     # the dispatching cycle's step record
+    # row of ``toks`` that holds ``reqs[0]``'s token (a mixed step's
+    # completing prompts lie behind its decode rows)
+    offset: int = 0
+
+    @property
+    def tail(self) -> jax.Array:
+        return self.toks
+
+    def rows(self) -> dict:
+        return {r.request_id: self.offset + i
+                for i, r in enumerate(self.reqs)}
+
+
+def decode_step_bytes(params, kv_cache, model_cfg, cache_cfg,
+                      seats: int) -> tuple[int, int]:
+    """``(weights, K/V)``: the bytes one decode step of a full batch
+    reads.  Weights: every leaf of ``params``, all held experts included
+    (a batch of rows touches them all), less the embedding table where the
+    head is untied (a lookup reads ``seats`` rows of it).  K/V: what the
+    seats make a step read from the pool, layer by layer: a seat reaches
+    back ``max_model_len`` tokens or its layer's window, all seats
+    together no more than the pool holds, and HALF of that: a pool the
+    seats fill holds sequences at every stage of their lives, so the
+    context a step's rows attend is on average half of what they will hold
+    at their ends.  Shapes suffice (``jax.eval_shape`` trees): nothing is
+    read from the device."""
+    def nbytes(tree) -> int:
+        return sum(int(np.prod(x.shape)) * jnp.dtype(x.dtype).itemsize
+                   for x in jax.tree.leaves(tree))
+
+    weights = nbytes(params)
+    if "lm_head" in params:
+        weights -= nbytes(params["embed"])
+    pool = cache_cfg.num_blocks * cache_cfg.block_size
+    kv = 0
+    for layer, pages in zip(model_cfg.kv_layers, kv_cache):
+        reach = min(cache_cfg.max_model_len,
+                    model_cfg.layer_window(layer) or cache_cfg.max_model_len)
+        kv += nbytes(pages) // pool * min(pool, seats * reach)
+    return weights, kv // 2
+
+
+# Weights a decode step reads in less time than the host takes to launch
+# it bind nothing: 1 GiB is 1.3 ms of a v5e's HBM, about one cycle of the
+# engine loop, which is what a mixed step costs more than the fused window
+# it displaces (one step a dispatch, not multi_step).  Such an engine's
+# decode is bound by the host, and riding saves it nothing.
+HOST_BOUND_WEIGHT_BYTES = 1 << 30
+
+
+def route_excluded(model_cfg: ModelConfig, *, staged: bool, mesh: bool,
+                   packed: bool) -> str | None:
+    """Why no mixed step is scheduled for an engine by observation,
+    whatever its decode is bound by; None where one may be.  ``staged``:
+    a pipeline or multi-host engine; ``mesh``: any mesh; ``packed``: the
+    engine's batched prefills take the ragged trunk
+    (``Engine._packed_prefill``: pages in the model's own dtype)."""
+    if model_cfg.has_state:
+        return ("recurrent state: a chunk of the scan would straddle the "
+                "decode rows' sequences")
+    if staged:
+        return ("the ragged trunk is neither stage-stacked nor in the "
+                "lockstep protocol")
+    if mesh:
+        return "the ragged kernel has no tp wrapper"
+    if not packed:
+        return ("pages narrower than the model's dtype: a mixed step "
+                "attends a prompt's K/V read back from them")
+    if model_cfg.is_mla:
+        return ("latent attention: the ragged kernel's latent entry has "
+                "not served decode rows on the chip")
+    return None
+
+
+def decode_route(weight_bytes, kv_bytes, *, forced: bool = False,
+                 excluded: str | None = None) -> dict:
+    """The engine's verdict on its decode route, with its two numbers
+    (logged once at build, shown on /debug/engine): ``"mixed"`` where a
+    decode step reads more bytes of weights than of K/V, so a prompt
+    dispatch that carries the decode rows saves the larger part of a
+    decode step; ``"phase_split"`` where the K/V read sets the pace (the
+    weights saved are the smaller part, and a mixed step gives up the
+    fused window) or where ``excluded`` names why no mixed step can
+    serve this engine.  ``forced`` (SchedulerConfig.mixed_batching)
+    overrides the observation, not the numbers.  Weights under
+    ``HOST_BOUND_WEIGHT_BYTES`` bind nothing either way."""
+    if forced:
+        rides, why = True, "forced by mixed_batching"
+    elif excluded is not None:
+        rides, why = False, excluded
+    elif weight_bytes < HOST_BOUND_WEIGHT_BYTES:
+        rides, why = False, ("a decode step's weights are read in less time "
+                             "than a dispatch takes: bound by the host")
+    elif weight_bytes >= kv_bytes:
+        rides, why = True, "a decode step is bound by its weights"
+    else:
+        rides, why = False, "a decode step is bound by its K/V read"
+    return {"route": "mixed" if rides else "phase_split", "rides": rides,
+            "why": why, "weight_bytes": weight_bytes, "kv_bytes": kv_bytes}
+
+
+def _host_shapes_token(r: Request, slack: int) -> bool:
+    """Whether the host shapes or picks ``r``'s next token from history it
+    holds, so a dispatch cannot chain it off tokens still on the device:
+    penalties and logprobs read the host's token history, guided
+    validation substitutes tokens host-side each step, and the min_tokens
+    floor reads the host's output length (``slack`` tokens stale under the
+    pipeline: the mask could lift that much late or early)."""
+    return (r.params.needs_penalties or r.params.logprobs is not None
+            or r.params.guided is not None
+            or (r.params.needs_min_tokens and r.params.min_tokens_active(
+                len(r.output_token_ids), slack=slack)))
 
 
 @jax.jit
@@ -723,6 +849,9 @@ class Engine:
             jnp.dtype(self.model_cfg.dtype).itemsize,
             self.cache_cfg.quantized)
         self._ragged_seqs = next_power_of_2(sched_cfg.max_num_seqs)
+        # the rows at the head of a mixed step that its decode rows own
+        self._decode_region = decode_region(self._ragged_seqs,
+                                            self._ragged_blk)
         # Packed batched prefill: a prefill batch laid out on ONE flat
         # token axis through the same ragged trunk (zero decode rows),
         # bucketed on T alone, instead of a (power-of-two batch x
@@ -743,6 +872,21 @@ class Engine:
             mesh is None and self._pp == 1 and jax.process_count() == 1
             and jnp.dtype(self.cache_cfg.dtype) == param_dtype(self.model_cfg))
         self._prefill_seqs = next_power_of_2(sched_cfg.max_prefill_seqs)
+        # Mixed ragged steps: where a decode step is bound by its WEIGHTS,
+        # every dispatch that carries prompt tokens carries the running
+        # decode rows too, so the weights are read once for both.  The
+        # route is observed from the model's shape and the pool just
+        # sized (decode_route), no option and no model's name;
+        # SchedulerConfig.mixed_batching (--mixed-batching) still forces
+        # it, under the exclusions above.
+        self._route = self._observe_route(sched_cfg.mixed_batching)
+        if self._route["rides"] and not sched_cfg.mixed_batching:
+            sched_cfg = dataclasses.replace(sched_cfg, mixed_batching=True)
+        logger.info(
+            "decode route: %s (%s): a decode step reads %s B of weights, "
+            "its seats %s B of K/V from the pool", self._route["route"],
+            self._route["why"], self._route["weight_bytes"],
+            self._route["kv_bytes"])
         # Pallas-under-tp runs the phase-split kernels via shard_map
         # (ops/pallas_tp.py); the ragged kernel has no tp wrapper yet, so
         # mixed steps fall back to the reference ragged attention there
@@ -760,7 +904,7 @@ class Engine:
             # starve admissions forever (mixed cycles returning None
             # schedule no prefill at all)
             blk = self._ragged_blk
-            floor = -(-sched_cfg.max_num_seqs // blk) * blk + blk
+            floor = self._decode_region + blk
             if sched_cfg.mixed_token_budget < floor:
                 logger.warning(
                     "mixed_token_budget %d cannot cover max_num_seqs %d "
@@ -771,7 +915,8 @@ class Engine:
                                                 mixed_token_budget=floor)
         self.scheduler = Scheduler(sched_cfg, self.block_manager,
                                    max_model_len=self.cache_cfg.max_model_len,
-                                   ragged_align=self._ragged_blk)
+                                   ragged_align=self._ragged_blk,
+                                   decode_region=self._decode_region)
         self.scheduler.clock = self.clock
         # SLO class scheduling + brownout ladder (runtime/slo.py): the
         # controller is consulted at intake (shed / max_tokens clamp),
@@ -806,7 +951,8 @@ class Engine:
             mixed_batching=sched_cfg.mixed_batching,
             multi_step=config.resolve_multi_step(),
             slo_classes=bool(self._slo is not None),
-            ssm_state_seats=sched_cfg.max_num_seqs if recurrent else 0)
+            ssm_state_seats=sched_cfg.max_num_seqs if recurrent else 0,
+            decode_route=self._route)
         self.scheduler.flight = self.flight
         if self._slo is not None:
             self._slo.flight = self.flight
@@ -820,6 +966,7 @@ class Engine:
         self.devprof = DeviceProfiler()
         self.flight.devprof = self.devprof
         self._step_kind = "idle"
+        self._step_ridden = 0        # a mixed step's decode rows (its record)
         # terminal errors for QUEUED requests decided engine-side
         # (deadline expiry, queue-full class eviction): (rid, exc) pairs
         # the runner drains and routes to the waiting clients — the
@@ -1021,7 +1168,8 @@ class Engine:
             multi_step=config.resolve_multi_step(),
             slo_classes=bool(self._slo is not None),
             ssm_state_seats=(self.scheduler.cfg.max_num_seqs
-                             if self.ssm_state is not None else 0))
+                             if self.ssm_state is not None else 0),
+            decode_route=self._route)
         self._note_hbm_budget()         # HBM watermark per resident model
         dt = self.clock.monotonic() - t0
         stats.model_swaps += 1
@@ -1035,6 +1183,23 @@ class Engine:
         logger.info("model swap %s -> %s (%s, %.2fs)", old_model,
                     config.model, source_tier, dt)
         return old_model, old_params
+
+    def _observe_route(self, forced: bool) -> dict:
+        """Whether this engine's decode rows ride its prompt dispatches
+        (mixed ragged steps), from what it can see: ``decode_route``'s
+        verdict on the weights and the pool, after the engines no mixed
+        step can serve (``route_excluded``).  ``forced``:
+        SchedulerConfig.mixed_batching, as the exclusions in ``__init__``
+        left it."""
+        why = route_excluded(
+            self.model_cfg, staged=self._pp > 1 or jax.process_count() > 1,
+            mesh=self.mesh is not None, packed=self._packed_prefill)
+        weights = kv = None
+        if self.params is not None and self.mesh is None:
+            weights, kv = decode_step_bytes(
+                self.params, self.kv_cache, self.model_cfg, self.cache_cfg,
+                self.config.scheduler.max_num_seqs)
+        return decode_route(weights, kv, forced=forced, excluded=why)
 
     def _no_pallas(self, why: str, attr: str = "attn_impl") -> None:
         """This engine cannot run the Pallas kernels (``why``).  A caller
@@ -1692,7 +1857,8 @@ class Engine:
             self.stats.step_actual_tokens if dispatched else 0,
             self.stats.step_padded_tokens if dispatched else 0,
             self.clock.monotonic() - t_cycle,
-            ctx_tokens=self.stats.step_ctx_tokens if dispatched else 0)
+            ctx_tokens=self.stats.step_ctx_tokens if dispatched else 0,
+            ridden_tokens=self._step_ridden)
         if self._slo is not None:
             # estimator tick once per successful cycle (queue depth +
             # the EWMAs fed during scheduling) drives the brownout
@@ -1731,6 +1897,7 @@ class Engine:
     def _step_inner(self) -> list[RequestOutput]:
         self._dispatch_rids = ()
         self._step_kind = "idle"
+        self._step_ridden = 0
         PROF.bump_cycle()
         self.devprof.bump_cycle()
         # overload robustness, BEFORE scheduling: deadline-expired queued
@@ -2648,24 +2815,31 @@ class Engine:
 
     # ---- mixed ragged prefill+decode ----------------------------------
 
-    def _pack_ragged(self, decode_reqs: list, slots, chunks: list, bucket,
+    def _pack_ragged(self, decode_reqs: list, ahead, chunks: list, bucket,
                      B: int) -> tuple:
         """Lay one ragged dispatch out as ONE flat token stream — the host
         side of the Pallas kernel's layout contract
         (ops/pallas_ragged_attention.py), shared by mixed steps and packed
         batched prefills: ``decode_reqs``' rows first, densely packed
-        (flat row == sequence index; ``slots`` are their fresh cache
-        slots), the decode region padded to the ragged block, then each
-        of ``chunks`` ((req, ids, done, take): ``take`` prompt tokens from
-        offset ``done``) starting block-aligned, in order.  ``bucket``
+        (flat row == sequence index), the decode region padded to the
+        ragged block, then each of ``chunks`` ((req, ids, done, take):
+        ``take`` prompt tokens from offset ``done``) starting
+        block-aligned, in order.  ``ahead``: rid -> a decode row's tokens
+        still on the device (:meth:`_unread_rows`): the row stands that
+        many positions past its host-known length, in the slot its block
+        table (reserved that far by the caller) gives it, and its token
+        is left 0 for the caller to take from the device; None for a
+        packed prefill, whose stream has no decode region.  ``bucket``
         maps the rows used to the dispatched T; ``B`` is the fixed
         descriptor width.  Returns ``(arrays, kw)``: _exec_forward_ragged's
         positional arguments as numpy arrays, and its ``ad`` keyword when
         an adapter stack is loaded."""
         blk = self._ragged_blk
         n_dec = len(decode_reqs)
-        cursor = -(-n_dec // blk) * blk if n_dec else 0
-        n_dec_blocks = cursor // blk
+        n_dec_blocks = -(-n_dec // blk)
+        # a mixed step's chunks start behind the decode region, whatever
+        # rows it holds (transformer.decode_region: static in the program)
+        cursor = self._decode_region if ahead is not None else 0
         starts = []
         for _, _, _, take in chunks:
             starts.append(cursor)
@@ -2681,21 +2855,23 @@ class Engine:
         q_lens = np.zeros((B,), np.int32)
         last_rows = np.zeros((B,), np.int32)
         block_tables = np.zeros((B, mb), np.int32)
-        for i, r in enumerate(decode_reqs):
-            nt = r.num_tokens
-            tokens[i] = r.output_token_ids[-1]
-            positions[i] = nt - 1
-            slot_ids[i] = slots[i]
-            row_seq[i] = i
-            kv_lens[i] = nt
-            q_starts[i] = i
-            q_lens[i] = 1
-            last_rows[i] = i
         if decode_reqs:
             self.flight.req_event_many(
                 tuple(r.request_id for r in decode_reqs), "WINDOW",
                 steps=1, mixed=True)
             self._bm_fill_tables(decode_reqs, block_tables)
+            n = np.arange(n_dec)
+            extra = np.asarray([ahead.get(r.request_id, 0)
+                                for r in decode_reqs])
+            at = np.asarray([r.num_tokens for r in decode_reqs]) + extra - 1
+            tokens[:n_dec] = [0 if e else r.output_token_ids[-1]
+                              for r, e in zip(decode_reqs, extra)]
+            positions[:n_dec] = at
+            bs = self.cache_cfg.block_size
+            slot_ids[:n_dec] = block_tables[n, at // bs] * bs + at % bs
+            row_seq[:n_dec] = q_starts[:n_dec] = last_rows[:n_dec] = n
+            kv_lens[:n_dec] = at + 1
+            q_lens[:n_dec] = 1
         blk_seq = np.full((T // blk,), -1, np.int32)
         for si, ((req, ids, done, take), start) in enumerate(
                 zip(chunks, starts), start=n_dec):
@@ -2739,44 +2915,66 @@ class Engine:
         """One ragged mixed step (scheduler mixed mode): every running
         stream's decode row plus the scheduled prefill-chunk tokens run
         as ONE flat token batch through the ragged trunk
-        (models/transformer.forward_ragged) — no phase split, so decode
-        streams get a token on every cycle even while prompts are being
-        admitted, and the executable set is bucketed on the single
-        flat-token dimension.
+        (models/transformer.forward_ragged) — the weights are read once
+        for both, decode streams get a token on every cycle even while
+        prompts are being admitted, and the executable set is bucketed on
+        the single flat-token dimension.
 
-        Synchronous by design: any in-flight window/step resolves first
-        (the flat layout needs host-known last tokens), so mixed steps
-        slot cleanly BETWEEN pipelined fused decode windows — the
-        prefill-free cycles around them keep PendingWindow pipelining.
+        Pipelined like the fused windows it stands between: a decode
+        row's input token is taken ON THE DEVICE from the record in
+        flight (the last window's tail, an earlier mixed step's tokens, a
+        completed prompt's first token: ``_unread_rows``), the step's own
+        tokens stay on the device as a window of one step
+        (``PendingWindow``) and its completing prompts' as
+        ``PendingFirst``, and the records in flight are read BEHIND this
+        dispatch, so the chip's queue does not drain around a mixed step.
+        Rows whose token the host shapes from history it holds
+        (penalties, logprobs, guided, an active min_tokens floor), an
+        engine that does not pipeline, and a pool too full to reserve
+        ahead read everything first and sample at once, as the
+        single-step path does.
 
         Row layout (the Pallas kernel's host contract,
         ops/pallas_ragged_attention.py): decode rows first, densely
         packed (flat row == sequence index), the decode region padded to
         the ragged block, each prefill chunk starting block-aligned;
         sequences are ordered decode -> completing prefills -> continuing
-        prefills so the rows that sample a token this step are a prefix
-        and the per-step ``_sample`` (penalties, logprobs, guided — all
-        host-side, identical to the phase-split paths) applies unchanged.
+        prefills so the rows that sample a token this step are a prefix.
         """
-        outputs = (self._flush_pending() + self._flush_window()
-                   + self._flush_first())
-        decode_reqs = [r for r in batch.requests if not r.finished]
+        outputs = self._flush_pending()
+        p = self._pending_window
+        slack = p.steps if p is not None else 1
+        defer = self._pipeline_decode and not any(
+            _host_shapes_token(r, slack)
+            for r in itertools.chain(batch.requests,
+                                     (c[0] for c in batch.prefill_chunks)))
+        if not defer:
+            outputs += self._flush_window() + self._flush_first()
+        p, pf = self._pending_window, self._pending_first
+        decode_reqs, pend_idx, first_idx, ahead = self._unread_rows(
+            batch.requests, p, pf)
         self._dispatch_rids = tuple(r.request_id for r in decode_reqs)
         self._step_kind = "mixed"
-        # decode rows each append one KV slot — the same reserve-then-
-        # append preemption discipline as _run_decode (no pending here:
-        # both pipelines were just flushed); probe + charge are one
-        # manager crossing each (_bm_* helpers)
-        while self._bm_decode_shortfall(decode_reqs) > 0:
-            victim = self.scheduler.preempt_last()
-            self.stats.preemptions += 1
-            if victim is None:
-                raise MemoryError("KV cache exhausted with a single "
-                                  "sequence")
-            decode_reqs = [r for r in decode_reqs if r is not victim]
+        # each decode row writes one KV slot past what is in flight: the
+        # window discipline (reserve now, commit when the record is read)
+        if not self._try_reserve_window(
+                decode_reqs, 1 + max(ahead.values(), default=0)):
+            # the pool is short: read what is in flight (a finish frees
+            # blocks), then evict as _run_decode does
+            outputs += self._flush_window() + self._flush_first()
+            p = pf = None
+            pend_idx = first_idx = ahead = {}
+            decode_reqs = [r for r in decode_reqs if not r.finished]
+            while self._bm_decode_shortfall(decode_reqs) > 0:
+                victim = self.scheduler.preempt_last()
+                self.stats.preemptions += 1
+                if victim is None:
+                    raise MemoryError("KV cache exhausted with a single "
+                                      "sequence")
+                decode_reqs = [r for r in decode_reqs if r is not victim]
+            if not self._try_reserve_window(decode_reqs, 1):
+                raise MemoryError("out of KV blocks on append")
         self.faults.check("kv_alloc", self._dispatch_rids)
-        slots = np.empty((len(decode_reqs),), np.int32)
-        self._bm_charge_decode(decode_reqs, slots)
         # prefill chunks: first chunk allocates (with prefix-cache
         # compute skip — prefill_chunk semantics); a request whose blocks
         # no longer fit (decode appends ate them) goes back to the head
@@ -2801,7 +2999,7 @@ class Engine:
                                   done=done, tokens=take,
                                   total=len(ids), mixed=True)
         if not decode_reqs and not chunks:
-            return outputs
+            return outputs + self._flush_window() + self._flush_first()
         self._dispatch_rids = tuple(
             [r.request_id for r in decode_reqs]
             + [c[0].request_id for c in chunks])
@@ -2813,21 +3011,39 @@ class Engine:
         B = self._ragged_seqs
         blk = self._ragged_blk
         arrays, kw = self._pack_ragged(
-            decode_reqs, slots, comp + cont,
-            lambda rows: max(next_power_of_2(rows), blk), B)
+            decode_reqs, ahead, comp + cont,
+            lambda rows: packed_prefill_bucket(rows, blk), B)
         self._demote_evicted()
         with PROF.phase("dispatch"):
+            tokens = jnp.asarray(arrays[0])
+            for rec, idx in ((p, pend_idx), (pf, first_idx)):
+                # rows whose last token is still on the device take it
+                # there: no host round-trip
+                at = [(i, idx[r.request_id])
+                      for i, r in enumerate(decode_reqs)
+                      if r.request_id in idx]
+                if at:
+                    gather = np.zeros(tokens.shape, np.int32)
+                    use_host = np.ones(tokens.shape, bool)
+                    rows, src = zip(*at)
+                    gather[list(rows)] = src
+                    use_host[list(rows)] = False
+                    tokens = _select_tokens(rec.tail, jnp.asarray(gather),
+                                            tokens, jnp.asarray(use_host))
             logits, self.kv_cache = self._exec_forward_ragged(
-                *map(jnp.asarray, arrays), **kw)
+                tokens, *map(jnp.asarray, arrays[1:]), **kw)
         self.stats.num_mixed_steps += 1
         if decode_reqs:
             self.stats.num_decode_steps += 1
         if chunks:
             self.stats.num_prefill_steps += 1
+            # the decode rows rode a dispatch that carried prompt tokens
+            self.stats.decode_tokens_ridden += n_dec
+            self._step_ridden = n_dec
         actual = n_dec + sum(c[3] for c in chunks)
         self._note_step_tokens(
             actual, len(arrays[0]),
-            sum(r.num_tokens for r in decode_reqs)
+            int(arrays[5][:n_dec].sum())
             + sum(done + take for _, _, done, take in chunks))
         # bookkeeping: chunk progress, requeue continuations, promote
         # completions to running BEFORE sampling/emit (finish() removes
@@ -2843,6 +3059,24 @@ class Engine:
             self.scheduler.mark_running(comp_reqs)
         emit_reqs = decode_reqs + comp_reqs
         if not emit_reqs:
+            # continuing chunks alone: nothing to sample; what is in
+            # flight is read behind this dispatch
+            return outputs + self._flush_window() + self._flush_first(
+                deferred=True)
+        if defer:
+            with PROF.phase("sample"):
+                _, toks = self._sample_enqueue(logits, emit_reqs, B, ahead)
+            # read the records this step chained off while it runs; a row
+            # that turns out to have finished in them is baked into this
+            # dispatch and dropped at its own flush (the window invariant)
+            outputs += self._flush_window() + self._flush_first(deferred=True)
+            seq = self.flight.seq
+            if decode_reqs:
+                self._pending_window = PendingWindow(
+                    reqs=decode_reqs, toks=toks, steps=1, seq=seq)
+            if comp_reqs:
+                self._pending_first = PendingFirst(
+                    reqs=comp_reqs, toks=toks, seq=seq, offset=n_dec)
             return outputs
         new_tokens = self._sample(logits, emit_reqs, B)
         now = self.clock.monotonic()
@@ -2851,12 +3085,36 @@ class Engine:
                 req.first_token_time = now
                 self.stats.ttft_sum += now - req.arrival_time
                 self.stats.ttft_count += 1
+        # commit the written KV before emitting (a finish frees blocks)
+        self._bm_advance(decode_reqs, 1)
         outputs += self._append_and_emit(decode_reqs, new_tokens[:n_dec])
         outputs += self._append_and_emit(comp_reqs, new_tokens[n_dec:],
                                          from_prefill=True)
         return outputs
 
     # ---- decode -------------------------------------------------------
+
+    def _unread_rows(self, requests: list, p: Optional[PendingWindow],
+                     pf: Optional[PendingFirst]) -> tuple:
+        """``(rows, pend_idx, first_idx, ahead)`` for a dispatch chained
+        off the records in flight: rid -> its row in the in-flight window
+        ``p`` / the pending first tokens ``pf`` (a row is in one record at
+        most), how many of its tokens are sampled on the device but not
+        read yet, and of ``requests`` the live rows that may take another
+        token: a request whose unread tokens reach max_tokens /
+        max_model_len (host-known) finishes when its record is flushed."""
+        first_idx = pf.rows() if pf is not None else {}
+        ahead = dict.fromkeys(first_idx, 1)
+        pend_idx = p.rows() if p is not None else {}
+        if p is not None:
+            ahead.update(dict.fromkeys(pend_idx, p.steps))
+        rows = [r for r in requests if not r.finished
+                and (r.request_id not in ahead
+                     or (len(r.output_token_ids) + ahead[r.request_id]
+                         < r.params.max_tokens
+                         and r.num_tokens + ahead[r.request_id]
+                         < self.max_seq_len))]
+        return rows, pend_idx, first_idx, ahead
 
     def _run_decode_multi(self, batch: ScheduledBatch
                           ) -> Optional[list[RequestOutput]]:
@@ -2943,29 +3201,8 @@ class Engine:
             outputs += self._flush_first()
             pf = None
         p = self._pending_window
-        reqs = [r for r in batch.requests if not r.finished]
-        # rid -> its row in the in-flight window / the pending prefill (a
-        # row is in one record at most), and how many of its tokens are
-        # sampled on the device but not read yet
-        pend_idx: dict[str, int] = {}
-        first_idx: dict[str, int] = {}
-        ahead: dict[str, int] = {}
-        if pf is not None:
-            first_idx = {r.request_id: i for i, r in enumerate(pf.reqs)}
-            ahead = dict.fromkeys(first_idx, 1)
-        if p is not None:
-            pend_idx = {r.request_id: i for i, r in enumerate(p.reqs)}
-            ahead.update(dict.fromkeys(pend_idx, p.steps))
-        if ahead:
-            # host-known completion rules: a request whose unread tokens
-            # reach max_tokens / max_model_len must not get another
-            # window — it finishes when its record is flushed below.
-            reqs = [r for r in reqs
-                    if r.request_id not in ahead
-                    or (len(r.output_token_ids) + ahead[r.request_id]
-                        < r.params.max_tokens
-                        and r.num_tokens + ahead[r.request_id]
-                        < self.max_seq_len)]
+        reqs, pend_idx, first_idx, ahead = self._unread_rows(
+            batch.requests, p, pf)
         if not reqs:
             return outputs + self._flush_window() + self._flush_first()
         self._dispatch_rids = tuple(r.request_id for r in reqs)
@@ -3095,7 +3332,7 @@ class Engine:
         with PROF.phase("dispatch"):
             tokens = jnp.asarray(host_tokens)
             if p is not None:
-                tokens = _select_tokens(p.toks[:, -1], jnp.asarray(gather),
+                tokens = _select_tokens(p.tail, jnp.asarray(gather),
                                         tokens, jnp.asarray(use_host))
             if pf is not None:
                 # the second device source, over the first select's result
@@ -3164,7 +3401,7 @@ class Engine:
         with self._sync("window"):
             # tpulint: sync-ok(THE designated sync: one device_get per S-token window is the whole fused-window design)
             toks_h, moe = jax.device_get((p.toks, moe))
-        toks_h = np.asarray(toks_h)
+        toks_h = np.asarray(toks_h).reshape(len(toks_h), p.steps)
         self._moe_note(due, moe)
         lp_h = None
         if p.lp is not None:
@@ -3332,17 +3569,7 @@ class Engine:
         # Penalties/logprobs read host-side token history, which is one step
         # stale under the pipeline — those batches run synchronously.
         pipeline_ok = self._pipeline_decode and not any(
-            r.params.needs_penalties or r.params.logprobs is not None
-            # guided validation substitutes tokens host-side each step —
-            # the pipelined path's device-resident token chain can't see
-            # the substitution
-            or r.params.guided is not None
-            # min_tokens reads host-side output lengths, one step stale
-            # under the pipeline — the mask could lift one step late/early
-            or (r.params.needs_min_tokens
-                and r.params.min_tokens_active(len(r.output_token_ids),
-                                               slack=1))
-            for r in reqs)
+            _host_shapes_token(r, 1) for r in reqs)
         if pending is not None and not pipeline_ok:
             outputs += self._flush_pending()
             pending = None
@@ -3394,7 +3621,7 @@ class Engine:
         block_tables = np.zeros((B, self.cache_cfg.max_blocks_per_seq), np.int32)
         self._bm_charge_decode(reqs, slot_arr)
         self._bm_fill_tables(reqs, block_tables)
-        in_flight = set()
+        in_flight = {}
         for i, req in enumerate(reqs):
             pend = pend_idx.get(req.request_id)
             nt = req.num_tokens + (0 if pend is None else 1)
@@ -3403,7 +3630,7 @@ class Engine:
             else:
                 use_host[i] = False
                 gather[i] = pend
-                in_flight.add(req.request_id)
+                in_flight[req.request_id] = 1
             positions[i] = nt - 1
             seq_lens[i] = nt
         self.flight.req_event_many(self._dispatch_rids, "WINDOW",
@@ -3628,9 +3855,10 @@ class Engine:
             return self._sample_sync(logits, reqs, B)
 
     def _sample_enqueue(self, logits: jnp.ndarray, reqs: list[Request],
-                        B: int) -> tuple:
+                        B: int, ahead: dict | None = None) -> tuple:
         """The per-step sampling rules and the sampler, enqueued: returns
-        (the logits sampled from, DEVICE tokens (B,))."""
+        (the logits sampled from, DEVICE tokens (B,)).  ``ahead``: as
+        :meth:`_sample_modes` takes it."""
         if any(r.params.needs_penalties for r in reqs):
             logits = self._apply_penalties(logits, reqs, B)
         if any(r.params.needs_logit_bias for r in reqs):
@@ -3645,7 +3873,7 @@ class Engine:
             # grammar-FSM rows: TRUE logit masking before sampling — the
             # sampled token is legal by construction, no substitution
             logits = self._apply_fsm_mask(logits, reqs, B)
-        return logits, self._sample_modes(logits, reqs, B, frozenset())
+        return logits, self._sample_modes(logits, reqs, B, ahead or {})
 
     def _sample_sync(self, logits: jnp.ndarray, reqs: list[Request],
                      B: int) -> np.ndarray:
@@ -3710,7 +3938,8 @@ class Engine:
                 (p.toks, p.lp, moe,
                  [a for r in p.reqs for a, *_ in r.prompt_picks]))
         self._moe_note(due, moe)
-        toks = np.array(toks[:len(p.reqs)])   # writable: guided picks in place
+        # (writable: guided picks in place)
+        toks = np.array(toks[p.offset:p.offset + len(p.reqs)])
         live = [i for i, r in enumerate(p.reqs) if not r.finished]
         now = self.clock.monotonic()
         for i in live:
@@ -4082,11 +4311,11 @@ class Engine:
             logits, jnp.asarray(ids), jnp.asarray(vals))
 
     def _sample_modes(self, logits: jnp.ndarray, reqs: list[Request], B: int,
-                      in_flight) -> jnp.ndarray:
+                      ahead) -> jnp.ndarray:
         """Pick the cheapest sampler covering this batch; returns DEVICE
-        tokens (B,).  ``in_flight`` holds request ids whose previous token is
-        still on device (pipelined decode) — their sampling-key step index
-        is one ahead of the host-visible output length."""
+        tokens (B,).  ``ahead``: request id -> its tokens still on the
+        device (pipelined decode) — their sampling-key step index is that
+        many ahead of the host-visible output length."""
         if all(r.params.greedy for r in reqs):
             return self._exec_sample(
                 logits, *self._greedy_dummies(B), mode="greedy")
@@ -4097,8 +4326,7 @@ class Engine:
         keys = np.zeros((B, 2), np.uint32)
         for i, r in enumerate(reqs):
             temperature[i] = r.params.temperature
-            keys[i] = self._row_key(
-                r, extra_step=1 if r.request_id in in_flight else 0)
+            keys[i] = self._row_key(r, extra_step=ahead.get(r.request_id, 0))
         kw = {}
         if mode == "full" and (min_p > 0).any():
             kw["min_p"] = jnp.asarray(min_p)
@@ -4597,19 +4825,21 @@ class Engine:
             # the engine warms it itself (callers were duplicating — and
             # drifting — this ladder logic).  mixed_buckets=None = auto:
             # the flat-token ladder up to the budget (the row-charged
-            # scheduler guarantees no dispatch ever exceeds it); cold, a
-            # bucket compiles inside a measured/served ITL.  And because
+            # scheduler guarantees no dispatch ever exceeds it: its rows
+            # are whole blocks, so a rung is never past the budget's own);
+            # cold, a bucket compiles inside a measured/served ITL.  And because
             # budget-staggered admission staggers FINISHES, the decode
             # tail shrinks through partial buckets even on a burst
             # workload — warm the whole decode ladder unless the caller
             # pinned one.
             if mixed_buckets is None:
-                top = next_power_of_2(scfg.mixed_token_budget)
-                t, ladder = self._ragged_blk, []
-                while t <= top:
-                    ladder.append(t)
-                    t *= 2
-                mixed_buckets = ladder
+                # the packed prefill's ladder from the decode region and
+                # one block of prompt up to the budget
+                blk = self._ragged_blk
+                mixed_buckets = sorted(
+                    {packed_prefill_bucket(r, blk) for r in range(
+                        self._decode_region + blk,
+                        scfg.mixed_token_budget + 1, blk)})
             if not decode_buckets:
                 decode_buckets = sorted(
                     {self.scheduler.decode_bucket(n)
@@ -4659,6 +4889,11 @@ class Engine:
                         for bk in prefill_buckets}
         if chunk_set:
             first_sizes.add(1)
+        if ragged_warm and scfg.mixed_batching:
+            # a mixed step leaves its tokens on the device at the ragged
+            # descriptor width, for the next window's rows as for the
+            # next mixed step's
+            first_sizes.add(self._ragged_seqs)
         logits = None
         # Two rounds: round 1 compiles each executable against the cache
         # layouts it happens to see; the kv_cache arrays that come OUT may
@@ -4788,6 +5023,11 @@ class Engine:
                             jnp.zeros((B,), jnp.int32),
                             jnp.zeros((B,), jnp.int32),
                             jnp.zeros((B,), bool)))
+                    if self._multi_step > 1:
+                        # a window's tail (PendingWindow.tail), each size
+                        self._warm_tails += [
+                            jnp.zeros((B, steps), jnp.int32)[:, -1]
+                            for steps in sorted(sizes)]
                 if self._spec is not None:
                     # the speculative verify pass is its own executable;
                     # left cold, the first spec step stalls on its compile
@@ -4851,6 +5091,16 @@ class Engine:
                         np.full((Tm // blkm,), -1, np.int32),
                         np.zeros((Bm,), np.int32))), **mkw, kind=kind)
                 self._warm_sampling(logits, sample_modes)
+                if kind == "mixed" and self._pipeline_decode:
+                    # a mixed step's decode rows take their tokens on the
+                    # device from the record in flight: a window's tail at
+                    # any decode bucket, a mixed step's own tokens
+                    for n in sorted({*decode_buckets, Bm}):
+                        self._warm_tails.append(_select_tokens(
+                            jnp.zeros((n,), jnp.int32),
+                            jnp.zeros((Tm,), jnp.int32),
+                            jnp.zeros((Tm,), jnp.int32),
+                            jnp.zeros((Tm,), bool)))
         if self._kv_tiers is not None:
             # tiered KV cache: the demote gather and restore scatter pad
             # their block axis to a power of two — warm the small end of

@@ -181,8 +181,11 @@ class FlightRecorder:
         return self.seq
 
     def note_step(self, kind: str, rows: int, actual: int, padded: int,
-                  dur_s: float, ctx_tokens: int = 0) -> None:
-        """One engine cycle's step record.  Phase ms are deltas of the
+                  dur_s: float, ctx_tokens: int = 0,
+                  ridden_tokens: int = 0) -> None:
+        """One engine cycle's step record (``ridden_tokens``: the decode
+        rows of a mixed step that also carried prompt tokens, of its
+        ``actual`` tokens).  Phase ms are deltas of the
         module hostprof profiler since the previous record, one key per
         span name (runtime/hostprof.py): a span still open here
         (engine.step, step.close) or opened by the runner after the step
@@ -203,7 +206,7 @@ class FlightRecorder:
             dev = self.devprof.step_delta()
         self._steps.append((self._clock.monotonic(), kind, rows, actual, padded,
                             round(dur_s * 1000, 4), phases or None, dev,
-                            self.seq, ctx_tokens))
+                            self.seq, ctx_tokens, ridden_tokens))
 
     def note_moe(self, seq: int, *counts: int) -> None:
         """The routing counts of step ``seq``'s dispatch (a model with
@@ -289,11 +292,13 @@ class FlightRecorder:
 
     def steps_snapshot(self, limit: int = 128) -> list[dict]:
         out = []
-        for t, kind, rows, actual, padded, ms, phases, dev, seq, ctx in \
-                self._steps.snapshot()[-limit:]:
+        for t, kind, rows, actual, padded, ms, phases, dev, seq, ctx, rode \
+                in self._steps.snapshot()[-limit:]:
             rec = {"t": t, "seq": seq, "kind": kind, "rows": rows,
                    "actual_tokens": actual, "padded_tokens": padded,
                    "ctx_tokens": ctx, "ms": ms}
+            if kind == "mixed":
+                rec["ridden_tokens"] = rode
             if phases:
                 rec["phase_ms"] = phases
             if dev:
@@ -329,6 +334,9 @@ class FlightRecorder:
             "steps": self.steps_snapshot(steps),
             "sli": self.sli_summary(),
             "control": dict(self._control),
+            # what the engine is (note_engine_facts): its sizes and the
+            # decode route it observed, with the route's two numbers
+            "engine": dict(self._facts),
             "postmortems": self.postmortems,
             "last_postmortem": self.last_postmortem,
         }

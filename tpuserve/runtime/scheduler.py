@@ -97,10 +97,20 @@ class SchedulerConfig:
     # cycle even mid-admission-burst, and bucketing collapses to the one
     # flat-token dimension.  Cycles with no admissible prefill stay on
     # the decode path (fused multi-step windows, speculation).
+    # Since PR 53 this FORCES the route; left False the engine observes
+    # it (engine.decode_route: mixed where a decode step is bound by its
+    # weights, so a prompt dispatch that carries the decode rows reads
+    # them once for both).
     mixed_batching: bool = False
-    # flat-token budget per mixed step; decode rows charge 1 token each,
-    # prefill chunks fill the remainder (Sarathi-style chunk sizing)
-    mixed_token_budget: int = 512
+    # flat-row budget per mixed step: the decode rows' region (whole
+    # ragged blocks for the seats) and prefill chunks in the remainder
+    # (Sarathi-style chunk sizing).  2,048 since PR 53 (512 before): on
+    # the chip a step of 512 rows re-reads an expert model's weights for
+    # 384 prompt tokens (HBM-bound, 43 us a prompt token where the packed
+    # ladder pays 31) and lost 10 % on Mellum2-12B widths, 8,192 leaves
+    # too few prompt dispatches for the decode rows to ride (+1 % on
+    # Mistral-7B widths, +6 % at 512); 2,048 gained in both (PERF.md §6).
+    mixed_token_budget: int = 2048
 
 
 @dataclasses.dataclass
@@ -122,7 +132,8 @@ class ScheduledBatch:
 
 class Scheduler:
     def __init__(self, cfg: SchedulerConfig, block_manager: BlockManager,
-                 max_model_len: int, ragged_align: int = 1):
+                 max_model_len: int, ragged_align: int = 1,
+                 decode_region: int | None = None):
         self.cfg = cfg
         self.block_manager = block_manager
         self.max_model_len = max_model_len
@@ -138,6 +149,11 @@ class Scheduler:
         # or a burst of tiny prompts would blow the flat bucket far past
         # the warmed ladder (one XLA compile stall per novel bucket).
         self.ragged_align = max(1, ragged_align)
+        # ... and the rows at the head of every mixed step that its decode
+        # rows own whatever their number (transformer.decode_region: static
+        # in the engine's programs).  None, a scheduler built without an
+        # engine: the running rows' own aligned span.
+        self.decode_region = decode_region
         self.waiting: deque[Request] = deque()
         self.running: list[Request] = []
         # Fault-salvage bisection (server/runner.py): when set, only these
@@ -479,12 +495,13 @@ class Scheduler:
             # engine's block-aligned layout (engine._run_mixed)
             return -(-n // align) * align
 
-        # budget is in FLAT ROWS (padding included): decode rows occupy
-        # one align-padded region, each chunk its own aligned span — so
-        # the dispatched bucket T never exceeds
-        # next_power_of_2(mixed_token_budget), which is exactly what
-        # warmup pre-compiles
-        budget = self.cfg.mixed_token_budget - rows(len(self.running))
+        # budget is in FLAT ROWS (padding included): decode rows own the
+        # region at the head of the stream, each chunk its own aligned
+        # span — so the dispatched bucket T never exceeds the ladder's
+        # rung for mixed_token_budget, which is what warmup pre-compiles
+        budget = self.cfg.mixed_token_budget - (
+            rows(len(self.running)) if self.decode_region is None
+            else self.decode_region)
         seats = self.cfg.max_num_seqs - len(self.running)
         if budget < align or seats <= 0:
             return None

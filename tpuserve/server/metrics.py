@@ -197,6 +197,13 @@ class ServerMetrics:
             "Ragged mixed prefill+decode dispatches (scheduler mixed "
             "mode) — zero under admission load means the engine is "
             "phase-splitting")
+        self.decode_tokens_ridden = counter(
+            "tpuserve_decode_tokens_ridden",
+            "Answer tokens produced by a dispatch that also carried prompt "
+            "tokens (a mixed step's decode rows): the weights were read "
+            "once for both; over vllm generation tokens, the share of "
+            "decode that rode a prompt dispatch (0 on an engine whose "
+            "decode is bound by its K/V read, /debug/engine decode_route)")
         self.guided_fsm_windows = counter(
             "tpuserve_guided_fsm_windows",
             "Fused multi-step windows that carried grammar-FSM masks — "

@@ -1939,17 +1939,21 @@ def build_server(argv=None):
                          "requests beyond this many waiting (0 = auto, "
                          "4x max-num-seqs; -1 disables)")
     ap.add_argument("--mixed-batching", action="store_true",
-                    help="ragged mixed prefill+decode batching: every "
-                         "step with admissible prefill work runs ONE "
-                         "flat-token dispatch carrying all running "
+                    help="FORCE ragged mixed prefill+decode batching: "
+                         "every step with admissible prefill work runs "
+                         "ONE flat-token dispatch carrying all running "
                          "decode rows plus prefill-chunk tokens — no "
                          "phase split, so no stream waits out an "
                          "admission burst (supersedes "
-                         "--interleave-batched-prefill)")
-    ap.add_argument("--mixed-token-budget", type=int, default=512,
-                    help="flat-token budget per mixed step (Sarathi "
-                         "chunk sizing; decode rows charge 1 each) — "
-                         "the p50-ITL vs admission-latency knob")
+                         "--interleave-batched-prefill).  Without the "
+                         "flag the engine observes the route: mixed "
+                         "where a decode step is bound by its weights "
+                         "(/debug/engine decode_route)")
+    ap.add_argument("--mixed-token-budget", type=int, default=2048,
+                    help="flat-row budget per mixed step (Sarathi "
+                         "chunk sizing; the decode rows' region comes "
+                         "off it) — the p50-ITL vs admission-latency "
+                         "knob")
     ap.add_argument("--interleave-batched-prefill", action="store_true",
                     help="compat shim (superseded by --mixed-batching): "
                          "one decode step between prefill admission "

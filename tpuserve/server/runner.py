@@ -1018,6 +1018,8 @@ class AsyncEngineRunner:
                                   self.metrics.first_tokens_flushed_early),
                                  ("num_mixed_steps",
                                   self.metrics.mixed_steps),
+                                 ("decode_tokens_ridden",
+                                  self.metrics.decode_tokens_ridden),
                                  ("kv_demoted_blocks",
                                   self.metrics.kv_demoted),
                                  ("kv_demote_declined_blocks",
