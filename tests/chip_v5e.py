@@ -117,3 +117,13 @@ def openpangu_share(**cut):
     return dataclasses.replace(
         get_model_config("FreedomIntelligence/openPangu-Ultra-MoE-718B"),
         moe_experts_held=16, vocab_size=19200, **cut)
+
+
+def ling_share(**cut):
+    """Ling-3.0-flash-VL's share of the benchmark's cell: routing group 0
+    (64 of 512 experts), an eighth of the vocabulary (19,648 rows: the
+    first in the benchmark that is not whole lane tiles)."""
+    from tpuserve.models.config import get_model_config
+    return dataclasses.replace(
+        get_model_config("inclusionAI/Ling-3.0-flash-VL"),
+        moe_experts_held=64, vocab_size=19648, **cut)

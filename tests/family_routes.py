@@ -55,7 +55,8 @@ class Family:
     atol: float             # a route's logits against the reference's
     # a trunk's last result: the routing counts of its expert layers
     # ("counts"), or the seat pool of its recurrent layers, which it also
-    # takes, with the rows' seats, after the paged cache ("pool")
+    # takes, with the rows' seats, after the paged cache ("pool"); a family
+    # with both returns the pool and then the counts ("pool+counts")
     returns_last: str
     prompts: tuple          # three uneven prompts, batched or packed
     chunked: int            # the prompt a chunked route takes 16 rows a time
@@ -94,6 +95,18 @@ FAMILIES = {
     # of 40 is two and a half chunks of 16 (a chunk against cached latents)
     "openpangu": Family("tiny-pangu", "openpangu_moe", 2e-4, "counts",
                         (40, 6, 29), 40, _EXPERTS),
+    # Kimi-delta layers beside latent attention behind grouped experts: a
+    # pool AND latent pages AND routing counts.  None a multiple of the
+    # scan's chunk of 32 or of its sub-block of 16; 16 + 16 + 11 rows.  The
+    # tolerance is Olmo-Hybrid's, for its reason: the chunked form against
+    # the reference's row-by-row recurrence, whose float32 orders of
+    # summation differ over a chunk's 32 rows
+    "ling_hybrid": Family("tiny-ling-hybrid", "ling_hybrid", 5e-4,
+                          "pool+counts", (5, 19, 37), 43,
+                          {"scheduler": {**_SEATED["scheduler"],
+                                         "min_prefill_bucket": 8,
+                                         "min_decode_bucket": 2},
+                           "cache": _SEATED["cache"]}),
 }
 
 
@@ -138,7 +151,8 @@ class Served:
             block_size=BLOCK, num_blocks=n_seqs * self.mb,
             max_blocks_per_seq=self.mb, dtype=dtype))
         self.pool, self.counts = None, 0
-        if family.returns_last == "pool":
+        self.counted = family.returns_last.endswith("counts")
+        if family.returns_last.startswith("pool"):
             # what a seat held before must not matter: fill it with junk
             self.pool = jax.tree.map(lambda x: jnp.full_like(x, 3.0),
                                      create_ssm_state(cfg, SEATS))
@@ -153,10 +167,10 @@ class Served:
         res = trunk(self.params, self.cfg, *map(jnp.asarray, args), self.kv,
                     *state, attn_impl=self.attn_impl, **kw)
         self.kv = res[1]
-        if self.pool is None:
+        if self.counted:
             self.counts = self.counts + np.asarray(res[-1][0], np.int64)
-        else:
-            self.pool = res[-1]
+        if self.pool is not None:
+            self.pool = res[-2 if self.counted else -1]
         return res
 
     def slots(self, i, start, n):
@@ -248,12 +262,12 @@ class Served:
         tables = np.zeros((B, self.mb), np.int32)
         tables[:len(seqs)] = self.tables[:len(seqs)]
         active = np.arange(B) < len(seqs)
-        toks, _, lp, last = self._run(
+        toks, _, lp, *_, last = self._run(
             transformer.decode_multi, tokens, n - 1, tables, n, active,
             np.zeros((B, 2), np.uint32), np.zeros((B,), np.float32),
             seats=np.where(active, np.arange(B), SEATS), steps=steps,
             mode="greedy", logprobs_n=1)
-        if self.pool is None:
+        if self.counted:
             # the rows' picks ride fourth with the logprobs, [row, step],
             # one entry an EXPERT layer (a dense layer has none)
             sparse, E = cfg.num_layers - cfg.moe_first_k_dense, cfg.num_experts

@@ -9,8 +9,8 @@ import jax
 import pytest
 
 from chip_v5e import (CHUNK, MAX_NUM_SEQS, MAX_PAGES, MIXED_BUDGET, PAGE,
-                      PREFILL_SEQS, WIDTHS, k_exaone_share, olmo_hybrid,
-                      shapes_on)
+                      PREFILL_SEQS, WIDTHS, k_exaone_share, ling_share,
+                      olmo_hybrid, shapes_on)
 from chip_v5e import (  # noqa: F401  (fixtures, found by name)
     _no_persistent_cache, one_chip, topo)
 
@@ -170,6 +170,54 @@ def test_the_k_exaone_cell_fits_the_chip(program, tokens, one_chip,
     weights = mem.argument_size_in_bytes - mem.alias_size_in_bytes
     assert 11.9e9 < weights < 12.1e9, weights
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.5e9
+
+
+@pytest.mark.parametrize("program,tokens", CELL_PROGRAMS)
+def test_the_ling_hybrid_cell_fits_the_chip(program, tokens, one_chip,
+                                            monkeypatch):
+    """``ling-3.0-flash-vl-ep8-l12.reason``'s whole trunks at the published
+    widths: 12 layers (10 Kimi-delta, 2 latent; 2 dense, 10 expert layers
+    of which routing group 0, 64 of 512 experts, is held), 19,648
+    vocabulary rows (153.5 lane tiles: the head's last tile is half full),
+    a fused decode window of 128 rows, the top rung of the packed-prefill
+    ladder and a chunk, beside 16,384 latent pages of 32 tokens for the 2
+    latent layers (more than 128 sequences of 3,072 tokens need) and a
+    pool of 130 seats.  The chip's compiler refuses what does not fit 16
+    GB; pages and pool stay in place, whole, in every program."""
+    from test_scopes import trunk_programs
+    from tpuserve.ops.pallas_ragged_attention import ragged_block_for
+
+    S, place = shapes_on(one_chip)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ling_share(num_layers=12)
+    assert cfg.kv_layers == (5, 11) and len(cfg.state_layers) == 10
+    blk = ragged_block_for(cfg.cache_q_heads, 1, cfg.cache_head_dim, PAGE,
+                           2, 2)
+    rows, pages = 128, 16384
+    fn, args, kwargs = trunk_programs(
+        cfg, S, place, rows=rows, steps=8, tokens=tokens or blk, blk=blk,
+        prompts=PREFILL_SEQS, chunk=CHUNK, block_size=PAGE,
+        num_blocks=pages, max_blocks=MAX_PAGES, attn_impl="pallas")[program]
+    compiled = fn.lower(*args, **kwargs).compile()
+    mem = compiled.memory_analysis()
+    weights = mem.argument_size_in_bytes - mem.alias_size_in_bytes
+    assert 9.4e9 < weights < 9.55e9, weights
+    seat = 2_097_152 + 3 * 96 * 128 * 4         # 12,288 channels: 96 tiles
+    assert mem.alias_size_in_bytes == (2 * pages * PAGE * 640 * 2
+                                       + 10 * (rows + 2) * seat)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.5e9
+    if program == "decode_multi":
+        text = compiled.as_text()
+
+        def calls(kernel):
+            return len(re.findall(rf"%{kernel}(?:\.\d+)? = [^\n]*?"
+                                  r"custom-call\(", text))
+        # one call a layer of each kind a step (the harness counts fused
+        # steps by the decode kernel's)
+        assert calls("_paged_decode_attention") == 2
+        assert calls("_kda_state_update") == 10
+        assert calls("_conv_tail_step") == 10
+        assert "_gdn_state_update" not in text
 
 
 # ---- a mixed ragged step: the riding engines' rungs ----------------------

@@ -74,6 +74,39 @@ def test_the_gdn_state_update_kernel_compiles_for_v5e(rows, one_chip):
     assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
 
 
+@pytest.mark.parametrize("rows", [4, MAX_NUM_SEQS, 128])
+def test_the_kda_state_update_kernel_compiles_for_v5e(rows, one_chip):
+    """``_kda_state_update`` at Ling-3.0-flash's sizes (32 heads of 128 x
+    128, one a slab: ``dv`` is one lane tile and nothing is packed) at the
+    smallest decode bucket, the other cells' largest and this cell's 128
+    rows: compiled, named as the benchmark's ``kda.*`` readers match it
+    (NOT as the scalar gate's kernel), and in place -- the pool's bytes are
+    aliased from input to output, not copied -- with no padding: 129 seats
+    x 2,097,152 B."""
+    from tpuserve.ops.pallas_gdn_update import heads_per_slab
+    from tpuserve.ops.pallas_kda_update import KERNEL_NAME, kda_state_update
+
+    S, _ = shapes_on(one_chip)
+    assert KERNEL_NAME == "_kda_state_update"
+    H, d, f32 = 32, 128, jnp.float32
+    assert heads_per_slab(H, d) == 1
+    pool = S((128 + 1, H, d, d), f32)
+    compiled = jax.jit(
+        lambda pool, seats, q, k, v, g, b: kda_state_update(
+            pool, seats, q, k, v, g, b, interpret=False),
+        donate_argnums=(0,)).lower(
+            pool, S((rows,), jnp.int32), S((rows, H, d), f32),
+            S((rows, H, d), f32), S((rows, H, d), f32), S((rows, H, d), f32),
+            S((rows, H), f32)).compile()
+    text = compiled.as_text()
+    assert re.search(rf"%{KERNEL_NAME}(\.\d+)? = [^\n]*custom-call\([^\n]*"
+                     r"tpu_custom_call", text)
+    assert "_gdn_state_update" not in text
+    pool_bytes = 129 * H * d * d * 4
+    assert pool_bytes == 129 * 2_097_152
+    assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
+
+
 # the convolution memory's decode step at both families' published sizes:
 # (channels, the pool's dtype, a bias or none)
 CONV_TAILS = {"olmo-hybrid-7b": (11520, jnp.float32, False),

@@ -431,9 +431,20 @@ def test_every_registered_config_is_structurally_sound():
     time, far from its cause."""
     from tpuserve.models.config import ModelConfig
     for name in list_model_configs():
+        if name.startswith("bench/"):
+            # a cell's CUT of a registered model, which a test of the
+            # benchmark's registered earlier in this process (a cut keeps
+            # the published per-layer lists whole): not hand-entered
+            continue
         cfg = get_model_config(name)
         assert cfg.num_heads % cfg.num_kv_heads == 0, name
-        assert cfg.q_size == cfg.num_heads * cfg.head_dim, name
+        # (a latent layer beside another mixer states its own q/k width:
+        # ModelConfig.mla_qk_head_dim; everywhere else it IS head_dim)
+        assert cfg.q_size == cfg.num_heads * cfg.qk_head_dim, name
+        assert cfg.qk_head_dim == (cfg.mla_qk_head_dim or cfg.head_dim), name
+        if cfg.mla_qk_head_dim is not None:
+            assert cfg.is_mla and cfg.linear_layers is not None, name
+            assert cfg.mla_qk_nope_head_dim > 0, name
         if cfg.window_layers is not None:
             assert len(cfg.window_layers) == cfg.num_layers, name
             assert cfg.sliding_window, name

@@ -41,6 +41,14 @@ FAMILY = {
     "tiny-pangu+share": COMMON | {scopes.MOE_ROUTE, scopes.MOE_GATHER,
                                   scopes.MOE_EXPERTS, scopes.MOE_COMBINE,
                                   scopes.MOE_SHARED},
+    # Kimi-delta layers beside latent attention behind grouped experts:
+    # the recurrent mixer's parts with the decay gate's inside two of
+    # them, attention's with the head gate's inside its output's
+    "tiny-ling-hybrid+share": COMMON | {
+        scopes.SSM_IN_PROJ, scopes.SSM_CONV, scopes.SSM_SCAN, scopes.SSM_OUT,
+        scopes.SSM_GATE, scopes.ATTN_GATE, scopes.MOE_ROUTE,
+        scopes.MOE_GATHER, scopes.MOE_EXPERTS, scopes.MOE_COMBINE,
+        scopes.MOE_SHARED},
 }
 ALL_PARTS = scopes.PARTS + scopes.LATER_PARTS
 
@@ -224,6 +232,27 @@ LOWERED = {
         ("reference", "decode_multi"): "2bd76c32b7874656",
         ("reference", "forward_ragged"): "925f32cdc424f297",
         ("reference", "prefill_chunk"): "1e18c77450bd2dec",
+    },
+    # (PR 55, taken on its parent 616a3fb and equal on its own tree: the
+    # two families whose helpers it edits -- the linear mixer's form is a
+    # static branch of ``_lin_*``, the head gate and the q/k norm static
+    # branches of ``_attn_residual`` / ``_mla_*``, the state update's
+    # kernel body shared with the channel gate's)
+    "tiny-olmo-hybrid": {
+        ("pallas", "decode_multi"): "21c1bc55af5d3515",
+        ("pallas", "forward_ragged"): "ca80fcf8115f464e",
+        ("pallas", "prefill_chunk"): "e094e1974fbac77c",
+        ("reference", "decode_multi"): "8d0b7cfa4c610cc5",
+        ("reference", "forward_ragged"): "3ab884e285ac9286",
+        ("reference", "prefill_chunk"): "4cfc807606095127",
+    },
+    "tiny-pangu+share": {
+        ("pallas", "decode_multi"): "845a597f3e2bcb97",
+        ("pallas", "forward_ragged"): "f05000f6cac1e496",
+        ("pallas", "prefill_chunk"): "77ecfc967b9fede3",
+        ("reference", "decode_multi"): "e97be8696c285456",
+        ("reference", "forward_ragged"): "a288146f6d270750",
+        ("reference", "prefill_chunk"): "5a9fec26742cf7a4",
     },
 }
 

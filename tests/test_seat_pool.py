@@ -1,7 +1,8 @@
-"""What the two families with a seat pool share word for word: Falcon-H1
-(state-space heads beside attention in every layer) and Olmo-Hybrid
-(linear-attention layers in place of attention in three of four), a case a
-family.  Their own cases, fixtures and tolerances are in
+"""What the families with a seat pool share word for word: Falcon-H1
+(state-space heads beside attention in every layer), Olmo-Hybrid
+(linear-attention layers in place of attention in three of four) and
+Ling-3.0-flash (Kimi-delta layers in place of LATENT attention in two of
+three, behind grouped experts), a case a family.  Their own cases, fixtures and tolerances are in
 ``tests/test_falcon_h1.py`` and ``tests/test_olmo_hybrid.py``; the harness
 is ``tests/family_routes.py``."""
 
@@ -16,8 +17,9 @@ from tpuserve.ops import ssm as ssm_ops
 from tpuserve.runtime import Engine, EngineConfig, SamplingParams
 
 SEATED = pytest.mark.parametrize(
-    "family", [FAMILIES["falcon_h1"], FAMILIES["olmo_hybrid"]],
-    ids=["falcon_h1", "olmo_hybrid"])
+    "family", [FAMILIES["falcon_h1"], FAMILIES["olmo_hybrid"],
+               FAMILIES["ling_hybrid"]],
+    ids=["falcon_h1", "olmo_hybrid", "ling_hybrid"])
 
 
 @SEATED

@@ -184,7 +184,9 @@ class ModelConfig:
     ssm_out_multiplier: float = 1.0
     mlp_multipliers: tuple = (1.0, 1.0)               # (gate, down)
     ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)  # z, x, B, C, dt
-    # Layers that differ in their KIND OF MIXER (Olmo-Hybrid): True where
+    # Layers that differ in their KIND OF MIXER (Olmo-Hybrid; Ling-3.0-
+    # flash, whose attention layers are LATENT ones: ``is_mla`` says what an
+    # attention layer caches, this tuple which layers attend): True where
     # a layer is a gated delta-rule linear-attention layer, which holds a
     # matrix state a SEQUENCE (lin_num_value_heads x lin_key_head_dim x
     # lin_value_head_dim, float32) and the last lin_conv_kernel - 1 inputs
@@ -204,6 +206,34 @@ class ModelConfig:
     lin_conv_kernel: int = 4
     lin_allow_neg_eigval: bool = False
     lin_chunk_size: int = 64
+    # The linear mixer's FORM, which the model's config answers: "scalar"
+    # (Olmo-Hybrid: ONE decay a head, -exp(A_log) softplus(a + dt_bias), a
+    # SiLU output gate) or "channel" (Kimi Delta Attention,
+    # arXiv:2510.26692: a decay for every KEY CHANNEL of every head, so
+    # the state's rows decay each at its own rate; the safe gate g =
+    # lin_gate_lower_bound * sigmoid(exp(A_log) (W_f x + dt_bias)) lies in
+    # (lin_gate_lower_bound, 0), which is what bounds the chunked scan's
+    # exponents (ops/gated_delta.py kda_chunk_scan); a sigmoid output
+    # gate).  The sizes are the lin_* fields' either way.
+    lin_gate: str = "scalar"
+    lin_gate_lower_bound: float = 0.0
+    # A sigmoid gate a HEAD on an attention layer's output before o_proj
+    # (bailing_hybrid ``gated_attention_proj_granularity_type`` head_wise:
+    # one more column a head beside the query projection).
+    attn_head_gate: bool = False
+    # A latent-attention layer's q/k width (qk_nope + qk_rope) in a model
+    # whose ``head_dim`` is ANOTHER mixer's head (bailing_hybrid: 128 is
+    # the linear layers' head and the rotary's base, 192 the latent
+    # layers' q/k); None: ``head_dim`` is that width, as in DeepSeek's
+    # family -- see qk_head_dim.
+    mla_qk_head_dim: Optional[int] = None
+    # bailing_hybrid's per-layer clamp on the gated activation of the
+    # routed and of the shared experts (``expert_swiglu_limit_list``,
+    # ``share_expert_swiglu_limit_list``), one entry a PUBLISHED layer, 0 =
+    # none.  The clamp is NOT built: require_built() refuses a running
+    # layer whose entry is not 0.
+    moe_swiglu_limits: Optional[tuple] = None
+    moe_shared_swiglu_limits: Optional[tuple] = None
     # Where a layer's two norms stand: "pre" on each branch's INPUT (every
     # family above), "post" on its OUTPUT before the add to the residual
     # stream (the OLMo 2 / 3 family: x + norm(mixer(x)), x + norm(mlp(x))).
@@ -236,6 +266,14 @@ class ModelConfig:
             if self.mamba_d_ssm:
                 raise ValueError(f"{self.name}: linear-attention layers "
                                  "and state-space heads in one model")
+            if self.lin_gate not in ("scalar", "channel"):
+                raise ValueError(f"{self.name}: lin_gate {self.lin_gate!r} "
+                                 "(scalar and channel are built)")
+            if self.lin_gate == "channel" and not self.lin_gate_lower_bound < 0:
+                raise ValueError(
+                    f"{self.name}: a channel gate needs its lower bound "
+                    f"(lin_gate_lower_bound {self.lin_gate_lower_bound!r}): "
+                    "the chunked scan's exponents are bounded by it")
 
     @property
     def has_ssm(self) -> bool:
@@ -247,8 +285,9 @@ class ModelConfig:
     def layer_mixer(self, layer_idx: int) -> str:
         """One layer's kind of mixer, beside layer_window() and
         layer_rotates() -- ONE function for every forward path, the cache
-        and the seat pool: ``MIXER_ATTENTION`` (K/V pages, no state),
-        ``MIXER_LINEAR`` (a gated delta-rule state, no pages) or
+        and the seat pool: ``MIXER_ATTENTION`` (K/V pages, or latent pages
+        where the model ``is_mla``, no state), ``MIXER_LINEAR`` (a gated
+        delta-rule state in ``lin_gate``'s form, no pages) or
         ``MIXER_BOTH`` (Falcon-H1: state-space heads beside attention
         heads, pages and a state)."""
         if self.mamba_d_ssm:
@@ -284,6 +323,59 @@ class ModelConfig:
         """Channels of a linear layer's short convolution: q, k, then v."""
         return (2 * self.lin_num_key_heads * self.lin_key_head_dim
                 + self.lin_num_value_heads * self.lin_value_head_dim)
+
+    @property
+    def layer_group_size(self) -> int:
+        """bailing_hybrid's spelling of ``linear_layers``: the period ``p``
+        with layer ``i`` an attention layer exactly where ``(i + 1) % p ==
+        0`` (over the PUBLISHED layers); 0 where the kinds follow no such
+        period or no layer is linear."""
+        kinds = self.linear_layers or ()
+        return next((p for p in range(2, len(kinds) + 1)
+                     if all(lin == bool((i + 1) % p)
+                            for i, lin in enumerate(kinds))), 0)
+
+    @property
+    def lin_safe_gate(self) -> bool:
+        """bailing_hybrid ``kda_safe_gate``: the channel gate's bounded
+        form, the only one built."""
+        return self.lin_gate == "channel"
+
+    @property
+    def attn_gate_granularity(self) -> Optional[str]:
+        """bailing_hybrid ``gated_attention_proj_granularity_type``."""
+        return "head_wise" if self.attn_head_gate else None
+
+    @property
+    def expert_swiglu_limit_list(self) -> list:
+        """The running layers' clamp on the routed experts' gated
+        activation as config.json lists it, 0 = none (the only value
+        :meth:`require_built` lets run)."""
+        return list((self.moe_swiglu_limits
+                     or (0,) * self.num_layers)[:self.num_layers])
+
+    @property
+    def share_expert_swiglu_limit_list(self) -> list:
+        return list((self.moe_shared_swiglu_limits
+                     or (0,) * self.num_layers)[:self.num_layers])
+
+    @property
+    def moe_shared_intermediate_size(self) -> int:
+        return self.expert_intermediate_size * self.moe_shared_experts
+
+    def require_built(self) -> None:
+        """Refuse, by name, what this configuration states and no code
+        computes -- asked where weights are made or loaded, so that a
+        registered model whose LATER layers need it can still be cut to
+        the layers that do not (a benchmark configuration's depth)."""
+        for key in ("expert_swiglu_limit_list",
+                    "share_expert_swiglu_limit_list"):
+            clamped = [i for i, x in enumerate(getattr(self, key)) if x]
+            if clamped:
+                raise ValueError(
+                    f"{self.name}: {key} clamps the gated activation of "
+                    f"layers {clamped}: the clamped activation is not built "
+                    "(only layers whose entry is 0 run)")
 
     @property
     def mamba_conv_dim(self) -> int:
@@ -452,11 +544,17 @@ class ModelConfig:
                         for i in range(self.num_layers)))
 
     @property
+    def qk_head_dim(self) -> int:
+        """Width of an attention layer's queries and keys: ``head_dim``,
+        but for a latent layer beside another mixer (mla_qk_head_dim)."""
+        return self.mla_qk_head_dim or self.head_dim
+
+    @property
     def attn_scale(self) -> float:
         """Attention score scale: Gemma2 uses query_pre_attn_scalar**-0.5
         instead of head_dim**-0.5; under YaRN with mscale_all_dim the
         DeepSeek magnitude correction squares in (HF DeepseekV3Attention)."""
-        scale = (self.query_pre_attn_scalar or self.head_dim) ** -0.5
+        scale = (self.query_pre_attn_scalar or self.qk_head_dim) ** -0.5
         if self.rope_yarn is not None and self.rope_yarn[4]:
             from tpuserve.ops.rope import yarn_mscale
             m = yarn_mscale(self.rope_yarn[0], self.rope_yarn[4])
@@ -465,7 +563,7 @@ class ModelConfig:
 
     @property
     def q_size(self) -> int:
-        return self.num_heads * self.head_dim
+        return self.num_heads * self.qk_head_dim
 
     @property
     def attn_out_size(self) -> int:
@@ -488,9 +586,10 @@ class ModelConfig:
 
     @property
     def mla_qk_nope_head_dim(self) -> int:
-        """q/k split: head_dim covers nope + rope (matches HF qk_head_dim,
-        so attn_scale = head_dim**-0.5 is DeepSeek's scaling)."""
-        return self.head_dim - self.mla_qk_rope_head_dim
+        """q/k split: qk_head_dim covers nope + rope (matches HF
+        qk_head_dim, so attn_scale = qk_head_dim**-0.5 is DeepSeek's
+        scaling)."""
+        return self.qk_head_dim - self.mla_qk_rope_head_dim
 
     @property
     def mla_latent_dim(self) -> int:
@@ -553,11 +652,23 @@ class ModelConfig:
         """Approximate parameter count (embeddings counted once if tied)."""
         h, i, l, v = self.hidden_size, self.intermediate_size, self.num_layers, self.vocab_size
         attn = h * self.q_size + 2 * h * self.kv_size + self.q_size * h
+        if self.is_mla:
+            attn = ((h * self.mla_q_lora_rank
+                     + self.mla_q_lora_rank * self.q_size)
+                    if self.mla_q_lora_rank else h * self.q_size) \
+                + h * self.mla_latent_dim + self.mla_kv_lora_rank * (
+                    self.num_heads * (self.mla_qk_nope_head_dim
+                                      + self.mla_v_head_dim)) \
+                + self.attn_out_size * h
+        dense_mlp = (3 if self.mlp_style == "gated" else 2) * h * i
         if self.num_experts:
-            mlp = (self.moe_local_experts * 3 * h
+            # the held experts, the router at its published width and the
+            # shared expert; the leading dense layers (moe_first_k_dense)
+            # are put right below
+            mlp = ((self.moe_local_experts + self.moe_shared_experts) * 3 * h
                    * self.expert_intermediate_size + h * self.num_experts)
         else:
-            mlp = (3 if self.mlp_style == "gated" else 2) * h * i
+            mlp = dense_mlp
         embed = v * h * (1 if self.tie_word_embeddings else 2)
         ssm = 0
         if self.has_ssm:
@@ -565,8 +676,9 @@ class ModelConfig:
                    + self.mamba_conv_dim * (self.mamba_d_conv + 1)
                    + 3 * self.mamba_n_heads + self.mamba_d_ssm)
         linear = len(self.state_layers) if not self.has_ssm else 0
+        dense = min(self.moe_first_k_dense, l) if self.num_experts else 0
         return ((l - linear) * attn + linear * self.lin_layer_params
-                + l * (mlp + ssm) + embed)
+                + l * (mlp + ssm) + dense * (dense_mlp - mlp) + embed)
 
     @property
     def lin_layer_params(self) -> int:
@@ -575,9 +687,15 @@ class ModelConfig:
         and decay) with ``A_log`` and ``dt_bias``, the convolution."""
         h, heads = self.hidden_size, self.lin_num_value_heads
         d_v = heads * self.lin_value_head_dim
-        return (h * (self.lin_conv_dim + d_v) + d_v * h + 2 * h * heads
-                + 2 * heads + self.lin_conv_kernel * self.lin_conv_dim
-                + self.lin_value_head_dim)
+        # the decay's projection and its bias: a scalar a head, or (the
+        # channel gate) a value a key channel; pre-norm layers carry the
+        # mixer's and the MLP's input norms
+        d_g = heads * self.lin_key_head_dim if self.lin_gate == "channel" \
+            else heads
+        norms = 0 if self.norm_placement == "post" else 2 * h
+        return (h * (self.lin_conv_dim + d_v) + d_v * h + h * (heads + d_g)
+                + heads + d_g + self.lin_conv_kernel * self.lin_conv_dim
+                + self.lin_value_head_dim + norms)
 
 
 _REGISTRY: dict[str, ModelConfig] = {}
@@ -642,6 +760,8 @@ def config_from_hf_json(name: str, hf: dict) -> ModelConfig:
         return _olmo_hybrid_config(hf, common)
     if family == "pangu_ultra_moe":
         return _pangu_ultra_moe_config(hf, common)
+    if family == "bailing_hybrid":
+        return _bailing_hybrid_config(hf, common)
     if "opt" in family:
         common["tie_word_embeddings"] = hf.get("tie_word_embeddings", True)
         return ModelConfig(
@@ -1051,6 +1171,126 @@ def _pangu_ultra_moe_config(hf: dict, common: dict) -> ModelConfig:
         moe_first_k_dense=hf.get("first_k_dense_replace", 0),
         **common,
     )
+
+
+def _bailing_hybrid_config(hf: dict, common: dict) -> ModelConfig:
+    """Ling-3.0-flash (``model_type`` ``bailing_hybrid``): a pre-norm
+    decoder whose layers differ in their kind of mixer by a PERIOD
+    (``layer_group_size`` p: layer i is a latent-attention layer where
+    ``(i + 1) % p == 0``, every other a Kimi-delta linear-attention layer,
+    arXiv:2510.26692: the gated delta rule with a decay for every key
+    channel, ``lin_gate`` "channel"), ``first_k_dense_replace`` dense MLPs
+    and then sigmoid-routed experts behind DeepSeek-V3's group-limited
+    router (``n_group`` / ``topk_group``, a selection bias) beside a shared
+    expert.  The latent layers are DeepSeek's without a query latent, with
+    a q/k norm (``use_qk_norm``) and a sigmoid gate a head on their output
+    (``gated_attention_proj_granularity_type`` head_wise).  ``head_dim``
+    is the linear layers' head and the base of ``partial_rotary_factor``;
+    the latent layers' q/k are ``qk_nope_head_dim + qk_rope_head_dim``
+    wide (``mla_qk_head_dim``).  What the keys leave open is listed with
+    its reason in benchmark/configs/ling-3.0-flash-vl-ep8-l12.json
+    (``assumed``).  The vision tower of the VL checkpoint (of which
+    config.json holds four token ids and no size), the multi-token-
+    prediction layer and the clamped gated activation of the last layers
+    (``expert_swiglu_limit_list``) are not built; every switch for a
+    variant this code does not compute rejects loudly."""
+    layers = hf["num_hidden_layers"]
+    period = hf.get("layer_group_size")
+    if not isinstance(period, int) or period < 2:
+        raise ValueError("bailing_hybrid configs must carry layer_group_size "
+                         "(layer i attends where (i + 1) % layer_group_size "
+                         f"== 0); got {period!r}")
+    nh = hf["num_attention_heads"]
+    d = hf.get("head_dim") or hf["hidden_size"] // nh
+    only = {"kda_safe_gate": True, "linear_silu": True, "group_norm_size": 1,
+            "use_mla_nope": False, "use_nGPT": False, "value_norm": False,
+            "scale_router_input": False, "up_proj_norm": False,
+            "use_kda_lora": False, "use_bias": False, "use_qkv_bias": False,
+            "rope_scaling": None, "q_lora_rank": None}
+    for key, built in only.items():
+        if hf.get(key, built) != built:
+            raise ValueError(f"bailing_hybrid with {key} {hf[key]!r} is not "
+                             f"supported ({built!r} is what is built)")
+    if not hf.get("no_kda_lora", True):
+        raise ValueError("bailing_hybrid with a low-rank decay projection "
+                         "(no_kda_lora false) is not supported")
+    if hf.get("num_kv_heads_for_linear_attn") not in (None, 0, nh) \
+            or hf.get("num_key_value_heads", nh) != nh:
+        raise ValueError(
+            "bailing_hybrid with fewer key heads than query heads "
+            f"(num_kv_heads_for_linear_attn "
+            f"{hf.get('num_kv_heads_for_linear_attn')!r}, "
+            f"num_key_value_heads {hf.get('num_key_value_heads')!r}) is not "
+            "supported")
+    bound = hf.get("kda_lower_bound")
+    if not isinstance(bound, (int, float)) or not bound < 0:
+        raise ValueError(f"bailing_hybrid kda_lower_bound {bound!r}: the safe "
+                         "gate needs a negative bound")
+    rope = hf["qk_rope_head_dim"]
+    factor = hf.get("partial_rotary_factor", rope / d)
+    if int(d * factor) != rope or hf.get("rotary_dim", rope) != rope:
+        raise ValueError(
+            f"bailing_hybrid partial_rotary_factor {factor!r} of head_dim "
+            f"{d} (rotary_dim {hf.get('rotary_dim')!r}) is not "
+            f"qk_rope_head_dim {rope}")
+    granularity = hf.get("gated_attention_proj_granularity_type")
+    if granularity not in (None, "head_wise"):
+        raise ValueError("bailing_hybrid "
+                         "gated_attention_proj_granularity_type "
+                         f"{granularity!r} is not supported (head_wise is)")
+    score = hf.get("score_function", hf.get("scoring_func", "sigmoid"))
+    if score != "sigmoid" or hf.get("topk_method", "noaux_tc") != "noaux_tc":
+        raise ValueError(f"unsupported bailing_hybrid router: score_function "
+                         f"{score!r}, topk_method {hf.get('topk_method')!r}")
+    ei = hf["moe_intermediate_size"]
+    si = hf.get("moe_shared_expert_intermediate_size", ei) \
+        * hf.get("num_shared_experts", 1)
+    if si % ei:
+        raise ValueError(f"bailing_hybrid shared expert of width {si} beside "
+                         f"experts of {ei}")
+
+    def limits(key):
+        got = hf.get(key)
+        if got is not None and len(got) != layers:
+            raise ValueError(f"bailing_hybrid {key} states {len(got)} "
+                             f"layers of {layers}")
+        return None if got is None else tuple(got)
+
+    cfg = ModelConfig(
+        intermediate_size=hf["intermediate_size"],
+        num_kv_heads=nh, head_dim=d,
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        partial_rotary_factor=factor,
+        norm_eps=hf.get("rms_norm_eps", 1e-6),
+        act=hf.get("hidden_act", "silu"),
+        qk_norm=bool(hf.get("use_qk_norm", False)),
+        attn_head_gate=granularity == "head_wise",
+        mla_kv_lora_rank=hf["kv_lora_rank"], mla_q_lora_rank=None,
+        mla_qk_rope_head_dim=rope, mla_v_head_dim=hf["v_head_dim"],
+        mla_qk_head_dim=hf["qk_nope_head_dim"] + rope,
+        mla_rope_interleave=hf.get("rope_interleave", True),
+        linear_layers=tuple(bool((i + 1) % period) for i in range(layers)),
+        lin_num_key_heads=nh, lin_num_value_heads=nh,
+        lin_key_head_dim=d, lin_value_head_dim=d,
+        lin_conv_kernel=hf.get("short_conv_kernel_size", 4),
+        lin_gate="channel", lin_gate_lower_bound=float(bound),
+        num_experts=hf["num_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=ei,
+        norm_topk_prob=hf.get("norm_topk_prob", True),
+        moe_scoring="sigmoid",
+        moe_router_bias=bool(hf.get("moe_router_enable_expert_bias", False)),
+        moe_n_group=hf.get("n_group") or 1,
+        moe_topk_group=hf.get("topk_group") or 1,
+        moe_routed_scaling=hf.get("routed_scaling_factor", 1.0),
+        moe_shared_experts=si // ei,
+        moe_first_k_dense=hf.get("first_k_dense_replace", 0),
+        moe_swiglu_limits=limits("expert_swiglu_limit_list"),
+        moe_shared_swiglu_limits=limits("share_expert_swiglu_limit_list"),
+        **common,
+    )
+    cfg.require_built()
+    return cfg
 
 
 def _olmo_hybrid_config(hf: dict, common: dict) -> ModelConfig:
@@ -1469,6 +1709,43 @@ register_model_config(ModelConfig(
     lin_value_head_dim=192, lin_conv_kernel=4, lin_allow_neg_eigval=True,
 ), "olmo-hybrid-7b")
 
+# Ling-3.0-flash-VL's language model (inclusionAI; ``bailing_hybrid``): 42
+# pre-norm layers of hidden 2,560 in periods of six, five Kimi-delta
+# linear-attention layers (32 heads, keys and values of 128, a causal
+# convolution of 4 in front, a decay for every key channel bounded below
+# at -5) to each latent-attention layer (32 heads, one cached vector of
+# 512 + 64 a token, 128 + 64 wide keys, values of 128, the plain rotary
+# table at theta 6e6, a q/k norm, a sigmoid gate a head on the output);
+# two dense layers of width 6,144, then 512 routed experts of width 768,
+# eight a token by sigmoid scores with a selection bias inside the 4 best
+# of 8 groups, renormalised and scaled 2.5, beside one shared expert.  The
+# numbers are config.json's; what it leaves open is in
+# benchmark/configs/ling-3.0-flash-vl-ep8-l12.json (``assumed``).  124 B
+# parameters: a chip serves its share of a cut of the depth
+# (``moe_experts_held``).  The vision tower, the multi-token-prediction
+# layer and the last layers' clamped activation (require_built) are not
+# built.
+register_model_config(ModelConfig(
+    name="inclusionAI/Ling-3.0-flash-VL",
+    vocab_size=157184, hidden_size=2560, intermediate_size=6144,
+    num_layers=42, num_heads=32, num_kv_heads=32, head_dim=128,
+    max_position_embeddings=131072, rope_theta=6000000.0, norm_eps=1e-6,
+    partial_rotary_factor=0.5, tie_word_embeddings=False,
+    qk_norm=True, attn_head_gate=True,
+    mla_kv_lora_rank=512, mla_qk_rope_head_dim=64, mla_v_head_dim=128,
+    mla_qk_head_dim=192,
+    linear_layers=tuple(bool((i + 1) % 6) for i in range(42)),
+    lin_num_key_heads=32, lin_num_value_heads=32, lin_key_head_dim=128,
+    lin_value_head_dim=128, lin_conv_kernel=4,
+    lin_gate="channel", lin_gate_lower_bound=-5.0,
+    num_experts=512, num_experts_per_tok=8, moe_intermediate_size=768,
+    norm_topk_prob=True, moe_scoring="sigmoid", moe_router_bias=True,
+    moe_n_group=8, moe_topk_group=4, moe_routed_scaling=2.5,
+    moe_shared_experts=1, moe_first_k_dense=2,
+    moe_swiglu_limits=(0,) * 35 + (4,) * 7,
+    moe_shared_swiglu_limits=(0,) * 34 + (5,) * 6 + (7,) * 2,
+), "ling-3.0-flash-vl")
+
 # Tiny configs for tests / CPU smoke (one per architectural family).
 register_model_config(ModelConfig(
     name="tiny-qwen3",
@@ -1570,6 +1847,34 @@ register_model_config(ModelConfig(
     lin_num_key_heads=6, lin_num_value_heads=6, lin_key_head_dim=24,
     lin_value_head_dim=48, lin_conv_kernel=4, lin_allow_neg_eigval=True,
     lin_chunk_size=8,
+))
+
+# Ling-3.0-flash in small: two periods of K K A (Kimi-delta, Kimi-delta,
+# latent attention), 4 heads of 16 (keys and values of the linear layers;
+# NOT a lane tile, so the pool's slabs of two heads run), a scan chunk of
+# 32 (two sub-blocks of 16), the cached vector 136 + 12 = 148 wide stored
+# as 256 (tiny-pangu's, for the same reason), q/k 16 + 12 wide under a
+# q/k norm and a gate a head; one dense layer, then 8 experts in 2 groups
+# of which 1 survives, 2 a token by biased sigmoid scores, scaled 2.5,
+# beside a shared one.  Every expert held; a test takes group 0 with
+# dataclasses.replace.  float32 like tiny-mistral.
+register_model_config(ModelConfig(
+    name="tiny-ling-hybrid",
+    vocab_size=256, hidden_size=64, intermediate_size=160,
+    num_layers=6, num_heads=4, num_kv_heads=4, head_dim=16,
+    max_position_embeddings=512, rope_theta=6000000.0, norm_eps=1e-6,
+    partial_rotary_factor=0.75, tie_word_embeddings=False, eos_token_id=1,
+    dtype="float32", qk_norm=True, attn_head_gate=True,
+    mla_kv_lora_rank=136, mla_qk_rope_head_dim=12, mla_v_head_dim=16,
+    mla_qk_head_dim=28,
+    linear_layers=tuple(bool((i + 1) % 3) for i in range(6)),
+    lin_num_key_heads=4, lin_num_value_heads=4, lin_key_head_dim=16,
+    lin_value_head_dim=16, lin_conv_kernel=4, lin_chunk_size=32,
+    lin_gate="channel", lin_gate_lower_bound=-5.0,
+    num_experts=8, num_experts_per_tok=2, moe_intermediate_size=32,
+    norm_topk_prob=True, moe_scoring="sigmoid", moe_router_bias=True,
+    moe_n_group=2, moe_topk_group=1, moe_routed_scaling=2.5,
+    moe_shared_experts=1, moe_first_k_dense=1,
 ))
 
 register_model_config(ModelConfig(

@@ -141,6 +141,15 @@ def _attn_residual(h: jnp.ndarray, out: jnp.ndarray, lp: dict,
     with jax.named_scope(scopes.ATTN_OUT):
         if out.shape[-2] != cfg.num_heads:      # cfg.cache_q_heads' zeros
             out = out[..., :cfg.num_heads, :]
+        if cfg.attn_head_gate:
+            # a sigmoid gate a HEAD (``attn_gate_proj``) from the layer's
+            # normed input, on the heads' output before o_proj.  The input
+            # norm is taken again here, where h and the weights are at
+            # hand: the compiler folds it into _mla_latents' own
+            with jax.named_scope(scopes.ATTN_GATE):
+                gate = jax.nn.sigmoid(_linear(
+                    _norm(h, lp["attn_norm"], cfg), lp["attn_gate_proj"], ad))
+                out = out * gate[..., None].astype(out.dtype)
         if cfg.norm_placement == "post":
             return h + _post_norm(
                 _linear(out.reshape(*out.shape[:-2], -1), lp["o_proj"], ad,
@@ -278,6 +287,7 @@ def _moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
         if "router_bias" in p:
             choice = choice + p["router_bias"]["bias"][None, :]
         E = scores.shape[-1]
+        group_rows = None
         if cfg.moe_n_group > 1:
             G = cfg.moe_n_group
             grouped = choice.reshape(T, G, E // G)
@@ -296,6 +306,14 @@ def _moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
             # HF masks non-selected groups to 0.0, not -inf
             choice = jnp.where(gmask[..., None] > 0, grouped,
                                0.0).reshape(T, E)
+            if cfg.moe_experts_held:
+                # rows one of whose surviving groups lies (partly) here:
+                # the rows a chip that holds this share is sent at all
+                per = E // G
+                lo = cfg.moe_first_expert // per
+                hi = -(-(cfg.moe_first_expert + cfg.moe_experts_held) // per)
+                group_rows = jnp.sum(jnp.any(gmask[:, lo:hi] > 0, axis=-1),
+                                     dtype=jnp.int32)
         _, topi = jax.lax.top_k(choice, k)                     # (T, k)
         topv = jnp.take_along_axis(scores, topi, axis=-1)      # unbiased
         if cfg.norm_topk_prob:
@@ -317,6 +335,9 @@ def _moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
                 f"{cfg.name}: the dense form of the expert layer runs ALL "
                 "experts under a mesh; a share of them has no such form")
         y, landed = _moe_held_experts(xt, ek, topi, topv, cfg)
+        if group_rows is not None:          # a fifth count under groups
+            with jax.named_scope(scopes.MOE_ROUTE):
+                landed = jnp.concatenate([landed, group_rows[None]])
     elif dense:
         y = _moe_dense_experts(xt, ek, topi, topv, cfg)
     else:
@@ -639,8 +660,16 @@ def _mla_latents(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
         c = rmsnorm(ckv[..., :cfg.mla_kv_lora_rank],
                     lp["kv_a_norm"]["scale"], cfg.norm_eps,
                     cfg.norm_weight_offset)
-        k_rope = _mla_rope(ckv[..., None, cfg.mla_kv_lora_rank:], cfg,
-                           positions)[..., 0, :]
+        k_rope = ckv[..., None, cfg.mla_kv_lora_rank:]
+        if cfg.qk_norm:
+            # the q/k norm's key side, on what of the ONE key all heads
+            # share the latent's own norm does not cover: the rope key,
+            # before its rotation.  (A norm over each head's DECOMPRESSED
+            # 192-wide key is a scalar a token a head that a 576-wide
+            # latent page does not hold: no absorbed form serves it.)
+            k_rope = rmsnorm(k_rope, lp["k_norm"]["scale"], cfg.norm_eps,
+                             cfg.norm_weight_offset)
+        k_rope = _mla_rope(k_rope, cfg, positions)[..., 0, :]
         return cq, jnp.concatenate([c, k_rope], axis=-1)
 
 
@@ -651,7 +680,10 @@ def _mla_queries(cq: jnp.ndarray, lp: dict, cfg: ModelConfig,
     the published sizes)."""
     with jax.named_scope(scopes.ATTN_QKV):
         q = _linear(cq, lp["q_b_proj" if "q_a_proj" in lp else "q_proj"], ad)
-        q = q.reshape(*cq.shape[:-1], cfg.num_heads, cfg.head_dim)
+        q = q.reshape(*cq.shape[:-1], cfg.num_heads, cfg.qk_head_dim)
+        if cfg.qk_norm:                     # a head at a time, unrotated
+            q = rmsnorm(q, lp["q_norm"]["scale"], cfg.norm_eps,
+                        cfg.norm_weight_offset)
         nope = cfg.mla_qk_nope_head_dim
         return q[..., :nope], _mla_rope(q[..., nope:], cfg, positions)
 
@@ -1055,12 +1087,19 @@ def _ssm_decode(hn: jnp.ndarray, sp: dict, cfg: ModelConfig,
 # readers by scope read both mixers alike, the kernels differ by name.
 # Equations: arXiv:2412.06464 (ops/gated_delta.py), the step size doubled
 # under ``lin_allow_neg_eigval``; benchmark/reference/olmo_hybrid.py
-# writes the layer out.
+# writes the layer out.  The mixer's FORM is ``cfg.lin_gate``: "scalar" is
+# the above; "channel" is Kimi Delta Attention (arXiv:2510.26692;
+# Ling-3.0-flash's linear layers, which stand five to one with LATENT
+# attention layers: pages of one kind and a pool in one model), the same
+# routes with a decay for every key channel (``f_proj``, the bounded gate),
+# a sigmoid output gate, ``kda_chunk_scan`` and the kernel
+# ``_kda_state_update``; benchmark/reference/ling_hybrid.py writes it out.
 
 def _lin_project(h: jnp.ndarray, lp: dict, cfg: ModelConfig):
     """The residual stream h (..., hidden) -> the convolution's input
     [q | k | v] (..., conv_dim), the output gate (..., H dv), the raw
-    decay and the raw step size (..., H)."""
+    decay (..., H; the channel gate's: (..., H dk)) and the raw step size
+    (..., H)."""
     sp = lp["lin"]
     with jax.named_scope(scopes.SSM_IN_PROJ):
         u = h.astype(cfg.dtype) if cfg.norm_placement == "post" \
@@ -1068,8 +1107,17 @@ def _lin_project(h: jnp.ndarray, lp: dict, cfg: ModelConfig):
         # every product leaves in float32 (_post_norm has the reason; the
         # two scalars a head most of all: a rounding of the decay's
         # exponent is a rounding of every later row's read of the state)
-        return tuple(_linear(u, sp[name], out=jnp.float32) for name in
-                     ("qkv_proj", "g_proj", "a_proj", "b_proj"))
+        def proj(name):
+            return _linear(u, sp[name], out=jnp.float32)
+
+        if cfg.lin_gate != "channel":
+            return tuple(map(proj, ("qkv_proj", "g_proj", "a_proj",
+                                    "b_proj")))
+        # Kimi-delta: the decay's projection is as wide as the keys
+        qkv, gate = proj("qkv_proj"), proj("g_proj")
+        with jax.named_scope(scopes.SSM_GATE):
+            f_raw = proj("f_proj")
+        return qkv, gate, f_raw, proj("b_proj")
 
 
 def _lin_qkv(x: jnp.ndarray, cfg: ModelConfig):
@@ -1095,9 +1143,10 @@ def _lin_inputs(conv_out: jnp.ndarray, a_raw: jnp.ndarray,
                 valid: jnp.ndarray):
     """Convolved [q | k | v] (f32), raw decay and step size -> the
     activated channels x (..., conv_dim) that :func:`_lin_qkv` splits,
-    the log of the decay g <= 0 and the step size beta in [0, 2) (..., H)
-    f32.  Rows that are not ``valid`` come out as zeros (``_ssm_inputs``
-    has the reason), which neither decay nor write the state."""
+    the log of the decay g <= 0 (..., H; the channel gate's (..., H, dk))
+    and the step size beta in [0, 2) (..., H) f32.  Rows that are not
+    ``valid`` come out as zeros (``_ssm_inputs`` has the reason), which
+    neither decay nor write the state."""
     with jax.named_scope(scopes.SSM_CONV):
         x = jnp.where(valid[..., None], jax.nn.silu(conv_out), 0.0)
         # activations in the model's dtype, as everywhere else in the
@@ -1106,6 +1155,16 @@ def _lin_inputs(conv_out: jnp.ndarray, a_raw: jnp.ndarray,
         beta = jax.nn.sigmoid(b_raw.astype(jnp.float32))
         if cfg.lin_allow_neg_eigval:
             beta = 2.0 * beta
+        if cfg.lin_gate == "channel":
+            # Kimi-delta's safe gate: a value a key channel in (bound, 0)
+            with jax.named_scope(scopes.SSM_GATE):
+                H, dk = cfg.lin_num_value_heads, cfg.lin_key_head_dim
+                logit = (a_raw.astype(jnp.float32) + sp["dt_bias"]).reshape(
+                    *a_raw.shape[:-1], H, dk)
+                g = cfg.lin_gate_lower_bound * jax.nn.sigmoid(
+                    jnp.exp(sp["A_log"].astype(jnp.float32))[:, None] * logit)
+                g = jnp.where(valid[..., None, None], g, 0.0)
+            return x, g, jnp.where(valid[..., None], beta, 0.0)
         g = -jnp.exp(sp["A_log"].astype(jnp.float32)) * jax.nn.softplus(
             a_raw.astype(jnp.float32) + sp["dt_bias"])
         return x, jnp.where(valid[..., None], g, 0.0), \
@@ -1123,11 +1182,18 @@ def _lin_output(o: jnp.ndarray, gate: jnp.ndarray, h: jnp.ndarray, lp: dict,
         o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True)
                               + cfg.norm_eps)
         o = o * sp["norm"]["scale"].astype(jnp.float32)
-        y = o.reshape(gate.shape) * jax.nn.silu(gate.astype(jnp.float32))
+        act = jax.nn.sigmoid if cfg.lin_gate == "channel" else jax.nn.silu
+        y = o.reshape(gate.shape) * act(gate.astype(jnp.float32))
         m = _linear(y.astype(cfg.dtype), sp["o_proj"], out=jnp.float32)
         if cfg.norm_placement == "post":
             return h + _post_norm(m, lp["post_attn_norm"], cfg)
         return h + m.astype(h.dtype)
+
+
+def _lin_scan(cfg: ModelConfig):
+    """The chunked scan of the linear mixer's form (``cfg.lin_gate``)."""
+    return gdn_ops.kda_chunk_scan if cfg.lin_gate == "channel" \
+        else gdn_ops.gated_delta_chunk_scan
 
 
 def _lin_slabs(entry: dict, cfg: ModelConfig) -> int:
@@ -1177,8 +1243,9 @@ def _lin_window(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
     x, g, beta = _lin_inputs(conv_out, a_raw, b_raw, sp, cfg, valid)
     # the scan's chunk: as _ssm_window chooses it
     Q = math.gcd(cfg.lin_chunk_size, L)
-    o, finals = gdn_ops.gated_delta_chunk_scan(
-        x.reshape(B * L, -1), g.reshape(B * L, H), beta.reshape(B * L, H),
+    o, finals = _lin_scan(cfg)(
+        x.reshape(B * L, -1), g.reshape(B * L, *g.shape[2:]),
+        beta.reshape(B * L, H),
         s0, jnp.repeat(jnp.arange(B, dtype=jnp.int32), L // Q), chunk=Q,
         split=partial(_lin_qkv, cfg=cfg), out_dtype=x.dtype)
     h = _lin_output(o.reshape(B, L, H, dv), gate, h, lp, cfg)
@@ -1212,7 +1279,7 @@ def _lin_packed(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
         conv_out = conv_out[0]
     x, g, beta = _lin_inputs(conv_out, a_raw, b_raw, sp, cfg, valid)
     Q = math.gcd(cfg.lin_chunk_size, blk)
-    o, finals = gdn_ops.gated_delta_chunk_scan(
+    o, finals = _lin_scan(cfg)(
         x, g, beta, jnp.zeros((q_lens.shape[0], H, dk, dv), jnp.float32),
         jnp.repeat(blk_seq, blk // Q), chunk=Q,
         split=partial(_lin_qkv, cfg=cfg), out_dtype=x.dtype)
@@ -1239,7 +1306,14 @@ def _lin_decode(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
     sp = lp["lin"]
     qkv, gate, a_raw, b_raw = _lin_project(h, lp, cfg)
     from tpuserve.ops import pallas_conv_tail as tap
-    from tpuserve.ops import pallas_gdn_update as upd
+    if cfg.lin_gate == "channel":
+        from tpuserve.ops import pallas_kda_update as upd
+        kernel, formula = (upd.kda_state_update,
+                           upd.kda_state_update_reference)
+    else:
+        from tpuserve.ops import pallas_gdn_update as upd
+        kernel, formula = (upd.gdn_state_update,
+                           upd.gdn_state_update_reference)
     pallas = attn_impl == "pallas"
     with jax.named_scope(scopes.SSM_CONV):
         valid = slot_ids != attn_ops.PAD_SLOT
@@ -1248,8 +1322,7 @@ def _lin_decode(h: jnp.ndarray, lp: dict, cfg: ModelConfig,
             entry["conv"], seats, qkv, sp["conv"]["kernel"], None)
     x, g, beta = _lin_inputs(conv_out, a_raw, b_raw, sp, cfg, valid)
     with jax.named_scope(scopes.SSM_SCAN):
-        o, state = (upd.gdn_state_update if pallas
-                    else upd.gdn_state_update_reference)(
+        o, state = (kernel if pallas else formula)(
             entry["state"], seats, *_lin_qkv(x, cfg), g, beta)
     h = _lin_output(o, gate, h, lp, cfg)
     return h, {"state": state, "conv": conv}
@@ -1328,7 +1401,9 @@ def _moe_counts(tally: list):
     touched expert's kernels are read once a layer).  Under a share
     (:func:`_moe_held_experts`) four more, summed over the layers: the
     rows that landed on held experts, the held expert-layers that got at
-    least one, the rows of buffer moved for them and the pieces moved."""
+    least one, the rows of buffer moved for them and the pieces moved;
+    behind a group-limited router a fifth, the rows one of whose
+    surviving groups is held here (:func:`_moe_mlp`)."""
     with jax.named_scope(scopes.MOE_ROUTE):
         sizes = jnp.stack([s for s, _, _ in tally])            # (L, E)
         counts = [jnp.sum(sizes, axis=0),

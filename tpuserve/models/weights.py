@@ -102,6 +102,8 @@ def _init_layer(key, cfg: ModelConfig, dense_mlp: bool, mesh=None,
     h = cfg.hidden_size
     if linear:
         lp = {"lin": _init_lin(d, cfg)}
+        if cfg.norm_placement != "post":    # pre-norm: on each branch's input
+            lp["attn_norm"], lp["mlp_norm"] = d.norm(h), d.norm(h)
     elif cfg.is_mla:
         # DeepSeek MLA: low-rank q (optional), compressed-KV latent +
         # shared roped key, per-head up-projections packed in kv_b_proj
@@ -124,6 +126,8 @@ def _init_layer(key, cfg: ModelConfig, dense_mlp: bool, mesh=None,
             lp["q_b_proj"] = d.dense(cfg.mla_q_lora_rank, cfg.q_size, False)
         else:
             lp["q_proj"] = d.dense(h, cfg.q_size, False)
+        if cfg.attn_head_gate:              # a sigmoid gate a head
+            lp["attn_gate_proj"] = d.dense(h, cfg.num_heads, False)
     else:
         lp = {
             "attn_norm": d.norm(h),
@@ -143,8 +147,11 @@ def _init_layer(key, cfg: ModelConfig, dense_mlp: bool, mesh=None,
         lp["ssm"] = _init_ssm(d, cfg)
     if cfg.qk_norm and not linear:
         # a head's width, or the whole projection's (qk_norm_whole)
+        # (a latent layer: each head's query, and the ONE key the heads
+        # share that the latent's own norm does not cover, the rope key)
         qn, kn = ((cfg.q_size, cfg.kv_size) if cfg.qk_norm_whole
-                  else (cfg.head_dim, cfg.head_dim))
+                  else (cfg.qk_head_dim, cfg.mla_qk_rope_head_dim)
+                  if cfg.is_mla else (cfg.head_dim, cfg.head_dim))
         lp["q_norm"] = {"scale": jnp.full((qn,), d.norm_init, d.dtype)}
         lp["k_norm"] = {"scale": jnp.full((kn,), d.norm_init, d.dtype)}
     if cfg.norm_placement == "post":
@@ -223,8 +230,8 @@ def _init_ssm(d: _Draw, cfg: ModelConfig) -> Params:
 
 def _init_lin(d: _Draw, cfg: ModelConfig) -> Params:
     """The gated delta-rule mixer of one linear-attention layer: the q, k
-    and v projections as one matrix, separate gate, decay (``a_proj``) and
-    step-size (``b_proj``) projections, one depthwise convolution over
+    and v projections as one matrix, separate gate, decay (``a_proj``; the
+    channel gate's ``f_proj``) and step-size (``b_proj``) projections, one depthwise convolution over
     [q | k | v], a per-head
     norm weight shared by the heads.  ``A_log`` and ``dt_bias`` as the
     published gated delta-rule code draws them (A in [0, 16], the step dt
@@ -245,19 +252,40 @@ def _init_lin(d: _Draw, cfg: ModelConfig) -> Params:
         if cfg.norm_placement == "post" else 1.0
     d.n += 1
     ka, kd = jax.random.split(jax.random.fold_in(d.key, d.n))
-    a = jax.random.uniform(ka, (hs,), jnp.float32, 1e-2, 16.0)
-    dt = jnp.exp(jax.random.uniform(kd, (hs,), jnp.float32,
-                                    jnp.log(1e-3), jnp.log(1e-1)))
+    if cfg.lin_gate == "channel":
+        # Kimi-delta: the decay's projection is a value a key channel
+        # (``f_proj``, full rank) under a bias a channel and A a head.
+        # exp(A_log) in [0.5, 1.5] scales the gate's logit and the bias, in
+        # [-6, -2], keeps it NEGATIVE under a unit-normal projection: the
+        # gate's sigmoid stands near 0.02 (0.38 to 1e-5 over the draws), a
+        # decay of exp(-0.09) a row at the published bound of -5, so a
+        # state remembers some ten rows (one to thousands by channel), as a
+        # trained layer's does.  Around zero the sigmoid would stand at a
+        # half and every channel forget inside one row: a head's output is
+        # then one row's (k . q) u, near zero as often as not, the per-head
+        # norm blows the rounding of a bf16 trunk up (_init_lin's note on
+        # the scalar gate) and the probe's log-probabilities read 0.06-0.08
+        # off on a sound trunk (PERF.md section 6, PR 55)
+        decay, width = "f_proj", hs * dk
+        a_log = jnp.log(jax.random.uniform(ka, (hs,), jnp.float32, 0.5, 1.5))
+        dt_bias = jax.random.uniform(kd, (width,), jnp.float32, -6.0, -2.0)
+    else:
+        decay, width = "a_proj", hs
+        a_log = jnp.log(jax.random.uniform(ka, (hs,), jnp.float32, 1e-2,
+                                           16.0))
+        dt = jnp.exp(jax.random.uniform(kd, (hs,), jnp.float32,
+                                        jnp.log(1e-3), jnp.log(1e-1)))
+        dt_bias = dt + jnp.log(-jnp.expm1(-dt))      # softplus^-1(dt)
     return {
         # Wq, Wk, Wv side by side, [q | k | v] as the convolution takes them
         "qkv_proj": d.dense(h, cfg.lin_conv_dim, False),
         "g_proj": d.dense(h, hs * dv, False),
-        "a_proj": d.dense(h, hs, False, stream),
+        decay: d.dense(h, width, False, stream),
         "b_proj": d.dense(h, hs, False, stream),
         "conv": {"kernel": d.normal((cfg.lin_conv_kernel, cfg.lin_conv_dim),
                                     cfg.lin_conv_kernel ** -0.5)},
-        "A_log": jnp.log(a),
-        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),    # softplus^-1(dt)
+        "A_log": a_log,
+        "dt_bias": dt_bias,
         "norm": {"scale": jnp.full((dv,), d.norm_init, d.dtype)},
         "o_proj": d.dense(hs * dv, h, False),
     }
@@ -289,6 +317,7 @@ def init_params(cfg: ModelConfig, seed: int = 0, mesh=None) -> Params:
     one device, so a model that needs tp to fit (Llama-3.1-8B on 16 GB
     chips) can be initialised at all.  The values depend on ``(cfg,
     seed)`` alone, not on the placement."""
+    cfg.require_built()
     key = jax.random.key(seed)
     params = _init_head(jax.random.fold_in(key, cfg.num_layers), cfg, mesh)
     # (``linear`` is passed only where it is set, so that a model without
@@ -353,10 +382,13 @@ def _mla_deinterleave(p: dict, cfg, heads: int, head_width: int) -> dict:
 
 def load_hf_checkpoint(cfg: ModelConfig, ckpt_dir: str) -> Params:
     """Convert an HF checkpoint directory into the transformer param pytree."""
+    cfg.require_built()
     raw = _read_safetensors(ckpt_dir)
     dtype = param_dtype(cfg)
     if cfg.pos == "learned":
         return _load_opt(cfg, raw, dtype)
+    if cfg.linear_layers is not None and cfg.lin_gate == "channel":
+        return _load_llama_family(cfg, raw, dtype)      # bailing_hybrid
     if cfg.linear_layers is not None:
         return _load_olmo_hybrid(cfg, raw, dtype)
     return _load_llama_family(cfg, raw, dtype)
@@ -413,6 +445,33 @@ def _load_olmo_hybrid(cfg: ModelConfig, raw: dict, dtype) -> Params:
             "lm_head": dense("lm_head")}
 
 
+def _load_kda(raw: dict, pre: str, dtype) -> Params:
+    """One Kimi-delta mixer (bailing_hybrid's linear layers).  The tensor
+    names are ASSUMED (the family's own code is unseen:
+    benchmark/configs/ling-3.0-flash-vl-ep8-l12.json ``assumed``): the
+    published Kimi Delta Attention layer's (``q_proj`` .. ``o_proj``, a
+    depthwise ``*_conv1d`` each for q, k and v, ``(channels, 1, width)``
+    with the last tap on the row itself, ``f_proj`` the decay's full-rank
+    projection, ``o_norm`` the per-head norm), joined as
+    :func:`_load_olmo_hybrid` joins Olmo-Hybrid's."""
+    def dense(name):
+        return {"kernel": _t(raw[pre + name + ".weight"], dtype)}
+
+    return {
+        "qkv_proj": {"kernel": jnp.concatenate([
+            dense(p)["kernel"] for p in ("q_proj", "k_proj", "v_proj")],
+            axis=1)},
+        **{p: dense(p) for p in ("g_proj", "f_proj", "b_proj", "o_proj")},
+        "conv": {"kernel": jnp.concatenate([
+            jnp.asarray(raw[pre + c + "_conv1d.weight"],
+                        dtype=dtype)[:, 0, :].T
+            for c in ("q", "k", "v")], axis=1)},
+        "A_log": jnp.asarray(raw[pre + "A_log"], jnp.float32),
+        "dt_bias": jnp.asarray(raw[pre + "dt_bias"], jnp.float32),
+        "norm": {"scale": jnp.asarray(raw[pre + "o_norm.weight"],
+                                      dtype=dtype)}}
+
+
 def _load_falcon_h1_ssm(raw: dict, pre: str, dtype) -> Params:
     """One layer's Mamba-2 mixer from HF ``modeling_falcon_h1`` names.
     ``conv1d.weight`` is (channels, 1, width), the last tap weighing the
@@ -453,10 +512,12 @@ def _load_llama_family(cfg: ModelConfig, raw: dict, dtype) -> Params:
     layers = []
     for i in range(cfg.num_layers):
         pre = f"model.layers.{i}."
-        lp = {
-            "attn_norm": norm_scale(pre + "input_layernorm.weight"),
-            "o_proj": dense(pre + "self_attn.o_proj.weight"),
-        }
+        linear = cfg.layer_mixer(i) == MIXER_LINEAR
+        lp = {"attn_norm": norm_scale(pre + "input_layernorm.weight")}
+        if linear:
+            lp["lin"] = _load_kda(raw, pre + "self_attn.", dtype)
+        else:
+            lp["o_proj"] = dense(pre + "self_attn.o_proj.weight")
         if cfg.sandwich_norms:
             # Gemma2: post_attention_layernorm wraps the ATTENTION OUTPUT;
             # the MLP pre-norm is pre_feedforward_layernorm
@@ -475,7 +536,9 @@ def _load_llama_family(cfg: ModelConfig, raw: dict, dtype) -> Params:
         else:
             lp["mlp_norm"] = norm_scale(
                 pre + "post_attention_layernorm.weight")
-        if cfg.is_mla:                                          # DeepSeek MLA
+        if linear:
+            pass                            # no attention projections
+        elif cfg.is_mla:                                        # DeepSeek MLA
             rope_d = cfg.mla_qk_rope_head_dim
             lp["kv_a_proj"] = _mla_deinterleave(
                 dense(pre + "self_attn.kv_a_proj_with_mqa.weight",
@@ -491,11 +554,11 @@ def _load_llama_family(cfg: ModelConfig, raw: dict, dtype) -> Params:
                     pre + "self_attn.q_a_layernorm.weight")
                 lp["q_b_proj"] = _mla_deinterleave(
                     dense(pre + "self_attn.q_b_proj.weight"), cfg,
-                    heads=cfg.num_heads, head_width=cfg.head_dim)
+                    heads=cfg.num_heads, head_width=cfg.qk_head_dim)
             else:
                 lp["q_proj"] = _mla_deinterleave(
                     dense(pre + "self_attn.q_proj.weight"), cfg,
-                    heads=cfg.num_heads, head_width=cfg.head_dim)
+                    heads=cfg.num_heads, head_width=cfg.qk_head_dim)
         elif pre + "self_attn.qkv_proj.weight" in raw:          # Phi-3 fused qkv
             qkv = jnp.asarray(raw[pre + "self_attn.qkv_proj.weight"], dtype=dtype)
             q, k, v = jnp.split(qkv, [cfg.q_size, cfg.q_size + cfg.kv_size], axis=0)
@@ -504,9 +567,21 @@ def _load_llama_family(cfg: ModelConfig, raw: dict, dtype) -> Params:
             for proj in ("q", "k", "v"):
                 lp[f"{proj}_proj"] = dense(pre + f"self_attn.{proj}_proj.weight",
                                            pre + f"self_attn.{proj}_proj.bias")
-        if cfg.qk_norm:
+        if cfg.attn_head_gate and not linear:
+            lp["attn_gate_proj"] = dense(pre + "self_attn.g_proj.weight")
+        if cfg.qk_norm and not linear:
             lp["q_norm"] = {"scale": jnp.asarray(get(pre + "self_attn.q_norm.weight"), dtype=dtype)}
             lp["k_norm"] = {"scale": jnp.asarray(get(pre + "self_attn.k_norm.weight"), dtype=dtype)}
+            if cfg.is_mla and cfg.mla_rope_interleave:
+                # a latent layer's q/k norm weighs the rope features the
+                # projections above were de-interleaved for: the same
+                # permutation, of the query's rope slice and of the rope key
+                perm = np.concatenate([np.arange(0, rope_d, 2),
+                                       np.arange(1, rope_d, 2)])
+                q_idx = np.arange(cfg.qk_head_dim)
+                q_idx[-rope_d:] = cfg.qk_head_dim - rope_d + perm
+                lp["q_norm"] = {"scale": lp["q_norm"]["scale"][q_idx]}
+                lp["k_norm"] = {"scale": lp["k_norm"]["scale"][perm]}
         moe_layer = cfg.num_experts and not cfg.moe_layer_is_dense(i)
         if moe_layer:                                           # Qwen3/DS MoE
             lp["router"] = {"kernel": _t(get(pre + "mlp.gate.weight"), dtype)}

@@ -95,7 +95,7 @@ def gdn_state_update_reference(state, seats, q, k, v, g, beta):
 
 
 def _kernel(seats_ref, q_ref, k_ref, v_ref, a_ref, b_ref, s_ref,
-            o_ref, so_ref, *, hp: int, dv: int):
+            o_ref, so_ref, *, hp: int, dv: int, channel: bool):
     del seats_ref                       # consumed by the index maps
     n_slab, dk = s_ref.shape[1], s_ref.shape[2]
     lane = jax.lax.broadcasted_iota(jnp.int32, (dk, hp * dv), 1)
@@ -110,7 +110,11 @@ def _kernel(seats_ref, q_ref, k_ref, v_ref, a_ref, b_ref, s_ref,
 
     for p in range(n_slab):
         kk = spread(k_ref, p)
-        s = s_ref[0, p] * a_ref[0, p:p + 1, :]               # (dk, hp dv)
+        # the decay: a row over the lanes (one scalar a head), or a COLUMN
+        # down the sublanes (ops/pallas_kda_update.py: a value a key
+        # channel, the state's rows each at their own rate)
+        s = s_ref[0, p] * (spread(a_ref, p) if channel      # (dk, hp dv)
+                           else a_ref[0, p:p + 1, :])
         u = b_ref[0, p:p + 1, :] * (
             v_ref[0, p:p + 1, :] - jnp.sum(s * kk, axis=0, keepdims=True))
         s = s + kk * u
@@ -133,6 +137,17 @@ def gdn_state_update(state, seats, q, k, v, g, beta, *,
 @functools.partial(jax.jit, static_argnames=("interpret",),
                    donate_argnames=("state",))
 def _gdn_state_update(state, seats, q, k, v, g, beta, *, interpret: bool):
+    return state_update_call(state, seats, q, k, v, g, beta,
+                             interpret=interpret, channel=False,
+                             name=KERNEL_NAME)
+
+
+def state_update_call(state, seats, q, k, v, g, beta, *, interpret: bool,
+                      channel: bool, name: str):
+    """The ``pallas_call`` of both forms of the gate, under the caller's
+    ``jax.jit`` and custom-call ``name``: ``g`` (B, H) the log of ONE
+    decay a head, or with ``channel`` (B, H, dk), a decay a key channel
+    (ops/pallas_kda_update.py)."""
     B, H, dk = k.shape
     dv = v.shape[-1]
     n_slab = state.shape[1]
@@ -155,12 +170,12 @@ def _gdn_state_update(state, seats, q, k, v, g, beta, *, interpret: bool):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B,),
-        in_specs=[col_spec, col_spec, row_spec, row_spec, row_spec,
-                  pool_spec],
+        in_specs=[col_spec, col_spec, row_spec,
+                  col_spec if channel else row_spec, row_spec, pool_spec],
         out_specs=[row_spec, pool_spec],
     )
     o, state = pl.pallas_call(
-        functools.partial(_kernel, hp=hp, dv=dv),
+        functools.partial(_kernel, hp=hp, dv=dv, channel=channel),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, n_slab, hp * dv), f32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
@@ -170,7 +185,7 @@ def _gdn_state_update(state, seats, q, k, v, g, beta, *, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name=KERNEL_NAME,
+        name=name,
     )(seats.astype(jnp.int32), cols(q), cols(k), rows(v),
-      lanes(jnp.exp(g)), lanes(beta), state)
+      (cols if channel else lanes)(jnp.exp(g)), lanes(beta), state)
     return o.reshape(B, H, dv), state

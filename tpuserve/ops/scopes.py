@@ -79,7 +79,16 @@ The table (scope -> where it opens -> which metric reads it):
                     routed ones (LATER_PARTS: the accepted
                     reader files it under mlp, which encloses
                     it)                                        moe.shared_device_share
-    ssm.in_proj     _ssm_project                              trunk.decode_proj_ms
+    ssm.gate        _lin_project, _lin_inputs: a Kimi-delta layer's decay
+                    gate, its projection (inside ssm.in_proj) and its
+                    activation (inside ssm.conv) (LATER_PARTS: the
+                    accepted reader files each under the part that
+                    encloses it)                              kda.proj_device_share (through
+                                                              the enclosing parts)
+    attn.gate       _attn_residual: a latent layer's sigmoid gate a head,
+                    its norm, projection and product (inside attn.out;
+                    LATER_PARTS)                              trunk.decode_proj_ms (through attn.out)
+    ssm.in_proj     _ssm_project, _lin_project                trunk.decode_proj_ms
     ssm.conv        ops/ssm.py causal_conv, next_tail; _ssm_inputs,
                     _lin_inputs; a prefill's read and write of the
                     memory (_keep_tails); under decode/ the memory's
@@ -133,6 +142,8 @@ HEAD = "head"
 SAMPLE = "sample"
 CARRY = "carry"
 MOE_SHARED = "moe.shared"
+SSM_GATE = "ssm.gate"
+ATTN_GATE = "attn.gate"
 # the parts ``_scope_trace.py`` files time under: its copy is the
 # benchmark's, and only a PR to the benchmark changes it
 PARTS = (EMBED, ATTN_QKV, ATTN_KV_WRITE, ATTN_KERNEL, ATTN_OUT, MLP,
@@ -140,4 +151,4 @@ PARTS = (EMBED, ATTN_QKV, ATTN_KV_WRITE, ATTN_KERNEL, ATTN_OUT, MLP,
          SSM_CONV, SSM_SCAN, SSM_OUT, HEAD, SAMPLE, CARRY)
 # parts opened since that copy was taken, each read by a reader of its
 # own; that copy files their time under the part that encloses them
-LATER_PARTS = (MOE_SHARED,)
+LATER_PARTS = (MOE_SHARED, SSM_GATE, ATTN_GATE)

@@ -350,6 +350,15 @@ class EngineStats:
     moe_held_hits: int = 0
     moe_buffer_rows: int = 0
     moe_held_pieces: int = 0
+    # behind a group-limited router (moe_n_group > 1) under a share: the
+    # rows one of whose surviving groups is held here, which is the rows a
+    # chip of the deployment is sent at all (over moe_routed_rows / k: the
+    # share of rows that reach this chip)
+    moe_group_rows: int = 0
+    # row-layers the channel-gated (Kimi-delta) state update served on
+    # decode: a decode dispatch's real tokens times the linear layers, from
+    # host integers
+    kda_state_row_layers: int = 0
     model_swaps: int = 0
     model_swaps_by_outcome: dict = dataclasses.field(default_factory=dict)
     swap_latencies: list = dataclasses.field(default_factory=list)
@@ -967,6 +976,7 @@ class Engine:
         self.flight.devprof = self.devprof
         self._step_kind = "idle"
         self._step_ridden = 0        # a mixed step's decode rows (its record)
+        self._step_kda = 0           # its Kimi-delta row-layers (its record)
         # terminal errors for QUEUED requests decided engine-side
         # (deadline expiry, queue-full class eviction): (rid, exc) pairs
         # the runner drains and routes to the waiting clients — the
@@ -1858,7 +1868,7 @@ class Engine:
             self.stats.step_padded_tokens if dispatched else 0,
             self.clock.monotonic() - t_cycle,
             ctx_tokens=self.stats.step_ctx_tokens if dispatched else 0,
-            ridden_tokens=self._step_ridden)
+            ridden_tokens=self._step_ridden, kda_row_layers=self._step_kda)
         if self._slo is not None:
             # estimator tick once per successful cycle (queue depth +
             # the EWMAs fed during scheduling) drives the brownout
@@ -1898,6 +1908,7 @@ class Engine:
         self._dispatch_rids = ()
         self._step_kind = "idle"
         self._step_ridden = 0
+        self._step_kda = 0
         PROF.bump_cycle()
         self.devprof.bump_cycle()
         # overload robustness, BEFORE scheduling: deadline-expired queued
@@ -2228,6 +2239,10 @@ class Engine:
             self.stats.prefill_kv_tokens_paged_total += actual * kv_by_page
         elif self.model_cfg.is_mla:
             self.stats.kv_latent_tokens_attended_total += ctx_tokens
+        if not prefill and self.model_cfg.lin_gate == "channel":
+            served = actual * len(self.model_cfg.state_layers)
+            self.stats.kda_state_row_layers += served
+            self._step_kda += served
         self.stats.step_actual_tokens = actual
         self.stats.step_padded_tokens = padded
         self.stats.step_ctx_tokens = ctx_tokens
@@ -2451,6 +2466,7 @@ class Engine:
                 self.stats.moe_held_hits += held[1]
                 self.stats.moe_buffer_rows += held[2]
                 self.stats.moe_held_pieces += held[3]
+                self.stats.moe_group_rows += sum(held[4:])
             # (a share moves its buffer's rows, a whole layer every row)
             plain = (held[2] if held else rows) * moves
             self.stats.moe_row_moves_plain += plain

@@ -74,10 +74,11 @@ SLI_KINDS = ("ttft", "itl", "e2e")
 # PVC into a bundle dump
 MAX_POSTMORTEMS = 32
 # a step record's routing counts, in the order note_moe() takes them: the
-# first three for every model with expert layers, all seven under a share
+# first three for every model with expert layers, seven under a share, the
+# eighth under a share behind a group-limited router
 MOE_FIELDS = ("moe_rows", "moe_expert_hits", "moe_moves_plain",
               "moe_held_rows", "moe_held_hits", "moe_buffer_rows",
-              "moe_held_pieces")
+              "moe_held_pieces", "moe_group_rows")
 
 
 class _Ring:
@@ -182,10 +183,11 @@ class FlightRecorder:
 
     def note_step(self, kind: str, rows: int, actual: int, padded: int,
                   dur_s: float, ctx_tokens: int = 0,
-                  ridden_tokens: int = 0) -> None:
+                  ridden_tokens: int = 0, kda_row_layers: int = 0) -> None:
         """One engine cycle's step record (``ridden_tokens``: the decode
         rows of a mixed step that also carried prompt tokens, of its
-        ``actual`` tokens).  Phase ms are deltas of the
+        ``actual`` tokens; ``kda_row_layers``: the row-layers the
+        channel-gated state update served in it).  Phase ms are deltas of the
         module hostprof profiler since the previous record, one key per
         span name (runtime/hostprof.py): a span still open here
         (engine.step, step.close) or opened by the runner after the step
@@ -206,7 +208,8 @@ class FlightRecorder:
             dev = self.devprof.step_delta()
         self._steps.append((self._clock.monotonic(), kind, rows, actual, padded,
                             round(dur_s * 1000, 4), phases or None, dev,
-                            self.seq, ctx_tokens, ridden_tokens))
+                            self.seq, ctx_tokens, ridden_tokens,
+                            kda_row_layers))
 
     def note_moe(self, seq: int, *counts: int) -> None:
         """The routing counts of step ``seq``'s dispatch (a model with
@@ -215,8 +218,9 @@ class FlightRecorder:
         the row moves around the kernel that went by the plain gather;
         where the model holds a share of its experts, four more (the
         rows that landed on held experts, the held expert-layers hit,
-        the rows of buffer moved and the pieces they moved in:
-        ``MOE_FIELDS``).
+        the rows of buffer moved and the pieces they moved in) and behind
+        a group-limited router a fifth, the rows one of whose surviving
+        groups is held here (``MOE_FIELDS``).
         They come back with the dispatch's tokens, a cycle or more after
         its step record was written, so they are kept beside the ring
         (as many as it holds) and joined in ``steps_snapshot``; a step
@@ -292,13 +296,15 @@ class FlightRecorder:
 
     def steps_snapshot(self, limit: int = 128) -> list[dict]:
         out = []
-        for t, kind, rows, actual, padded, ms, phases, dev, seq, ctx, rode \
-                in self._steps.snapshot()[-limit:]:
+        for (t, kind, rows, actual, padded, ms, phases, dev, seq, ctx, rode,
+             kda) in self._steps.snapshot()[-limit:]:
             rec = {"t": t, "seq": seq, "kind": kind, "rows": rows,
                    "actual_tokens": actual, "padded_tokens": padded,
                    "ctx_tokens": ctx, "ms": ms}
             if kind == "mixed":
                 rec["ridden_tokens"] = rode
+            if kda:
+                rec["kda_row_layers"] = kda
             if phases:
                 rec["phase_ms"] = phases
             if dev:

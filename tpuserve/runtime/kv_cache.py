@@ -66,8 +66,10 @@ def bytes_per_block(model_cfg: ModelConfig, cache_cfg: CacheConfig,
 
 def _ssm_layer(c: ModelConfig, num_seats: int) -> dict:
     """One layer of the recurrent-state pool, as shapes: Mamba-2 heads'
-    (Falcon-H1), or a linear-attention layer's matrix states in slabs of
-    heads whose lane axis is whole tiles (ops/pallas_gdn_update.py).
+    (Falcon-H1), or a linear-attention layer's matrix states (either form
+    of the gate: ``ModelConfig.lin_gate``) in slabs of heads whose lane
+    axis is whole tiles (ops/pallas_gdn_update.py; one head a slab where a
+    head's values are a lane tile, as Ling-3.0-flash's 128).
     Beside either, the short convolution's last ``W - 1`` inputs, each row
     of channels as whole 128-lane tiles down the sublanes
     (ops/pallas_conv_tail.py ``tail_slab``: a seat's memory is one
@@ -106,7 +108,8 @@ def ssm_state_bytes(model_cfg: ModelConfig, num_seats: int) -> int:
 def create_ssm_state(model_cfg: ModelConfig, num_seats: int) -> list[dict]:
     """Zero-initialised recurrent state, one entry a layer that holds one
     (``ModelConfig.state_layers``, in order: every layer of Falcon-H1, the
-    linear-attention layers of Olmo-Hybrid), each ``{"state": (seats + 1,
+    linear-attention layers of Olmo-Hybrid and of Ling-3.0-flash, whose
+    other layers hold LATENT pages in the cache beside it), each ``{"state": (seats + 1,
     H, P, N) float32 (a linear layer: :func:`_ssm_layer`), "conv": (seats
     + 1, W - 1, channels / 128, 128)}``: one slot a running sequence —
     NOT a page a token like the KV cache beside it — and a last one that

@@ -329,6 +329,18 @@ class ServerMetrics:
             "Rows of buffer the expert layer gathered and multiplied for "
             "the held rows (whole pieces: over tpuserve_moe_held_rows by "
             "the last piece's slack)")
+        self.moe_group_rows = counter(
+            "tpuserve_moe_group_rows",
+            "Behind a group-limited router, under a share: the rows one "
+            "of whose surviving expert groups is held by this process, "
+            "summed over the expert layers -- the rows a chip of the "
+            "deployment is sent at all (over tpuserve_moe_routed_rows / "
+            "experts per token: topk_group / n_group under even routing)")
+        self.kda_state_row_layers = counter(
+            "tpuserve_kda_state_row_layers",
+            "Row-layers the channel-gated (Kimi-delta) state update served "
+            "on decode: a decode dispatch's real tokens times the linear "
+            "layers, each one state read and written")
         self.moe_experts_held = gauge(
             "tpuserve_moe_experts_held",
             "Experts of each expert layer this process holds (0: all)")

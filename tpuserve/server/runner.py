@@ -1059,7 +1059,11 @@ class AsyncEngineRunner:
                                  ("moe_held_hits",
                                   self.metrics.moe_held_hits),
                                  ("moe_buffer_rows",
-                                  self.metrics.moe_buffer_rows)):
+                                  self.metrics.moe_buffer_rows),
+                                 ("moe_group_rows",
+                                  self.metrics.moe_group_rows),
+                                 ("kda_state_row_layers",
+                                  self.metrics.kda_state_row_layers)):
                 _advance_counter(
                     metric, sum(getattr(s, attr, 0) for s in stats_objs))
             by_expert = [s.moe_expert_rows for s in stats_objs
