@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from chip_v5e import (k_exaone_share, shapes_on)
+from chip_v5e import (k_exaone_share, ling_share, shapes_on)
 from chip_v5e import (  # noqa: F401  (fixtures, found by name)
     _no_persistent_cache, one_chip, topo)
 
@@ -107,6 +107,24 @@ def test_the_expert_layer_compiles_for_v5e_at_every_rung(tokens, one_chip,
                             S((E, H, I), bf16), S((E,), jnp.int32)).compile()
 
 
+def held_layer_shapes(S, cfg, shared: bool) -> dict:
+    """One expert layer's parameters under ``cfg``'s share, as shapes: the
+    router over every expert and its selection bias, the held experts'
+    stacked kernels, the shared expert where the model has one."""
+    H, I, E = cfg.hidden_size, cfg.expert_intermediate_size, cfg.num_experts
+    held, bf16 = cfg.moe_experts_held, jnp.bfloat16
+    p = {"router": {"kernel": S((H, E), bf16)},
+         "router_bias": {"bias": S((E,), jnp.float32)},
+         "experts": {"gate_proj": {"kernel": S((held, H, I), bf16)},
+                     "up_proj": {"kernel": S((held, H, I), bf16)},
+                     "down_proj": {"kernel": S((held, I, H), bf16)}}}
+    if shared:
+        p["shared"] = {"gate_proj": {"kernel": S((H, I), bf16)},
+                       "up_proj": {"kernel": S((H, I), bf16)},
+                       "down_proj": {"kernel": S((I, H), bf16)}}
+    return p
+
+
 # K-EXAONE-236B-A23B's share of the benchmark's cell: 16 of 128 experts of
 # width 2,048 on a hidden size of 6,144, 8 a token; the flat-token rungs
 # of its packed prefills (64-row ragged blocks, so multiples of 128 up to
@@ -133,21 +151,44 @@ def test_the_expert_layer_under_a_share_compiles_for_v5e_at_every_rung(
     S, _ = shapes_on(one_chip)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = k_exaone_share(num_layers=2)
-    H, I, E = cfg.hidden_size, cfg.expert_intermediate_size, cfg.num_experts
-    held, bf16 = cfg.moe_experts_held, jnp.bfloat16
-    p = {"router": {"kernel": S((H, E), bf16)},
-         "router_bias": {"bias": S((E,), jnp.float32)},
-         "experts": {"gate_proj": {"kernel": S((held, H, I), bf16)},
-                     "up_proj": {"kernel": S((held, H, I), bf16)},
-                     "down_proj": {"kernel": S((held, I, H), bf16)}},
-         "shared": {"gate_proj": {"kernel": S((H, I), bf16)},
-                    "up_proj": {"kernel": S((H, I), bf16)},
-                    "down_proj": {"kernel": S((I, H), bf16)}}}
+    H = cfg.hidden_size
     compiled = jax.jit(lambda x, p: transformer._moe_mlp(x, p, cfg)).lower(
-        S((tokens, H), bf16), p).compile()
+        S((tokens, H), jnp.bfloat16),
+        held_layer_shapes(S, cfg, shared=True)).compile()
     text = compiled.as_text()
     assert text.count("_moe_grouped_matmul") >= 3
     assert " while(" in text            # the pieces: a trip count from data
     every_pick = tokens * cfg.num_experts_per_tok * H * 2
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 1.25 * every_pick + (16 << 20), (temp, every_pick)
+
+
+@pytest.mark.parametrize("tokens", [128, 1024])
+def test_the_group_limited_layer_sorts_twice_on_v5e(tokens, one_chip,
+                                                    monkeypatch):
+    """Ling-3.0-flash-VL's expert layer under its share (a 512-wide router
+    in 8 groups of which 4 survive, routing group 0 held) at a decode
+    step's rows and a packed prefill's: the chip's compiler is handed TWO
+    sorts, the picks' ``top_k`` over ``(T, 512)`` and the held picks'
+    ``argsort``, as a layer with no groups is; the groups' top-2 and the
+    surviving groups are maxima and comparisons (they were two sorts more
+    and a scatter, 113 us of a 158 us route at 128 rows: PERF.md §6, PR
+    56)."""
+    from tpuserve.models import transformer
+
+    S, _ = shapes_on(one_chip)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ling_share(num_layers=2)
+    assert (cfg.num_experts, cfg.moe_n_group, cfg.moe_topk_group) == (
+        512, 8, 4)
+    text = jax.jit(lambda x, p: transformer._moe_mlp(x, p, cfg)).lower(
+        S((tokens, cfg.hidden_size), jnp.bfloat16),
+        held_layer_shapes(S, cfg, shared=False)).compile().as_text()
+    # (a packed prefill's add-back sorts its scatter's indices besides,
+    # under ``moe.combine``: not the route's)
+    sorts = re.findall(r"^\s*%\S+ = (\S+) \S+ sort\([^\n]*op_name=\"[^\"]*"
+                       r"moe\.route", text, re.M)
+    assert len(sorts) == 2, sorts
+    assert any(f"f32[{tokens},512]" in out for out in sorts), sorts
+    assert any(f"s32[{tokens * cfg.num_experts_per_tok}]" in out
+               for out in sorts), sorts

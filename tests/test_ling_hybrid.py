@@ -552,6 +552,40 @@ def test_served_greedy_tokens_are_the_references(cfg, params, multi_step,
     assert stats.kv_latent_tokens_attended_total > 0
 
 
+# what the engine served before the group-limited selection went sort-free
+# (recorded on the ``lax.top_k`` form): a position a word, its five expert
+# layers' two picks each a digit
+SERVED_PICKS = {
+    19: "5764542357 0176327613 6503672321 7631207575 2032207565 6756032356 "
+        "0130742121 6754645765 4665742167 1230457621 1054767621 6556746776 "
+        "5431757465 1254757676 0147763056 0113204674 0145757467 4664026756 "
+        "4657317502 6465756764 7675203212 0230656556 1064756774 0256323176",
+    7: "5431202310 6757207513 0231455445 0131655713 1031200276 0167302156 "
+       "0156471257 0357323102 0154216776 0156672376 2057677664 1031767657",
+}
+
+
+def test_the_served_picks_and_group_rows_are_what_they_were(cfg, params):
+    """``routed_experts`` of two fixed prompts, layer by layer, and the
+    count behind ``tpuserve_moe_group_rows_total`` for them: the selection
+    chooses what it chose by ``lax.top_k``."""
+    from tpuserve.runtime import SamplingParams
+    scfg, sparams = share_of(cfg, params, 0, 4)
+    engine = engine_for(FAMILY, sparams, scfg, multi_step=4)
+    prompts = prompts_of(*SERVED_PICKS, seed=7)
+    outs = engine.generate(prompts, SamplingParams(
+        max_tokens=6, temperature=0.0, ignore_eos=True, logprobs=1))
+    for prompt, out in zip(prompts, outs):
+        got = np.asarray(out.logprobs[0]["prompt_routed_experts"]
+                         + [e["routed_experts"] for e in out.logprobs[1:]])
+        assert got.shape == (len(prompt) + 5, 5, 2)
+        assert " ".join("".join(map(str, row.reshape(-1)))
+                        for row in got) == SERVED_PICKS[len(prompt)]
+    stats = engine.stats
+    assert (stats.moe_group_rows, stats.moe_held_rows,
+            stats.moe_routed_rows) == (134, 268, 560)
+
+
 def test_the_counters_reach_the_metrics_page(cfg, params):
     from tpuserve.server.metrics import ServerMetrics
     m = ServerMetrics(MODEL)

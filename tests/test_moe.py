@@ -206,6 +206,34 @@ def test_moe_config_rejects_interleaved_dense():
 # sparse dispatch (ops/pallas_moe_gmm.py) against the dense form
 # --------------------------------------------------------------------------
 
+def top_k_selection(choice, cfg):
+    """A router's picks by ``lax.top_k`` throughout, as the trunk chose
+    them before its group-limited selection went sort-free: the groups'
+    top-2 (or maximum), the surviving groups and a scatter, the top-k.
+    Kept here as the plain reference: ``(topi, gmask, group_rows)``."""
+    T, E = choice.shape
+    gmask = group_rows = None
+    if cfg.moe_n_group > 1:
+        G = cfg.moe_n_group
+        grouped = choice.reshape(T, G, E // G)
+        if cfg.moe_scoring == "sigmoid":
+            group_scores = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        else:
+            group_scores = jnp.max(grouped, axis=-1)
+        _, gidx = jax.lax.top_k(group_scores, cfg.moe_topk_group)
+        gmask = jnp.zeros_like(group_scores).at[
+            jnp.arange(T)[:, None], gidx].set(1.0)
+        choice = jnp.where(gmask[..., None] > 0, grouped, 0.0).reshape(T, E)
+        if cfg.moe_experts_held:
+            per = E // G
+            lo = cfg.moe_first_expert // per
+            hi = -(-(cfg.moe_first_expert + cfg.moe_experts_held) // per)
+            group_rows = jnp.sum(jnp.any(gmask[:, lo:hi] > 0, axis=-1),
+                                 dtype=jnp.int32)
+    _, topi = jax.lax.top_k(choice, cfg.num_experts_per_tok)
+    return topi, gmask, group_rows
+
+
 def dense_oracle(x, p, cfg):
     """The expert layer as it was before the sparse dispatch: every expert
     on every token (``"th,ehi->tei"``), the unpicked ones weighted zero.
@@ -218,19 +246,7 @@ def dense_oracle(x, p, cfg):
     choice = scores
     if "router_bias" in p:
         choice = choice + p["router_bias"]["bias"][None, :]
-    E = scores.shape[-1]
-    if cfg.moe_n_group > 1:
-        G = cfg.moe_n_group
-        grouped = choice.reshape(T, G, E // G)
-        if cfg.moe_scoring == "sigmoid":
-            group_scores = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
-        else:
-            group_scores = jnp.max(grouped, axis=-1)
-        _, gidx = jax.lax.top_k(group_scores, cfg.moe_topk_group)
-        gmask = jnp.zeros_like(group_scores).at[
-            jnp.arange(T)[:, None], gidx].set(1.0)
-        choice = jnp.where(gmask[..., None] > 0, grouped, 0.0).reshape(T, E)
-    _, topi = jax.lax.top_k(choice, cfg.num_experts_per_tok)
+    topi, _, _ = top_k_selection(choice, cfg)
     topv = jnp.take_along_axis(scores, topi, axis=-1)
     if cfg.norm_topk_prob:
         eps = 1e-20 if cfg.moe_scoring == "sigmoid" else 0.0
@@ -384,3 +400,86 @@ def test_the_grouped_product_is_the_row_by_row_product(m, k, n, tiles):
         np.asarray(grouped_matmul(lhs, q, jnp.asarray(sizes))),
         np.asarray(grouped_matmul_reference(lhs, q, jnp.asarray(sizes))),
         atol=2e-2, rtol=1e-5)
+
+
+# --------------------------------------------------------------------------
+# the group-limited router's selection (transformer._group_limited_select)
+# --------------------------------------------------------------------------
+
+ROUTERS = [("sigmoid", 8, 4), ("sigmoid", 2, 1), ("softmax", 8, 3)]
+
+
+def _group_limited(scoring, n_group, topk_group, experts=512):
+    """A configuration whose router limits its picks to ``topk_group`` of
+    ``n_group`` groups, the LAST group held here (a share that starts at no
+    zero, so the slice of the mask is a real one)."""
+    per = experts // n_group
+    return dataclasses.replace(
+        get_model_config("tiny-deepseek"), num_experts=experts,
+        num_experts_per_tok=8, moe_scoring=scoring, moe_n_group=n_group,
+        moe_topk_group=topk_group, moe_experts_held=per,
+        moe_first_expert=experts - per)
+
+
+def _selection_scores(draw, scoring, T, E, seed):
+    """``(T, E)`` float32 selection scores: ``continuous`` (a router's
+    scores under a selection bias), ``halves`` (quantised so that exact
+    ties stand inside and across groups) or ``equal``."""
+    if draw == "equal":
+        return jnp.full((T, E), 0.5, jnp.float32)
+    rng = np.random.default_rng(seed)
+    logits = rng.standard_normal((T, E)).astype(np.float32)
+    if draw == "halves":
+        return jnp.asarray(np.round(logits * 2.0) / 2.0)
+    scores = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+              else jax.nn.softmax(jnp.asarray(logits), axis=-1))
+    return scores + jnp.asarray(0.1 * rng.standard_normal(E), jnp.float32)
+
+
+@pytest.mark.parametrize("draw", ["continuous", "halves", "equal"])
+@pytest.mark.parametrize("T", [1, 128, 1536])
+@pytest.mark.parametrize("scoring,n_group,topk_group", ROUTERS)
+def test_the_group_limited_selection_is_the_top_k_form(scoring, n_group,
+                                                       topk_group, T, draw):
+    """The picks, the surviving groups and the count of rows sent here are
+    what ``lax.top_k`` throughout gives, exact ties included (the lower
+    index first, among groups and among experts)."""
+    c = _group_limited(scoring, n_group, topk_group)
+    choice = _selection_scores(draw, scoring, T, c.num_experts,
+                               seed=T + n_group)
+    want_i, want_mask, want_rows = top_k_selection(choice, c)
+    topi, gmask, group_rows = jax.jit(
+        lambda x: transformer._group_limited_select(x, c))(choice)
+    assert gmask.dtype == jnp.bool_ and gmask.shape == (T, n_group)
+    np.testing.assert_array_equal(np.asarray(gmask),
+                                  np.asarray(want_mask) > 0)
+    assert (np.asarray(gmask).sum(-1) == topk_group).all()
+    np.testing.assert_array_equal(np.asarray(topi), np.asarray(want_i))
+    assert topi.dtype == want_i.dtype
+    assert int(group_rows) == int(want_rows)
+    if draw == "equal":         # the lowest groups, their first experts
+        assert np.asarray(gmask)[:, :topk_group].all()
+        assert list(np.asarray(topi)[0]) == list(range(8))
+        assert int(group_rows) == 0
+    # without a share no rows are counted
+    assert transformer._group_limited_select(
+        choice, dataclasses.replace(c, moe_experts_held=0,
+                                    moe_first_expert=0))[2] is None
+
+
+def _primitives(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+def test_the_group_limited_selection_issues_one_top_k_and_no_sort():
+    """At a decode step's ``(128, 512)`` scores: no ``sort``, no
+    ``scatter``, and at most one ``top_k`` (the picks')."""
+    c = _group_limited("sigmoid", 8, 4)
+    names = list(_primitives(jax.make_jaxpr(
+        lambda x: transformer._group_limited_select(x, c))(
+            jnp.zeros((128, 512), jnp.float32)).jaxpr))
+    assert not [n for n in names if "sort" in n or "scatter" in n], names
+    assert names.count("top_k") <= 1

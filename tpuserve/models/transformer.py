@@ -289,32 +289,9 @@ def _moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
         E = scores.shape[-1]
         group_rows = None
         if cfg.moe_n_group > 1:
-            G = cfg.moe_n_group
-            grouped = choice.reshape(T, G, E // G)
-            # group score: V3 (sigmoid) sums the group's top-2 member
-            # scores; V2's group_limited_greedy (softmax) takes the single
-            # max (HF modeling_deepseek_v2 vs _v3 — using the wrong one
-            # silently routes full V2/V2.5 checkpoints to different expert
-            # groups)
-            if cfg.moe_scoring == "sigmoid":
-                group_scores = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
-            else:
-                group_scores = jnp.max(grouped, axis=-1)
-            _, gidx = jax.lax.top_k(group_scores, cfg.moe_topk_group)
-            gmask = jnp.zeros_like(group_scores).at[
-                jnp.arange(T)[:, None], gidx].set(1.0)         # (T, G)
-            # HF masks non-selected groups to 0.0, not -inf
-            choice = jnp.where(gmask[..., None] > 0, grouped,
-                               0.0).reshape(T, E)
-            if cfg.moe_experts_held:
-                # rows one of whose surviving groups lies (partly) here:
-                # the rows a chip that holds this share is sent at all
-                per = E // G
-                lo = cfg.moe_first_expert // per
-                hi = -(-(cfg.moe_first_expert + cfg.moe_experts_held) // per)
-                group_rows = jnp.sum(jnp.any(gmask[:, lo:hi] > 0, axis=-1),
-                                     dtype=jnp.int32)
-        _, topi = jax.lax.top_k(choice, k)                     # (T, k)
+            topi, _, group_rows = _group_limited_select(choice, cfg)
+        else:
+            _, topi = jax.lax.top_k(choice, k)                 # (T, k)
         topv = jnp.take_along_axis(scores, topi, axis=-1)      # unbiased
         if cfg.norm_topk_prob:
             # HF adds 1e-20 on the sigmoid path (sums are not 1 there)
@@ -382,6 +359,60 @@ def _moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig,
         # operation's name is part of its program's key in the compile
         # cache)
         return y.reshape(shape)
+
+
+def _surviving_groups(choice: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """The groups a group-limited router keeps for each row: ``choice``
+    (T, E) float32 selection scores -> (T, G) bool, ``moe_topk_group`` true
+    a row.  What ``lax.top_k`` over the group scores picks, ties included
+    (the lower index first), and no sort: 64 values are not ordered to
+    learn their two largest, nor 8 to learn which four are largest."""
+    T, E = choice.shape
+    G = cfg.moe_n_group
+    grouped = choice.reshape(T, G, E // G)
+    # group score: V3 (sigmoid) sums the group's top-2 member scores; V2's
+    # group_limited_greedy (softmax) takes the single max (HF
+    # modeling_deepseek_v2 vs _v3 — using the wrong one silently routes
+    # full V2/V2.5 checkpoints to different expert groups)
+    group_scores = jnp.max(grouped, axis=-1)                   # (T, G)
+    if cfg.moe_scoring == "sigmoid":
+        # the second largest: the maximum again where it stands twice,
+        # else the largest of what lies below it
+        top = grouped == group_scores[..., None]
+        below = jnp.max(jnp.where(top, -jnp.inf, grouped), axis=-1)
+        twice = jnp.sum(top, axis=-1, dtype=jnp.int32) > 1
+        group_scores = group_scores + jnp.where(twice, group_scores, below)
+    # a group's rank: the groups that score higher, or as high at a lower
+    # index
+    mine, other = group_scores[:, :, None], group_scores[:, None, :]
+    g = jnp.arange(G)
+    ahead = (other > mine) | ((other == mine) & (g[None, :] < g[:, None]))
+    return jnp.sum(ahead, axis=-1, dtype=jnp.int32) < cfg.moe_topk_group
+
+
+def _group_limited_select(choice: jnp.ndarray, cfg: ModelConfig):
+    """A group-limited router's picks (``cfg.moe_n_group > 1``; DeepSeek-V2
+    / -V3 style): ``choice`` (T, E) float32 selection scores -> ``(topi,
+    gmask, group_rows)``: the (T, k) picks among the surviving groups'
+    experts in ``lax.top_k``'s order, the (T, G) bool survivors, and under
+    a share (``cfg.moe_experts_held``) the int32 count of rows one of
+    whose surviving groups lies (partly) here, the rows a chip that holds
+    this share is sent at all; None without one."""
+    T, E = choice.shape
+    G = cfg.moe_n_group
+    gmask = _surviving_groups(choice, cfg)
+    # HF masks non-selected groups to 0.0, not -inf
+    choice = jnp.where(gmask[..., None], choice.reshape(T, G, E // G),
+                       0.0).reshape(T, E)
+    _, topi = jax.lax.top_k(choice, cfg.num_experts_per_tok)   # (T, k)
+    group_rows = None
+    if cfg.moe_experts_held:
+        per = E // G
+        lo = cfg.moe_first_expert // per
+        hi = -(-(cfg.moe_first_expert + cfg.moe_experts_held) // per)
+        group_rows = jnp.sum(jnp.any(gmask[:, lo:hi], axis=-1),
+                             dtype=jnp.int32)
+    return topi, gmask, group_rows
 
 
 def held_piece_rows(pairs: int, held: int, experts: int) -> int:
