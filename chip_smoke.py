@@ -103,20 +103,23 @@ def check(cond, what: str) -> None:
 
 class Meter:
     """Per-phase counts: wall seconds, persistent-compile-cache hits and
-    misses (JAX's own monitoring events), cache entries on disk, and the
+    misses (the program's own compile ledger,
+    ``tpuserve/utils/compile_cache.py``), cache entries on disk, and the
     device's peak bytes."""
 
     def __init__(self, cache_dir: str):
-        import jax
+        from tpuserve.utils import compile_cache
         self.cache_dir = cache_dir
-        self.hits = self.misses = 0
-        jax.monitoring.register_event_listener(self._on_event)
+        self._ledger = compile_cache.LEDGER
+        self._ledger.listen()
 
-    def _on_event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
+    @property
+    def hits(self) -> int:
+        return self._ledger.totals()["hits"]
+
+    @property
+    def misses(self) -> int:
+        return self._ledger.totals()["misses"]
 
     @contextlib.contextmanager
     def phase(self, name: str, ladder: "Ladder | None" = None):

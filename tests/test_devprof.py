@@ -9,6 +9,7 @@ reconciles against is itself cross-checked every cycle."""
 
 import json
 import os
+import time
 import urllib.error
 import urllib.request
 
@@ -120,6 +121,89 @@ def test_ladder_registry_correctness(server):
     assert kinds <= {"prefill", "prefill_chunk", "decode", "decode_multi",
                      "verify", "verify_sampled", "draft", "mixed", "sample"}
     assert all(r["compile_ms"] > 0 for r in rows)
+    # what each first dispatch was (PR 57): the compile ledger's stages
+    # inside its bracket, zeros and "none" where no ledger listens
+    for r in rows:
+        assert r["cache"] in ("hit", "miss", "none")
+        assert 0 <= r["trace_ms"] + r["lower_ms"] + r["backend_ms"] \
+            <= r["compile_ms"] + 1.0
+    assert snap["cache_hits"] + snap["cache_misses"] <= snap["compiles"]
+    assert set(snap["unbracketed"]) == {
+        "requests", "cache_hits", "cache_misses", "trace_ms", "lower_ms",
+        "backend_ms"}
+
+
+TRACE = "/jax/core/compile/jaxpr_trace_duration"
+LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND = "/jax/core/compile/backend_compile_duration"
+ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+ANSWERS = {"hit": "/jax/compilation_cache/cache_hits",
+           "miss": "/jax/compilation_cache/cache_misses"}
+
+
+def _ready_a_program(led, answer, backend_s=0.25, small_jits=0):
+    """JAX's events for one program, as jax 0.9 sends them: a trunk's
+    trace with a layer body's inside it, the lowering, the backend's part
+    with the cache's answer.  ``small_jits``: the ``jnp`` functions a
+    trunk's trace goes through, each a jit that reports a trace of its
+    own (31,709 in the Ling cell's start, PERF.md §6)."""
+    led._on_open(TRACE, 0.0, fun_name="trunk")
+    led._on_open(TRACE, 0.0, fun_name="layer")
+    for _ in range(small_jits):                        # jnp's own jits
+        led._on_open(TRACE, 0.0, fun_name="add")
+        led._on_duration(TRACE, 0.0, fun_name="add")
+    led._on_duration(TRACE, 0.25, fun_name="layer")
+    led._on_duration(TRACE, 1.0, fun_name="trunk")     # 0.75 its own
+    led._on_open(LOWER, 0.0, fun_name="trunk")
+    led._on_duration(LOWER, 0.5, fun_name="trunk")
+    led._on_open(BACKEND, 0.0, fun_name="trunk")
+    if answer != "none":
+        led._on_event(ASKED)
+        led._on_event(ANSWERS[answer])
+    if answer == "hit":
+        led._on_duration("/jax/compilation_cache/cache_retrieval_time_sec",
+                         0.125)
+    led._on_duration(BACKEND, backend_s, fun_name="trunk")
+
+
+@pytest.mark.parametrize("answer", ["hit", "miss", "none"])
+def test_a_first_dispatch_takes_the_ledgers_events_of_its_bracket(
+        monkeypatch, answer):
+    """The ladder row of a first dispatch holds the ledger's events that
+    fell inside its bracket, a nested trace counted once; what came
+    before any bracket stays ``unbracketed``; a later dispatch of the same
+    executable never asks the ledger."""
+    from tpuserve.runtime import devprof as devprof_mod
+    from tpuserve.utils.compile_cache import CompileLedger
+    led = CompileLedger()
+    monkeypatch.setattr(devprof_mod, "LEDGER", led)
+    _ready_a_program(led, "miss", backend_s=2.0)       # an initialiser
+    dp = devprof_mod.DeviceProfiler()
+    with dp.dispatch("decode", ((4,),)):
+        # (an event is stamped where it ENDS, and a real one lasts longer
+        # than the bracket's own close)
+        time.sleep(0.002)
+        _ready_a_program(led, answer, small_jits=2000)
+    for _ in range(3):
+        with dp.dispatch("decode", ((4,),)):
+            pass
+    assert led.lookups == 1
+    snap = dp.ladder_snapshot()
+    (row,) = snap["executables"]
+    assert (row["trace_ms"], row["lower_ms"], row["backend_ms"],
+            row["cache"], row["hits"]) == (1000.0, 500.0, 250.0, answer, 4)
+    assert (snap["cache_hits"], snap["cache_misses"]) == (
+        int(answer == "hit"), int(answer == "miss"))
+    assert snap["unbracketed"] == {
+        "requests": 1, "cache_hits": 0, "cache_misses": 1,
+        "trace_ms": 1000.0, "lower_ms": 500.0, "backend_ms": 2000.0}
+    totals = led.totals()
+    # the bracket's program is ONE kept record a stage, however many
+    # traces closed inside its trunk's: none fell off the kept records
+    assert (totals["traces"], totals["lowers"], totals["requests"]) \
+        == (2004, 2, 2)
+    assert totals["trace_s"] == 2.0 and totals["backend_s"] == 2.25
+    assert totals["cache_read_s"] == (0.125 if answer == "hit" else 0)
 
 
 def test_debug_engine_surfaces_compile_cache_stats(server):
@@ -139,6 +223,9 @@ def test_debug_engine_surfaces_compile_cache_stats(server):
     # prior tests re-served warm shapes: hits outnumber compiles
     assert lad["hits"] > 0
     assert lad["compile_ms"] > 0
+    # ``misses`` are first dispatches; these say how many were compiles
+    assert 0 <= lad["cache_hits"] + lad["cache_misses"] <= lad["misses"]
+    assert snap["startup"]["cold_start_s"] == snap["cold_start_s"] > 0
 
 
 # ---- HBM watermark reconciliation --------------------------------------

@@ -266,8 +266,13 @@ def test_debug_endpoints_always_answer(idle_server, endpoint, status):
         assert "enabled" not in body and "enabled" not in body["devprof"]
         assert body["requests"] == []
         assert {"steps", "sli", "control", "devprof",
-                "compile_caches"} <= set(body)
+                "compile_caches", "startup"} <= set(body)
         assert "tracked" not in body["compile_caches"]["ladder"]
+        # no token served yet: the start-up block is whole all the same
+        assert body["startup"]["cold_start_s"] is None
+        assert {"trace_s", "lower_s", "backend_s", "requests", "hits",
+                "misses"} <= set(body["startup"]["compile"])
+        assert body["devprof"]["ladder"]["unbracketed"]["requests"] >= 0
     elif endpoint == "/debug/engine/dump":
         assert body["engine"]["model"] == "tiny-qwen3"
         assert body["requests"] == {} and "devprof" in body

@@ -210,6 +210,9 @@ DEFAULT_CONFIG: dict = {
             # "devprof" section + compile-cache stats are operator/jq
             # surface; the autoscaler reads control scalars, not these
             "devprof", "compile_caches",
+            # what a start is made of (hostprof's start-up spans, the
+            # compile ledger at the first token): operator/jq surface
+            "startup", "phases", "compile",
             # model pool (tpuserve/modelpool): the /debug/engine
             # "modelpool" block is operator/jq surface; the gateway
             # consumes the /healthz catalog ("models"/"model_current"),

@@ -19,11 +19,20 @@ the capability/cost signals heterogeneous routing wants (arxiv
   pipelined design successfully hid everywhere else, split per sync
   kind.  Dispatch brackets (``dispatch(kind, key)``) time the ASYNC
   enqueue, i.e. pure host trace/dispatch cost — except on an
-  executable's FIRST call, where the blocking XLA compile lands in the
-  same bracket and is recorded as that (kind, bucket)'s compile wall.
+  executable's FIRST call, where readying the program (tracing,
+  lowering, and the backend's compile OR the persistent cache's read in
+  its place) blocks inside the same bracket and is recorded as that
+  (kind, bucket)'s ``compile_ms``.
 - **executable-ladder registry**: every (dispatch kind, bucket key)
-  pair the engine ever dispatched — compile wall ms, hit count, an
-  activation-bytes estimate — so compile storms and ladder bloat are a
+  pair the engine ever dispatched — first-dispatch wall ms, hit count,
+  and what that first dispatch WAS, from the process's compile ledger
+  (utils/compile_cache.py): ``trace_ms`` / ``lower_ms`` / ``backend_ms``
+  and ``cache`` (``"miss"``: XLA compiled it; ``"hit"``: read back from
+  the persistent cache; ``"none"``: the cache was not asked, or no ledger
+  listens).  **A first dispatch is a compile only where ``cache ==
+  "miss"``**: ``compiles`` and ``compile_ms`` keep their names and count
+  every first dispatch.  What the ledger saw outside every bracket is the
+  one row ``unbracketed``.  So compile storms and ladder bloat are a
   table on /debug/engine, not an inference from step-time spikes.
 - **HBM watermark accounting**: the engine reconciles its block-manager
   KV reservation with loaded weight bytes and the backend's
@@ -57,11 +66,24 @@ from functools import partial
 from typing import Optional
 
 from tpuserve.runtime.hostprof import PROF, Span
+from tpuserve.utils.compile_cache import LEDGER
 
 #: bound the ladder table in snapshots/bundles: a pathological bucket
 #: explosion must not turn /debug/engine into a megabyte payload (the
 #: registry itself is unbounded — seeing the overflow COUNT is the point)
 MAX_LADDER_SNAPSHOT = 128
+
+
+def _stage_ms(led: dict) -> dict:
+    """The compile ledger's three stages over a bracket, as a row's ms."""
+    return {"trace_ms": round(led["trace_s"] * 1000, 3),
+            "lower_ms": round(led["lower_s"] * 1000, 3),
+            "backend_ms": round(led["backend_s"] * 1000, 3)}
+
+
+def _cache_word(led: dict) -> str:
+    """What the persistent cache answered inside a first dispatch."""
+    return "miss" if led["misses"] else "hit" if led["hits"] else "none"
 
 
 class DeviceProfiler:
@@ -76,7 +98,8 @@ class DeviceProfiler:
         # kind — the measurable device time of the pipelined design
         self.sync_s: dict[str, float] = defaultdict(float)
         self.sync_counts: dict[str, int] = defaultdict(int)
-        # (kind, bucket key) -> [compile_ms, hits]
+        # (kind, bucket key) -> [compile_ms, hits, the compile ledger's
+        # events inside the first dispatch's bracket]
         self.ladder: dict[tuple, list] = {}
         self.compiles = 0
         self.compile_s = 0.0
@@ -111,10 +134,12 @@ class DeviceProfiler:
     def _note_dispatch(self, lk: tuple, dt: float) -> None:
         ent = self.ladder.get(lk)
         if ent is None:
-            # first dispatch of this (kind, bucket): the blocking XLA
-            # compile ran inside this bracket — that wall IS the
-            # compile cost
-            self.ladder[lk] = [round(dt * 1000, 3), 1]
+            # first dispatch of this (kind, bucket): readying the program
+            # blocked inside this bracket — that wall IS its cost, and
+            # the ledger's events of those dt seconds say what it was
+            # (the only call of the ledger a dispatch ever makes)
+            first = LEDGER.within(dt)
+            self.ladder[lk] = [round(dt * 1000, 3), 1, first]
             self.compiles += 1
             self.compile_s += dt
         else:
@@ -197,6 +222,13 @@ class DeviceProfiler:
         self._last_compiles = self.compiles
         return dev or None
 
+    def cache_answers(self) -> dict:
+        """Of the ladder's first dispatches, how many the persistent cache
+        answered and how many XLA compiled (the rest asked nobody)."""
+        words = [_cache_word(ent[2]) for ent in list(self.ladder.values())]
+        return {"cache_hits": words.count("hit"),
+                "cache_misses": words.count("miss")}
+
     def ladder_snapshot(self) -> dict:
         """The executable ladder as a bounded table: one row per
         (kind, bucket), hottest first, plus the registry totals (which
@@ -204,19 +236,28 @@ class DeviceProfiler:
         items = sorted(self.ladder.items(),
                        key=lambda kv: kv[1][1], reverse=True)
         rows = [{"kind": kind, "bucket": repr(key),
-                 "compile_ms": ent[0], "hits": ent[1]}
+                 "compile_ms": ent[0], "hits": ent[1],
+                 **_stage_ms(ent[2]), "cache": _cache_word(ent[2])}
                 for (kind, key), ent in items[:MAX_LADDER_SNAPSHOT]]
+        loose = LEDGER.unbracketed()
         return {
             "retained": len(self.ladder),
             "compiles": self.compiles,
             "compile_ms": round(self.compile_s * 1000, 2),
+            **self.cache_answers(),
             "truncated": max(0, len(self.ladder) - MAX_LADDER_SNAPSHOT),
             "executables": rows,
+            # the process's programs no dispatch bracket saw (samplers,
+            # token selects, eager jnp, the weights' initialisers)
+            "unbracketed": {"requests": loose["requests"],
+                            "cache_hits": loose["hits"],
+                            "cache_misses": loose["misses"],
+                            **_stage_ms(loose)},
         }
 
     def snapshot(self) -> dict:
         """Machine-readable breakdown (/debug/engine, flight bundles):
-        per-kind device/dispatch ms totals and ms-per-cycle, ladder
+        per-kind device/dispatch ms totals, device ms per cycle, ladder
         summary, HBM watermark, recorded captures."""
         cycles = max(self.cycles, 1)
         device = {k: {"total_ms": round(v * 1000, 2),
@@ -226,11 +267,9 @@ class DeviceProfiler:
                         "calls": self.dispatch_counts[k]}
                     for k, v in sorted(self.dispatch_s.items())}
         dev_total = sum(self.sync_s.values())
-        disp_total = sum(self.dispatch_s.values())
         return {
             "cycles": self.cycles,
             "device_ms_per_cycle": round(1000 * dev_total / cycles, 4),
-            "dispatch_ms_per_cycle": round(1000 * disp_total / cycles, 4),
             "device": device,
             "dispatch": dispatch,
             "ladder": self.ladder_snapshot(),

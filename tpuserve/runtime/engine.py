@@ -35,7 +35,7 @@ from tpuserve.models.weights import load_or_init, param_dtype
 from tpuserve.ops import sampling as sampling_ops
 from tpuserve.ops.attention import PAD_SLOT, kv_stream_by_page
 from tpuserve.runtime.block_manager import BlockManager, create_block_manager
-from tpuserve.runtime.hostprof import PROF
+from tpuserve.runtime.hostprof import PROF, STARTUP
 from tpuserve.runtime.kv_cache import CacheConfig, create_kv_cache
 from tpuserve.runtime.request import (
     FinishReason, Request, RequestOutput, RequestState, SamplingParams, check_stop)
@@ -535,6 +535,17 @@ def _host_shapes_token(r: Request, slack: int) -> bool:
                 len(r.output_token_ids), slack=slack)))
 
 
+def _warm_each(family: str, rnd: int, buckets):
+    """A warm-up loop's buckets, each handed out under a span
+    ``startup.warmup.<family>`` that stays open for the loop's body (the
+    ``with`` closes when the loop asks for the next one): which family and
+    which rung the warm-up's seconds went to (runtime/hostprof.py)."""
+    for bucket in buckets:
+        with STARTUP.phase("startup.warmup." + family, round=rnd,
+                           bucket=str(bucket)):
+            yield bucket
+
+
 @jax.jit
 def _select_tokens(toks, gather, host, use_host):
     """Next-step input tokens without a host round-trip: previous step's
@@ -618,22 +629,26 @@ class Engine:
                     "(lora_dir)")
         self.tokenizer = load_tokenizer(config.checkpoint_dir or config.model,
                                         vocab_size=self.model_cfg.vocab_size)
-        if params is None:
-            # random init is born tp-sharded (a model that needs the mesh
-            # to fit cannot pass through one device); the pp engine
-            # restacks whole layers itself below
-            params = load_or_init(self.model_cfg, config.checkpoint_dir,
-                                  config.seed,
-                                  mesh=mesh if self._pp == 1 else None)
-        if config.lora_dir:
-            # before quantization/sharding: the merge targets bf16 kernels
-            from tpuserve.models.weights import apply_lora
-            params = apply_lora(params, self.model_cfg, config.lora_dir)
-            logger.info("merged LoRA adapter from %s", config.lora_dir)
-        if config.quantization == "int8":
-            from tpuserve.models.weights import quantize_params_int8
-            if "scale" not in params["embed"]:    # not already quantized
-                params = quantize_params_int8(params)
+        with STARTUP.phase("startup.weights"):
+            # (no sync closes the span: initialisers are enqueued, and
+            # what the device still owes surfaces under the warm-up)
+            if params is None:
+                # random init is born tp-sharded (a model that needs the
+                # mesh to fit cannot pass through one device); the pp
+                # engine restacks whole layers itself below
+                params = load_or_init(self.model_cfg, config.checkpoint_dir,
+                                      config.seed,
+                                      mesh=mesh if self._pp == 1 else None)
+            if config.lora_dir:
+                # before quantization/sharding: the merge targets bf16
+                # kernels
+                from tpuserve.models.weights import apply_lora
+                params = apply_lora(params, self.model_cfg, config.lora_dir)
+                logger.info("merged LoRA adapter from %s", config.lora_dir)
+            if config.quantization == "int8":
+                from tpuserve.models.weights import quantize_params_int8
+                if "scale" not in params["embed"]:  # not already quantized
+                    params = quantize_params_int8(params)
         self._lora_names: Optional[list] = None
         if config.lora_modules:
             # after quantization on purpose: the stacked deltas apply
@@ -660,6 +675,10 @@ class Engine:
             logger.info("loaded %d LoRA adapter(s): %s",
                         len(self._lora_names), self._lora_names)
         self.params = params
+        # the paged cache, the state pool, the block manager and what
+        # stands on them, up to devprof.set_hbm: closed by hand at this
+        # method's end, 470 lines on
+        pools = STARTUP.phase("startup.pools").__enter__()
         if self.cache_cfg.num_blocks == 0:
             # vLLM gpu_memory_utilization analog: size the KV cache to
             # what the HBM budget leaves after the (possibly quantized)
@@ -1125,6 +1144,7 @@ class Engine:
             (self.cache_cfg.num_blocks - 1) * self.cache_cfg.block_size)
         # seed the devprof HBM watermark once weights + cache exist
         self._note_hbm_budget()
+        pools.__exit__(None, None, None)
 
     def swap_model(self, config: EngineConfig, *, params=None,
                    source_tier: str = "cold"):
@@ -4078,8 +4098,11 @@ class Engine:
         /debug/engine ("compile_caches") so compile churn is visible
         without log archaeology.  FSM misses count full determinizing
         walks AND disk-cache loads (disk_hits is the subset the
-        fleet-wide PVC cache absorbed); ladder misses are first-dispatch
-        compiles as attributed by devprof."""
+        fleet-wide PVC cache absorbed); ladder ``misses`` are an
+        executable's FIRST dispatches as devprof brackets them, each a
+        compile only where the persistent cache missed: ``cache_misses``
+        of them XLA compiled, ``cache_hits`` were read back from the
+        cache (the rest did not ask it; runtime/devprof.py)."""
         dp = self.devprof
         return {
             "fsm": {"hits": self._fsm_stats["hits"],
@@ -4090,7 +4113,8 @@ class Engine:
                                    - dp.compiles),
                        "misses": dp.compiles,
                        "size": len(dp.ladder),
-                       "compile_ms": round(dp.compile_s * 1000.0, 3)},
+                       "compile_ms": round(dp.compile_s * 1000.0, 3),
+                       **dp.cache_answers()},
         }
 
     def _fsm_device_tables(self, fsm):
@@ -4799,7 +4823,7 @@ class Engine:
         same ``_exec_*`` hooks as serving, and an armed chaos spec firing
         during startup compiles would fail the pod before it ever served —
         not the failure mode the injector exists to test."""
-        with self.faults.suspended():
+        with self.faults.suspended(), STARTUP.phase("startup.warmup"):
             try:
                 return self._warmup(*args, **kwargs)
             finally:
@@ -4923,7 +4947,7 @@ class Engine:
         # the followers (already in follower_loop), so startup compiles in
         # lockstep instead of deadlocking the SPMD program (round-1 bug).
         for _round in range(2):
-            for bucket in prefill_buckets:
+            for bucket in _warm_each("prefill", _round, prefill_buckets):
                 B, L = bucket if isinstance(bucket, tuple) else (1, bucket)
                 tokens = jnp.zeros((B, L), jnp.int32)
                 lens = jnp.ones((B,), jnp.int32)
@@ -4932,7 +4956,7 @@ class Engine:
                 logits, self.kv_cache = self._exec_prefill(tokens, lens,
                                                            slots, **wkw)
                 self._warm_sampling(logits, sample_modes)
-            for B in decode_buckets:
+            for B in _warm_each("decode", _round, decode_buckets):
                 tokens = jnp.zeros((B,), jnp.int32)
                 positions = jnp.zeros((B,), jnp.int32)
                 slots = jnp.full((B,), PAD_SLOT, jnp.int32)
@@ -5068,7 +5092,7 @@ class Engine:
                                 jnp.ones((B,), jnp.float32),
                                 jnp.zeros((B,), jnp.float32))
                         self._warm_tails.append(acc)
-            for C in sorted(chunk_set):
+            for C in _warm_each("chunk", _round, sorted(chunk_set)):
                 tokens = jnp.zeros((1, C), jnp.int32)
                 slots = jnp.full((1, C), PAD_SLOT, jnp.int32)
                 bt = jnp.zeros((1, self.cache_cfg.max_blocks_per_seq),
@@ -5078,7 +5102,7 @@ class Engine:
                     tokens, jnp.zeros((1,), jnp.int32),
                     jnp.ones((1,), jnp.int32), slots, bt, **ckw)
                 self._warm_sampling(logits, sample_modes)
-            for Tm, Bm, kind in ragged_warm:
+            for Tm, Bm, kind in _warm_each("ragged", _round, ragged_warm):
                 # ragged trunk (mixed steps, packed prefills): one
                 # executable per flat-token bucket (the whole point — no
                 # (batch x length) grid); left cold, the first

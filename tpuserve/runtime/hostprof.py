@@ -48,6 +48,40 @@ The span tree (where each opens -> what reads it):
 (every ``sync`` feeds it), kept because step records report it under
 that name (``phase_ms.flush``).
 
+START-UP has the same primitive and an accumulator of its own
+(``STARTUP``, so the engine loop's per-cycle diffs never see it): what a
+pod restart or a scale-up spends before its first token, by stage.  A
+capture taken while the process starts (``jax.profiler.start_trace``
+around ``build_server``) shows these on the host plane beside the device's
+first programs; their seconds are ``/debug/engine`` ``startup.phases``,
+beside the compile ledger's totals at the first served token
+(utils/compile_cache.py) and ``cold_start_s``.  A slow scale-up is read
+from ``/metrics`` first: ``tpuserve_compile_cache_misses_total`` over hits
++ misses says whether the programs were compiled or read, then the stage.
+
+    startup.build     server/openai_api.py build_server, argv parsed to
+                      the server object: everything below but the warm-up
+                                        tpuserve_startup_build_seconds, setup.build_s
+      startup.backend   the first touch of the backend there: the TPU
+                        runtime's start (~0 where the caller touched it
+                        first, as the benchmark does)
+      startup.weights   Engine.__init__: the checkpoint's load or the
+                        initialisers' ENQUEUE, adapters, quantisation.  No
+                        sync closes it: what the device still owes
+                        surfaces under the first span that waits for it
+                        (the warm-up's closing block_until_ready)
+      startup.pools     Engine.__init__: the paged cache, the state pool,
+                        the block manager, scheduler and recorder, up to
+                        devprof.set_hbm
+    startup.warmup    Engine.warmup (both rounds); a second call adds
+                                        tpuserve_startup_warmup_seconds, setup.warmup_s
+      startup.warmup.prefill / .decode / .chunk / .ragged
+                        one a bucket of each of _warmup's four loops, args
+                        round and bucket; decode holds its decode_multi
+                        variants and the chained-token selects.  What is
+                        left of startup.warmup is the KV tier's gathers,
+                        embed buckets and the closing wait for the device
+
 Cost: a span is one ``TraceAnnotation`` and two ``perf_counter`` calls,
 always: there is no off state, and the cost was measured on the chip
 (PERF.md §6, PR 24).  The profiler is engine-loop single-threaded like
@@ -120,3 +154,7 @@ class HostPhaseProfiler:
 # module singleton: the engine loop is single-threaded, and profile runs
 # build one engine per process
 PROF = HostPhaseProfiler()
+# the start-up spans' seconds and counts (``STARTUP.phase(name)``), by the
+# process like the compile ledger: engines built or warmed again add to
+# the same keys
+STARTUP = HostPhaseProfiler()

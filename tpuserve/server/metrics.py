@@ -576,6 +576,56 @@ class ServerMetrics:
             "has ever dispatched and jit retains — ladder bloat is HBM "
             "spent on compiled code, bounded by design by the "
             "power-of-2 bucketing")
+        # The process's compile ledger (utils/compile_cache.py: JAX's own
+        # monitoring events, process-wide) and the start-up spans
+        # (runtime/hostprof.py STARTUP).  One sample each: what a pod
+        # restart or a scale-up spent before its first token, and in
+        # steady state which stage a compile stall was.
+        self.jit_trace_seconds = counter(
+            "tpuserve_jit_trace_seconds",
+            "Seconds this process spent tracing Python to jaxprs (self "
+            "time: a layer body traced inside a trunk counts once) — "
+            "paid again at every start, whatever the compile cache holds")
+        self.jit_lower_seconds = counter(
+            "tpuserve_jit_lower_seconds",
+            "Seconds this process spent lowering jaxprs to MLIR modules "
+            "— paid again at every start, like tracing")
+        self.backend_compile_seconds = counter(
+            "tpuserve_backend_compile_seconds",
+            "Seconds inside the backend's part of readying programs: "
+            "XLA compiles, or the persistent cache's reads in their "
+            "place (tpuserve_compile_cache_read_seconds_total is that "
+            "part)")
+        self.compile_cache_read_seconds = counter(
+            "tpuserve_compile_cache_read_seconds",
+            "Seconds of tpuserve_backend_compile_seconds_total that were "
+            "reads of the persistent compile cache (file read, "
+            "decompress, deserialize, load)")
+        self.compile_requests = counter(
+            "tpuserve_compile_requests",
+            "Programs this process asked its backend for, compiled or "
+            "read back — rising in steady state is a stall in the engine "
+            "loop each time: a shape that was not warmed")
+        self.compile_cache_hits = counter(
+            "tpuserve_compile_cache_hits",
+            "Compile requests the persistent cache answered; misses / "
+            "(hits + misses) near 0 is a warm start, near 1 a first run "
+            "— the first thing to read on a slow scale-up")
+        self.compile_cache_misses = counter(
+            "tpuserve_compile_cache_misses",
+            "Compile requests XLA compiled and wrote to the persistent "
+            "cache (a compile the cache declines to keep counts in "
+            "neither: requests - hits - misses is compiled at every "
+            "start)")
+        self.startup_build_seconds = gauge(
+            "tpuserve_startup_build_seconds",
+            "Seconds under the startup.build span: flags to the server "
+            "object — backend start, weights, cache pools; no warm-up")
+        self.startup_warmup_seconds = gauge(
+            "tpuserve_startup_warmup_seconds",
+            "Seconds under the startup.warmup span (Engine.warmup, every "
+            "call): readying and running each executable of the ladder "
+            "once; /debug/engine startup.phases splits it by family")
         self.profile_captures = counter(
             "tpuserve_profile_captures",
             "jax.profiler traces captured on demand (POST "

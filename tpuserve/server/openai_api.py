@@ -23,12 +23,13 @@ from typing import Optional
 
 from tpuserve.models.tokenizer import default_chat_template
 from tpuserve.server.tool_calls import ToolContext, normalize_messages
+from tpuserve.runtime.hostprof import STARTUP
 from tpuserve.runtime.request import SamplingParams
 from tpuserve.runtime.slo import SLO_CLASSES, ShedError
 from tpuserve.server.metrics import ServerMetrics
 from tpuserve.server.runner import AsyncEngineRunner
 from tpuserve.server.tenants import TenantRegistry, estimate_cost
-from tpuserve.utils import env_flag
+from tpuserve.utils import compile_cache, env_flag
 
 logger = logging.getLogger("tpuserve.server")
 
@@ -756,6 +757,15 @@ class _Handler(BaseHTTPRequestHandler):
         # tpuserve_cold_start_seconds
         out["cold_start_s"] = getattr(self.ctx.runner, "cold_start_s",
                                       None)
+        # what that number is made of (runtime/hostprof.py's start-up
+        # spans; the process's compile ledger as it stood at the first
+        # served token, as it stands now until then)
+        out["startup"] = {
+            "cold_start_s": out["cold_start_s"],
+            "phases": {k: round(v, 6)
+                       for k, v in sorted(STARTUP.seconds.items())},
+            "compile": getattr(self.ctx.runner, "startup_compile", None)
+            or compile_cache.LEDGER.totals()}
         # in-process SLO burn-rate state (tpuserve/obs): the loop-thread-
         # published snapshot — firing alerts + per-objective burn rates
         # as plain scalars, aggregated fleet-wide by /gateway/slo
@@ -1918,10 +1928,6 @@ def build_server(argv=None):
     tpuserve.server`` would."""
     import argparse
 
-    from tpuserve.runtime.engine import Engine, EngineConfig
-    from tpuserve.runtime.kv_cache import CacheConfig
-    from tpuserve.runtime.scheduler import SchedulerConfig
-
     ap = argparse.ArgumentParser("tpuserve.server")
     ap.add_argument("--model", default="Qwen/Qwen3-0.6B")
     ap.add_argument("--checkpoint-dir", default=None)
@@ -2119,11 +2125,28 @@ def build_server(argv=None):
                     help="graceful-drain budget on SIGTERM, seconds; keep "
                          "below the pod's terminationGracePeriodSeconds")
     args = ap.parse_args(argv)
+    with STARTUP.phase("startup.build"):
+        return _server_from_args(ap, args), args
+
+
+def _server_from_args(ap, args):
+    """``build_server``'s second half, under its ``startup.build`` span:
+    the parsed flags to the server object (None on a multi-host
+    follower)."""
+    import jax
+
+    from tpuserve.runtime.engine import Engine, EngineConfig
+    from tpuserve.runtime.kv_cache import CacheConfig
+    from tpuserve.runtime.scheduler import SchedulerConfig
 
     logging.basicConfig(level=logging.INFO)
     if args.multihost:
         from tpuserve.parallel.mesh import multihost_initialize
         multihost_initialize()
+    with STARTUP.phase("startup.backend"):
+        # the first touch of the backend: the TPU runtime starts here,
+        # unless the caller touched it first
+        jax.devices()
     spec = None
     if args.speculative_k > 0:
         from tpuserve.runtime.spec import SpecConfig
@@ -2206,14 +2229,12 @@ def build_server(argv=None):
     else:
         engine = Engine(ecfg, mesh=mesh)
     if args.multihost:
-        import jax
-
         from tpuserve.parallel import multihost
         if not multihost.is_coordinator():
             # Followers never serve HTTP: mirror the coordinator's steps
             # until it broadcasts OP_STOP, then exit.
             multihost.follower_loop(engine)
-            return None, args
+            return None
         multihost.MultihostCoordinator(engine)
     chat_template = None
     if args.chat_template:
@@ -2239,12 +2260,11 @@ def build_server(argv=None):
         weight_host_bytes=args.weight_host_bytes,
         weight_spill_dir=args.weight_spill_dir,
         allow_kv_migration=args.role == "decode"))
-    return server, args
+    return server
 
 
 def main(argv=None):
     """Start the server ``argv`` describes, wait for SIGTERM, drain."""
-    from tpuserve.utils import compile_cache
     compile_cache.configure()
     server, args = build_server(argv)
     if server is None:

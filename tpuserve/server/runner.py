@@ -19,9 +19,10 @@ from typing import Optional, Sequence, Union
 from tpuserve.models import transformer
 from tpuserve.runtime.clock import MONOTONIC
 from tpuserve.runtime.engine import Engine
-from tpuserve.runtime.hostprof import PROF
+from tpuserve.runtime.hostprof import PROF, STARTUP
 from tpuserve.runtime.request import RequestOutput, RequestState, SamplingParams
 from tpuserve.runtime.slo import ShedError
+from tpuserve.utils.compile_cache import LEDGER
 
 logger = logging.getLogger("tpuserve.server")
 
@@ -131,6 +132,8 @@ class AsyncEngineRunner:
         # routed rows at the last pass that wrote the per-expert counters
         self._moe_rows_exported = 0.0
         self._layer_calls_exported = 0
+        # compile-ledger events + warm-up calls already on /metrics
+        self._startup_exported = -1
         self._stop = threading.Event()
         self._wake = threading.Event()
         self._thread = threading.Thread(target=self._loop, daemon=True,
@@ -155,6 +158,9 @@ class AsyncEngineRunner:
         # token leaves); /healthz + /debug/engine report it and the
         # autoscaler's probe feeds it into tpuserve_cold_start_seconds
         self.cold_start_s: Optional[float] = None
+        # the compile ledger's totals as they stood at that token
+        # (/debug/engine startup.compile)
+        self.startup_compile: Optional[dict] = None
         # In-process SLO burn-rate evaluation (tpuserve/obs/burnrate.py):
         # set by the server when enabled.  Fed and evaluated ONLY on the
         # loop thread (observe at delivery, evaluate throttled in
@@ -390,6 +396,7 @@ class AsyncEngineRunner:
                 # all inside the measurement)
                 self.cold_start_s = round(
                     time.monotonic() - _BOOT_MONOTONIC, 6)  # tpulint: sync-ok(cold start is real wall seconds)
+                self.startup_compile = LEDGER.totals()
                 logger.info("cold start: first token %.3fs after boot",
                             self.cold_start_s)
             q = self._out_queues.get(out.request_id)
@@ -1146,6 +1153,26 @@ class AsyncEngineRunner:
                     body=body, **label), transformer.LAYER_TRACES[body])
                 _advance_counter(self.metrics.trunk_layer_calls.labels(
                     body=body, **label), n)
+        # the process's compile ledger and start-up spans: they move only
+        # while something compiles or warms, one comparison a cycle else
+        mark = LEDGER.events + STARTUP.counts["startup.warmup"]
+        if mark != self._startup_exported:
+            self._startup_exported = mark
+            led = LEDGER.totals()
+            for ctr, field in (
+                    (self.metrics.jit_trace_seconds, "trace_s"),
+                    (self.metrics.jit_lower_seconds, "lower_s"),
+                    (self.metrics.backend_compile_seconds, "backend_s"),
+                    (self.metrics.compile_cache_read_seconds,
+                     "cache_read_s"),
+                    (self.metrics.compile_requests, "requests"),
+                    (self.metrics.compile_cache_hits, "hits"),
+                    (self.metrics.compile_cache_misses, "misses")):
+                _advance_counter(ctr, led[field])
+            self.metrics.startup_build_seconds.set(
+                STARTUP.seconds["startup.build"])
+            self.metrics.startup_warmup_seconds.set(
+                STARTUP.seconds["startup.warmup"])
         # device telemetry (runtime/devprof.py): HBM watermark gauges,
         # per-sync-kind device seconds, ladder compile totals, capture
         # count.  Engines keep cumulative totals; counters advance by
