@@ -190,14 +190,21 @@ class Served:
             np.asarray([len(p) for p in prompts], np.int32), slot_ids,
             seats=np.arange(B))[0])
 
-    def packed(self, prompts, blk=8):
+    def packed(self, prompts, blk=8, riding=()):
         """Several prompts on one flat token axis, each starting on a
-        ``blk``-row boundary, as Engine._pack_ragged lays them out."""
-        starts, cursor = [], 0
+        ``blk``-row boundary, as Engine._pack_ragged lays them out.
+        ``riding``: a mixed step, in which each of these running sequences
+        (the first of the cache) rides with its last token, a decode row at
+        the head of the stream (flat row == sequence), and the prompts, the
+        next sequences', start behind the decode region.  A row of logits
+        a sequence, the riding ones first."""
+        B, n_dec = 4, len(riding)                       # a spare row
+        starts = []
+        cursor = transformer.decode_region(B, blk) if riding else 0
         for p in prompts:
             starts.append(cursor)
             cursor += -(-len(p) // blk) * blk
-        T, B = cursor + blk, 4                  # a padding block, a spare row
+        T = cursor + blk                                # a padding block
         tokens = np.zeros((T,), np.int32)
         positions = np.zeros((T,), np.int32)
         slot_ids = np.full((T,), PAD_SLOT, np.int32)
@@ -207,7 +214,13 @@ class Served:
         last_rows = np.zeros((B,), np.int32)
         tables = np.zeros((B, self.mb), np.int32)
         blk_seq = np.full((T // blk,), -1, np.int32)
-        for i, (p, s) in enumerate(zip(prompts, starts)):
+        for i, s in enumerate(riding):
+            tokens[i], positions[i] = s[-1], len(s) - 1
+            slot_ids[i] = self.slots(i, len(s) - 1, 1)[0]
+            row_seq[i] = q_starts[i] = last_rows[i] = i
+            kv_lens[i], q_lens[i] = len(s), 1
+            tables[i] = self.tables[i]
+        for i, (p, s) in enumerate(zip(prompts, starts), start=n_dec):
             n = len(p)
             tokens[s:s + n], positions[s:s + n] = p, np.arange(n)
             slot_ids[s:s + n], row_seq[s:s + n] = self.slots(i, 0, n), i
@@ -215,14 +228,16 @@ class Served:
             q_starts[i], last_rows[i] = s, s + n - 1
             tables[i] = self.tables[i]
             blk_seq[s // blk:(s + -(-n // blk) * blk) // blk] = i
+        used = n_dec + len(prompts)
         seats = np.full((B,), SEATS, np.int32)          # spare row: trash
-        seats[:len(prompts)] = np.arange(len(prompts))
+        seats[:used] = np.arange(used)
         logits = self._run(
             transformer.forward_ragged, tokens, positions, slot_ids, row_seq,
-            tables, kv_lens, q_starts, q_lens, np.zeros((2,), np.int32),
-            blk_seq, last_rows, seats=seats, ragged_blk=blk,
-            decode_rows=False)[0]
-        return np.asarray(logits)[:len(prompts)]
+            tables, kv_lens, q_starts, q_lens,
+            np.asarray([n_dec, -(-n_dec // blk)], np.int32), blk_seq,
+            last_rows, seats=seats, ragged_blk=blk,
+            decode_rows=bool(riding))[0]
+        return np.asarray(logits)[:used]
 
     def chunks(self, prompt, C=16):
         """One prompt, ``C`` rows a dispatch (state and convolution memory
@@ -313,9 +328,10 @@ def then_decode(served, seqs, first_logits):
 
 def run_route(family, cfg, params, route, attn_impl):
     """(B, L) ``prefill``, a ``packed`` prefill of the family's three uneven
-    prompts, or one prompt over three ``chunks``; then ``decode_step`` and
-    a fused ``decode_multi`` window.  ``pallas``: the kernels in interpret
-    mode.  Returns the hand-driven cache."""
+    prompts, one prompt over three ``chunks``, or a ``mixed`` step (two
+    running rows riding the third prompt's dispatch); then ``decode_step``
+    and a fused ``decode_multi`` window.  ``pallas``: the kernels in
+    interpret mode.  Returns the hand-driven cache."""
     if route == "chunks":
         seqs = prompts_of(family.chunked)
         served = Served(family, cfg, params, 1, attn_impl)
@@ -326,6 +342,15 @@ def run_route(family, cfg, params, route, attn_impl):
                 ref_logits(family, params, cfg, seqs[0], [upto - 1])[0],
                 atol=family.atol)
         first = [per_chunk[-1]]
+    elif route == "mixed":
+        seqs = prompts_of(*family.prompts)
+        served = Served(family, cfg, params, 3, attn_impl)
+        for s, logits in zip(seqs[:2], served.packed(seqs[:2])):
+            np.testing.assert_allclose(
+                logits, ref_logits(family, params, cfg, s, [len(s) - 1])[0],
+                atol=family.atol)
+            s.append(int(np.argmax(logits)))
+        first = served.packed(seqs[2:], riding=seqs[:2])
     else:
         seqs = prompts_of(*family.prompts)
         served = Served(family, cfg, params, 3, attn_impl)

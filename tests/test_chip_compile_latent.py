@@ -122,13 +122,18 @@ def test_a_page_of_576_lanes_as_it_is_has_no_whole_tiles_to_copy(one_chip):
         _decode_call(one_chip, 576).compile()
 
 
-def test_the_latent_ragged_kernel_compiles_at_its_block(one_chip,
-                                                        monkeypatch):
-    """The packed prefill's kernel at the top rung of its ladder: 128
-    query heads of a 640-lane latent take a ragged block of 8 rows (1,024
-    query rows a dot; 16 pass the kernel's fast memory), one KV head's
-    pages land as (page, 640) with no head row to pad, and the group of
-    pages stays 16 deep."""
+@pytest.mark.parametrize("tokens,seqs,decode_rows", [
+    (8192, PREFILL_SEQS, False), (2048, ROWS, True)],
+    ids=["packed-top-rung", "mixed-top-rung"])
+def test_the_latent_ragged_kernel_compiles_at_its_block(
+        tokens, seqs, decode_rows, one_chip, monkeypatch):
+    """The packed prefill's kernel at the top rung of its ladder, and a
+    mixed step's at the top rung of its own (2,048 rows, the first 128 the
+    seats' decode rows, each against its own pages: the kernel WITH its
+    decode part): 128 query heads of a 640-lane latent take a ragged block
+    of 8 rows (1,024 query rows a dot; 16 pass the kernel's fast memory),
+    one KV head's pages land as (page, 640) with no head row to pad, and
+    the group of pages stays 16 deep."""
     from tpuserve.ops import pallas_ragged_attention as ragged
     from tpuserve.ops.pallas_paged_attention import _clamp_to_vmem_budget
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -139,16 +144,17 @@ def test_the_latent_ragged_kernel_compiles_at_its_block(one_chip,
     assert _clamp_to_vmem_budget(16, blk, PAGE, 1, lanes, 2, hq, 2,
                                  rows_per_dot=True, flat_page=True) \
         == (16, blk)
-    tokens, seqs = 8192, S((PREFILL_SEQS,), jnp.int32)
+    per_seq = S((seqs,), jnp.int32)
 
     def call(q, pages, tables, kv, qs, ql, meta, blocks):
         return ragged.ragged_paged_attention(
             q, pages, None, tables, kv, qs, ql, meta, blocks, SCALE,
-            interpret=False, blk_q=blk, decode_rows=False, v_lanes=v_lanes)
+            interpret=False, blk_q=blk, decode_rows=decode_rows,
+            v_lanes=v_lanes)
     text = jax.jit(call).lower(
         S((tokens, hq, lanes), jnp.bfloat16),
         S((POOL, PAGE, 1, lanes), jnp.bfloat16),
-        S((PREFILL_SEQS, MAX_PAGES), jnp.int32), seqs, seqs, seqs,
+        S((seqs, MAX_PAGES), jnp.int32), per_seq, per_seq, per_seq,
         S((2,), jnp.int32), S((tokens // blk,), jnp.int32)
     ).compile().as_text()
     assert re.search(rf"%_ragged_paged_attention(?:\.\d+)? = bf16"
@@ -156,19 +162,24 @@ def test_the_latent_ragged_kernel_compiles_at_its_block(one_chip,
     assert not re.search(rf"bf16\[{POOL},\S* copy\(", text)
 
 
-@pytest.mark.parametrize("program,tokens", [
-    ("decode_multi", 0), ("forward_ragged", 8192), ("prefill_chunk", 0)])
-def test_the_openpangu_cell_fits_the_chip(program, tokens, one_chip,
+@pytest.mark.parametrize("program,tokens,riding", [
+    ("decode_multi", 0, 0), ("forward_ragged", 8192, 0),
+    ("prefill_chunk", 0, 0), ("forward_ragged", 2048, ROWS)],
+    ids=["decode_multi", "forward_ragged", "prefill_chunk", "mixed"])
+def test_the_openpangu_cell_fits_the_chip(program, tokens, riding, one_chip,
                                           monkeypatch):
     """The cell's whole trunks at the published widths: the first 7 layers
     (3 dense, 4 of experts), 16 of 256 experts, 19,200 vocabulary rows; a
     fused decode window of 128 rows, the top rung of the packed-prefill
-    ladder and a chunk against cached latents, beside a pool of 10,104
+    ladder, a chunk against cached latents and (``riding``: the decode
+    rows at the head of the stream) a mixed step at the top rung of its
+    ladder, 2,048 rows whose attention stands WHOLE (no
+    ``MLA_PACKED_ROWS`` pieces with decode rows), beside a pool of 10,104
     latent pages (2.90 GB: what 0.9 of the chip leaves after 12.32 GB of
     weights).
     The chip's compiler refuses what does not fit 16 GB.  The pool is
     aliased in and out WHOLE and EXACTLY (2 B x 640 lanes x 32 tokens x 7
-    layers a page, no V pages, no copy), every attention call of the three
+    layers a page, no V pages, no copy), every attention call of the
     programs is a Pallas kernel's latent entry, and decode's keeps the
     name the benchmark's readers count steps by, once a layer."""
     from test_scopes import trunk_programs
@@ -182,8 +193,9 @@ def test_the_openpangu_cell_fits_the_chip(program, tokens, one_chip,
     assert (blk, cfg.cache_head_dim) == (8, 640)
     fn, args, kwargs = trunk_programs(
         cfg, S, place, rows=ROWS, steps=8, tokens=tokens or blk, blk=blk,
-        prompts=PREFILL_SEQS, chunk=CHUNK, block_size=PAGE, num_blocks=POOL,
-        max_blocks=MAX_PAGES, attn_impl="pallas")[program]
+        prompts=riding or PREFILL_SEQS, chunk=CHUNK, block_size=PAGE,
+        num_blocks=POOL, max_blocks=MAX_PAGES, attn_impl="pallas",
+        decode_rows=bool(riding))[program]
     compiled = fn.lower(*args, **kwargs).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == POOL * PAGE * 640 * 2 * 7

@@ -294,14 +294,16 @@ CELL_ROUTES = [
     ("mellum2-12b-l12.batch", 5450, "mixed", "a decode step is bound by its weights"),
     ("k-exaone-236b-ep8-l8.reason", 3108, "mixed", "a decode step is bound by its weights"),
     ("olmo-hybrid-7b-l16.reason", 2471, "phase_split", "recurrent state"),
-    ("openpangu-ultra-718b-ep16-l7.reason", 10096, "phase_split", "latent attention"),
+    ("openpangu-ultra-718b-ep16-l7.reason", 10096, "mixed", "a decode step is bound by its weights"),
+    # two latent layers, and they do not decide it: the state is asked first
+    ("ling-3.0-flash-vl-ep8-l12.reason", 16512, "phase_split", "recurrent state"),
 ]
 
 
 @pytest.mark.parametrize("cell,num_blocks,route,why", CELL_ROUTES,
                          ids=[c[0] for c in CELL_ROUTES])
 def test_route_is_observed_not_configured(cell, num_blocks, route, why):
-    """Which of the benchmark's seven engines ride and which do not, and
+    """Which of the benchmark's eight engines ride and which do not, and
     why: the engine's own functions on each configuration AS IT RUNS
     (every layer, the share of experts and vocabulary it holds; shapes
     alone, nothing is allocated) beside the pool the chip left it."""
@@ -369,8 +371,15 @@ def _bf16_engine(monkeypatch, *, floor=0, model="tiny-qwen3", num_blocks=64,
     ({"floor": 1 << 30}, False, "a decode step's weights are read in less"),
     ({"model": "tiny-falcon-h1", "cache_dtype": "float32"}, False,
      "recurrent state"),
-    ({"model": "tiny-pangu", "cache_dtype": "float32"}, False,
-     "latent attention"),
+    # latent attention is observed like the rest (PR 58): one latent vector
+    # a token a layer is a small K/V read beside any weights
+    ({"model": "tiny-pangu", "cache_dtype": "float32"}, True,
+     "a decode step is bound by its weights"),
+    ({"model": "tiny-pangu", "cache_dtype": "float32", "floor": 1 << 30},
+     False, "a decode step's weights are read in less"),
+    # latent layers beside recurrent ones: the state is asked first
+    ({"model": "tiny-ling-hybrid", "cache_dtype": "float32"}, False,
+     "recurrent state"),
     ({"cache_dtype": "int8"}, False, "pages narrower"),
     ({"mesh": {"tp": 2}}, False, "the ragged kernel has no tp"),
     ({"mesh": {"pp": 2}}, False, "the ragged trunk is neither"),
@@ -378,7 +387,8 @@ def _bf16_engine(monkeypatch, *, floor=0, model="tiny-qwen3", num_blocks=64,
     ({"floor": 1 << 30, "scheduler": {"mixed_batching": True}}, True,
      "forced by mixed_batching"),
 ], ids=["weights-bound", "kv-bound", "host-bound", "recurrent", "mla",
-        "int8-kv", "tp-mesh", "pp", "forced"])
+        "mla-host-bound", "mla-beside-state", "int8-kv", "tp-mesh", "pp",
+        "forced"])
 def test_the_engine_builds_the_scheduler_it_observed(monkeypatch, build,
                                                      rides, why):
     eng = _bf16_engine(monkeypatch, **build)

@@ -203,12 +203,15 @@ def test_absorbed_decode_is_the_naive_form_at_float32(whole, attn_impl):
 
 @pytest.mark.parametrize("route,attn_impl", [
     ("prefill", "reference"), ("packed", "reference"), ("packed", "pallas"),
-    ("chunks", "reference"), ("chunks", "pallas")])
+    ("chunks", "reference"), ("chunks", "pallas"),
+    ("mixed", "reference"), ("mixed", "pallas")])
 def test_every_route_matches_the_reference_under_a_share(
         shared, route, attn_impl):
     """(B, L) prefill (the XLA path's alone: it has no Pallas form), a
     packed prefill of three uneven prompts, a prompt over three chunks (the
-    second and third against cached latents); then ``decode_step`` and a
+    second and third against cached latents), a mixed step (two running
+    rows, each against its own latent pages, riding the third prompt's
+    dispatch); then ``decode_step`` and a
     fused ``decode_multi`` window, with 4 of 16 experts held.  ``pallas``:
     the paged attention kernels' latent entry in interpret mode (the
     grouped product is a kernel on both)."""
@@ -349,26 +352,47 @@ def test_the_references_own_switches_are_live(shared):
 # the engine
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("multi_step,attn_impl", [
-    (1, "reference"), (4, "reference"), (4, "pallas")])
+@pytest.mark.parametrize("multi_step,attn_impl,rides", [
+    (1, "reference", False), (4, "reference", False), (4, "pallas", False),
+    (4, "reference", True), (4, "pallas", True)])
 def test_served_greedy_tokens_are_the_references(shared, multi_step,
-                                                 attn_impl):
+                                                 attn_impl, rides,
+                                                 monkeypatch):
     """Through ``Engine.step``: packed prefills, then decode steps or fused
     windows, the latent kernels under ``pallas``; and the counter of
     context tokens attended against latent pages is the step records'
-    ``ctx_tokens`` of the dispatches that are no prefill, summed."""
+    ``ctx_tokens`` of the dispatches that are no prefill, summed.
+    ``rides``: the engine observes that its decode step is bound by its
+    weights (the floor under which the host binds it steered to 0, as
+    ``tests/test_mixed.py`` does), so the second and third prompts arrive
+    on mixed steps that carry the running rows."""
+    from tpuserve.runtime import engine as engine_mod
     cfg, params = shared
+    if rides:
+        monkeypatch.setattr(engine_mod, "HOST_BOUND_WEIGHT_BYTES", 0)
     eng = engine_for(FAMILY, params, cfg, multi_step=multi_step,
                      attn_impl=attn_impl)
     assert eng.attn_impl == attn_impl and eng._packed_prefill
-    prompts = prompts_of(40, 9, seed=5)
-    outs = eng.generate(prompts, SamplingParams(
-        max_tokens=10, temperature=0.0, ignore_eos=True))
-    for p, o in zip(prompts, outs):
-        assert o.output_token_ids == ref_greedy(FAMILY, params, cfg, p, 10)
+    assert eng._route["rides"] is rides, eng._route
+    prompts = prompts_of(40, 9, 21, seed=5)
+    sampling = SamplingParams(max_tokens=10, temperature=0.0,
+                              ignore_eos=True)
+    rids = [eng.add_request(prompt_token_ids=prompts[0], params=sampling)]
+    got, cycles = {}, 0
+    while eng.has_work():
+        for o in eng.step():
+            got.setdefault(o.request_id, []).extend(o.new_token_ids)
+        cycles += 1
+        if cycles == 2:         # the first prompt's row is running by now
+            rids += [eng.add_request(prompt_token_ids=p, params=sampling)
+                     for p in prompts[1:]]
+    for p, rid in zip(prompts, rids):
+        assert got[rid] == ref_greedy(FAMILY, params, cfg, p, 10)
     assert eng.block_manager.num_seqs() == 0
-    steps = [s for s in eng.flight.steps_snapshot(1024)
-             if s["kind"] in ("decode", "window")]
+    records = eng.flight.steps_snapshot(1024)
+    assert ("mixed" in {s["kind"] for s in records}) is rides
+    assert (eng.stats.decode_tokens_ridden > 0) is rides
+    steps = [s for s in records if s["kind"] in ("decode", "window", "mixed")]
     assert steps and eng.stats.kv_latent_tokens_attended_total == sum(
         s["ctx_tokens"] for s in steps) > 0
 

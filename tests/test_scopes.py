@@ -81,11 +81,13 @@ def scope_of(op_name: str) -> tuple:
 def trunk_programs(cfg, S=jax.ShapeDtypeStruct, place=lambda tree: tree, *,
                    rows=4, steps=4, tokens=64, blk=8, prompts=4, chunk=16,
                    block_size=4, num_blocks=16, max_blocks=8,
-                   attn_impl="reference") -> dict:
+                   attn_impl="reference", decode_rows=False) -> dict:
     """``{program: (jitted trunk, args, keyword args)}`` of shapes alone:
     a fused decode window over ``rows`` rows, a packed prefill of
-    ``tokens`` flat tokens, one chunk of one prompt.  ``S`` makes a shape,
-    ``place`` puts a tree of shapes where the caller compiles for."""
+    ``tokens`` flat tokens (``decode_rows``: a mixed step, whose first rows
+    are ``prompts`` sequences' decode rows), one chunk of one prompt.  ``S``
+    makes a shape, ``place`` puts a tree of shapes where the caller
+    compiles for."""
     i32 = jnp.int32
     params = place(jax.eval_shape(lambda: init_params(cfg, 0)))
     cache_cfg = CacheConfig(block_size=block_size, num_blocks=num_blocks,
@@ -112,7 +114,8 @@ def trunk_programs(cfg, S=jax.ShapeDtypeStruct, place=lambda tree: tree, *,
             S((tokens,), i32), S((tokens,), i32),
             S((prompts, max_blocks), i32), seqs, seqs, seqs, S((2,), i32),
             S((tokens // blk,), i32), seqs, kv, *tail(prompts)),
-            dict(ragged_blk=blk, attn_impl=attn_impl, decode_rows=False)),
+            dict(ragged_blk=blk, attn_impl=attn_impl,
+                 decode_rows=decode_rows)),
         "prefill_chunk": (transformer.prefill_chunk, (
             params, cfg, S((1, chunk), i32), S((1,), i32), S((1,), i32),
             S((1, chunk), i32), S((1, max_blocks), i32), kv, *tail(1)),

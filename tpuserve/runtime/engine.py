@@ -477,7 +477,9 @@ def route_excluded(model_cfg: ModelConfig, *, staged: bool, mesh: bool,
     whatever its decode is bound by; None where one may be.  ``staged``:
     a pipeline or multi-host engine; ``mesh``: any mesh; ``packed``: the
     engine's batched prefills take the ragged trunk
-    (``Engine._packed_prefill``: pages in the model's own dtype)."""
+    (``Engine._packed_prefill``: pages in the model's own dtype).  The
+    kind of attention is no reason: a latent engine's route falls to
+    ``decode_route``'s two numbers, its latent pages counted as K/V."""
     if model_cfg.has_state:
         return ("recurrent state: a chunk of the scan would straddle the "
                 "decode rows' sequences")
@@ -489,9 +491,6 @@ def route_excluded(model_cfg: ModelConfig, *, staged: bool, mesh: bool,
     if not packed:
         return ("pages narrower than the model's dtype: a mixed step "
                 "attends a prompt's K/V read back from them")
-    if model_cfg.is_mla:
-        return ("latent attention: the ragged kernel's latent entry has "
-                "not served decode rows on the chip")
     return None
 
 
@@ -904,9 +903,9 @@ class Engine:
         # every dispatch that carries prompt tokens carries the running
         # decode rows too, so the weights are read once for both.  The
         # route is observed from the model's shape and the pool just
-        # sized (decode_route), no option and no model's name;
-        # SchedulerConfig.mixed_batching (--mixed-batching) still forces
-        # it, under the exclusions above.
+        # sized (decode_route: latent pages count as K/V), no option and
+        # no model's name; SchedulerConfig.mixed_batching
+        # (--mixed-batching) still forces it, under the exclusions above.
         self._route = self._observe_route(sched_cfg.mixed_batching)
         if self._route["rides"] and not sched_cfg.mixed_batching:
             sched_cfg = dataclasses.replace(sched_cfg, mixed_batching=True)
